@@ -159,15 +159,12 @@ def iter_eqns(jaxpr) -> Iterable[Any]:
 
 def eqn_frame(eqn) -> Optional[Tuple[str, int, str]]:
     """(file, line, function) of the user code that traced this equation."""
-    try:
-        from jax._src import source_info_util
+    from jax._src import source_info_util
 
-        fr = source_info_util.user_frame(eqn.source_info)
-        if fr is None:
-            return None
-        return fr.file_name, fr.start_line, fr.function_name
-    except Exception:  # noqa: BLE001 - attribution is best-effort
+    fr = source_info_util.user_frame(eqn.source_info.traceback)
+    if fr is None:
         return None
+    return fr.file_name, fr.start_line, fr.function_name
 
 
 # HLO instruction: `%name = <shape> <opcode>(...)`. The optional -start

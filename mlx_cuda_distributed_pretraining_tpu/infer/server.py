@@ -40,6 +40,7 @@ import argparse
 import json
 import queue as queue_mod
 import struct
+import sys
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -797,6 +798,9 @@ def main(argv=None) -> int:
                    help="membership slot index under --fleet-dir")
     a = p.parse_args(argv)
 
+    from ..utils.compile_cache import enable_compilation_cache
+
+    print(enable_compilation_cache(), file=sys.stderr)
     mesh = None
     if a.mesh:
         if a.engine != "batch":

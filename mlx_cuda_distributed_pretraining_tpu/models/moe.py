@@ -12,7 +12,7 @@ real block with two interchangeable dispatch implementations
   scatter-add combine. Every shape is static (sort + gather, no
   data-dependent shapes) and **no token is ever dropped** — there is no
   expert capacity. On ``ep`` meshes the sorted dispatch drops below GSPMD
-  via ``parallel/compat.shard_map``: each shard routes its local tokens,
+  via ``jax.shard_map``: each shard routes its local tokens,
   exchanges rows with the owning expert shard through a pair of
   ``all_to_all`` collectives with static per-destination send slots, and
   scatter-adds the returned rows (mirroring how
@@ -338,13 +338,7 @@ def _usable_ep_mesh(args, num_experts: int):
     ep = mesh.shape.get("ep", 1)
     if num_experts % max(ep, 1):
         return None
-    try:
-        from jax._src import core as _core
-
-        active = set(_core.unsafe_get_axis_names())
-    except Exception:  # pragma: no cover - private-API drift
-        active = set()
-    if active & set(mesh.axis_names):
+    if set(jax.sharding.get_abstract_mesh().manual_axes) & set(mesh.axis_names):
         return None
     return mesh
 
@@ -367,7 +361,6 @@ def _grouped_moe_ep(
     dropless); a positive factor shrinks the exchange to
     ``factor · TK / ep`` and overflow beyond it is dropped and counted.
     """
-    from ..parallel.compat import shard_map
     from ..parallel.sharding_rules import moe_dispatch_specs
 
     B, S, D = x.shape
@@ -459,7 +452,7 @@ def _grouped_moe_ep(
             (TK - keep.sum()).astype(jnp.float32), tuple(mesh.axis_names))
         return out.reshape(b_l, s_l, D), dropped
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(specs["activation"], specs["gate"], specs["gate"],
                   specs["expert_weight"], specs["expert_weight"],

@@ -1,14 +1,18 @@
 """ctypes bindings for the native C++ data-plane (dataplane.cpp).
 
 Compiles the shared library on first use with g++ (cached next to the
-source, rebuilt when the source is newer). Every entry point degrades to
-the pure-Python path when the toolchain is unavailable — callers check
-``available()`` or just get ``None`` from ``byte_pack_docs``.
+source; rebuilt when it is absent or when the source's content hash differs
+from the one recorded at build time — a copy of the tree does not preserve
+mtimes, and the library itself is not committed). Every entry point
+degrades to the pure-Python path when the toolchain is unavailable —
+callers check ``available()`` or just get ``None`` from ``byte_pack_docs``;
+``status()`` says which of the three happened.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,10 +23,26 @@ import numpy as np
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "dataplane.cpp")
 _LIB_PATH = os.path.join(_HERE, "_dataplane.so")
+# Hash of the source the library was built from, written next to it.
+_HASH_PATH = _LIB_PATH + ".src-sha256"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_status = "not loaded"
+
+
+def _src_hash() -> str:
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _built_from() -> Optional[str]:
+    try:
+        with open(_HASH_PATH) as f:
+            return f.read().strip()
+    except OSError:
+        return None
 
 
 def _build() -> bool:
@@ -31,25 +51,29 @@ def _build() -> bool:
             ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", _LIB_PATH, _SRC],
             check=True, capture_output=True, timeout=120,
         )
-        return True
     except (OSError, subprocess.SubprocessError):
         return False
+    with open(_HASH_PATH, "w") as f:
+        f.write(_src_hash())
+    return True
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, _status
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
+        _status = "unavailable (python packer)"
         stale = (not os.path.exists(_LIB_PATH)
-                 or os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC))
+                 or _built_from() != _src_hash())
         if stale and not _build():
             return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
         except OSError:
             return None
+        _status = "built" if stale else "reused"
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i64p = ctypes.POINTER(ctypes.c_int64)
         i32p = ctypes.POINTER(ctypes.c_int32)
@@ -66,6 +90,14 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def status() -> str:
+    """``built`` (compiled from dataplane.cpp by this process), ``reused``
+    (a library built from this exact source was already there) or
+    ``unavailable (python packer)`` (no toolchain: callers pack in Python)."""
+    _load()
+    return _status
 
 
 def byte_pack_docs(

@@ -8,11 +8,12 @@ could theoretically sustain:
 
     MFU = flops_per_token * tokens_per_second / (peak_flops_per_chip * n_chips)
 
-Peak FLOPs are detected from ``jax.devices()[0].device_kind`` for known
-TPU/GPU generations (bf16 dense peak, matching how the matmuls actually
-run) and can be forced with ``GRAFT_PEAK_FLOPS`` for unlisted hardware.
-On CPU or unknown chips detection returns None and callers report
-``mfu=unknown`` — same convention as bench.py's vocab-less rows.
+Peak FLOPs are looked up from ``jax.devices()[0].device_kind`` for the
+TPU generations the installed libtpu drives (bf16 dense peak, matching how
+the matmuls actually run); ``GRAFT_PEAK_FLOPS`` forces a value. On CPU the
+lookup returns None and callers report ``mfu=unknown``. An accelerator that
+is missing from the table is an error, not a default: a number computed
+against no peak, or the wrong one, must not reach a log line.
 
 Decode is bandwidth-bound, not FLOPs-bound: every generated token must
 stream the (active) weight plane from HBM, so the decode roofline is
@@ -37,6 +38,8 @@ from typing import Any, Dict, Optional
 
 # bf16 dense peak FLOPs per chip, keyed by device_kind substring
 # (checked in order — first match wins, so more specific kinds first).
+# Source: Google Cloud TPU documentation, per-generation system pages
+# (v5e: 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s).
 _PEAK_BY_KIND = (
     ("v6e", 918e12), ("v6 lite", 918e12),
     ("v5p", 459e12),
@@ -44,9 +47,6 @@ _PEAK_BY_KIND = (
     ("v4", 275e12),
     ("v3", 123e12),
     ("v2", 45e12),
-    ("h100", 989e12),
-    ("a100", 312e12),
-    ("v100", 125e12),
 )
 
 PEAK_FLOPS_ENV = "GRAFT_PEAK_FLOPS"
@@ -60,9 +60,6 @@ _HBM_BW_BY_KIND = (
     ("v4", 1228e9),
     ("v3", 900e9),
     ("v2", 700e9),
-    ("h100", 3350e9),
-    ("a100", 2039e9),
-    ("v100", 900e9),
 )
 
 HBM_BW_ENV = "GRAFT_HBM_BW"
@@ -118,56 +115,46 @@ def model_flops_per_token(model_cfg: Any, n_params: int, seq_len: int) -> float:
     return flops_per_token(n_active, int(model_cfg.num_layers), int(seq_len), d_attn)
 
 
-def peak_flops_per_chip(device_kind: Optional[str] = None) -> Optional[float]:
-    """bf16 peak FLOPs for one chip, or None when undetectable.
-
-    ``GRAFT_PEAK_FLOPS`` (float, FLOPs) overrides detection — the escape
-    hatch for hardware missing from the table.
-    """
-    env = os.environ.get(PEAK_FLOPS_ENV)
+def _per_chip(table, env_name: str, what: str,
+              device_kind: Optional[str]) -> Optional[float]:
+    env = os.environ.get(env_name)
     if env:
         try:
             return float(env)
         except ValueError:
             pass
     if device_kind is None:
-        try:
-            import jax
+        import jax
 
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            return None
+        device_kind = jax.devices()[0].device_kind
     kind = str(device_kind).lower()
-    for needle, peak in _PEAK_BY_KIND:
+    if kind == "cpu":
+        return None
+    for needle, value in table:
         if needle in kind:
-            return peak
-    return None
+            return value
+    raise ValueError(
+        f"no {what} listed for device_kind {device_kind!r}: add it to the "
+        f"table in obs/flops.py with its source (or set {env_name})")
+
+
+def peak_flops_per_chip(device_kind: Optional[str] = None) -> Optional[float]:
+    """bf16 peak FLOPs for one chip; None on CPU; an accelerator missing
+    from the table raises.
+
+    ``GRAFT_PEAK_FLOPS`` (float, FLOPs) overrides the lookup.
+    """
+    return _per_chip(_PEAK_BY_KIND, PEAK_FLOPS_ENV, "peak FLOP/s", device_kind)
 
 
 def hbm_bw_per_chip(device_kind: Optional[str] = None) -> Optional[float]:
-    """Peak HBM bytes/s for one chip, or None when undetectable.
+    """Peak HBM bytes/s for one chip; None on CPU; an accelerator missing
+    from the table raises.
 
-    ``GRAFT_HBM_BW`` (float, bytes/s) overrides detection, mirroring
+    ``GRAFT_HBM_BW`` (float, bytes/s) overrides the lookup, mirroring
     ``GRAFT_PEAK_FLOPS``.
     """
-    env = os.environ.get(HBM_BW_ENV)
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    if device_kind is None:
-        try:
-            import jax
-
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            return None
-    kind = str(device_kind).lower()
-    for needle, bw in _HBM_BW_BY_KIND:
-        if needle in kind:
-            return bw
-    return None
+    return _per_chip(_HBM_BW_BY_KIND, HBM_BW_ENV, "HBM bandwidth", device_kind)
 
 
 def quantizable_weight_counts(model_cfg: Any) -> tuple:

@@ -25,6 +25,13 @@ models/attention/flash_attention.py:100,134-151). This is the real thing:
 
 Runs in Pallas interpret mode off-TPU, so the same code path is exercised
 by the CPU test suite.
+
+Under a device mesh the call wraps itself in a ``jax.shard_map`` over the
+batch and head dims (:func:`_mesh_partition`): GSPMD cannot partition a
+Mosaic kernel ("Mosaic kernels cannot be automatically partitioned"), so
+left to the partitioner every sharded training config with flash attention
+fails to lower on a TPU. The wrap is the same on CPU, where the interpreter
+would not need it, so the mesh tests check the program the chip runs.
 """
 
 from __future__ import annotations
@@ -37,16 +44,10 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from .masks import NEG_INF, MaskMod, ScoreMod
-
-try:  # pltpu only resolves on TPU-enabled jaxlib builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
 
 # Lane width of the TPU vector unit: scratch vectors are padded to a full
 # register row so stores never touch partial lanes.
@@ -94,26 +95,21 @@ def _interpret() -> bool:
 
 def _vmem_spec(block_shape=None, index_map=None):
     kwargs = {}
-    if _VMEM is not None and not _interpret():
-        kwargs["memory_space"] = _VMEM
+    if not _interpret():
+        kwargs["memory_space"] = pltpu.VMEM
     if block_shape is None:
         return pl.BlockSpec(**kwargs)
     return pl.BlockSpec(block_shape, index_map, **kwargs)
 
 
 def _scratch(shape, dtype=jnp.float32):
-    if pltpu is None:  # pragma: no cover - this jaxlib has pltpu even on CPU
-        raise RuntimeError(
-            "flash_attention needs jax.experimental.pallas.tpu (for VMEM "
-            "scratch shapes, also used by interpret mode); use "
-            "attention_type='simple' on builds without it")
     return pltpu.VMEM(shape, dtype)
 
 
 def _compiler_params(n_parallel: int, n_total: int):
     """Mark leading grid dims parallel, trailing (reduction) dims arbitrary
     so Mosaic knows scratch state only flows along the last dim."""
-    if pltpu is None or _interpret():
+    if _interpret():
         return None
     sem = ("parallel",) * n_parallel + ("arbitrary",) * (n_total - n_parallel)
     return pltpu.CompilerParams(dimension_semantics=sem)
@@ -612,6 +608,35 @@ def _cached_core(mask_fn, score_fn, mask_type, window, prefix_len, block_q,
                            block_q, block_kv, scale, canonical_mask)
 
 
+def _mesh_partition(batch: int, q_heads: int, kv_heads: int, shard_heads: bool):
+    """``(mesh, spec, manual_axes)`` to shard_map the kernel with, or None
+    when no mesh is active or this trace is already manual over all of it
+    (ring attention, the overlap schedule, the MoE dispatch).
+
+    ``spec`` is for the [B, S, H, D] operands: batch over the data axes
+    (as ``parallel.sharding_rules.batch_pspec``) when it divides, heads
+    over ``tp`` when both head counts divide — a GQA group never straddles
+    shards, since Hq/tp = G * Hkv/tp. The sequence stays whole: splitting
+    it is ring attention's job. Axes the spec leaves out hold replicas.
+    """
+    from ..parallel.context import current_mesh
+
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return None
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
+    free = [a for a in mesh.axis_names if mesh.shape[a] > 1 and a not in manual]
+    if not free:
+        return None
+    data = tuple(a for a in ("dp", "fsdp", "ep") if a in free)
+    if batch % math.prod(mesh.shape[a] for a in data):
+        data = ()
+    tp = "tp" if (shard_heads and "tp" in free
+                  and q_heads % mesh.shape["tp"] == 0
+                  and kv_heads % mesh.shape["tp"] == 0) else None
+    return mesh, P(data or None, None, tp, None), set(free)
+
+
 # Defaults from an on-chip sweep (scripts/bench_attention.py) on TPU v5e:
 # (256, 512) is within noise of the best (block_q, block_kv) across
 # seq 1024-8192 for D in {64, 128}; override per-call or via env.
@@ -655,6 +680,19 @@ def flash_attention(
     B, Sq, Hq, D = q.shape
     _, Skv, Hkv, _ = k.shape
     scale = (D ** -0.5) if scale is None else scale
+
+    # A score program may read the global head index, so heads stay whole
+    # under one.
+    part = _mesh_partition(B, Hq, Hkv, shard_heads=score_fn is None)
+    if part is not None:
+        mesh, spec, manual_axes = part
+        local = functools.partial(
+            flash_attention, mask_type=mask_type, window_size=window_size,
+            prefix_len=prefix_len, scale=scale, block_q=block_q,
+            block_kv=block_kv, mask_fn=mask_fn, score_fn=score_fn)
+        return jax.shard_map(
+            local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            axis_names=manual_axes, check_vma=False)(q, k, v)
 
     block_q = fit_block(block_q, Sq)
     block_kv = fit_block(block_kv, Skv)

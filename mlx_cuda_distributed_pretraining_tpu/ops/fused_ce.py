@@ -163,14 +163,7 @@ def fused_cross_entropy_sp(
                                      with_z=True)
         return jax.lax.psum((nll, z), tuple(mesh.axis_names))
 
-    # Current API straight off jax when present; the compat shim only
-    # backfills the deprecated experimental path (ROADMAP: trainer-side
-    # collectives off the shim).
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from ..parallel.compat import shard_map as sm
-
-    fn = sm(local, mesh=mesh, in_specs=tuple(in_specs),
+    fn = jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
             out_specs=(P(), P()), check_vma=False)
     nll_sum, z_sum = fn(*args)
     if with_z:

@@ -44,11 +44,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pragma: no cover - pltpu imports fine on CPU jaxlib builds
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "DEFAULT_BLOCK_T",
@@ -119,7 +115,7 @@ def tile_experts(group_sizes: jnp.ndarray, n_tiles: int, block_t: int) -> jnp.nd
 
 
 def _compiler_params(semantics):
-    if pltpu is None or _interpret():
+    if _interpret():
         return None
     return pltpu.CompilerParams(dimension_semantics=semantics)
 
@@ -352,6 +348,4 @@ def gmm(
     if backend != "pallas":
         raise ValueError(
             f"unknown gmm backend {backend!r} (pallas|blocked|ragged)")
-    if pltpu is None:  # pragma: no cover - pltpu ships with this jaxlib
-        raise RuntimeError("gmm pallas backend needs jax.experimental.pallas.tpu")
     return _gmm_pallas_diff(x, w, group_sizes.astype(jnp.int32), block_t, block_n)

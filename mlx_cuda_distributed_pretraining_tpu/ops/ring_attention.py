@@ -38,7 +38,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..parallel.compat import axis_size as _axis_size
 from .masks import NEG_INF, MaskMod
 
 
@@ -92,7 +91,7 @@ def _ring_attention_flash(q, k, v, axis_name: str, scale: float,
     B, Sl, Hq, D = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     kw = dict(block_q=block_q, block_kv=block_kv, scale=scale)
 
     @jax.custom_vjp
@@ -239,7 +238,7 @@ def _ring_attention_flash_sw(q, k, v, axis_name: str, scale: float,
     B, Sl, Hq, D = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     kw = dict(block_q=block_q, block_kv=block_kv, scale=scale)
     # distances with any visible element: i*Sl < window + Sl - 1
     n_live = ring_live_hops(sp, Sl, window)
@@ -354,7 +353,7 @@ def _ring_attention_jnp(q, k, v, axis_name, mask_mod, scale):
     B, Sl, Hq, D = q.shape
     _, _, Hkv, _ = k.shape
     G = Hq // Hkv
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
 
     qg = q.reshape(B, Sl, Hkv, G, D)
@@ -443,12 +442,6 @@ def make_ring_attention(mesh, axis_name: str = "sp", mask_mod: Optional[MaskMod]
 
     fn = partial(ring_attention, axis_name=axis_name, mask_mod=mask_mod,
                  block_q=block_q, block_kv=block_kv)
-    # Current API straight off jax when present; the compat shim only
-    # backfills the deprecated experimental path.
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from ..parallel.compat import shard_map as sm
-
-    return sm(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False,
     )

@@ -24,10 +24,7 @@ module schedules the fsdp collectives by hand, Megatron-style:
   re-gathers shards rather than keeping full per-layer params alive.
 
 Scope: pure dp×fsdp meshes, dense uniform layers, no int8 leaves
-(:func:`can_overlap`). Everything else falls back to GSPMD. Under
-legacy-jax shard_map (parallel/compat.py) the layer loop is Python-
-unrolled — its transpose cannot differentiate a nested ``lax.scan``
-(the same limitation parallel/pipeline.py works around).
+(:func:`can_overlap`). Everything else falls back to GSPMD.
 """
 
 from __future__ import annotations
@@ -40,10 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import shard_map
 from .sharding_rules import batch_pspec, param_pspec
-
-_LEGACY_SHARD_MAP = not hasattr(jax, "shard_map")
 
 # One bucket ≈ 4 MiB of shard bytes: large enough to amortize collective
 # launch overhead, small enough that a layer still drains as several
@@ -241,7 +235,8 @@ def overlapped_layer_scan(
                 h, aux = carry
                 h, a = f_ckpt(take(i), h, *consts_in)
                 return (h, aux + a), None
-            h, aux = _scan_or_unroll(ck_step, (h, aux), range(0, n_ck))
+            (h, aux), _ = jax.lax.scan(
+                ck_step, (h, aux), jnp.arange(0, n_ck, dtype=jnp.int32))
 
         # Plain suffix: double-buffered — gather layer i+1 before layer
         # i's compute (dataflow-independent, so it overlaps).
@@ -260,12 +255,13 @@ def overlapped_layer_scan(
                 return body(jax.tree_util.tree_unflatten(treedef, full),
                             h, *cs)
 
-            (h, aux, _) = _scan_or_unroll(
-                db_step, (h, aux, gathered), range(n_ck, L))
+            (h, aux, _), _ = jax.lax.scan(
+                db_step, (h, aux, gathered),
+                jnp.arange(n_ck, L, dtype=jnp.int32))
         return h, aux
 
     specs_in = (x_spec, tuple(const_specs), *param_specs)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         run, mesh=mesh, in_specs=specs_in, out_specs=(x_spec, P()),
         # The body is validated by parity tests (tests/test_overlap.py);
         # replication checking can't see through the manual bucket
@@ -273,16 +269,3 @@ def overlapped_layer_scan(
         check_vma=False,
     )
     return mapped(x, tuple(consts), *stacked)
-
-
-def _scan_or_unroll(step, carry, idx_range):
-    """``lax.scan`` over layer indices, Python-unrolled under the legacy
-    shard_map shim (its transpose cannot differentiate a nested scan —
-    same workaround as parallel/pipeline.py)."""
-    if _LEGACY_SHARD_MAP:
-        for i in idx_range:
-            carry, _ = step(carry, jnp.int32(i))
-        return carry
-    idxs = jnp.arange(idx_range.start, idx_range.stop, dtype=jnp.int32)
-    carry, _ = jax.lax.scan(step, carry, idxs)
-    return carry

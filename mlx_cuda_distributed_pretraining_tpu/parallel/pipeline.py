@@ -45,51 +45,19 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-if hasattr(jax, "shard_map"):
-    # Current API straight off jax; the compat shim only backfills the
-    # deprecated experimental path (ROADMAP: collectives off the shim).
-    shard_map = jax.shard_map
-else:
-    from .compat import shard_map
-
 from .sharding_rules import _axis, batch_pspec, param_pspec
 from ..utils.tree import flatten_dict, unflatten_dict
 
 Params = Dict[str, Any]
 
-# Test/bench instrumentation: when set to a zero-arg callable, it is invoked
-# (via jax.debug.callback) once per EXECUTED stage chunk application per
-# device — the honest evidence that compute-skip really skips (counts fall
-# from P*(V*M+P-1) to P*V*M when skip is on). None in production: the hook
-# is read at trace time, so the shipped program carries no callback at all.
-_SLAB_APP_HOOK: Optional[Callable[[], None]] = None
+def _scan_indexed(body, carry, n, index_xs):
+    """``lax.scan`` of ``body`` over ``range(n)``; ``index_xs(i)`` produces
+    the per-iteration operand for the traced index ``i``."""
+    def step(c, i):
+        c, _ = body(c, index_xs(i))
+        return c, None
 
-# The 0.4.x ``jax.experimental.shard_map`` fallback (parallel/compat.py)
-# cannot transpose a ``lax.scan`` nested inside the mapped body: the
-# transposed shard_map's cotangent outputs fail its spec check
-# (``_SpecError``), making the pipeline loss non-differentiable. Python-
-# unrolling the tick/layer loops restores grads at the cost of trace size
-# O(ticks + layers-per-stage); the modern ``jax.shard_map`` keeps the scans.
-_LEGACY_SHARD_MAP = not hasattr(jax, "shard_map")
-
-
-def _scan_or_unroll(body, carry, xs_leading_dim, index_xs):
-    """``lax.scan`` over ``range(xs_leading_dim)``, unrolled under the shim.
-
-    ``index_xs(i)`` produces the per-iteration slice for a static or traced
-    index ``i``; the scan path feeds ``jnp.arange``-driven dynamic slices so
-    both paths see identical per-step operands.
-    """
-    if not _LEGACY_SHARD_MAP:
-        def step(c, i):
-            c, _ = body(c, index_xs(i))
-            return c, None
-
-        carry, _ = jax.lax.scan(
-            step, carry, jnp.arange(xs_leading_dim, dtype=jnp.int32))
-        return carry
-    for i in range(xs_leading_dim):
-        carry, _ = body(carry, index_xs(jnp.int32(i)))
+    carry, _ = jax.lax.scan(step, carry, jnp.arange(n, dtype=jnp.int32))
     return carry
 
 
@@ -311,6 +279,7 @@ def make_pipeline_loss(
     interleave: int = 1,
     compute_skip: bool = True,
     with_moe_stats: bool = False,
+    with_slab_count: bool = False,
 ) -> Callable:
     """Build ``loss(stacked_params, batch) -> (loss, token_count)`` running
     the GPipe schedule over the mesh's pp axis.
@@ -339,6 +308,12 @@ def make_pipeline_loss(
     ``(loss, (token_count, stats))`` — the same contract as
     ``llama.loss_fn(with_moe_stats=True)``, so pp runs report the same
     routing gauges as non-pp runs.
+
+    ``with_slab_count`` appends to the aux the number of chunk applications
+    the schedule EXECUTED, summed over stages: an int32 carried through the
+    ticks and incremented inside the cond's work branch, so it is evidence
+    that compute-skip skips (``P*(V*M + P-1)`` falls to ``P*V*M``), not the
+    formula restated. Tests and the pp bench ask for it; training does not.
     """
     if getattr(args, "attention_type", "simple") == "ring":
         raise ValueError("ring (sp) attention inside a pipeline stage is not supported")
@@ -359,7 +334,6 @@ def make_pipeline_loss(
     if with_moe_stats and not getattr(args, "is_moe", False):
         with_moe_stats = False
     num_experts = int(getattr(args, "num_local_experts", 0) or 0)
-    slab_hook = _SLAB_APP_HOOK  # bound at trace time, like the tap
 
     def zero_moe_stats():
         from ..models.moe import zero_stats
@@ -369,8 +343,6 @@ def make_pipeline_loss(
     def stage_apply(layers_loc, x, positions):
         # layers_loc: one chunk [L/(P*V), ...] (V=1: the whole stage slab).
         cast = partial(jax.tree_util.tree_map, lambda a: a.astype(compute_dtype))
-        if slab_hook is not None:
-            jax.debug.callback(lambda: slab_hook())
 
         def one_layer(p_layer, h):
             ret = transformer_block(cast(p_layer), h, args, positions, None, None)
@@ -392,7 +364,7 @@ def make_pipeline_loss(
 
         stats0 = zero_moe_stats() if with_moe_stats else None
         n_loc = jax.tree_util.tree_leaves(layers_loc)[0].shape[0]
-        x, aux, stats = _scan_or_unroll(
+        x, aux, stats = _scan_indexed(
             body, (x, jnp.zeros((), jnp.float32), stats0), n_loc,
             lambda i: jax.tree_util.tree_map(
                 lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
@@ -449,16 +421,18 @@ def make_pipeline_loss(
             """Chunk application, skipped entirely on non-working ticks when
             compute_skip: the cond's pass branch is the identity, and its VJP
             is too, so forward AND backward slab FLOPs drop out."""
+            applied = jnp.ones((), jnp.int32)
             if compute_skip:
                 def work(x):
-                    return stage_apply(chunk, x, positions)
+                    return (*stage_apply(chunk, x, positions), applied)
 
                 def idle(x):
                     stats0 = zero_moe_stats() if with_moe_stats else None
-                    return x, jnp.zeros((), jnp.float32), stats0
+                    return (x, jnp.zeros((), jnp.float32), stats0,
+                            jnp.zeros((), jnp.int32))
 
                 return jax.lax.cond(working, work, idle, inp)
-            return stage_apply(chunk, inp, positions)
+            return (*stage_apply(chunk, inp, positions), applied)
 
         def head_cond(pred, out, m_idx):
             tgt = jax.lax.dynamic_index_in_dim(tgt_m, m_idx, keepdims=False)
@@ -486,7 +460,7 @@ def make_pipeline_loss(
         def tick_v1(carry, t):
             # Single-circuit GPipe tick. With compute_skip=False this is the
             # original schedule, bit for bit.
-            state, nll_sum, tok_sum, aux_sum, stats_sum = carry
+            state, nll_sum, tok_sum, aux_sum, stats_sum, apps = carry
             my_idx = t - p
             working = (my_idx >= 0) & (my_idx < M)
             if compute_skip:
@@ -504,7 +478,7 @@ def make_pipeline_loss(
                 x0 = embed_feed(feed_idx)
                 feed_valid = (t < M).astype(compute_dtype)
                 inp = is_first * feed_valid * x0 + (1.0 - is_first) * state
-            out, aux, stats = apply_chunk(layers_loc, inp, working)
+            out, aux, stats, applied = apply_chunk(layers_loc, inp, working)
             aux_sum = aux_sum + aux * working.astype(jnp.float32)
             stats_sum = acc_stats(stats_sum, mask_stats(stats, working))
             # Only the last working stage runs the vocab head (lax.cond:
@@ -515,7 +489,8 @@ def make_pipeline_loss(
             tok_sum = tok_sum + tok_c
             # rotate activations one stage forward
             state_next = jax.lax.ppermute(out, "pp", perm)
-            return (state_next, nll_sum, tok_sum, aux_sum, stats_sum), None
+            return (state_next, nll_sum, tok_sum, aux_sum, stats_sum,
+                    apps + applied), None
 
         def tick_circular(carry, t):
             # Interleaved circuits: work item j = t - p is (circuit v,
@@ -524,7 +499,7 @@ def make_pipeline_loss(
             # the last stage for circuit v-1, buffered per microbatch until
             # its re-feed tick comes up (arrives at (v-1)M+m+P, consumed at
             # vM+m — hence the M >= P requirement).
-            state, wrap_buf, nll_sum, tok_sum, aux_sum, stats_sum = carry
+            state, wrap_buf, nll_sum, tok_sum, aux_sum, stats_sum, apps = carry
             # Store the activation that rotated in at the end of the last
             # tick: stage P-1's output for item j_in = t - P. All stages run
             # the same store (SPMD); only stage 0 ever reads the buffer.
@@ -559,7 +534,7 @@ def make_pipeline_loss(
                 lambda a: jax.lax.dynamic_index_in_dim(a, v, keepdims=False),
                 layers_loc,
             )
-            out, aux, stats = apply_chunk(chunk, inp, working)
+            out, aux, stats, applied = apply_chunk(chunk, inp, working)
             aux_sum = aux_sum + aux * working.astype(jnp.float32)
             stats_sum = acc_stats(stats_sum, mask_stats(stats, working))
             # The vocab head fires on the last stage's final-circuit items.
@@ -569,30 +544,31 @@ def make_pipeline_loss(
             tok_sum = tok_sum + tok_c
             state_next = jax.lax.ppermute(out, "pp", perm)
             return (state_next, wrap_buf, nll_sum, tok_sum, aux_sum,
-                    stats_sum), None
+                    stats_sum, apps + applied), None
 
         D = embed_w.shape[1]
         state0 = jnp.zeros((mb, S, D), compute_dtype)
         zero = jnp.zeros((), jnp.float32)
         stats0 = zero_moe_stats() if with_moe_stats else None
+        apps0 = jnp.zeros((), jnp.int32)
         if V == 1:
-            state, nll, toks, aux, stats = _scan_or_unroll(
-                tick_v1, (state0, zero, zero, zero, stats0),
+            state, nll, toks, aux, stats, apps = _scan_indexed(
+                tick_v1, (state0, zero, zero, zero, stats0, apps0),
                 M + P_stages - 1, lambda t: t,
             )
         else:
             wrap0 = jnp.zeros((M, mb, S, D), compute_dtype)
-            state, wrap, nll, toks, aux, stats = _scan_or_unroll(
-                tick_circular, (state0, wrap0, zero, zero, zero, stats0),
+            state, wrap, nll, toks, aux, stats, apps = _scan_indexed(
+                tick_circular, (state0, wrap0, zero, zero, zero, stats0, apps0),
                 M * V + P_stages - 1, lambda t: t,
             )
-        nll = jax.lax.psum(nll, "pp")
-        toks = jax.lax.psum(toks, "pp")
-        aux = jax.lax.psum(aux, "pp")
+        outs = [jax.lax.psum(nll, "pp"), jax.lax.psum(toks, "pp"),
+                jax.lax.psum(aux, "pp")]
         if with_moe_stats:
-            stats = {k: jax.lax.psum(v, "pp") for k, v in stats.items()}
-            return nll, toks, aux, stats
-        return nll, toks, aux
+            outs.append({k: jax.lax.psum(v, "pp") for k, v in stats.items()})
+        if with_slab_count:
+            outs.append(jax.lax.psum(apps, "pp"))
+        return tuple(outs)
 
     def loss(stacked_params: Params, batch: Dict[str, jnp.ndarray]):
         layers = stacked_params["layers"]
@@ -610,18 +586,21 @@ def make_pipeline_loss(
         lead = P(None, "pp") if V > 1 else P("pp")
         layer_in_specs = jax.tree_util.tree_map(lambda _: lead, layers)
         bspec = P()  # batch enters replicated w.r.t. pp (auto axes may shard)
-        n_out = 4 if with_moe_stats else 3
-        sm = shard_map(
+        out_like = [0.0, 0.0, 0.0]
+        if with_moe_stats:
+            out_like.append({"moe_load": 0.0, "moe_dropped": 0.0})
+        if with_slab_count:
+            out_like.append(0)
+        sm = jax.shard_map(
             partial(inner, ce_rows),
             mesh=mesh,
             in_specs=(layer_in_specs, P(), P(), P(), bspec, bspec, bspec),
-            out_specs=jax.tree_util.tree_map(
-                lambda _: P(),
-                (0.0, 0.0, 0.0, {"moe_load": 0.0, "moe_dropped": 0.0})
-                if with_moe_stats else (0.0, 0.0, 0.0)),
+            out_specs=jax.tree_util.tree_map(lambda _: P(), tuple(out_like)),
             axis_names={"pp"},
             check_vma=False,
         )
+        operands = (layers, embed_w, norm_w, out_w,
+                    batch["inputs"], batch["targets"], batch["mask"])
         if with_moe_stats:
             from ..models.moe import routing_stats_tap
 
@@ -629,22 +608,15 @@ def make_pipeline_loss(
             # routing stats as return values (models/llama.py) — the tick
             # carries then thread them across the scan/cond boundaries.
             with routing_stats_tap():
-                nll, toks, aux, stats = sm(
-                    layers, embed_w, norm_w, out_w,
-                    batch["inputs"], batch["targets"], batch["mask"],
-                )
+                nll, toks, aux, *extra = sm(*operands)
         else:
-            nll, toks, aux = sm(
-                layers, embed_w, norm_w, out_w,
-                batch["inputs"], batch["targets"], batch["mask"],
-            )
-            stats = None
+            nll, toks, aux, *extra = sm(*operands)
         loss_val = nll / jnp.maximum(toks, 1.0)
         if getattr(args, "is_moe", False) and include_aux:
             loss_val = loss_val + aux / M  # aux is pre-scaled per microbatch
-        if with_moe_stats:
-            return loss_val, (toks, stats)
-        return loss_val, toks
+        # aux: token_count, then the routing stats and the slab count in
+        # that order, each only when asked for.
+        return loss_val, ((toks, *extra) if extra else toks)
 
     return loss
 

@@ -1,9 +1,8 @@
-"""GOOD fixture: legacy-shard-map-import — the shim import plus nearby
+"""GOOD fixture: legacy-shard-map-import — the current API plus nearby
 jax.experimental names the rule must not confuse with shard_map."""
+import jax
 from jax.experimental import mesh_utils
-
-from mlx_cuda_distributed_pretraining_tpu.parallel.compat import shard_map
 
 
 def run(f, mesh, x):
-    return shard_map(f, mesh=mesh)(x), mesh_utils
+    return jax.shard_map(f, mesh=mesh)(x), mesh_utils

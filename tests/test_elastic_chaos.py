@@ -47,7 +47,7 @@ def _write_inputs(workdir, vocab=256):
     return shard_dir
 
 
-def _write_cfg(workdir, name, shard_dir, cache_dir):
+def _write_cfg(workdir, name, shard_dir):
     cfg = {
         "name": name,
         "overwrite": False,
@@ -73,8 +73,7 @@ def _write_cfg(workdir, name, shard_dir, cache_dir):
                               "checkpoint_interval": 4,
                               "validation_interval": 0}},
         "system": {"seed": 0, "compute_dtype": "float32",
-                   "mesh": {"fsdp": 4},
-                   "compilation_cache_dir": cache_dir},
+                   "mesh": {"fsdp": 4}},
         # hang_timeout_s 0: the fleet watchdog still runs (process_count>1)
         # but only for peer restart markers — no stale-heartbeat false
         # positives during the cold compile, and a tight 0.5s marker poll
@@ -96,6 +95,12 @@ def _free_port():
 
 def _launch_fleet(cfg_path, runs_root, workdir, tag):
     port = _free_port()
+    # Ask for the persistent compile cache, as a deployment would: on
+    # multi-process CPU the helper must refuse it (cached executables do
+    # not survive gloo re-initialization), whatever the environment says.
+    env = device_env(2)
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "true"
+    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(workdir, "xla_cache")
     procs = []
     for i in range(2):
         log = open(os.path.join(workdir, f"{tag}_sup_p{i}.log"), "w")
@@ -106,7 +111,7 @@ def _launch_fleet(cfg_path, runs_root, workdir, tag):
              "--auto-resume", "--max-crashes", "5", "--backoff-base", "0.1",
              "--coordinator", f"localhost:{port}",
              "--num-processes", "2", "--process-id", str(i)],
-            env=device_env(2), stdout=log, stderr=subprocess.STDOUT))
+            env=env, stdout=log, stderr=subprocess.STDOUT))
     return procs
 
 
@@ -154,19 +159,21 @@ def _last_losses(events):
 def test_host_kill_chaos_resumes_with_loss_parity(tmp_path):
     workdir = str(tmp_path)
     shard_dir = _write_inputs(workdir)
-    cache_dir = os.path.join(workdir, "xla_cache")
 
-    # Uninterrupted 2-process baseline (also warms the compile cache).
-    base_cfg = _write_cfg(workdir, "chaos-base", shard_dir, cache_dir)
+    # Uninterrupted 2-process baseline.
+    base_cfg = _write_cfg(workdir, "chaos-base", shard_dir)
     base_root = os.path.join(workdir, "runs_base")
     _wait_fleet(_launch_fleet(base_cfg, base_root, workdir, "base"),
                 workdir, "base")
     base_losses = _last_losses(_events(os.path.join(base_root, "chaos-base")))
     assert sorted(base_losses) == list(range(1, ITERS + 1)), base_losses
+    with open(os.path.join(base_root, "chaos-base", "log.txt")) as f:
+        assert "compilation cache: disabled on multi-process CPU" in f.read()
+    assert not os.path.isdir(os.path.join(workdir, "xla_cache"))
 
     # Chaos fleet: SIGKILL host 1's trainer once it has progressed past the
     # step-4 checkpoint (pid comes from its per-host heartbeat file).
-    chaos_cfg = _write_cfg(workdir, "chaos", shard_dir, cache_dir)
+    chaos_cfg = _write_cfg(workdir, "chaos", shard_dir)
     chaos_root = os.path.join(workdir, "runs_chaos")
     run_dir = os.path.join(chaos_root, "chaos")
     procs = _launch_fleet(chaos_cfg, chaos_root, workdir, "chaos")

@@ -148,8 +148,10 @@ def test_model_flops_per_token_uses_heads_times_head_dim():
 def test_peak_flops_detection_and_env_override(monkeypatch):
     assert peak_flops_per_chip("TPU v5 lite") == 197e12
     assert peak_flops_per_chip("TPU v5p chip") == 459e12
-    assert peak_flops_per_chip("NVIDIA H100 80GB") == 989e12
     assert peak_flops_per_chip("cpu") is None
+    # an accelerator missing from the table is an error, not "unknown"
+    with pytest.raises(ValueError, match="no peak FLOP/s listed"):
+        peak_flops_per_chip("NVIDIA H100 80GB")
     monkeypatch.setenv("GRAFT_PEAK_FLOPS", "123e12")
     assert peak_flops_per_chip("cpu") == 123e12
     monkeypatch.setenv("GRAFT_PEAK_FLOPS", "not-a-number")

@@ -380,24 +380,19 @@ def test_compute_skip_bit_identical(interleave):
     (1, True), (1, False), (2, True), (2, False),
 ])
 def test_compute_skip_slab_application_count(interleave, compute_skip):
-    """The schedule really skips bubble ticks: per-device slab applications
-    drop from P*(V*M + P-1) to P*(V*M) with compute_skip on (counted via the
-    debug-callback hook inside the cond's work branch)."""
+    """The schedule really skips bubble ticks: slab applications summed
+    over stages drop from P*(V*M + P-1) to P*(V*M) with compute_skip on
+    (an int32 carried through the ticks, incremented inside the cond's
+    work branch, returned in the loss aux)."""
     mesh = _mesh((2, 1))
     params = llama.init_params(jax.random.PRNGKey(0), ARGS)
     batch = _batch()
     M, P, V = 4, 2, interleave
-    n = [0]
-    # the hook is bound when make_pipeline_loss builds the schedule
-    pl._SLAB_APP_HOOK = lambda: n.__setitem__(0, n[0] + 1)
-    try:
-        loss_fn = pl.make_pipeline_loss(ARGS, mesh, num_microbatches=M,
-                                        interleave=V, compute_skip=compute_skip)
-        loss, _ = jax.jit(loss_fn)(pl.stack_layers(params, interleave=V), batch)
-        loss.block_until_ready()
-        jax.effects_barrier()
-    finally:
-        pl._SLAB_APP_HOOK = None
+    loss_fn = pl.make_pipeline_loss(ARGS, mesh, num_microbatches=M,
+                                    interleave=V, compute_skip=compute_skip,
+                                    with_slab_count=True)
+    _, (_, slabs) = jax.jit(loss_fn)(pl.stack_layers(params, interleave=V), batch)
+    n = [int(slabs)]
     expected = P * (V * M) if compute_skip else P * (V * M + P - 1)
     assert n[0] == expected, f"slab applications {n[0]} != {expected}"
 

@@ -379,18 +379,31 @@ def test_schedule_value_matches_device_path():
 
 
 def test_compilation_cache_enabled_and_logged(tmp_path):
-    from mlx_cuda_distributed_pretraining_tpu.train.trainer import Trainer
+    """The trainer's main() places the cache where the environment says
+    (utils/compile_cache.py), populates it, and logs a cold/warm line. A
+    child process: the suite itself runs with the cache off."""
+    import subprocess
+    import sys
+
+    from conftest import device_env
 
     cache_dir = str(tmp_path / "xla-cache")
-    cfg = _tiny_cfg(tmp_path, "cache-run", 2)
-    cfg.system.compilation_cache_dir = cache_dir
-    tr = Trainer(cfg, runs_root=str(tmp_path / "runs-cache"), quiet=True)
-    tr.train()
-    assert os.path.isdir(cache_dir)
-    with open(os.path.join(tr.run_dir, "log.txt")) as f:
+    cfg_path = str(tmp_path / "cache-run.yaml")
+    _tiny_cfg(tmp_path, "cache-run", 2).to_yaml(cfg_path)
+    env = device_env(1)
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "true"
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+    runs = str(tmp_path / "runs-cache")
+    proc = subprocess.run(
+        [sys.executable, "-m", "mlx_cuda_distributed_pretraining_tpu.train.trainer",
+         "--config", cfg_path, "--runs-root", runs],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert os.listdir(cache_dir), "the run compiled and cached nothing"
+    with open(os.path.join(runs, "cache-run", "log.txt")) as f:
         log = f.read()
-    assert "compilation cache" in log
-    assert "cold" in log or "warm" in log
+    assert f"compilation cache: {cache_dir}" in log
+    assert "cold (will populate)" in log
 
 
 def test_stats_state_mean_data_wait_frac_gauge():

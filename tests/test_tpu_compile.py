@@ -1,0 +1,201 @@
+"""The chip's compiler, without the chip.
+
+libtpu compiles for a TPU that is described and not attached
+(``jax.experimental.topologies``), so the kernels of the main path are
+compiled here at their real widths for one v5e device: what the chip's
+compiler refuses (a slice off the tiling, too much VMEM, a program over
+16 GB) fails in tier-1 instead of on a chip call. Nothing executes, so
+these say nothing about results or times — ``chip_smoke.py`` does.
+
+``_interpret()`` in ops/ asks ``jax.default_backend()``, which is the CPU
+here; the tests steer it (monkeypatch), the program has no option for it.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from mlx_cuda_distributed_pretraining_tpu.models import llama
+from mlx_cuda_distributed_pretraining_tpu.ops import flash_attention as fa
+from mlx_cuda_distributed_pretraining_tpu.ops import grouped_matmul as gm
+
+HBM_BYTES = 16 * 10**9  # one v5e chip (Google Cloud TPU v5e documentation)
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    """The four described devices of a v5e:2x2 host; skip where libtpu
+    cannot describe them."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = no compiler here
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {type(e).__name__}: {e}")
+    # A compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip: keep the cache off here.
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def v5e(v5e_devices):
+    """One described v5e device."""
+    return SingleDeviceSharding(v5e_devices[0])
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Take the Mosaic branch of the kernels, as on a TPU backend."""
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_interpret", lambda: False)
+    # Trace fresh: a core cached by an interpret-mode test would be reused.
+    fa._cached_core.cache_clear()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+FLASH_CASES = {
+    # the 1B recipe: 16 heads of 128, context 2048
+    "causal_16x128": dict(Hq=16, Hkv=16, D=128, mask_type="causal", window_size=512),
+    # grouped queries at the narrower head the ladder's 100M-650M configs use
+    "gqa_12over4x64": dict(Hq=12, Hkv=4, D=64, mask_type="causal", window_size=512),
+    "sliding_window_512": dict(Hq=16, Hkv=16, D=128,
+                               mask_type="sliding_window", window_size=512),
+}
+
+
+def _flash_args(case, dev):
+    B, S = 2, 2048
+    q = _sds((B, S, case["Hq"], case["D"]), jnp.bfloat16, dev)
+    kv = _sds((B, S, case["Hkv"], case["D"]), jnp.bfloat16, dev)
+    return q, kv, kv
+
+
+@pytest.mark.parametrize("name", sorted(FLASH_CASES))
+def test_flash_forward_compiles_for_v5e(name, v5e, compiled_kernels):
+    case = FLASH_CASES[name]
+
+    def fwd(q, k, v):
+        return fa.flash_attention(q, k, v, mask_type=case["mask_type"],
+                                  window_size=case["window_size"])
+
+    hlo = jax.jit(fwd).lower(*_flash_args(case, v5e)).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= 1
+
+
+@pytest.mark.parametrize("name", sorted(FLASH_CASES))
+def test_flash_backward_compiles_for_v5e(name, v5e, compiled_kernels):
+    case = FLASH_CASES[name]
+
+    def loss(q, k, v):
+        o = fa.flash_attention(q, k, v, mask_type=case["mask_type"],
+                               window_size=case["window_size"])
+        return o.astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_flash_args(case, v5e)).compile().as_text()
+    # forward (for the residuals), dQ and dK/dV
+    assert hlo.count("tpu_custom_call") >= 3
+
+
+def test_flash_under_fsdp_mesh_compiles_for_v5e(v5e_devices, compiled_kernels):
+    """GSPMD cannot partition a Mosaic kernel, so under a mesh the call has
+    to go through a shard_map (ops/flash_attention.py _mesh_partition); left
+    to the partitioner this lowering raises NotImplementedError, and with it
+    every sharded training config that uses flash attention."""
+    from mlx_cuda_distributed_pretraining_tpu.parallel.context import use_mesh
+
+    mesh = Mesh(np.array(v5e_devices), ("fsdp",))
+    rows = NamedSharding(mesh, P("fsdp"))
+    case = FLASH_CASES["causal_16x128"]
+    qkv = [_sds((8, 2048, h, case["D"]), jnp.bfloat16, rows)
+           for h in (case["Hq"], case["Hkv"], case["Hkv"])]
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    with use_mesh(mesh):
+        hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*qkv).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= 3
+    # each chip attends over its own two rows: nothing to exchange
+    assert "all-gather" not in hlo and "all-reduce" not in hlo
+
+
+# OLMoE's expert shapes (ROADMAP R1): 64 experts of width 1024 on h2048,
+# one 8192-token dispatch.
+GMM = dict(T=8192, K=2048, N=1024, E=64)
+
+
+def _gmm_args(dev):
+    return (_sds((GMM["T"], GMM["K"]), jnp.bfloat16, dev),
+            _sds((GMM["E"], GMM["K"], GMM["N"]), jnp.bfloat16, dev),
+            _sds((GMM["E"],), jnp.int32, dev))
+
+
+def test_gmm_compiles_for_v5e(v5e, compiled_kernels):
+    def fwd(x, w, sizes):
+        return gm.gmm(x, w, sizes, backend="pallas")
+
+    hlo = jax.jit(fwd).lower(*_gmm_args(v5e)).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= 1
+
+
+def test_tgmm_compiles_for_v5e(v5e, compiled_kernels):
+    def loss(x, w, sizes):
+        return gm.gmm(x, w, sizes, backend="pallas").astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *_gmm_args(v5e)).compile().as_text()
+    # dX (a gmm against transposed weights) and dW (tgmm); the forward's
+    # output feeds neither, so the compiler drops it
+    assert hlo.count("tpu_custom_call") >= 2
+
+
+def test_paged_decode_step_fits_one_v5e(v5e, monkeypatch):
+    """The batch engine's decode step at the 1B widths, 8 rows over a full
+    2048-token attend bucket, fp32 weights and KV as the server holds them.
+    No Pallas kernel is in it today (it attends through reference_attention,
+    ROADMAP S2), so this only asserts that the chip's compiler takes the
+    program and that it fits the chip's memory."""
+    from mlx_cuda_distributed_pretraining_tpu.serve import batch_step
+
+    # Declare the donation the step has on an accelerator (ops/donation.py):
+    # without it the KV arena would be counted twice.
+    monkeypatch.setenv("GRAFTAUDIT_FORCE_DONATE", "1")
+    args = llama.LlamaArgs(
+        vocab_size=259, hidden_size=2048, intermediate_size=5632, num_layers=16,
+        num_heads=16, num_kv_heads=16, head_dim=128, max_position_embeddings=2048)
+    rows, block, max_len = 8, 32, 2048
+    width = max_len // block
+    on_dev = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: _sds(x.shape, x.dtype, v5e), t)
+    params = on_dev(jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), args)))
+    cache = on_dev(jax.eval_shape(
+        lambda: llama.init_paged_cache(args, rows * width + 1, block,
+                                       dtype=jnp.float32)))
+    step = batch_step.paged_decode_step(args, 0, max_len, width, block)
+    compiled = step.lower(
+        params, cache,
+        _sds((rows, 1), jnp.int32, v5e), _sds((rows,), jnp.int32, v5e),
+        _sds((rows, width), jnp.int32, v5e), _sds((rows,), jnp.float32, v5e),
+        _sds((rows, 2), jnp.uint32, v5e)).compile()
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert ma.alias_size_in_bytes > 0, "the KV arena is not donated"
+    assert total < HBM_BYTES, f"paged decode step needs {total / 1e9:.2f} GB"
