@@ -398,14 +398,28 @@ def attention_block(
     B, S, _ = x.shape
     Hq, Hkv, Dh = args.num_heads, args.num_kv_heads, args.head_dim
 
-    q = checkpoint_name(_linear(x, p["wq"]), "qkv").reshape(B, S, Hq, Dh)
-    k = checkpoint_name(_linear(x, p["wk"]), "qkv").reshape(B, S, Hkv, Dh)
-    v = checkpoint_name(_linear(x, p["wv"]), "qkv").reshape(B, S, Hkv, Dh)
+    with jax.named_scope("attn_qkv"):
+        q = checkpoint_name(_linear(x, p["wq"]), "qkv").reshape(B, S, Hq, Dh)
+        k = checkpoint_name(_linear(x, p["wk"]), "qkv").reshape(B, S, Hkv, Dh)
+        v = checkpoint_name(_linear(x, p["wv"]), "qkv").reshape(B, S, Hkv, Dh)
 
-    cos, sin = rope_cos_sin(positions, Dh, args.rope_theta, args.rope_scaling_factor)
-    q = apply_rope(q, cos, sin, args.rope_traditional)
-    k = apply_rope(k, cos, sin, args.rope_traditional)
+        cos, sin = rope_cos_sin(positions, Dh, args.rope_theta, args.rope_scaling_factor)
+        q = apply_rope(q, cos, sin, args.rope_traditional)
+        k = apply_rope(k, cos, sin, args.rope_traditional)
 
+    with jax.named_scope("attn_core"):
+        out, new_cache = _attend(q, k, v, args, positions, cache,
+                                 attn_impl, attend_len)
+    with jax.named_scope("attn_out"):
+        out = checkpoint_name(out.reshape(B, S, Hq * Dh), "attn_out")
+        return _linear(out, p["wo"]), new_cache
+
+
+def _attend(q, k, v, args, positions, cache, attn_impl, attend_len):
+    """The attention proper on rotated q/k/v: cached decode (fp or int8
+    buffers) or the training-path dispatch flash / ring / flex / reference.
+    Returns ``(out [B, S, Hq, Dh], new_cache | None)``."""
+    S = q.shape[1]
     new_cache = None
     if cache is not None and "k_q" in cache:
         # int8-quantized cache (reference: generation_lite.py:75-89 optional
@@ -462,9 +476,7 @@ def attention_block(
             )
         else:
             out = reference_attention(q, k, v, mask_mod=mask_mod, score_mod=build_score_mod(args))
-
-    out = checkpoint_name(out.reshape(B, S, Hq * Dh), "attn_out")
-    return _linear(out, p["wo"]), new_cache
+    return out, new_cache
 
 
 def _quantize_kv(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -509,27 +521,30 @@ def transformer_block(
     this layer's routing stats: the stats are re-emitted as RETURN VALUES
     here, inside any ``jax.checkpoint`` wrapping this block, so they cross
     the remat/scan boundary instead of leaking out of its trace."""
-    h, new_cache = attention_block(
-        p["attention"], rms_norm(x, p["attention_norm"]["weight"], args.rms_norm_eps),
-        args, positions, cache, attn_impl, attend_len,
-    )
-    x = x + h
-    normed = rms_norm(x, p["ffn_norm"]["weight"], args.rms_norm_eps)
-    if args.is_moe:
-        from . import moe as moe_lib
+    with jax.named_scope("layer"):
+        with jax.named_scope("norm"):
+            normed = rms_norm(x, p["attention_norm"]["weight"], args.rms_norm_eps)
+        h, new_cache = attention_block(
+            p["attention"], normed, args, positions, cache, attn_impl, attend_len)
+        x = x + h
+        with jax.named_scope("norm"):
+            normed = rms_norm(x, p["ffn_norm"]["weight"], args.rms_norm_eps)
+        if args.is_moe:
+            from . import moe as moe_lib
 
-        if moe_lib.stats_tap_active():
-            with moe_lib.routing_stats_tap() as tap:
-                ff, aux = moe_lib.moe_block(p["feed_forward"], normed, args)
-            x = x + ff
-            return x, new_cache, aux, moe_lib.merge_stats(
-                tap, args.num_local_experts)
-        ff, aux = moe_lib.moe_block(p["feed_forward"], normed, args)
-    else:
-        ff = mlp_block(p["feed_forward"], normed)
-        aux = jnp.zeros((), jnp.float32)
-    x = x + ff
-    return x, new_cache, aux
+            if moe_lib.stats_tap_active():
+                with moe_lib.routing_stats_tap() as tap:
+                    ff, aux = moe_lib.moe_block(p["feed_forward"], normed, args)
+                x = x + ff
+                return x, new_cache, aux, moe_lib.merge_stats(
+                    tap, args.num_local_experts)
+            ff, aux = moe_lib.moe_block(p["feed_forward"], normed, args)
+        else:
+            with jax.named_scope("ffn"):
+                ff = mlp_block(p["feed_forward"], normed)
+            aux = jnp.zeros((), jnp.float32)
+        x = x + ff
+        return x, new_cache, aux
 
 
 # -- full model -------------------------------------------------------------
@@ -577,16 +592,21 @@ def forward(
     this flag is a no-op and GSPMD schedules the collectives.
     """
     B, S = tokens.shape
-    x = params["tok_embeddings"]["weight"].astype(compute_dtype)[tokens]
+    with jax.named_scope("embed"):
+        x = params["tok_embeddings"]["weight"].astype(compute_dtype)[tokens]
     positions = jnp.arange(S, dtype=jnp.int32) + start_pos
 
     remat = normalize_remat(remat)
     wrap = remat_wrap(remat)
     block = wrap(transformer_block) if wrap is not None else transformer_block
 
-    # int8 (quantized) leaves must stay int8 through the compute-dtype cast
-    cast = partial(jax.tree_util.tree_map,
-                   lambda a: a if a.dtype == jnp.int8 else a.astype(compute_dtype))
+    def cast(layer):
+        # int8 (quantized) leaves must stay int8 through the compute-dtype
+        # cast; a layer's cast weights are the layer's cost
+        with jax.named_scope("layer"):
+            return jax.tree_util.tree_map(
+                lambda a: a if a.dtype == jnp.int8 else a.astype(compute_dtype), layer)
+
     new_cache = [] if cache is not None else None
     n_remat = int(round(args.num_layers * remat_ratio))
     aux_total = jnp.zeros((), jnp.float32)
@@ -638,8 +658,6 @@ def forward(
         for seg, blk in segments:
             if not seg:
                 continue
-            stacked = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *seg)
-
             def body(h, layer, blk=blk):
                 # transformer_block grows a stats element under an active
                 # tap; routing it through the scan ys keeps the traced
@@ -651,7 +669,9 @@ def forward(
                 h, _, aux = out
                 return h, aux
 
-            x, ys = jax.lax.scan(body, x, stacked)
+            with jax.named_scope("layer"):  # the scan's stacking and slicing too
+                stacked = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *seg)
+                x, ys = jax.lax.scan(body, x, stacked)
             if collect_stats:
                 auxs, stats = ys
                 stats_total = {k: stats_total[k] + stats[k].sum(axis=0)
@@ -676,11 +696,21 @@ def forward(
     if collect_stats:
         moe_lib.record_stats(stats_total)
 
-    x = rms_norm(x, params["norm"]["weight"], args.rms_norm_eps)
+    with jax.named_scope("final_norm"):
+        x = rms_norm(x, params["norm"]["weight"], args.rms_norm_eps)
     if return_hidden:
         if return_aux:
             return x, new_cache, aux_total
         return x, new_cache
+    with jax.named_scope("lm_head_ce"):
+        logits = _project_logits(params, x, args, compute_dtype)
+    if return_aux:
+        return logits, new_cache, aux_total
+    return logits, new_cache
+
+
+def _project_logits(params: Params, x: jnp.ndarray, args: LlamaArgs,
+                    compute_dtype) -> jnp.ndarray:
     # Output projection accumulates in fp32 (preferred_element_type) so the
     # logits never round through bf16 — bit-identical to the fused-CE path
     # (ops/fused_ce.py) under any compute dtype.
@@ -700,9 +730,7 @@ def forward(
             logits = logits + params["output"]["bias"].astype(jnp.float32)
     if args.logit_scale:
         logits = logits * args.logit_scale
-    if return_aux:
-        return logits, new_cache, aux_total
-    return logits, new_cache
+    return logits
 
 
 def init_cache(
@@ -829,46 +857,49 @@ def loss_fn(
             remat=remat, remat_ratio=remat_ratio, return_aux=True,
             return_hidden=True, scan_layers=scan_layers, overlap=overlap,
         )
-        if untied:
-            w_vd = params["output"]["weight"].astype(compute_dtype).T
-            bias = params["output"].get("bias")
-        else:
-            w_vd = params["tok_embeddings"]["weight"].astype(compute_dtype)
-            bias = None
+        with jax.named_scope("lm_head_ce"):
+            if untied:
+                w_vd = params["output"]["weight"].astype(compute_dtype).T
+                bias = params["output"].get("bias")
+            else:
+                w_vd = params["tok_embeddings"]["weight"].astype(compute_dtype)
+                bias = None
         from ..parallel.context import current_mesh
 
         mesh = current_mesh()
         want_z = z_loss_weight > 0.0
-        if (mesh is not None and mesh.shape.get("sp", 1) > 1
-                and mesh.shape.get("tp", 1) == 1):
-            # Sequence-sharded: shard_map keeps the chunked CE local to
-            # each sp shard (ops/fused_ce.py::fused_cross_entropy_sp).
-            out = fused_ce.fused_cross_entropy_sp(
-                hidden, w_vd, targets, mask, mesh, bias_v=bias,
-                logit_scale=args.logit_scale, chunk=ce_chunk, with_z=want_z,
-            )
-        else:
-            out = fused_ce.fused_cross_entropy(
-                hidden, w_vd, targets, mask, bias_v=bias,
-                logit_scale=args.logit_scale, chunk=ce_chunk, with_z=want_z,
-            )
-        if want_z:
-            nll_sum, z_sum = out
-            loss = nll_sum / count + z_loss_weight * z_sum / count
-        else:
-            loss = out / count
+        with jax.named_scope("lm_head_ce"):
+            if (mesh is not None and mesh.shape.get("sp", 1) > 1
+                    and mesh.shape.get("tp", 1) == 1):
+                # Sequence-sharded: shard_map keeps the chunked CE local to
+                # each sp shard (ops/fused_ce.py::fused_cross_entropy_sp).
+                out = fused_ce.fused_cross_entropy_sp(
+                    hidden, w_vd, targets, mask, mesh, bias_v=bias,
+                    logit_scale=args.logit_scale, chunk=ce_chunk, with_z=want_z,
+                )
+            else:
+                out = fused_ce.fused_cross_entropy(
+                    hidden, w_vd, targets, mask, bias_v=bias,
+                    logit_scale=args.logit_scale, chunk=ce_chunk, with_z=want_z,
+                )
+            if want_z:
+                nll_sum, z_sum = out
+                loss = nll_sum / count + z_loss_weight * z_sum / count
+            else:
+                loss = out / count
     else:
         logits, _, aux = forward(
             params, batch["inputs"], args, compute_dtype=compute_dtype,
             remat=remat, remat_ratio=remat_ratio, return_aux=True,
             scan_layers=scan_layers, overlap=overlap,
         )
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        nll = (logz - gold) * mask
-        loss = nll.sum() / count
-        if z_loss_weight > 0.0:
-            loss = loss + z_loss_weight * jnp.sum(jnp.square(logz) * mask) / count
+        with jax.named_scope("lm_head_ce"):
+            logz = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+            nll = (logz - gold) * mask
+            loss = nll.sum() / count
+            if z_loss_weight > 0.0:
+                loss = loss + z_loss_weight * jnp.sum(jnp.square(logz) * mask) / count
     if args.is_moe and include_aux:
         loss = loss + aux  # pre-scaled inside moe_block
     return loss, mask.sum()

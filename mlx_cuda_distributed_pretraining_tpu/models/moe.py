@@ -490,36 +490,37 @@ def moe_block(p: Params, x: jnp.ndarray, args) -> Tuple[jnp.ndarray, jnp.ndarray
     # bf16 compute the old fp32 projection paid an activation-sized
     # convert plus a 2x-wide matmul for logits that top_k/softmax need at
     # fp32 anyway (caught by graftaudit's dtype-upcast rule).
-    router_logits = (x @ p["router"]["weight"].astype(x.dtype)).astype(jnp.float32)
-    probs = jax.nn.softmax(router_logits, axis=-1)  # [B, S, E] fp32
-
-    if impl == "einsum":
-        out, dropped = _einsum_moe(p, x, probs, args)
-        gate_idx = jax.lax.top_k(probs, K)[1]  # stats only
-    elif impl == "grouped":
-        gate_w, gate_idx = jax.lax.top_k(probs, K)  # [B, S, K]
-        gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
-        mesh = _usable_ep_mesh(args, E)
-        if mesh is not None:
-            out, dropped = _grouped_moe_ep(p, x, gate_idx, gate_w, args, mesh)
-        else:
-            out = _grouped_ffn(
-                p["experts"], x.reshape(B * S, D), gate_idx.reshape(B * S, K),
-                gate_w.reshape(B * S, K), E,
-                gm.pick_block_t(B * S * K, E),
-                precision=getattr(args, "matmul_precision", None),
-            ).reshape(B, S, D)
-            dropped = jnp.zeros((), jnp.float32)
-    else:
+    if impl not in ("einsum", "grouped"):
         raise ValueError(f"unknown moe impl {impl!r} (grouped|einsum)")
+    with jax.named_scope("moe_router"):
+        router_logits = (x @ p["router"]["weight"].astype(x.dtype)).astype(jnp.float32)
+        probs = jax.nn.softmax(router_logits, axis=-1)  # [B, S, E] fp32
+        gate_w, gate_idx = jax.lax.top_k(probs, K)  # [B, S, K]; einsum: stats only
+        gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
 
-    aw = float(getattr(args, "moe_aux_weight", 0.0) or 0.0)
-    zw = float(getattr(args, "router_z_weight", 0.0) or 0.0)
-    aux = jnp.zeros((), jnp.float32)
-    if aw:
-        aux = aux + aw * load_balancing_loss(probs, jnp.argmax(router_logits, axis=-1), E)
-    if zw:
-        aux = aux + zw * router_z_loss(router_logits)
+        aw = float(getattr(args, "moe_aux_weight", 0.0) or 0.0)
+        zw = float(getattr(args, "router_z_weight", 0.0) or 0.0)
+        aux = jnp.zeros((), jnp.float32)
+        if aw:
+            aux = aux + aw * load_balancing_loss(probs, jnp.argmax(router_logits, axis=-1), E)
+        if zw:
+            aux = aux + zw * router_z_loss(router_logits)
+
+    with jax.named_scope("moe_experts"):
+        if impl == "einsum":
+            out, dropped = _einsum_moe(p, x, probs, args)
+        else:
+            mesh = _usable_ep_mesh(args, E)
+            if mesh is not None:
+                out, dropped = _grouped_moe_ep(p, x, gate_idx, gate_w, args, mesh)
+            else:
+                out = _grouped_ffn(
+                    p["experts"], x.reshape(B * S, D), gate_idx.reshape(B * S, K),
+                    gate_w.reshape(B * S, K), E,
+                    gm.pick_block_t(B * S * K, E),
+                    precision=getattr(args, "matmul_precision", None),
+                ).reshape(B, S, D)
+                dropped = jnp.zeros((), jnp.float32)
 
     if stats_tap_active():
         record_stats({

@@ -22,6 +22,13 @@ Design constraints, in order:
   anchor_mono)``.  Files from the router and N replicas therefore share
   one timeline (to NTP accuracy) while individual durations keep
   monotonic precision.
+* **One call site, two sinks.**  :meth:`Tracer.phase` is the entry
+  point for a *live* host phase: it always enters a
+  ``jax.profiler.TraceAnnotation`` (a flag test when no profiler session
+  is open), so the phase lands in the profiler's trace on the device
+  trace's clock, and when the ring is enabled it records the same span,
+  under the same name, in the ring.  ``scripts/trace_report.py`` and the
+  profiler's trace therefore share span names.
 * **W3C-style propagation.**  :func:`new_trace_id` mints a 16-byte hex
   trace id; the router sends it as the ``X-Trace-Id`` header
   (:data:`TRACE_HEADER`) and every span recorded on behalf of that
@@ -41,8 +48,11 @@ import time
 import uuid
 from typing import Any, Dict, Iterable, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "TRACE_HEADER",
+    "Phase",
     "Span",
     "Tracer",
     "new_trace_id",
@@ -108,6 +118,38 @@ class Span:
 
     def __exit__(self, *exc: Any) -> None:
         self.end()
+
+
+class Phase:
+    """A live host phase (see :meth:`Tracer.phase`); ``seconds`` holds its
+    duration once the ``with`` block has ended, so a ledger booked from it
+    carries the number the span carries."""
+
+    __slots__ = ("_tracer", "_name", "_trace_id", "_args", "_ann", "_t0",
+                 "seconds")
+
+    def __init__(self, tracer: "Tracer", name: str,
+                 trace_id: Optional[str], args: Dict[str, Any]):
+        self._tracer = tracer
+        self._name = name
+        self._trace_id = trace_id
+        self._args = args
+        self._ann = TraceAnnotation(name, **args)
+        self.seconds = 0.0
+
+    def __enter__(self) -> "Phase":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        tr = self._tracer
+        if tr.enabled and (self._trace_id is None
+                           or sampled(self._trace_id, tr.sample)):
+            tr._record(self._name, self._t0, self.seconds, self._trace_id,
+                       self._args or None)
 
 
 class _NullSpan:
@@ -206,6 +248,14 @@ class Tracer:
 
     # ``begin`` is an alias kept for call sites that read better with it.
     begin = span
+
+    def phase(self, name: str, trace_id: Optional[str] = None,
+              **args: Any) -> Phase:
+        """A live host phase, as a context manager: always a
+        ``jax.profiler.TraceAnnotation(name)`` (so a profiler session puts
+        it on the device trace's clock), and a ring span of the same name
+        when the ring is enabled."""
+        return Phase(self, name, trace_id, args)
 
     def complete(self, name: str, dur_s: float,
                  trace_id: Optional[str] = None,

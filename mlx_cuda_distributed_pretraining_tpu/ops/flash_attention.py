@@ -456,6 +456,7 @@ def flash_fwd(q, k, v, *, mask_fn=None, score_fn=None, mask_type="causal",
         ],
         compiler_params=_compiler_params(3, 4),
         interpret=_interpret(),
+        name="flash_fwd",  # also the innermost scope of its ops
     )(q, k, v)
 
 
@@ -499,6 +500,7 @@ def flash_bwd_dq(q, k, v, g, lse, delta, *, mask_fn=None, score_fn=None,
         scratch_shapes=[_scratch((bq, D))],
         compiler_params=_compiler_params(3, 4),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(q, k, v, g, lse, delta)
 
 
@@ -554,6 +556,7 @@ def flash_bwd_dkv(q, k, v, g, lse, delta, *, mask_fn=None, score_fn=None,
         scratch_shapes=[_scratch((bkv, D)), _scratch((bkv, D))],
         compiler_params=_compiler_params(3, 4),
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(q, k, v, g, lse, delta)
 
 

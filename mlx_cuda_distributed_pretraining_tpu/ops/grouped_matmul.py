@@ -155,6 +155,7 @@ def _gmm_pallas(x, w, group_sizes, block_t, block_n):
         out_shape=jax.ShapeDtypeStruct((T, N), x.dtype),
         compiler_params=_compiler_params(("parallel", "parallel")),
         interpret=_interpret(),
+        name="gmm",  # also the innermost scope of its ops
     )(te, x, w)
 
 
@@ -203,6 +204,7 @@ def _tgmm_pallas(x, dy, group_sizes, n_experts, block_t, block_n):
         out_shape=jax.ShapeDtypeStruct((n_experts, K, N), x.dtype),
         compiler_params=_compiler_params(("parallel", "arbitrary")),
         interpret=_interpret(),
+        name="tgmm",
     )(te, x, dy)
     # Experts that received no tiles were never written; also covers the
     # clamped tail tiles double-writing the last expert with zero rows.

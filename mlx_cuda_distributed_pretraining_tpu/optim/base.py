@@ -61,9 +61,10 @@ def clip_by_global_norm(max_norm: float) -> Transform:
     optimizers/enhanced_optimizers.py:104-119)."""
 
     def update(grads, state, params):
-        norm = global_norm(grads)
-        scale = jnp.minimum(1.0, max_norm / jnp.maximum(norm, 1e-9))
-        return tree_map(lambda g: g * scale, grads), state
+        with jax.named_scope("grad_clip"):
+            norm = global_norm(grads)
+            scale = jnp.minimum(1.0, max_norm / jnp.maximum(norm, 1e-9))
+            return tree_map(lambda g: g * scale, grads), state
 
     return Transform(lambda p: {}, update)
 

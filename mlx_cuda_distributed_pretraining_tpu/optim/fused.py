@@ -72,8 +72,9 @@ def fused_adamw(
         if grad_clip:
             # same reduction as base.clip_by_global_norm — the one
             # unavoidable extra pass (it is a global reduction)
-            norm = global_norm(grads)
-            clip_scale = jnp.minimum(1.0, grad_clip / jnp.maximum(norm, 1e-9))
+            with jax.named_scope("grad_clip"):
+                norm = global_norm(grads)
+                clip_scale = jnp.minimum(1.0, grad_clip / jnp.maximum(norm, 1e-9))
 
         def leaf(path, p, g, m, v, *vmax):
             # clip → adam → wd → -lr → apply, verbatim expression order

@@ -170,20 +170,51 @@ def _write_kv_slot(layer_cache, k, v, slot, pos):
     return new, keys, values
 
 
-def _ffn(p, x, args):
-    """Post-attention half of a block (dense MLP or MoE) — the MoE block
-    is position-free, so it is shared with the training forward as-is."""
+def _qkv(p, x, args, rope):
+    """First half of a block up to the rotated q/k/v ``[B, S, H, Dh]``,
+    under the scope names of the training forward (models/llama.py).
+    ``rope`` rotates one ``[B, S, H, Dh]`` tensor by the step's positions."""
+    B, S, _ = x.shape
+    Hq, Hkv, Dh = args.num_heads, args.num_kv_heads, args.head_dim
+    with jax.named_scope("norm"):
+        h = llama.rms_norm(x, p["attention_norm"]["weight"], args.rms_norm_eps)
+    pa = p["attention"]
+    with jax.named_scope("attn_qkv"):
+        q = llama._linear(h, pa["wq"]).reshape(B, S, Hq, Dh)
+        k = llama._linear(h, pa["wk"]).reshape(B, S, Hkv, Dh)
+        v = llama._linear(h, pa["wv"]).reshape(B, S, Hkv, Dh)
+        return rope(q), rope(k), v
+
+
+def _attn_out_ffn(p, x, out, args):
+    """Second half of a block: output projection and residual, then the
+    dense MLP or MoE (position-free, so shared with the training forward
+    as-is) and its residual."""
+    B, S, _ = x.shape
+    with jax.named_scope("attn_out"):
+        x = x + llama._linear(out.reshape(B, S, -1), p["attention"]["wo"])
+    with jax.named_scope("norm"):
+        h = llama.rms_norm(x, p["ffn_norm"]["weight"], args.rms_norm_eps)
     if args.is_moe:
         from ..models.moe import moe_block
 
-        ff, _aux = moe_block(p["feed_forward"], x, args)
-        return ff
-    return llama.mlp_block(p["feed_forward"], x)
+        ff, _aux = moe_block(p["feed_forward"], h, args)
+        return x + ff
+    with jax.named_scope("ffn"):
+        return x + llama.mlp_block(p["feed_forward"], h)
 
 
 def _project_logits(params, x, args):
-    """Output projection, op-identical to llama.forward's logits path
-    (fp32 accumulation; params assumed fp32 — serving compute dtype)."""
+    """Final norm and output projection, op-identical to llama.forward's
+    logits path (fp32 accumulation; params assumed fp32 — serving compute
+    dtype)."""
+    with jax.named_scope("final_norm"):
+        x = llama.rms_norm(x, params["norm"]["weight"], args.rms_norm_eps)
+    with jax.named_scope("lm_head_ce"):
+        return _head(params, x, args)
+
+
+def _head(params, x, args):
     if args.tie_word_embeddings or "output" not in params:
         logits = jax.lax.dot_general(
             x, params["tok_embeddings"]["weight"],
@@ -270,57 +301,52 @@ def decode_step(args: llama.LlamaArgs, attend_len: int,
     if key_ in _STEP_CACHE:
         return _STEP_CACHE[key_]
 
-    Hq, Hkv, Dh = args.num_heads, args.num_kv_heads, args.head_dim
-    kv_spec = kv_cache_pspec(mesh, Hkv)
+    kv_spec = kv_cache_pspec(mesh, args.num_kv_heads)
 
     @partial(jax.jit, donate_argnums=_donate_cache())
-    def step(params, cache, tokens, pos, temps, keys):
+    def decode_step(params, cache, tokens, pos, temps, keys):
         B = tokens.shape[0]
         rows = jnp.arange(B)
         positions = pos[:, None]  # [B, 1]
-        x = params["tok_embeddings"]["weight"][tokens][:, None, :]  # [B,1,D]
+        with jax.named_scope("embed"):
+            x = params["tok_embeddings"]["weight"][tokens][:, None, :]  # [B,1,D]
         x = _c(x, mesh, _batch_pspec(mesh, B))
+        rope = lambda t: _rope_rows(t, positions, args)
         k_idx = jnp.arange(attend_len, dtype=jnp.int32)
         # keys at or before each row's own position (junk beyond a row's
         # write head is never attendable — pool invariant)
         mask = (k_idx[None, None, :] <= positions[:, :, None])  # [B,1,L]
         new_cache = []
         for p, layer_cache in zip(params["layers"], cache):
-            layer_cache = _c_layer(layer_cache, mesh, kv_spec)
-            h = llama.rms_norm(x, p["attention_norm"]["weight"],
-                               args.rms_norm_eps)
-            pa = p["attention"]
-            q = llama._linear(h, pa["wq"]).reshape(B, 1, Hq, Dh)
-            k = llama._linear(h, pa["wk"]).reshape(B, 1, Hkv, Dh)
-            v = llama._linear(h, pa["wv"]).reshape(B, 1, Hkv, Dh)
-            q = _rope_rows(q, positions, args)
-            k = _rope_rows(k, positions, args)
-            new_layer, ck, cv = _write_kv_rows(layer_cache, k, v, rows, pos)
-            new_cache.append(_c_layer(new_layer, mesh, kv_spec))
-            out = reference_attention(
-                q, ck[:, :attend_len], cv[:, :attend_len],
-                explicit_mask=mask[:, None, None, :, :])
-            x = x + llama._linear(out.reshape(B, 1, Hq * Dh), pa["wo"])
-            x = x + _ffn(p, llama.rms_norm(x, p["ffn_norm"]["weight"],
-                                           args.rms_norm_eps), args)
-        x = llama.rms_norm(x, params["norm"]["weight"], args.rms_norm_eps)
+            with jax.named_scope("layer"):
+                layer_cache = _c_layer(layer_cache, mesh, kv_spec)
+                q, k, v = _qkv(p, x, args, rope)
+                with jax.named_scope("kv_gather"):
+                    new_layer, ck, cv = _write_kv_rows(layer_cache, k, v, rows, pos)
+                new_cache.append(_c_layer(new_layer, mesh, kv_spec))
+                with jax.named_scope("attn_core"):
+                    out = reference_attention(
+                        q, ck[:, :attend_len], cv[:, :attend_len],
+                        explicit_mask=mask[:, None, None, :, :])
+                x = _attn_out_ffn(p, x, out, args)
         logits = _project_logits(params, x, args)[:, 0, :]  # [B, V]
         # Replicate logits before sampling (vocab-parallel output proj
         # leaves V sharded over tp; the Megatron-style gather point).
         logits = _c(logits, mesh, P())
-        lp_all = jax.nn.log_softmax(logits, axis=-1)
-        split = jax.vmap(lambda kk: jax.random.split(kk, 2))(keys)  # [B,2,2]
-        new_keys, subs = split[:, 0], split[:, 1]
-        sampled = jax.vmap(
-            lambda kk, lg, t: jax.random.categorical(
-                kk, lg / jnp.maximum(t, 1e-6)))(subs, logits, temps)
-        tok = jnp.where(temps > 0.0, sampled.astype(jnp.int32),
-                        jnp.argmax(logits, axis=-1).astype(jnp.int32))
-        lp = jnp.take_along_axis(lp_all, tok[:, None], axis=-1)[:, 0]
+        with jax.named_scope("sample"):
+            lp_all = jax.nn.log_softmax(logits, axis=-1)
+            split = jax.vmap(lambda kk: jax.random.split(kk, 2))(keys)  # [B,2,2]
+            new_keys, subs = split[:, 0], split[:, 1]
+            sampled = jax.vmap(
+                lambda kk, lg, t: jax.random.categorical(
+                    kk, lg / jnp.maximum(t, 1e-6)))(subs, logits, temps)
+            tok = jnp.where(temps > 0.0, sampled.astype(jnp.int32),
+                            jnp.argmax(logits, axis=-1).astype(jnp.int32))
+            lp = jnp.take_along_axis(lp_all, tok[:, None], axis=-1)[:, 0]
         return new_cache, tok, lp, new_keys
 
-    _STEP_CACHE[key_] = step
-    return step
+    _STEP_CACHE[key_] = decode_step
+    return decode_step
 
 
 def prefill_step(args: llama.LlamaArgs, chunk: int, attend_len: int,
@@ -338,15 +364,16 @@ def prefill_step(args: llama.LlamaArgs, chunk: int, attend_len: int,
     if key_ in _STEP_CACHE:
         return _STEP_CACHE[key_]
 
-    Hq, Hkv, Dh = args.num_heads, args.num_kv_heads, args.head_dim
-    kv_spec = kv_cache_pspec(mesh, Hkv)
+    kv_spec = kv_cache_pspec(mesh, args.num_kv_heads)
 
     @partial(jax.jit, donate_argnums=_donate_cache())
-    def step(params, cache, tokens, slot, pos, last_idx):
-        x = params["tok_embeddings"]["weight"][tokens][None]  # [1, C, D]
+    def prefill_step(params, cache, tokens, slot, pos, last_idx):
+        with jax.named_scope("embed"):
+            x = params["tok_embeddings"]["weight"][tokens][None]  # [1, C, D]
         positions = jnp.arange(chunk, dtype=jnp.int32) + pos  # [C]
-        cos, sin = llama.rope_cos_sin(positions, Dh, args.rope_theta,
+        cos, sin = llama.rope_cos_sin(positions, args.head_dim, args.rope_theta,
                                       args.rope_scaling_factor)
+        rope = lambda t: llama.apply_rope(t, cos, sin, args.rope_traditional)
         k_idx = jnp.arange(attend_len, dtype=jnp.int32)
         # same positional validity mask as the single-sequence cached
         # decode (llama._cached_attention)
@@ -354,32 +381,25 @@ def prefill_step(args: llama.LlamaArgs, chunk: int, attend_len: int,
             & (k_idx[None, :] < pos + chunk)  # [C, L]
         new_cache = []
         for p, layer_cache in zip(params["layers"], cache):
-            layer_cache = _c_layer(layer_cache, mesh, kv_spec)
-            h = llama.rms_norm(x, p["attention_norm"]["weight"],
-                               args.rms_norm_eps)
-            pa = p["attention"]
-            q = llama._linear(h, pa["wq"]).reshape(1, chunk, Hq, Dh)
-            k = llama._linear(h, pa["wk"]).reshape(1, chunk, Hkv, Dh)
-            v = llama._linear(h, pa["wv"]).reshape(1, chunk, Hkv, Dh)
-            q = llama.apply_rope(q, cos, sin, args.rope_traditional)
-            k = llama.apply_rope(k, cos, sin, args.rope_traditional)
-            new_layer, ck, cv = _write_kv_slot(layer_cache, k, v, slot, pos)
-            new_cache.append(_c_layer(new_layer, mesh, kv_spec))
-            out = reference_attention(q, ck[:, :attend_len],
-                                      cv[:, :attend_len], explicit_mask=mask)
-            x = x + llama._linear(out.reshape(1, chunk, Hq * Dh), pa["wo"])
-            x = x + _ffn(p, llama.rms_norm(x, p["ffn_norm"]["weight"],
-                                           args.rms_norm_eps), args)
+            with jax.named_scope("layer"):
+                layer_cache = _c_layer(layer_cache, mesh, kv_spec)
+                q, k, v = _qkv(p, x, args, rope)
+                with jax.named_scope("kv_gather"):
+                    new_layer, ck, cv = _write_kv_slot(layer_cache, k, v, slot, pos)
+                new_cache.append(_c_layer(new_layer, mesh, kv_spec))
+                with jax.named_scope("attn_core"):
+                    out = reference_attention(q, ck[:, :attend_len],
+                                              cv[:, :attend_len], explicit_mask=mask)
+                x = _attn_out_ffn(p, x, out, args)
         if not with_logits:
             return new_cache, None
-        x = llama.rms_norm(x, params["norm"]["weight"], args.rms_norm_eps)
         logits = _project_logits(params, x, args)  # [1, C, V]
         logits = _c(logits, mesh, P())
         last = jax.lax.dynamic_slice_in_dim(logits, last_idx, 1, axis=1)
         return new_cache, last[:, 0, :]  # [1, V]
 
-    _STEP_CACHE[key_] = step
-    return step
+    _STEP_CACHE[key_] = prefill_step
+    return prefill_step
 
 
 def _paged_write(layer_cache, k, v, blocks, offs):
@@ -462,12 +482,11 @@ def paged_decode_step(args: llama.LlamaArgs, draft_len: int, attend_len: int,
     if attend_len % block_size:
         raise ValueError(f"attend_len {attend_len} not a multiple of "
                          f"block_size {block_size}")
-    Hq, Hkv, Dh = args.num_heads, args.num_kv_heads, args.head_dim
     S = draft_len + 1
     nb = attend_len // block_size
-    kv_spec = kv_cache_pspec(mesh, Hkv)
+    kv_spec = kv_cache_pspec(mesh, args.num_kv_heads)
 
-    def step(params, cache, tokens, pos, tables, temps, keys):
+    def paged_decode_step(params, cache, tokens, pos, tables, temps, keys):
         B = tokens.shape[0]
         positions = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
         # Write coordinates. Positions past the table extent are redirected
@@ -479,35 +498,36 @@ def paged_decode_step(args: llama.LlamaArgs, draft_len: int, attend_len: int,
         blocks = jnp.take_along_axis(tables, pc // block_size, axis=1)
         blocks = jnp.where(safe, blocks, 0)
         offs = pc % block_size
-        x = params["tok_embeddings"]["weight"][tokens]  # [B, S, D]
+        with jax.named_scope("embed"):
+            x = params["tok_embeddings"]["weight"][tokens]  # [B, S, D]
         x = _c(x, mesh, _batch_pspec(mesh, B))
+        rope = lambda t: _rope_rows(t, positions, args)
         k_idx = jnp.arange(attend_len, dtype=jnp.int32)
         # verify position s attends everything at or before pos + s — its
         # own KV is written first, so drafts see their accepted prefix
         mask = (k_idx[None, None, :] <= positions[:, :, None])  # [B, S, L]
         new_cache = []
         for p, layer_cache in zip(params["layers"], cache):
-            layer_cache = _c_layer(layer_cache, mesh, kv_spec)
-            h = llama.rms_norm(x, p["attention_norm"]["weight"],
-                               args.rms_norm_eps)
-            pa = p["attention"]
-            q = llama._linear(h, pa["wq"]).reshape(B, S, Hq, Dh)
-            k = llama._linear(h, pa["wk"]).reshape(B, S, Hkv, Dh)
-            v = llama._linear(h, pa["wv"]).reshape(B, S, Hkv, Dh)
-            q = _rope_rows(q, positions, args)
-            k = _rope_rows(k, positions, args)
-            new_layer = _c_layer(_paged_write(layer_cache, k, v, blocks, offs),
-                                 mesh, kv_spec)
-            new_cache.append(new_layer)
-            ck, cv = _paged_gather(new_layer, tables, nb)
-            out = reference_attention(
-                q, ck, cv, explicit_mask=mask[:, None, None, :, :])
-            x = x + llama._linear(out.reshape(B, S, Hq * Dh), pa["wo"])
-            x = x + _ffn(p, llama.rms_norm(x, p["ffn_norm"]["weight"],
-                                           args.rms_norm_eps), args)
-        x = llama.rms_norm(x, params["norm"]["weight"], args.rms_norm_eps)
+            with jax.named_scope("layer"):
+                layer_cache = _c_layer(layer_cache, mesh, kv_spec)
+                q, k, v = _qkv(p, x, args, rope)
+                with jax.named_scope("kv_gather"):
+                    new_layer = _c_layer(
+                        _paged_write(layer_cache, k, v, blocks, offs), mesh, kv_spec)
+                    ck, cv = _paged_gather(new_layer, tables, nb)
+                new_cache.append(new_layer)
+                with jax.named_scope("attn_core"):
+                    out = reference_attention(
+                        q, ck, cv, explicit_mask=mask[:, None, None, :, :])
+                x = _attn_out_ffn(p, x, out, args)
         logits = _project_logits(params, x, args)  # [B, S, V]
         logits = _c(logits, mesh, P())
+        with jax.named_scope("sample"):
+            return (new_cache,) + _verify_and_sample(logits, tokens, temps, keys)
+
+    def _verify_and_sample(logits, tokens, temps, keys):
+        """Greedy verify outputs and the point-mass sampled-acceptance
+        outputs for every row (the tail of the returned tuple)."""
         lp_all = jax.nn.log_softmax(logits, axis=-1)
         preds = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, S]
         lp_preds = jnp.take_along_axis(lp_all, preds[..., None],
@@ -541,10 +561,11 @@ def paged_decode_step(args: llama.LlamaArgs, draft_len: int, attend_len: int,
 
         accept, alts, lp_draft, lp_alt, bonus, lp_bonus = jax.vmap(row)(
             subs, logits, temps, tokens[:, 1:])
-        return (new_cache, preds, lp_preds, accept, alts, lp_draft, lp_alt,
+        return (preds, lp_preds, accept, alts, lp_draft, lp_alt,
                 bonus, lp_bonus, new_keys)
 
-    fn = step if raw else partial(jax.jit, donate_argnums=_donate_cache())(step)
+    fn = paged_decode_step if raw else partial(
+        jax.jit, donate_argnums=_donate_cache())(paged_decode_step)
     _STEP_CACHE[key_] = fn
     return fn
 
@@ -569,16 +590,17 @@ def paged_prefill_step(args: llama.LlamaArgs, chunk: int, attend_len: int,
     if attend_len % block_size:
         raise ValueError(f"attend_len {attend_len} not a multiple of "
                          f"block_size {block_size}")
-    Hq, Hkv, Dh = args.num_heads, args.num_kv_heads, args.head_dim
     nb = attend_len // block_size
-    kv_spec = kv_cache_pspec(mesh, Hkv)
+    kv_spec = kv_cache_pspec(mesh, args.num_kv_heads)
 
     @partial(jax.jit, donate_argnums=_donate_cache())
-    def step(params, cache, tokens, table, pos, last_idx):
-        x = params["tok_embeddings"]["weight"][tokens][None]  # [1, C, D]
+    def paged_prefill_step(params, cache, tokens, table, pos, last_idx):
+        with jax.named_scope("embed"):
+            x = params["tok_embeddings"]["weight"][tokens][None]  # [1, C, D]
         positions = jnp.arange(chunk, dtype=jnp.int32) + pos  # [C]
-        cos, sin = llama.rope_cos_sin(positions, Dh, args.rope_theta,
+        cos, sin = llama.rope_cos_sin(positions, args.head_dim, args.rope_theta,
                                       args.rope_scaling_factor)
+        rope = lambda t: llama.apply_rope(t, cos, sin, args.rope_traditional)
         safe = positions < table_width * block_size
         pc = jnp.where(safe, positions, 0)
         blocks = jnp.where(safe, table[pc // block_size], 0)[None]  # [1, C]
@@ -588,33 +610,26 @@ def paged_prefill_step(args: llama.LlamaArgs, chunk: int, attend_len: int,
             & (k_idx[None, :] < pos + chunk)  # [C, L]
         new_cache = []
         for p, layer_cache in zip(params["layers"], cache):
-            layer_cache = _c_layer(layer_cache, mesh, kv_spec)
-            h = llama.rms_norm(x, p["attention_norm"]["weight"],
-                               args.rms_norm_eps)
-            pa = p["attention"]
-            q = llama._linear(h, pa["wq"]).reshape(1, chunk, Hq, Dh)
-            k = llama._linear(h, pa["wk"]).reshape(1, chunk, Hkv, Dh)
-            v = llama._linear(h, pa["wv"]).reshape(1, chunk, Hkv, Dh)
-            q = llama.apply_rope(q, cos, sin, args.rope_traditional)
-            k = llama.apply_rope(k, cos, sin, args.rope_traditional)
-            new_layer = _c_layer(_paged_write(layer_cache, k, v, blocks, offs),
-                                 mesh, kv_spec)
-            new_cache.append(new_layer)
-            ck, cv = _paged_gather(new_layer, table[None], nb)
-            out = reference_attention(q, ck, cv, explicit_mask=mask)
-            x = x + llama._linear(out.reshape(1, chunk, Hq * Dh), pa["wo"])
-            x = x + _ffn(p, llama.rms_norm(x, p["ffn_norm"]["weight"],
-                                           args.rms_norm_eps), args)
+            with jax.named_scope("layer"):
+                layer_cache = _c_layer(layer_cache, mesh, kv_spec)
+                q, k, v = _qkv(p, x, args, rope)
+                with jax.named_scope("kv_gather"):
+                    new_layer = _c_layer(
+                        _paged_write(layer_cache, k, v, blocks, offs), mesh, kv_spec)
+                    ck, cv = _paged_gather(new_layer, table[None], nb)
+                new_cache.append(new_layer)
+                with jax.named_scope("attn_core"):
+                    out = reference_attention(q, ck, cv, explicit_mask=mask)
+                x = _attn_out_ffn(p, x, out, args)
         if not with_logits:
             return new_cache, None
-        x = llama.rms_norm(x, params["norm"]["weight"], args.rms_norm_eps)
         logits = _project_logits(params, x, args)  # [1, C, V]
         logits = _c(logits, mesh, P())
         last = jax.lax.dynamic_slice_in_dim(logits, last_idx, 1, axis=1)
         return new_cache, last[:, 0, :]  # [1, V]
 
-    _STEP_CACHE[key_] = step
-    return step
+    _STEP_CACHE[key_] = paged_prefill_step
+    return paged_prefill_step
 
 
 def sample_token(logits: jnp.ndarray, temperature: float,

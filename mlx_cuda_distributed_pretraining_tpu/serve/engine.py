@@ -28,9 +28,11 @@ import time
 from typing import Any, Dict, List, Optional
 
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from . import batch_step, faults
 from ..analysis import sync_runtime
+from ..obs import compiles
 from .kv_pool import PagedKVPool, SlotKVPool
 from .scheduler import (
     DECODE,
@@ -184,6 +186,10 @@ class BatchEngine:
         self._thread: Optional[threading.Thread] = None
         self._stats = None
         self.iterations = 0  # graftsync: owner=engine-thread
+        # Loop turns that ran a prefill chunk or a decode step: what device
+        # time per iteration divides by (``iterations`` also ticks on the
+        # idle turns of a waiting engine).
+        self.busy_iterations = 0  # graftsync: owner=engine-thread
         # Cross-thread work: the engine thread is the SOLE mutator of pool
         # bookkeeping and self.params, so KV export/adopt and weight swaps
         # enqueue closures here and _iteration drains them between steps.
@@ -219,6 +225,15 @@ class BatchEngine:
             "serve_requests_total", "requests by outcome")
         self._mc_iterations = reg.counter(
             "serve_iterations_total", "engine loop iterations")
+        self._mc_busy_iterations = reg.counter(
+            "serve_busy_iterations_total",
+            "engine loop iterations that ran a prefill chunk or a decode step")
+        self._mc_compiles = reg.counter(
+            "serve_xla_compiles_total",
+            "XLA compilations and compile-cache loads in this process")
+        self._mc_compile_s = reg.counter(
+            "serve_xla_compile_seconds_total",
+            "seconds spent in XLA compilations and compile-cache loads")
         # TTFT as a real distribution (the old last-value gauge reported
         # whichever request finished last); components let dashboards
         # split queue wait from prefill from decode without a trace file.
@@ -279,6 +294,7 @@ class BatchEngine:
         self._m_last = {  # graftsync: owner=engine-thread
             "admitted": 0, "rejected": 0, "evicted": 0,
             "completed": 0, "preempted": 0, "iterations": 0,
+            "busy_iterations": 0, "xla_compiles": 0, "xla_compile_s": 0.0,
             "spec_proposed": 0, "spec_accepted": 0,
             "prefix_hits": 0, "prefix_misses": 0,
             "prefix_evictions": 0}
@@ -596,8 +612,12 @@ class BatchEngine:
         # /metrics runs on HTTP handler threads while the engine thread
         # mutates them under scheduler.lock.
         sched = self.scheduler.counters()
+        xla_compiles, xla_compile_s = compiles.totals()
         snap = {
             "iterations": self.iterations,
+            "busy_iterations": self.busy_iterations,
+            "xla_compiles": xla_compiles,
+            "xla_compile_s": round(xla_compile_s, 4),
             "batch_occupancy": self.pool.num_used,
             "num_slots": self.pool.num_slots,
             **sched,
@@ -701,6 +721,7 @@ class BatchEngine:
                "completed": sched["completed"],
                "preempted": sched["preempted"],
                "iterations": self.iterations,
+               "busy_iterations": self.busy_iterations,
                "spec_proposed": self._spec_proposed,
                "spec_accepted": self._spec_accepted,
                "prefix_hits": prefix.hits if prefix else 0,
@@ -728,9 +749,14 @@ class BatchEngine:
                 c.inc(d)
         if prefix is not None:
             self._mg_prefix_hit_rate.set(prefix.hit_rate())
-        d = cur["iterations"] - self._m_last["iterations"]
-        if d > 0:
-            self._mc_iterations.inc(d)
+        cur["xla_compiles"], cur["xla_compile_s"] = compiles.totals()
+        for k, c in (("iterations", self._mc_iterations),
+                     ("busy_iterations", self._mc_busy_iterations),
+                     ("xla_compiles", self._mc_compiles),
+                     ("xla_compile_s", self._mc_compile_s)):
+            d = cur[k] - self._m_last[k]
+            if d > 0:
+                c.inc(d)
         self._m_last = cur
         if self._stats is not None:
             # "tok/s" is the key the stats server's aggregate sums, so a
@@ -751,7 +777,10 @@ class BatchEngine:
                                      error=f"engine error: {type(e).__name__}: {e}")
                 busy = False
             if not busy:
-                self._wake.wait(timeout=0.02)
+                # Profiler-only: fifty idle turns a second would push every
+                # request's spans out of the ring.
+                with TraceAnnotation("engine.idle_wait"):
+                    self._wake.wait(timeout=0.02)
                 self._wake.clear()
 
     def _iteration(self) -> bool:
@@ -760,7 +789,10 @@ class BatchEngine:
         sched, pool = self.scheduler, self.pool
         for r in sched.expire(pool):
             self._resolve_evicted(r)
-        admitted = sched.admit(pool)
+        admitted = []
+        if sched.queue_depth():  # a phase only when someone is waiting
+            with self.tracer.phase("engine.admit"):
+                admitted = sched.admit(pool)
         if admitted and self.tracer.enabled:
             for r in admitted:
                 # queue_wait closes at slot binding; kv_alloc and any
@@ -775,17 +807,20 @@ class BatchEngine:
                     self.tracer.instant(
                         "prefix_adopt", trace_id=r.trace_id,
                         cached_tokens=r.cached_tokens)
-        busy = False
         pre = sched.prefilling()
-        if pre:
-            self._prefill_chunk(pre[0])
-            busy = True
-        dec = sched.decoding()
-        if dec:
-            self._decode(dec)
-            busy = True
+        dec = None if pre else sched.decoding()
+        if not pre and not dec:
+            self._publish()
+            return False
+        self.busy_iterations += 1
+        with StepTraceAnnotation("engine_iter", step_num=self.busy_iterations):
+            if pre:
+                self._prefill_chunk(pre[0])
+                dec = sched.decoding()  # a finished prefill decodes this turn
+            if dec:
+                self._decode(dec)
         self._publish()
-        return busy
+        return True
 
     def _resolve_evicted(self, req: Request) -> None:
         # expire() already resolved the waiter; nothing device-side to undo
@@ -811,38 +846,39 @@ class BatchEngine:
             self.pool.register_upto(req.slot, req.prefill_source())
 
     def _prefill_chunk(self, req: Request) -> None:
-        tr = self.tracer
-        t0 = time.perf_counter() if tr.enabled else 0.0
-        pool, C = self.pool, self.chunk
         source = req.prefill_source()
-        P = len(source)
         start = req.prefilled
-        n = min(C, P - start)
-        final = start + n >= P
-        toks = np.zeros(C, np.int32)
-        toks[:n] = source[start:start + n]
-        attend = self._attend(start + C)
-        if pool.kind == "paged":
-            step = batch_step.paged_prefill_step(
-                self.args, C, attend, pool.max_blocks, pool.block_size,
-                with_logits=final, mesh=self.mesh)
-            cache, last_logits = step(self.params, pool.cache, toks,
-                                      pool.tables[req.slot], np.int32(start),
-                                      np.int32(max(n - 1, 0)))
-        else:
-            step = batch_step.prefill_step(self.args, C, attend,
-                                           with_logits=final, mesh=self.mesh)
-            cache, last_logits = step(self.params, pool.cache, toks,
-                                      np.int32(req.slot), np.int32(start),
-                                      np.int32(max(n - 1, 0)))
+        n = min(self.chunk, len(source) - start)
+        final = start + n >= len(source)
+        with self.tracer.phase("engine.prefill_chunk", trace_id=req.trace_id,
+                               req=req.id, start=start, tokens=n, final=final):
+            self._prefill_chunk_inner(req, source, start, n, final)
+
+    def _prefill_chunk_inner(self, req: Request, source: List[int],
+                             start: int, n: int, final: bool) -> None:
+        tr = self.tracer
+        pool, C = self.pool, self.chunk
+        P = len(source)
+        with tr.phase("engine.build_tables"):
+            toks = np.zeros(C, np.int32)
+            toks[:n] = source[start:start + n]
+            attend = self._attend(start + C)
+            if pool.kind == "paged":
+                step = batch_step.paged_prefill_step(
+                    self.args, C, attend, pool.max_blocks, pool.block_size,
+                    with_logits=final, mesh=self.mesh)
+                where = pool.tables[req.slot]
+            else:
+                step = batch_step.prefill_step(self.args, C, attend,
+                                               with_logits=final, mesh=self.mesh)
+                where = np.int32(req.slot)
+        with tr.phase("engine.dispatch"):
+            cache, last_logits = step(self.params, pool.cache, toks, where,
+                                      np.int32(start), np.int32(max(n - 1, 0)))
         pool.cache = cache
         req.prefilled = start + n
         pool.lengths[req.slot] = min(start + n, P)
         self._register_prefix(req)
-        if tr.enabled:
-            tr.complete("prefill_chunk", time.perf_counter() - t0,
-                        trace_id=req.trace_id, req=req.id, start=start,
-                        tokens=n, final=final)
         if not final:
             return
         pool.lengths[req.slot] = P
@@ -855,9 +891,10 @@ class BatchEngine:
                 req.first_token_at = time.monotonic()
             self._finish(req, "prefill")
             return
-        tok, lp, key = batch_step.sample_token(last_logits, req.temperature,
-                                               req.rng_key)
-        req.rng_key = np.asarray(key)
+        with tr.phase("engine.sample_fetch"):
+            tok, lp, key = batch_step.sample_token(last_logits, req.temperature,
+                                                   req.rng_key)
+            req.rng_key = np.asarray(key)
         if req.first_token_at is None:  # unset on preemption re-prefill
             req.first_token_at = time.monotonic()
         self._emit(req, tok, lp)
@@ -887,32 +924,39 @@ class BatchEngine:
         req._decode_ticks = 0
 
     def _decode(self, dec: List[Request]) -> None:
-        if self.pool.kind == "paged":
-            self._decode_paged(dec)
-            return
-        pool = self.pool
-        B = pool.num_slots
-        tokens = np.zeros(B, np.int32)
-        # Free / prefilling rows ride the fixed-shape step pointed at the
-        # reserved junk position; their outputs are discarded.
-        pos = np.full(B, pool.max_len - 1, np.int32)
-        temps = np.zeros(B, np.float32)
-        keys = np.zeros((B, 2), np.uint32)
-        if self.tracer.enabled:
+        with self.tracer.phase("engine.decode", rows=len(dec)):
+            if self.pool.kind == "paged":
+                self._decode_paged(dec)
+            else:
+                self._decode_slotted(dec)
+
+    def _decode_slotted(self, dec: List[Request]) -> None:
+        pool, tr = self.pool, self.tracer
+        if tr.enabled:
             self._open_decode_spans(dec)
-        for r in dec:
-            tokens[r.slot] = r.last_token
-            pos[r.slot] = pool.lengths[r.slot]
-            temps[r.slot] = r.temperature
-            keys[r.slot] = r.rng_key
-        bucket = batch_step.attend_bucket(
-            int(pos[[r.slot for r in dec]].max()) + 1, pool.max_len)
-        step = batch_step.decode_step(self.args, bucket, mesh=self.mesh)
-        cache, tok, lp, new_keys = step(self.params, pool.cache, tokens,
-                                        pos, temps, keys)
+        with tr.phase("engine.build_tables"):
+            B = pool.num_slots
+            tokens = np.zeros(B, np.int32)
+            # Free / prefilling rows ride the fixed-shape step pointed at the
+            # reserved junk position; their outputs are discarded.
+            pos = np.full(B, pool.max_len - 1, np.int32)
+            temps = np.zeros(B, np.float32)
+            keys = np.zeros((B, 2), np.uint32)
+            for r in dec:
+                tokens[r.slot] = r.last_token
+                pos[r.slot] = pool.lengths[r.slot]
+                temps[r.slot] = r.temperature
+                keys[r.slot] = r.rng_key
+            bucket = batch_step.attend_bucket(
+                int(pos[[r.slot for r in dec]].max()) + 1, pool.max_len)
+            step = batch_step.decode_step(self.args, bucket, mesh=self.mesh)
+        with tr.phase("engine.dispatch"):
+            cache, tok, lp, new_keys = step(self.params, pool.cache, tokens,
+                                            pos, temps, keys)
         pool.cache = cache
-        tok_h, lp_h, keys_h = (np.asarray(tok), np.asarray(lp),
-                               np.asarray(new_keys))
+        with tr.phase("engine.sample_fetch"):
+            tok_h, lp_h, keys_h = (np.asarray(tok), np.asarray(lp),
+                                   np.asarray(new_keys))
         for r in dec:
             pool.lengths[r.slot] += 1
             r.rng_key = keys_h[r.slot]
@@ -963,41 +1007,44 @@ class BatchEngine:
 
         from ..infer.generate import _prompt_lookup_draft
 
-        pool, cfg = self.pool, self.cfg
-        k = self._effective_draft_len()
-        S = k + 1
-        dec = self._grow_or_preempt(dec, S)
-        if not dec:
-            return
-        if self.tracer.enabled:
-            self._open_decode_spans(dec)
-        B = pool.num_slots
-        # Masked rows: token 0 at position 0 — their (freed) table rows map
-        # every entry to the shared junk block, so their writes land there.
-        tokens = np.zeros((B, S), np.int32)
-        pos = np.zeros(B, np.int32)
-        temps = np.zeros(B, np.float32)
-        keys = np.zeros((B, 2), np.uint32)
-        drafts: Dict[int, List[int]] = {}
-        for r in dec:
-            d = (_prompt_lookup_draft(r.prompt_ids + r.tokens, k,
-                                      cfg.spec_max_ngram) if k else [])
-            drafts[r.slot] = d
-            tokens[r.slot] = [r.last_token] + d
-            pos[r.slot] = pool.lengths[r.slot]
-            temps[r.slot] = r.temperature
-            keys[r.slot] = r.rng_key
-        bucket = self._attend(
-            int(pos[[r.slot for r in dec]].max()) + S)
-        step = batch_step.paged_decode_step(self.args, k, bucket,
-                                            pool.max_blocks, pool.block_size,
-                                            mesh=self.mesh)
-        out = step(self.params, pool.cache, tokens, pos, pool.tables,
-                   temps, keys)
+        pool, cfg, tr = self.pool, self.cfg, self.tracer
+        with tr.phase("engine.build_tables"):
+            k = self._effective_draft_len()
+            S = k + 1
+            dec = self._grow_or_preempt(dec, S)
+            if not dec:
+                return
+            if tr.enabled:
+                self._open_decode_spans(dec)
+            B = pool.num_slots
+            # Masked rows: token 0 at position 0 — their (freed) table rows map
+            # every entry to the shared junk block, so their writes land there.
+            tokens = np.zeros((B, S), np.int32)
+            pos = np.zeros(B, np.int32)
+            temps = np.zeros(B, np.float32)
+            keys = np.zeros((B, 2), np.uint32)
+            drafts: Dict[int, List[int]] = {}
+            for r in dec:
+                d = (_prompt_lookup_draft(r.prompt_ids + r.tokens, k,
+                                          cfg.spec_max_ngram) if k else [])
+                drafts[r.slot] = d
+                tokens[r.slot] = [r.last_token] + d
+                pos[r.slot] = pool.lengths[r.slot]
+                temps[r.slot] = r.temperature
+                keys[r.slot] = r.rng_key
+            bucket = self._attend(
+                int(pos[[r.slot for r in dec]].max()) + S)
+            step = batch_step.paged_decode_step(self.args, k, bucket,
+                                                pool.max_blocks, pool.block_size,
+                                                mesh=self.mesh)
+        with tr.phase("engine.dispatch"):
+            out = step(self.params, pool.cache, tokens, pos, pool.tables,
+                       temps, keys)
         pool.cache = out[0]
-        # ONE blocking transfer for every small output.
-        (preds, lp_preds, accept, alts, lp_draft, lp_alt,
-         bonus, lp_bonus, new_keys) = jax.device_get(out[1:])
+        with tr.phase("engine.sample_fetch"):
+            # ONE blocking transfer for every small output.
+            (preds, lp_preds, accept, alts, lp_draft, lp_alt,
+             bonus, lp_bonus, new_keys) = jax.device_get(out[1:])
         for r in dec:
             s = r.slot
             p0 = pool.lengths[s]

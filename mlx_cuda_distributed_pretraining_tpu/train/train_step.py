@@ -92,7 +92,8 @@ def make_train_step(
         def body(carry, mb):
             acc_loss, acc_toks, acc_s, acc_g = carry
             loss, toks, stats, g = grads_of(params, mb)
-            acc_g = jax.tree_util.tree_map(lambda a, b: a + b, acc_g, g)
+            with jax.named_scope("grad_accum"):
+                acc_g = jax.tree_util.tree_map(lambda a, b: a + b, acc_g, g)
             if moe_stats:
                 acc_s = {k: acc_s[k] + stats[k] for k in acc_s}
             return (acc_loss + loss, acc_toks + toks, acc_s, acc_g), None
@@ -102,8 +103,9 @@ def make_train_step(
             (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32), zero_s, zero_g),
             micro,
         )
-        inv = 1.0 / accum_steps
-        return loss_sum * inv, toks, stats, jax.tree_util.tree_map(lambda g: g * inv, grads)
+        with jax.named_scope("grad_accum"):
+            inv = 1.0 / accum_steps
+            return loss_sum * inv, toks, stats, jax.tree_util.tree_map(lambda g: g * inv, grads)
 
     def train_step(state: TrainState, batch: Dict[str, jnp.ndarray]):
         params = state["params"]
@@ -112,15 +114,16 @@ def make_train_step(
         else:
             loss, toks, stats, grads = grads_of(params, batch)
         fused = fused_apply_of(optimizer)
-        if fused is not None:
-            # Single-pass update+apply (optim/fused.py): bitwise equal to
-            # the chain below, but with no intermediate updates tree, so
-            # the donated params/moments alias input->output cleanly
-            # (graftaudit donation-gap 0 on this program).
-            new_params, opt_state = fused(grads, state["opt_state"], params)
-        else:
-            updates, opt_state = optimizer.update(grads, state["opt_state"], params)
-            new_params = apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            if fused is not None:
+                # Single-pass update+apply (optim/fused.py): bitwise equal to
+                # the chain below, but with no intermediate updates tree, so
+                # the donated params/moments alias input->output cleanly
+                # (graftaudit donation-gap 0 on this program).
+                new_params, opt_state = fused(grads, state["opt_state"], params)
+            else:
+                updates, opt_state = optimizer.update(grads, state["opt_state"], params)
+                new_params = apply_updates(params, updates)
         metrics = {
             "loss": loss,
             "toks": toks,

@@ -44,7 +44,9 @@ from ..obs.events import (
     replay_into,
     write_heartbeat,
 )
-from ..obs.flops import GoodputLedger, model_flops_per_token, peak_flops_per_chip
+from ..obs import compiles
+from ..obs.flops import (GoodputLedger, matmul_params, model_flops_per_token,
+                         peak_flops_per_chip)
 from ..obs.flops import mfu as compute_mfu
 from ..obs.metrics import MetricsRegistry
 from ..obs.trace import Tracer
@@ -447,7 +449,8 @@ class Trainer:
         # param count, peak from the chip's device_kind (None on CPU — log
         # lines then report mfu=unknown; an unlisted accelerator raises).
         self.flops_per_token = model_flops_per_token(
-            cfg.model, self.n_params, cfg.data.max_context_size)
+            cfg.model, self.n_params, cfg.data.max_context_size,
+            vocab_size=self.model_args.vocab_size)
         self.peak_flops = peak_flops_per_chip()
         self.goodput = GoodputLedger()
         # Span tracer (obs/trace.py): mirrors every goodput booking as a
@@ -485,6 +488,9 @@ class Trainer:
         # breakdown rides the same durable stream as tok/s and MFU.
         self._prof_fields: Dict[str, float] = {}
         self._compiled = False  # first dispatch books into compile_s
+        # obs/compiles.py totals at the last window's close: the difference
+        # rides each step_window event as xla_compiles / xla_compile_s.
+        self._compiles_seen = compiles.totals()
         self._metrics_server = None
         # events.jsonl is the durable telemetry source: replay it FIRST so
         # counters survive crash-restarts, then open for append. Chief only
@@ -635,12 +641,10 @@ class Trainer:
         async) books into the goodput ledger as ``ckpt_save_s`` and lands
         in events.jsonl, and the heartbeat is refreshed afterwards so a
         long blocking save never trips the hang watchdog."""
-        t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation("checkpoint_save"):
+        with self.tracer.phase("checkpoint_save", step=str(step)) as ph:
             self._save_checkpoint_inner(step, blocking)
-        dt = time.perf_counter() - t0
+        dt = ph.seconds
         self.goodput.add("ckpt_save_s", dt)
-        self._trace_phase("ckpt_save", dt, step=str(step))
         self._m_saves.inc()
         if self.events is not None:
             self.events.append("checkpoint_save", step=step,
@@ -648,10 +652,25 @@ class Trainer:
         self._touch_heartbeat()
 
     def _trace_phase(self, name: str, dur_s: float, **args) -> None:
-        """Record one goodput-phase span (same duration the ledger got).
-        A no-op method call when tracing is off — nothing allocated."""
+        """Record, after the fact, a wait the prefetch worker measured
+        (``data_wait`` / ``h2d_wait``; same duration the ledger got). The
+        loop's own phases are live spans (``tracer.phase``). A no-op
+        method call when tracing is off — nothing allocated."""
         if self.tracer.enabled:
             self.tracer.complete(name, dur_s, **args)
+
+    def _book_dispatch(self, seconds: float, step: int) -> None:
+        """Book one call into the jitted step: the run's first dispatch is
+        dominated by the XLA compile and goes to ``compile_s``, so that
+        steady-state ``dispatch_s`` stays meaningful (later compilations
+        show as ``xla_compiles`` on the window's event)."""
+        if self._compiled:
+            self.goodput.add("dispatch_s", seconds)
+            return
+        self._compiled = True
+        self.goodput.add("compile_s", seconds)
+        if self.events is not None:
+            self.events.append("compile", seconds=round(seconds, 4), step=step)
 
     def _touch_heartbeat(self, step: Optional[int] = None) -> None:
         if self._hb_path is None:
@@ -670,7 +689,8 @@ class Trainer:
         trainer already holds for MFU, split into the 6N matmul term and
         the attention residual (obs/flops.py convention)."""
         cfg = self.config
-        matmul = 6.0 * float(self.n_params)
+        matmul = 6.0 * matmul_params(cfg.model, self.n_params,
+                                     self.model_args.vocab_size)
         return {
             "tokens_per_step": float(cfg.training.batch_size)
             * float(cfg.data.max_context_size),
@@ -930,12 +950,10 @@ class Trainer:
         """Timed + profiler-annotated wrapper (see save_checkpoint): eval
         wall clock books into goodput as ``eval_s``; each completed pass
         counts in the registry and events.jsonl."""
-        t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation("eval"):
+        with self.tracer.phase("eval") as ph:
             result = self._validate_inner(cap)
-        dt = time.perf_counter() - t0
+        dt = ph.seconds
         self.goodput.add("eval_s", dt)
-        self._trace_phase("eval", dt)
         if result is not None:
             self._m_evals.inc()
             if self.events is not None:
@@ -1249,7 +1267,8 @@ class Trainer:
                             # Stacked [K, B, L], already device-resident and
                             # sharded; StopIteration mid-group served the
                             # fetched prefix on the previous get().
-                            stacked, group_tokens, waits = self.prefetcher.get()
+                            with self.tracer.phase("train.data_get", step=step):
+                                stacked, group_tokens, waits = self.prefetcher.get()
                         except StopIteration:
                             self.logger.log(
                                 f"Data stream exhausted before step {step}; stopping")
@@ -1261,26 +1280,13 @@ class Trainer:
                             self.goodput.add("h2d_wait_s", waits["h2d_wait_s"])
                             self._trace_phase("h2d_wait", waits["h2d_wait_s"],
                                               step=step)
-                        t_dispatch = time.perf_counter()
                         # StepTraceAnnotation: profiler traces carry the
                         # trainer's step numbering, lining up with
                         # events.jsonl step_window records.
-                        with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                        with jax.profiler.StepTraceAnnotation("train", step_num=step), \
+                                self.tracer.phase("train.dispatch", step=step) as ph:
                             self.state, mm = self.train_multi_step(self.state, stacked)
-                        t_d = time.perf_counter() - t_dispatch
-                        if not self._compiled:
-                            # The run's first dispatch is dominated by the
-                            # XLA compile — book it separately so steady-
-                            # state dispatch_s stays meaningful.
-                            self._compiled = True
-                            self.goodput.add("compile_s", t_d)
-                            self._trace_phase("compile", t_d, step=step)
-                            if self.events is not None:
-                                self.events.append("compile", seconds=round(t_d, 4),
-                                                   step=step)
-                        else:
-                            self.goodput.add("dispatch_s", t_d)
-                            self._trace_phase("dispatch", t_d, step=step)
+                        self._book_dispatch(ph.seconds, step)
                         pending = [
                             (jax.tree_util.tree_map(lambda a, i=i: a[i], mm),
                              t * jax.process_count())
@@ -1291,7 +1297,8 @@ class Trainer:
                     self.total_tokens += step_tokens
                 else:
                     try:
-                        batch, local_tokens, waits = self.prefetcher.get()
+                        with self.tracer.phase("train.data_get", step=step):
+                            batch, local_tokens, waits = self.prefetcher.get()
                     except StopIteration:  # finite stream ran dry (streaming sources)
                         self.logger.log(f"Data stream exhausted before step {step}; stopping")
                         break
@@ -1308,140 +1315,140 @@ class Trainer:
                         self.goodput.add("h2d_wait_s", waits["h2d_wait_s"])
                         self._trace_phase("h2d_wait", waits["h2d_wait_s"],
                                           step=step)
-                    t_dispatch = time.perf_counter()
-                    with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                    with jax.profiler.StepTraceAnnotation("train", step_num=step), \
+                            self.tracer.phase("train.dispatch", step=step) as ph:
                         self.state, metrics = self.train_step(self.state, batch)
-                    t_d = time.perf_counter() - t_dispatch
-                    if not self._compiled:
-                        self._compiled = True
-                        self.goodput.add("compile_s", t_d)
-                        self._trace_phase("compile", t_d, step=step)
-                        if self.events is not None:
-                            self.events.append("compile", seconds=round(t_d, 4),
-                                               step=step)
-                    else:
-                        self.goodput.add("dispatch_s", t_d)
-                        self._trace_phase("dispatch", t_d, step=step)
+                    self._book_dispatch(ph.seconds, step)
 
                 window_steps += 1
                 if self.moe_stats_experts and "moe_load" in metrics:
                     # Device arrays, no sync: summed/read at the log line.
                     window_moe.append((metrics["moe_load"], metrics["moe_dropped"]))
                 if step % log_int == 0 or step == self.total_steps:
-                    loss = float(metrics["loss"])  # device sync point
-                    last_loss = loss
-                    elapsed = max(time.perf_counter() - window_start, 1e-9)
-                    # Close the goodput window: components (compile, data
-                    # wait, h2d, dispatch, ckpt save, eval) plus the
-                    # other_s residual sum to elapsed by construction.
-                    gp = self.goodput.close_window(elapsed)
-                    tok_s = window_tokens / elapsed
-                    mfu_val = compute_mfu(tok_s, self.flops_per_token,
-                                          self.peak_flops, jax.device_count())
-                    line = {
-                        "loss": loss,
-                        "ppl": float(math.exp(min(loss, 30.0))),
-                        # Host-side numpy evaluation: the jnp path re-traces
-                        # the schedule closure and syncs a device scalar on
-                        # every log line (see tests/lint_fixtures).
-                        "lr": schedule_value(self.schedule, step),
-                        "tok/s": tok_s,
-                        "toks": int(window_tokens),
-                        # Hardware efficiency: analytic FLOPs/token * tok/s
-                        # over chip peak (obs/flops.py); "unknown" on CPU,
-                        # which has no listed peak.
-                        "mfu": mfu_val if mfu_val is not None else "unknown",
-                        # Goodput breakdown for this window (sums to wall
-                        # time): data_wait is the only true input stall
-                        # (queue get); h2d is booked only when the transfer
-                        # blocks the step loop (prefetch_depth=0); dispatch
-                        # is time inside the jitted-step calls; other_s is
-                        # the residual.
-                        "data_wait_s": gp["data_wait_s"],
-                        "h2d_wait_s": gp["h2d_wait_s"],
-                        "dispatch_s": gp["dispatch_s"],
-                        "compile_s": gp["compile_s"],
-                        "ckpt_save_s": gp["ckpt_save_s"],
-                        "eval_s": gp["eval_s"],
-                        "other_s": gp["other_s"],
-                        "data_wait_frac": min(gp["data_wait_s"] / elapsed, 1.0),
-                    }
-                    if "grad_norm" in metrics:
-                        line["grad_norm"] = float(metrics["grad_norm"])
-                        self._g_grad_norm.set(line["grad_norm"])
-                    if self.pipeline:
-                        # Honest schedule accounting: the bubble is a
-                        # property of (pp, M, V), constant across the run,
-                        # but belongs on every window line next to mfu= so
-                        # readers see the idle fraction the MFU number is
-                        # already paying for.
-                        line["bubble"] = round(self._bubble_frac, 4)
-                        self._g_bubble.set(self._bubble_frac)
-                    if window_moe:
-                        # Routing observability (models/moe.py stats tap):
-                        # expert-load fractions over the window, normalized
-                        # balance entropy (1.0 = uniform routing, 0.0 = one
-                        # expert takes everything), and the dropped-selection
-                        # count (always 0 for the dropless grouped impl;
-                        # nonzero under einsum capacity or a capped ep
-                        # exchange factor).
-                        import numpy as _np
-
-                        load = _np.asarray(sum(m[0] for m in window_moe), _np.float64)
-                        dropped = int(sum(m[1] for m in window_moe))
-                        total = max(load.sum(), 1.0)
-                        frac = load / total
-                        nz = frac[frac > 0]
-                        ent = float(-(nz * _np.log(nz)).sum() / math.log(max(len(load), 2)))
-                        line["moe_entropy"] = ent
-                        line["moe_drop"] = dropped
-                        line["moe_load_max"] = float(frac.max())
-                        self._g_moe_entropy.set(ent)
-                        self._m_moe_dropped.inc(dropped)
-                        for e, f in enumerate(frac):
-                            self._g_moe_load.set(float(f), expert=str(e))
-                        window_moe = []
-                    if int(metrics["nonfinite"]):
-                        self.logger.log(f"WARNING: non-finite loss at step {step}")
-                        self._m_nonfinite.inc()
-                    self.logger.log_metrics(step, line)
-                    if self.stats_client is not None:
-                        self.stats_client.log_metrics(step, line)
-                    # Registry + event log: the durable counters Prometheus
-                    # exports and replay_into rebuilds must move in lockstep
-                    # with the step_window events.
-                    self._m_steps.inc(window_steps)
-                    self._m_toks.inc(window_tokens)
-                    self._g_step.set(step)
-                    self._g_loss.set(loss)
-                    self._g_tok_s.set(tok_s)
-                    if mfu_val is not None:
-                        self._g_mfu.set(mfu_val)
-                    for comp, secs in gp.items():
-                        if secs > 0:
-                            self._m_goodput.inc(secs, component=comp)
-                    if self.events is not None:
-                        ev = dict(
-                            step=step, steps=window_steps,
-                            toks=int(window_tokens), loss=round(loss, 6),
-                            tok_s=round(tok_s, 2), mfu=mfu_val,
-                            goodput={k: round(v, 6) for k, v in gp.items()})
+                    with self.tracer.phase("train.loss_sync", step=step):
+                        loss = float(metrics["loss"])  # device sync point
+                    with self.tracer.phase("train.log_window", step=step):
+                        last_loss = loss
+                        elapsed = max(time.perf_counter() - window_start, 1e-9)
+                        # Close the goodput window: components (compile, data
+                        # wait, h2d, dispatch, ckpt save, eval) plus the
+                        # other_s residual sum to elapsed by construction.
+                        gp = self.goodput.close_window(elapsed)
+                        tok_s = window_tokens / elapsed
+                        # tok_s is the job's; its chips are the mesh's
+                        # (one device without a mesh), not the host's.
+                        mfu_val = compute_mfu(
+                            tok_s, self.flops_per_token, self.peak_flops,
+                            self.mesh.size if self.mesh is not None else 1)
+                        line = {
+                            "loss": loss,
+                            "ppl": float(math.exp(min(loss, 30.0))),
+                            # Host-side numpy evaluation: the jnp path re-traces
+                            # the schedule closure and syncs a device scalar on
+                            # every log line (see tests/lint_fixtures).
+                            "lr": schedule_value(self.schedule, step),
+                            "tok/s": tok_s,
+                            "toks": int(window_tokens),
+                            # Hardware efficiency: analytic FLOPs/token * tok/s
+                            # over chip peak (obs/flops.py); "unknown" on CPU,
+                            # which has no listed peak.
+                            "mfu": mfu_val if mfu_val is not None else "unknown",
+                            # Goodput breakdown for this window (sums to wall
+                            # time): data_wait is the only true input stall
+                            # (queue get); h2d is booked only when the transfer
+                            # blocks the step loop (prefetch_depth=0); dispatch
+                            # is time inside the jitted-step calls; other_s is
+                            # the residual.
+                            "data_wait_s": gp["data_wait_s"],
+                            "h2d_wait_s": gp["h2d_wait_s"],
+                            "dispatch_s": gp["dispatch_s"],
+                            "compile_s": gp["compile_s"],
+                            "ckpt_save_s": gp["ckpt_save_s"],
+                            "eval_s": gp["eval_s"],
+                            "other_s": gp["other_s"],
+                            "data_wait_frac": min(gp["data_wait_s"] / elapsed, 1.0),
+                        }
+                        if "grad_norm" in metrics:
+                            line["grad_norm"] = float(metrics["grad_norm"])
+                            self._g_grad_norm.set(line["grad_norm"])
                         if self.pipeline:
-                            ev["bubble"] = round(self._bubble_frac, 6)
-                        # Latest graftprof fractions ride every window
-                        # after a capture, so the durable stream records
-                        # the breakdown next to the tok/s it explains.
-                        ev.update(self._prof_fields)
-                        self.events.append("step_window", **ev)
-                    if self.tracer.enabled:
-                        self.tracer.instant(
-                            "step_window", step=step, tok_s=round(tok_s, 2),
-                            mfu=(mfu_val if mfu_val is not None
-                                 else "unknown"))
-                    self._touch_heartbeat(step)
-                    window_tokens = 0
-                    window_steps = 0
-                    window_start = time.perf_counter()
+                            # Honest schedule accounting: the bubble is a
+                            # property of (pp, M, V), constant across the run,
+                            # but belongs on every window line next to mfu= so
+                            # readers see the idle fraction the MFU number is
+                            # already paying for.
+                            line["bubble"] = round(self._bubble_frac, 4)
+                            self._g_bubble.set(self._bubble_frac)
+                        if window_moe:
+                            # Routing observability (models/moe.py stats tap):
+                            # expert-load fractions over the window, normalized
+                            # balance entropy (1.0 = uniform routing, 0.0 = one
+                            # expert takes everything), and the dropped-selection
+                            # count (always 0 for the dropless grouped impl;
+                            # nonzero under einsum capacity or a capped ep
+                            # exchange factor).
+                            import numpy as _np
+
+                            load = _np.asarray(sum(m[0] for m in window_moe), _np.float64)
+                            dropped = int(sum(m[1] for m in window_moe))
+                            total = max(load.sum(), 1.0)
+                            frac = load / total
+                            nz = frac[frac > 0]
+                            ent = float(-(nz * _np.log(nz)).sum() / math.log(max(len(load), 2)))
+                            line["moe_entropy"] = ent
+                            line["moe_drop"] = dropped
+                            line["moe_load_max"] = float(frac.max())
+                            self._g_moe_entropy.set(ent)
+                            self._m_moe_dropped.inc(dropped)
+                            for e, f in enumerate(frac):
+                                self._g_moe_load.set(float(f), expert=str(e))
+                            window_moe = []
+                        if int(metrics["nonfinite"]):
+                            self.logger.log(f"WARNING: non-finite loss at step {step}")
+                            self._m_nonfinite.inc()
+                        self.logger.log_metrics(step, line)
+                        if self.stats_client is not None:
+                            self.stats_client.log_metrics(step, line)
+                        # Registry + event log: the durable counters Prometheus
+                        # exports and replay_into rebuilds must move in lockstep
+                        # with the step_window events.
+                        self._m_steps.inc(window_steps)
+                        self._m_toks.inc(window_tokens)
+                        self._g_step.set(step)
+                        self._g_loss.set(loss)
+                        self._g_tok_s.set(tok_s)
+                        if mfu_val is not None:
+                            self._g_mfu.set(mfu_val)
+                        for comp, secs in gp.items():
+                            if secs > 0:
+                                self._m_goodput.inc(secs, component=comp)
+                        if self.events is not None:
+                            ev = dict(
+                                step=step, steps=window_steps,
+                                toks=int(window_tokens), loss=round(loss, 6),
+                                tok_s=round(tok_s, 2), mfu=mfu_val,
+                                goodput={k: round(v, 6) for k, v in gp.items()})
+                            seen = compiles.totals()
+                            ev["xla_compiles"] = seen[0] - self._compiles_seen[0]
+                            ev["xla_compile_s"] = round(
+                                seen[1] - self._compiles_seen[1], 4)
+                            self._compiles_seen = seen
+                            if self.pipeline:
+                                ev["bubble"] = round(self._bubble_frac, 6)
+                            # Latest graftprof fractions ride every window
+                            # after a capture, so the durable stream records
+                            # the breakdown next to the tok/s it explains.
+                            ev.update(self._prof_fields)
+                            self.events.append("step_window", **ev)
+                        if self.tracer.enabled:
+                            self.tracer.instant(
+                                "step_window", step=step, tok_s=round(tok_s, 2),
+                                mfu=(mfu_val if mfu_val is not None
+                                     else "unknown"))
+                        self._touch_heartbeat(step)
+                        window_tokens = 0
+                        window_steps = 0
+                        window_start = time.perf_counter()
 
                 if val_int and step % val_int == 0:
                     v = self.validate()

@@ -8,7 +8,7 @@ replica's ``GET /trace``, and the trainer's ``trace_step<N>.json`` /
 wall-clock timeline and are joined by the ``trace_id`` each span
 carries in its args (minted by the router, propagated via the
 ``X-Trace-Id`` header), so a single request's ``route`` span on the
-router nests the ``queue_wait`` / ``prefill_chunk`` / ``decode`` spans
+router nests the ``queue_wait`` / ``engine.prefill_chunk`` / ``decode`` spans
 recorded on whichever replica served it:
 
     python scripts/trace_report.py router_trace.json \
@@ -24,10 +24,13 @@ Prints, in ``key=value`` form:
   * per-component TTFT breakdown percentiles (queue_wait, prefill,
     decode, route overhead) across all completed requests;
   * the top-k slowest requests, each with its indented span tree;
-  * trainer step-time attribution — per-phase totals from the goodput
-    ledger's span mirrors (data_wait / h2d_wait / dispatch / ckpt_save
-    / eval / compile) next to the MFU the ``step_window`` instants
-    reported — when a trainer trace file is among the inputs;
+  * trainer step-time attribution — per-phase totals of the loop's live
+    phases (train.data_get / train.dispatch / train.loss_sync /
+    train.log_window / checkpoint_save / eval: the names a jax.profiler
+    trace of the same run carries), and inside train.data_get the waits
+    the prefetch worker measured (data_wait / h2d_wait), next to the MFU
+    the ``step_window`` instants reported — when a trainer trace file is
+    among the inputs;
   * with ``--run-dir <run>``: the run's own trace exports join the
     inputs automatically, and when the run holds a jax.profiler dump
     (``<run>/profile/``) the graftprof op-level attribution
@@ -49,14 +52,16 @@ import os
 import sys
 from typing import Any, Dict, List, Optional
 
-# Trainer phase span names (obs/trace.py complete() mirrors of the
-# goodput ledger components, minus the "_s" suffix).
-TRAIN_PHASES = ("compile", "data_wait", "h2d_wait", "dispatch",
-                "ckpt_save", "eval")
+# Trainer phase span names (obs/trace.py phase(): the loop's live spans,
+# which do not overlap), then the prefetch worker's waits, recorded
+# after the fact inside train.data_get and so left out of the booked sum.
+TRAIN_PHASES = ("train.data_get", "train.dispatch", "train.loss_sync",
+                "train.log_window", "checkpoint_save", "eval")
+TRAIN_WAITS = ("data_wait", "h2d_wait")
 # Request-path component span names emitted by serve/engine.py +
 # serve/router.py (+ the prefill->decode KV push from infer/server.py
 # in a disaggregated fleet).
-REQUEST_COMPONENTS = ("queue_wait", "prefill_chunk", "decode",
+REQUEST_COMPONENTS = ("queue_wait", "engine.prefill_chunk", "decode",
                       "kv_transfer")
 # Wall-clock slack (µs) tolerated when nesting spans from different
 # processes: their timelines share one wall anchor but not one clock.
@@ -202,7 +207,7 @@ def request_report(spans, top: int) -> List[str]:
         per = {}
         for e in g["evs"]:
             if e["name"] in REQUEST_COMPONENTS:
-                key = ("prefill" if e["name"] == "prefill_chunk"
+                key = ("prefill" if e["name"] == "engine.prefill_chunk"
                        else e["name"])
                 per[key] = per.get(key, 0.0) + e["dur"] / 1e3
         if g["route"] is not None:
@@ -244,7 +249,7 @@ def trainer_report(spans, instants) -> List[str]:
     # parallel. Single-host traces produce one group and no service= key.
     svc_spans: Dict[str, List[Dict[str, Any]]] = {}
     for s in spans:
-        if s["name"] in TRAIN_PHASES:
+        if s["name"] in TRAIN_PHASES or s["name"] in TRAIN_WAITS:
             svc_spans.setdefault(s["service"], []).append(s)
     if not svc_spans:
         return []
@@ -263,7 +268,7 @@ def trainer_report(spans, instants) -> List[str]:
                 and (not multi or i["service"] == svc)]
         mfus = [float(i["args"]["mfu"]) for i in wins
                 if isinstance(i["args"].get("mfu"), (int, float))]
-        booked = sum(phase_s.values())
+        booked = sum(v for k, v in phase_s.items() if k in TRAIN_PHASES)
         tag = f"service={svc} " if multi else ""
         lines.append(
             f"trainer_attribution=1 {tag}"
@@ -271,7 +276,7 @@ def trainer_report(spans, instants) -> List[str]:
             f"mfu_mean={_fmt(round(sum(mfus) / len(mfus), 4) if mfus else None)} "
             f"booked_s={round(booked, 3)} "
             f"span_wall_s={round(wall, 3)}")
-        for name in TRAIN_PHASES:
+        for name in TRAIN_PHASES + TRAIN_WAITS:
             if name not in phase_s:
                 continue
             lines.append(

@@ -141,8 +141,34 @@ def test_model_flops_per_token_uses_heads_times_head_dim():
         num_heads = 4
         head_dim = 8
 
+        hidden_size = 16
+        misc = {"tie_word_embeddings": False}
+
     assert model_flops_per_token(M, 1000, 64) == \
         flops_per_token(1000, 2, 64, 32)
+    # an untied input table is a lookup, not a matmul: left out of N once
+    # the vocabulary is known (a tied table is the output head and stays)
+    assert model_flops_per_token(M, 1000, 64, vocab_size=10) == \
+        flops_per_token(1000 - 10 * 16, 2, 64, 32)
+    M.misc = {"tie_word_embeddings": True}
+    assert model_flops_per_token(M, 1000, 64, vocab_size=10) == \
+        flops_per_token(1000, 2, 64, 32)
+
+
+def test_trainer_mfu_counts_like_the_benchmark():
+    """The 4-layer Mistral-7B cut of benchmark cell 1: the trainer's count
+    agrees with benchmark/flops/llama_dense.py within the norm gains."""
+    class M:
+        num_layers, num_heads, head_dim, hidden_size = 4, 32, 128, 4096
+        intermediate_size = 14336
+        misc = {"tie_word_embeddings": False}
+
+    n_params = 1_140_887_552  # PERF.md section 4
+    got = model_flops_per_token(M, n_params, 4096, vocab_size=32768)
+    assert got == pytest.approx(6.442e9, rel=1e-3)  # PERF.md section 6, PR 23
+    assert got < 0.9 * flops_per_token(n_params, 4, 4096, 4096)  # the old count
+    # and the job's chips are the mesh's: one device reads 8x an 8-device host
+    assert mfu(1000.0, got, 197e12, 1) == pytest.approx(8 * mfu(1000.0, got, 197e12, 8))
 
 
 def test_peak_flops_detection_and_env_override(monkeypatch):

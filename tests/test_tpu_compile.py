@@ -199,3 +199,58 @@ def test_paged_decode_step_fits_one_v5e(v5e, monkeypatch):
              + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert ma.alias_size_in_bytes > 0, "the KV arena is not donated"
     assert total < HBM_BYTES, f"paged decode step needs {total / 1e9:.2f} GB"
+
+
+def test_scoped_train_step_compiles_for_v5e(v5e, compiled_kernels):
+    """The train step at the benchmark's Mistral-7B widths (two scanned
+    layers, full remat, flash, fused CE, Adafactor with clipping), as the
+    chip's compiler leaves it: every Mosaic call is one of the three named
+    flash kernels and every matmul sits under a scope of the vocabulary, so
+    a trace of the chip can be reduced by scope (README "Reading a profile")."""
+    import re
+    from functools import partial
+
+    from mlx_cuda_distributed_pretraining_tpu.optim.adafactor import adafactor
+    from mlx_cuda_distributed_pretraining_tpu.train.train_step import (
+        init_train_state, make_train_step)
+
+    vocabulary = {
+        "embed", "layer", "norm", "attn_qkv", "attn_core", "attn_out", "ffn",
+        "final_norm", "lm_head_ce", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+        "grad_accum", "grad_clip", "optimizer"}
+    args = llama.LlamaArgs(
+        vocab_size=32768, hidden_size=4096, intermediate_size=14336, num_layers=2,
+        num_heads=32, num_kv_heads=8, head_dim=128, max_position_embeddings=32768,
+        rope_theta=1e6, tie_word_embeddings=False, attention_type="flash")
+    loss = partial(llama.loss_fn, args=args, compute_dtype=jnp.bfloat16,
+                   remat="full", scan_layers=True)
+    opt = adafactor(lambda count: 1e-3, grad_clip=1.0)
+    step, _ = make_train_step(lambda p, b: loss(p, b), opt)
+    on_dev = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: _sds(x.shape, x.dtype, v5e), t)
+    state = on_dev(jax.eval_shape(
+        lambda: init_train_state(llama.init_params(jax.random.PRNGKey(0), args), opt)))
+    batch = {k: _sds((4, 4096), jnp.int32, v5e) for k in ("inputs", "targets", "mask")}
+    hlo = step.lower(state, batch).compile().as_text()
+
+    def innermost(op_name):
+        return next((t for t in reversed(re.split(r"[/()]", op_name)) if t in vocabulary),
+                    None)
+
+    kernels, matmuls = [], []
+    for line in hlo.split("\n"):
+        m = re.search(r'op_name="([^"]+)"', line)
+        if m and "tpu_custom_call" in line:
+            kernels.append(m.group(1))
+        elif m and " convolution(" in line:
+            matmuls.append(m.group(1))
+    # forward, its recomputation, dQ, dK/dV: four calls, three names
+    assert sorted(innermost(k) for k in kernels) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd", "flash_fwd"], kernels
+    assert sum("rematted_computation" in k for k in kernels) == 1
+    assert all(re.search(r"%flash_(fwd|bwd_dq|bwd_dkv)[.\d]* = ", line)
+               for line in hlo.split("\n") if "tpu_custom_call" in line and " = " in line
+               and "custom-call(" in line), "XLA names the instruction after the kernel"
+    assert len(matmuls) >= 20
+    assert {innermost(m) for m in matmuls} == {"attn_qkv", "attn_out", "ffn", "lm_head_ce"}, \
+        sorted({m for m in matmuls if innermost(m) is None})
