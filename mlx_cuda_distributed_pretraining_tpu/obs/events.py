@@ -10,7 +10,8 @@ Event types written by the trainer / supervisor:
   resume           run resumed from a checkpoint (tag, step)
   compile          first dispatch finished compiling (seconds)
   step_window      one logging window (step, steps, toks, loss, tok_s,
-                   mfu, goodput breakdown)
+                   mfu, goodput breakdown; a run's first one also
+                   flash_plan, the flash forward calls traced by path)
   checkpoint_save  a checkpoint landed (step, seconds, blocking)
   verify           checkpoint verification outcome (tag, ok, reason)
   eval             validation ran (step, loss, seconds)

@@ -4,20 +4,30 @@ The reference's "FlashAttention" materializes the full [B,H,S,S] score
 matrix ("Simple approach without tiling for now", reference:
 models/attention/flash_attention.py:100,134-151). This is the real thing:
 
-- forward: online-softmax accumulation with **KV streamed through the
-  grid** — K/V enter VMEM one [block_kv, D] tile at a time via the Pallas
-  pipeline (double-buffered HBM→VMEM DMA), so VMEM never holds the whole
-  sequence and max context is bounded by HBM, not VMEM; fp32 accumulators
-  live in VMEM scratch across the KV grid steps; MXU matmuls via
-  ``dot_general(..., preferred_element_type=f32)``;
+- forward: online-softmax accumulation, fp32 statistics and accumulator in
+  VMEM scratch, MXU matmuls via ``dot_general(..., preferred_element_type=
+  f32)``, in one of two kernels that :func:`flash_plan` picks from the
+  call's shapes while it is traced (:func:`plan_counts` tallies which):
+  **resident** where the K and V of one (sequence, KV head), double-
+  buffered, fit a stated VMEM budget: they enter VMEM once per KV head (the
+  query tiles and the G query heads of a group reuse them) and the kernel
+  walks them itself, a loop over ``[block_kv, D]`` slices from the first
+  live chunk to the last, so no grid step is dead; **streamed** beyond the
+  budget: K/V enter VMEM one ``[block_kv, D]`` tile a grid step via the
+  Pallas pipeline (double-buffered HBM->VMEM DMA), so VMEM never holds the
+  whole sequence and max context is bounded by HBM, not VMEM;
 - block sparsity: per-mask-type KV tile ranges (causal skips the upper
-  triangle, sliding-window skips everything outside the band) — skipped
-  tiles are gated with ``pl.when`` AND their index maps are clamped into
-  the live range, so the pipeline never fetches a tile it will not use;
+  triangle, sliding-window skips everything outside the band). The
+  resident walk runs from ``lo`` to ``hi`` and nothing else; in the
+  streamed grid, skipped tiles are gated with ``pl.when`` AND their index
+  maps are clamped into the live range, so the pipeline never fetches a
+  tile it will not use. Tiles a canonical mask leaves whole skip the mask
+  program: per tile in the streamed kernels, as one unmasked run between
+  masked edges in the resident walk;
 - backward: recomputation-based (saves only O and the logsumexp), split
   into a dQ kernel (KV streamed, dQ in scratch) and a dK/dV kernel
   (Q/dO streamed, dK/dV in scratch), the flash-attention-2 decomposition;
-- GQA: native — each query head reads its KV group's tile; dK/dV are
+- GQA: native — each query head reads its KV group's K/V; dK/dV are
   accumulated per query head and group-reduced outside the kernel;
 - masks/score mods are traceable index-lattice functions (ops/masks.py)
   traced INTO the kernel, which is what makes flex_attention.py a thin
@@ -36,10 +46,12 @@ would not need it, so the mesh tests check the program the chip runs.
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 import os
-from typing import Callable, Optional, Tuple
+import threading
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -273,6 +285,114 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         lse_ref[0, 0, 0] = (m + jnp.log(l_safe)).astype(lse_ref.dtype)
 
 
+# -- resident-KV forward kernel ----------------------------------------------
+def _full_range(mask_type: str, window: int, prefix_len: int,
+                block_q: int, block_kv: int):
+    """``(a, b)``: the chunks ``a(qi) <= j < b(qi)`` of a query tile's KV
+    walk are the ones :func:`_full_tile_fn` calls fully valid: one run for
+    every canonical mask, so the walk is masked edge, unmasked interior,
+    masked edge with no test per chunk. ``None`` for ``a`` means no masked
+    left edge (the run starts where the walk does), for ``b`` no right one.
+    The caller clamps both into the walk's ``[lo, hi)``."""
+    def causal_b(qi):  # (j + 1) * block_kv - 1 <= qi * block_q
+        return (qi * block_q + 1) // block_kv
+
+    def window_a(qi):  # max_row - j * block_kv <= window - 1
+        return pl.cdiv(qi * block_q + block_q - window, block_kv)
+
+    return {
+        "causal": (None, causal_b),
+        "sliding_window": (window_a, causal_b),
+        "band": (window_a, None),
+        "prefix_lm": (None, lambda qi: jnp.maximum(causal_b(qi), prefix_len // block_kv)),
+    }[mask_type]
+
+
+def _lane_tile(x, n: int):
+    """A lane-replicated ``[rows, _LANES]`` statistic as ``[rows, n]``:
+    whole registers side by side, no cross-lane broadcast."""
+    if n == _LANES:
+        return x
+    if n < _LANES:
+        return x[:, :n]
+    if n % _LANES == 0:
+        return jnp.concatenate([x] * (n // _LANES), axis=1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _fwd_resident_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
+                         scale, mask_fn, score_fn, kv_lo, kv_hi, bkv, full_range=None):
+    """One query tile against the whole K/V of its KV head, which the
+    pipeline holds in VMEM: the KV walk is a loop in here over ``[bkv, D]``
+    slices of the refs, from ``kv_lo(qi)`` to ``kv_hi(qi)`` in ascending
+    order, so no grid step is dead and K/V are fetched once a KV head. The
+    running max and denominator stay lane-replicated ``[bq, _LANES]``."""
+    qi = pl.program_id(2)
+    h = pl.program_id(1)
+    bq, D = q_ref.shape[2], q_ref.shape[3]
+    m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+    q = q_ref[0, 0]
+
+    def chunk(j, apply_mask):
+        cols = pl.ds(pl.multiple_of(j * bkv, bkv), bkv)
+        k = k_ref[0, 0, cols, :]
+        v = v_ref[0, 0, cols, :]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if score_fn is not None or apply_mask:
+            row = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
+            col = j * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
+            if score_fn is not None:
+                s = score_fn(s, row, col, h)
+            if apply_mask:
+                s = jnp.where(mask_fn(row, col), s, NEG_INF)
+        m = m_scr[...]                                       # [bq, _LANES]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _lane_tile(m_new, bkv))
+        alpha = jnp.exp(m - m_new)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * _lane_tile(alpha, D) + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+
+    def walk(lo, hi, apply_mask, unroll=1):
+        """Chunks ``lo <= j < hi`` in order (none where ``hi <= lo``). The
+        bounds are traced, so an unroll is by hand: ``unroll`` chunks a
+        trip, which lets the scheduler run one chunk's matmuls against its
+        neighbour's softmax, then the remainder one at a time."""
+        trips = jnp.maximum(hi - lo, 0) // unroll
+
+        def trip(t, carry):
+            for u in range(unroll):
+                chunk(lo + t * unroll + u, apply_mask)
+            return carry
+
+        jax.lax.fori_loop(0, trips, trip, None)
+        if unroll > 1:
+            walk(lo + trips * unroll, hi, apply_mask)
+
+    lo, hi = kv_lo(qi), kv_hi(qi)
+    if mask_fn is None or full_range is None:
+        walk(lo, hi, mask_fn is not None, _RESIDENT_UNROLL)
+    else:
+        a_fn, b_fn = full_range
+        a = lo if a_fn is None else jnp.clip(a_fn(qi), lo, hi)
+        b = hi if b_fn is None else jnp.clip(b_fn(qi), a, hi)
+        if a_fn is not None:
+            walk(lo, a, True)
+        walk(a, b, False, _RESIDENT_UNROLL)
+        if b_fn is not None:
+            walk(b, hi, True)
+
+    l_safe = jnp.maximum(l_scr[...], 1e-30)
+    o_ref[0, 0] = (acc_scr[...] / _lane_tile(l_safe, D)).astype(o_ref.dtype)
+    # lse is laid out [B, H, 1, Sq], as the streamed kernel writes it.
+    lse_ref[0, 0, 0] = (m_scr[:, 0] + jnp.log(l_safe[:, 0])).astype(lse_ref.dtype)
+
+
 # -- backward kernels --------------------------------------------------------
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *,
                    scale, mask_fn, score_fn, kv_lo, kv_hi, nkv, full_tile=None):
@@ -396,22 +516,167 @@ def _check_divisible(Sq, bq, Skv, bkv):
             "(fit_block) or use the reference path")
 
 
+# -- the forward's plan: which kernel, which blocks --------------------------
+class FlashPlan(NamedTuple):
+    path: str       # "resident" | "streamed" | "reference"
+    block_q: int    # query rows a grid step
+    block_kv: int   # KV columns a tile: one a grid step (streamed), one a loop chunk (resident)
+
+
+# Blocks where the caller names none. Streamed: (256, 512), the pre-ledger
+# default, which the backward kernels share. Resident: (512, 512) and two
+# chunks a loop trip, from this sweep of the kernel alone on a TPU v5e (my chip
+# runs, PR 25: scripts/bench_attention.py --forward-only; ms a call, causal,
+# bf16; resident 512x512 and streamed 256x512 give the same bits):
+#
+#   B x S, heads, D            streamed   resident, block_q x block_kv
+#                              256x512    256x512  512x512  512x1024  1024x512
+#   4 x 4,096, 32/8, 128       13.55      5.96     5.63     6.24      6.16
+#   8 x 2,048, 16/16, 128       4.12      1.95     1.79     2.11      2.00
+#   8 x 2,048, 12/4, 64         2.95      1.45     1.34     1.57      1.49
+#   16 x 1,024, 16/16, 128      2.62      1.52     1.36     1.60      1.50
+#   2 x 8,192, 32/8, 128       24.50      9.46     9.05     9.62      9.75
+#   1 x 16,384, 32/8, 128      45.69     16.39    15.88    16.35     16.91
+#   4 x 4,096, window 512       7.61      3.85     3.49     4.37      4.23
+#
+# Also at 4 x 4,096: 256x256 9.34, 128x512 8.07, 256x1024 6.41, 1024x1024
+# 6.20, 256x2048 7.68; at 512x512 one chunk a trip 5.70 (256x512: 6.97
+# against 5.96) and four 5.59; the statistics as loop carries instead of
+# scratch 7.02; exp2 with the scale folded in 5.64 (no gain: not VPU-bound).
+_STREAMED_BLOCKS = (256, 512)
+_RESIDENT_BLOCKS = (512, 512)
+_RESIDENT_UNROLL = 2
+# The resident call raises Mosaic's scoped VMEM limit to this (a v5e core has
+# 128 MiB), and takes the path only where K and V, double-buffered by the
+# pipeline, plus a chunk's float32 scores and probabilities stay under the
+# budget; the rest is for q, o, the statistics and what the compiler spills
+# (0.66 MiB at 256x512 by Mosaic's own count). At heads of 128 in bf16 that
+# admits 16,384 positions (16 MiB; resident 15.9 ms against 45.7 streamed,
+# table above) and not 32,768, whose 32 MiB Mosaic refuses under this limit.
+_RESIDENT_VMEM_LIMIT = 32 * 2**20
+_RESIDENT_VMEM_BUDGET = 24 * 2**20
+
+
+def _plan_blocks(default, Sq, Skv, block_q, block_kv) -> Tuple[int, int]:
+    return (min(block_q, Sq) if block_q else fit_block(default[0], Sq),
+            min(block_kv, Skv) if block_kv else fit_block(default[1], Skv))
+
+
+def flash_plan(Sq: int, Skv: int, D: int, dtype, block_q: Optional[int] = None,
+               block_kv: Optional[int] = None) -> FlashPlan:
+    """Which forward a call of these shapes runs, and its blocks; a pure
+    function of its arguments. ``resident`` holds the whole K and V of a KV
+    head in VMEM and walks them inside the kernel; ``streamed`` fetches one
+    KV tile a grid step, so its context is bounded by HBM and not by VMEM;
+    ``reference`` (no kernel) is for sequences no block divides. A block
+    the caller names is taken as given (capped at the sequence); one left
+    ``None`` is the path's default, fitted to the sequence."""
+    bq, bkv = _plan_blocks(_RESIDENT_BLOCKS, Sq, Skv, block_q, block_kv)
+    if Sq % bq == 0 and Skv % bkv == 0:
+        lanes = -(-D // _LANES) * _LANES  # VMEM pads the head dim to whole registers
+        kv_bytes = 2 * 2 * Skv * lanes * jnp.dtype(dtype).itemsize
+        chunk_bytes = 2 * bq * bkv * 4
+        if kv_bytes + chunk_bytes <= _RESIDENT_VMEM_BUDGET:
+            return FlashPlan("resident", bq, bkv)
+    bq, bkv = _plan_blocks(_STREAMED_BLOCKS, Sq, Skv, block_q, block_kv)
+    if Sq % bq or Skv % bkv:
+        return FlashPlan("reference", bq, bkv)
+    return FlashPlan("streamed", bq, bkv)
+
+
+# The path is chosen while tracing, so this counts traces, not calls of the
+# compiled step: what a jitted program runs is what its one trace counted.
+_plan_counts: Dict[str, int] = collections.Counter()
+_plan_counts_lock = threading.Lock()
+
+
+def _count_plan(path: str) -> None:
+    with _plan_counts_lock:
+        _plan_counts[path] += 1
+
+
+def plan_counts() -> Dict[str, int]:
+    """Forward calls traced so far in this process, by path."""
+    with _plan_counts_lock:
+        return {p: _plan_counts[p] for p in ("resident", "streamed", "reference")}
+
+
 # -- raw kernel entry points (reused by ring attention) ----------------------
 def flash_fwd(q, k, v, *, mask_fn=None, score_fn=None, mask_type="causal",
-              window=512, prefix_len=0, block_q=256, block_kv=512, scale=1.0,
-              canonical_mask=False):
+              window=512, prefix_len=0, block_q=None, block_kv=None, scale=1.0,
+              canonical_mask=False, _path=None):
     """Raw tiled forward on [B, H, S, D] layout. Returns ``(o, lse)`` with
     lse laid out [B, Hq, 1, Sq]. Building block for the custom-vjp wrapper
     and for ring attention's per-chunk calls. ``canonical_mask`` asserts
     that ``mask_fn`` computes exactly the ``mask_type`` predicate, enabling
     the interior-tile fast path (skip in-tile masking where the tile is
-    provably fully valid)."""
+    provably fully valid). :func:`flash_plan` picks the kernel from the
+    shapes; ``_path`` is for tests, which run both on one input."""
+    B, Hq, Sq, D = q.shape
+    _, Hkv, Skv, _ = k.shape
+    plan = flash_plan(Sq, Skv, D, k.dtype, block_q, block_kv)
+    if _path is not None and _path != plan.path:
+        default = _RESIDENT_BLOCKS if _path == "resident" else _STREAMED_BLOCKS
+        plan = FlashPlan(_path, *_plan_blocks(default, Sq, Skv, block_q, block_kv))
+    _check_divisible(Sq, plan.block_q, Skv, plan.block_kv)
+    _count_plan(plan.path)
+    fwd = _flash_fwd_resident if plan.path == "resident" else _flash_fwd_streamed
+    return fwd(q, k, v, plan.block_q, plan.block_kv, mask_fn=mask_fn, score_fn=score_fn,
+               mask_type=mask_type, window=window, prefix_len=prefix_len,
+               scale=scale, canonical_mask=canonical_mask)
+
+
+def _flash_fwd_resident(q, k, v, bq, bkv, *, mask_fn, score_fn, mask_type,
+                        window, prefix_len, scale, canonical_mask):
     B, Hq, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape
     G = Hq // Hkv
-    bq = min(block_q, Sq)
-    bkv = min(block_kv, Skv)
-    _check_divisible(Sq, bq, Skv, bkv)
+    kv_lo, kv_hi = _kv_range(mask_type, window, prefix_len, bq, bkv, Skv // bkv)
+    full_range = (_full_range(mask_type, window, prefix_len, bq, bkv)
+                  if canonical_mask and mask_type != "full" else None)
+    kernel = functools.partial(
+        _fwd_resident_kernel, scale=scale, mask_fn=mask_fn, score_fn=score_fn,
+        kv_lo=kv_lo, kv_hi=kv_hi, bkv=bkv, full_range=full_range)
+
+    def kv_index(b, h, i):
+        # Not a function of the query tile, and the same for the G heads of
+        # a group: the pipeline fetches K and V once a (sequence, KV head).
+        return (b, h // G, 0, 0)
+
+    return pl.pallas_call(
+        kernel,
+        grid=(B, Hq, Sq // bq),
+        in_specs=[
+            _vmem_spec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
+            _vmem_spec((1, 1, Skv, D), kv_index),
+            _vmem_spec((1, 1, Skv, D), kv_index),
+        ],
+        out_specs=[
+            _vmem_spec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
+            _vmem_spec((1, 1, 1, bq), lambda b, h, i: (b, h, 0, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, Hq, 1, Sq), jnp.float32),
+        ],
+        scratch_shapes=[
+            _scratch((bq, _LANES)),      # running max
+            _scratch((bq, _LANES)),      # running denominator
+            _scratch((bq, D)),           # fp32 output accumulator
+        ],
+        compiler_params=None if _interpret() else pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3,
+            vmem_limit_bytes=_RESIDENT_VMEM_LIMIT),
+        interpret=_interpret(),
+        name="flash_fwd",  # one name for both paths: the trace's reader keys on it
+    )(q, k, v)
+
+
+def _flash_fwd_streamed(q, k, v, bq, bkv, *, mask_fn, score_fn, mask_type,
+                        window, prefix_len, scale, canonical_mask):
+    B, Hq, Sq, D = q.shape
+    _, Hkv, Skv, _ = k.shape
+    G = Hq // Hkv
     nq = Sq // bq
     nkv = Skv // bkv
     kv_lo, kv_hi = _kv_range(mask_type, window, prefix_len, bq, bkv, nkv)
@@ -564,15 +829,20 @@ def flash_bwd_dkv(q, k, v, g, lse, delta, *, mask_fn=None, score_fn=None,
 def _attention_core(
     mask_fn, score_fn, mask_type: str, window: int, prefix_len: int,
     block_q: int, block_kv: int, scale: float, canonical_mask: bool = False,
+    fwd_blocks: Tuple[Optional[int], Optional[int]] = (None, None),
 ):
     """Build the custom-vjp flash attention for a fixed mask/score program.
 
     Inputs (to the returned fn): q [B, Hq, Sq, D], k/v [B, Hkv, Skv, D].
     Output: o [B, Hq, Sq, D]. ``scale`` is baked in (nondiff).
+    ``block_q``/``block_kv`` tile the backward kernels; ``fwd_blocks`` is
+    what the caller asked of the forward, ``None`` leaving it to
+    :func:`flash_plan`.
     """
     kw = dict(mask_fn=mask_fn, score_fn=score_fn, mask_type=mask_type,
               window=window, prefix_len=prefix_len, block_q=block_q,
               block_kv=block_kv, scale=scale, canonical_mask=canonical_mask)
+    fwd_kw = dict(kw, block_q=fwd_blocks[0], block_kv=fwd_blocks[1])
 
     @jax.custom_vjp
     def attn(q, k, v):
@@ -580,7 +850,7 @@ def _attention_core(
         return o
 
     def _fwd(q, k, v):
-        o, lse = flash_fwd(q, k, v, **kw)
+        o, lse = flash_fwd(q, k, v, **fwd_kw)
         return o, (q, k, v, o, lse)
 
     def _bwd(res, g):
@@ -606,9 +876,9 @@ def _attention_core(
 
 @functools.lru_cache(maxsize=64)
 def _cached_core(mask_fn, score_fn, mask_type, window, prefix_len, block_q,
-                 block_kv, scale, canonical_mask=False):
+                 block_kv, scale, canonical_mask=False, fwd_blocks=(None, None)):
     return _attention_core(mask_fn, score_fn, mask_type, window, prefix_len,
-                           block_q, block_kv, scale, canonical_mask)
+                           block_q, block_kv, scale, canonical_mask, fwd_blocks)
 
 
 def _mesh_partition(batch: int, q_heads: int, kv_heads: int, shard_heads: bool):
@@ -640,11 +910,10 @@ def _mesh_partition(batch: int, q_heads: int, kv_heads: int, shard_heads: bool):
     return mesh, P(data or None, None, tp, None), set(free)
 
 
-# Defaults from an on-chip sweep (scripts/bench_attention.py) on TPU v5e:
-# (256, 512) is within noise of the best (block_q, block_kv) across
-# seq 1024-8192 for D in {64, 128}; override per-call or via env.
-_DEF_BLOCK_Q = int(os.environ.get("FLASH_BLOCK_Q", 256))
-_DEF_BLOCK_KV = int(os.environ.get("FLASH_BLOCK_KV", 512))
+# No block named: each path's own default (``flash_plan``). The environment
+# pair names one for every call of the process, as an argument does for one.
+_DEF_BLOCK_Q = int(os.environ.get("FLASH_BLOCK_Q", 0)) or None
+_DEF_BLOCK_KV = int(os.environ.get("FLASH_BLOCK_KV", 0)) or None
 
 
 def flash_attention(
@@ -655,8 +924,8 @@ def flash_attention(
     window_size: int = 512,
     prefix_len: int = 0,
     scale: Optional[float] = None,
-    block_q: int = _DEF_BLOCK_Q,
-    block_kv: int = _DEF_BLOCK_KV,
+    block_q: Optional[int] = _DEF_BLOCK_Q,
+    block_kv: Optional[int] = _DEF_BLOCK_KV,
     mask_fn: Optional[Callable] = None,
     score_fn: Optional[Callable] = None,
     precision: Optional[str] = None,
@@ -697,8 +966,12 @@ def flash_attention(
             local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             axis_names=manual_axes, check_vma=False)(q, k, v)
 
-    block_q = fit_block(block_q, Sq)
-    block_kv = fit_block(block_kv, Skv)
+    # The forward's blocks are the plan's; the backward kernels keep the
+    # streamed tiling.
+    fwd_blocks = (block_q and fit_block(block_q, Sq), block_kv and fit_block(block_kv, Skv))
+    fwd_path = flash_plan(Sq, Skv, D, k.dtype, *fwd_blocks).path
+    block_q = fit_block(block_q or _STREAMED_BLOCKS[0], Sq)
+    block_kv = fit_block(block_kv or _STREAMED_BLOCKS[1], Skv)
 
     from . import masks as M
 
@@ -721,12 +994,12 @@ def flash_attention(
             "full": None,
         }[mask_type]
 
-    bq = min(block_q, Sq)
-    bkv = min(block_kv, Skv)
-    if Sq % bq or Skv % bkv or Hq % Hkv:
+    if fwd_path == "reference" or Sq % block_q or Skv % block_kv or Hq % Hkv:
         # Odd sizes: reference path with the SAME mask and score program
         # (kernel-style score_fn adapted to the [B, Hkv, G, Sq, Skv] layout).
         from .attention import reference_attention
+
+        _count_plan("reference")
 
         ref_score = None
         if score_fn is not None:
@@ -741,7 +1014,7 @@ def flash_attention(
         return reference_attention(q, k, v, mask_mod=mask_fn, score_mod=ref_score, scale=scale)
 
     core = _cached_core(mask_fn, score_fn, mask_type, window_size, prefix_len,
-                        block_q, block_kv, float(scale), canonical)
+                        block_q, block_kv, float(scale), canonical, fwd_blocks)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)

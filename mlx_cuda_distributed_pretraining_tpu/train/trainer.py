@@ -45,6 +45,7 @@ from ..obs.events import (
     write_heartbeat,
 )
 from ..obs import compiles
+from ..ops.flash_attention import plan_counts as flash_plan_counts
 from ..obs.flops import (GoodputLedger, matmul_params, model_flops_per_token,
                          peak_flops_per_chip)
 from ..obs.flops import mfu as compute_mfu
@@ -491,6 +492,11 @@ class Trainer:
         # obs/compiles.py totals at the last window's close: the difference
         # rides each step_window event as xla_compiles / xla_compile_s.
         self._compiles_seen = compiles.totals()
+        # Which forward kernel the step's flash calls were traced to
+        # (ops/flash_attention.py flash_plan): the tally since here, logged
+        # after the first compile and carried by the first step_window event.
+        self._flash_plan_seen = flash_plan_counts()
+        self._flash_plan: Optional[Dict[str, int]] = None
         self._metrics_server = None
         # events.jsonl is the durable telemetry source: replay it FIRST so
         # counters survive crash-restarts, then open for append. Chief only
@@ -671,6 +677,10 @@ class Trainer:
         self.goodput.add("compile_s", seconds)
         if self.events is not None:
             self.events.append("compile", seconds=round(seconds, 4), step=step)
+        self._flash_plan = {path: n - self._flash_plan_seen[path]
+                            for path, n in flash_plan_counts().items()}
+        self.logger.log("flash forward plan (calls traced): " + ", ".join(
+            f"{path}={n}" for path, n in self._flash_plan.items()))
 
     def _touch_heartbeat(self, step: Optional[int] = None) -> None:
         if self._hb_path is None:
@@ -1433,6 +1443,9 @@ class Trainer:
                             ev["xla_compile_s"] = round(
                                 seen[1] - self._compiles_seen[1], 4)
                             self._compiles_seen = seen
+                            if self._flash_plan is not None:
+                                ev["flash_plan"] = self._flash_plan
+                                self._flash_plan = None
                             if self.pipeline:
                                 ev["bubble"] = round(self._bubble_frac, 6)
                             # Latest graftprof fractions ride every window

@@ -1,16 +1,28 @@
 """Attention kernel microbenchmark on the real TPU chip.
 
-Compares the Pallas flash kernel (fwd+bwd) against XLA's fused attention
-(reference_attention: einsum + softmax, fully materialized scores) across
-sequence lengths, and sweeps (block_q, block_kv). The VERDICT r1 done-bar:
-flash >= XLA at seq 2048/4096/8192 and seq 16k running without OOM.
+Three modes:
+
+- default: the Pallas flash kernel (fwd+bwd) against XLA's fused attention
+  (reference_attention: einsum + softmax, fully materialized scores) across
+  sequence lengths. The VERDICT r1 done-bar: flash >= XLA at seq
+  2048/4096/8192 and seq 16k running without OOM.
+- ``--sweep``: (block_q, block_kv) over three shapes, through
+  ``flash_attention`` (fwd+bwd, transposes included).
+- ``--forward-only``: the raw ``flash_fwd`` kernel alone on [B, H, S, D],
+  one row per (path, block_q x block_kv) of ``--paths`` x ``--blocks`` at
+  ``--batch`` x ``--seq``, with ``share_of_peak`` by the benchmark's count
+  (``benchmark/flops/flash_attention.py``: 2 B Hq S^2 D a causal call, twice
+  that under ``--mask-type full``) over ``benchmark/peaks.py``. This is the
+  row a trace's ``flash_fwd`` self time compares with: the cell
+  ``mistral-7b-v0_3-l4.train-1chip`` is
+  ``--heads 32 --kv-heads 8 --head-dim 128 --batch 4 --seq 4096``.
 
 Methodology: each measurement jits an on-device ``lax.fori_loop`` that
 chains N attention calls (output feeds the next query, so nothing is
 DCE'd), syncs via a 1-element ``device_get``, and reports
 (T(n_hi) - T(n_lo)) / (n_hi - n_lo) to cancel the fixed per-call overhead.
 
-Usage (on TPU):  python scripts/bench_attention.py [--sweep]
+Usage (on TPU):  python scripts/bench_attention.py [--sweep | --forward-only]
 Writes results to stdout as JSON lines.
 """
 
@@ -55,27 +67,81 @@ def attn_flops(B, H, Sq, Skv, D, causal=True):
     return f / 2 if causal else f
 
 
+def forward_only(a, dtype):
+    """Rows of the raw forward kernel; ``path`` "auto" leaves the choice to
+    ``flash_plan`` and any other value forces it."""
+    from benchmark import peaks
+    from mlx_cuda_distributed_pretraining_tpu.ops import masks as M
+    from mlx_cuda_distributed_pretraining_tpu.ops.flash_attention import flash_fwd
+
+    B, S, Hq, Hkv, D = a.batch, a.seq, a.heads, a.kv_heads, a.head_dim
+    peak = peaks.peak(jax.devices()[0].device_kind)["bf16_flops"]
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (B, Hq, S, D), dtype)
+    k = jax.random.normal(ks[1], (B, Hkv, S, D), dtype)
+    v = jax.random.normal(ks[2], (B, Hkv, S, D), dtype)
+    causal = a.mask_type == "causal"
+    mask_kw = dict(mask_type=a.mask_type, mask_fn=M.causal() if causal else None,
+                   canonical_mask=causal, scale=D ** -0.5)
+    fl = attn_flops(B, Hq, S, S, D, causal=causal)
+    for path in a.paths.split(","):
+        for blocks in a.blocks.split(","):
+            # "auto": the path's own default blocks
+            bq, bkv = (None, None) if blocks == "auto" else (int(x) for x in blocks.split("x"))
+            kw = dict(mask_kw, block_q=bq, block_kv=bkv)
+            if path != "auto":
+                kw["_path"] = path
+            row = {"name": "flash_fwd", "mask_type": a.mask_type, "path": path,
+                   "block_q": bq, "block_kv": bkv, "B": B, "Hq": Hq, "Hkv": Hkv,
+                   "S": S, "D": D}
+            try:
+                t = timed_loop(lambda qq, kk, vv: flash_fwd(qq, kk, vv, **kw)[0],
+                               q, k, v, n_hi=45)
+                row.update(fwd_ms=round(t * 1e3, 3),
+                           share_of_peak=round(fl / t / peak, 4))
+            except Exception as e:  # noqa: BLE001 - a block the compiler refuses is a row too
+                row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+            print(json.dumps(row), flush=True)
+
+
 def main():
     parser = argparse.ArgumentParser()
     parser.add_argument("--sweep", action="store_true", help="sweep block sizes")
+    parser.add_argument("--forward-only", action="store_true",
+                        help="raw flash_fwd rows at --batch x --seq")
     parser.add_argument("--dtype", default="bfloat16")
     parser.add_argument("--head-dim", type=int, default=64)
     parser.add_argument("--heads", type=int, default=16)
+    parser.add_argument("--kv-heads", type=int, default=None,
+                        help="KV heads (default: --heads)")
+    parser.add_argument("--batch", type=int, default=4)
+    parser.add_argument("--seq", type=int, default=4096)
+    parser.add_argument("--mask-type", default="causal", choices=("causal", "full"))
+    parser.add_argument("--blocks", default="auto",
+                        help="comma list of auto | block_q x block_kv for --forward-only")
+    parser.add_argument("--paths", default="auto",
+                        help="comma list of auto|resident|streamed for --forward-only")
     a = parser.parse_args()
+    a.kv_heads = a.kv_heads or a.heads
 
     from mlx_cuda_distributed_pretraining_tpu.ops import masks as M
     from mlx_cuda_distributed_pretraining_tpu.ops.attention import reference_attention
     from mlx_cuda_distributed_pretraining_tpu.ops.flash_attention import flash_attention
 
     dtype = jnp.dtype(a.dtype)
-    H, D = a.heads, a.head_dim
+    H, Hkv, D = a.heads, a.kv_heads, a.head_dim
     dev = jax.devices()[0]
-    print(json.dumps({"device": str(dev), "dtype": str(dtype), "H": H, "D": D}))
+    print(json.dumps({"device": str(dev), "device_kind": dev.device_kind,
+                      "dtype": str(dtype), "H": H, "Hkv": Hkv, "D": D}))
+
+    if a.forward_only:
+        forward_only(a, dtype)
+        return
 
     def make_inputs(B, S, key=0):
         ks = jax.random.split(jax.random.PRNGKey(key), 3)
-        shape = (B, S, H, D)
-        return tuple(jax.random.normal(k, shape, dtype) for k in ks)
+        return tuple(jax.random.normal(k, (B, S, h, D), dtype)
+                     for k, h in zip(ks, (H, Hkv, Hkv)))
 
     def run_case(name, fn, q, k, v):
         B, S = q.shape[0], q.shape[1]
@@ -99,10 +165,6 @@ def main():
         }
 
     if a.sweep:
-        # Large bkv included deliberately: KV for one head at seq 2048 is
-        # only 512 KB bf16 — VMEM-resident KV (bkv == S) collapses the
-        # streamed inner grid dim entirely, trading in-tile causal masking
-        # work for ~8x fewer grid steps and no KV re-reads.
         for B, S in [(16, 2048), (8, 4096), (4, 8192)]:
             q, k, v = make_inputs(B, S)
             for bq in (128, 256, 512, 1024):

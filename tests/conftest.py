@@ -6,8 +6,11 @@ correctness is validated on a virtual CPU mesh
 ``chip_smoke.py`` is what runs on one.
 """
 
+import functools
 import os
 import sys
+
+import pytest
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -65,8 +68,6 @@ def spawn_with_devices(argv, n, **popen_kw):
 # parallel runner never splits them across simultaneously-busy workers.
 
 def pytest_collection_modifyitems(config, items):
-    import pytest
-
     serial = [it for it in items if it.get_closest_marker("serial")]
     if not serial:
         return
@@ -74,3 +75,27 @@ def pytest_collection_modifyitems(config, items):
     for it in serial:
         it.add_marker(pytest.mark.xdist_group("serial"))
     items[:] = rest + serial
+
+
+# --- both flash forward kernels on one test ---------------------------------
+# ops/flash_attention.py picks its forward (K/V resident in VMEM, or streamed
+# through the grid) from the call's shapes, and at test sizes that is always
+# the resident one. A test that takes ``flash_path`` runs once under each:
+# every flash_fwd call inside it (the custom-vjp wrapper's, ring attention's
+# raw ones) is forced through the internal ``_path`` argument, and the tally
+# has to show that the named kernel, and not the other, was traced.
+
+@pytest.fixture(params=("resident", "streamed"))
+def flash_path(request, monkeypatch):
+    from mlx_cuda_distributed_pretraining_tpu.ops import flash_attention as fa
+
+    path = request.param
+    other = "streamed" if path == "resident" else "resident"
+    monkeypatch.setattr(fa, "flash_fwd", functools.partial(fa.flash_fwd, _path=path))
+    fa._cached_core.cache_clear()  # a core traced under the other path
+    before = fa.plan_counts()
+    yield path
+    fa._cached_core.cache_clear()
+    after = fa.plan_counts()
+    assert after[path] > before[path], f"no {path} forward was traced"
+    assert after[other] == before[other], f"a {other} forward was traced"

@@ -2,7 +2,8 @@
 item a): per mask type, forward and gradients, GQA/MQA, fp32.
 
 Runs the real kernel code in Pallas interpret mode on CPU; the identical
-code compiles to Mosaic on TPU.
+code compiles to Mosaic on TPU. A test that takes ``flash_path``
+(conftest.py) runs under both forward kernels, resident and streamed.
 """
 
 import jax
@@ -12,6 +13,7 @@ import pytest
 
 from mlx_cuda_distributed_pretraining_tpu.ops import masks as M
 from mlx_cuda_distributed_pretraining_tpu.ops.attention import reference_attention
+from mlx_cuda_distributed_pretraining_tpu.ops import flash_attention as fa
 from mlx_cuda_distributed_pretraining_tpu.ops.flash_attention import flash_attention
 from mlx_cuda_distributed_pretraining_tpu.ops.flex_attention import (
     alibi_score_fn,
@@ -40,7 +42,7 @@ MASKS = {
 
 
 @pytest.mark.parametrize("mask_type", list(MASKS))
-def test_forward_parity(mask_type):
+def test_forward_parity(mask_type, flash_path):
     q, k, v = _qkv()
     out = flash_attention(q, k, v, mask_type=mask_type, window_size=96,
                           prefix_len=80, block_q=BLOCK, block_kv=BLOCK)
@@ -48,21 +50,21 @@ def test_forward_parity(mask_type):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("hq,hkv", [(4, 2), (4, 1)])
-def test_forward_parity_gqa_mqa(hq, hkv):
+@pytest.mark.parametrize("hq,hkv", [(4, 2), (8, 2), (4, 1)])  # GQA 2:1, 4:1, MQA
+def test_forward_parity_gqa_mqa(hq, hkv, flash_path):
     q, k, v = _qkv(hq, hkv)
     out = flash_attention(q, k, v, block_q=BLOCK, block_kv=BLOCK)
     ref = reference_attention(q, k, v, mask_mod=M.causal())
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("mask_type", ["causal", "sliding_window", "full"])
-def test_gradient_parity(mask_type):
+@pytest.mark.parametrize("mask_type", ["causal", "sliding_window", "prefix_lm", "full"])
+def test_gradient_parity(mask_type, flash_path):
     q, k, v = _qkv()
 
     def loss_flash(q, k, v):
         o = flash_attention(q, k, v, mask_type=mask_type, window_size=96,
-                            block_q=BLOCK, block_kv=BLOCK)
+                            prefix_len=80, block_q=BLOCK, block_kv=BLOCK)
         return jnp.sum(o * jnp.cos(o))  # nontrivial cotangent
 
     def loss_ref(q, k, v):
@@ -76,7 +78,7 @@ def test_gradient_parity(mask_type):
                                    err_msg=f"d{name} mismatch for {mask_type}")
 
 
-def test_gradient_parity_gqa():
+def test_gradient_parity_gqa(flash_path):
     q, k, v = _qkv(4, 2)
 
     def loss(fn):
@@ -93,7 +95,7 @@ def test_gradient_parity_gqa():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4, rtol=5e-3)
 
 
-def test_flex_alibi_parity():
+def test_flex_alibi_parity(flash_path):
     q, k, v = _qkv()
     out = flex_attention(q, k, v, mask_mod=M.causal(), score_mod=alibi_score_fn(4),
                          block_q=BLOCK, block_kv=BLOCK)
@@ -116,7 +118,7 @@ def _soft_cap_ref(q, k, v, cap=5.0):
     return reference_attention(q, k, v, mask_mod=M.causal(), score_mod=ref_score)
 
 
-def test_flex_soft_cap_forward_parity():
+def test_flex_soft_cap_forward_parity(flash_path):
     q, k, v = _qkv()
     capped = flex_attention(q, k, v, mask_mod=M.causal(), score_mod=soft_cap_score_fn(5.0),
                             block_q=BLOCK, block_kv=BLOCK)
@@ -126,7 +128,7 @@ def test_flex_soft_cap_forward_parity():
     assert not np.allclose(np.asarray(capped), np.asarray(plain))
 
 
-def test_flex_soft_cap_gradient_parity():
+def test_flex_soft_cap_gradient_parity(flash_path):
     """Non-additive score mod: backward must chain through the tanh
     Jacobian (regression for the missing sech^2 factor)."""
     q, k, v = _qkv()
@@ -177,7 +179,7 @@ def test_fallback_preserves_mask_and_score():
     np.testing.assert_allclose(np.asarray(out_a), np.asarray(ref_a), atol=2e-5, rtol=2e-5)
 
 
-def test_flex_custom_mask_exact():
+def test_flex_custom_mask_exact(flash_path):
     """An arbitrary untagged mask mod (causal AND not-multiple-of-7 col) is
     applied exactly, not block-sampled."""
 
@@ -190,7 +192,7 @@ def test_flex_custom_mask_exact():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
-def test_bf16_inputs():
+def test_bf16_inputs(flash_path):
     q, k, v = _qkv()
     qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
     out = flash_attention(qb, kb, vb, block_q=BLOCK, block_kv=BLOCK)
@@ -207,7 +209,7 @@ def test_odd_sizes_fallback():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
-def test_model_level_flash_matches_simple():
+def test_model_level_flash_matches_simple(flash_path):
     from mlx_cuda_distributed_pretraining_tpu.models import llama
     from mlx_cuda_distributed_pretraining_tpu.models.llama import LlamaArgs
 
@@ -222,7 +224,7 @@ def test_model_level_flash_matches_simple():
     np.testing.assert_allclose(np.asarray(l_simple), np.asarray(l_flash), atol=1e-3, rtol=1e-3)
 
 
-def test_interior_tile_fast_path_matches():
+def test_interior_tile_fast_path_matches(flash_path):
     """canonical_mask=True (interior tiles skip in-tile masking) produces
     identical outputs to the always-masked path for every canonical mask."""
     import jax
@@ -253,7 +255,7 @@ def test_interior_tile_fast_path_matches():
                                    err_msg=mask_type)
 
 
-def test_band_mask_multiblock_matches_reference():
+def test_band_mask_multiblock_matches_reference(flash_path):
     """Band masks (sliding-window ring chunks) with negative/partial edges
     across MULTIPLE kv blocks — exercises empty tile ranges whose index
     maps must stay in [0, n_blocks-1] (OOB DMA regression guard)."""
@@ -291,7 +293,7 @@ def test_band_mask_multiblock_matches_reference():
             assert np.all(np.asarray(lse)[:, :, 0][:, :, ~valid] < -1e29)
 
 
-def test_flash_under_mesh_matches_unsharded():
+def test_flash_under_mesh_matches_unsharded(flash_path):
     """Under a mesh the kernel runs inside a shard_map over batch (dp) and
     heads (tp) — GSPMD cannot partition a Mosaic kernel on the chip — and a
     GQA group never straddles two head shards. Same values, same grads."""
@@ -322,3 +324,124 @@ def test_flash_under_mesh_matches_unsharded():
     np.testing.assert_allclose(got_o, want_o, atol=1e-5)
     for got, want in zip(got_g, want_g):
         np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+# -- the two forward kernels, and the plan that picks one --------------------
+def _raw_qkv(hq=2, hkv=2, sq=512, skv=512, d=32, dtype=jnp.float32, seed=3):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (1, hq, sq, d), dtype),
+            jax.random.normal(ks[1], (1, hkv, skv, d), dtype),
+            jax.random.normal(ks[2], (1, hkv, skv, d), dtype))
+
+
+RAW_CASES = {
+    "causal": dict(mask_type="causal", mask_fn=M.causal()),
+    "sliding_window": dict(mask_type="sliding_window", window=96,
+                           mask_fn=M.sliding_window(96)),
+    "prefix_lm": dict(mask_type="prefix_lm", prefix_len=130, mask_fn=M.prefix_lm(130)),
+    "full": dict(mask_type="full", mask_fn=None),
+    "band_partial": dict(mask_type="band", window=64, mask_fn=M.band(64)),
+    # every query tile but the last has lo > hi: an empty KV walk
+    "band_empty_ranges": dict(mask_type="band", window=-384, mask_fn=M.band(-384)),
+    "custom_mask": dict(mask_type="full", canonical_mask=False,
+                        mask_fn=lambda r, c: (r >= c) & ((c % 7) != 0)),
+    "gqa_4to1": dict(mask_type="causal", mask_fn=M.causal(), hq=8, hkv=2),
+    "short_q": dict(mask_type="full", mask_fn=None, sq=128),
+    "short_kv_causal": dict(mask_type="causal", mask_fn=M.causal(), skv=256),
+    "bf16": dict(mask_type="causal", mask_fn=M.causal(), dtype=jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (64, 128), (256, 64)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("name", sorted(RAW_CASES))
+def test_resident_matches_streamed(name, blocks):
+    """One input through both forward kernels: the same chunks in the same
+    order with the same arithmetic, so o and lse agree to float32 round-off
+    (an empty walk leaves o zero and lse at its floor in both)."""
+    case = dict(RAW_CASES[name])
+    shape = {key: case.pop(key) for key in ("hq", "hkv", "sq", "skv", "dtype") if key in case}
+    case.setdefault("canonical_mask", True)
+    q, k, v = _raw_qkv(**shape)
+    out = {path: fa.flash_fwd(q, k, v, block_q=blocks[0], block_kv=blocks[1],
+                              scale=32 ** -0.5, _path=path, **case)
+           for path in ("resident", "streamed")}
+    tol = 1e-2 if q.dtype == jnp.bfloat16 else 1e-6
+    np.testing.assert_allclose(np.asarray(out["resident"][0], np.float32),
+                               np.asarray(out["streamed"][0], np.float32), atol=tol)
+    np.testing.assert_allclose(np.asarray(out["resident"][1]),
+                               np.asarray(out["streamed"][1]), rtol=1e-6, atol=1e-5)
+    if name == "band_empty_ranges":
+        dead = np.arange(512) >= 256  # query tiles whose whole walk is empty
+        assert np.all(np.asarray(out["resident"][0])[:, :, dead] == 0)
+        assert np.all(np.asarray(out["resident"][1])[:, :, 0][:, :, dead] < -1e29)
+
+
+@pytest.mark.parametrize("mask_type,sq,skv", [("full", 128, 512), ("causal", 512, 256)])
+def test_unequal_lengths_match_reference(mask_type, sq, skv, flash_path):
+    """Sq != Skv, as a ring-attention chunk or a cross-attention call has it."""
+    q, k, v = _raw_qkv(sq=sq, skv=skv)
+    mask = M.causal() if mask_type == "causal" else None
+    o, _ = fa.flash_fwd(q, k, v, mask_type=mask_type, mask_fn=mask, canonical_mask=True,
+                        block_q=64, block_kv=128, scale=32 ** -0.5)
+    ref = reference_attention(*(t.transpose(0, 2, 1, 3) for t in (q, k, v)),
+                              mask_mod=mask).transpose(0, 2, 1, 3)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(ref), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("mask_type,window,prefix", [
+    ("causal", 0, 0), ("sliding_window", 96, 0), ("sliding_window", 300, 0),
+    ("sliding_window", 1, 0), ("prefix_lm", 0, 130), ("prefix_lm", 0, 512),
+    ("band", 64, 0), ("band", -384, 0), ("band", 700, 0)])
+@pytest.mark.parametrize("bq,bkv", [(128, 128), (64, 256), (256, 64)])
+def test_full_range_is_the_run_of_full_tiles(mask_type, window, prefix, bq, bkv):
+    """The resident walk's unmasked run [a, b), clamped into [lo, hi) as the
+    kernel clamps it, holds exactly the tiles _full_tile_fn calls full."""
+    S = 1024
+    nq, nkv = S // bq, S // bkv
+    kv_lo, kv_hi = fa._kv_range(mask_type, window, prefix, bq, bkv, nkv)
+    full = fa._full_tile_fn(mask_type, window, prefix, bq, bkv)
+    a_fn, b_fn = fa._full_range(mask_type, window, prefix, bq, bkv)
+    for qi in range(nq):
+        lo, hi = int(kv_lo(qi)), int(kv_hi(qi))
+        a = lo if a_fn is None else int(jnp.clip(a_fn(qi), lo, hi))
+        b = hi if b_fn is None else int(jnp.clip(b_fn(qi), a, hi))
+        for j in range(lo, hi):
+            assert bool(full(qi, j)) == (a <= j < b), (qi, j, lo, a, b, hi)
+
+
+def test_flash_plan_picks_by_shape():
+    """The path is a function of the shapes alone: K and V of a KV head in
+    VMEM where they fit the budget, the streamed kernel beyond it, no kernel
+    where no block divides; and every traced call is tallied by path."""
+    # mistral-7b-v0_3-l4.train-1chip: 4 x 4,096, heads of 128, bf16
+    cell = fa.flash_plan(4096, 4096, 128, jnp.bfloat16)
+    assert cell.path == "resident"
+    assert (cell.block_q, cell.block_kv) == fa._RESIDENT_BLOCKS
+    long = fa.flash_plan(32768, 32768, 128, jnp.bfloat16)
+    assert (long.path, long.block_q, long.block_kv) == ("streamed", *fa._STREAMED_BLOCKS)
+    # the budget counts bytes: float32 K/V fill it at half the length
+    edge = max(s for s in (2 ** n for n in range(10, 17))
+               if fa.flash_plan(s, s, 128, jnp.bfloat16).path == "resident")
+    assert fa.flash_plan(edge, edge, 128, jnp.float32).path == "streamed"
+    assert fa.flash_plan(edge // 2, edge // 2, 128, jnp.float32).path == "resident"
+    # a narrow head is padded to whole registers in VMEM
+    assert fa.flash_plan(edge, edge, 64, jnp.bfloat16).path == "resident"
+    assert fa.flash_plan(2 * edge, 2 * edge, 64, jnp.bfloat16).path == "streamed"
+    assert fa.flash_plan(192, 192, 32, jnp.float32).path == "reference"
+    assert fa.flash_plan(4096, 192, 128, jnp.bfloat16).path == "reference"
+    assert fa.flash_plan(100, 100, 32, jnp.float32, 64, 64).path == "reference"
+    # a block the caller names is taken, one left out is the path's default
+    assert fa.flash_plan(4096, 4096, 128, jnp.bfloat16, 128, None)[1:] == (
+        128, fa._RESIDENT_BLOCKS[1])
+
+    before = fa.plan_counts()
+    q, k, v = _qkv()
+    flash_attention(q, k, v, block_q=BLOCK, block_kv=BLOCK)            # resident
+    flash_attention(*_qkv(s=100), block_q=BLOCK, block_kv=BLOCK)       # odd: reference
+    rq, rk, rv = _raw_qkv()
+    fa.flash_fwd(rq, rk, rv, mask_fn=M.causal(), block_q=128, block_kv=128,
+                 _path="streamed")
+    after = fa.plan_counts()
+    assert {p: after[p] - before[p] for p in after} == {
+        "resident": 1, "streamed": 1, "reference": 1}

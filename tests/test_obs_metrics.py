@@ -456,6 +456,27 @@ def test_trainer_telemetry_end_to_end(tmp_path):
             tr._metrics_server.shutdown()
 
 
+def test_trainer_reports_the_flash_forward_plan(tmp_path):
+    """Which forward kernel the step's flash calls were traced to
+    (ops/flash_attention.py flash_plan) is written once: a log line after the
+    first compile, and ``flash_plan`` on the first step_window event alone."""
+    from mlx_cuda_distributed_pretraining_tpu.train.trainer import Trainer
+
+    cfg = _tiny_config(tmp_path, name="flashplan", iters=10,
+                       **{"model.attention.attention_type": "flash"})
+    tr = Trainer(cfg, runs_root=str(tmp_path / "runs"), quiet=True)
+    tr.train()
+    windows = [e for e in iter_events(events_path(tr.run_dir))
+               if e["type"] == "step_window"]
+    assert len(windows) == 2
+    plan = windows[0]["flash_plan"]
+    assert plan["resident"] > 0 and plan["streamed"] == 0 and plan["reference"] == 0
+    assert "flash_plan" not in windows[1]
+    with open(tr.logger.log_path) as f:
+        lines = [ln for ln in f if "flash forward plan" in ln]
+    assert len(lines) == 1 and f"resident={plan['resident']}" in lines[0]
+
+
 def test_trainer_registry_replays_on_construction(tmp_path):
     """A second Trainer on the same run dir rebuilds its counters from
     events.jsonl — Prometheus totals survive process death."""

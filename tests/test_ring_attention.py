@@ -1,5 +1,7 @@
 """Ring attention (sequence/context parallelism) on the virtual 8-CPU mesh:
-exact parity with single-device attention, gradients included."""
+exact parity with single-device attention, gradients included. A test that
+takes ``flash_path`` (conftest.py) runs its per-chunk flash_fwd calls under
+both forward kernels, resident and streamed."""
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +28,7 @@ def _qkv(hq=4, hkv=4, b=2, s=64, d=16, seed=0):
     )
 
 
-def test_ring_matches_reference_causal():
+def test_ring_matches_reference_causal(flash_path):
     mesh = _mesh({"sp": 8})
     q, k, v = _qkv()
     ring = make_ring_attention(mesh, mask_mod=M.causal())
@@ -35,7 +37,7 @@ def test_ring_matches_reference_causal():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5)
 
 
-def test_ring_gqa_and_dp_axis():
+def test_ring_gqa_and_dp_axis(flash_path):
     mesh = _mesh({"dp": 2, "sp": 4})
     q, k, v = _qkv(hq=4, hkv=2)
     ring = make_ring_attention(mesh, mask_mod=M.causal())
@@ -44,7 +46,7 @@ def test_ring_gqa_and_dp_axis():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5)
 
 
-def test_ring_sliding_window():
+def test_ring_sliding_window(flash_path):
     mesh = _mesh({"sp": 4})
     q, k, v = _qkv(s=64)
     ring = make_ring_attention(mesh, mask_mod=M.sliding_window(24))
@@ -53,7 +55,7 @@ def test_ring_sliding_window():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5, rtol=1e-5)
 
 
-def test_ring_gradients_match():
+def test_ring_gradients_match(flash_path):
     mesh = _mesh({"sp": 4})
     q, k, v = _qkv(s=32)
     ring = make_ring_attention(mesh, mask_mod=M.causal())
@@ -138,6 +140,27 @@ def test_flash_raw_entries_reject_non_divisible():
         flash_fwd(q, q, q, block_q=256, block_kv=256)
 
 
+def test_ring_sliding_window_band_grads_match(flash_path):
+    """Forward and gradients through the tiled sliding-window ring at a
+    window that makes its second hop a ``band`` chunk (Sl=16, window 24:
+    the diagonal, then a band clipped to the corner), under both forward
+    kernels; the slow test below walks the other chunk kinds."""
+    mesh = _mesh({"sp": 4})
+    q, k, v = _qkv(s=64, seed=24)
+    ring = make_ring_attention(mesh, mask_mod=M.sliding_window(24))
+
+    def loss_ring(q, k, v):
+        return (ring(q, k, v) ** 2).sum()
+
+    def loss_ref(q, k, v):
+        return (reference_attention(q, k, v, mask_mod=M.sliding_window(24)) ** 2).sum()
+
+    g_ring = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_ring, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4, rtol=1e-4)
+
+
 @pytest.mark.slow
 def test_ring_sliding_window_tiled_grads_match():
     """The statically-unrolled tiled sliding-window ring (fwd+bwd custom
@@ -163,7 +186,7 @@ def test_ring_sliding_window_tiled_grads_match():
                                        err_msg=f"window={window}")
 
 
-def test_ring_sliding_window_gqa():
+def test_ring_sliding_window_gqa(flash_path):
     mesh = _mesh({"dp": 2, "sp": 4})
     q, k, v = _qkv(hq=4, hkv=2, s=64)
     ring = make_ring_attention(mesh, mask_mod=M.sliding_window(20))

@@ -112,27 +112,61 @@ def test_flash_backward_compiles_for_v5e(name, v5e, compiled_kernels):
     assert hlo.count("tpu_custom_call") >= 3
 
 
-def test_flash_under_fsdp_mesh_compiles_for_v5e(v5e_devices, compiled_kernels):
+# mistral-7b-v0_3-l4.train-1chip, the benchmark's cell: 4 x 4,096, GQA 32/8 of 128
+CELL = dict(B=4, S=4096, Hq=32, Hkv=8, D=128)
+
+
+def _largest_resident_seq(D=128, dtype=jnp.bfloat16):
+    return max(s for s in (2 ** n for n in range(10, 18))
+               if fa.flash_plan(s, s, D, dtype).path == "resident")
+
+
+@pytest.mark.parametrize("name", ["cell", "largest_resident", "first_streamed"])
+def test_flash_forward_paths_compile_for_v5e(name, v5e, compiled_kernels):
+    """The forward ``flash_plan`` picks, at the sizes that decide it: the
+    benchmark cell's shape (K/V resident in VMEM), the longest sequence the
+    resident budget admits (a budget Mosaic's scoped VMEM limit refuses
+    fails here), and twice that, the first one streamed through the grid."""
+    edge = _largest_resident_seq()
+    B, S = {"cell": (CELL["B"], CELL["S"]), "largest_resident": (1, edge),
+            "first_streamed": (1, 2 * edge)}[name]
+    want = "streamed" if name == "first_streamed" else "resident"
+    assert fa.flash_plan(S, S, CELL["D"], jnp.bfloat16).path == want
+    q = _sds((B, S, CELL["Hq"], CELL["D"]), jnp.bfloat16, v5e)
+    kv = _sds((B, S, CELL["Hkv"], CELL["D"]), jnp.bfloat16, v5e)
+    before = fa.plan_counts()
+    hlo = jax.jit(fa.flash_attention).lower(q, kv, kv).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= 1
+    assert fa.plan_counts()[want] == before[want] + 1
+
+
+@pytest.mark.parametrize("shape", ["recipe_1b", "cell"])
+def test_flash_under_fsdp_mesh_compiles_for_v5e(shape, v5e_devices, compiled_kernels):
     """GSPMD cannot partition a Mosaic kernel, so under a mesh the call has
     to go through a shard_map (ops/flash_attention.py _mesh_partition); left
     to the partitioner this lowering raises NotImplementedError, and with it
-    every sharded training config that uses flash attention."""
+    every sharded training config that uses flash attention. Both shapes run
+    the resident forward: each chip holds the K/V of its own rows."""
     from mlx_cuda_distributed_pretraining_tpu.parallel.context import use_mesh
 
     mesh = Mesh(np.array(v5e_devices), ("fsdp",))
     rows = NamedSharding(mesh, P("fsdp"))
-    case = FLASH_CASES["causal_16x128"]
-    qkv = [_sds((8, 2048, h, case["D"]), jnp.bfloat16, rows)
+    case = FLASH_CASES["causal_16x128"] if shape == "recipe_1b" else CELL
+    S = case.get("S", 2048)
+    qkv = [_sds((8, S, h, case["D"]), jnp.bfloat16, rows)
            for h in (case["Hq"], case["Hkv"], case["Hkv"])]
 
     def loss(q, k, v):
         return fa.flash_attention(q, k, v).astype(jnp.float32).sum()
 
+    before = fa.plan_counts()
     with use_mesh(mesh):
         hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*qkv).compile().as_text()
     assert hlo.count("tpu_custom_call") >= 3
     # each chip attends over its own two rows: nothing to exchange
     assert "all-gather" not in hlo and "all-reduce" not in hlo
+    after = fa.plan_counts()
+    assert after["resident"] > before["resident"] and after["streamed"] == before["streamed"]
 
 
 # OLMoE's expert shapes (ROADMAP R1): 64 experts of width 1024 on h2048,
