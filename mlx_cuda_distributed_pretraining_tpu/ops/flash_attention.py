@@ -6,17 +6,23 @@ models/attention/flash_attention.py:100,134-151). This is the real thing:
 
 - forward: online-softmax accumulation, fp32 statistics and accumulator in
   VMEM scratch, MXU matmuls via ``dot_general(..., preferred_element_type=
-  f32)``, in one of two kernels that :func:`flash_plan` picks from the
-  call's shapes while it is traced (:func:`plan_counts` tallies which):
-  **resident** where the K and V of one (sequence, KV head), double-
-  buffered, fit a stated VMEM budget: they enter VMEM once per KV head (the
-  query tiles and the G query heads of a group reuse them) and the kernel
-  walks them itself, a loop over ``[block_kv, D]`` slices from the first
-  live chunk to the last, so no grid step is dead; **streamed** beyond the
-  budget: K/V enter VMEM one ``[block_kv, D]`` tile a grid step via the
-  Pallas pipeline (double-buffered HBM->VMEM DMA), so VMEM never holds the
-  whole sequence and max context is bounded by HBM, not VMEM;
-- block sparsity: per-mask-type KV tile ranges (causal skips the upper
+  f32)``;
+- backward: recomputation-based (saves only O and the logsumexp), split
+  into a dQ kernel (walks K/V, dQ in scratch) and a dK/dV kernel (walks
+  Q/dO, dK/dV in scratch), the flash-attention-2 decomposition;
+- each of the three kernels in one of two forms that :func:`flash_plan`
+  picks from the call's shapes while it is traced (:func:`plan_counts`
+  tallies which): **resident** where the two operands the kernel walks (K
+  and V of one (sequence, KV head); in dK/dV, Q and dO of one query head),
+  double-buffered, fit a stated VMEM budget: they enter VMEM once (the
+  tiles of the other axis, and in the forward and dQ the G query heads of a
+  group, reuse them) and the kernel walks them itself, a loop over
+  ``[block, D]`` slices from the first live chunk to the last, so no grid
+  step is dead; **streamed** beyond the budget: they enter VMEM one tile a
+  grid step via the Pallas pipeline (double-buffered HBM->VMEM DMA), so
+  VMEM never holds the whole sequence and max context is bounded by HBM,
+  not VMEM;
+- block sparsity: per-mask-type tile ranges (causal skips the upper
   triangle, sliding-window skips everything outside the band). The
   resident walk runs from ``lo`` to ``hi`` and nothing else; in the
   streamed grid, skipped tiles are gated with ``pl.when`` AND their index
@@ -24,9 +30,6 @@ models/attention/flash_attention.py:100,134-151). This is the real thing:
   tile it will not use. Tiles a canonical mask leaves whole skip the mask
   program: per tile in the streamed kernels, as one unmasked run between
   masked edges in the resident walk;
-- backward: recomputation-based (saves only O and the logsumexp), split
-  into a dQ kernel (KV streamed, dQ in scratch) and a dK/dV kernel
-  (Q/dO streamed, dK/dV in scratch), the flash-attention-2 decomposition;
 - GQA: native — each query head reads its KV group's K/V; dK/dV are
   accumulated per query head and group-reduced outside the kernel;
 - masks/score mods are traceable index-lattice functions (ops/masks.py)
@@ -320,6 +323,42 @@ def _lane_tile(x, n: int):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
+def _walk(chunk, lo, hi, apply_mask, unroll=1):
+    """``chunk(j, apply_mask)`` for ``lo <= j < hi`` in order (none where
+    ``hi <= lo``). The bounds are traced, so an unroll is by hand: ``unroll``
+    chunks a trip, which lets the scheduler run one chunk's matmuls against
+    its neighbour's elementwise work, then the remainder one at a time."""
+    trips = jnp.maximum(hi - lo, 0) // unroll
+
+    def trip(t, carry):
+        for u in range(unroll):
+            chunk(lo + t * unroll + u, apply_mask)
+        return carry
+
+    jax.lax.fori_loop(0, trips, trip, None)
+    if unroll > 1:
+        _walk(chunk, lo + trips * unroll, hi, apply_mask)
+
+
+def _split_walk(chunk, lo, hi, i, full_range, masked):
+    """The walk of grid step ``i`` over ``[lo, hi)``, for all three resident
+    kernels: where ``full_range`` (:func:`_full_range`) names the run of
+    chunks the mask leaves whole, a masked edge, the unmasked run, a masked
+    edge, with no test per chunk; else every chunk alike. The unmasked run
+    goes ``_RESIDENT_UNROLL`` chunks a trip."""
+    if not masked or full_range is None:
+        _walk(chunk, lo, hi, masked, _RESIDENT_UNROLL)
+        return
+    a_fn, b_fn = full_range
+    a = lo if a_fn is None else jnp.clip(a_fn(i), lo, hi)
+    b = hi if b_fn is None else jnp.clip(b_fn(i), a, hi)
+    if a_fn is not None:
+        _walk(chunk, lo, a, True)
+    _walk(chunk, a, b, False, _RESIDENT_UNROLL)
+    if b_fn is not None:
+        _walk(chunk, b, hi, True)
+
+
 def _fwd_resident_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
                          scale, mask_fn, score_fn, kv_lo, kv_hi, bkv, full_range=None):
     """One query tile against the whole K/V of its KV head, which the
@@ -358,34 +397,7 @@ def _fwd_resident_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
-    def walk(lo, hi, apply_mask, unroll=1):
-        """Chunks ``lo <= j < hi`` in order (none where ``hi <= lo``). The
-        bounds are traced, so an unroll is by hand: ``unroll`` chunks a
-        trip, which lets the scheduler run one chunk's matmuls against its
-        neighbour's softmax, then the remainder one at a time."""
-        trips = jnp.maximum(hi - lo, 0) // unroll
-
-        def trip(t, carry):
-            for u in range(unroll):
-                chunk(lo + t * unroll + u, apply_mask)
-            return carry
-
-        jax.lax.fori_loop(0, trips, trip, None)
-        if unroll > 1:
-            walk(lo + trips * unroll, hi, apply_mask)
-
-    lo, hi = kv_lo(qi), kv_hi(qi)
-    if mask_fn is None or full_range is None:
-        walk(lo, hi, mask_fn is not None, _RESIDENT_UNROLL)
-    else:
-        a_fn, b_fn = full_range
-        a = lo if a_fn is None else jnp.clip(a_fn(qi), lo, hi)
-        b = hi if b_fn is None else jnp.clip(b_fn(qi), a, hi)
-        if a_fn is not None:
-            walk(lo, a, True)
-        walk(a, b, False, _RESIDENT_UNROLL)
-        if b_fn is not None:
-            walk(b, hi, True)
+    _split_walk(chunk, kv_lo(qi), kv_hi(qi), qi, full_range, mask_fn is not None)
 
     l_safe = jnp.maximum(l_scr[...], 1e-30)
     o_ref[0, 0] = (acc_scr[...] / _lane_tile(l_safe, D)).astype(o_ref.dtype)
@@ -498,6 +510,125 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
 
 
+# -- resident backward kernels -------------------------------------------------
+def _full_range_q(mask_type: str, window: int, prefix_len: int,
+                  block_q: int, block_kv: int):
+    """:func:`_full_range` along the other axis: the query chunks
+    ``a(ki) <= j < b(ki)`` of a KV tile's walk that :func:`_full_tile_fn`
+    calls fully valid, again one run for every canonical mask."""
+    def causal_a(ki):  # j * block_q >= ki * block_kv + block_kv - 1
+        return pl.cdiv(ki * block_kv + block_kv - 1, block_q)
+
+    def window_b(ki):  # j * block_q + block_q - 1 - ki * block_kv <= window - 1
+        return (window - block_q + ki * block_kv) // block_q + 1
+
+    def prefix_a(ki):  # a KV tile inside the prefix is whole for every query
+        return jnp.where(ki * block_kv + block_kv - 1 < prefix_len, 0, causal_a(ki))
+
+    return {
+        "causal": (causal_a, None),
+        "sliding_window": (causal_a, window_b),
+        "band": (None, window_b),
+        "prefix_lm": (prefix_a, None),
+    }[mask_type]
+
+
+def _bwd_p_ds(s_raw, dp, lse, delta, row, col, h, *, scale, mask_fn, score_fn, apply_mask):
+    """``(p, ds)`` of one tile from its raw scores and ``dp = dO V^T``, in
+    whichever orientation the caller holds them: ``lse`` and ``delta``
+    broadcast against the tile, ``row``/``col`` are its index lattices."""
+    s = score_fn(s_raw, row, col, h) if score_fn is not None else s_raw
+    if apply_mask:
+        s = jnp.where(mask_fn(row, col), s, NEG_INF)
+    p = jnp.exp(s - lse)
+    ds = p * (dp - delta)
+    d_mod = getattr(score_fn, "_d_score", None) if score_fn is not None else None
+    if d_mod is not None:  # non-additive score mod: chain through its Jacobian
+        ds = ds * d_mod(s_raw, row, col, h)
+    return p, ds * scale
+
+
+def _bwd_dq_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *,
+                            scale, mask_fn, score_fn, kv_lo, kv_hi, bkv, full_range=None):
+    """dQ of one query tile against the whole K/V of its KV head, held in
+    VMEM as :func:`_fwd_resident_kernel` holds them: q, dO and the tile's
+    ``lse`` and ``delta`` are read once, the two statistics re-laid from
+    lanes to sublanes once, and the KV walk is a loop in here."""
+    qi = pl.program_id(2)
+    h = pl.program_id(1)
+    bq = q_ref.shape[2]
+    dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
+    q = q_ref[0, 0]
+    do = do_ref[0, 0]
+    lse = lse_ref[0, 0, 0].astype(jnp.float32)[:, None]       # [bq, 1]
+    delta = delta_ref[0, 0, 0].astype(jnp.float32)[:, None]
+
+    def chunk(j, apply_mask):
+        cols = pl.ds(pl.multiple_of(j * bkv, bkv), bkv)
+        k = k_ref[0, 0, cols, :]
+        v = v_ref[0, 0, cols, :]
+        s_raw = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        row = col = None
+        if score_fn is not None or apply_mask:
+            row = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
+            col = j * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
+        _, ds = _bwd_p_ds(s_raw, dp, lse, delta, row, col, h, scale=scale,
+                          mask_fn=mask_fn, score_fn=score_fn, apply_mask=apply_mask)
+        dq_scr[...] = dq_scr[...] + jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    _split_walk(chunk, kv_lo(qi), kv_hi(qi), qi, full_range, mask_fn is not None)
+    dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
+
+
+def _bwd_dkv_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+                             dk_scr, dv_scr, *, scale, mask_fn, score_fn, q_lo, q_hi,
+                             bq, full_range=None):
+    """dK and dV of one KV tile against the whole Q and dO of one query
+    head, held in VMEM with that head's ``lse`` and ``delta``; the query walk
+    is a loop in here. The tile is worked K-major: scores as ``[bkv, bq]``,
+    so ``lse`` and ``delta`` broadcast along sublanes from the ``[1, bq]``
+    layout they come in and no operand of the four matmuls is transposed."""
+    ki = pl.program_id(2)
+    h = pl.program_id(1)
+    bkv = k_ref.shape[2]
+    dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
+    dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
+    k = k_ref[0, 0]
+    v = v_ref[0, 0]
+
+    def chunk(j, apply_mask):
+        rows = pl.ds(pl.multiple_of(j * bq, bq), bq)
+        q = q_ref[0, 0, rows, :]
+        do = do_ref[0, 0, rows, :]
+        s_raw = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+        dp = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        row = col = None
+        if score_fn is not None or apply_mask:
+            row = j * bq + jax.lax.broadcasted_iota(jnp.int32, (bkv, bq), 1)
+            col = ki * bkv + jax.lax.broadcasted_iota(jnp.int32, (bkv, bq), 0)
+        p, ds = _bwd_p_ds(s_raw, dp, lse_ref[0, 0, :, rows].astype(jnp.float32),
+                          delta_ref[0, 0, :, rows].astype(jnp.float32), row, col, h,
+                          scale=scale, mask_fn=mask_fn, score_fn=score_fn,
+                          apply_mask=apply_mask)
+        dv_scr[...] = dv_scr[...] + jax.lax.dot_general(
+            p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_scr[...] = dk_scr[...] + jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    _split_walk(chunk, q_lo(ki), q_hi(ki), ki, full_range, mask_fn is not None)
+    dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
+    dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
+
+
 def fit_block(block: int, dim: int) -> int:
     """Largest power-of-two block <= requested that divides the sequence;
     128 is the TPU lane width / minimum tile. May still fail to divide for
@@ -516,18 +647,19 @@ def _check_divisible(Sq, bq, Skv, bkv):
             "(fit_block) or use the reference path")
 
 
-# -- the forward's plan: which kernel, which blocks --------------------------
+# -- the plan: which kernel, which blocks --------------------------------------
 class FlashPlan(NamedTuple):
     path: str       # "resident" | "streamed" | "reference"
-    block_q: int    # query rows a grid step
-    block_kv: int   # KV columns a tile: one a grid step (streamed), one a loop chunk (resident)
+    block_q: int    # query rows: a grid step's tile, or a loop chunk of the resident dK/dV
+    block_kv: int   # KV columns: a grid step's tile, or a loop chunk of the resident forward and dQ
 
 
 # Blocks where the caller names none. Streamed: (256, 512), the pre-ledger
-# default, which the backward kernels share. Resident: (512, 512) and two
-# chunks a loop trip, from this sweep of the kernel alone on a TPU v5e (my chip
-# runs, PR 25: scripts/bench_attention.py --forward-only; ms a call, causal,
-# bf16; resident 512x512 and streamed 256x512 give the same bits):
+# default, for all three kernels. Resident: (512, 512) and two chunks a loop
+# trip, for all three, from these sweeps of each kernel alone on a TPU v5e
+# (ms a call, causal, bf16). The forward (my chip runs, PR 25:
+# scripts/bench_attention.py --forward-only; resident 512x512 and streamed
+# 256x512 give the same bits):
 #
 #   B x S, heads, D            streamed   resident, block_q x block_kv
 #                              256x512    256x512  512x512  512x1024  1024x512
@@ -543,16 +675,42 @@ class FlashPlan(NamedTuple):
 # 6.20, 256x2048 7.68; at 512x512 one chunk a trip 5.70 (256x512: 6.97
 # against 5.96) and four 5.59; the statistics as loop carries instead of
 # scratch 7.02; exp2 with the scale folded in 5.64 (no gain: not VPU-bound).
+#
+# dQ | dK/dV (my chip runs, PR 28: scripts/bench_attention.py --backward-only;
+# resident 512x512 dQ and streamed 256x512 give the same bits, dK/dV differ in
+# the last place of bf16: it sums 512 query rows a chunk, K-major):
+#
+#   4 x 4,096, 32/8, 128     9.09|13.35  7.09| 8.18  6.52| 7.89  6.93| 8.13  6.84| 8.25
+#   8 x 2,048, 16/16, 128    2.79| 3.74  2.27| 2.36  2.04| 2.30  2.28| 2.54  2.24| 2.58
+#   8 x 2,048, 12/4, 64      1.88| 2.88  1.64| 1.72  1.48| 1.64  1.67| 1.86  1.63| 1.89
+#   16 x 1,024, 16/16, 128   1.62| 2.24  1.67| 1.57  1.48| 1.50  1.67| 1.71  1.60| 1.84
+#   2 x 8,192, 32/8, 128    15.95|24.94 11.84|14.37 10.99|13.78 11.26|13.92 11.17|14.17
+#   1 x 16,384, 32/8, 128   29.24|47.47 21.32|26.66 19.92|25.55 19.92|25.62 19.83|25.71
+#   4 x 4,096, window 512    5.83| 8.75  4.15| 5.01  3.73| 5.34  4.59| 6.12  4.54| 6.98
+#
+# Also at 4 x 4,096: one chunk a trip 6.63|7.99, three 6.48|7.85, four
+# 6.51|7.88; 256x1024 7.36|8.31, 1024x1024 6.69|8.00 (one a trip), 512x256
+# 8.09|8.72, 256x256 9.85|9.48; dK/dV with the tile Q-major (p and ds
+# contracted over their first axis, lse[:, None] a chunk, as the streamed
+# kernel has it) 8.01 at 512x512 and 9.37 at 256x512; dQ's lse and delta
+# lane-replicated in scratch instead of [block_q, 1] values: the same to
+# 0.01 ms. Past the budget, 1 x 32,768 streamed: 127.96|197.05 at (256, 512),
+# 79.52|103.92 at (1024, 1024).
 _STREAMED_BLOCKS = (256, 512)
 _RESIDENT_BLOCKS = (512, 512)
 _RESIDENT_UNROLL = 2
-# The resident call raises Mosaic's scoped VMEM limit to this (a v5e core has
-# 128 MiB), and takes the path only where K and V, double-buffered by the
-# pipeline, plus a chunk's float32 scores and probabilities stay under the
-# budget; the rest is for q, o, the statistics and what the compiler spills
-# (0.66 MiB at 256x512 by Mosaic's own count). At heads of 128 in bf16 that
-# admits 16,384 positions (16 MiB; resident 15.9 ms against 45.7 streamed,
-# table above) and not 32,768, whose 32 MiB Mosaic refuses under this limit.
+# float32 [block_q, block_kv] arrays a loop chunk keeps live: scores and
+# probabilities in the forward; in the backward dp and ds beside them.
+_CHUNK_TEMPS = {"flash_fwd": 2, "flash_bwd_dq": 4, "flash_bwd_dkv": 4}
+# A resident call raises Mosaic's scoped VMEM limit to this (a v5e core has
+# 128 MiB), and takes the path only where the two operands it holds (K and V
+# of a KV head; Q and dO of a query head in dK/dV, with that head's lse and
+# delta rows), double-buffered by the pipeline, plus a chunk's float32
+# temporaries stay under the budget; the rest is for the tile's own blocks,
+# the scratch and what the compiler spills (0.66 MiB in the forward at 256x512
+# by Mosaic's own count). At heads of 128 in bf16 that admits 16,384 positions
+# (16 MiB; the forward resident 15.9 ms against 45.7 streamed, table above)
+# and not 32,768, whose 32 MiB Mosaic refuses under this limit.
 _RESIDENT_VMEM_LIMIT = 32 * 2**20
 _RESIDENT_VMEM_BUDGET = 24 * 2**20
 
@@ -563,20 +721,28 @@ def _plan_blocks(default, Sq, Skv, block_q, block_kv) -> Tuple[int, int]:
 
 
 def flash_plan(Sq: int, Skv: int, D: int, dtype, block_q: Optional[int] = None,
-               block_kv: Optional[int] = None) -> FlashPlan:
-    """Which forward a call of these shapes runs, and its blocks; a pure
-    function of its arguments. ``resident`` holds the whole K and V of a KV
-    head in VMEM and walks them inside the kernel; ``streamed`` fetches one
-    KV tile a grid step, so its context is bounded by HBM and not by VMEM;
-    ``reference`` (no kernel) is for sequences no block divides. A block
-    the caller names is taken as given (capped at the sequence); one left
-    ``None`` is the path's default, fitted to the sequence."""
+               block_kv: Optional[int] = None, kernel: str = "flash_fwd") -> FlashPlan:
+    """Which kernel a call of these shapes runs, and its blocks, for
+    ``kernel`` ``flash_fwd``, ``flash_bwd_dq`` or ``flash_bwd_dkv``; a pure
+    function of its arguments. ``resident`` holds in VMEM the two operands
+    the kernel streams (K and V; in dK/dV, Q and dO) and walks them inside
+    the kernel; ``streamed`` fetches one tile of them a grid step, so its
+    context is bounded by HBM and not by VMEM; ``reference`` (no kernel) is
+    for sequences no block divides. A block the caller names is taken as
+    given (capped at the sequence); one left ``None`` is the path's default,
+    fitted to the sequence."""
     bq, bkv = _plan_blocks(_RESIDENT_BLOCKS, Sq, Skv, block_q, block_kv)
     if Sq % bq == 0 and Skv % bkv == 0:
         lanes = -(-D // _LANES) * _LANES  # VMEM pads the head dim to whole registers
-        kv_bytes = 2 * 2 * Skv * lanes * jnp.dtype(dtype).itemsize
-        chunk_bytes = 2 * bq * bkv * 4
-        if kv_bytes + chunk_bytes <= _RESIDENT_VMEM_BUDGET:
+        row_bytes = 2 * 2 * lanes * jnp.dtype(dtype).itemsize  # two operands, two buffers
+        if kernel == "flash_bwd_dkv":
+            # Q and dO, and the [1, Sq] float32 rows of lse and delta, which
+            # VMEM pads to 8 sublanes
+            held_bytes = Sq * (row_bytes + 2 * 2 * 8 * 4)
+        else:
+            held_bytes = Skv * row_bytes
+        chunk_bytes = _CHUNK_TEMPS[kernel] * bq * bkv * 4
+        if held_bytes + chunk_bytes <= _RESIDENT_VMEM_BUDGET:
             return FlashPlan("resident", bq, bkv)
     bq, bkv = _plan_blocks(_STREAMED_BLOCKS, Sq, Skv, block_q, block_kv)
     if Sq % bq or Skv % bkv:
@@ -586,19 +752,44 @@ def flash_plan(Sq: int, Skv: int, D: int, dtype, block_q: Optional[int] = None,
 
 # The path is chosen while tracing, so this counts traces, not calls of the
 # compiled step: what a jitted program runs is what its one trace counted.
+_PLAN_KEYS = ("resident", "streamed", "reference", "bwd_dq_resident", "bwd_dq_streamed",
+              "bwd_dkv_resident", "bwd_dkv_streamed")
 _plan_counts: Dict[str, int] = collections.Counter()
 _plan_counts_lock = threading.Lock()
 
 
-def _count_plan(path: str) -> None:
+def _count_plan(path: str, kernel: str = "flash_fwd") -> None:
+    key = path if kernel == "flash_fwd" else f"{kernel[len('flash_'):]}_{path}"
     with _plan_counts_lock:
-        _plan_counts[path] += 1
+        _plan_counts[key] += 1
 
 
 def plan_counts() -> Dict[str, int]:
-    """Forward calls traced so far in this process, by path."""
+    """Kernel calls traced so far in this process: the forward's by path
+    (``reference``: no kernel, forward or backward), the two backward
+    kernels' by kernel and path."""
     with _plan_counts_lock:
-        return {p: _plan_counts[p] for p in ("resident", "streamed", "reference")}
+        return {key: _plan_counts[key] for key in _PLAN_KEYS}
+
+
+def _traced_plan(kernel, q, k, block_q, block_kv, _path) -> FlashPlan:
+    """The plan of one raw call, tallied; ``_path`` (the tests') overrides
+    the choice and takes that path's default blocks."""
+    Sq, D = q.shape[2:]
+    Skv = k.shape[2]
+    held = q if kernel == "flash_bwd_dkv" else k
+    plan = flash_plan(Sq, Skv, D, held.dtype, block_q, block_kv, kernel)
+    if _path is not None and _path != plan.path:
+        default = _RESIDENT_BLOCKS if _path == "resident" else _STREAMED_BLOCKS
+        plan = FlashPlan(_path, *_plan_blocks(default, Sq, Skv, block_q, block_kv))
+    _check_divisible(Sq, plan.block_q, Skv, plan.block_kv)
+    _count_plan(plan.path, kernel)
+    return plan
+
+
+def _resident_params():
+    return None if _interpret() else pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * 3, vmem_limit_bytes=_RESIDENT_VMEM_LIMIT)
 
 
 # -- raw kernel entry points (reused by ring attention) ----------------------
@@ -612,14 +803,7 @@ def flash_fwd(q, k, v, *, mask_fn=None, score_fn=None, mask_type="causal",
     the interior-tile fast path (skip in-tile masking where the tile is
     provably fully valid). :func:`flash_plan` picks the kernel from the
     shapes; ``_path`` is for tests, which run both on one input."""
-    B, Hq, Sq, D = q.shape
-    _, Hkv, Skv, _ = k.shape
-    plan = flash_plan(Sq, Skv, D, k.dtype, block_q, block_kv)
-    if _path is not None and _path != plan.path:
-        default = _RESIDENT_BLOCKS if _path == "resident" else _STREAMED_BLOCKS
-        plan = FlashPlan(_path, *_plan_blocks(default, Sq, Skv, block_q, block_kv))
-    _check_divisible(Sq, plan.block_q, Skv, plan.block_kv)
-    _count_plan(plan.path)
+    plan = _traced_plan("flash_fwd", q, k, block_q, block_kv, _path)
     fwd = _flash_fwd_resident if plan.path == "resident" else _flash_fwd_streamed
     return fwd(q, k, v, plan.block_q, plan.block_kv, mask_fn=mask_fn, score_fn=score_fn,
                mask_type=mask_type, window=window, prefix_len=prefix_len,
@@ -664,9 +848,7 @@ def _flash_fwd_resident(q, k, v, bq, bkv, *, mask_fn, score_fn, mask_type,
             _scratch((bq, _LANES)),      # running denominator
             _scratch((bq, D)),           # fp32 output accumulator
         ],
-        compiler_params=None if _interpret() else pltpu.CompilerParams(
-            dimension_semantics=("parallel",) * 3,
-            vmem_limit_bytes=_RESIDENT_VMEM_LIMIT),
+        compiler_params=_resident_params(),
         interpret=_interpret(),
         name="flash_fwd",  # one name for both paths: the trace's reader keys on it
     )(q, k, v)
@@ -727,14 +909,48 @@ def _flash_fwd_streamed(q, k, v, bq, bkv, *, mask_fn, score_fn, mask_type,
 
 def flash_bwd_dq(q, k, v, g, lse, delta, *, mask_fn=None, score_fn=None,
                  mask_type="causal", window=512, prefix_len=0,
-                 block_q=256, block_kv=512, scale=1.0, canonical_mask=False):
-    """Raw dQ kernel. ``lse``/``delta``: [B, Hq, 1, Sq] fp32."""
+                 block_q=None, block_kv=None, scale=1.0, canonical_mask=False,
+                 _path=None):
+    """Raw dQ kernel. ``lse``/``delta``: [B, Hq, 1, Sq] fp32. Resident K/V
+    or streamed by :func:`flash_plan`, as the forward (``_path``: tests)."""
+    plan = _traced_plan("flash_bwd_dq", q, k, block_q, block_kv, _path)
+    bwd = _flash_bwd_dq_resident if plan.path == "resident" else _flash_bwd_dq_streamed
+    return bwd(q, k, v, g, lse, delta, plan.block_q, plan.block_kv, mask_fn=mask_fn,
+               score_fn=score_fn, mask_type=mask_type, window=window,
+               prefix_len=prefix_len, scale=scale, canonical_mask=canonical_mask)
+
+
+def _flash_bwd_dq_resident(q, k, v, g, lse, delta, bq, bkv, *, mask_fn, score_fn,
+                           mask_type, window, prefix_len, scale, canonical_mask):
     B, Hq, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape
     G = Hq // Hkv
-    bq = min(block_q, Sq)
-    bkv = min(block_kv, Skv)
-    _check_divisible(Sq, bq, Skv, bkv)
+    kv_lo, kv_hi = _kv_range(mask_type, window, prefix_len, bq, bkv, Skv // bkv)
+    full_range = (_full_range(mask_type, window, prefix_len, bq, bkv)
+                  if canonical_mask and mask_type != "full" else None)
+    tile = _vmem_spec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0))
+    held = _vmem_spec((1, 1, Skv, D), lambda b, h, i: (b, h // G, 0, 0))
+    stat = _vmem_spec((1, 1, 1, bq), lambda b, h, i: (b, h, 0, i))
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_dq_resident_kernel, scale=scale, mask_fn=mask_fn, score_fn=score_fn,
+            kv_lo=kv_lo, kv_hi=kv_hi, bkv=bkv, full_range=full_range),
+        grid=(B, Hq, Sq // bq),
+        in_specs=[tile, held, held, tile, stat, stat],
+        out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
+        scratch_shapes=[_scratch((bq, D))],
+        compiler_params=_resident_params(),
+        interpret=_interpret(),
+        name="flash_bwd_dq",  # one name for both paths, as the forward's
+    )(q, k, v, g, lse, delta)
+
+
+def _flash_bwd_dq_streamed(q, k, v, g, lse, delta, bq, bkv, *, mask_fn, score_fn,
+                           mask_type, window, prefix_len, scale, canonical_mask):
+    B, Hq, Sq, D = q.shape
+    _, Hkv, Skv, _ = k.shape
+    G = Hq // Hkv
     nq = Sq // bq
     nkv = Skv // bkv
     kv_lo, kv_hi = _kv_range(mask_type, window, prefix_len, bq, bkv, nkv)
@@ -771,15 +987,55 @@ def flash_bwd_dq(q, k, v, g, lse, delta, *, mask_fn=None, score_fn=None,
 
 def flash_bwd_dkv(q, k, v, g, lse, delta, *, mask_fn=None, score_fn=None,
                   mask_type="causal", window=512, prefix_len=0,
-                  block_q=256, block_kv=512, scale=1.0, canonical_mask=False):
+                  block_q=None, block_kv=None, scale=1.0, canonical_mask=False,
+                  _path=None):
     """Raw dK/dV kernel. Returns per-QUERY-head grads [B, Hq, Skv, D]
-    (caller reduces GQA groups)."""
+    (caller reduces GQA groups). Resident Q/dO or streamed by
+    :func:`flash_plan` (``_path``: tests)."""
+    plan = _traced_plan("flash_bwd_dkv", q, k, block_q, block_kv, _path)
+    bwd = _flash_bwd_dkv_resident if plan.path == "resident" else _flash_bwd_dkv_streamed
+    return bwd(q, k, v, g, lse, delta, plan.block_q, plan.block_kv, mask_fn=mask_fn,
+               score_fn=score_fn, mask_type=mask_type, window=window,
+               prefix_len=prefix_len, scale=scale, canonical_mask=canonical_mask)
+
+
+def _flash_bwd_dkv_resident(q, k, v, g, lse, delta, bq, bkv, *, mask_fn, score_fn,
+                            mask_type, window, prefix_len, scale, canonical_mask):
     B, Hq, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape
     G = Hq // Hkv
-    bq = min(block_q, Sq)
-    bkv = min(block_kv, Skv)
-    _check_divisible(Sq, bq, Skv, bkv)
+    q_lo, q_hi = _q_range(mask_type, window, prefix_len, bq, bkv, Sq // bq)
+    full_range = (_full_range_q(mask_type, window, prefix_len, bq, bkv)
+                  if canonical_mask and mask_type != "full" else None)
+    # Q, dO and the statistics are the query head's own: fetched once a
+    # (sequence, query head), whatever the KV tile.
+    held = _vmem_spec((1, 1, Sq, D), lambda b, h, i: (b, h, 0, 0))
+    stat = _vmem_spec((1, 1, 1, Sq), lambda b, h, i: (b, h, 0, 0))
+    tile = _vmem_spec((1, 1, bkv, D), lambda b, h, i: (b, h // G, i, 0))
+    out = _vmem_spec((1, 1, bkv, D), lambda b, h, i: (b, h, i, 0))
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_dkv_resident_kernel, scale=scale, mask_fn=mask_fn, score_fn=score_fn,
+            q_lo=q_lo, q_hi=q_hi, bq=bq, full_range=full_range),
+        grid=(B, Hq, Skv // bkv),
+        in_specs=[held, tile, tile, held, stat, stat],
+        out_specs=[out, out],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, Hq, Skv, D), k.dtype),
+            jax.ShapeDtypeStruct((B, Hq, Skv, D), v.dtype),
+        ],
+        scratch_shapes=[_scratch((bkv, D)), _scratch((bkv, D))],
+        compiler_params=_resident_params(),
+        interpret=_interpret(),
+        name="flash_bwd_dkv",
+    )(q, k, v, g, lse, delta)
+
+
+def _flash_bwd_dkv_streamed(q, k, v, g, lse, delta, bq, bkv, *, mask_fn, score_fn,
+                            mask_type, window, prefix_len, scale, canonical_mask):
+    B, Hq, Sq, D = q.shape
+    _, Hkv, Skv, _ = k.shape
+    G = Hq // Hkv
     nq = Sq // bq
     nkv = Skv // bkv
     q_lo, q_hi = _q_range(mask_type, window, prefix_len, bq, bkv, nq)
@@ -828,21 +1084,19 @@ def flash_bwd_dkv(q, k, v, g, lse, delta, *, mask_fn=None, score_fn=None,
 # -- host-side wrapper -------------------------------------------------------
 def _attention_core(
     mask_fn, score_fn, mask_type: str, window: int, prefix_len: int,
-    block_q: int, block_kv: int, scale: float, canonical_mask: bool = False,
-    fwd_blocks: Tuple[Optional[int], Optional[int]] = (None, None),
+    block_q: Optional[int], block_kv: Optional[int], scale: float,
+    canonical_mask: bool = False,
 ):
     """Build the custom-vjp flash attention for a fixed mask/score program.
 
     Inputs (to the returned fn): q [B, Hq, Sq, D], k/v [B, Hkv, Skv, D].
     Output: o [B, Hq, Sq, D]. ``scale`` is baked in (nondiff).
-    ``block_q``/``block_kv`` tile the backward kernels; ``fwd_blocks`` is
-    what the caller asked of the forward, ``None`` leaving it to
-    :func:`flash_plan`.
+    ``block_q``/``block_kv`` are what the caller asked of all three
+    kernels, ``None`` leaving each to :func:`flash_plan`.
     """
     kw = dict(mask_fn=mask_fn, score_fn=score_fn, mask_type=mask_type,
               window=window, prefix_len=prefix_len, block_q=block_q,
               block_kv=block_kv, scale=scale, canonical_mask=canonical_mask)
-    fwd_kw = dict(kw, block_q=fwd_blocks[0], block_kv=fwd_blocks[1])
 
     @jax.custom_vjp
     def attn(q, k, v):
@@ -850,7 +1104,7 @@ def _attention_core(
         return o
 
     def _fwd(q, k, v):
-        o, lse = flash_fwd(q, k, v, **fwd_kw)
+        o, lse = flash_fwd(q, k, v, **kw)
         return o, (q, k, v, o, lse)
 
     def _bwd(res, g):
@@ -876,9 +1130,9 @@ def _attention_core(
 
 @functools.lru_cache(maxsize=64)
 def _cached_core(mask_fn, score_fn, mask_type, window, prefix_len, block_q,
-                 block_kv, scale, canonical_mask=False, fwd_blocks=(None, None)):
+                 block_kv, scale, canonical_mask=False):
     return _attention_core(mask_fn, score_fn, mask_type, window, prefix_len,
-                           block_q, block_kv, scale, canonical_mask, fwd_blocks)
+                           block_q, block_kv, scale, canonical_mask)
 
 
 def _mesh_partition(batch: int, q_heads: int, kv_heads: int, shard_heads: bool):
@@ -966,12 +1220,13 @@ def flash_attention(
             local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             axis_names=manual_axes, check_vma=False)(q, k, v)
 
-    # The forward's blocks are the plan's; the backward kernels keep the
-    # streamed tiling.
-    fwd_blocks = (block_q and fit_block(block_q, Sq), block_kv and fit_block(block_kv, Skv))
-    fwd_path = flash_plan(Sq, Skv, D, k.dtype, *fwd_blocks).path
-    block_q = fit_block(block_q or _STREAMED_BLOCKS[0], Sq)
-    block_kv = fit_block(block_kv or _STREAMED_BLOCKS[1], Skv)
+    # The blocks the caller names, fitted, for all three kernels; where it
+    # names none, each kernel's own by ``flash_plan``. Every default is a
+    # power of two that ``fit_block`` halves down to the lane width, so a
+    # sequence the forward's blocks do not divide, no kernel's do.
+    block_q = block_q and fit_block(block_q, Sq)
+    block_kv = block_kv and fit_block(block_kv, Skv)
+    no_kernel = flash_plan(Sq, Skv, D, k.dtype, block_q, block_kv).path == "reference"
 
     from . import masks as M
 
@@ -994,7 +1249,7 @@ def flash_attention(
             "full": None,
         }[mask_type]
 
-    if fwd_path == "reference" or Sq % block_q or Skv % block_kv or Hq % Hkv:
+    if no_kernel or Hq % Hkv:
         # Odd sizes: reference path with the SAME mask and score program
         # (kernel-style score_fn adapted to the [B, Hkv, G, Sq, Skv] layout).
         from .attention import reference_attention
@@ -1014,7 +1269,7 @@ def flash_attention(
         return reference_attention(q, k, v, mask_mod=mask_fn, score_mod=ref_score, scale=scale)
 
     core = _cached_core(mask_fn, score_fn, mask_type, window_size, prefix_len,
-                        block_q, block_kv, float(scale), canonical, fwd_blocks)
+                        block_q, block_kv, float(scale), canonical)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)

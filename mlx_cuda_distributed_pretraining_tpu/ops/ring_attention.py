@@ -80,7 +80,7 @@ def _gqa_reduce(d_h, B, Hkv, G, Sl, D):
 # Flash-kernel causal path
 # ---------------------------------------------------------------------------
 def _ring_attention_flash(q, k, v, axis_name: str, scale: float,
-                          block_q: int, block_kv: int):
+                          block_q: Optional[int], block_kv: Optional[int]):
     """Causal ring attention with Pallas-tiled chunk math. Runs INSIDE
     shard_map; q/k/v are local shards [B, S_local, H, D]."""
     from . import masks as M
@@ -214,7 +214,7 @@ def _ring_attention_flash(q, k, v, axis_name: str, scale: float,
 # Flash-kernel sliding-window path
 # ---------------------------------------------------------------------------
 def _ring_attention_flash_sw(q, k, v, axis_name: str, scale: float,
-                             block_q: int, block_kv: int, window: int):
+                             block_q: Optional[int], block_kv: Optional[int], window: int):
     """Sliding-window ring attention with Pallas-tiled chunk math.
 
     The ring loop is **statically unrolled over the rotation distance** i,
@@ -404,21 +404,22 @@ def ring_attention(
     axis_name: str = "sp",
     mask_mod: Optional[MaskMod] = None,
     scale: Optional[float] = None,
-    block_q: int = 256,
-    block_kv: int = 512,
+    block_q: Optional[int] = None,
+    block_kv: Optional[int] = None,
 ) -> jnp.ndarray:
     """Runs INSIDE shard_map. q/k/v: local shards [B, S_local, H, D] with the
     global sequence laid out contiguously across the axis. ``mask_mod``
     takes GLOBAL (q_idx, kv_idx). Default mask is causal (flash-kernel
-    path); non-causal mods use the exact jnp chunk path."""
-    from .flash_attention import fit_block
+    path); non-causal mods use the exact jnp chunk path. Blocks left ``None``
+    are each chunk kernel's own by ``flash_plan``, as in ``flash_attention``."""
+    from .flash_attention import fit_block, flash_plan
 
     Sl, D = q.shape[1], q.shape[-1]
     scale = (D ** -0.5) if scale is None else scale
     plan = getattr(mask_mod, "_plan", None) if mask_mod is not None else ("causal", 0, 0)
-    bq = fit_block(block_q, Sl)
-    bkv = fit_block(block_kv, Sl)
-    if plan is not None and Sl % bq == 0 and Sl % bkv == 0:
+    bq = block_q and fit_block(block_q, Sl)
+    bkv = block_kv and fit_block(block_kv, Sl)
+    if plan is not None and flash_plan(Sl, Sl, D, k.dtype, bq, bkv).path != "reference":
         if plan[0] == "causal":
             return _ring_attention_flash(q, k, v, axis_name, scale, bq, bkv)
         if plan[0] == "sliding_window":
@@ -430,8 +431,8 @@ def ring_attention(
 
 
 def make_ring_attention(mesh, axis_name: str = "sp", mask_mod: Optional[MaskMod] = None,
-                        batch_axes=("dp", "fsdp"), block_q: int = 256,
-                        block_kv: int = 512):
+                        batch_axes=("dp", "fsdp"), block_q: Optional[int] = None,
+                        block_kv: Optional[int] = None):
     """shard_map wrapper: [B, S_global, H, D] (sharded batch over dp/fsdp,
     sequence over sp) -> same. Heads/D replicated across sp."""
     from jax.sharding import PartitionSpec as P

@@ -492,7 +492,7 @@ class Trainer:
         # obs/compiles.py totals at the last window's close: the difference
         # rides each step_window event as xla_compiles / xla_compile_s.
         self._compiles_seen = compiles.totals()
-        # Which forward kernel the step's flash calls were traced to
+        # Which path the step's flash kernels, forward and backward, were traced to
         # (ops/flash_attention.py flash_plan): the tally since here, logged
         # after the first compile and carried by the first step_window event.
         self._flash_plan_seen = flash_plan_counts()
@@ -679,7 +679,7 @@ class Trainer:
             self.events.append("compile", seconds=round(seconds, 4), step=step)
         self._flash_plan = {path: n - self._flash_plan_seen[path]
                             for path, n in flash_plan_counts().items()}
-        self.logger.log("flash forward plan (calls traced): " + ", ".join(
+        self.logger.log("flash plan (kernel calls traced, by path): " + ", ".join(
             f"{path}={n}" for path, n in self._flash_plan.items()))
 
     def _touch_heartbeat(self, step: Optional[int] = None) -> None:

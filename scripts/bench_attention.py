@@ -1,6 +1,6 @@
 """Attention kernel microbenchmark on the real TPU chip.
 
-Three modes:
+Four modes:
 
 - default: the Pallas flash kernel (fwd+bwd) against XLA's fused attention
   (reference_attention: einsum + softmax, fully materialized scores) across
@@ -16,13 +16,17 @@ Three modes:
   row a trace's ``flash_fwd`` self time compares with: the cell
   ``mistral-7b-v0_3-l4.train-1chip`` is
   ``--heads 32 --kv-heads 8 --head-dim 128 --batch 4 --seq 4096``.
+- ``--backward-only``: the same rows for the raw ``flash_bwd_dq`` and
+  ``flash_bwd_dkv`` (``--kernels``), on the residuals of one forward call
+  (3 and 4 half squares a causal call by the benchmark's count): what a
+  trace's ``flash_bwd_dq`` / ``flash_bwd_dkv`` self times compare with.
 
 Methodology: each measurement jits an on-device ``lax.fori_loop`` that
 chains N attention calls (output feeds the next query, so nothing is
 DCE'd), syncs via a 1-element ``device_get``, and reports
 (T(n_hi) - T(n_lo)) / (n_hi - n_lo) to cancel the fixed per-call overhead.
 
-Usage (on TPU):  python scripts/bench_attention.py [--sweep | --forward-only]
+Usage (on TPU):  python scripts/bench_attention.py [--sweep | --forward-only | --backward-only]
 Writes results to stdout as JSON lines.
 """
 
@@ -39,15 +43,17 @@ import jax
 import jax.numpy as jnp
 
 
-def timed_loop(step, q, k, v, n_lo=5, n_hi=25):
-    """step: (q, k, v) -> array shaped like q. Returns seconds per call."""
+def timed_loop(step, q, k, v, *rest, n_lo=5, n_hi=25):
+    """step: (q, k, v, *rest) -> array shaped like q. Returns seconds per
+    call. Every array the step reads is an argument of the jitted loop: one
+    it closed over would be compiled in as a constant."""
 
-    @partial(jax.jit, static_argnums=(3,))
-    def loop(q, k, v, iters):
-        return jax.lax.fori_loop(0, iters, lambda i, qq: step(qq, k, v), q)
+    @partial(jax.jit, static_argnums=(0,))
+    def loop(iters, q, k, v, *rest):
+        return jax.lax.fori_loop(0, iters, lambda i, qq: step(qq, k, v, *rest), q)
 
     def run(iters):
-        out = loop(q, k, v, iters)
+        out = loop(iters, q, k, v, *rest)
         jax.device_get(out[(0,) * (out.ndim - 1) + (slice(0, 1),)])
 
     run(n_lo)  # compile both shapes
@@ -67,41 +73,56 @@ def attn_flops(B, H, Sq, Skv, D, causal=True):
     return f / 2 if causal else f
 
 
-def forward_only(a, dtype):
-    """Rows of the raw forward kernel; ``path`` "auto" leaves the choice to
-    ``flash_plan`` and any other value forces it."""
+def raw_kernel_rows(a, dtype):
+    """Rows of the raw kernels, forward or backward; ``path`` "auto" leaves
+    the choice to ``flash_plan`` and any other value forces it."""
     from benchmark import peaks
+    from benchmark.flops import flash_attention as flops
+    from mlx_cuda_distributed_pretraining_tpu.ops import flash_attention as fa
     from mlx_cuda_distributed_pretraining_tpu.ops import masks as M
-    from mlx_cuda_distributed_pretraining_tpu.ops.flash_attention import flash_fwd
 
     B, S, Hq, Hkv, D = a.batch, a.seq, a.heads, a.kv_heads, a.head_dim
     peak = peaks.peak(jax.devices()[0].device_kind)["bf16_flops"]
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(ks[0], (B, Hq, S, D), dtype)
     k = jax.random.normal(ks[1], (B, Hkv, S, D), dtype)
     v = jax.random.normal(ks[2], (B, Hkv, S, D), dtype)
     causal = a.mask_type == "causal"
     mask_kw = dict(mask_type=a.mask_type, mask_fn=M.causal() if causal else None,
                    canonical_mask=causal, scale=D ** -0.5)
-    fl = attn_flops(B, Hq, S, S, D, causal=causal)
-    for path in a.paths.split(","):
-        for blocks in a.blocks.split(","):
-            # "auto": the path's own default blocks
-            bq, bkv = (None, None) if blocks == "auto" else (int(x) for x in blocks.split("x"))
-            kw = dict(mask_kw, block_q=bq, block_kv=bkv)
-            if path != "auto":
-                kw["_path"] = path
-            row = {"name": "flash_fwd", "mask_type": a.mask_type, "path": path,
-                   "block_q": bq, "block_kv": bkv, "B": B, "Hq": Hq, "Hkv": Hkv,
-                   "S": S, "D": D}
-            try:
-                t = timed_loop(lambda qq, kk, vv: flash_fwd(qq, kk, vv, **kw)[0],
-                               q, k, v, n_hi=45)
-                row.update(fwd_ms=round(t * 1e3, 3),
-                           share_of_peak=round(fl / t / peak, 4))
-            except Exception as e:  # noqa: BLE001 - a block the compiler refuses is a row too
-                row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
-            print(json.dumps(row), flush=True)
+    rest = ()
+    if a.forward_only:
+        kernels = {"flash_fwd": lambda qq, kk, vv, **kw: fa.flash_fwd(qq, kk, vv, **kw)[0]}
+    else:
+        # the residuals a backward call gets: o and lse of the forward, a
+        # cotangent, and delta = rowsum(dO * O)
+        g = jax.random.normal(ks[3], (B, Hq, S, D), dtype)
+        o, lse = jax.jit(lambda q, k, v: fa.flash_fwd(q, k, v, **mask_kw))(q, k, v)
+        delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[:, :, None, :]
+        rest = (g, lse, delta)
+        # dK per query head has q's shape where Sq == Skv: chained like dQ
+        backward = {"dq": fa.flash_bwd_dq,
+                    "dkv": lambda *ops, **kw: fa.flash_bwd_dkv(*ops, **kw)[0]}
+        kernels = {f"flash_bwd_{n}": backward[n] for n in a.kernels.split(",")}
+    for name, fn in kernels.items():
+        # the benchmark's count is the causal call's; a full mask runs twice that
+        fl = flops.BY_KERNEL[name](B, Hq, S, D) * (1 if causal else 2)
+        for path in a.paths.split(","):
+            for blocks in a.blocks.split(","):
+                # "auto": the path's own default blocks
+                bq, bkv = (None, None) if blocks == "auto" else (int(x) for x in blocks.split("x"))
+                kw = dict(mask_kw, block_q=bq, block_kv=bkv)
+                if path != "auto":
+                    kw["_path"] = path
+                row = {"name": name, "mask_type": a.mask_type, "path": path,
+                       "block_q": bq, "block_kv": bkv, "B": B, "Hq": Hq, "Hkv": Hkv,
+                       "S": S, "D": D}
+                try:
+                    t = timed_loop(lambda *ops: fn(*ops, **kw), q, k, v, *rest, n_hi=45)
+                    row.update(ms=round(t * 1e3, 3), share_of_peak=round(fl / t / peak, 4))
+                except Exception as e:  # noqa: BLE001 - a block the compiler refuses is a row too
+                    row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+                print(json.dumps(row), flush=True)
 
 
 def main():
@@ -109,6 +130,10 @@ def main():
     parser.add_argument("--sweep", action="store_true", help="sweep block sizes")
     parser.add_argument("--forward-only", action="store_true",
                         help="raw flash_fwd rows at --batch x --seq")
+    parser.add_argument("--backward-only", action="store_true",
+                        help="raw flash_bwd_dq / flash_bwd_dkv rows at --batch x --seq")
+    parser.add_argument("--kernels", default="dq,dkv",
+                        help="comma list of dq|dkv for --backward-only")
     parser.add_argument("--dtype", default="bfloat16")
     parser.add_argument("--head-dim", type=int, default=64)
     parser.add_argument("--heads", type=int, default=16)
@@ -118,9 +143,10 @@ def main():
     parser.add_argument("--seq", type=int, default=4096)
     parser.add_argument("--mask-type", default="causal", choices=("causal", "full"))
     parser.add_argument("--blocks", default="auto",
-                        help="comma list of auto | block_q x block_kv for --forward-only")
+                        help="comma list of auto | block_q x block_kv for --forward-only "
+                             "and --backward-only")
     parser.add_argument("--paths", default="auto",
-                        help="comma list of auto|resident|streamed for --forward-only")
+                        help="comma list of auto|resident|streamed, likewise")
     a = parser.parse_args()
     a.kv_heads = a.kv_heads or a.heads
 
@@ -134,8 +160,8 @@ def main():
     print(json.dumps({"device": str(dev), "device_kind": dev.device_kind,
                       "dtype": str(dtype), "H": H, "Hkv": Hkv, "D": D}))
 
-    if a.forward_only:
-        forward_only(a, dtype)
+    if a.forward_only or a.backward_only:
+        raw_kernel_rows(a, dtype)
         return
 
     def make_inputs(B, S, key=0):

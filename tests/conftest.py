@@ -77,13 +77,14 @@ def pytest_collection_modifyitems(config, items):
     items[:] = rest + serial
 
 
-# --- both flash forward kernels on one test ---------------------------------
-# ops/flash_attention.py picks its forward (K/V resident in VMEM, or streamed
-# through the grid) from the call's shapes, and at test sizes that is always
-# the resident one. A test that takes ``flash_path`` runs once under each:
-# every flash_fwd call inside it (the custom-vjp wrapper's, ring attention's
-# raw ones) is forced through the internal ``_path`` argument, and the tally
-# has to show that the named kernel, and not the other, was traced.
+# --- both paths of the flash kernels on one test -----------------------------
+# ops/flash_attention.py picks each kernel's path (operands resident in VMEM,
+# or streamed through the grid) from the call's shapes, and at test sizes that
+# is always the resident one. A test that takes ``flash_path`` runs once under
+# each: every raw call inside it, forward or backward (the custom-vjp
+# wrapper's, ring attention's), is forced through the internal ``_path``
+# argument, and the tally has to show that the named path, and not the other,
+# was traced, for the forward and for whichever backward kernels ran.
 
 @pytest.fixture(params=("resident", "streamed"))
 def flash_path(request, monkeypatch):
@@ -91,11 +92,15 @@ def flash_path(request, monkeypatch):
 
     path = request.param
     other = "streamed" if path == "resident" else "resident"
-    monkeypatch.setattr(fa, "flash_fwd", functools.partial(fa.flash_fwd, _path=path))
+    for entry in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        monkeypatch.setattr(fa, entry, functools.partial(getattr(fa, entry), _path=path))
     fa._cached_core.cache_clear()  # a core traced under the other path
     before = fa.plan_counts()
     yield path
     fa._cached_core.cache_clear()
     after = fa.plan_counts()
     assert after[path] > before[path], f"no {path} forward was traced"
-    assert after[other] == before[other], f"a {other} forward was traced"
+    for key in (other, f"bwd_dq_{other}", f"bwd_dkv_{other}"):
+        assert after[key] == before[key], f"a {key} kernel was traced"
+    assert (after[f"bwd_dq_{path}"] - before[f"bwd_dq_{path}"]
+            == after[f"bwd_dkv_{path}"] - before[f"bwd_dkv_{path}"])

@@ -3,7 +3,8 @@ item a): per mask type, forward and gradients, GQA/MQA, fp32.
 
 Runs the real kernel code in Pallas interpret mode on CPU; the identical
 code compiles to Mosaic on TPU. A test that takes ``flash_path``
-(conftest.py) runs under both forward kernels, resident and streamed.
+(conftest.py) runs under both paths of every kernel, forward and backward:
+resident and streamed.
 """
 
 import jax
@@ -326,7 +327,7 @@ def test_flash_under_mesh_matches_unsharded(flash_path):
         np.testing.assert_allclose(got, want, atol=1e-5)
 
 
-# -- the two forward kernels, and the plan that picks one --------------------
+# -- the two paths of each kernel, and the plan that picks one ---------------
 def _raw_qkv(hq=2, hkv=2, sq=512, skv=512, d=32, dtype=jnp.float32, seed=3):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     return (jax.random.normal(ks[0], (1, hq, sq, d), dtype),
@@ -341,7 +342,8 @@ RAW_CASES = {
     "prefix_lm": dict(mask_type="prefix_lm", prefix_len=130, mask_fn=M.prefix_lm(130)),
     "full": dict(mask_type="full", mask_fn=None),
     "band_partial": dict(mask_type="band", window=64, mask_fn=M.band(64)),
-    # every query tile but the last has lo > hi: an empty KV walk
+    # every query tile but the last has lo > hi: an empty KV walk (and every
+    # KV tile but the first an empty query walk)
     "band_empty_ranges": dict(mask_type="band", window=-384, mask_fn=M.band(-384)),
     "custom_mask": dict(mask_type="full", canonical_mask=False,
                         mask_fn=lambda r, c: (r >= c) & ((c % 7) != 0)),
@@ -349,32 +351,71 @@ RAW_CASES = {
     "short_q": dict(mask_type="full", mask_fn=None, sq=128),
     "short_kv_causal": dict(mask_type="causal", mask_fn=M.causal(), skv=256),
     "bf16": dict(mask_type="causal", mask_fn=M.causal(), dtype=jnp.bfloat16),
+    # a non-additive score program: the backward chains through its _d_score
+    "soft_cap": dict(mask_type="causal", mask_fn=M.causal(), score_fn=soft_cap_score_fn(5.0)),
+    "alibi_gqa": dict(mask_type="causal", mask_fn=M.causal(), score_fn=alibi_score_fn(4),
+                      hq=4, hkv=2),
+    "d64": dict(mask_type="causal", mask_fn=M.causal(), d=64),
+    "d128_window": dict(mask_type="sliding_window", window=200,
+                        mask_fn=M.sliding_window(200), d=128),
 }
 
 
-@pytest.mark.parametrize("blocks", [(128, 128), (64, 128), (256, 64)],
-                         ids=lambda b: f"{b[0]}x{b[1]}")
+def _close(got, want, name, tol):
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
+                               atol=tol, rtol=tol, err_msg=name)
+
+
+# "auto": each path's own default blocks, which differ (512x512 against 256x512)
+@pytest.mark.parametrize("blocks", [(128, 128), (64, 128), (256, 64), (None, None)],
+                         ids=lambda b: "auto" if b[0] is None else f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("kernels", ["fwd", "bwd"])
 @pytest.mark.parametrize("name", sorted(RAW_CASES))
-def test_resident_matches_streamed(name, blocks):
-    """One input through both forward kernels: the same chunks in the same
-    order with the same arithmetic, so o and lse agree to float32 round-off
-    (an empty walk leaves o zero and lse at its floor in both)."""
+def test_resident_matches_streamed(name, kernels, blocks):
+    """One input through both paths of the forward (``fwd``) or of dQ and
+    dK/dV (``bwd``): the same tiles with the same arithmetic, so the results
+    agree to float32 round-off (the order of the sums where the blocks
+    differ). An empty walk leaves o, dq, dk and dv zero and lse at its floor
+    in both."""
     case = dict(RAW_CASES[name])
-    shape = {key: case.pop(key) for key in ("hq", "hkv", "sq", "skv", "dtype") if key in case}
+    shape = {key: case.pop(key) for key in ("hq", "hkv", "sq", "skv", "d", "dtype")
+             if key in case}
     case.setdefault("canonical_mask", True)
     q, k, v = _raw_qkv(**shape)
-    out = {path: fa.flash_fwd(q, k, v, block_q=blocks[0], block_kv=blocks[1],
-                              scale=32 ** -0.5, _path=path, **case)
-           for path in ("resident", "streamed")}
+    case.update(block_q=blocks[0], block_kv=blocks[1], scale=q.shape[-1] ** -0.5)
     tol = 1e-2 if q.dtype == jnp.bfloat16 else 1e-6
-    np.testing.assert_allclose(np.asarray(out["resident"][0], np.float32),
-                               np.asarray(out["streamed"][0], np.float32), atol=tol)
-    np.testing.assert_allclose(np.asarray(out["resident"][1]),
-                               np.asarray(out["streamed"][1]), rtol=1e-6, atol=1e-5)
+    o, lse = fa.flash_fwd(q, k, v, _path="streamed", **case)
+    # a row with no key to see has lse at its floor and an o that depends on
+    # which tiles were live: compared, and handed on, as a merge would take it
+    seen = np.asarray(lse)[0, 0, 0] > -1e29
+    if kernels == "fwd":
+        o_r, lse_r = fa.flash_fwd(q, k, v, _path="resident", **case)
+        _close(o_r[:, :, seen], o[:, :, seen], "o", tol)
+        np.testing.assert_allclose(np.asarray(lse_r), np.asarray(lse), rtol=1e-6, atol=1e-5)
+        outs = [o_r, lse_r]
+    else:
+        g = jnp.cos(jax.random.normal(jax.random.PRNGKey(11), q.shape, q.dtype))
+        lse = jnp.where(lse > -1e29, lse, 0.0)  # ring attention's merged lse is finite
+        delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[:, :, None, :]
+        got = {path: (fa.flash_bwd_dq(q, k, v, g, lse, delta, _path=path, **case),
+                      *fa.flash_bwd_dkv(q, k, v, g, lse, delta, _path=path, **case))
+               for path in ("resident", "streamed")}
+        # sums of up to 512 products of O(1) terms in another order
+        for r, s, grad in zip(got["resident"], got["streamed"], ("dq", "dk", "dv")):
+            _close(r, s, grad, 2e-2 if q.dtype == jnp.bfloat16 else 2e-5)
+        outs = got["resident"]
     if name == "band_empty_ranges":
-        dead = np.arange(512) >= 256  # query tiles whose whole walk is empty
-        assert np.all(np.asarray(out["resident"][0])[:, :, dead] == 0)
-        assert np.all(np.asarray(out["resident"][1])[:, :, 0][:, :, dead] < -1e29)
+        # row - col < -384: only rows under 128 see anything, and only
+        # columns from 385 on are seen
+        dead_q, dead_kv = np.arange(512) >= 256, np.arange(512) < 256
+        if kernels == "fwd":
+            if blocks[0]:  # "auto" has one query tile, live
+                assert np.all(np.asarray(outs[0])[:, :, dead_q] == 0)
+            assert np.all(np.asarray(outs[1])[:, :, 0][:, :, dead_q] < -1e29)
+        else:
+            assert np.all(np.asarray(outs[0])[:, :, dead_q] == 0)
+            assert np.all(np.asarray(outs[1])[:, :, dead_kv] == 0)
+            assert np.all(np.asarray(outs[2])[:, :, dead_kv] == 0)
 
 
 @pytest.mark.parametrize("mask_type,sq,skv", [("full", 128, 512), ("causal", 512, 256)])
@@ -394,20 +435,29 @@ def test_unequal_lengths_match_reference(mask_type, sq, skv, flash_path):
     ("sliding_window", 1, 0), ("prefix_lm", 0, 130), ("prefix_lm", 0, 512),
     ("band", 64, 0), ("band", -384, 0), ("band", 700, 0)])
 @pytest.mark.parametrize("bq,bkv", [(128, 128), (64, 256), (256, 64)])
-def test_full_range_is_the_run_of_full_tiles(mask_type, window, prefix, bq, bkv):
-    """The resident walk's unmasked run [a, b), clamped into [lo, hi) as the
-    kernel clamps it, holds exactly the tiles _full_tile_fn calls full."""
+@pytest.mark.parametrize("axis", ["kv", "q"])
+def test_full_range_is_the_run_of_full_tiles(mask_type, window, prefix, bq, bkv, axis):
+    """A resident walk's unmasked run [a, b), clamped into [lo, hi) as the
+    kernels clamp it, holds exactly the tiles _full_tile_fn calls full: along
+    the KV axis for a query tile (forward, dQ), along the query axis for a KV
+    tile (dK/dV)."""
     S = 1024
     nq, nkv = S // bq, S // bkv
-    kv_lo, kv_hi = fa._kv_range(mask_type, window, prefix, bq, bkv, nkv)
     full = fa._full_tile_fn(mask_type, window, prefix, bq, bkv)
-    a_fn, b_fn = fa._full_range(mask_type, window, prefix, bq, bkv)
-    for qi in range(nq):
-        lo, hi = int(kv_lo(qi)), int(kv_hi(qi))
-        a = lo if a_fn is None else int(jnp.clip(a_fn(qi), lo, hi))
-        b = hi if b_fn is None else int(jnp.clip(b_fn(qi), a, hi))
+    if axis == "kv":
+        lo_fn, hi_fn = fa._kv_range(mask_type, window, prefix, bq, bkv, nkv)
+        a_fn, b_fn = fa._full_range(mask_type, window, prefix, bq, bkv)
+        n, is_full = nq, full
+    else:
+        lo_fn, hi_fn = fa._q_range(mask_type, window, prefix, bq, bkv, nq)
+        a_fn, b_fn = fa._full_range_q(mask_type, window, prefix, bq, bkv)
+        n, is_full = nkv, lambda ki, j: full(j, ki)
+    for i in range(n):
+        lo, hi = int(lo_fn(i)), int(hi_fn(i))
+        a = lo if a_fn is None else int(jnp.clip(a_fn(i), lo, hi))
+        b = hi if b_fn is None else int(jnp.clip(b_fn(i), a, hi))
         for j in range(lo, hi):
-            assert bool(full(qi, j)) == (a <= j < b), (qi, j, lo, a, b, hi)
+            assert bool(is_full(i, j)) == (a <= j < b), (i, j, lo, a, b, hi)
 
 
 def test_flash_plan_picks_by_shape():
@@ -443,5 +493,45 @@ def test_flash_plan_picks_by_shape():
     fa.flash_fwd(rq, rk, rv, mask_fn=M.causal(), block_q=128, block_kv=128,
                  _path="streamed")
     after = fa.plan_counts()
-    assert {p: after[p] - before[p] for p in after} == {
-        "resident": 1, "streamed": 1, "reference": 1}
+    assert {p: after[p] - before[p] for p in after if after[p] != before[p]} == {
+        "resident": 1, "streamed": 1, "reference": 1}  # no backward was traced
+
+
+@pytest.mark.parametrize("kernel", ["flash_bwd_dq", "flash_bwd_dkv"])
+def test_flash_plan_backward_at_the_budgets_edge(kernel):
+    """The backward kernels answer from the same budget: the bytes of the two
+    operands they hold (K and V for dQ; Q and dO, with the lse and delta
+    rows, for dK/dV) plus four float32 chunk arrays where the forward has
+    two, so a backward kernel never stays resident past the forward and may
+    leave it earlier; and every traced call is tallied by kernel and path."""
+    cell = fa.flash_plan(4096, 4096, 128, jnp.bfloat16, kernel=kernel)
+    assert cell.path == "resident"
+    assert (cell.block_q, cell.block_kv) == fa._RESIDENT_BLOCKS
+    sizes = [2 ** n for n in range(10, 17)]
+    edge = {kern: max(s for s in sizes
+                      if fa.flash_plan(s, s, 128, jnp.bfloat16, kernel=kern).path == "resident")
+            for kern in ("flash_fwd", kernel)}
+    assert 4096 < edge[kernel] <= edge["flash_fwd"]
+    past = fa.flash_plan(2 * edge[kernel], 2 * edge[kernel], 128, jnp.bfloat16, kernel=kernel)
+    assert (past.path, past.block_q, past.block_kv) == ("streamed", *fa._STREAMED_BLOCKS)
+    assert fa.flash_plan(edge[kernel], edge[kernel], 128, jnp.float32, kernel=kernel).path == "streamed"
+    # what a kernel holds is what counts: K/V for dQ, Q/dO for dK/dV
+    long, short = 2 * edge[kernel], 1024
+    held_long = dict(flash_bwd_dq=(short, long), flash_bwd_dkv=(long, short))[kernel]
+    assert fa.flash_plan(*held_long, 128, jnp.bfloat16, kernel=kernel).path == "streamed"
+    assert fa.flash_plan(*held_long[::-1], 128, jnp.bfloat16, kernel=kernel).path == "resident"
+    # a chunk's temporaries count: blocks too large for the budget stream
+    assert fa.flash_plan(edge[kernel], edge[kernel], 128, jnp.bfloat16, 2048, 2048,
+                         kernel=kernel).path == "streamed"
+    assert fa.flash_plan(192, 192, 32, jnp.float32, kernel=kernel).path == "reference"
+
+    before = fa.plan_counts()
+    q, k, v = _raw_qkv()
+    stat = jnp.zeros((1, 2, 1, 512), jnp.float32)
+    entry = getattr(fa, kernel)
+    entry(q, k, v, q, stat, stat, mask_fn=M.causal())
+    entry(q, k, v, q, stat, stat, mask_fn=M.causal(), _path="streamed")
+    after = fa.plan_counts()
+    short_name = kernel[len("flash_"):]
+    assert {p: after[p] - before[p] for p in after if after[p] != before[p]} == {
+        f"{short_name}_resident": 1, f"{short_name}_streamed": 1}

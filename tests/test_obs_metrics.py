@@ -456,10 +456,10 @@ def test_trainer_telemetry_end_to_end(tmp_path):
             tr._metrics_server.shutdown()
 
 
-def test_trainer_reports_the_flash_forward_plan(tmp_path):
-    """Which forward kernel the step's flash calls were traced to
-    (ops/flash_attention.py flash_plan) is written once: a log line after the
-    first compile, and ``flash_plan`` on the first step_window event alone."""
+def test_trainer_reports_the_flash_plan(tmp_path):
+    """Which path the step's flash kernels, forward and backward, were traced
+    to (ops/flash_attention.py flash_plan) is written once: a log line after
+    the first compile, and ``flash_plan`` on the first step_window event alone."""
     from mlx_cuda_distributed_pretraining_tpu.train.trainer import Trainer
 
     cfg = _tiny_config(tmp_path, name="flashplan", iters=10,
@@ -471,10 +471,14 @@ def test_trainer_reports_the_flash_forward_plan(tmp_path):
     assert len(windows) == 2
     plan = windows[0]["flash_plan"]
     assert plan["resident"] > 0 and plan["streamed"] == 0 and plan["reference"] == 0
+    # one dQ and one dK/dV call a traced forward that is differentiated
+    assert plan["bwd_dq_resident"] == plan["bwd_dkv_resident"] > 0
+    assert plan["bwd_dq_streamed"] == plan["bwd_dkv_streamed"] == 0
     assert "flash_plan" not in windows[1]
     with open(tr.logger.log_path) as f:
-        lines = [ln for ln in f if "flash forward plan" in ln]
-    assert len(lines) == 1 and f"resident={plan['resident']}" in lines[0]
+        lines = [ln for ln in f if "flash plan" in ln]
+    assert len(lines) == 1
+    assert all(f"{key}={n}" in lines[0] for key, n in plan.items())
 
 
 def test_trainer_registry_replays_on_construction(tmp_path):

@@ -1,7 +1,7 @@
 """Ring attention (sequence/context parallelism) on the virtual 8-CPU mesh:
 exact parity with single-device attention, gradients included. A test that
-takes ``flash_path`` (conftest.py) runs its per-chunk flash_fwd calls under
-both forward kernels, resident and streamed."""
+takes ``flash_path`` (conftest.py) runs its per-chunk kernel calls, forward
+and backward, under both paths, resident and streamed."""
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +70,45 @@ def test_ring_gradients_match(flash_path):
     gf = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gr, gf):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("mask", ["causal", "sliding_window"])
+@pytest.mark.parametrize("named", [None, (16, 8)], ids=["plan", "named"])
+def test_ring_chunks_take_the_plans_blocks(mask, named, monkeypatch):
+    """Ring attention names no block of its own: its chunk calls hand all
+    three kernels ``None`` and so get ``flash_plan``'s blocks for the chunk's
+    shape, and a block its caller names reaches all three."""
+    import functools
+
+    from mlx_cuda_distributed_pretraining_tpu.ops import flash_attention as fa
+
+    seen = []
+
+    def recording(entry):
+        raw = getattr(fa, entry)
+
+        @functools.wraps(raw)
+        def call(*args, **kw):
+            seen.append((entry, kw["block_q"], kw["block_kv"]))
+            return raw(*args, **kw)
+
+        return call
+
+    for entry in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        monkeypatch.setattr(fa, entry, recording(entry))
+    mesh = _mesh({"sp": 4})
+    q, k, v = _qkv(s=128)
+    mask_mod = M.causal() if mask == "causal" else M.sliding_window(40)
+    kw = dict(block_q=named[0], block_kv=named[1]) if named else {}
+    ring = make_ring_attention(mesh, mask_mod=mask_mod, **kw)
+    grads = jax.jit(jax.grad(lambda q, k, v: jnp.sum(ring(q, k, v) ** 2),
+                             argnums=(0, 1, 2)))(q, k, v)
+    ref = jax.grad(lambda q, k, v: jnp.sum(
+        reference_attention(q, k, v, mask_mod=mask_mod) ** 2), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(grads, ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
+    assert {entry for entry, _, _ in seen} == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    assert {(bq, bkv) for _, bq, bkv in seen} == {named or (None, None)}
 
 
 @pytest.mark.slow

@@ -190,10 +190,10 @@ def test_named_scopes_in_the_program_are_the_vocabulary():
             # a pallas_call's name= is also the innermost scope of its ops
             kernels += re.findall(r'interpret=_interpret\(\),\n\s+name="(\w+)",', text)
             pallas_calls += text.count("pl.pallas_call(")
-    # flash_fwd twice: the resident and the streamed forward share the name
-    # the trace's reader keys on (plan_counts() tells them apart)
-    assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd", "flash_fwd",
-                               "gmm", "tgmm"]
+    # each flash kernel twice: its resident and its streamed path share the
+    # name the trace's reader keys on (plan_counts() tells them apart)
+    assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dkv", "flash_bwd_dq",
+                               "flash_bwd_dq", "flash_fwd", "flash_fwd", "gmm", "tgmm"]
     assert pallas_calls == len(kernels), "a pallas_call without a name="
     assert found | set(kernels) == VOCABULARY
 

@@ -121,23 +121,55 @@ def _largest_resident_seq(D=128, dtype=jnp.bfloat16):
                if fa.flash_plan(s, s, D, dtype).path == "resident")
 
 
+def _path_case(name, v5e):
+    """``(q, kv, want)`` of a size that decides the plan: the benchmark cell's
+    shape, the longest sequence the resident budget admits, and twice that."""
+    edge = _largest_resident_seq()
+    B, S = {"cell": (CELL["B"], CELL["S"]), "largest_resident": (1, edge),
+            "first_streamed": (1, 2 * edge)}[name]
+    q = _sds((B, S, CELL["Hq"], CELL["D"]), jnp.bfloat16, v5e)
+    kv = _sds((B, S, CELL["Hkv"], CELL["D"]), jnp.bfloat16, v5e)
+    return q, kv, "streamed" if name == "first_streamed" else "resident"
+
+
 @pytest.mark.parametrize("name", ["cell", "largest_resident", "first_streamed"])
 def test_flash_forward_paths_compile_for_v5e(name, v5e, compiled_kernels):
     """The forward ``flash_plan`` picks, at the sizes that decide it: the
     benchmark cell's shape (K/V resident in VMEM), the longest sequence the
     resident budget admits (a budget Mosaic's scoped VMEM limit refuses
     fails here), and twice that, the first one streamed through the grid."""
-    edge = _largest_resident_seq()
-    B, S = {"cell": (CELL["B"], CELL["S"]), "largest_resident": (1, edge),
-            "first_streamed": (1, 2 * edge)}[name]
-    want = "streamed" if name == "first_streamed" else "resident"
-    assert fa.flash_plan(S, S, CELL["D"], jnp.bfloat16).path == want
-    q = _sds((B, S, CELL["Hq"], CELL["D"]), jnp.bfloat16, v5e)
-    kv = _sds((B, S, CELL["Hkv"], CELL["D"]), jnp.bfloat16, v5e)
+    q, kv, want = _path_case(name, v5e)
+    assert fa.flash_plan(q.shape[1], q.shape[1], CELL["D"], jnp.bfloat16).path == want
     before = fa.plan_counts()
     hlo = jax.jit(fa.flash_attention).lower(q, kv, kv).compile().as_text()
     assert hlo.count("tpu_custom_call") >= 1
     assert fa.plan_counts()[want] == before[want] + 1
+
+
+@pytest.mark.parametrize("name", ["cell", "largest_resident", "first_streamed"])
+def test_flash_backward_paths_compile_for_v5e(name, v5e, compiled_kernels):
+    """The same three sizes through the backward: dQ with K/V resident and
+    dK/dV with Q/dO resident compile at the cell's shape and at the longest
+    length their budget admits (four float32 chunk arrays and, in dK/dV, the
+    lse and delta rows beside the held operands: what Mosaic's scoped VMEM
+    limit refuses fails here), the streamed pair past it, and the tally says
+    which ran."""
+    q, kv, want = _path_case(name, v5e)
+    S = q.shape[1]
+    for kernel in ("flash_bwd_dq", "flash_bwd_dkv"):
+        assert fa.flash_plan(S, S, CELL["D"], jnp.bfloat16, kernel=kernel).path == want
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    before = fa.plan_counts()
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= 3
+    after = fa.plan_counts()
+    other = "resident" if want == "streamed" else "streamed"
+    for kernel in ("bwd_dq", "bwd_dkv"):
+        assert after[f"{kernel}_{want}"] == before[f"{kernel}_{want}"] + 1
+        assert after[f"{kernel}_{other}"] == before[f"{kernel}_{other}"]
 
 
 @pytest.mark.parametrize("shape", ["recipe_1b", "cell"])
@@ -167,6 +199,9 @@ def test_flash_under_fsdp_mesh_compiles_for_v5e(shape, v5e_devices, compiled_ker
     assert "all-gather" not in hlo and "all-reduce" not in hlo
     after = fa.plan_counts()
     assert after["resident"] > before["resident"] and after["streamed"] == before["streamed"]
+    for kernel in ("bwd_dq", "bwd_dkv"):
+        assert after[f"{kernel}_resident"] == before[f"{kernel}_resident"] + 1
+        assert after[f"{kernel}_streamed"] == before[f"{kernel}_streamed"]
 
 
 # OLMoE's expert shapes (ROADMAP R1): 64 experts of width 1024 on h2048,
