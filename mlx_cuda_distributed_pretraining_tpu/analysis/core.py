@@ -465,7 +465,7 @@ def classify_findings(findings: Sequence[Finding],
 
 def result_to_json(tool: str, result: LintResult) -> Dict[str, Any]:
     """The stable machine-readable document both CLIs emit under
-    ``--format json`` and bench.py's gate consumes. Top-level keys
+    ``--format json``. Top-level keys
     ``new``/``baselined``/``suppressed``/``stale_baseline`` are kept for
     existing consumers; ``findings`` is the flat per-finding schema
     (rule, path, line, col, message, baselined, suppressed)."""

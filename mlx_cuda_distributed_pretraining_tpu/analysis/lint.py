@@ -5,8 +5,8 @@
 Lints ``paths`` (files or directories; default: the package itself) with
 every registered rule, subtracts inline suppressions and the committed
 baseline, and exits nonzero when any NEW finding remains. ``--format
-json`` emits one machine-readable document (used by tests and the
-bench.py gate); ``--write-baseline`` regenerates the baseline from the
+json`` emits one machine-readable document (used by the tests);
+``--write-baseline`` regenerates the baseline from the
 current findings, preserving the reasons of entries that still match.
 
 Stale-baseline hygiene: a full-package run that finds baseline entries

@@ -36,7 +36,7 @@ def default_sync_baseline_path() -> str:
 
 
 def run_sync(paths: List[str], baseline=None):
-    """In-process entry point (bench.py gate / tests): graftlint's
+    """In-process entry point (the tests'): graftlint's
     runner with the sync rule registry and the graftsync comment tag."""
     return run_lint(paths, baseline=baseline, rules=all_sync_rules(),
                     suppress_re=SYNC_SUPPRESS_RE)

@@ -331,7 +331,7 @@ class SystemConfig:
     # warmup/drain ticks via lax.cond: per-step slab applications drop
     # from P*(V*M+P-1) to exactly P*V*M, forward and backward. False
     # reproduces the original every-tick schedule bit-identically — only
-    # useful for apples-to-apples benches.
+    # useful for apples-to-apples comparisons.
     pipeline_compute_skip: bool = True
     # Fused chunked cross-entropy (ops/fused_ce.py): rows per chunk.
     # 0 = always materialize full logits; -1 = auto (enable when the
@@ -350,15 +350,6 @@ class SystemConfig:
     # compile-time saver at 400M-1B. Training path only; under
     # pipeline parallelism pp stacks layers itself.
     scan_layers: bool = False
-    # Train K steps per device dispatch (lax.scan over the jitted step,
-    # batches stacked [K, B, L]). Each dispatch pays a fixed host-side
-    # cost, which K steps share; how much that buys on a locally attached
-    # chip is not measured. Checkpoints,
-    # validation, and profiler windows stay exact: the trainer shrinks a
-    # group so it never straddles an interval boundary. Per-step losses
-    # still come back (scan stacks the metrics); preemption latency grows
-    # to at most K steps. Not supported under pipeline parallelism.
-    steps_per_dispatch: int = 1
     # XLA scheduling flags (parallel/xla_flags.py)::
     #
     #   xla:
@@ -367,7 +358,7 @@ class SystemConfig:
     #
     # The named set resolves per backend (CPU resolves empty — XLA:CPU
     # has no latency-hiding scheduler), is applied before the backend
-    # initializes, and is stamped into events.jsonl / bench rows.
+    # initializes, and is stamped into events.jsonl.
     xla: Dict[str, Any] = field(default_factory=dict)
     # Manual comm/compute overlap (parallel/overlap.py): under a pure
     # dp×fsdp mesh with scan_layers, all-gather the NEXT layer's

@@ -36,11 +36,11 @@ from __future__ import annotations
 import queue
 import threading
 import time
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec
+from jax.sharding import NamedSharding
 
 from ..parallel.sharding_rules import batch_pspec
 
@@ -48,18 +48,9 @@ from ..parallel.sharding_rules import batch_pspec
 class DevicePrefetcher:
     """Wraps a host loader and serves device-resident, pre-sharded batches.
 
-    Single-step mode (``group_len_fn=None``): ``get()`` returns
-    ``(device_batch, local_tokens, waits)`` for data steps ``start_step``,
-    ``start_step+1``, ... — matching the trainer's
+    ``get()`` returns ``(device_batch, local_tokens, waits)`` for data
+    steps ``start_step``, ``start_step+1``, ... — matching the trainer's
     ``generate_batch(step - 1)`` convention.
-
-    Group mode (``steps_per_dispatch > 1``): ``group_len_fn(step)`` gives
-    the dispatch-group length at each group-start step (the trainer passes
-    ``_dispatch_group_len`` so groups land on exactly the same boundaries
-    as before); ``get()`` returns a stacked ``[K, B, L]`` batch and a list
-    of per-step token counts. A StopIteration mid-group yields the fetched
-    prefix, then end-of-stream on the next ``get()`` — same prefix-dispatch
-    semantics as the old inline loop.
     """
 
     def __init__(
@@ -69,14 +60,12 @@ class DevicePrefetcher:
         depth: int = 2,
         start_step: int = 0,
         total_steps: Optional[int] = None,
-        group_len_fn: Optional[Callable[[int], int]] = None,
         metrics: Any = None,
     ):
         self.loader = loader
         self.mesh = mesh
         self.depth = int(depth)
         self.total_steps = total_steps  # None: run until StopIteration
-        self.group_len_fn = group_len_fn
         # Optional obs.MetricsRegistry: input-pipeline health lands in the
         # same registry the trainer exports (counters/histograms, no dicts).
         self._m_batches = self._m_queue = self._m_data_wait = self._m_h2d = None
@@ -97,20 +86,9 @@ class DevicePrefetcher:
         self._consumed_state: Optional[Dict[str, Any]] = None  # graftsync: owner=trainer-thread
 
         self._sharding = None
-        self._group_sharding = None
         if mesh is not None:
-            bp = batch_pspec(mesh)
-            self._sharding = NamedSharding(mesh, bp)
-            # Group batches are [K, B, L]: step axis unsharded, matching
-            # make_multi_step's batch_shardings (train/train_step.py).
-            self._group_sharding = NamedSharding(mesh, PartitionSpec(None, *bp))
+            self._sharding = NamedSharding(mesh, batch_pspec(mesh))
 
-        # Group-stacking buffers are reused across groups ONLY when the
-        # transfer is a real copy (TPU/GPU HBM). CPU jax.device_put can be
-        # zero-copy — the device array aliases the host buffer, and a
-        # refill would corrupt a group still in flight.
-        self._reuse_group_bufs = jax.default_backend() != "cpu"
-        self._group_bufs: Dict[int, Dict[str, np.ndarray]] = {}  # graftsync: owner=prefetch-worker
         # next trainer step to feed
         self._cursor = int(start_step) + 1  # graftsync: owner=prefetch-worker
         self._done = False  # graftsync: owner=prefetch-worker
@@ -137,53 +115,42 @@ class DevicePrefetcher:
     # -- producer ------------------------------------------------------------
 
     def _produce_one(self) -> Dict[str, Any]:
-        """Fetch the next (group of) host batch(es), transfer, advance the
-        cursor. Returns a queue item; never raises (errors become items so
-        they surface at the consumer's ``get()``, not in the thread)."""
+        """Fetch the next host batch, transfer, advance the cursor.
+        Returns a queue item; never raises (errors become items so they
+        surface at the consumer's ``get()``, not in the thread)."""
         if self._done or (
                 self.total_steps is not None and self._cursor > self.total_steps):
             self._done = True
             return {"kind": "end"}
         step = self._cursor
-        glen = 1 if self.group_len_fn is None else max(1, int(self.group_len_fn(step)))
-        batches = []
         snapshot = None
-        exhausted = False
         t0 = time.perf_counter()
         try:
-            for i in range(glen):
-                batches.append(self.loader.generate_batch(step - 1 + i))
-                if self._stateful:
-                    snapshot = self.loader.state_dict()
+            batch = self.loader.generate_batch(step - 1)
+            if self._stateful:
+                snapshot = self.loader.state_dict()
         except StopIteration:
-            exhausted = True
+            self._done = True
+            return {"kind": "end"}
         except Exception as exc:  # producer errors (e.g. streaming RuntimeError)
             self._done = True
             return {"kind": "error", "error": exc}
         fetch_s = time.perf_counter() - t0
-        if not batches:
-            self._done = True
-            return {"kind": "end"}
-        # Host-side token counts (non-pad targets) — off the critical path
+        # Host-side token count (non-pad targets) — off the critical path
         # here, so tok/s stays correct even though device metrics are only
         # read every logging_interval steps.
-        tokens = [int(b["mask"].sum()) for b in batches]
+        tokens = int(batch["mask"].sum())
         t0 = time.perf_counter()
-        if self.group_len_fn is not None:
-            dev = self._transfer(self._fill_group_buffers(batches), self._group_sharding)
-        else:
-            dev = self._transfer(batches[0], self._sharding)
-        # Block HERE, in the worker: the consumer's get() never waits on the
-        # copy, and the preallocated group buffers are free for reuse.
+        dev = self._transfer(batch, self._sharding)
+        # Block HERE, in the worker: the consumer's get() never waits on
+        # the copy.
         jax.block_until_ready(dev)
         h2d_s = time.perf_counter() - t0
-        self._cursor = step + len(batches)
-        if exhausted:
-            self._done = True
+        self._cursor = step + 1
         return {
             "kind": "batch",
             "batch": dev,
-            "tokens": tokens if self.group_len_fn is not None else tokens[0],
+            "tokens": tokens,
             "snapshot": snapshot,
             "fetch_s": fetch_s,
             "h2d_s": h2d_s,
@@ -199,23 +166,6 @@ class DevicePrefetcher:
         if sharding is not None:
             return jax.device_put(host_batch, sharding)
         return jax.device_put(host_batch)
-
-    def _fill_group_buffers(self, batches):
-        """Stack a dispatch group into ``[K, B, L]`` buffers preallocated
-        once per group length and filled in place (``np.stack`` allocates a
-        fresh array every group). Reuse is safe because ``_produce_one``
-        blocks on the transfer before the next fill of the same buffer."""
-        glen = len(batches)
-        bufs = self._group_bufs.get(glen) if self._reuse_group_bufs else None
-        if bufs is None:
-            bufs = {k: np.empty((glen,) + np.shape(v), np.asarray(v).dtype)
-                    for k, v in batches[0].items()}
-            if self._reuse_group_bufs:
-                self._group_bufs[glen] = bufs
-        for i, b in enumerate(batches):
-            for k, v in b.items():
-                bufs[k][i] = v
-        return bufs
 
     def _worker(self) -> None:  # graftsync: owner=prefetch-worker
         while not self._stop_evt.is_set():
@@ -234,13 +184,12 @@ class DevicePrefetcher:
     def get(self):  # graftsync: owner=trainer-thread
         """Next device-resident batch: ``(batch, tokens, waits)``.
 
-        ``tokens`` is this host's non-pad target count (an int, or a list
-        of per-step ints in group mode). ``waits`` carries ``data_wait_s``
-        (time this call blocked waiting for input — the true stall) and
-        ``h2d_wait_s`` (host→device transfer time for the item: overlapped
-        with compute when the worker thread is running, on the critical
-        path in synchronous mode). Raises StopIteration at end of stream;
-        re-raises loader errors.
+        ``tokens`` is this host's non-pad target count. ``waits`` carries
+        ``data_wait_s`` (time this call blocked waiting for input — the
+        true stall) and ``h2d_wait_s`` (host→device transfer time for the
+        item: overlapped with compute when the worker thread is running, on
+        the critical path in synchronous mode). Raises StopIteration at end
+        of stream; re-raises loader errors.
         """
         if self._terminal is not None:
             item = self._terminal

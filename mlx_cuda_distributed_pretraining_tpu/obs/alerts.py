@@ -5,7 +5,7 @@ rule into a small state machine evaluated against the graftscope TSDB
 (obs/tsdb.py) every collection round.  The grammar is deliberately tiny —
 eight rule kinds cover every SLO and training-anomaly alert the ROADMAP
 asks for — and every rule is validated up front (scripts/lint.sh
-LINT_ALERTS, bench.py gate) so a typo'd metric name or a dangling capture
+LINT_ALERTS) so a typo'd metric name or a dangling capture
 action fails in CI rather than silently never firing in production.
 
 Rule kinds:
@@ -21,8 +21,8 @@ Rule kinds:
   goodput_floor    share of goodput_seconds_total in good components
   zscore           newest sample vs trailing mean/std (loss spike)
   nonfinite        NaN/Inf sample, or any increase of a *_total sentinel
-  baseline_drop    windowed average vs the committed bench_baseline.json
-                   (MFU collapse)
+  baseline_drop    windowed average vs a value pinned in a baseline file
+                   the operator names
   flap             count of value transitions in a window (breaker flaps)
 
 States follow the Prometheus convention: ``inactive`` → ``pending``

@@ -20,9 +20,8 @@ stream the (active) weight plane from HBM, so the decode roofline is
 ``HBM bytes/s / weight bytes per token``. :func:`weight_bytes_per_token`
 models that byte cost per serving ``weight_dtype`` (fp / weight-only
 int8 / packed int4 + per-channel scales) and
-:func:`decode_roofline_tok_s` turns it into the tok/s ceiling the
-perf-gate compares measured decode rates against — the analytic
-justification for the int8 ≥ 1.5x acceptance bar.
+:func:`decode_roofline_tok_s` turns it into the tok/s ceiling of a
+bandwidth-bound decode.
 
 The goodput ledger answers "where did the wall clock go": every logging
 window books seconds into named components (compile, data wait, H2D
@@ -70,8 +69,6 @@ def flops_per_token(n_params: int, num_layers: int, seq_len: int,
     """Analytic train-step FLOPs per token: 6N matmul + attention term.
 
     ``d_attn`` is the total attention width ``num_heads * head_dim``.
-    Identical to the bench.py accounting so BENCH rows and log-line MFU
-    agree by construction.
     """
     return 6.0 * float(n_params) + 6.0 * float(num_layers) * float(seq_len) * float(d_attn)
 
@@ -124,8 +121,8 @@ def matmul_params(model_cfg: Any, n_params: int,
 def model_flops_per_token(model_cfg: Any, n_params: int, seq_len: int,
                           vocab_size: Optional[int] = None) -> float:
     """FLOPs/token of a training step: ``6 * matmul_params`` (see there for
-    what counts) plus the attention term, so ``mfu=`` on window lines and
-    bench rows reflects work the model requires."""
+    what counts) plus the attention term, so ``mfu=`` on window lines
+    reflects work the model requires."""
     d_attn = int(model_cfg.num_heads) * int(model_cfg.head_dim)
     return flops_per_token(matmul_params(model_cfg, n_params, vocab_size),
                            int(model_cfg.num_layers), int(seq_len), d_attn)
@@ -297,7 +294,7 @@ def pipeline_executed_flops_ratio(pp: int, microbatches: int,
     chips burn ``(V*M + P - 1) / (V*M)`` times the useful FLOPs — strictly
     worse than an idle bubble. MFU never credits the excess (see
     :func:`mfu`); this ratio is the honest "what did the hardware do"
-    multiplier for bench rows and capacity planning.
+    multiplier for capacity planning.
     """
     if compute_skip:
         return 1.0

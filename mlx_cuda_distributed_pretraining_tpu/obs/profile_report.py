@@ -53,7 +53,7 @@ from typing import Any, Dict, List, Optional, Tuple
 REPORT_VERSION = 1
 SUMMARY_FILENAME = "prof_summary.json"
 
-# Fraction gauge / event-field / bench-column names, in reporting order.
+# Fraction gauge / event-field names, in reporting order.
 PROF_FIELDS = ("prof_compute_frac", "prof_comm_frac",
                "prof_overlap_frac", "prof_idle_frac")
 
@@ -509,8 +509,8 @@ def generate_report(dump_or_file: str,
 
 
 def prof_fields(report: Dict[str, Any], digits: int = 4) -> Dict[str, float]:
-    """The four headline fractions under their gauge / event-field /
-    bench-column names (PROF_FIELDS)."""
+    """The four headline fractions under their gauge / event-field
+    names (PROF_FIELDS)."""
     agg = report["aggregate"]
     return {
         "prof_compute_frac": round(agg["compute_frac"], digits),

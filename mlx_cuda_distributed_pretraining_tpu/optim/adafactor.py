@@ -2,8 +2,8 @@
 
 Not in the reference (its optimizer set is adam/adamw/sgd/lion/muon/
 shampoo/hybrid): added because Adafactor is THE TPU-native answer to
-optimizer-state HBM pressure — the motivating case here is the 1B bench
-row, where AdamW's fp32 m+v alone is ~7.7 GB of the 16 GB chip while
+optimizer-state HBM pressure — the motivating case here is a 1B model
+on one chip, where AdamW's fp32 m+v alone is ~7.7 GB of the 16 GB chip while
 Adafactor's factored second moments for a [V, D] or [D, I] matrix are one
 row vector + one column vector (~KBs). With it, 1B-on-one-chip trains
 with batch headroom instead of at the OOM edge.

@@ -301,7 +301,7 @@ def make_pipeline_loss(
     scanned VJP, backward. Numerics are unchanged: non-working outputs were
     already masked out of the loss, so skip on/off differ only in wasted
     compute. ``compute_skip=False`` reproduces the original schedule (every
-    tick applies the chunk to masked garbage) for apples-to-apples benches.
+    tick applies the chunk to masked garbage) for apples-to-apples comparisons.
 
     ``with_moe_stats`` threads MoE routing stats (``moe_load`` [E] /
     ``moe_dropped``) through the tick carries and returns
@@ -313,7 +313,7 @@ def make_pipeline_loss(
     the schedule EXECUTED, summed over stages: an int32 carried through the
     ticks and incremented inside the cond's work branch, so it is evidence
     that compute-skip skips (``P*(V*M + P-1)`` falls to ``P*V*M``), not the
-    formula restated. Tests and the pp bench ask for it; training does not.
+    formula restated. The tests ask for it; training does not.
     """
     if getattr(args, "attention_type", "simple") == "ring":
         raise ValueError("ring (sp) attention inside a pipeline stage is not supported")

@@ -5,7 +5,7 @@ and async-collective lowering overlap the fsdp param all-gathers and the
 gradient reduce-scatter with surrounding matmuls — but only when the
 right backend flags are set *before the backend initializes*, and a
 silently dropped flag set is indistinguishable from a scheduling
-regression in a bench row. So flag sets are:
+regression. So flag sets are:
 
 - **named** — configs request ``system.xla.flag_set: latency_hiding``
   rather than carrying raw flag strings;
@@ -14,8 +14,8 @@ regression in a bench row. So flag sets are:
   current backend understands (CPU resolves to the empty set: XLA:CPU
   has no latency-hiding scheduler and every collective is synchronous);
 - **stamped** — :func:`apply_flag_set` returns a JSON-able stamp that the
-  trainer writes into the ``run_start`` event and bench writes into every
-  row, so every number is attributable to its flag set; and
+  trainer writes into the ``run_start`` event, so every run is
+  attributable to its flag set; and
 - **audited** — analysis/audit_rules.py's dropped-flag-set rule compares
   a program's requested set against the environment it was actually
   lowered under (:func:`missing_flags`), catching the

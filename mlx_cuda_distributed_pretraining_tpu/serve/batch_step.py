@@ -446,7 +446,7 @@ def _paged_gather(layer_cache, tables, nb):
 
 
 def paged_decode_step(args: llama.LlamaArgs, draft_len: int, attend_len: int,
-                      table_width: int, block_size: int, raw: bool = False,
+                      table_width: int, block_size: int,
                       mesh: Optional[Mesh] = None):
     """Compiled once per (args, draft_len, attend bucket, table shape, mesh).
 
@@ -470,12 +470,9 @@ def paged_decode_step(args: llama.LlamaArgs, draft_len: int, attend_len: int,
     vmapped over rows with per-row temperature). The host picks per row:
     greedy rows use preds, sampled rows use accept/alts/bonus. With
     ``draft_len == 0`` the S axis is 1 and this is plain paged decode.
-
-    ``raw=True`` returns the un-jitted function (for embedding in a
-    caller's own jit, e.g. the bench decode chain).
     """
     key_ = ("paged_decode", args, draft_len, attend_len, table_width,
-            block_size, raw, mesh)
+            block_size, mesh)
     if key_ in _STEP_CACHE:
         return _STEP_CACHE[key_]
 
@@ -564,8 +561,7 @@ def paged_decode_step(args: llama.LlamaArgs, draft_len: int, attend_len: int,
         return (preds, lp_preds, accept, alts, lp_draft, lp_alt,
                 bonus, lp_bonus, new_keys)
 
-    fn = paged_decode_step if raw else partial(
-        jax.jit, donate_argnums=_donate_cache())(paged_decode_step)
+    fn = partial(jax.jit, donate_argnums=_donate_cache())(paged_decode_step)
     _STEP_CACHE[key_] = fn
     return fn
 

@@ -40,9 +40,7 @@ phases across POOLS of replicas (DistServe/Splitwise):
   promoted. A canary error halts the rollout with the rest of the fleet
   untouched.
 
-``scripts/serve_stack.sh --fleet`` launches a local fleet; the
-``serve_fleet`` bench case races a 1+1 disaggregated fleet against a
-2-replica homogeneous baseline under a mixed prefill/decode flood.
+``scripts/serve_stack.sh --fleet`` launches a local fleet.
 """
 
 from __future__ import annotations

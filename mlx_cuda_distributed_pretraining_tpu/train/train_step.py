@@ -155,57 +155,6 @@ def make_train_step(
     return step_fn, shardings
 
 
-def make_multi_step(
-    loss_fn: Callable,
-    optimizer: Transform,
-    accum_steps: int = 1,
-    mesh: Optional[Mesh] = None,
-    zero_level: int = 0,
-    log_grad_norm: bool = False,
-    params_like: Optional[Any] = None,
-    moe_stats_experts: int = 0,
-) -> Tuple[Callable, Optional[Any]]:
-    """K train steps per device dispatch (``system.steps_per_dispatch``).
-
-    ``multi_fn(state, batches) -> (state, metrics)`` where every batch
-    leaf is stacked ``[K, B, L]`` and every metric comes back stacked
-    ``[K]`` — the scan preserves per-step losses, so logging stays exact.
-    Each dispatch pays a fixed host-side cost; compiling K steps into one
-    ``lax.scan`` dispatch shares it K ways with bit-identical math (the
-    schedule counter
-    lives in opt_state, so K scanned updates == K dispatched updates).
-    K is taken from the leading batch axis: one compile per distinct
-    group length (the trainer clamps groups at interval boundaries, so
-    only a handful of lengths ever occur).
-    """
-    # The scan body calls the JITTED single step: jax inlines a jitted
-    # function when it is traced inside another jit, so this reuses
-    # make_train_step's exact body (no drift) with no dispatch overhead.
-    single, shardings = make_train_step(
-        loss_fn, optimizer, accum_steps=accum_steps, mesh=mesh,
-        zero_level=zero_level, log_grad_norm=log_grad_norm,
-        params_like=params_like, moe_stats_experts=moe_stats_experts)
-
-    def multi_step(state, batches):
-        def body(s, b):
-            return single(s, b)
-        return jax.lax.scan(body, state, batches)
-
-    if mesh is None:
-        return jax.jit(multi_step, donate_argnums=donate_argnums(0)), None
-
-    bp = batch_pspec(mesh)
-    b_shard = NamedSharding(mesh, jax.sharding.PartitionSpec(None, *bp))
-    batch_shardings = {"inputs": b_shard, "targets": b_shard, "mask": b_shard}
-    multi_fn = jax.jit(
-        multi_step,
-        donate_argnums=donate_argnums(0),
-        in_shardings=(shardings, batch_shardings),
-        out_shardings=(shardings, None),
-    )
-    return multi_fn, shardings
-
-
 def make_eval_step(loss_fn: Callable, mesh: Optional[Mesh] = None,
                    state_shardings: Optional[Any] = None) -> Callable:
     """Jitted ``(params, batch) -> (loss, token_count)`` (token-weighted val

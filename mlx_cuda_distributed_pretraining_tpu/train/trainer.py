@@ -331,18 +331,6 @@ class Trainer:
         )
         self.pipeline_interleave = 1
         self.pipeline_compute_skip = True
-        # K train steps per device dispatch (see SystemConfig). Pipeline
-        # builds its own step; K>1 is a dense/sharded-step feature.
-        self.steps_per_dispatch = max(1, int(
-            getattr(cfg.system, "steps_per_dispatch", 1) or 1))
-        self.train_multi_step = None
-        if self.pipeline and self.steps_per_dispatch > 1:
-            raise ValueError(
-                "system.steps_per_dispatch > 1 is not supported with "
-                "pipeline parallelism (system.mesh.pp > 1): the GPipe step "
-                "already amortizes dispatches over microbatches — set "
-                "steps_per_dispatch: 1"
-            )
         if self.pipeline:
             from ..parallel.pipeline import (
                 make_pipeline_loss,
@@ -407,18 +395,6 @@ class Trainer:
                 params_like=self.params_like,
                 moe_stats_experts=self.moe_stats_experts,
             )
-            if self.steps_per_dispatch > 1:
-                from .train_step import make_multi_step
-
-                self.train_multi_step, _ = make_multi_step(
-                    self.loss_fn, self.optimizer,
-                    accum_steps=self.accum_steps,
-                    mesh=self.mesh,
-                    zero_level=cfg.system.zero_optimization_level,
-                    log_grad_norm=cfg.logging.log_gradient_norm,
-                    params_like=self.params_like,
-                    moe_stats_experts=self.moe_stats_experts,
-                )
             self.eval_step = make_eval_step(self.eval_loss_fn, self.mesh, self.state_shardings)
 
             self.state = init_train_state(params, self.optimizer)
@@ -1055,41 +1031,12 @@ class Trainer:
             params_like=self.params_like,
             moe_stats_experts=self.moe_stats_experts,
         )
-        if self.steps_per_dispatch > 1:
-            from .train_step import make_multi_step
-
-            self.train_multi_step, _ = make_multi_step(
-                self.loss_fn, self.optimizer,
-                accum_steps=self.accum_steps,
-                mesh=self.mesh,
-                zero_level=self.config.system.zero_optimization_level,
-                log_grad_norm=self.config.logging.log_gradient_norm,
-                params_like=self.params_like,
-                moe_stats_experts=self.moe_stats_experts,
-            )
         self.state = init_train_state(self.state["params"], self.optimizer)
         if self.mesh is not None and self.state_shardings is not None:
             self.state = _put_tree(self.state, self.state_shardings)
         return suggested
 
     # -- the loop -----------------------------------------------------------
-    def _dispatch_group_len(self, step: int, val_int, ckpt_int,
-                            prof_start: int, prof_stop: int) -> int:
-        """Steps to run in this dispatch group: at most steps_per_dispatch,
-        never past total_steps, never straddling a validation/checkpoint
-        step (events fire at group end) or a profiler window boundary
-        (traces must toggle between dispatches)."""
-        end = min(step + self.steps_per_dispatch - 1, self.total_steps)
-        for intv in (val_int, ckpt_int):
-            if intv:
-                nxt = ((step + intv - 1) // intv) * intv
-                end = min(end, nxt)
-        if prof_stop > prof_start:
-            for b in (prof_start, prof_stop):
-                if b > step:
-                    end = min(end, b - 1)
-        return max(1, end - step + 1)
-
     def train(self) -> Dict[str, Any]:
         cfg = self.config
         train_t0 = time.perf_counter()
@@ -1136,22 +1083,13 @@ class Trainer:
         # Device-side input pipeline: a background worker keeps
         # data.prefetch_depth batches resident on device, pre-sharded to the
         # jitted step's expected layout, so the loop below never blocks on a
-        # host->device copy (data/device_prefetch.py). In group mode the
-        # worker computes dispatch-group boundaries with the same
-        # _dispatch_group_len the loop uses, so group/interval semantics
-        # are unchanged.
-        group_len_fn = None
-        if self.steps_per_dispatch > 1:
-            def group_len_fn(s):
-                return self._dispatch_group_len(
-                    s, val_int, ckpt_int, prof_start, prof_stop)
+        # host->device copy (data/device_prefetch.py).
         self.prefetcher = DevicePrefetcher(
             self.data,
             mesh=self.mesh,
             depth=int(getattr(cfg.data, "prefetch_depth", 2)),
             start_step=self.start_step,
             total_steps=self.total_steps,
-            group_len_fn=group_len_fn,
             metrics=self.metrics,
         )
 
@@ -1217,14 +1155,6 @@ class Trainer:
         except (ValueError, OSError):  # non-main thread: no signal hooks
             prev_handlers = {}
 
-        # steps_per_dispatch>1: each dispatch runs a GROUP of steps via
-        # lax.scan (make_multi_step) and the per-step loop below consumes
-        # the stacked results one step at a time — logging, validation,
-        # checkpoints, and preemption handling stay byte-identical because
-        # _dispatch_group_len never lets a group straddle an interval
-        # boundary or the profiler window.
-        pending: list = []
-
         try:
             for step in range(self.start_step + 1, self.total_steps + 1):
                 if prof_stop > prof_start:
@@ -1238,11 +1168,8 @@ class Trainer:
                         if self.profiler.start(step) \
                                 and self.events is not None:
                             self.events.append("profiler", action="start", step=step)
-                # On-demand capture window (SIGUSR2): both edges gate on
-                # group boundaries (`not pending`) so a scan-dispatched
-                # group never straddles the window.
-                if self._trace_until and step >= self._trace_until \
-                        and not pending:
+                # On-demand capture window (SIGUSR2).
+                if self._trace_until and step >= self._trace_until:
                     self._trace_until = 0
                     if self._trace_owns_prof and self.profiler.active:
                         report = self.profiler.stop(step)
@@ -1255,8 +1182,7 @@ class Trainer:
                     if self.events is not None:
                         self.events.append("trace_capture", action="stop",
                                            step=step, path=out)
-                if self._trace_request and not self._trace_until \
-                        and not pending:
+                if self._trace_request and not self._trace_until:
                     self._trace_request = 0
                     self._trace_until = step + max(1, self._trace_capture_steps)
                     self._trace_prev_enabled = self.tracer.enabled
@@ -1271,64 +1197,32 @@ class Trainer:
                     if self.events is not None:
                         self.events.append("trace_capture", action="start",
                                            step=step, until=self._trace_until)
-                if self.steps_per_dispatch > 1:
-                    if not pending:
-                        try:
-                            # Stacked [K, B, L], already device-resident and
-                            # sharded; StopIteration mid-group served the
-                            # fetched prefix on the previous get().
-                            with self.tracer.phase("train.data_get", step=step):
-                                stacked, group_tokens, waits = self.prefetcher.get()
-                        except StopIteration:
-                            self.logger.log(
-                                f"Data stream exhausted before step {step}; stopping")
-                            break
-                        self.goodput.add("data_wait_s", waits["data_wait_s"])
-                        self._trace_phase("data_wait", waits["data_wait_s"],
-                                          step=step)
-                        if self.prefetcher.h2d_blocks_consumer:
-                            self.goodput.add("h2d_wait_s", waits["h2d_wait_s"])
-                            self._trace_phase("h2d_wait", waits["h2d_wait_s"],
-                                              step=step)
-                        # StepTraceAnnotation: profiler traces carry the
-                        # trainer's step numbering, lining up with
-                        # events.jsonl step_window records.
-                        with jax.profiler.StepTraceAnnotation("train", step_num=step), \
-                                self.tracer.phase("train.dispatch", step=step) as ph:
-                            self.state, mm = self.train_multi_step(self.state, stacked)
-                        self._book_dispatch(ph.seconds, step)
-                        pending = [
-                            (jax.tree_util.tree_map(lambda a, i=i: a[i], mm),
-                             t * jax.process_count())
-                            for i, t in enumerate(group_tokens)
-                        ]
-                    metrics, step_tokens = pending.pop(0)
-                    window_tokens += step_tokens
-                    self.total_tokens += step_tokens
-                else:
-                    try:
-                        with self.tracer.phase("train.data_get", step=step):
-                            batch, local_tokens, waits = self.prefetcher.get()
-                    except StopIteration:  # finite stream ran dry (streaming sources)
-                        self.logger.log(f"Data stream exhausted before step {step}; stopping")
-                        break
-                    # Token counts (non-pad targets) come host-counted from
-                    # the prefetch worker, so tok/s stays correct even when
-                    # device metrics are only read every log_int steps.
-                    step_tokens = local_tokens * jax.process_count()
-                    window_tokens += step_tokens
-                    self.total_tokens += step_tokens
-                    self.goodput.add("data_wait_s", waits["data_wait_s"])
-                    self._trace_phase("data_wait", waits["data_wait_s"],
+                try:
+                    with self.tracer.phase("train.data_get", step=step):
+                        batch, local_tokens, waits = self.prefetcher.get()
+                except StopIteration:  # finite stream ran dry (streaming sources)
+                    self.logger.log(f"Data stream exhausted before step {step}; stopping")
+                    break
+                # Token counts (non-pad targets) come host-counted from
+                # the prefetch worker, so tok/s stays correct even when
+                # device metrics are only read every log_int steps.
+                step_tokens = local_tokens * jax.process_count()
+                window_tokens += step_tokens
+                self.total_tokens += step_tokens
+                self.goodput.add("data_wait_s", waits["data_wait_s"])
+                self._trace_phase("data_wait", waits["data_wait_s"],
+                                  step=step)
+                if self.prefetcher.h2d_blocks_consumer:
+                    self.goodput.add("h2d_wait_s", waits["h2d_wait_s"])
+                    self._trace_phase("h2d_wait", waits["h2d_wait_s"],
                                       step=step)
-                    if self.prefetcher.h2d_blocks_consumer:
-                        self.goodput.add("h2d_wait_s", waits["h2d_wait_s"])
-                        self._trace_phase("h2d_wait", waits["h2d_wait_s"],
-                                          step=step)
-                    with jax.profiler.StepTraceAnnotation("train", step_num=step), \
-                            self.tracer.phase("train.dispatch", step=step) as ph:
-                        self.state, metrics = self.train_step(self.state, batch)
-                    self._book_dispatch(ph.seconds, step)
+                # StepTraceAnnotation: profiler traces carry the trainer's
+                # step numbering, lining up with events.jsonl step_window
+                # records.
+                with jax.profiler.StepTraceAnnotation("train", step_num=step), \
+                        self.tracer.phase("train.dispatch", step=step) as ph:
+                    self.state, metrics = self.train_step(self.state, batch)
+                self._book_dispatch(ph.seconds, step)
 
                 window_steps += 1
                 if self.moe_stats_experts and "moe_load" in metrics:
@@ -1483,13 +1377,7 @@ class Trainer:
                     self.save_checkpoint(
                         step, blocking=not cfg.system.async_checkpointing)
 
-                # With steps_per_dispatch>1, drain the already-dispatched
-                # group before saving: the device state is at the group
-                # end, so breaking mid-group would tag the checkpoint with
-                # a step the state has already passed and undercount
-                # total_tokens. Draining is host-side only (no new
-                # dispatches) — preemption latency grows by < K steps.
-                if self._preempted and not pending:
+                if self._preempted:
                     self.logger.log(
                         f"Preemption signal received: saving checkpoint at step {step} and exiting"
                     )

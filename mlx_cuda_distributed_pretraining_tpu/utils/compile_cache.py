@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache, placed from outside the program.
 
 One rule for every entry point that compiles (trainer ``main``, server
-``main``, ``chip_smoke.py``, bench children): the cache lives where
+``main``, ``chip_smoke.py``): the cache lives where
 ``JAX_COMPILATION_CACHE_DIR`` says, and JAX reads that variable itself, so
 nothing here sets a directory then. With the variable unset the cache lives
 at one fixed path inside the checkout. The path is part of a cache key, so

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Harvest a real natural-language corpus from the local machine (no egress).
 
-The bench/judging environment has zero network egress, so FineWeb-style hub
+The build environment has zero network egress, so FineWeb-style hub
 streaming can't supply real text. This builds an honest offline corpus of
 English prose from what the image ships:
 

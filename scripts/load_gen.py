@@ -13,8 +13,7 @@ Stdlib-only, so it runs anywhere the repo does:
         --concurrency 8 --requests 64 --max-tokens 32
 
 Point it at a ``--engine locked`` server and then a ``--engine batch``
-one to see continuous batching under identical offered load (the
-serve_batch bench case does the same comparison in-process).
+one to see continuous batching under identical offered load.
 
 Shared-prefix workload (``--shared-prefix-tokens N --prefix-groups G``):
 every request's prompt starts with one of G fixed ~N-token prefixes
@@ -33,8 +32,8 @@ generation (bandwidth-bound; its TTFT is what prefill interference
 destroys on a homogeneous replica). Class weights repeat via ``*N``
 (``prefill-heavy*2:decode-heavy``); shapes via ``--mix-*`` flags. The
 summary gains per-class TTFT and TPOT (per-output-token decode latency)
-p50/p95/p99 — the ``serve_fleet`` bench case reads exactly these to
-score a prefill/decode fleet against a homogeneous baseline.
+p50/p95/p99, the numbers that score a prefill/decode fleet against a
+homogeneous baseline.
 
 Per-request tracing (``--trace-out FILE``): writes one CSV row per
 request with the server-minted trace id and the server-side TTFT

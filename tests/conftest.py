@@ -28,12 +28,24 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 # --- shared subprocess-spawn helpers ---------------------------------------
-# Several suites (test_multiprocess, test_supervisor, test_serve_tp, bench
-# children) spawn real Python subprocesses that must see a forced virtual
-# CPU device count. The env recipe is identical everywhere; keep it in ONE
+# Several suites (test_multiprocess, test_supervisor, test_serve_tp) spawn
+# real Python subprocesses that must see a forced virtual CPU device
+# count. The env recipe is identical everywhere; keep it in ONE
 # place so "how do child processes get N devices" has a single answer.
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def load_script(name):
+    """Import ``scripts/<name>.py`` by path (scripts/ is not a package).
+    trace_report and load_gen are stdlib-only, so this stays cheap."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(REPO, "scripts", name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def device_env(n, base=None):

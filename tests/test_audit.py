@@ -8,7 +8,7 @@ virtual CPU platform the conftest forces; nothing executes.
 
 The full-config gate (audit the sample config end to end, zero new
 findings, committed budget matches a fresh census) runs in a subprocess
-and is marked slow — scripts/lint.sh and the bench gate run it too.
+and is marked slow — scripts/lint.sh runs it too.
 """
 import json
 import os
@@ -330,8 +330,8 @@ def test_cli_list_rules(capsys):
 @pytest.mark.slow
 def test_sample_config_audits_clean():
     """The merged tree must audit green: zero new findings and a committed
-    budget that matches a fresh lowering, exactly what scripts/lint.sh and
-    the bench gate enforce."""
+    budget that matches a fresh lowering, exactly what scripts/lint.sh
+    enforces."""
     env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
     proc = subprocess.run(

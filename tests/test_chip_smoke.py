@@ -42,12 +42,12 @@ def test_chip_smoke_without_a_chip_fails_and_prints_no_result():
 
 
 def test_importing_entry_points_initializes_no_backend():
-    """Supervisors, routers, launchers and the bench parent import these
-    and must stay off the chip so that their children can have it."""
+    """Supervisors, routers and launchers import these and must stay off
+    the chip so that their children can have it."""
     modules = [f"mlx_cuda_distributed_pretraining_tpu.{m}" for m in (
         "train.supervisor", "serve.fleet", "serve.router", "parallel.launch",
         "train.trainer", "infer.server", "serve.engine", "utils.compile_cache",
-    )] + ["bench", "chip_smoke"]
+    )] + ["chip_smoke"]
     code = (
         "import importlib, sys\n"
         "from jax._src import xla_bridge\n"

@@ -134,9 +134,8 @@ def test_mesh_config():
 
 
 def test_system_compute_dtype_explicit_key():
-    """system.compute_dtype in YAML is honored even though the dataclass
-    derives it (it lands in _extras — the bench trainer config relies on
-    this)."""
+    """system.compute_dtype in YAML is honored; without it the dataclass
+    derives it from mixed_precision."""
     from mlx_cuda_distributed_pretraining_tpu.config import Config
 
     cfg = Config.from_dict({

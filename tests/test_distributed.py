@@ -205,50 +205,3 @@ def test_sp_fused_ce_matches_dense():
         assert mx < 1e-6, mx
     finally:
         set_mesh(None)
-
-
-@pytest.mark.slow
-def test_multi_step_sharded_matches_single_dispatch():
-    """K scanned steps in ONE dispatch (make_multi_step) on a dp+tp mesh
-    == K individual dispatched steps with the same batches (the trainer's
-    system.steps_per_dispatch path; amortizes host->device latency)."""
-    from mlx_cuda_distributed_pretraining_tpu.train.train_step import make_multi_step
-    from mlx_cuda_distributed_pretraining_tpu.optim import build_optimizer as _bo
-
-    mesh_cfg = {"dp": 4, "tp": 2}
-    mesh, step, state, shardings = _setup(mesh_cfg)
-    sys_cfg = SystemConfig(seed=0, device="cpu", mesh=mesh_cfg)
-    tr_cfg = TrainingConfig(
-        hyperparameters={"learning_rate": 1e-2, "gradient_clip": 1.0},
-        scheduler={"type": "constant"},
-        optimization={"optimizer": "adamw"},
-    )
-    opt = _bo(tr_cfg, 100)
-    params = llama.init_params(jax.random.PRNGKey(0), ARGS)
-
-    def loss_fn(p, b):
-        return llama.loss_fn(p, b, ARGS)
-
-    multi, _ = make_multi_step(loss_fn, opt, mesh=mesh, params_like=params)
-
-    batches = [_batch(seed=s) for s in range(3)]
-
-    # reference: 3 individual dispatches
-    ref_state = state
-    ref_losses = []
-    for b in batches:
-        ref_state, m = step(ref_state, b)
-        ref_losses.append(float(m["loss"]))
-
-    # one dispatch of the scanned triple
-    state2 = jax.device_put(
-        init_train_state(llama.init_params(jax.random.PRNGKey(0), ARGS), opt),
-        shardings)
-    stacked = {k: jnp.stack([b[k] for b in batches]) for k in batches[0]}
-    state2, mm = multi(state2, stacked)
-
-    np.testing.assert_allclose(
-        np.asarray(mm["loss"]), np.asarray(ref_losses), atol=1e-5)
-    pa = ref_state["params"]["layers"][0]["attention"]["wq"]["weight"]
-    pb = state2["params"]["layers"][0]["attention"]["wq"]["weight"]
-    np.testing.assert_allclose(np.asarray(pa), np.asarray(pb), atol=1e-5)

@@ -11,7 +11,7 @@ world) takes a SIGKILL on one host's trainer mid-run. The fleet must:
 - book the lost wall clock as ``restart`` events with goodput >= 95% read
   off the ledger (components still sum to the window wall time).
 
-Python-level mirror of ``scripts/chaos_train.sh`` / bench ``train_elastic``.
+Python-level mirror of ``scripts/chaos_train.sh``.
 """
 
 import json

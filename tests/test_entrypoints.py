@@ -58,35 +58,3 @@ def test_entry_compiles():
     fn, args = __graft_entry__.entry()
     out = jax.jit(fn).lower(*args).compile()
     assert out is not None
-
-
-@pytest.mark.slow
-def test_bench_subprocess_harness_end_to_end(tmp_path):
-    """Drive the real bench.py parent -> --one child machinery on
-    CPU with the CI-only tiny case: the stdout contract line must appear
-    with a populated matrix and a real device string."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env.update({
-        "PYTHONPATH": "",
-        "JAX_PLATFORMS": "cpu",
-        "BENCH_CASES": "tiny",
-        "BENCH_STEPS": "2",
-        "BENCH_VOCAB": "512",
-        "BENCH_BUDGET_S": "240",
-    })
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py")],
-        capture_output=True, text=True, timeout=300, env=env, cwd=str(tmp_path),
-    )
-    assert proc.returncode == 0, proc.stderr[-800:]
-    contract = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert contract["unit"] == "tok/s"
-    assert "CPU" in contract["device"].upper()
-    [case] = [r for r in contract["matrix"] if r.get("case") == "tiny_simple"]
-    assert case["tok_s"] > 0 and case["final_loss"] > 0

@@ -20,6 +20,7 @@ from types import SimpleNamespace
 import jax
 import numpy as np
 import pytest
+from conftest import load_script
 from jax.sharding import NamedSharding
 
 from mlx_cuda_distributed_pretraining_tpu.checkpoint.manager import (
@@ -628,15 +629,7 @@ def test_fleet_http_handoff_trace_join_and_drain(tmp_path):
             with open(path, "w") as fh:
                 json.dump(doc, fh)
             files.append(path)
-        import importlib.util
-        import os
-        spec = importlib.util.spec_from_file_location(
-            "trace_report", os.path.join(
-                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                "scripts", "trace_report.py"))
-        tr = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(tr)
-        lines = tr.report(files, top=1)
+        lines = load_script("trace_report").report(files, top=1)
         acct = next(ln for ln in lines if "requests_complete=" in ln)
         assert "requests_complete=1" in acct
         assert "handoffs=1" in acct and "kv_transfers=1" in acct
@@ -667,6 +660,73 @@ def test_fleet_http_handoff_trace_join_and_drain(tmp_path):
             timeout=10.0)
         router.poll_once()
         assert router.replicas[rid].state == "active"
+    finally:
+        rhttpd.shutdown()
+        rhttpd.server_close()
+        router.stop()
+        for s, h in ((pre_s, pre_h), (dec_s, dec_h)):
+            s.close()
+            h.shutdown()
+            h.server_close()
+
+
+def test_rolling_swap_under_flood_fails_no_request(tmp_path):
+    # A live canary weight swap rolls through a 1 prefill + 1 decode fleet
+    # while mixed traffic keeps flowing through the router (the new
+    # checkpoint is value-identical, as in a deploy of retrained weights):
+    # both replicas cut over, and not one request of the flood fails.
+    from mlx_cuda_distributed_pretraining_tpu.serve.router import (
+        serve_router,
+    )
+
+    load_gen = load_script("load_gen")
+
+    path = str(tmp_path / "model.safetensors")
+    save_safetensors(path, {k: np.asarray(v)
+                            for k, v in flatten_dict(PARAMS).items()})
+    pre_s, pre_h, pre_url = _fleet_replica("prefill")
+    dec_s, dec_h, dec_url = _fleet_replica("decode")
+    # 48: the prefill-heavy class (~80 bytes) hands its KV off, the
+    # decode-heavy one (~12 bytes) prefills on the decode replica.
+    router = FleetRouter([pre_url], [dec_url], poll_interval_s=0.1,
+                         handoff_min_prompt_bytes=48)
+    rhttpd = serve_router(router, port=0)
+    rurl = f"http://127.0.0.1:{rhttpd.server_address[1]}"
+    batch, floods, swapped = 8, [], threading.Event()
+
+    def flood():
+        return load_gen.run_load(
+            rurl, concurrency=4, requests=batch, prompt="", max_tokens=4,
+            temperature=0.0, deadline_s=None, timeout=300.0,
+            mix="prefill-heavy:decode-heavy",
+            mix_shapes={"prefill-heavy": (80, 4), "decode-heavy": (12, 16)})
+
+    def keep_flooding():
+        while not swapped.is_set():
+            floods.append(flood())
+        floods.append(flood())  # and once more on the new weights
+
+    try:
+        flood()  # warm every compile variant both classes will see
+        t = threading.Thread(target=keep_flooding)
+        t.start()
+        try:
+            out = FleetController(router, FleetConfig()).rolling_swap(
+                model_path=path, canary_requests=2, canary_timeout_s=120.0)
+        finally:
+            swapped.set()
+            t.join(timeout=300.0)
+        assert not t.is_alive()
+        assert out["failed"] == []
+        assert sorted(s["replica"] for s in out["swapped"]) == ["r0", "r1"]
+        assert all(s["canary_ok"] >= 2 and s["params_version"] == 1
+                   for s in out["swapped"])
+        assert pre_s.engine.metrics()["params_version"] == 1
+        assert dec_s.engine.metrics()["params_version"] == 1
+        assert len(floods) >= 2
+        for summary in floods:
+            assert summary["completed"] == summary["ok"] == batch, summary
+            assert summary["outcomes"]["error"] == 0
     finally:
         rhttpd.shutdown()
         rhttpd.server_close()

@@ -163,24 +163,6 @@ def test_sync_mode_matches_async_sequence():
     assert off._thread is None  # sync mode runs no worker at all
 
 
-def test_group_mode_stacks_and_serves_prefix_on_exhaustion():
-    pf = DevicePrefetcher(
-        FakeLoader(limit=7), depth=2, start_step=0, total_steps=100,
-        group_len_fn=lambda step: 4)
-    try:
-        g, tokens, _ = pf.get()
-        assert np.asarray(g["inputs"]).shape == (4, 2, 4)
-        assert np.asarray(g["inputs"])[:, 0, 0].tolist() == [0, 1, 2, 3]
-        assert tokens == [8, 8, 8, 8]
-        g, tokens, _ = pf.get()  # steps 4-6, then the stream runs dry
-        assert np.asarray(g["inputs"])[:, 0, 0].tolist() == [4, 5, 6]
-        assert tokens == [8, 8, 8]
-        with pytest.raises(StopIteration):
-            pf.get()
-    finally:
-        pf.stop()
-
-
 # -- checkpoint position: consumed, not fetched ------------------------------
 
 def test_state_dict_reflects_consumed_not_fetched(tmp_path):
@@ -256,7 +238,7 @@ def test_resume_equivalence_prefetch_on_vs_off(tmp_path):
 
 # -- trainer integration: loss parity, checkpoints, breakdown ----------------
 
-def _tiny_cfg(tmp_path, name, prefetch_depth, ckpt_interval=0, spd=1):
+def _tiny_cfg(tmp_path, name, prefetch_depth, ckpt_interval=0):
     train = str(tmp_path / "train.jsonl")
     if not os.path.exists(train):
         _write_shard(train, 80)
@@ -284,7 +266,7 @@ def _tiny_cfg(tmp_path, name, prefetch_depth, ckpt_interval=0, spd=1):
             "steps": {"logging_interval": 2, "checkpoint_interval": ckpt_interval,
                       "validation_interval": 0},
         },
-        "system": {"seed": 0, "steps_per_dispatch": spd},
+        "system": {"seed": 0},
     })
 
 
@@ -300,16 +282,15 @@ def _loss_series(run_dir):
     return losses, fracs
 
 
-@pytest.mark.parametrize("spd", [1, 2])
-def test_trainer_loss_parity_prefetch_on_vs_off(tmp_path, spd):
+def test_trainer_loss_parity_prefetch_on_vs_off(tmp_path):
     """Same seed, prefetch on vs off: identical batch sequence, identical
     losses (final loss bitwise), and both runs report data_wait_frac."""
     from mlx_cuda_distributed_pretraining_tpu.train.trainer import Trainer
 
     results, series = {}, {}
     for depth in (2, 0):
-        cfg = _tiny_cfg(tmp_path, f"parity-d{depth}-k{spd}", depth, spd=spd)
-        tr = Trainer(cfg, runs_root=str(tmp_path / f"runs-d{depth}-k{spd}"), quiet=True)
+        cfg = _tiny_cfg(tmp_path, f"parity-d{depth}", depth)
+        tr = Trainer(cfg, runs_root=str(tmp_path / f"runs-d{depth}"), quiet=True)
         results[depth] = tr.train()
         series[depth] = _loss_series(tr.run_dir)
 
@@ -319,6 +300,37 @@ def test_trainer_loss_parity_prefetch_on_vs_off(tmp_path, spd):
     losses_off, fracs_off = series[0]
     assert losses_on == losses_off and len(losses_on) >= 4
     assert all(0.0 <= fr <= 1.0 for fr in fracs_on + fracs_off)
+
+
+def test_depth0_books_h2d_wait(tmp_path):
+    """``prefetch_depth: 0`` copies inline, so the copy is the step loop's
+    own wall time: booked as ``h2d_wait_s`` on every window line and in the
+    goodput ledger. With a worker (depth 2) the copy overlaps compute and
+    books nothing; a stall there already shows as ``data_wait_s``."""
+    from mlx_cuda_distributed_pretraining_tpu.obs.events import events_path, iter_events
+    from mlx_cuda_distributed_pretraining_tpu.train.trainer import Trainer
+
+    booked = {}
+    for depth in (0, 2):
+        cfg = _tiny_cfg(tmp_path, f"h2d-d{depth}", depth)
+        tr = Trainer(cfg, runs_root=str(tmp_path / f"runs-h2d-d{depth}"), quiet=True)
+        tr.train()
+        assert tr.prefetcher.h2d_blocks_consumer is (depth == 0)
+        events = list(iter_events(events_path(tr.run_dir)))
+        windows = [e["goodput"] for e in events if e["type"] == "step_window"]
+        [end] = [e for e in events if e["type"] == "run_end"]
+        lines = [float(line.split("h2d_wait_s=")[1].split()[0].rstrip("|"))
+                 for line in open(os.path.join(tr.run_dir, "log.txt"))
+                 if "h2d_wait_s=" in line]
+        assert len(windows) == len(lines) == 4
+        booked[depth] = ([gp["h2d_wait_s"] for gp in windows], lines,
+                         end["goodput_totals"]["h2d_wait_s"])
+
+    windows, lines, total = booked[0]
+    assert all(w > 0 for w in windows) and all(v > 0 for v in lines)
+    assert total == pytest.approx(sum(windows), abs=1e-3)
+    windows, lines, total = booked[2]
+    assert windows == [0.0] * 4 and lines == [0.0] * 4 and total == 0.0
 
 
 def test_trainer_checkpoint_position_prefetch_on_vs_off(tmp_path):

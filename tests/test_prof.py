@@ -1,5 +1,5 @@
 """graftprof tests: trace parsing, step-time attribution, the CLI, the
-profiler helper, per-host exposition, and the perf gate.
+profiler helper, and per-host exposition.
 
 The golden fixture is a hand-built Chrome trace (two annotated steps,
 an overlapping collective+matmul pair, an infeed slice, and a torn
@@ -10,11 +10,11 @@ with fractions summing to ~1.
 """
 
 import gzip
-import importlib.util
 import json
 import os
 
 import pytest
+from conftest import load_script
 
 from mlx_cuda_distributed_pretraining_tpu.obs.profile_report import (
     PROF_FIELDS,
@@ -32,16 +32,6 @@ from mlx_cuda_distributed_pretraining_tpu.obs.profiler import ProfileCapture
 from mlx_cuda_distributed_pretraining_tpu.obs.prometheus import (
     render_prometheus,
 )
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load_script(name):
-    path = os.path.join(REPO, "scripts", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 # -- fixture --------------------------------------------------------------
@@ -428,7 +418,7 @@ def test_render_prometheus_process_index_stamp():
 # -- trace_report fold ----------------------------------------------------
 
 def test_trace_report_folds_graftprof(tmp_path, capsys):
-    mod = _load_script("trace_report")
+    mod = load_script("trace_report")
     run = _make_run_dir(tmp_path)
     lines = mod.graftprof_report(str(run))
     assert lines and lines[0].startswith("graftprof=1")
@@ -440,116 +430,6 @@ def test_trace_report_folds_graftprof(tmp_path, capsys):
     # --run-dir end to end through main().
     assert mod.main([ "--run-dir", str(run)]) == 0
     assert "graftprof=1" in capsys.readouterr().out
-
-
-# -- perf gate ------------------------------------------------------------
-
-def _gate_doc(rows):
-    return {"metric": "x", "value": 1, "matrix": rows}
-
-
-def test_perf_gate_ok_and_regression(tmp_path, capsys):
-    gate = _load_script("perf_gate")
-    baseline = {"version": 1, "tolerance": 0.1, "cases": {
-        "2m_flash": {"tok_s": 1000.0, "mfu": 0.10,
-                     "prof_idle_frac": 0.20}}}
-    base_path = tmp_path / "bench_baseline.json"
-    with open(base_path, "w") as f:
-        json.dump(baseline, f)
-
-    ok_doc = tmp_path / "BENCH_ok.json"
-    with open(ok_doc, "w") as f:
-        json.dump(_gate_doc([{"case": "2m_flash", "tok_s": 980.0,
-                              "mfu": 0.095, "prof_idle_frac": 0.25}]), f)
-    rc = gate.main(["--bench", str(ok_doc), "--baseline", str(base_path)])
-    assert rc == 0
-
-    bad_doc = tmp_path / "BENCH_bad.json"
-    with open(bad_doc, "w") as f:
-        json.dump(_gate_doc([{"case": "2m_flash", "tok_s": 500.0,
-                              "mfu": 0.04, "prof_idle_frac": 0.45}]), f)
-    rc = gate.main(["--bench", str(bad_doc), "--baseline", str(base_path)])
-    assert rc == 1
-    out = capsys.readouterr().out
-    assert "REGRESSION" in out
-    # tok_s and mfu regress relatively; the idle fraction regresses
-    # absolutely (0.45 vs 0.20 > 0.1 abs tolerance).
-    assert out.count("REGRESSION") >= 3
-
-
-def test_perf_gate_improvement_hint_and_skips(tmp_path, capsys):
-    gate = _load_script("perf_gate")
-    base_path = tmp_path / "bench_baseline.json"
-    with open(base_path, "w") as f:
-        json.dump({"version": 1, "tolerance": 0.1, "cases": {
-            "2m_flash": {"tok_s": 1000.0},
-            "100m_flash": {"tok_s": 5000.0, "mfu": 0.3}}}, f)
-    doc = tmp_path / "BENCH_x.json"
-    with open(doc, "w") as f:
-        # 2m improved beyond tolerance; 100m row incomplete (tok_s null
-        # = device-unreachable skip row) -> skipped, never a failure.
-        json.dump(_gate_doc([
-            {"case": "2m_flash", "tok_s": 1300.0},
-            {"case": "100m_flash", "tok_s": None, "mfu": None},
-        ]), f)
-    rc = gate.main(["--bench", str(doc), "--baseline", str(base_path)])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "refresh the baseline" in out
-    assert "case=100m_flash SKIP" in out
-
-
-def test_perf_gate_missing_inputs_exit_2(tmp_path, capsys):
-    gate = _load_script("perf_gate")
-    doc = tmp_path / "BENCH_y.json"
-    with open(doc, "w") as f:
-        json.dump(_gate_doc([{"case": "a", "tok_s": 1.0}]), f)
-    rc = gate.main(["--bench", str(doc),
-                    "--baseline", str(tmp_path / "nope.json")])
-    assert rc == 2
-    rc = gate.main(["--bench", str(tmp_path / "missing.json"),
-                    "--baseline", str(tmp_path / "nope.json")])
-    assert rc == 2
-
-
-def test_perf_gate_write_baseline_roundtrip(tmp_path):
-    gate = _load_script("perf_gate")
-    doc = tmp_path / "BENCH_z.json"
-    with open(doc, "w") as f:
-        json.dump(_gate_doc([
-            {"case": "2m_flash", "tok_s": 1200.0, "mfu": 0.06,
-             "prof_compute_frac": 0.7, "prof_idle_frac": 0.1,
-             "final_loss": 3.0},
-            {"case": "skipme", "tok_s": None},
-        ]), f)
-    base_path = tmp_path / "bench_baseline.json"
-    rc = gate.main(["--bench", str(doc), "--baseline", str(base_path),
-                    "--write-baseline"])
-    assert rc == 0
-    with open(base_path) as f:
-        base = json.load(f)
-    # Schema v2: cases pinned under the doc's backend section (the doc
-    # carries no device stamp, so it lands under "cpu").
-    assert base["version"] == 2
-    assert base["backends"]["cpu"]["cases"] == {"2m_flash": {
-        "tok_s": 1200.0, "mfu": 0.06,
-        "prof_compute_frac": 0.7, "prof_idle_frac": 0.1}}
-    # And the fresh baseline gates its own doc clean.
-    assert gate.main(["--bench", str(doc),
-                      "--baseline", str(base_path)]) == 0
-
-
-def test_committed_baseline_is_valid():
-    gate = _load_script("perf_gate")
-    with open(os.path.join(REPO, "bench_baseline.json")) as f:
-        base = json.load(f)
-    assert base["version"] == 2 and base["backends"]
-    for backend, section in base["backends"].items():
-        assert backend in ("cpu", "tpu", "gpu")
-        assert section["cases"]
-        for case, pinned in section["cases"].items():
-            for metric in pinned:
-                assert metric in gate.DIRECTIONS, (backend, case, metric)
 
 
 # -- trainer auto-report (slow) -------------------------------------------

@@ -393,6 +393,57 @@ def test_paged_preemption_recompute_keeps_greedy_output():
     assert m["kv_blocks_used"] == 0 and m["kv_blocks_free"] == 4
 
 
+def test_paged_holds_twice_the_sequences_of_worst_case_rows():
+    # One KV budget, 2,048 cache positions, spent two ways: 8 rows sized
+    # for the worst case (8 x 256), or 64 blocks of 32 behind 24 lanes. A
+    # flood of 24 short-skewed requests: rows admit 8 at a time whatever
+    # their lengths; blocks admit by actual length, so at least twice as
+    # many run at once. Same greedy tokens either way.
+    import dataclasses
+
+    import numpy as np
+
+    max_len, budget, block, new = 256, 2048, 32, 32
+    args = dataclasses.replace(ARGS, max_position_embeddings=max_len)
+    rng = np.random.default_rng(0)
+    lens = [16, 24, 32, 48, 16, 80, 24, 32] * 3
+    prompts = [rng.integers(2, ARGS.vocab_size, size=n).tolist() for n in lens]
+
+    def flood(**kw):
+        eng = BatchEngine(PARAMS, args, TOK, EngineConfig(
+            max_len=max_len, prefill_chunk=64, max_queue=64, **kw))
+        # The peak falls right after an admission: read it there, on the
+        # engine's own thread, not from a poller that can miss it.
+        peak, allocate = [0], eng.pool.allocate
+
+        def counting_allocate(*a, **k):
+            got = allocate(*a, **k)
+            peak[0] = max(peak[0], eng.pool.num_used)
+            return got
+
+        eng.pool.allocate = counting_allocate
+        eng.start()
+        try:
+            reqs = [eng._submit_ids(ids, new, 0.0, 0) for ids in prompts]
+            assert all(r.wait(300.0) for r in reqs)
+            metrics = eng.metrics()
+        finally:
+            eng.stop()
+        assert all(r.error is None for r in reqs)
+        return peak[0], [r.tokens for r in reqs], metrics
+
+    rows_peak, rows_tokens, _ = flood(
+        kv_backend="slotted", num_slots=budget // max_len)
+    paged_peak, paged_tokens, m = flood(
+        kv_backend="paged", num_slots=len(prompts), block_size=block,
+        num_blocks=budget // block)
+    assert rows_peak == budget // max_len == 8
+    assert paged_peak >= 2 * rows_peak, (paged_peak, rows_peak)
+    assert m["preempted"] == 0 and m["kv_blocks_used"] == 0
+    assert paged_tokens == rows_tokens
+    assert all(len(t) > 0 for t in paged_tokens)
+
+
 def test_moe_model_batch_engine_greedy_matches_generate_text():
     # The batch engine's step shares moe_block with training: a MoE
     # checkpoint must greedy-decode under --engine batch token-for-token

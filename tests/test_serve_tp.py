@@ -146,6 +146,46 @@ def test_tp2_greedy_matches_unsharded(arm):
         assert m["spec_proposed"] >= m["spec_accepted"] >= 0
 
 
+def test_tp2_adds_no_host_readbacks(monkeypatch):
+    # GSPMD keeps logits and sampling on the device: every device->host
+    # read of the serve loop goes through np.asarray(jax.Array) or
+    # jax.device_get, and tp must add none. The flood is queued before the
+    # engine starts, so both arms admit and step on the same schedule.
+    reads = [0]
+    asarray, device_get = np.asarray, jax.device_get
+
+    def counting_asarray(a, *args, **kw):
+        if isinstance(a, jax.Array):
+            reads[0] += 1
+        return asarray(a, *args, **kw)
+
+    def counting_device_get(x):
+        reads[0] += 1
+        return device_get(x)
+
+    monkeypatch.setattr(np, "asarray", counting_asarray)
+    monkeypatch.setattr(jax, "device_get", counting_device_get)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(2, ARGS.vocab_size, size=40).tolist()
+               for _ in range(4)]
+
+    def flood(mesh):
+        eng = _engine(mesh=mesh, num_slots=4)
+        reqs = [eng._submit_ids(ids, 24, 0.0, 0) for ids in prompts]
+        before = reads[0]
+        eng.start()
+        try:
+            assert all(r.wait(300.0) for r in reqs)
+        finally:
+            eng.stop()
+        return reads[0] - before, [list(r.tokens) for r in reqs]
+
+    one_reads, one_tokens = flood(None)
+    two_reads, two_tokens = flood(_tp2())
+    assert two_tokens == one_tokens
+    assert two_reads == one_reads > 0, (one_reads, two_reads)
+
+
 def test_tp2_prefix_cache_adoption_parity():
     # Sequential requests sharing a long prefix: the second adopts the
     # first one's cached KV blocks, which under tp=2 live sharded over

@@ -15,7 +15,6 @@ Three layers:
      match is by construction, and the test pins that construction).
 """
 
-import importlib.util
 import json
 import math
 import os
@@ -23,6 +22,7 @@ import urllib.request
 
 import jax
 import pytest
+from conftest import load_script
 
 from mlx_cuda_distributed_pretraining_tpu.config import Config, DataConfig
 from mlx_cuda_distributed_pretraining_tpu.infer.server import (
@@ -49,8 +49,6 @@ from mlx_cuda_distributed_pretraining_tpu.serve import (
 )
 from mlx_cuda_distributed_pretraining_tpu.tokenizer import TokenizerManager
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 TOK = TokenizerManager(DataConfig())
 ARGS = LlamaArgs(
     vocab_size=TOK.vocab_size, hidden_size=32, intermediate_size=64,
@@ -58,16 +56,6 @@ ARGS = LlamaArgs(
     max_position_embeddings=128,
 )
 PARAMS = llama.init_params(jax.random.PRNGKey(0), ARGS)
-
-
-def _load_script(name):
-    """Import a scripts/*.py module by path (scripts/ is not a package).
-    trace_report and load_gen are stdlib-only, so this stays cheap."""
-    path = os.path.join(REPO, "scripts", name + ".py")
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 # -- recorder semantics (no device) -------------------------------------------
@@ -247,7 +235,7 @@ def test_router_propagates_one_trace_id_and_report_merges(tmp_path):
     url = f"http://127.0.0.1:{rhttpd.server_address[1]}"
     try:
         # flood through the router with load_gen, CSV capture on
-        load_gen = _load_script("load_gen")
+        load_gen = load_script("load_gen")
         csv_path = str(tmp_path / "requests.csv")
         summary = load_gen.run_load(
             url, concurrency=2, requests=5, prompt="the quick brown fox",
@@ -297,7 +285,7 @@ def test_router_propagates_one_trace_id_and_report_merges(tmp_path):
         assert csv_ids <= route_ids
         assert csv_ids <= request_ids
 
-        report = _load_script("trace_report").report(paths, top=2)
+        report = load_script("trace_report").report(paths, top=2)
         acct = next(ln for ln in report
                     if ln.startswith("requests_complete="))
         assert "requests_complete=6" in acct
@@ -425,7 +413,7 @@ def test_trainer_spans_reconcile_with_goodput_ledger(tmp_path):
     assert doc["displayTimeUnit"] == "ms"
     assert any(e.get("name") == "train.dispatch" for e in doc["traceEvents"])
     # and trace_report's attribution section reads it
-    report = _load_script("trace_report").report([out])
+    report = load_script("trace_report").report([out])
     assert any(ln.startswith("trainer_attribution=1") for ln in report)
     assert any(ln.startswith("phase=train.dispatch") for ln in report)
     for ln in report:
