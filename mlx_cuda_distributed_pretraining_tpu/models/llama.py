@@ -822,10 +822,14 @@ def loss_fn(
 
     ``ce_chunk``: rows per fused-CE chunk (ops/fused_ce.py — folds the
     output projection into a chunked loss, never materializing [B,S,V]
-    logits). 0 disables; -1 (default) auto-enables when the logits tensor
-    would be HBM-significant. Both paths run the projection with fp32
-    accumulation and reduce in fp32, so toggling ce_chunk changes memory
-    behavior only, not the computed loss.
+    logits; differentiated, it computes the head's gradients in the same
+    chunk walk, three matmuls a chunk as the full-logits path has). 0
+    disables; -1 (default) auto-enables when the logits tensor would be
+    HBM-significant. Both paths run the projection with fp32 accumulation
+    and reduce in fp32, so toggling ce_chunk changes memory behavior only,
+    not the computed loss. The fused path is a ``jax.custom_vjp``:
+    forward-mode differentiation (``jax.jvp``, ``jacfwd``) through this
+    loss is unsupported while it is on.
 
     ``with_moe_stats=True`` (MoE training step) opens a routing-stats tap
     around the forward pass and returns ``(loss, (token_count, stats))``
@@ -867,7 +871,6 @@ def loss_fn(
         from ..parallel.context import current_mesh
 
         mesh = current_mesh()
-        want_z = z_loss_weight > 0.0
         with jax.named_scope("lm_head_ce"):
             if (mesh is not None and mesh.shape.get("sp", 1) > 1
                     and mesh.shape.get("tp", 1) == 1):
@@ -875,18 +878,16 @@ def loss_fn(
                 # each sp shard (ops/fused_ce.py::fused_cross_entropy_sp).
                 out = fused_ce.fused_cross_entropy_sp(
                     hidden, w_vd, targets, mask, mesh, bias_v=bias,
-                    logit_scale=args.logit_scale, chunk=ce_chunk, with_z=want_z,
+                    logit_scale=args.logit_scale, chunk=ce_chunk,
+                    z_weight=z_loss_weight,
                 )
             else:
                 out = fused_ce.fused_cross_entropy(
                     hidden, w_vd, targets, mask, bias_v=bias,
-                    logit_scale=args.logit_scale, chunk=ce_chunk, with_z=want_z,
+                    logit_scale=args.logit_scale, chunk=ce_chunk,
+                    z_weight=z_loss_weight,
                 )
-            if want_z:
-                nll_sum, z_sum = out
-                loss = nll_sum / count + z_loss_weight * z_sum / count
-            else:
-                loss = out / count
+            loss = out / count
     else:
         logits, _, aux = forward(
             params, batch["inputs"], args, compute_dtype=compute_dtype,

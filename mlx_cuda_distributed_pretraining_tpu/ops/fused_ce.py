@@ -7,30 +7,171 @@ memory consumer in the whole step, and pure bandwidth (the reference pays
 the same cost: core/training.py compute_loss materializes full logits).
 
 This is the standard TPU trick instead: fold the output projection INTO the
-loss and compute it in row chunks under ``jax.checkpoint`` inside a
-``lax.scan``:
+loss and walk the rows in chunks inside a ``lax.scan``, so that nothing of
+size ``[N, V]`` outlives its chunk. :func:`fused_cross_entropy` is a
+``jax.custom_vjp``; JAX, by differentiating it or not, picks what a chunk
+does (:func:`plan_counts` tallies which was traced):
 
-- forward: for each chunk of N rows, one ``[N, D] @ [D, V]`` MXU matmul
-  (bf16 operands, fp32 accumulation) -> logsumexp + gold-logit gather ->
-  scalar partial sum. Peak logits memory is ``chunk x V`` fp32 (a few
-  hundred MB at most) instead of ``B*S x V``.
-- backward: ``jax.checkpoint`` recomputes each chunk's logits, so the
-  softmax Jacobian never exists whole either; the scan accumulates dW
-  across chunks and emits per-chunk dX. FLOPs are identical to the naive
-  path + one extra forward matmul per chunk (the remat), traded for ~3x
-  less HBM traffic at the projection.
+- not differentiated (evaluation, validation): one ``[N, D] @ [D, V]`` MXU
+  matmul a chunk (operands in the compute dtype, fp32 accumulation) ->
+  logsumexp + gold-logit gather -> scalar partial sum. Peak logits memory
+  is ``chunk x V`` fp32 instead of ``B*S x V``.
+- differentiated: the same matmul and loss terms, and from the same logits
+  the gradient of the loss with respect to them, ``(softmax - onehot) *
+  mask``, which needs nothing the chunk does not already hold; two more
+  matmuls turn it into the chunk's ``dX`` rows and add its share to ``dW``.
+  The backward pass only scales ``dX``, ``dW`` and ``dbias`` by the loss's
+  scalar cotangent. Three matmuls a chunk, the FLOPs of the naive path; no
+  ``jax.checkpoint``, so no logits are computed twice.
+
+Only reverse mode is defined: ``jax.jvp``, ``jax.linearize`` and
+``jax.jacfwd`` through :func:`fused_cross_entropy` raise (nothing in the
+package takes them). Under an enclosing ``jax.checkpoint`` the forward pass
+runs the one-matmul walk and the backward pass the three-matmul one: four
+matmuls a chunk. A call inside a scan that is differentiated from outside
+wants that wrapper (parallel/pipeline.py's head has it): bare, every
+iteration keeps its fp32 ``dW`` for the backward pass.
 
 Exactness: identical math to ``logsumexp(logits) - logits[target]`` in fp32
 (same reduction, same dtype), verified against the unfused path by
-tests/test_model.py.
+tests/test_fused_ce.py and tests/test_model.py.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+import collections
+import functools
+import threading
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+
+# Counted while tracing, so this counts traces, not calls of the compiled
+# step: what a jitted program runs is what its one trace counted.
+_PLAN_KEYS = ("grad_in_forward", "forward_only")
+_plan_counts: Dict[str, int] = collections.Counter()
+_plan_counts_lock = threading.Lock()
+
+
+def _count_plan(key: str) -> None:
+    with _plan_counts_lock:
+        _plan_counts[key] += 1
+
+
+def plan_counts() -> Dict[str, int]:
+    """Chunk walks traced so far in this process: ``grad_in_forward`` with
+    the head's gradients computed in the walk (the call was differentiated),
+    ``forward_only`` with the loss alone."""
+    with _plan_counts_lock:
+        return {key: _plan_counts[key] for key in _PLAN_KEYS}
+
+
+def _row_chunks(hidden, targets, mask, chunk):
+    """Rows flattened, zero-padded to whole chunks (a padded row's mask is
+    0) and split: ``xs [n, chunk, D]``, ``ts``, ``ms [n, chunk]``."""
+    B, S, D = hidden.shape
+    N = B * S
+    x = hidden.reshape(N, D)
+    t = targets.reshape(N).astype(jnp.int32)
+    m = mask.reshape(N).astype(jnp.float32)
+    chunk = max(min(chunk, N), 1)
+    n_chunks = -(-N // chunk)
+    pad = n_chunks * chunk - N
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+        t = jnp.pad(t, (0, pad))
+        m = jnp.pad(m, (0, pad))
+    return (x.reshape(n_chunks, chunk, D), t.reshape(n_chunks, chunk),
+            m.reshape(n_chunks, chunk))
+
+
+def _chunk_loss(xc, tc, mc, w_vd, bias_v, logit_scale, z_weight):
+    """One chunk's fp32 logits, their logsumexp, and its masked loss sum."""
+    logits = jax.lax.dot_general(
+        xc, w_vd, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    if bias_v is not None:
+        logits = logits + bias_v.astype(jnp.float32)
+    if logit_scale:
+        logits = logits * logit_scale
+    logz = jax.nn.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
+    terms = logz - gold
+    if z_weight:  # trace-time constant
+        terms = terms + z_weight * jnp.square(logz)
+    return logits, logz, jnp.sum(terms * mc)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _fused(hidden, w_vd, targets, mask, bias_v, logit_scale, chunk, z_weight):
+    _count_plan("forward_only")
+
+    def body(acc, inp):
+        xc, tc, mc = inp
+        _, _, loss_c = _chunk_loss(xc, tc, mc, w_vd, bias_v, logit_scale, z_weight)
+        return acc + loss_c, None
+
+    acc, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32),
+                          _row_chunks(hidden, targets, mask, chunk))
+    return acc
+
+
+def _fused_fwd(hidden, w_vd, targets, mask, bias_v, logit_scale, chunk, z_weight):
+    _count_plan("grad_in_forward")
+    B, S, D = hidden.shape
+    V = w_vd.shape[0]
+
+    def body(carry, inp):
+        acc, dw, db = carry
+        xc, tc, mc = inp
+        logits, logz, loss_c = _chunk_loss(xc, tc, mc, w_vd, bias_v, logit_scale,
+                                           z_weight)
+        # d loss / d logits, from the logits and logsumexp the loss just used
+        p = jnp.exp(logits - logz[:, None])
+        onehot = jax.lax.broadcasted_iota(jnp.int32, p.shape, 1) == tc[:, None]
+        d = p - onehot.astype(jnp.float32)
+        if z_weight:
+            d = d + (2.0 * z_weight) * logz[:, None] * p
+        d = d * mc[:, None]
+        if logit_scale:
+            d = d * logit_scale
+        # Operands in the compute dtype, fp32 accumulation: what the MXU makes
+        # of autodiff's fp32 d as well. Behind the barrier d is written once
+        # and read by both matmuls; left to itself XLA recomputes it, exp and
+        # all, inside each (98.8 against 90.8 ms for the head at 16,384 x 4,096
+        # x 32,768 on a v5e; PERF.md section 6, PR 30). dW is summed across
+        # chunks in fp32 and rounded once, in the backward pass.
+        if bias_v is not None:
+            db = db + jnp.sum(d, axis=0)  # no matmul: summed before rounding
+        d = jax.lax.optimization_barrier(d.astype(xc.dtype))
+        dx = jax.lax.dot_general(d, w_vd, (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        dw = dw + jax.lax.dot_general(d, xc, (((0,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+        return (acc + loss_c, dw, db), dx
+
+    db0 = None if bias_v is None else jnp.zeros((V,), jnp.float32)
+    (acc, dw, db), dxs = jax.lax.scan(
+        body, (jnp.zeros((), jnp.float32), jnp.zeros((V, D), jnp.float32), db0),
+        _row_chunks(hidden, targets, mask, chunk))
+    dx = dxs.reshape(-1, D)[:B * S].reshape(B, S, D)
+    # fp32 residuals; an empty array of each input's dtype says what the
+    # backward pass rounds to, once, after scaling
+    like = tuple(None if a is None else jnp.zeros((0,), a.dtype)
+                 for a in (hidden, w_vd, bias_v))
+    return acc, ((dx, dw, db), like)
+
+
+def _fused_bwd(logit_scale, chunk, z_weight, res, g):
+    (dx, dw, db), like = res
+    dx, dw, db = (None if r is None else (g * r).astype(a.dtype)
+                  for r, a in zip((dx, dw, db), like))
+    return dx, dw, None, None, db  # integer targets and the mask get none
+
+
+_fused.defvjp(_fused_fwd, _fused_bwd)
 
 
 def fused_cross_entropy(
@@ -41,7 +182,7 @@ def fused_cross_entropy(
     bias_v: Optional[jnp.ndarray] = None,
     logit_scale: Optional[float] = None,
     chunk: int = 2048,
-    with_z: bool = False,
+    z_weight: float = 0.0,
 ):
     """Masked NLL sum without materializing full logits.
 
@@ -50,57 +191,24 @@ def fused_cross_entropy(
     targets [B, S]     int32
     mask    [B, S]     0/1
     bias_v  [V]        optional output-projection bias
-    Returns the fp32 scalar sum of masked token NLLs (caller divides by
-    the token count); with ``with_z`` returns ``(nll_sum, z_sum)`` where
-    z_sum is the masked sum of logsumexp(logits)^2 — the z-loss
-    regularizer's numerator (PaLM-style logit-drift control), computed
-    from the same per-chunk logsumexp at zero extra memory.
+    Returns the fp32 scalar ``sum(mask * (nll + z_weight * logsumexp^2))``
+    (caller divides by the token count): the masked token NLLs plus, for
+    ``z_weight > 0``, the z-loss regularizer (PaLM-style logit-drift
+    control) from the same per-chunk logsumexp at zero extra memory.
+    ``logit_scale``, ``chunk`` and ``z_weight`` are Python numbers.
+
+    Differentiable in reverse mode only, with respect to ``hidden``,
+    ``w_vd`` and ``bias_v``; ``targets`` and ``mask`` get no gradient.
     """
-    B, S, D = hidden.shape
-    N = B * S
-    x = hidden.reshape(N, D)
-    t = targets.reshape(N).astype(jnp.int32)
-    m = mask.reshape(N).astype(jnp.float32)
-
-    chunk = max(min(chunk, N), 1)
-    n_chunks = -(-N // chunk)
-    pad = n_chunks * chunk - N
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-        t = jnp.pad(t, (0, pad))
-        m = jnp.pad(m, (0, pad))
-    xs = x.reshape(n_chunks, chunk, D)
-    ts = t.reshape(n_chunks, chunk)
-    ms = m.reshape(n_chunks, chunk)
-
-    def body(acc, inp):
-        xc, tc, mc = inp
-        logits = jax.lax.dot_general(
-            xc, w_vd, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if bias_v is not None:
-            logits = logits + bias_v.astype(jnp.float32)
-        if logit_scale:
-            logits = logits * logit_scale
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, tc[:, None], axis=-1)[:, 0]
-        nll_c = jnp.sum((logz - gold) * mc)
-        if with_z:  # trace-time constant: pure-CE callers keep one carry
-            nll_acc, z_acc = acc
-            return (nll_acc + nll_c, z_acc + jnp.sum(jnp.square(logz) * mc)), None
-        return acc + nll_c, None
-
-    init = ((jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
-            if with_z else jnp.zeros((), jnp.float32))
-    acc, _ = jax.lax.scan(jax.checkpoint(body), init, (xs, ts, ms))
-    return acc  # (nll_sum, z_sum) when with_z, else the nll_sum scalar
+    return _fused(hidden, w_vd, targets, mask, bias_v, logit_scale or None,
+                  int(chunk), float(z_weight))
 
 
 def auto_chunk(batch: int, seq: int, vocab: int) -> int:
     """Chunk-size policy for ``fused_ce_chunk: -1`` (auto).
 
-    Fused CE pays one extra projection matmul per chunk (the remat); it wins
+    Fused CE costs the naive path's three projection matmuls and a scan
+    (and reverse-mode differentiation only: no ``jvp`` through it); it wins
     when the full logits tensor is HBM-significant. Threshold: enable when
     ``B*S*V`` fp32 exceeds 256 MB, with 2048-row chunks (a 2048 x 32k fp32
     chunk is 256 MB peak — comfortably resident)."""
@@ -118,7 +226,7 @@ def fused_cross_entropy_sp(
     bias_v: Optional[jnp.ndarray] = None,
     logit_scale: Optional[float] = None,
     chunk: int = 2048,
-    with_z: bool = False,
+    z_weight: float = 0.0,
 ):
     """Sequence-sharded fused CE for sp (context-parallel) meshes.
 
@@ -128,14 +236,15 @@ def fused_cross_entropy_sp(
     exists to avoid, and sp runs are where S is LONGEST. This variant
     drops to ``shard_map``: every device runs the chunked fused CE on its
     own local [B_local, S_local] block (chunking over local rows), and one
-    ``psum`` reduces the masked NLL sums. Requires the vocab projection
+    ``psum`` reduces the masked loss sums. Requires the vocab projection
     replicated — i.e. ``tp == 1`` (with tp, the projection is
     vocab-sharded and GSPMD's own vocab-parallel handling of the unfused
     path applies instead).
 
     Exactness: identical math to the single-device path — the row chunks
     are just distributed; the psum is the same fp32 sum re-associated per
-    device (tests assert loss AND grad parity on a dp x sp mesh).
+    device (tests assert loss AND grad parity on a dp x sp mesh). Reverse
+    mode only, like :func:`fused_cross_entropy`.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -158,14 +267,10 @@ def fused_cross_entropy_sp(
 
     def local(h, w, t, m, *rest):
         b = rest[0] if rest else None
-        nll, z = fused_cross_entropy(h, w, t, m, bias_v=b,
-                                     logit_scale=logit_scale, chunk=chunk,
-                                     with_z=True)
-        return jax.lax.psum((nll, z), tuple(mesh.axis_names))
+        loss = fused_cross_entropy(h, w, t, m, bias_v=b, logit_scale=logit_scale,
+                                   chunk=chunk, z_weight=z_weight)
+        return jax.lax.psum(loss, tuple(mesh.axis_names))
 
     fn = jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
-            out_specs=(P(), P()), check_vma=False)
-    nll_sum, z_sum = fn(*args)
-    if with_z:
-        return nll_sum, z_sum
-    return nll_sum
+                       out_specs=P(), check_vma=False)
+    return fn(*args)

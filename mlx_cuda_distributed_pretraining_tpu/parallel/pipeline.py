@@ -389,15 +389,15 @@ def make_pipeline_loss(
         def head_nll(out, tgt, msk):
             h = rms_norm(out, norm_w, args.rms_norm_eps)
             if ce_rows > 0:
-                out_ce = fused_ce.fused_cross_entropy(
-                    h, out_w.astype(compute_dtype).T, tgt, msk,
-                    logit_scale=args.logit_scale, chunk=ce_rows,
-                    with_z=z_loss_weight > 0.0,
-                )
-                if z_loss_weight > 0.0:
-                    nll, z = out_ce
-                    return nll + z_loss_weight * z, msk.sum()
-                return out_ce, msk.sum()
+                # The head runs inside the tick scan, which is differentiated
+                # from outside: left bare, each tick would keep the fused
+                # CE's float32 dW ([V, D]) for the backward pass. Under
+                # jax.checkpoint a tick keeps its inputs, and the backward
+                # pass computes the loss and the gradients in one walk.
+                fused = jax.checkpoint(partial(
+                    fused_ce.fused_cross_entropy, logit_scale=args.logit_scale,
+                    chunk=ce_rows, z_weight=z_loss_weight))
+                return fused(h, out_w.astype(compute_dtype).T, tgt, msk), msk.sum()
             # fp32-accumulated projection — matches the non-pp loss exactly.
             logits = jax.lax.dot_general(
                 h, out_w.astype(compute_dtype), (((2,), (0,)), ((), ())),

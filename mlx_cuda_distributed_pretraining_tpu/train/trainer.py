@@ -46,6 +46,7 @@ from ..obs.events import (
 )
 from ..obs import compiles
 from ..ops.flash_attention import plan_counts as flash_plan_counts
+from ..ops.fused_ce import plan_counts as fused_ce_plan_counts
 from ..obs.flops import (GoodputLedger, matmul_params, model_flops_per_token,
                          peak_flops_per_chip)
 from ..obs.flops import mfu as compute_mfu
@@ -469,10 +470,14 @@ class Trainer:
         # rides each step_window event as xla_compiles / xla_compile_s.
         self._compiles_seen = compiles.totals()
         # Which path the step's flash kernels, forward and backward, were traced to
-        # (ops/flash_attention.py flash_plan): the tally since here, logged
-        # after the first compile and carried by the first step_window event.
+        # (ops/flash_attention.py flash_plan), and whether its fused CE computes
+        # the head's gradients in the forward walk (ops/fused_ce.py): the tallies
+        # since here, logged after the first compile and carried by the first
+        # step_window event.
         self._flash_plan_seen = flash_plan_counts()
         self._flash_plan: Optional[Dict[str, int]] = None
+        self._fused_ce_plan_seen = fused_ce_plan_counts()
+        self._fused_ce_plan: Optional[Dict[str, int]] = None
         self._metrics_server = None
         # events.jsonl is the durable telemetry source: replay it FIRST so
         # counters survive crash-restarts, then open for append. Chief only
@@ -655,8 +660,13 @@ class Trainer:
             self.events.append("compile", seconds=round(seconds, 4), step=step)
         self._flash_plan = {path: n - self._flash_plan_seen[path]
                             for path, n in flash_plan_counts().items()}
-        self.logger.log("flash plan (kernel calls traced, by path): " + ", ".join(
-            f"{path}={n}" for path, n in self._flash_plan.items()))
+        self._fused_ce_plan = {walk: n - self._fused_ce_plan_seen[walk]
+                               for walk, n in fused_ce_plan_counts().items()}
+        self.logger.log(
+            "flash plan (kernel calls traced, by path): " + ", ".join(
+                f"{path}={n}" for path, n in self._flash_plan.items())
+            + "; fused CE (chunk walks traced): " + ", ".join(
+                f"{walk}={n}" for walk, n in self._fused_ce_plan.items()))
 
     def _touch_heartbeat(self, step: Optional[int] = None) -> None:
         if self._hb_path is None:
@@ -1339,7 +1349,8 @@ class Trainer:
                             self._compiles_seen = seen
                             if self._flash_plan is not None:
                                 ev["flash_plan"] = self._flash_plan
-                                self._flash_plan = None
+                                ev["fused_ce_plan"] = self._fused_ce_plan
+                                self._flash_plan = self._fused_ce_plan = None
                             if self.pipeline:
                                 ev["bubble"] = round(self._bubble_frac, 6)
                             # Latest graftprof fractions ride every window

@@ -463,7 +463,8 @@ def test_trainer_reports_the_flash_plan(tmp_path):
     from mlx_cuda_distributed_pretraining_tpu.train.trainer import Trainer
 
     cfg = _tiny_config(tmp_path, name="flashplan", iters=10,
-                       **{"model.attention.attention_type": "flash"})
+                       **{"model.attention.attention_type": "flash",
+                          "system.fused_ce_chunk": 64})
     tr = Trainer(cfg, runs_root=str(tmp_path / "runs"), quiet=True)
     tr.train()
     windows = [e for e in iter_events(events_path(tr.run_dir))
@@ -475,10 +476,16 @@ def test_trainer_reports_the_flash_plan(tmp_path):
     assert plan["bwd_dq_resident"] == plan["bwd_dkv_resident"] > 0
     assert plan["bwd_dq_streamed"] == plan["bwd_dkv_streamed"] == 0
     assert "flash_plan" not in windows[1]
+    # beside it, whether the fused CE computes the head's gradients in its
+    # forward walk (ops/fused_ce.py): the train step's one call does, and the
+    # validation before step 1 walked the forward only
+    ce_plan = windows[0]["fused_ce_plan"]
+    assert ce_plan == {"grad_in_forward": 1, "forward_only": 1}
+    assert "fused_ce_plan" not in windows[1]
     with open(tr.logger.log_path) as f:
         lines = [ln for ln in f if "flash plan" in ln]
     assert len(lines) == 1
-    assert all(f"{key}={n}" in lines[0] for key, n in plan.items())
+    assert all(f"{key}={n}" in lines[0] for key, n in {**plan, **ce_plan}.items())
 
 
 def test_trainer_registry_replays_on_construction(tmp_path):

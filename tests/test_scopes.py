@@ -25,6 +25,7 @@ from mlx_cuda_distributed_pretraining_tpu.config import Config, DataConfig
 from mlx_cuda_distributed_pretraining_tpu.models import llama
 from mlx_cuda_distributed_pretraining_tpu.obs import compiles
 from mlx_cuda_distributed_pretraining_tpu.obs.trace import Tracer
+from mlx_cuda_distributed_pretraining_tpu.ops import fused_ce
 from mlx_cuda_distributed_pretraining_tpu.ops import grouped_matmul as gm
 from mlx_cuda_distributed_pretraining_tpu.optim.enhanced import adamw
 from mlx_cuda_distributed_pretraining_tpu.serve import BatchEngine, EngineConfig, batch_step
@@ -68,14 +69,18 @@ def _args(**kw):
         "tie_word_embeddings": False, "attention_type": "flash", **kw})
 
 
-def _train_step_op_names(args, accum_steps=1):
+def _train_step_hlo(args, accum_steps=1):
     loss = partial(llama.loss_fn, args=args, remat="full", scan_layers=True, ce_chunk=16)
     opt = adamw(lambda count: 1e-3, grad_clip=1.0)
     step, _ = make_train_step(lambda p, b: loss(p, b), opt, accum_steps=accum_steps)
     params = llama.init_params(jax.random.PRNGKey(0), args)
     state = init_train_state(params, opt)
     batch = {k: jnp.ones((4, 32), jnp.int32) for k in ("inputs", "targets", "mask")}
-    return op_names(step.lower(state, batch).compile().as_text())
+    return step.lower(state, batch).compile().as_text()
+
+
+def _train_step_op_names(args, accum_steps=1):
+    return op_names(_train_step_hlo(args, accum_steps))
 
 
 @pytest.fixture(scope="module")
@@ -112,6 +117,92 @@ def test_train_step_keeps_its_name_and_the_kernels_theirs(dense_names):
     # and again in the recomputation: separable rows
     fwd = {("rematted_computation" in n) for n in dense_names if "/flash_fwd/" in n}
     assert fwd == {False, True}
+
+
+# -- the head: gradients in the fused CE's forward walk (ops/fused_ce.py) ------------
+HEAD_VOCAB, HEAD_CHUNK = 80, 16  # a vocabulary no other width of _args() equals
+
+
+def instructions(hlo_text):
+    """(opcode, result dimensions, op_name or None) of each instruction."""
+    out = []
+    for line in hlo_text.split("\n"):
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+        if m:
+            dims = [tuple(int(n) for n in d.split(",") if n)
+                    for d in re.findall(r"\[([\d,]*)\]", m.group(1))]
+            name = re.search(r'op_name="([^"]+)"', line)
+            out.append((m.group(2), dims, name.group(1) if name else None))
+    return out
+
+
+def _head_work(hlo_text):
+    """What only the head computes: everything shaped like a chunk of logits,
+    and every matmul over the vocabulary (parameters of fused computations
+    carry no name)."""
+    return [(op, dims, name) for op, dims, name in instructions(hlo_text)
+            if op != "parameter"
+            and ((HEAD_CHUNK, HEAD_VOCAB) in dims
+                 or (op == "dot" and any(HEAD_VOCAB in d for d in dims)))]
+
+
+@pytest.fixture(scope="module")
+def head_step():
+    """The compiled train step at a vocabulary of its own, and what tracing
+    it added to ``fused_ce.plan_counts()``."""
+    before = fused_ce.plan_counts()
+    hlo = _train_step_hlo(_args(vocab_size=HEAD_VOCAB))
+    after = fused_ce.plan_counts()
+    return hlo, {k: after[k] - before[k] for k in after}
+
+
+@pytest.fixture(scope="module")
+def head_eval():
+    """The same loss compiled with nothing differentiating it, as the
+    trainer's validation does."""
+    args = _args(vocab_size=HEAD_VOCAB)
+    params = llama.init_params(jax.random.PRNGKey(0), args)
+    batch = {k: jnp.ones((4, 32), jnp.int32) for k in ("inputs", "targets", "mask")}
+    before = fused_ce.plan_counts()
+    hlo = jax.jit(partial(llama.loss_fn, args=args, scan_layers=True, ce_chunk=HEAD_CHUNK)
+                  ).lower(params, batch).compile().as_text()
+    after = fused_ce.plan_counts()
+    return hlo, {k: after[k] - before[k] for k in after}
+
+
+def test_head_is_not_recomputed(head_step):
+    hlo, _ = head_step
+    head = [n for n in op_names(hlo) if "lm_head_ce" in scopes_of(n)]
+    assert head and not any("rematted_computation" in n for n in head)
+    # the layers' recomputation is the recipe's, and stays
+    assert any("rematted_computation" in n for n in op_names(hlo))
+
+
+def test_head_matmuls_three_in_the_train_step_one_in_an_evaluation(head_step, head_eval):
+    def head_dots(hlo):
+        return [dims for op, dims, name in instructions(hlo)
+                if op == "dot" and name and scopes_of(name)[-1:] == ["lm_head_ce"]]
+
+    V, D, C = HEAD_VOCAB, 32, HEAD_CHUNK
+    # one chunk walk: logits, dX of the chunk's rows, dW
+    assert sorted(d[0] for d in head_dots(head_step[0])) == sorted([(C, V), (C, D), (V, D)])
+    assert [d[0] for d in head_dots(head_eval[0])] == [(C, V)]
+
+
+def test_no_head_operation_is_unscoped(head_step, head_eval):
+    for hlo in (head_step[0], head_eval[0]):
+        work = _head_work(hlo)
+        assert len(work) >= 5
+        stray = [(op, dims, name) for op, dims, name in work
+                 if name is None or scopes_of(name)[-1:] != ["lm_head_ce"]]
+        assert not stray, stray
+    # the backward pass, which only scales the residuals, carries the scope too
+    assert any("transpose(jvp(lm_head_ce))" in n for n in op_names(head_step[0]))
+
+
+def test_fused_ce_plan_counts_tell_a_train_step_from_an_evaluation(head_step, head_eval):
+    assert head_step[1] == {"grad_in_forward": 1, "forward_only": 0}
+    assert head_eval[1] == {"grad_in_forward": 0, "forward_only": 1}
 
 
 def test_moe_step_scopes():

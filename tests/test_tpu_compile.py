@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from mlx_cuda_distributed_pretraining_tpu.models import llama
 from mlx_cuda_distributed_pretraining_tpu.ops import flash_attention as fa
+from mlx_cuda_distributed_pretraining_tpu.ops import fused_ce
 from mlx_cuda_distributed_pretraining_tpu.ops import grouped_matmul as gm
 
 HBM_BYTES = 16 * 10**9  # one v5e chip (Google Cloud TPU v5e documentation)
@@ -270,12 +271,15 @@ def test_paged_decode_step_fits_one_v5e(v5e, monkeypatch):
     assert total < HBM_BYTES, f"paged decode step needs {total / 1e9:.2f} GB"
 
 
-def test_scoped_train_step_compiles_for_v5e(v5e, compiled_kernels):
-    """The train step at the benchmark's Mistral-7B widths (two scanned
-    layers, full remat, flash, fused CE, Adafactor with clipping), as the
-    chip's compiler leaves it: every Mosaic call is one of the three named
-    flash kernels and every matmul sits under a scope of the vocabulary, so
-    a trace of the chip can be reduced by scope (README "Reading a profile")."""
+def test_scoped_train_step_compiles_for_v5e(v5e, compiled_kernels, monkeypatch):
+    """The train step of the benchmark's cell (Mistral-7B widths, four scanned
+    layers, full remat, flash, fused CE, Adafactor with clipping, 4 x 4,096),
+    as the chip's compiler leaves it: every Mosaic call is one of the three
+    named flash kernels and every matmul sits under a scope of the vocabulary,
+    so a trace of the chip can be reduced by scope (README "Reading a
+    profile"); the head is three matmuls a chunk walk, none of them
+    recomputed (ops/fused_ce.py); and the step fits the chip as the
+    benchmark's ``step_hbm_gib`` counts it."""
     import re
     from functools import partial
 
@@ -287,8 +291,11 @@ def test_scoped_train_step_compiles_for_v5e(v5e, compiled_kernels):
         "embed", "layer", "norm", "attn_qkv", "attn_core", "attn_out", "ffn",
         "final_norm", "lm_head_ce", "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
         "grad_accum", "grad_clip", "optimizer"}
+    # Declare the donation the step has on an accelerator (ops/donation.py):
+    # without it the state would be counted twice.
+    monkeypatch.setenv("GRAFTAUDIT_FORCE_DONATE", "1")
     args = llama.LlamaArgs(
-        vocab_size=32768, hidden_size=4096, intermediate_size=14336, num_layers=2,
+        vocab_size=32768, hidden_size=4096, intermediate_size=14336, num_layers=4,
         num_heads=32, num_kv_heads=8, head_dim=128, max_position_embeddings=32768,
         rope_theta=1e6, tie_word_embeddings=False, attention_type="flash")
     loss = partial(llama.loss_fn, args=args, compute_dtype=jnp.bfloat16,
@@ -300,7 +307,10 @@ def test_scoped_train_step_compiles_for_v5e(v5e, compiled_kernels):
     state = on_dev(jax.eval_shape(
         lambda: init_train_state(llama.init_params(jax.random.PRNGKey(0), args), opt)))
     batch = {k: _sds((4, 4096), jnp.int32, v5e) for k in ("inputs", "targets", "mask")}
-    hlo = step.lower(state, batch).compile().as_text()
+    ce_before = fused_ce.plan_counts()
+    compiled = step.lower(state, batch).compile()
+    ce_after = fused_ce.plan_counts()
+    hlo = compiled.as_text()
 
     def innermost(op_name):
         return next((t for t in reversed(re.split(r"[/()]", op_name)) if t in vocabulary),
@@ -323,3 +333,12 @@ def test_scoped_train_step_compiles_for_v5e(v5e, compiled_kernels):
     assert len(matmuls) >= 20
     assert {innermost(m) for m in matmuls} == {"attn_qkv", "attn_out", "ffn", "lm_head_ce"}, \
         sorted({m for m in matmuls if innermost(m) is None})
+    # logits, dX and dW of a chunk, all in the forward walk's loop
+    head = [m for m in matmuls if innermost(m) == "lm_head_ce"]
+    assert len(head) == 3 and not any("rematted_computation" in m for m in head), head
+    assert (ce_after["grad_in_forward"] - ce_before["grad_in_forward"],
+            ce_after["forward_only"] - ce_before["forward_only"]) == (1, 0)
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes > 0, "the state is not donated"
+    step_hbm_gib = (ma.argument_size_in_bytes + ma.temp_size_in_bytes) / 2**30
+    assert step_hbm_gib < 13.5, f"the cell's step needs {step_hbm_gib:.3f} GiB"
