@@ -62,6 +62,12 @@ class ModelConfig:
     rope: Dict[str, Any] = field(default_factory=dict)
     misc: Dict[str, Any] = field(default_factory=dict)
     moe: Dict[str, Any] = field(default_factory=dict)
+    # Sections only architecture "xing_mla_moe" reads (models/xing.py):
+    # latent attention's ranks and head sizes, the residual streams'
+    # hyperparameters, the multi-token-prediction module.
+    mla: Dict[str, Any] = field(default_factory=dict)
+    hyper_connections: Dict[str, Any] = field(default_factory=dict)
+    mtp: Dict[str, Any] = field(default_factory=dict)
     # Named rematerialization policy: "none" | "dots" | "full" |
     # "save_attn" (models/llama.py REMAT_POLICIES — save_attn keeps the
     # checkpoint_name-tagged attention activations and replays only the

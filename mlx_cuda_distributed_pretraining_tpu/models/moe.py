@@ -258,7 +258,7 @@ def _einsum_moe(
 
 
 # -- grouped (sort-based dropless) implementation ----------------------------
-def _grouped_ffn(
+def grouped_ffn(
     experts: Params,
     x_flat: jnp.ndarray,
     gate_idx: jnp.ndarray,
@@ -266,33 +266,42 @@ def _grouped_ffn(
     num_experts: int,
     block_t: int,
     precision=None,
+    first: int = 0,
 ) -> jnp.ndarray:
     """Sorted dropless expert FFN over local tokens.
 
-    x_flat [T, D], gate_idx [T, K] int32, gate_w [T, K] → out [T, D].
-    Selections are stably sorted by expert id and scattered into a
-    per-expert ``block_t``-aligned buffer (static size: every expert's group
-    rounds up to a full tile), the three expert matmuls run as grouped
-    GEMMs, and the gate-weighted rows scatter-add back. No capacity, no
-    drops.
+    x_flat [T, D], gate_idx [T, K] int32 (ids over the router's whole
+    width), gate_w [T, K] → out [T, D]. ``experts`` holds the
+    ``num_experts`` banks of ids ``first .. first + num_experts - 1``: a
+    selection of any other id belongs to an expert some other chip holds,
+    gets no row here and adds nothing (the caller's gate weights are
+    normalised over all chosen, held or not). Selections are stably sorted
+    by expert id, those of absent experts last, and scattered into a
+    per-expert ``block_t``-aligned buffer (static size: every selection
+    could be a held one, and every expert's group rounds up to a full
+    tile), the three expert matmuls run as grouped GEMMs, and the
+    gate-weighted rows scatter-add back. No capacity, no drops.
     """
     T, D = x_flat.shape
     K = gate_idx.shape[-1]
     TK = T * K
-    ids = gate_idx.reshape(TK)
+    local = gate_idx.reshape(TK) - first
+    # Selections of experts held elsewhere sort past every real group.
+    ids = jnp.where((local >= 0) & (local < num_experts), local, num_experts)
     tok = jnp.arange(TK, dtype=jnp.int32) // K
 
-    counts = jnp.bincount(ids, length=num_experts)  # [E]
+    counts = jnp.bincount(ids, length=num_experts + 1)[:num_experts]  # [E]
     padded = ((counts + block_t - 1) // block_t) * block_t
     p_off = jnp.concatenate([jnp.zeros((1,), padded.dtype), jnp.cumsum(padded)])
     raw_off = jnp.cumsum(counts) - counts  # group starts in sorted order
 
     order = jnp.argsort(ids, stable=True)  # token-major within each expert
     ids_s = ids[order]
-    rank = jnp.arange(TK, dtype=jnp.int32) - raw_off[ids_s].astype(jnp.int32)
-    dest = (p_off[ids_s] + rank).astype(jnp.int32)
-
+    real = ids_s < num_experts
+    ids_c = jnp.minimum(ids_s, num_experts - 1)
+    rank = jnp.arange(TK, dtype=jnp.int32) - raw_off[ids_c].astype(jnp.int32)
     T_buf = gm.round_up(TK + num_experts * (block_t - 1), block_t)
+    dest = jnp.where(real, (p_off[ids_c] + rank).astype(jnp.int32), T_buf)  # OOB = no row
     x_buf = jnp.zeros((T_buf, D), x_flat.dtype).at[dest].set(x_flat[tok[order]])
 
     gs = padded
@@ -317,10 +326,26 @@ def _grouped_ffn(
     ) * scaled(gm.gmm(x_buf, wu, gs, block_t=block_t, precision=precision), su)
     y_buf = scaled(gm.gmm(h, wd, gs, block_t=block_t, precision=precision), sd)
 
-    w_s = gate_w.reshape(TK)[order].astype(y_buf.dtype)
-    out = jnp.zeros((T, D), x_flat.dtype).at[tok[order]].add(
-        y_buf[dest] * w_s[:, None])
-    return out
+    w_s = jnp.where(real, gate_w.reshape(TK)[order], 0).astype(y_buf.dtype)
+    return jnp.zeros((T, D), x_flat.dtype).at[tok[order]].add(
+        y_buf[jnp.minimum(dest, T_buf - 1)] * w_s[:, None])
+
+
+def sigmoid_route(x: jnp.ndarray, router: Params, k: int, route_scale: float
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Sigmoid scoring with a selection bias → ``(gate_idx [..., K], gate_w
+    [..., K], scores [..., E])``, float32. The top-k is taken of ``scores +
+    bias``; the weights are the chosen *scores*, normalised over the chosen
+    and times ``route_scale``. ``router["bias"]`` is a buffer (it moves the
+    choice, never the weights, and so gets no gradient)."""
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "...d,de->...e", x, router["weight"].astype(x.dtype),
+        preferred_element_type=jnp.float32))
+    _, gate_idx = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(router["bias"].astype(jnp.float32)), k)
+    chosen = jnp.take_along_axis(scores, gate_idx, axis=-1)
+    gate_w = chosen / (chosen.sum(-1, keepdims=True) + 1e-20) * route_scale
+    return gate_idx, gate_w, scores
 
 
 def _usable_ep_mesh(args, num_experts: int):
@@ -514,7 +539,7 @@ def moe_block(p: Params, x: jnp.ndarray, args) -> Tuple[jnp.ndarray, jnp.ndarray
             if mesh is not None:
                 out, dropped = _grouped_moe_ep(p, x, gate_idx, gate_w, args, mesh)
             else:
-                out = _grouped_ffn(
+                out = grouped_ffn(
                     p["experts"], x.reshape(B * S, D), gate_idx.reshape(B * S, K),
                     gate_w.reshape(B * S, K), E,
                     gm.pick_block_t(B * S * K, E),

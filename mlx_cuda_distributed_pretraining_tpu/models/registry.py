@@ -21,9 +21,15 @@ class Architecture(NamedTuple):
     forward: Callable
     loss_fn: Callable
     force_attention: str | None = None
+    # (args, seq_len) -> training FLOPs a token, where the llama count
+    # (obs/flops.py) does not describe the model
+    flops_per_token: Callable | None = None
 
 
 _REGISTRY: Dict[str, Architecture] = {}
+# Architectures in modules of their own, imported (and so registered) when a
+# config first names them: a llama run pays nothing for them.
+_LAZY_MODULES = {"xing_mla_moe": "xing"}
 
 
 def register(arch: Architecture) -> None:
@@ -32,8 +38,13 @@ def register(arch: Architecture) -> None:
 
 def resolve_architecture(name: str) -> Architecture:
     key = name.lower()
+    if key not in _REGISTRY and key in _LAZY_MODULES:
+        import importlib
+
+        importlib.import_module("." + _LAZY_MODULES[key], __package__)
     if key not in _REGISTRY:
-        raise ValueError(f"unknown architecture {name!r}; available: {sorted(_REGISTRY)}")
+        raise ValueError(f"unknown architecture {name!r}; available: "
+                         f"{sorted(set(_REGISTRY) | set(_LAZY_MODULES))}")
     return _REGISTRY[key]
 
 

@@ -7,7 +7,8 @@ by reshaping to head groups (no materialized repeat), fp32 softmax, mask and
 score mods applied on index lattices.
 
 Layout convention throughout the framework: ``q [B, Sq, Hq, D]``,
-``k/v [B, Skv, Hkv, D]`` with Hq a multiple of Hkv.
+``k/v [B, Skv, Hkv, D]`` with Hq a multiple of Hkv; v's head size may
+differ from q's and k's (latent attention), and is then the output's.
 """
 
 from __future__ import annotations
@@ -65,4 +66,4 @@ def reference_attention(
     probs = probs.astype(v.dtype)
 
     out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, vh)
-    return out.reshape(B, Hq, Sq, D).transpose(0, 2, 1, 3)
+    return out.reshape(B, Hq, Sq, v.shape[-1]).transpose(0, 2, 1, 3)  # v may be narrower than q and k

@@ -368,7 +368,7 @@ def _fwd_resident_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_
     running max and denominator stay lane-replicated ``[bq, _LANES]``."""
     qi = pl.program_id(2)
     h = pl.program_id(1)
-    bq, D = q_ref.shape[2], q_ref.shape[3]
+    bq, D = q_ref.shape[2], v_ref.shape[3]  # the accumulator is as wide as v
     m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
     l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
     acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
@@ -721,7 +721,8 @@ def _plan_blocks(default, Sq, Skv, block_q, block_kv) -> Tuple[int, int]:
 
 
 def flash_plan(Sq: int, Skv: int, D: int, dtype, block_q: Optional[int] = None,
-               block_kv: Optional[int] = None, kernel: str = "flash_fwd") -> FlashPlan:
+               block_kv: Optional[int] = None, kernel: str = "flash_fwd",
+               Dv: Optional[int] = None) -> FlashPlan:
     """Which kernel a call of these shapes runs, and its blocks, for
     ``kernel`` ``flash_fwd``, ``flash_bwd_dq`` or ``flash_bwd_dkv``; a pure
     function of its arguments. ``resident`` holds in VMEM the two operands
@@ -730,11 +731,14 @@ def flash_plan(Sq: int, Skv: int, D: int, dtype, block_q: Optional[int] = None,
     context is bounded by HBM and not by VMEM; ``reference`` (no kernel) is
     for sequences no block divides. A block the caller names is taken as
     given (capped at the sequence); one left ``None`` is the path's default,
-    fitted to the sequence."""
+    fitted to the sequence. ``D`` is the head size of q and k, ``Dv`` that
+    of v and dO where it differs (latent attention: 192 and 128): of the two
+    operands a kernel holds, one is ``D`` wide and the other ``Dv``."""
     bq, bkv = _plan_blocks(_RESIDENT_BLOCKS, Sq, Skv, block_q, block_kv)
     if Sq % bq == 0 and Skv % bkv == 0:
-        lanes = -(-D // _LANES) * _LANES  # VMEM pads the head dim to whole registers
-        row_bytes = 2 * 2 * lanes * jnp.dtype(dtype).itemsize  # two operands, two buffers
+        # VMEM pads a head dim to whole registers; two operands, two buffers each
+        lanes = sum(-(-d // _LANES) * _LANES for d in (D, D if Dv is None else Dv))
+        row_bytes = 2 * lanes * jnp.dtype(dtype).itemsize
         if kernel == "flash_bwd_dkv":
             # Q and dO, and the [1, Sq] float32 rows of lse and delta, which
             # VMEM pads to 8 sublanes
@@ -772,13 +776,13 @@ def plan_counts() -> Dict[str, int]:
         return {key: _plan_counts[key] for key in _PLAN_KEYS}
 
 
-def _traced_plan(kernel, q, k, block_q, block_kv, _path) -> FlashPlan:
+def _traced_plan(kernel, q, k, v, block_q, block_kv, _path) -> FlashPlan:
     """The plan of one raw call, tallied; ``_path`` (the tests') overrides
     the choice and takes that path's default blocks."""
     Sq, D = q.shape[2:]
     Skv = k.shape[2]
     held = q if kernel == "flash_bwd_dkv" else k
-    plan = flash_plan(Sq, Skv, D, held.dtype, block_q, block_kv, kernel)
+    plan = flash_plan(Sq, Skv, D, held.dtype, block_q, block_kv, kernel, Dv=v.shape[3])
     if _path is not None and _path != plan.path:
         default = _RESIDENT_BLOCKS if _path == "resident" else _STREAMED_BLOCKS
         plan = FlashPlan(_path, *_plan_blocks(default, Sq, Skv, block_q, block_kv))
@@ -803,7 +807,7 @@ def flash_fwd(q, k, v, *, mask_fn=None, score_fn=None, mask_type="causal",
     the interior-tile fast path (skip in-tile masking where the tile is
     provably fully valid). :func:`flash_plan` picks the kernel from the
     shapes; ``_path`` is for tests, which run both on one input."""
-    plan = _traced_plan("flash_fwd", q, k, block_q, block_kv, _path)
+    plan = _traced_plan("flash_fwd", q, k, v, block_q, block_kv, _path)
     fwd = _flash_fwd_resident if plan.path == "resident" else _flash_fwd_streamed
     return fwd(q, k, v, plan.block_q, plan.block_kv, mask_fn=mask_fn, score_fn=score_fn,
                mask_type=mask_type, window=window, prefix_len=prefix_len,
@@ -814,6 +818,7 @@ def _flash_fwd_resident(q, k, v, bq, bkv, *, mask_fn, score_fn, mask_type,
                         window, prefix_len, scale, canonical_mask):
     B, Hq, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape
+    Dv = v.shape[3]
     G = Hq // Hkv
     kv_lo, kv_hi = _kv_range(mask_type, window, prefix_len, bq, bkv, Skv // bkv)
     full_range = (_full_range(mask_type, window, prefix_len, bq, bkv)
@@ -833,20 +838,20 @@ def _flash_fwd_resident(q, k, v, bq, bkv, *, mask_fn, score_fn, mask_type,
         in_specs=[
             _vmem_spec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
             _vmem_spec((1, 1, Skv, D), kv_index),
-            _vmem_spec((1, 1, Skv, D), kv_index),
+            _vmem_spec((1, 1, Skv, Dv), kv_index),
         ],
         out_specs=[
-            _vmem_spec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
+            _vmem_spec((1, 1, bq, Dv), lambda b, h, i: (b, h, i, 0)),
             _vmem_spec((1, 1, 1, bq), lambda b, h, i: (b, h, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, Hq, Sq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, Hq, 1, Sq), jnp.float32),
         ],
         scratch_shapes=[
             _scratch((bq, _LANES)),      # running max
             _scratch((bq, _LANES)),      # running denominator
-            _scratch((bq, D)),           # fp32 output accumulator
+            _scratch((bq, Dv)),          # fp32 output accumulator
         ],
         compiler_params=_resident_params(),
         interpret=_interpret(),
@@ -858,6 +863,7 @@ def _flash_fwd_streamed(q, k, v, bq, bkv, *, mask_fn, score_fn, mask_type,
                         window, prefix_len, scale, canonical_mask):
     B, Hq, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape
+    Dv = v.shape[3]
     G = Hq // Hkv
     nq = Sq // bq
     nkv = Skv // bkv
@@ -886,20 +892,20 @@ def _flash_fwd_streamed(q, k, v, bq, bkv, *, mask_fn, score_fn, mask_type,
         in_specs=[
             _vmem_spec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
             _vmem_spec((1, 1, bkv, D), kv_index),
-            _vmem_spec((1, 1, bkv, D), kv_index),
+            _vmem_spec((1, 1, bkv, Dv), kv_index),
         ],
         out_specs=[
-            _vmem_spec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            _vmem_spec((1, 1, bq, Dv), lambda b, h, i, j: (b, h, i, 0)),
             _vmem_spec((1, 1, 1, bq), lambda b, h, i, j: (b, h, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
+            jax.ShapeDtypeStruct((B, Hq, Sq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, Hq, 1, Sq), jnp.float32),
         ],
         scratch_shapes=[
             _scratch((bq, _LANES)),      # running max
             _scratch((bq, _LANES)),      # running denominator
-            _scratch((bq, D)),           # fp32 output accumulator
+            _scratch((bq, Dv)),          # fp32 output accumulator
         ],
         compiler_params=_compiler_params(3, 4),
         interpret=_interpret(),
@@ -913,7 +919,7 @@ def flash_bwd_dq(q, k, v, g, lse, delta, *, mask_fn=None, score_fn=None,
                  _path=None):
     """Raw dQ kernel. ``lse``/``delta``: [B, Hq, 1, Sq] fp32. Resident K/V
     or streamed by :func:`flash_plan`, as the forward (``_path``: tests)."""
-    plan = _traced_plan("flash_bwd_dq", q, k, block_q, block_kv, _path)
+    plan = _traced_plan("flash_bwd_dq", q, k, v, block_q, block_kv, _path)
     bwd = _flash_bwd_dq_resident if plan.path == "resident" else _flash_bwd_dq_streamed
     return bwd(q, k, v, g, lse, delta, plan.block_q, plan.block_kv, mask_fn=mask_fn,
                score_fn=score_fn, mask_type=mask_type, window=window,
@@ -928,15 +934,17 @@ def _flash_bwd_dq_resident(q, k, v, g, lse, delta, bq, bkv, *, mask_fn, score_fn
     kv_lo, kv_hi = _kv_range(mask_type, window, prefix_len, bq, bkv, Skv // bkv)
     full_range = (_full_range(mask_type, window, prefix_len, bq, bkv)
                   if canonical_mask and mask_type != "full" else None)
-    tile = _vmem_spec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0))
-    held = _vmem_spec((1, 1, Skv, D), lambda b, h, i: (b, h // G, 0, 0))
+    Dv = v.shape[3]  # v and dO; q, k and dQ are D wide
+    tile, tile_v = (_vmem_spec((1, 1, bq, d), lambda b, h, i: (b, h, i, 0)) for d in (D, Dv))
+    held, held_v = (_vmem_spec((1, 1, Skv, d), lambda b, h, i: (b, h // G, 0, 0))
+                    for d in (D, Dv))
     stat = _vmem_spec((1, 1, 1, bq), lambda b, h, i: (b, h, 0, i))
     return pl.pallas_call(
         functools.partial(
             _bwd_dq_resident_kernel, scale=scale, mask_fn=mask_fn, score_fn=score_fn,
             kv_lo=kv_lo, kv_hi=kv_hi, bkv=bkv, full_range=full_range),
         grid=(B, Hq, Sq // bq),
-        in_specs=[tile, held, held, tile, stat, stat],
+        in_specs=[tile, held, held_v, tile_v, stat, stat],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
         scratch_shapes=[_scratch((bq, D))],
@@ -971,8 +979,8 @@ def _flash_bwd_dq_streamed(q, k, v, g, lse, delta, bq, bkv, *, mask_fn, score_fn
         in_specs=[
             _vmem_spec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
             _vmem_spec((1, 1, bkv, D), kv_index),
-            _vmem_spec((1, 1, bkv, D), kv_index),
-            _vmem_spec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            _vmem_spec((1, 1, bkv, v.shape[3]), kv_index),
+            _vmem_spec((1, 1, bq, v.shape[3]), lambda b, h, i, j: (b, h, i, 0)),
             _vmem_spec((1, 1, 1, bq), lambda b, h, i, j: (b, h, 0, i)),
             _vmem_spec((1, 1, 1, bq), lambda b, h, i, j: (b, h, 0, i)),
         ],
@@ -992,7 +1000,7 @@ def flash_bwd_dkv(q, k, v, g, lse, delta, *, mask_fn=None, score_fn=None,
     """Raw dK/dV kernel. Returns per-QUERY-head grads [B, Hq, Skv, D]
     (caller reduces GQA groups). Resident Q/dO or streamed by
     :func:`flash_plan` (``_path``: tests)."""
-    plan = _traced_plan("flash_bwd_dkv", q, k, block_q, block_kv, _path)
+    plan = _traced_plan("flash_bwd_dkv", q, k, v, block_q, block_kv, _path)
     bwd = _flash_bwd_dkv_resident if plan.path == "resident" else _flash_bwd_dkv_streamed
     return bwd(q, k, v, g, lse, delta, plan.block_q, plan.block_kv, mask_fn=mask_fn,
                score_fn=score_fn, mask_type=mask_type, window=window,
@@ -1009,22 +1017,24 @@ def _flash_bwd_dkv_resident(q, k, v, g, lse, delta, bq, bkv, *, mask_fn, score_f
                   if canonical_mask and mask_type != "full" else None)
     # Q, dO and the statistics are the query head's own: fetched once a
     # (sequence, query head), whatever the KV tile.
-    held = _vmem_spec((1, 1, Sq, D), lambda b, h, i: (b, h, 0, 0))
+    Dv = v.shape[3]  # v, dO and dV; q, k and dK are D wide
+    held, held_v = (_vmem_spec((1, 1, Sq, d), lambda b, h, i: (b, h, 0, 0)) for d in (D, Dv))
     stat = _vmem_spec((1, 1, 1, Sq), lambda b, h, i: (b, h, 0, 0))
-    tile = _vmem_spec((1, 1, bkv, D), lambda b, h, i: (b, h // G, i, 0))
-    out = _vmem_spec((1, 1, bkv, D), lambda b, h, i: (b, h, i, 0))
+    tile, tile_v = (_vmem_spec((1, 1, bkv, d), lambda b, h, i: (b, h // G, i, 0))
+                    for d in (D, Dv))
+    out, out_v = (_vmem_spec((1, 1, bkv, d), lambda b, h, i: (b, h, i, 0)) for d in (D, Dv))
     return pl.pallas_call(
         functools.partial(
             _bwd_dkv_resident_kernel, scale=scale, mask_fn=mask_fn, score_fn=score_fn,
             q_lo=q_lo, q_hi=q_hi, bq=bq, full_range=full_range),
         grid=(B, Hq, Skv // bkv),
-        in_specs=[held, tile, tile, held, stat, stat],
-        out_specs=[out, out],
+        in_specs=[held, tile, tile_v, held_v, stat, stat],
+        out_specs=[out, out_v],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hq, Skv, D), k.dtype),
-            jax.ShapeDtypeStruct((B, Hq, Skv, D), v.dtype),
+            jax.ShapeDtypeStruct((B, Hq, Skv, Dv), v.dtype),
         ],
-        scratch_shapes=[_scratch((bkv, D)), _scratch((bkv, D))],
+        scratch_shapes=[_scratch((bkv, D)), _scratch((bkv, Dv))],
         compiler_params=_resident_params(),
         interpret=_interpret(),
         name="flash_bwd_dkv",
@@ -1035,6 +1045,7 @@ def _flash_bwd_dkv_streamed(q, k, v, g, lse, delta, bq, bkv, *, mask_fn, score_f
                             mask_type, window, prefix_len, scale, canonical_mask):
     B, Hq, Sq, D = q.shape
     _, Hkv, Skv, _ = k.shape
+    Dv = v.shape[3]
     G = Hq // Hkv
     nq = Sq // bq
     nkv = Skv // bkv
@@ -1061,20 +1072,20 @@ def _flash_bwd_dkv_streamed(q, k, v, g, lse, delta, bq, bkv, *, mask_fn, score_f
         in_specs=[
             _vmem_spec((1, 1, bq, D), q_index),
             _vmem_spec((1, 1, bkv, D), lambda b, h, i, j: (b, h // G, i, 0)),
-            _vmem_spec((1, 1, bkv, D), lambda b, h, i, j: (b, h // G, i, 0)),
-            _vmem_spec((1, 1, bq, D), q_index),
+            _vmem_spec((1, 1, bkv, Dv), lambda b, h, i, j: (b, h // G, i, 0)),
+            _vmem_spec((1, 1, bq, Dv), q_index),
             _vmem_spec((1, 1, 1, bq), stat_index),
             _vmem_spec((1, 1, 1, bq), stat_index),
         ],
         out_specs=[
             _vmem_spec((1, 1, bkv, D), lambda b, h, i, j: (b, h, i, 0)),
-            _vmem_spec((1, 1, bkv, D), lambda b, h, i, j: (b, h, i, 0)),
+            _vmem_spec((1, 1, bkv, Dv), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hq, Skv, D), k.dtype),
-            jax.ShapeDtypeStruct((B, Hq, Skv, D), v.dtype),
+            jax.ShapeDtypeStruct((B, Hq, Skv, Dv), v.dtype),
         ],
-        scratch_shapes=[_scratch((bkv, D)), _scratch((bkv, D))],
+        scratch_shapes=[_scratch((bkv, D)), _scratch((bkv, Dv))],
         compiler_params=_compiler_params(3, 4),
         interpret=_interpret(),
         name="flash_bwd_dkv",
@@ -1119,7 +1130,7 @@ def _attention_core(
         # GQA: reduce per-query-head dK/dV over each group
         if G > 1:
             dk = dk_h.reshape(B, Hkv, G, Skv, D).sum(axis=2).astype(k.dtype)
-            dv = dv_h.reshape(B, Hkv, G, Skv, D).sum(axis=2).astype(v.dtype)
+            dv = dv_h.reshape(B, Hkv, G, Skv, v.shape[3]).sum(axis=2).astype(v.dtype)
         else:
             dk, dv = dk_h, dv_h
         return dq, dk, dv
@@ -1184,7 +1195,9 @@ def flash_attention(
     score_fn: Optional[Callable] = None,
     precision: Optional[str] = None,
 ) -> jnp.ndarray:
-    """Flash attention on [B, S, H, D] layout (framework convention).
+    """Flash attention on [B, S, H, D] layout (framework convention). The
+    head size of v (and so of the output) may differ from that of q and k:
+    all three kernels and the plan take both from the call's shapes.
 
     ``mask_type`` selects the block-sparsity plan (causal / sliding_window /
     prefix_lm / full); ``mask_fn``/``score_fn`` override the in-tile
@@ -1226,7 +1239,8 @@ def flash_attention(
     # sequence the forward's blocks do not divide, no kernel's do.
     block_q = block_q and fit_block(block_q, Sq)
     block_kv = block_kv and fit_block(block_kv, Skv)
-    no_kernel = flash_plan(Sq, Skv, D, k.dtype, block_q, block_kv).path == "reference"
+    no_kernel = flash_plan(Sq, Skv, D, k.dtype, block_q, block_kv,
+                           Dv=v.shape[3]).path == "reference"
 
     from . import masks as M
 

@@ -37,11 +37,20 @@ _RULES = [
     (r"attention\.w[qkv]\.weight_s$", ("tp",)),              # [H*Dh]
     (r"attention\.wo\.weight(_q4?)?$", ("tp", "fsdp")),      # [H*Dh, D] row
     (r"attention\.wo\.weight_s$", ("fsdp",)),                # [D]
+    # Latent attention (models/xing.py): the down-projections to the latents
+    # are replicated over tp (every head reads the whole latent), the
+    # up-projections are column-parallel over heads like wq/wk/wv.
+    (r"attention\.w(q|kv)_a\.weight$", ("fsdp", None)),      # [D, rank]
+    (r"attention\.w(q|kv)_b\.weight$", ("fsdp", "tp")),      # [rank, H*d]
     (r"experts\.w_(gate|up)\.weight(_q4?)?$", ("ep", "fsdp", "tp")),  # [E, D, I]
     (r"experts\.w_(gate|up)\.weight_s$", ("ep", "tp")),               # [E, I]
     (r"experts\.w_down\.weight(_q4?)?$", ("ep", "tp", "fsdp")),       # [E, I, D]
     (r"experts\.w_down\.weight_s$", ("ep", "fsdp")),                  # [E, D]
     (r"feed_forward\.router\.weight$", ("fsdp", None)),        # [D, E]
+    (r"shared\.w_(gate|up)\.weight$", ("fsdp", "tp")),         # shared expert, as a dense MLP
+    (r"shared\.w_down\.weight$", ("tp", "fsdp")),
+    (r"_hc\.phi\.weight$", ("fsdp", None)),                   # [n*D, n+n+n*n] mixing map
+    (r"eh_proj\.weight$", ("fsdp", None)),                    # [2D, D] MTP joining projection
     (r"feed_forward\.w_(gate|up)\.weight(_q4?)?$", ("fsdp", "tp")),  # [D, I] column
     (r"feed_forward\.w_(gate|up)\.weight_s$", ("tp",)),              # [I]
     (r"feed_forward\.w_down\.weight(_q4?)?$", ("tp", "fsdp")),       # [I, D] row

@@ -63,14 +63,10 @@ def make_train_step(
     ``with_moe_stats`` and returns ``(loss, (token_count, stats))``
     (models/llama.py loss_fn / models/moe.py): the layer-summed expert-load
     vector and dropped-selection count then ride the metrics dict as
-    ``moe_load`` [E] / ``moe_dropped``.
+    ``moe_load`` [E] / ``moe_dropped``, with whatever else the model's
+    stats hold (a second head's ``main_loss`` / ``mtp_loss``: models/xing.py).
     """
     moe_stats = moe_stats_experts > 0
-
-    def zero_stats():
-        from ..models.moe import zero_stats as zs
-
-        return zs(moe_stats_experts)
 
     def grads_of(params, batch):
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, batch)
@@ -87,7 +83,11 @@ def make_train_step(
 
         micro = jax.tree_util.tree_map(reshape, batch)
         zero_g = jax.tree_util.tree_map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-        zero_s = zero_stats() if moe_stats else None
+        # every key the loss_fn reports, which may be more than the routing pair
+        zero_s = jax.tree_util.tree_map(
+            lambda a: jnp.zeros(a.shape, a.dtype),
+            jax.eval_shape(lambda: grads_of(params, jax.tree_util.tree_map(
+                lambda x: x[0], micro))[2])) if moe_stats else None
 
         def body(carry, mb):
             acc_loss, acc_toks, acc_s, acc_g = carry
@@ -105,6 +105,8 @@ def make_train_step(
         )
         with jax.named_scope("grad_accum"):
             inv = 1.0 / accum_steps
+            if moe_stats:  # loss terms average over microbatches, counts add up
+                stats = {k: v * inv if k.endswith("_loss") else v for k, v in stats.items()}
             return loss_sum * inv, toks, stats, jax.tree_util.tree_map(lambda g: g * inv, grads)
 
     def train_step(state: TrainState, batch: Dict[str, jnp.ndarray]):
@@ -130,8 +132,7 @@ def make_train_step(
             "nonfinite": jnp.logical_not(jnp.isfinite(loss)).astype(jnp.int32),
         }
         if moe_stats:
-            metrics["moe_load"] = stats["moe_load"]
-            metrics["moe_dropped"] = stats["moe_dropped"]
+            metrics.update(stats)
         if log_grad_norm:
             metrics["grad_norm"] = global_norm(grads)
         new_state = {"params": new_params, "opt_state": opt_state, "step": state["step"] + 1}

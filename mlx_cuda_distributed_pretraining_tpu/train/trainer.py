@@ -33,7 +33,6 @@ from ..config import Config, apply_overrides
 from ..data import DataManager
 from ..data.device_prefetch import DevicePrefetcher
 from ..data.streaming import build_data_manager
-from ..models.llama import LlamaArgs
 from ..models import llama as llama_mod
 from ..models.registry import resolve_architecture
 from ..obs import Logger
@@ -206,7 +205,7 @@ class Trainer:
                 if os.path.isfile(idx_path):
                     with open(idx_path) as f:
                         vocab_size = int(json.load(f).get("vocab_size", vocab_size))
-        args = LlamaArgs.from_config(cfg.model, vocab_size)
+        args = arch.args_cls.from_config(cfg.model, vocab_size)
         if arch.force_attention:
             args = args.__class__(**{**args.__dict__, "attention_type": arch.force_attention})
         self.model_args = args
@@ -426,9 +425,11 @@ class Trainer:
         # MFU accounting: analytic FLOPs/token from the model config + exact
         # param count, peak from the chip's device_kind (None on CPU — log
         # lines then report mfu=unknown; an unlisted accelerator raises).
-        self.flops_per_token = model_flops_per_token(
-            cfg.model, self.n_params, cfg.data.max_context_size,
-            vocab_size=self.model_args.vocab_size)
+        self.flops_per_token = (
+            arch.flops_per_token(args, cfg.data.max_context_size)
+            if arch.flops_per_token is not None else model_flops_per_token(
+                cfg.model, self.n_params, cfg.data.max_context_size,
+                vocab_size=self.model_args.vocab_size))
         self.peak_flops = peak_flops_per_chip()
         self.goodput = GoodputLedger()
         # Span tracer (obs/trace.py): mirrors every goodput booking as a
@@ -1303,8 +1304,11 @@ class Trainer:
                             # exchange factor).
                             import numpy as _np
 
-                            load = _np.asarray(sum(m[0] for m in window_moe), _np.float64)
-                            dropped = int(sum(m[1] for m in window_moe))
+                            # Summed on the host: adding device arrays here would
+                            # launch a device program or two a window, which a
+                            # profile's Steps line counts as steps of their own.
+                            load = sum(_np.asarray(m[0], _np.float64) for m in window_moe)
+                            dropped = int(sum(float(m[1]) for m in window_moe))
                             total = max(load.sum(), 1.0)
                             frac = load / total
                             nz = frac[frac > 0]
@@ -1312,11 +1316,23 @@ class Trainer:
                             line["moe_entropy"] = ent
                             line["moe_drop"] = dropped
                             line["moe_load_max"] = float(frac.max())
+                            held = getattr(self.model_args, "experts_held", None)
+                            if held is not None:
+                                # An expert layer that holds a share of the
+                                # router's experts: selections that landed on
+                                # it, and how unevenly.
+                                mine = load[held[0]:held[0] + held[1]]
+                                line["moe_rows_held"] = int(mine.sum())
+                                line["moe_load_max_over_mean"] = float(
+                                    mine.max() / max(mine.mean(), 1e-9))
                             self._g_moe_entropy.set(ent)
                             self._m_moe_dropped.inc(dropped)
                             for e, f in enumerate(frac):
                                 self._g_moe_load.set(float(f), expert=str(e))
                             window_moe = []
+                        for term in ("main_loss", "mtp_loss"):  # a loss of several heads
+                            if term in metrics:
+                                line[term] = float(metrics[term])
                         if int(metrics["nonfinite"]):
                             self.logger.log(f"WARNING: non-finite loss at step {step}")
                             self._m_nonfinite.inc()
@@ -1353,6 +1369,9 @@ class Trainer:
                                 self._flash_plan = self._fused_ce_plan = None
                             if self.pipeline:
                                 ev["bubble"] = round(self._bubble_frac, 6)
+                            ev.update({k: line[k] for k in (
+                                "moe_rows_held", "moe_load_max_over_mean", "moe_drop",
+                                "main_loss", "mtp_loss") if k in line})
                             # Latest graftprof fractions ride every window
                             # after a capture, so the durable stream records
                             # the breakdown next to the tok/s it explains.
@@ -1497,7 +1516,8 @@ def load_trained(run_name_or_dir: str, runs_root: str = "runs", mesh=None,
     run_dir = run_name_or_dir if os.path.isdir(run_name_or_dir) else os.path.join(runs_root, run_name_or_dir)
     cfg = Config.from_yaml(os.path.join(run_dir, "config.yaml"))
     tok = TokenizerManager.from_run_dir(run_dir)
-    args = LlamaArgs.from_config(cfg.model, tok.vocab_size)
+    ref = resolve_architecture(cfg.model.architecture)
+    args = ref.args_cls.from_config(cfg.model, tok.vocab_size)
     ckpts = CheckpointManager(run_dir)
     # Verified resolution: never serve a torn checkpoint (falling back to
     # unverified pre-manifest steps only). Read-only scan: this path may
@@ -1508,7 +1528,6 @@ def load_trained(run_name_or_dir: str, runs_root: str = "runs", mesh=None,
     if tag is None:
         raise FileNotFoundError(f"no verified checkpoints in {run_dir}")
     model_path, _, _ = ckpts.paths_for_step(tag)
-    ref = resolve_architecture(cfg.model.architecture)
     from ..models.quantize import check_weight_dtype, quantize_weights
 
     wd = check_weight_dtype(weight_dtype)

@@ -535,3 +535,56 @@ def test_flash_plan_backward_at_the_budgets_edge(kernel):
     short_name = kernel[len("flash_"):]
     assert {p: after[p] - before[p] for p in after if after[p] != before[p]} == {
         f"{short_name}_resident": 1, f"{short_name}_streamed": 1}
+
+
+# -- head sizes that differ: q and k wider than v (latent attention) ----------
+def _qkv_two_widths(dk, dv, hq=4, hkv=4, s=S, seed=3):
+    rng = np.random.default_rng(seed)
+    draw = lambda h, d: jnp.asarray(rng.normal(size=(B, s, h, d)).astype(np.float32))
+    return draw(hq, dk), draw(hkv, dk), draw(hkv, dv)
+
+
+@pytest.mark.parametrize("dk,dv", [(48, 32), (24, 16), (32, 64)])
+def test_two_head_sizes_forward_and_gradients(dk, dv, flash_path):
+    """q and k of one head size, v of another, through all three kernels of
+    either path against the einsum reference: output [.., dv], dq and dk
+    [.., dk], dv [.., dv]."""
+    q, k, v = _qkv_two_widths(dk, dv)
+    scale = 0.7 * dk ** -0.5  # a scale the caller names, as latent attention does
+    weight = jnp.cos(jnp.arange(dv, dtype=jnp.float32))
+    got = lambda q, k, v: jnp.sum(weight * flash_attention(
+        q, k, v, scale=scale, block_q=BLOCK, block_kv=BLOCK))
+    want = lambda q, k, v: jnp.sum(weight * reference_attention(
+        q, k, v, mask_mod=M.causal(), scale=scale))
+    out = flash_attention(q, k, v, scale=scale, block_q=BLOCK, block_kv=BLOCK)
+    assert out.shape == (B, S, 4, dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(
+        reference_attention(q, k, v, mask_mod=M.causal(), scale=scale)), atol=2e-5, rtol=2e-5)
+    for g, r, name in zip(jax.grad(got, (0, 1, 2))(q, k, v), jax.grad(want, (0, 1, 2))(q, k, v),
+                          ("dq", "dk", "dv")):
+        assert g.shape == r.shape, name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=5e-5, rtol=5e-5,
+                                   err_msg=name)
+
+
+def test_two_head_sizes_grouped_queries(flash_path):
+    q, k, v = _qkv_two_widths(48, 32, hq=4, hkv=2)
+    got = lambda q, k, v: jnp.sum(jnp.sin(flash_attention(q, k, v, block_q=BLOCK, block_kv=BLOCK)))
+    want = lambda q, k, v: jnp.sum(jnp.sin(reference_attention(q, k, v, mask_mod=M.causal())))
+    for g, r in zip(jax.grad(got, (0, 1, 2))(q, k, v), jax.grad(want, (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=5e-5, rtol=5e-5)
+
+
+def test_flash_plan_takes_both_head_sizes():
+    """The plan's VMEM count holds one operand of each width; with equal
+    widths it is the count it always was, so a dense model's plan (and its
+    compiled step) does not move."""
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        same = fa.flash_plan(4096, 4096, 128, jnp.bfloat16, kernel=kernel)
+        assert same == fa.flash_plan(4096, 4096, 128, jnp.bfloat16, kernel=kernel, Dv=128)
+        assert fa.flash_plan(4096, 4096, 192, jnp.bfloat16, kernel=kernel, Dv=128) == same
+    # 192 pads to 256 lanes: held K (256) + V (128) is 3/4 of a 256/256 call
+    edge = max(s for s in (2 ** n for n in range(9, 18))
+               if fa.flash_plan(s, s, 192, jnp.bfloat16, Dv=128).path == "resident")
+    assert fa.flash_plan(edge, edge, 192, jnp.bfloat16, Dv=192).path == "resident"
+    assert fa.flash_plan(2 * edge, 2 * edge, 192, jnp.bfloat16, Dv=128).path == "streamed"
