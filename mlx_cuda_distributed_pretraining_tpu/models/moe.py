@@ -9,9 +9,14 @@ real block with two interchangeable dispatch implementations
 - ``grouped`` (default) — MegaBlocks-style **dropless** routing: fp32 router
   → top-k → stable argsort by expert id → gather into a per-expert
   block-aligned buffer → grouped GEMM SwiGLU (ops/grouped_matmul.py) →
-  scatter-add combine. Every shape is static (sort + gather, no
-  data-dependent shapes) and **no token is ever dropped** — there is no
-  expert capacity. On ``ep`` meshes the sorted dispatch drops below GSPMD
+  gather of each token's rows back and a gate-weighted sum. Every shape is
+  static (sort + gather, no data-dependent shapes) and **no token is ever
+  dropped** — there is no expert capacity. On one device both directions
+  are gathers in the backward pass too (:func:`dispatch_rows`,
+  :func:`combine_rows`: custom backwards over one :class:`DispatchPlan`),
+  so the layer's gradient holds no scatter of activation rows. On ``ep``
+  meshes (``_grouped_moe_ep``, which still scatters and can take the same
+  two functions) the sorted dispatch drops below GSPMD
   via ``jax.shard_map``: each shard routes its local tokens,
   exchanges rows with the owning expert shard through a pair of
   ``all_to_all`` collectives with static per-destination send slots, and
@@ -40,8 +45,10 @@ them to the train step.
 
 from __future__ import annotations
 
+import collections
 import contextlib
-from typing import Any, Dict, List, Optional, Tuple
+import threading
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -258,6 +265,153 @@ def _einsum_moe(
 
 
 # -- grouped (sort-based dropless) implementation ----------------------------
+# The dispatch into the expert buffer and the combine out of it are gathers in
+# both directions: each is a ``jax.custom_vjp`` whose backward gathers the
+# other way through the same plan, so neither the forward nor the backward
+# holds a scatter of activation rows (XLA's scatters on the TPU are serial in
+# their updates). Counted while tracing, so this counts traces, not calls of
+# the compiled step: what a jitted program runs is what its one trace counted.
+_PLAN_KEYS = ("dispatch_gather", "combine_gather")
+_plan_counts: Dict[str, int] = collections.Counter()
+_plan_counts_lock = threading.Lock()
+
+
+def _count_plan(key: str) -> None:
+    with _plan_counts_lock:
+        _plan_counts[key] += 1
+
+
+def plan_counts() -> Dict[str, int]:
+    """Dispatches into an expert buffer and combines out of one traced so far
+    in this process, both in the gather form (:func:`dispatch_rows`,
+    :func:`combine_rows`)."""
+    with _plan_counts_lock:
+        return {key: _plan_counts[key] for key in _PLAN_KEYS}
+
+
+class DispatchPlan(NamedTuple):
+    """Both directions of one dispatch, as indices: no array here is made by
+    a scatter. Rows are the buffer's (``T_buf``), selections are ``[T, K]``."""
+    group_sizes: jnp.ndarray  # [E] rows of each expert's group, block_t-aligned
+    row_sel: jnp.ndarray      # [T_buf] flat selection t * K + k of the row (0 where dead)
+    row_live: jnp.ndarray     # [T_buf] bool: the row holds a selection
+    sel_row: jnp.ndarray      # [T, K] buffer row of the selection (0 where not held)
+    sel_held: jnp.ndarray     # [T, K] bool: the selection's expert is held here
+
+    @property
+    def row_tok(self) -> jnp.ndarray:
+        """[T_buf] token whose row this is (0 where dead)."""
+        return self.row_sel // self.sel_row.shape[-1]
+
+
+def dispatch_plan(gate_idx: jnp.ndarray, num_experts: int, block_t: int,
+                  first: int = 0) -> DispatchPlan:
+    """Where every selection's row lies in the ``block_t``-aligned buffer of
+    the ``num_experts`` experts ``first ..``, and which selection every row
+    holds. Token-major within an expert, as a stable sort by expert id gives.
+
+    A selection's rank inside its group is a running count over the one-hot of
+    its expert id, so its row comes selection-major without inverting the
+    sort; a row finds its group by comparing its number with the groups'
+    bounds, and its selection in the sorted order at the group's start plus
+    its rank. Selections of experts held elsewhere have an all-zero one-hot."""
+    T, K = gate_idx.shape
+    TK = T * K
+    T_buf = gm.round_up(TK + num_experts * (block_t - 1), block_t)
+    local = gate_idx.reshape(TK).astype(jnp.int32) - first
+    held = (local >= 0) & (local < num_experts)
+    onehot = (local[:, None] == jnp.arange(num_experts, dtype=jnp.int32)).astype(jnp.int32)
+    running = jnp.cumsum(onehot, axis=0)                       # [TK, E]
+    counts = running[-1]
+    padded = ((counts + block_t - 1) // block_t) * block_t
+    p_off = jnp.cumsum(padded) - padded                        # group starts in the buffer
+    raw_off = jnp.cumsum(counts) - counts                      # group starts in sorted order
+    sel_row = jnp.sum(onehot * (p_off + running - 1), axis=-1)  # 0 where not held
+
+    # Selections of experts held elsewhere sort past every real group.
+    order = jnp.argsort(jnp.where(held, local, num_experts), stable=True).astype(jnp.int32)
+    row = jnp.arange(T_buf, dtype=jnp.int32)[:, None]
+    in_group = (row >= p_off) & (row < p_off + counts)         # [T_buf, E], one-hot or zero
+    row_live = in_group.any(-1)
+    sorted_pos = jnp.sum(jnp.where(in_group, row + (raw_off - p_off), 0), axis=-1)
+    row_sel = jnp.where(row_live, order.at[sorted_pos].get(mode="promise_in_bounds"), 0)
+    return DispatchPlan(padded, row_sel, row_live, sel_row.reshape(T, K), held.reshape(T, K))
+
+
+def _take_rows(a: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``a[idx]`` along axis 0; a plan's indices are in bounds by construction."""
+    return a.at[idx].get(mode="promise_in_bounds")
+
+
+def _sum_held(rows: jnp.ndarray, scale: jnp.ndarray, dtype) -> jnp.ndarray:
+    """``sum_k scale[t, k] * rows[t, k]`` accumulated in float32, cast once."""
+    return jnp.sum(rows.astype(jnp.float32) * scale[..., None].astype(jnp.float32),
+                   axis=1).astype(dtype)
+
+
+def _dispatch_rows(x_flat, plan):
+    return jnp.where(plan.row_live[:, None], _take_rows(x_flat, plan.row_tok), 0)
+
+
+def _dispatch_fwd(x_flat, plan):
+    return _dispatch_rows(x_flat, plan), plan
+
+
+def _dispatch_bwd(plan, dx_buf):
+    with jax.named_scope("moe_experts"):
+        dx = _sum_held(_take_rows(dx_buf, plan.sel_row), plan.sel_held, dx_buf.dtype)
+    return dx, None
+
+
+_dispatch = jax.custom_vjp(_dispatch_rows)
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+def _combine_rows(y_buf, gate_w, plan):
+    return _sum_held(_take_rows(y_buf, plan.sel_row),
+                     jnp.where(plan.sel_held, gate_w, 0), y_buf.dtype)
+
+
+def _combine_fwd(y_buf, gate_w, plan):
+    return _combine_rows(y_buf, gate_w, plan), (y_buf, gate_w, plan)
+
+
+def _combine_bwd(residuals, dout):
+    y_buf, gate_w, plan = residuals
+    with jax.named_scope("moe_experts"):
+        w_row = _take_rows(gate_w.reshape(-1), plan.row_sel).astype(jnp.float32)
+        dy_buf = jnp.where(
+            plan.row_live[:, None],
+            _take_rows(dout, plan.row_tok).astype(jnp.float32) * w_row[:, None],
+            0).astype(y_buf.dtype)
+        # The forward's gathered rows again, and a row dot with the cotangent.
+        rows = _take_rows(y_buf, plan.sel_row).astype(jnp.float32)
+        dw = jnp.sum(rows * dout[:, None, :].astype(jnp.float32), axis=-1)
+        dw = jnp.where(plan.sel_held, dw, 0).astype(gate_w.dtype)
+    return dy_buf, dw, None
+
+
+_combine = jax.custom_vjp(_combine_rows)
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def dispatch_rows(x_flat: jnp.ndarray, plan: DispatchPlan) -> jnp.ndarray:
+    """x_flat [T, D] → the expert buffer [T_buf, D]: every held selection's
+    token row at its place, pad rows zero. One gather of ``T_buf`` rows; its
+    backward one gather of ``[T, K, D]`` and a sum over ``K``."""
+    _count_plan("dispatch_gather")
+    return _dispatch(x_flat, plan)
+
+
+def combine_rows(y_buf: jnp.ndarray, gate_w: jnp.ndarray, plan: DispatchPlan) -> jnp.ndarray:
+    """``out[t] = sum_k gate_w[t, k] * y_buf[row of (t, k)]`` over the held
+    selections → [T, D] in ``y_buf``'s dtype, the sum in float32. One gather
+    of ``[T, K, D]``; its backward one gather of ``T_buf`` rows for ``dy_buf``
+    and the forward's rows again, dotted with the cotangent, for ``dgate_w``."""
+    _count_plan("combine_gather")
+    return _combine(y_buf, gate_w, plan)
+
+
 def grouped_ffn(
     experts: Params,
     x_flat: jnp.ndarray,
@@ -275,36 +429,20 @@ def grouped_ffn(
     ``num_experts`` banks of ids ``first .. first + num_experts - 1``: a
     selection of any other id belongs to an expert some other chip holds,
     gets no row here and adds nothing (the caller's gate weights are
-    normalised over all chosen, held or not). Selections are stably sorted
-    by expert id, those of absent experts last, and scattered into a
-    per-expert ``block_t``-aligned buffer (static size: every selection
-    could be a held one, and every expert's group rounds up to a full
-    tile), the three expert matmuls run as grouped GEMMs, and the
-    gate-weighted rows scatter-add back. No capacity, no drops.
+    normalised over all chosen, held or not). :func:`dispatch_plan` places
+    the selections, stably sorted by expert id, in a per-expert
+    ``block_t``-aligned buffer (static size: every selection could be a held
+    one, and every expert's group rounds up to a full tile);
+    :func:`dispatch_rows` gathers the tokens' rows into it, the three expert
+    matmuls run as grouped GEMMs, and :func:`combine_rows` gathers the
+    gate-weighted rows back and sums them per token. No capacity, no drops,
+    and no scatter, forward or backward.
     """
-    T, D = x_flat.shape
-    K = gate_idx.shape[-1]
-    TK = T * K
-    local = gate_idx.reshape(TK) - first
-    # Selections of experts held elsewhere sort past every real group.
-    ids = jnp.where((local >= 0) & (local < num_experts), local, num_experts)
-    tok = jnp.arange(TK, dtype=jnp.int32) // K
+    plan = dispatch_plan(gate_idx, num_experts, block_t, first)
+    x_buf = dispatch_rows(x_flat, plan)
+    T_buf = x_buf.shape[0]
 
-    counts = jnp.bincount(ids, length=num_experts + 1)[:num_experts]  # [E]
-    padded = ((counts + block_t - 1) // block_t) * block_t
-    p_off = jnp.concatenate([jnp.zeros((1,), padded.dtype), jnp.cumsum(padded)])
-    raw_off = jnp.cumsum(counts) - counts  # group starts in sorted order
-
-    order = jnp.argsort(ids, stable=True)  # token-major within each expert
-    ids_s = ids[order]
-    real = ids_s < num_experts
-    ids_c = jnp.minimum(ids_s, num_experts - 1)
-    rank = jnp.arange(TK, dtype=jnp.int32) - raw_off[ids_c].astype(jnp.int32)
-    T_buf = gm.round_up(TK + num_experts * (block_t - 1), block_t)
-    dest = jnp.where(real, (p_off[ids_c] + rank).astype(jnp.int32), T_buf)  # OOB = no row
-    x_buf = jnp.zeros((T_buf, D), x_flat.dtype).at[dest].set(x_flat[tok[order]])
-
-    gs = padded
+    gs = plan.group_sizes
     wg_, sg = _expert_bank(experts, "w_gate", x_buf.dtype)
     wu, su = _expert_bank(experts, "w_up", x_buf.dtype)
     wd, sd = _expert_bank(experts, "w_down", x_buf.dtype)
@@ -326,9 +464,7 @@ def grouped_ffn(
     ) * scaled(gm.gmm(x_buf, wu, gs, block_t=block_t, precision=precision), su)
     y_buf = scaled(gm.gmm(h, wd, gs, block_t=block_t, precision=precision), sd)
 
-    w_s = jnp.where(real, gate_w.reshape(TK)[order], 0).astype(y_buf.dtype)
-    return jnp.zeros((T, D), x_flat.dtype).at[tok[order]].add(
-        y_buf[jnp.minimum(dest, T_buf - 1)] * w_s[:, None])
+    return combine_rows(y_buf, gate_w, plan).astype(x_flat.dtype)
 
 
 def sigmoid_route(x: jnp.ndarray, router: Params, k: int, route_scale: float

@@ -4,8 +4,10 @@ A grouped GEMM multiplies a token-sorted activation matrix ``x [T, N_in]``
 against stacked per-expert weights ``w [E, N_in, N_out]``: rows
 ``[offset_e, offset_{e+1})`` of ``x`` hit expert ``e``'s weight. This is the
 MegaBlocks formulation (Gale et al., 2022): routing becomes a sort + two
-gathers and the expert FFN becomes three grouped GEMMs, so no token is ever
-dropped and no dispatch one-hots are materialized.
+gathers (into the buffer by row, out of it by selection; their backward is
+the same two gathers the other way: models/moe.py ``dispatch_rows`` and
+``combine_rows``) and the expert FFN becomes three grouped GEMMs, so no
+token is ever dropped and no dispatch one-hots are materialized.
 
 Two backends behind one differentiable entry point:
 

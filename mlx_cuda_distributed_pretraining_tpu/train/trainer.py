@@ -45,6 +45,7 @@ from ..obs.events import (
 )
 from ..obs import compiles
 from ..ops.flash_attention import plan_counts as flash_plan_counts
+from ..models.moe import plan_counts as moe_plan_counts
 from ..ops.fused_ce import plan_counts as fused_ce_plan_counts
 from ..obs.flops import (GoodputLedger, matmul_params, model_flops_per_token,
                          peak_flops_per_chip)
@@ -58,6 +59,14 @@ from ..utils.compile_cache import enable_compilation_cache
 from .early_stopping import EarlyStoppingMonitor
 from .lr_finder import run_lr_finder
 from .train_step import init_train_state, make_eval_step, make_train_step
+
+# What was traced into the step, by the modules that choose while tracing: the
+# key on the first step_window event -> (the log line's label, the tally).
+_PLAN_TALLIES = {
+    "flash_plan": ("flash plan (kernel calls traced, by path)", flash_plan_counts),
+    "fused_ce_plan": ("fused CE (chunk walks traced)", fused_ce_plan_counts),
+    "moe_plan": ("expert layers (traced, by form)", moe_plan_counts),
+}
 
 
 def _put_tree(tree: Any, shardings: Any) -> Any:
@@ -472,13 +481,12 @@ class Trainer:
         self._compiles_seen = compiles.totals()
         # Which path the step's flash kernels, forward and backward, were traced to
         # (ops/flash_attention.py flash_plan), and whether its fused CE computes
-        # the head's gradients in the forward walk (ops/fused_ce.py): the tallies
-        # since here, logged after the first compile and carried by the first
-        # step_window event.
-        self._flash_plan_seen = flash_plan_counts()
-        self._flash_plan: Optional[Dict[str, int]] = None
-        self._fused_ce_plan_seen = fused_ce_plan_counts()
-        self._fused_ce_plan: Optional[Dict[str, int]] = None
+        # the head's gradients in the forward walk (ops/fused_ce.py), and how many
+        # expert layers it dispatches and combines by gathers (models/moe.py): the
+        # tallies since here, logged after the first compile and carried by the
+        # first step_window event.
+        self._plans_seen = {name: counts() for name, (_, counts) in _PLAN_TALLIES.items()}
+        self._plans: Optional[Dict[str, Dict[str, int]]] = None
         self._metrics_server = None
         # events.jsonl is the durable telemetry source: replay it FIRST so
         # counters survive crash-restarts, then open for append. Chief only
@@ -659,15 +667,11 @@ class Trainer:
         self.goodput.add("compile_s", seconds)
         if self.events is not None:
             self.events.append("compile", seconds=round(seconds, 4), step=step)
-        self._flash_plan = {path: n - self._flash_plan_seen[path]
-                            for path, n in flash_plan_counts().items()}
-        self._fused_ce_plan = {walk: n - self._fused_ce_plan_seen[walk]
-                               for walk, n in fused_ce_plan_counts().items()}
-        self.logger.log(
-            "flash plan (kernel calls traced, by path): " + ", ".join(
-                f"{path}={n}" for path, n in self._flash_plan.items())
-            + "; fused CE (chunk walks traced): " + ", ".join(
-                f"{walk}={n}" for walk, n in self._fused_ce_plan.items()))
+        self._plans = {name: {key: n - self._plans_seen[name][key] for key, n in counts().items()}
+                       for name, (_, counts) in _PLAN_TALLIES.items()}
+        self.logger.log("; ".join(
+            f"{label}: " + ", ".join(f"{key}={n}" for key, n in self._plans[name].items())
+            for name, (label, _) in _PLAN_TALLIES.items()))
 
     def _touch_heartbeat(self, step: Optional[int] = None) -> None:
         if self._hb_path is None:
@@ -1363,10 +1367,9 @@ class Trainer:
                             ev["xla_compile_s"] = round(
                                 seen[1] - self._compiles_seen[1], 4)
                             self._compiles_seen = seen
-                            if self._flash_plan is not None:
-                                ev["flash_plan"] = self._flash_plan
-                                ev["fused_ce_plan"] = self._fused_ce_plan
-                                self._flash_plan = self._fused_ce_plan = None
+                            if self._plans is not None:
+                                ev.update(self._plans)
+                                self._plans = None
                             if self.pipeline:
                                 ev["bubble"] = round(self._bubble_frac, 6)
                             ev.update({k: line[k] for k in (

@@ -8,6 +8,7 @@ correctness is validated on a virtual CPU mesh
 
 import functools
 import os
+import re
 import sys
 
 import pytest
@@ -46,6 +47,13 @@ def load_script(name):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def activation_scatters(hlo_text, width):
+    """``scatter`` instructions of an HLO text (``lowered.as_text(dialect="hlo")``)
+    with an operand or result ``width`` wide in its last dimension."""
+    return [ln for ln in hlo_text.splitlines()
+            if re.search(r"\bscatter\(", ln) and re.search(r"\[[0-9,]*\b%d\]" % width, ln)]
 
 
 def device_env(n, base=None):

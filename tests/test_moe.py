@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import activation_scatters
 from mlx_cuda_distributed_pretraining_tpu.models import llama, moe
 from mlx_cuda_distributed_pretraining_tpu.optim import build_optimizer
 from mlx_cuda_distributed_pretraining_tpu.config import SystemConfig, TrainingConfig
@@ -281,6 +282,124 @@ def test_gmm_backends_match_ragged_fwd_and_bwd():
                                    atol=1e-3, rtol=1e-4, err_msg=backend)
         np.testing.assert_allclose(np.asarray(dw), np.asarray(ref_dw),
                                    atol=1e-3, rtol=1e-4, err_msg=backend)
+
+
+def _scatter_ffn(experts, x_flat, gate_idx, gate_w, num_experts, block_t, first=0):
+    """``moe.grouped_ffn`` as it was before its dispatch and combine became
+    gathers: a scatter of the sorted rows into the buffer and a scatter-add of
+    the weighted rows back, differentiated by XLA. The reference of the pair."""
+    from mlx_cuda_distributed_pretraining_tpu.ops import grouped_matmul as gm
+
+    T, D = x_flat.shape
+    K = gate_idx.shape[-1]
+    TK = T * K
+    local = gate_idx.reshape(TK) - first
+    ids = jnp.where((local >= 0) & (local < num_experts), local, num_experts)
+    tok = jnp.arange(TK, dtype=jnp.int32) // K
+    counts = jnp.bincount(ids, length=num_experts + 1)[:num_experts]
+    padded = ((counts + block_t - 1) // block_t) * block_t
+    p_off = jnp.concatenate([jnp.zeros((1,), padded.dtype), jnp.cumsum(padded)])
+    raw_off = jnp.cumsum(counts) - counts
+    order = jnp.argsort(ids, stable=True)
+    ids_s = ids[order]
+    real = ids_s < num_experts
+    ids_c = jnp.minimum(ids_s, num_experts - 1)
+    rank = jnp.arange(TK, dtype=jnp.int32) - raw_off[ids_c].astype(jnp.int32)
+    T_buf = gm.round_up(TK + num_experts * (block_t - 1), block_t)
+    dest = jnp.where(real, (p_off[ids_c] + rank).astype(jnp.int32), T_buf)  # OOB = no row
+    x_buf = jnp.zeros((T_buf, D), x_flat.dtype).at[dest].set(x_flat[tok[order]])
+    w = lambda name: experts[name]["weight"]
+    h = jax.nn.silu(gm.gmm(x_buf, w("w_gate"), padded, block_t=block_t)) * gm.gmm(
+        x_buf, w("w_up"), padded, block_t=block_t)
+    y_buf = gm.gmm(h, w("w_down"), padded, block_t=block_t)
+    w_s = jnp.where(real, gate_w.reshape(TK)[order], 0).astype(y_buf.dtype)
+    return jnp.zeros((T, D), x_flat.dtype).at[tok[order]].add(
+        y_buf[jnp.minimum(dest, T_buf - 1)] * w_s[:, None])
+
+
+def _dispatch_case(case, dtype, D=24, width=16):
+    """(experts, x, gate_idx, gate_w, num_experts, block_t, first) of one case."""
+    rng = np.random.default_rng(11)
+    T, K, E, first, bt, router = 32, 2, 4, 0, 8, 4
+    if case == "held_share":          # most selections belong to experts held elsewhere
+        first, router = 4, 16
+    elif case == "ragged_rows":       # T * K no multiple of block_t
+        T, K = 13, 3
+    idx = np.stack([rng.permutation(router)[:K] for _ in range(T)]).astype(np.int32)
+    if case == "empty_expert":        # expert 2 gets no row
+        idx = np.where(idx == 2, 3, idx)
+    elif case == "one_expert":        # every selection on expert 1
+        idx = np.ones_like(idx)
+    bank = lambda *shape: jnp.asarray(rng.normal(size=shape) * 0.3, dtype)
+    experts = {"w_gate": {"weight": bank(E, D, width)}, "w_up": {"weight": bank(E, D, width)},
+               "w_down": {"weight": bank(E, width, D)}}
+    x = jnp.asarray(rng.normal(size=(T, D)), dtype)
+    gate_w = jnp.asarray(rng.uniform(0.1, 1.0, size=(T, K)), dtype)
+    return experts, x, jnp.asarray(idx), gate_w, E, bt, first
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["every_expert_held", "held_share", "empty_expert",
+                                  "one_expert", "ragged_rows"])
+def test_gather_dispatch_and_combine_match_the_scatter_form(case, dtype):
+    """Output and the gradients of ``x``, ``gate_w`` and the three banks of the
+    gathers both ways (custom backward) against the scatter formulation that
+    XLA differentiates: in float32 exact to 1e-6 of a leaf's largest value (the
+    sums run in another order)."""
+    experts, x, idx, gate_w, E, bt, first = _dispatch_case(case, jnp.dtype(dtype))
+    held = int(((np.asarray(idx) >= first) & (np.asarray(idx) < first + E)).sum())
+    assert 0 < held < idx.size // 2 if case == "held_share" else held == idx.size
+    if case == "ragged_rows":
+        assert idx.size % bt
+
+    def run(ffn, cast=None):
+        leaves = jax.tree_util.tree_map(lambda a: a.astype(cast or a.dtype), (experts, x, gate_w))
+
+        def loss(experts, x, gate_w):
+            out = ffn(experts, x, idx, gate_w, E, bt, first=first)
+            return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(*leaves)
+        return [out] + jax.tree_util.tree_leaves(grads)
+
+    got, want = run(moe.grouped_ffn), run(_scatter_ffn)
+    assert len(got) == 1 + 3 + 2
+    # bfloat16: both forms against the scatter form in float32 on the same rounded inputs
+    truth = want if dtype == "float32" else run(_scatter_ffn, jnp.float32)
+    for name, a, b, t in zip(("out", "w_down", "w_gate", "w_up", "x", "gate_w"), got, want, truth):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        a, b, t = (np.asarray(v, np.float32) for v in (a, b, t))
+        scale = np.abs(t).max()
+        assert scale > 0, name
+        if dtype == "float32":
+            np.testing.assert_allclose(a, b, atol=1e-6 * scale, rtol=1e-6, err_msg=name)
+        else:  # the file's 2e-2 of the leaf's largest value, or what the scatter form is off by
+            off, was = np.abs(a - t).max() / scale, np.abs(b - t).max() / scale
+            assert off <= max(2e-2, 1.25 * was), (name, off, was)
+
+
+def test_grouped_block_gradient_has_no_scatter_of_activation_rows(monkeypatch):
+    """``moe_block`` with ``moe_impl: grouped`` on one device: no scatter or
+    scatter-add of rows as wide as the activations in the lowered gradient
+    (``ragged`` forced: the ``blocked`` backend's own dW is a scatter-add
+    through its weight gather), where the scatter form has both; and the
+    dispatch and the combine are tallied as traced."""
+    import dataclasses
+
+    monkeypatch.setenv("GMM_BACKEND", "ragged")
+    D = 40
+    args = dataclasses.replace(MOE_ARGS, hidden_size=D, moe_impl="grouped")
+    p = moe.init_moe_params(iter(jax.random.split(jax.random.PRNGKey(0), 4)), args)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, D), jnp.float32)
+    seen = moe.plan_counts()
+    grad = jax.jit(jax.grad(lambda p, x: jnp.sum(jnp.sin(moe.moe_block(p, x, args)[0])), (0, 1)))
+    hlo = grad.lower(p, x).as_text(dialect="hlo")
+    assert {k: n - seen[k] for k, n in moe.plan_counts().items()} == {
+        "dispatch_gather": 1, "combine_gather": 1}
+    assert " gather(" in hlo and not activation_scatters(hlo, D)
+
+    experts, xs, idx, gate_w, E, bt, first = _dispatch_case("held_share", jnp.float32, D=D)
+    old = jax.jit(jax.grad(lambda x: jnp.sum(_scatter_ffn(experts, x, idx, gate_w, E, bt, first))))
+    assert len(activation_scatters(old.lower(xs).as_text(dialect="hlo"), D)) >= 2
 
 
 def test_gmm_unknown_backend_rejected():

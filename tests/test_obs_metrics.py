@@ -482,10 +482,15 @@ def test_trainer_reports_the_flash_plan(tmp_path):
     ce_plan = windows[0]["fused_ce_plan"]
     assert ce_plan == {"grad_in_forward": 1, "forward_only": 1}
     assert "fused_ce_plan" not in windows[1]
+    # and how many expert layers it dispatches and combines by gathers
+    # (models/moe.py): a dense model has none
+    moe_plan = windows[0]["moe_plan"]
+    assert moe_plan == {"dispatch_gather": 0, "combine_gather": 0}
+    assert "moe_plan" not in windows[1]
     with open(tr.logger.log_path) as f:
         lines = [ln for ln in f if "flash plan" in ln]
     assert len(lines) == 1
-    assert all(f"{key}={n}" in lines[0] for key, n in {**plan, **ce_plan}.items())
+    assert all(f"{key}={n}" in lines[0] for key, n in {**plan, **ce_plan, **moe_plan}.items())
 
 
 def test_trainer_registry_replays_on_construction(tmp_path):

@@ -7,16 +7,20 @@ and the benchmark's traffic kind for it.
 """
 
 import dataclasses
+import itertools
 import json
 import os
+import re
 import subprocess
 import sys
+import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import activation_scatters
 from benchmark import run as harness
 from benchmark.flops import flash_mla
 from benchmark.flops import xing_mla_moe as flops
@@ -167,6 +171,28 @@ def test_a_held_share_drops_nothing(tiny, monkeypatch, chunk_rows, crowded):
     for a_, b_ in zip(jax.tree_util.tree_leaves(grads),
                       jax.tree_util.tree_leaves(jax.grad(loss, (0, 1))(ff, x))):
         np.testing.assert_allclose(np.asarray(a_), np.asarray(b_), atol=3e-6)
+
+
+def test_a_held_shares_gradient_has_no_scatter_of_activation_rows(tiny, monkeypatch):
+    """The routed layer with a held share in chunks: no scatter or scatter-add
+    of rows as wide as the activations in its lowered gradient (``ragged``
+    forced: the ``blocked`` backend's own dW is a scatter-add through its
+    weight gather), only gathers; the chunk loop traces one dispatch and one
+    combine."""
+    cfg, args, params, _ = tiny
+    monkeypatch.setenv("GMM_BACKEND", "ragged")
+    monkeypatch.setattr(xing, "HELD_CHUNK_ROWS", 128)
+    assert xing.held_chunks(args, B * S) == 4 and args.experts_held[0] > 0
+    ff = jax.tree_util.tree_map(jnp.asarray, params["layers"][0]["feed_forward"])
+    C = cfg["hidden_size"]
+    x = jax.random.normal(jax.random.PRNGKey(2), (B, S, C), jnp.float32)
+    seen = moe_lib.plan_counts()
+    grad = jax.jit(jax.grad(lambda ff, x: jnp.sum(jnp.sin(xing.routed_ffn(ff, x, args)[0])), (0, 1)))
+    hlo = grad.lower(ff, x).as_text(dialect="hlo")
+    assert {k: n - seen[k] for k, n in moe_lib.plan_counts().items()} == {
+        "dispatch_gather": 1, "combine_gather": 1}
+    assert " gather(" in hlo and " scatter(" in hlo         # the load's bincount is one
+    assert not activation_scatters(hlo, C)
 
 
 def test_router_bias_moves_the_choice_and_not_the_weights():
@@ -324,12 +350,20 @@ def test_cell_one_imports_none_of_the_new_modules():
         resolve_architecture("no_such_model")
 
 
-def test_the_cell_rehearses_through_its_traffic_kind(tmp_path):
+def test_the_cell_rehearses_through_its_traffic_kind(tmp_path, monkeypatch):
     """``run.py --rehearse`` looks a kind up in rehearse.json, which is closed;
     this is the new cell's rehearsal: a Context at tiny widths, the kind's own
     ``run``: Trainer.train() on architecture xing_mla_moe from a dict config,
     the window, the events' counters, the reference's three steps, the
     comparison."""
+    # The window counts steps here, not this machine's seconds: the recorder's clock ticks
+    # once a reading, three readings a step, so its 1.5 s hold 62 whole steps whatever else the
+    # machine runs. At these widths the loss falls by 0.07 in 60 steps and varies by 0.015 from
+    # batch to batch: on the wall clock an idle machine fitted 21 steps (0.04 down) and a loaded
+    # one 5, whose last loss lay above step 1's, which the kind rightly calls not correct.
+    ticks = itertools.count()
+    monkeypatch.setattr(kind.base, "time", types.SimpleNamespace(
+        perf_counter=lambda: 0.008 * next(ticks)))
     bench, cell, config, mix = harness.load_cell(CELL)
     assert mix["kind"] == "train_job_arch" and cell["chips"] == 1
     base_mix = _load("benchmark/traffic/pack4k-b4.json")
@@ -346,6 +380,7 @@ def test_the_cell_rehearses_through_its_traffic_kind(tmp_path):
                           rehearse=True, workdir=str(tmp_path), quiet=True)
     res = kind.run(ctx)
     assert res["correct"], res["check_numbers"]
+    assert len(res["sources"]["timed_steps"]) == 62
     assert len(res["check_numbers"]) == 3 * 3 + 3      # three terms a step, three steps
     assert max(v for k, v in res["check_numbers"].items() if k.startswith("loss_gap")) < 1e-3
     events = res["sources"]["step_window_events"]
@@ -353,6 +388,11 @@ def test_the_cell_rehearses_through_its_traffic_kind(tmp_path):
         {"moe_rows_held", "moe_load_max_over_mean", "main_loss", "mtp_loss", "moe_drop"} <= set(e)
         for e in events)
     assert all(e["moe_drop"] == 0 and e["moe_rows_held"] > 0 for e in events)
+    # the run's first window says the step traced its expert layers (the scanned stack's and
+    # the module's) in the gather form: what tells it from an old executable out of a cache
+    run_dir, = (os.path.join(tmp_path, "runs", d) for d in os.listdir(os.path.join(tmp_path, "runs")))
+    first = next(e for e in kind.base._read_events(run_dir) if e.get("type") == "step_window")
+    assert first["moe_plan"]["dispatch_gather"] == first["moe_plan"]["combine_gather"] >= 2
     assert not {"held_capacity_factor", "held_passes"} & set(FULL)   # no capacity anywhere
     assert res["end_to_end"]["train_tokens_per_s_per_chip"] > 0 and res["end_to_end"]["setup_s"] > 0
     flops_per_token = res["sources"]["flops_per_token"]
@@ -430,8 +470,6 @@ def test_the_train_step_carries_the_scopes_the_metrics_read(tiny):
     both names; its head's rows are walked with the main head's, under
     ``lm_head_ce`` alone), and nothing of the model's work is left without a
     scope."""
-    import re
-
     _, args, params, batch = tiny
     step = jax.jit(jax.grad(lambda p: xing.loss_fn(p, batch, args, remat="full", scan_layers=True)[0]))
     hlo = step.lower(params).compile().as_text()
@@ -449,6 +487,17 @@ def test_the_train_step_carries_the_scopes_the_metrics_read(tiny):
     assert [n for n in names if "lm_head_ce" in stack(n)]
     for scope in ("attn_out", "norm"):
         assert any(scope in stack(n) for n in names), scope
+    # The expert layer's backward is gathers too (a custom backward: models/moe.py), and they
+    # are the layer's: every gather of the backward pass proper, in the scanned layers and in
+    # the module, carries ``moe_experts`` innermost, as the forward's and the recomputed do.
+    gathers = re.findall(r'\bgather\([^\n]*op_name="([^"]+)"', hlo)
+    backward = [n for n in gathers if "transpose" in stack(n)
+                and "rematted_computation" not in stack(n)]
+    assert len(backward) >= 2 * 4                       # dx, dy_buf's rows and weights, dgate_w's rows
+    assert all(stack(n)[-2] == "moe_experts" for n in backward), backward
+    assert any("mtp" in stack(n) for n in backward) and any("mtp" not in stack(n) for n in backward)
+    for when in ("rematted_computation", "jvp"):
+        assert any(when in stack(n) and "moe_experts" in stack(n) for n in gathers), when
 
 
 def test_a_mixing_map_with_a_wrong_gradient_fails_the_check():
