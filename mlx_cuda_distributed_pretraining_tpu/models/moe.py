@@ -484,6 +484,74 @@ def sigmoid_route(x: jnp.ndarray, router: Params, k: int, route_scale: float
     return gate_idx, gate_w, scores
 
 
+# Rows of one expert buffer of a layer that holds a share, where the model names no
+# size of its own. ``grouped_ffn`` is dropless: its buffer has a row for every
+# selection, since any of them may be a held one, and a share of the experts fills few
+# of them. So such a layer takes its tokens in chunks whose selections fit this many
+# rows, one after another, and the step holds the buffers of one (xing4_0's benchmark
+# cell compiles to 14.36, 14.41 and 14.73 GiB at 2,048, 4,096 and 8,192).
+HELD_CHUNK_ROWS = 4096
+
+
+def held_chunks(tokens: int, top_k: int, held: int, n_routed: int,
+                chunk_rows: int = HELD_CHUNK_ROWS) -> int:
+    """Chunks (a power of two dividing ``tokens``) a layer that holds ``held``
+    of ``n_routed`` experts takes its tokens in: 1 where it holds every
+    expert, whose buffer is all rows."""
+    n = 1
+    if held < n_routed:
+        while tokens * top_k > n * chunk_rows and tokens % (2 * n) == 0:
+            n *= 2
+    return n
+
+
+def held_share_ffn(experts: Params, x: jnp.ndarray, gate_idx: jnp.ndarray, gate_w: jnp.ndarray,
+                   held: Tuple[int, int], n_routed: int, chunk_rows: int = HELD_CHUNK_ROWS,
+                   precision=None) -> jnp.ndarray:
+    """The held experts' part of a routed layer: ``x [B, S, C]``, the router's
+    ``gate_idx``/``gate_w [B, S, K]`` over all ``n_routed`` → ``[B, S, C]``.
+    ``experts`` holds the banks of ``held = (first, count)``; the tokens go
+    through :func:`grouped_ffn` in :func:`held_chunks` chunks."""
+    B, S, C = x.shape
+    K, (first, count) = gate_idx.shape[-1], held
+    n = held_chunks(B * S, K, count, n_routed, chunk_rows)
+    T = B * S // n
+
+    def one(x_c, idx_c, w_c):
+        return grouped_ffn(experts, x_c, idx_c, w_c, count, gm.pick_block_t(T * K, count),
+                           precision=precision, first=first)
+
+    chunks = (x.reshape(n, T, C), gate_idx.reshape(n, T, K),
+              gate_w.reshape(n, T, K).astype(x.dtype))
+    if n == 1:
+        routed = one(*(a[0] for a in chunks))
+    else:  # rematerialised, or the loop keeps every chunk's buffers for the backward
+        _, routed = jax.lax.scan(lambda _, c: (None, jax.checkpoint(one)(*c)), None, chunks)
+    return routed.reshape(B, S, C)
+
+
+def sigmoid_routed_ffn(p: Params, x: jnp.ndarray, top_k: int, route_scale: float,
+                       held: Tuple[int, int], n_routed: int, chunk_rows: int = HELD_CHUNK_ROWS,
+                       precision=None):
+    """A sigmoid-routed layer as one expert-parallel rank computes it: the
+    shared expert every token visits + the held share of the routed experts →
+    ``(y, stats)``. ``p`` holds ``router`` (``weight``, the buffer ``bias``),
+    ``shared`` and the held ``experts``; the load counts every selection over
+    the router's whole width, and nothing is dropped."""
+    from .llama import mlp_block
+
+    with jax.named_scope("moe_router"):
+        gate_idx, gate_w, _ = sigmoid_route(x, p["router"], top_k, route_scale)
+    with jax.named_scope("ffn"):
+        shared = mlp_block(p["shared"], x)
+    with jax.named_scope("moe_experts"):
+        routed = held_share_ffn(p["experts"], x, gate_idx, gate_w, held, n_routed, chunk_rows,
+                                precision)
+    stats = dict(zero_stats(n_routed), moe_load=jax.lax.stop_gradient(
+        jnp.bincount(gate_idx.reshape(-1), length=n_routed).astype(jnp.float32)))
+    return shared + routed, stats
+
+
 def _usable_ep_mesh(args, num_experts: int):
     """The mesh to drop below GSPMD with, or None for the local path.
 

@@ -24,12 +24,15 @@ class Architecture(NamedTuple):
     # (args, seq_len) -> training FLOPs a token, where the llama count
     # (obs/flops.py) does not describe the model
     flops_per_token: Callable | None = None
+    # tallies of what the model chose while tracing, beside the trainer's own
+    # (train/trainer.py _PLAN_TALLIES): {event key: (log label, counts())}
+    plans: Dict[str, Any] | None = None
 
 
 _REGISTRY: Dict[str, Architecture] = {}
 # Architectures in modules of their own, imported (and so registered) when a
 # config first names them: a llama run pays nothing for them.
-_LAZY_MODULES = {"xing_mla_moe": "xing"}
+_LAZY_MODULES = {"xing_mla_moe": "xing", "afmoe": "afmoe"}
 
 
 def register(arch: Architecture) -> None:

@@ -42,7 +42,6 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import fused_ce
-from ..ops import grouped_matmul as gm
 from . import moe as moe_lib
 from .llama import (apply_rope, mlp_block, normalize_remat, remat_checkpoint_for_overlap,
                     rms_norm)
@@ -323,51 +322,16 @@ def latent_attention(p: Params, x: jnp.ndarray, args: XingArgs, positions) -> jn
         return out.reshape(B, S, H * dv) @ p["wo"]["weight"]
 
 
-# Rows of one expert buffer of a layer that holds a share. ``moe.grouped_ffn`` is dropless:
-# its buffer has a row for every selection, since any of them may be a held one, and a share
-# of the experts fills few of them. So such a layer takes its tokens in chunks whose
-# selections fit this many rows, one after another, and the step holds the buffers of one
-# (the benchmark cell's step compiles to 14.36, 14.41 and 14.73 GiB at 2,048, 4,096 and 8,192).
-HELD_CHUNK_ROWS = 4096
-
-
-def held_chunks(args: XingArgs, tokens: int) -> int:
-    """Chunks (a power of two dividing ``tokens``) the routed experts take the
-    tokens in: 1 where the chip holds every expert, whose buffer is all rows."""
-    n = 1
-    if args.experts_held[1] < args.n_routed_experts:
-        while tokens * args.num_experts_per_tok > n * HELD_CHUNK_ROWS and tokens % (2 * n) == 0:
-            n *= 2
-    return n
+# Rows of a chunk's expert buffer (``moe.sigmoid_routed_ffn``): this model's size is the
+# default's, settled by its benchmark cell's compiles.
+HELD_CHUNK_ROWS = moe_lib.HELD_CHUNK_ROWS
 
 
 def routed_ffn(p: Params, x: jnp.ndarray, args: XingArgs):
     """Shared expert + the held share of the routed experts → ``(y, stats)``."""
-    B, S, C = x.shape
-    K, (first, count) = args.num_experts_per_tok, args.experts_held
-    with jax.named_scope("moe_router"):
-        gate_idx, gate_w, _ = moe_lib.sigmoid_route(x, p["router"], K, args.routed_scaling_factor)
-    with jax.named_scope("ffn"):
-        shared = mlp_block(p["shared"], x)
-    with jax.named_scope("moe_experts"):
-        n = held_chunks(args, B * S)
-        T = B * S // n
-
-        def experts(x_c, idx_c, w_c):
-            return moe_lib.grouped_ffn(p["experts"], x_c, idx_c, w_c, count,
-                                       gm.pick_block_t(T * K, count),
-                                       precision=args.matmul_precision, first=first)
-
-        chunks = (x.reshape(n, T, C), gate_idx.reshape(n, T, K),
-                  gate_w.reshape(n, T, K).astype(x.dtype))
-        if n == 1:
-            routed = experts(*(a[0] for a in chunks))
-        else:  # rematerialised, or the loop keeps every chunk's buffers for the backward
-            _, routed = jax.lax.scan(
-                lambda _, one: (None, jax.checkpoint(experts)(*one)), None, chunks)
-    stats = dict(moe_lib.zero_stats(args.n_routed_experts), moe_load=jax.lax.stop_gradient(
-        jnp.bincount(gate_idx.reshape(-1), length=args.n_routed_experts).astype(jnp.float32)))
-    return shared + routed.reshape(B, S, C), stats
+    return moe_lib.sigmoid_routed_ffn(p, x, args.num_experts_per_tok, args.routed_scaling_factor,
+                                      args.experts_held, args.n_routed_experts, HELD_CHUNK_ROWS,
+                                      args.matmul_precision)
 
 
 def block(p: Params, X: jnp.ndarray, positions, args: XingArgs, routed: bool):

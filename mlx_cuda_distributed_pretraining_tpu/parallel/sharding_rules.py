@@ -33,7 +33,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 _RULES = [
     (r"tok_embeddings\.weight$", ("tp", "fsdp")),  # [V, D] vocab-parallel
     (r"output\.weight$", ("fsdp", "tp")),          # [D, V]
-    (r"attention\.w[qkv]\.weight(_q4?)?$", ("fsdp", "tp")),  # [D, H*Dh] column
+    # wg: the output gate's projection (models/afmoe.py), column-parallel over heads like wq
+    (r"attention\.w[qkvg]\.weight(_q4?)?$", ("fsdp", "tp")),  # [D, H*Dh] column
     (r"attention\.w[qkv]\.weight_s$", ("tp",)),              # [H*Dh]
     (r"attention\.wo\.weight(_q4?)?$", ("tp", "fsdp")),      # [H*Dh, D] row
     (r"attention\.wo\.weight_s$", ("fsdp",)),                # [D]

@@ -485,7 +485,8 @@ class Trainer:
         # expert layers it dispatches and combines by gathers (models/moe.py): the
         # tallies since here, logged after the first compile and carried by the
         # first step_window event.
-        self._plans_seen = {name: counts() for name, (_, counts) in _PLAN_TALLIES.items()}
+        self._plan_tallies = {**_PLAN_TALLIES, **(arch.plans or {})}
+        self._plans_seen = {name: counts() for name, (_, counts) in self._plan_tallies.items()}
         self._plans: Optional[Dict[str, Dict[str, int]]] = None
         self._metrics_server = None
         # events.jsonl is the durable telemetry source: replay it FIRST so
@@ -667,11 +668,12 @@ class Trainer:
         self.goodput.add("compile_s", seconds)
         if self.events is not None:
             self.events.append("compile", seconds=round(seconds, 4), step=step)
-        self._plans = {name: {key: n - self._plans_seen[name][key] for key, n in counts().items()}
-                       for name, (_, counts) in _PLAN_TALLIES.items()}
+        self._plans = {name: {key: n - self._plans_seen[name].get(key, 0)
+                              for key, n in counts().items()}
+                       for name, (_, counts) in self._plan_tallies.items()}
         self.logger.log("; ".join(
             f"{label}: " + ", ".join(f"{key}={n}" for key, n in self._plans[name].items())
-            for name, (label, _) in _PLAN_TALLIES.items()))
+            for name, (label, _) in self._plan_tallies.items()))
 
     def _touch_heartbeat(self, step: Optional[int] = None) -> None:
         if self._hb_path is None:

@@ -286,10 +286,11 @@ def test_named_scopes_in_the_program_are_the_vocabulary():
     assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dkv", "flash_bwd_dq",
                                "flash_bwd_dq", "flash_fwd", "flash_fwd", "gmm", "tgmm"]
     assert pallas_calls == len(kernels), "a pallas_call without a name="
-    # architecture xing_mla_moe opens two more, which the benchmark reads by
-    # their own helper (layer_metrics/_named_scopes.py) until its closed
-    # vocabulary takes them in
-    assert found | set(kernels) == VOCABULARY | {"hc_mix", "mtp"}
+    # architecture xing_mla_moe opens two more and afmoe three, which the
+    # benchmark reads by their own helpers (layer_metrics/_named_scopes.py,
+    # _attn_kinds.py) until its closed vocabulary takes them in
+    assert found | set(kernels) == VOCABULARY | {"hc_mix", "mtp"} | {
+        "attn_window", "attn_global", "attn_gate"}
 
 
 # -- the host's turns ---------------------------------------------------------------

@@ -11,6 +11,7 @@ these say nothing about results or times — ``chip_smoke.py`` does.
 here; the tests steer it (monkeypatch), the program has no option for it.
 """
 
+import dataclasses
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs under /tmp
@@ -342,3 +343,40 @@ def test_scoped_train_step_compiles_for_v5e(v5e, compiled_kernels, monkeypatch):
     assert ma.alias_size_in_bytes > 0, "the state is not donated"
     step_hbm_gib = (ma.argument_size_in_bytes + ma.temp_size_in_bytes) / 2**30
     assert step_hbm_gib < 13.5, f"the cell's step needs {step_hbm_gib:.3f} GiB"
+
+
+def test_two_kinds_of_attention_core_compile_for_v5e_at_the_cells_length(v5e, compiled_kernels):
+    """``trinity-mini-ep8.train-seq16k``'s attention (models/afmoe.py): one
+    row of 16,384, GQA 32/4 of 128, a traced flag choosing by ``lax.cond``
+    between the window-2,048 core (with RoPE) and the causal one, forward and
+    backward: both branches' three kernels are in the program the chip's
+    compiler leaves, each under its kind's scope, all six on the resident
+    path (16,384 is the last length K and V stay in VMEM)."""
+    import re
+
+    from mlx_cuda_distributed_pretraining_tpu.models import afmoe
+
+    args = afmoe.AfmoeArgs(hidden_size=2048, num_heads=32, num_kv_heads=4, head_dim=128,
+                           sliding_window=2048, attention_type="flash")
+    S = 16384
+    p = jax.eval_shape(lambda: afmoe.init_params(jax.random.PRNGKey(0), dataclasses.replace(
+        args, num_layers=2, layer_types=(afmoe.SLIDING, afmoe.FULL)), jnp.bfloat16))
+    att = jax.tree_util.tree_map(lambda a: _sds(a.shape, a.dtype, v5e), p["layers"][0]["attention"])
+
+    def loss(att, x, flag):
+        out = afmoe.gated_attention(att, x, args, jnp.arange(S, dtype=jnp.int32), flag)
+        return out.astype(jnp.float32).sum()
+
+    before = afmoe.plan_counts()
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        att, _sds((1, S, 2048), jnp.bfloat16, v5e), _sds((), jnp.bool_, v5e)).compile().as_text()
+    traced = {k: n - before.get(k, 0) for k, n in afmoe.plan_counts().items() if n - before.get(k, 0)}
+    assert traced == {f"{kind}_{what}": 1 for kind in ("window", "global")
+                      for what in ("layers", "fwd_resident", "bwd_dq_resident", "bwd_dkv_resident")}
+    calls = [m.group(1) for line in hlo.split("\n") if "tpu_custom_call" in line
+             for m in [re.search(r'op_name="([^"]+)"', line)] if m]
+    for kind in ("attn_window", "attn_global"):
+        mine = [c for c in calls if kind in re.split(r"[/()]", c)]
+        assert sorted(next(t for t in reversed(re.split(r"[/()]", c)) if t.startswith("flash_"))
+                      for c in mine) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"], (kind, calls)
+    assert len(calls) == 6 and " conditional(" in hlo

@@ -158,7 +158,8 @@ def test_a_held_share_drops_nothing(tiny, monkeypatch, chunk_rows, crowded):
         ff = {**ff, "router": {**ff["router"], "bias": ff["router"]["bias"].at[first:first + count].set(10.0)}}
     x = jax.random.normal(jax.random.PRNGKey(2), (B, S, cfg["hidden_size"]), jnp.float32)
     monkeypatch.setattr(xing, "HELD_CHUNK_ROWS", chunk_rows)
-    assert xing.held_chunks(args, B * S) == max(1, B * S * 2 // min(chunk_rows, B * S * 2))
+    assert moe_lib.held_chunks(B * S, 2, count, cfg["n_routed_experts"], chunk_rows) == \
+        max(1, B * S * 2 // min(chunk_rows, B * S * 2))
     got, stats = xing.routed_ffn(ff, x, args)
     held = float(stats["moe_load"][first:first + count].sum())
     assert held == B * S * count if crowded else 0 < held < B * S
@@ -182,7 +183,8 @@ def test_a_held_shares_gradient_has_no_scatter_of_activation_rows(tiny, monkeypa
     cfg, args, params, _ = tiny
     monkeypatch.setenv("GMM_BACKEND", "ragged")
     monkeypatch.setattr(xing, "HELD_CHUNK_ROWS", 128)
-    assert xing.held_chunks(args, B * S) == 4 and args.experts_held[0] > 0
+    assert moe_lib.held_chunks(B * S, 2, args.experts_held[1], args.n_routed_experts, 128) == 4 \
+        and args.experts_held[0] > 0
     ff = jax.tree_util.tree_map(jnp.asarray, params["layers"][0]["feed_forward"])
     C = cfg["hidden_size"]
     x = jax.random.normal(jax.random.PRNGKey(2), (B, S, C), jnp.float32)
