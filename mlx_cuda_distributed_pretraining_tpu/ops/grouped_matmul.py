@@ -9,15 +9,38 @@ the same two gathers the other way: models/moe.py ``dispatch_rows`` and
 ``combine_rows``) and the expert FFN becomes three grouped GEMMs, so no
 token is ever dropped and no dispatch one-hots are materialized.
 
-Two backends behind one differentiable entry point:
+Three backends behind one differentiable entry point:
 
-- ``pallas`` — a tiled TPU kernel. Row tiles of ``block_t`` map onto expert
-  weight blocks through a scalar-prefetch ``tile → expert`` table, so the
-  MXU only ever touches the experts that actually received tokens. Backward
-  is a custom VJP: dX is a gmm against transposed weights, dW is a
-  per-group accumulation kernel (``tgmm``) that revisits each expert's
-  output block across that expert's row tiles. Runs under Pallas interpret
-  mode off-TPU, so tier-1 CPU tests exercise the same kernel code.
+- ``pallas`` — two tiled TPU kernels, ``gmm`` and ``tgmm``. Row tiles of
+  ``block_t`` map onto expert weight blocks through a scalar-prefetch
+  ``tile → expert`` table. **What is multiplied:** every row tile of the
+  buffer, the dead tail (clamped to the last expert) included: the buffer
+  is sized for the worst case the router could send, its shape is static,
+  and no tile is skipped, so a call's time does not follow the router.
+  **What is resident:** the operand that is the same for consecutive row
+  tiles of an expert stays in VMEM across them (:func:`gmm_plan`, a pure
+  function of the shapes, as ``flash_plan`` is for attention). In ``gmm``
+  (forward, recomputation, and dX) the grid is (column blocks, row tiles)
+  with the row tile fastest, so the weight block ``[K, bn]`` at
+  ``(te[t], 0, n)`` keeps its index across an expert's tiles and across
+  the whole dead tail, Pallas skips the copy, and what is streamed a grid
+  step is one ``[block_t, K]`` row tile at ``bn`` operations a byte. In
+  ``tgmm`` (dW) the grid is the same and the output block ``[K, bn]`` of
+  an expert is revisited across that expert's row tiles; its partial sums
+  are kept in a float32 VMEM scratch and rounded once, on the expert's
+  last tile, and the ``[T, K]`` buffer is read ``N / bn`` times. The plan
+  picks the widest ``bn`` (``N`` itself, else a multiple of 128 dividing
+  it, down to 128) whose blocks fit a VMEM budget, and every call raises
+  Mosaic's scoped limit to hold them. Until PR 34 the row tile was
+  outermost at a ``bn`` of 128, which streamed an expert's whole
+  ``[K, N]`` matrix from HBM again for every row tile (110 operations a
+  byte at ``block_t`` 128: the 35% of peak the kernels read then). There
+  is no knob: the ``GMM_BLOCK_N`` override is gone, and
+  :func:`plan_counts` says how many calls were traced and with which
+  ``bn``. Backward is a custom VJP: dX is a gmm over the forward's own
+  weights, contracting their last axis inside the kernel (no transposed
+  copy of the bank), dW is ``tgmm``. Runs under Pallas interpret mode
+  off-TPU, so tier-1 CPU tests exercise the same kernel code.
 - ``blocked`` — the kernel's tiling expressed as plain XLA ops: reshape the
   tile-aligned buffer to ``[n_tiles, block_t, K]``, gather each tile's
   expert weight through the same ``tile_experts`` table, one batched
@@ -30,7 +53,7 @@ Two backends behind one differentiable entry point:
   backend and differentiates itself; the reference semantics the other
   two backends are tested against.
 
-Contract shared by both backends (the dispatcher in models/moe.py
+Contract shared by the backends (the dispatcher in models/moe.py
 guarantees it): ``group_sizes`` must each be a multiple of ``block_t`` so a
 row tile never straddles two experts, and rows inside a group beyond the
 real token count are zero padding. Rows past ``sum(group_sizes)`` are
@@ -39,9 +62,11 @@ compute-garbage tiles the caller must never read back.
 
 from __future__ import annotations
 
+import collections
 import functools
 import os
-from typing import Optional
+import threading
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -51,15 +76,16 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = [
     "DEFAULT_BLOCK_T",
     "gmm",
+    "gmm_plan",
     "pick_block_t",
+    "plan_counts",
     "round_up",
     "tile_experts",
 ]
 
-# Row-tile height and output-column tile width. 128 matches the MXU systolic
-# array; off-TPU the values only shape the dispatch padding.
+# Row-tile height. 128 matches the MXU systolic array; off-TPU the value only
+# shapes the dispatch padding. The column block is the plan's (gmm_plan).
 DEFAULT_BLOCK_T = int(os.environ.get("GMM_BLOCK_T", 128))
-DEFAULT_BLOCK_N = int(os.environ.get("GMM_BLOCK_N", 128))
 
 
 def _interpret() -> bool:
@@ -116,45 +142,131 @@ def tile_experts(group_sizes: jnp.ndarray, n_tiles: int, block_t: int) -> jnp.nd
     return jnp.minimum(te, group_sizes.shape[0] - 1).astype(jnp.int32)
 
 
+# -- the plan: a call's column block from its shapes ---------------------------
+_LANES = 128
+# Every call raises Mosaic's scoped VMEM limit to this (a v5e core has
+# 128 MiB; the default scope is 16), as the flash kernels' resident paths do,
+# and takes the widest column block whose buffers stay under the budget: the
+# held block (an expert's weights ``[K, bn]`` in gmm, its dW ``[K, bn]`` in
+# tgmm) and the streamed tiles, each double-buffered by the pipeline, tgmm's
+# float32 partial sums, and the float32 product of a grid step before it is
+# cast or added. The rest is for what the compiler spills.
+_RESIDENT_VMEM_LIMIT = 32 * 2**20
+_RESIDENT_VMEM_BUDGET = 24 * 2**20
+# tgmm multiplies a row tile against dy in chunks of this many of K's columns,
+# so the float32 product that is added to the partial sums stays small.
+_TGMM_K_CHUNK = 512
+
+
+def _tgmm_k_chunk(K: int) -> int:
+    return _TGMM_K_CHUNK if K % _TGMM_K_CHUNK == 0 else K
+
+
+def _vmem_bytes(kernel: str, K: int, bn: int, block_t: int, itemsize: int) -> int:
+    """VMEM one call holds at column block ``bn``: VMEM pads the last axis to
+    128 lanes and the one before it to a register's rows."""
+    sublanes = 32 // itemsize
+    bt = round_up(block_t, sublanes)
+    lanes = round_up(bn, _LANES)
+    held = 2 * round_up(K, sublanes) * lanes * itemsize
+    tiles = 2 * bt * (round_up(K, _LANES) + lanes) * itemsize
+    if kernel == "gmm":
+        return held + tiles + round_up(block_t, 8) * lanes * 4
+    return held + tiles + (round_up(K, 8) + _tgmm_k_chunk(K)) * lanes * 4
+
+
+def gmm_plan(K: int, N: int, block_t: int, dtype, kernel: str = "gmm") -> int:
+    """The column block ``bn`` of a ``gmm`` or ``tgmm`` call over ``[T, K]``
+    rows and ``[E, K, N]`` weights; a pure function of its arguments. The
+    widest of ``N`` itself and the multiples of 128 dividing it whose buffers
+    fit the budget; the narrowest of them where none does (Mosaic then has
+    the last word on the call)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    widths = [N] + [bn for bn in range((N - 1) // _LANES * _LANES, 0, -_LANES) if N % bn == 0]
+    for bn in widths:
+        if _vmem_bytes(kernel, K, bn, block_t, itemsize) <= _RESIDENT_VMEM_BUDGET:
+            return bn
+    return widths[-1]
+
+
+# The plan is chosen while tracing, so this counts traces, not calls of the
+# compiled step: what a jitted program runs is what its one trace counted.
+_PLAN_KEYS = ("gmm_resident", "tgmm_resident")
+_plan_counts: Dict[str, int] = collections.Counter()
+_plan_counts_lock = threading.Lock()
+
+
+def plan_counts() -> Dict[str, int]:
+    """Kernel calls traced so far in this process, by kernel (every call
+    holds an expert's block across its row tiles), and again by their column
+    block (``gmm_bn1024``: a key a block that was traced; the two totals
+    always)."""
+    with _plan_counts_lock:
+        return {**{key: _plan_counts[key] for key in _PLAN_KEYS},
+                **{key: n for key, n in sorted(_plan_counts.items()) if key not in _PLAN_KEYS}}
+
+
+def _traced_plan(kernel, K, N, block_t, dtype) -> int:
+    """The column block of one kernel call, tallied."""
+    bn = gmm_plan(K, N, block_t, dtype, kernel)
+    with _plan_counts_lock:
+        _plan_counts[f"{kernel}_resident"] += 1
+        _plan_counts[f"{kernel}_bn{bn}"] += 1
+    return bn
+
+
 def _compiler_params(semantics):
     if _interpret():
         return None
-    return pltpu.CompilerParams(dimension_semantics=semantics)
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=_RESIDENT_VMEM_LIMIT)
 
 
-# -- forward kernel ----------------------------------------------------------
-def _gmm_kernel(te_ref, x_ref, w_ref, o_ref):
-    del te_ref  # only consumed by the index maps
-    o_ref[...] = jax.lax.dot_general(
-        x_ref[...], w_ref[0],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(o_ref.dtype)
-
-
-def _gmm_pallas(x, w, group_sizes, block_t, block_n):
-    T, K = x.shape
-    E, _, N = w.shape
-    bn = min(block_n, N)
+def _blocks(T, N, block_t, bn):
     if T % block_t or N % bn:
         raise ValueError(
             f"gmm pallas backend needs T ({T}) % block_t ({block_t}) == 0 and "
             f"N ({N}) % block_n ({bn}) == 0; the moe dispatcher pads for this")
-    n_t, n_n = T // block_t, N // bn
+    return T // block_t, N // bn
+
+
+# -- forward kernel ----------------------------------------------------------
+def _gmm_kernel(te_ref, x_ref, w_ref, o_ref, *, w_contracts):
+    del te_ref  # only consumed by the index maps
+    o_ref[...] = jax.lax.dot_general(
+        x_ref[...], w_ref[0],
+        (((1,), (w_contracts,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ).astype(o_ref.dtype)
+
+
+def _gmm_pallas(x, w, group_sizes, block_t, transposed_w=False):
+    """``x [T, K]`` against ``w [E, K, N]``, or with ``transposed_w`` against
+    ``w [E, N, K]`` contracted over its last axis (dX against the forward's
+    own weights, with no transposed copy of the bank)."""
+    T, K = x.shape
+    N = w.shape[1] if transposed_w else w.shape[2]
+    bn = _traced_plan("gmm", K, N, block_t, x.dtype)
+    n_t, n_n = _blocks(T, N, block_t, bn)
     te = tile_experts(group_sizes, n_t, block_t)
+    # The column block outermost and the row tile fastest, so the weight
+    # block's index repeats across an expert's tiles (and the dead tail's)
+    # and it is copied in once.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n_t, n_n),
+        grid=(n_n, n_t),
         in_specs=[
-            pl.BlockSpec((block_t, K), lambda t, n, te: (t, 0)),
-            pl.BlockSpec((1, K, bn), lambda t, n, te: (te[t], 0, n)),
+            pl.BlockSpec((block_t, K), lambda n, t, te: (t, 0)),
+            pl.BlockSpec((1, bn, K), lambda n, t, te: (te[t], n, 0)) if transposed_w
+            else pl.BlockSpec((1, K, bn), lambda n, t, te: (te[t], 0, n)),
         ],
-        out_specs=pl.BlockSpec((block_t, bn), lambda t, n, te: (t, n)),
+        out_specs=pl.BlockSpec((block_t, bn), lambda n, t, te: (t, n)),
     )
     return pl.pallas_call(
-        _gmm_kernel,
+        functools.partial(_gmm_kernel, w_contracts=1 if transposed_w else 0),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, N), x.dtype),
+        # every output block is written once: both axes may be split over cores
         compiler_params=_compiler_params(("parallel", "parallel")),
         interpret=_interpret(),
         name="gmm",  # also the innermost scope of its ops
@@ -162,34 +274,35 @@ def _gmm_pallas(x, w, group_sizes, block_t, block_n):
 
 
 # -- backward dW kernel (tgmm) -----------------------------------------------
-def _tgmm_kernel(te_ref, x_ref, dy_ref, dw_ref):
+def _tgmm_kernel(te_ref, x_ref, dy_ref, dw_ref, acc_ref, *, k_chunk):
     # Grid is (n_n, n_t) with t fastest, so revisits of one expert's output
-    # block are consecutive — initialize on the first tile of each group,
-    # accumulate on the rest (the Pallas output-revisit rule).
-    t = pl.program_id(1)
-    prev = te_ref[jnp.maximum(t - 1, 0)]
-    first = jnp.logical_or(t == 0, te_ref[t] != prev)
-    part = jax.lax.dot_general(
-        x_ref[...], dy_ref[...],
-        (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[None].astype(dw_ref.dtype)
+    # block are consecutive: the partial sums start on the first tile of
+    # each group, grow in float32 on the rest, and are rounded into the
+    # output block once, on the group's last tile.
+    t, n_t = pl.program_id(1), pl.num_programs(1)
+    first = jnp.logical_or(t == 0, te_ref[t] != te_ref[jnp.maximum(t - 1, 0)])
+    last = jnp.logical_or(t == n_t - 1, te_ref[t] != te_ref[jnp.minimum(t + 1, n_t - 1)])
 
-    @pl.when(first)
-    def _init():
-        dw_ref[...] = part
+    for k in range(0, x_ref.shape[1], k_chunk):
+        rows = pl.ds(k, k_chunk)
+        part = jax.lax.dot_general(
+            x_ref[:, rows], dy_ref[...],
+            (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        # a select, not 0 * the scratch: what the last expert left there is stale
+        acc_ref[rows, :] = jnp.where(first, part, acc_ref[rows, :] + part)
 
-    @pl.when(jnp.logical_not(first))
-    def _accumulate():
-        dw_ref[...] = dw_ref[...] + part
+    @pl.when(last)
+    def _round():
+        dw_ref[0] = acc_ref[...].astype(dw_ref.dtype)
 
 
-def _tgmm_pallas(x, dy, group_sizes, n_experts, block_t, block_n):
+def _tgmm_pallas(x, dy, group_sizes, n_experts, block_t):
     """dW ``[E, K, N]`` = per-group ``x_rows.T @ dy_rows``."""
     T, K = x.shape
     _, N = dy.shape
-    bn = min(block_n, N)
-    n_t, n_n = T // block_t, N // bn
+    bn = _traced_plan("tgmm", K, N, block_t, x.dtype)
+    n_t, n_n = _blocks(T, N, block_t, bn)
     te = tile_experts(group_sizes, n_t, block_t)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -199,9 +312,10 @@ def _tgmm_pallas(x, dy, group_sizes, n_experts, block_t, block_n):
             pl.BlockSpec((block_t, bn), lambda n, t, te: (t, n)),
         ],
         out_specs=pl.BlockSpec((1, K, bn), lambda n, t, te: (te[t], 0, n)),
+        scratch_shapes=[pltpu.VMEM((K, bn), jnp.float32)],
     )
     dw = pl.pallas_call(
-        _tgmm_kernel,
+        functools.partial(_tgmm_kernel, k_chunk=_tgmm_k_chunk(K)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_experts, K, N), x.dtype),
         compiler_params=_compiler_params(("parallel", "arbitrary")),
@@ -284,19 +398,22 @@ _gmm_int8.defvjp(_gmm_int8_fwd, _gmm_int8_bwd)
 
 
 # -- differentiable entry point ----------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _gmm_pallas_diff(x, w, group_sizes, block_t, block_n):
-    return _gmm_pallas(x, w, group_sizes, block_t, block_n)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm_pallas_diff(x, w, group_sizes, block_t):
+    return _gmm_pallas(x, w, group_sizes, block_t)
 
 
-def _gmm_fwd(x, w, group_sizes, block_t, block_n):
-    return _gmm_pallas(x, w, group_sizes, block_t, block_n), (x, w, group_sizes)
+def _gmm_fwd(x, w, group_sizes, block_t):
+    return _gmm_pallas(x, w, group_sizes, block_t), (x, w, group_sizes)
 
 
-def _gmm_bwd(block_t, block_n, residuals, dy):
+def _gmm_bwd(block_t, residuals, dy):
     x, w, group_sizes = residuals
-    dx = _gmm_pallas(dy, w.transpose(0, 2, 1), group_sizes, block_t, block_n)
-    dw = _tgmm_pallas(x, dy, group_sizes, w.shape[0], block_t, block_n)
+    # dX contracts the weights' last axis inside the kernel: handing it
+    # w.transpose(0, 2, 1) costs a layout copy of every expert's matrix a
+    # call, and the MXU takes either orientation alike.
+    dx = _gmm_pallas(dy, w, group_sizes, block_t, transposed_w=True)
+    dw = _tgmm_pallas(x, dy, group_sizes, w.shape[0], block_t)
     return dx.astype(x.dtype), dw.astype(w.dtype), None
 
 
@@ -309,7 +426,6 @@ def gmm(
     group_sizes: jnp.ndarray,
     *,
     block_t: int = DEFAULT_BLOCK_T,
-    block_n: int = DEFAULT_BLOCK_N,
     backend: Optional[str] = None,
     precision: Optional[str] = None,
 ) -> jnp.ndarray:
@@ -352,4 +468,4 @@ def gmm(
     if backend != "pallas":
         raise ValueError(
             f"unknown gmm backend {backend!r} (pallas|blocked|ragged)")
-    return _gmm_pallas_diff(x, w, group_sizes.astype(jnp.int32), block_t, block_n)
+    return _gmm_pallas_diff(x, w, group_sizes.astype(jnp.int32), block_t)

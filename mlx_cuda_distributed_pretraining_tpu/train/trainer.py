@@ -47,6 +47,7 @@ from ..obs import compiles
 from ..ops.flash_attention import plan_counts as flash_plan_counts
 from ..models.moe import plan_counts as moe_plan_counts
 from ..ops.fused_ce import plan_counts as fused_ce_plan_counts
+from ..ops.grouped_matmul import plan_counts as gmm_plan_counts
 from ..obs.flops import (GoodputLedger, matmul_params, model_flops_per_token,
                          peak_flops_per_chip)
 from ..obs.flops import mfu as compute_mfu
@@ -66,6 +67,8 @@ _PLAN_TALLIES = {
     "flash_plan": ("flash plan (kernel calls traced, by path)", flash_plan_counts),
     "fused_ce_plan": ("fused CE (chunk walks traced)", fused_ce_plan_counts),
     "moe_plan": ("expert layers (traced, by form)", moe_plan_counts),
+    "gmm_plan": ("grouped matmuls (kernel calls traced, by kernel and column block)",
+                 gmm_plan_counts),
 }
 
 
@@ -482,9 +485,10 @@ class Trainer:
         # Which path the step's flash kernels, forward and backward, were traced to
         # (ops/flash_attention.py flash_plan), and whether its fused CE computes
         # the head's gradients in the forward walk (ops/fused_ce.py), and how many
-        # expert layers it dispatches and combines by gathers (models/moe.py): the
-        # tallies since here, logged after the first compile and carried by the
-        # first step_window event.
+        # expert layers it dispatches and combines by gathers (models/moe.py), and
+        # how many gmm and tgmm calls keep an expert's block in VMEM, at which
+        # column block (ops/grouped_matmul.py gmm_plan): the tallies since here,
+        # logged after the first compile and carried by the first step_window event.
         self._plan_tallies = {**_PLAN_TALLIES, **(arch.plans or {})}
         self._plans_seen = {name: counts() for name, (_, counts) in self._plan_tallies.items()}
         self._plans: Optional[Dict[str, Dict[str, int]]] = None

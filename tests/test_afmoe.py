@@ -552,6 +552,8 @@ def test_the_cell_rehearses_through_its_traffic_kind(tmp_path, monkeypatch):
     assert plan["window_layers"] >= 2 and plan["global_layers"] >= 1     # the dense layer; the scan
     assert plan["window_simple"] == plan["window_layers"] and "flash_plan" in first
     assert first["moe_plan"]["dispatch_gather"] == first["moe_plan"]["combine_gather"] >= 1
+    # off the chip the expert layers run the blocked backend: no gmm or tgmm call was traced
+    assert not any(first["gmm_plan"].values()) and "gmm_resident" in first["gmm_plan"]
     assert res["end_to_end"]["train_tokens_per_s_per_chip"] > 0 and res["end_to_end"]["setup_s"] > 0
     assert flops.train_flops_per_token(config, mix["seq_len"], 0.0) < res["sources"]["flops_per_token"]
     # every metric the cell is listed under has a reader file, and the shares of a causal

@@ -256,32 +256,96 @@ def test_grouped_is_dropless_keeps_overflow_tokens():
     assert loss_g != pytest.approx(loss_e, abs=1e-7)
 
 
-def test_gmm_backends_match_ragged_fwd_and_bwd():
-    # blocked and (interpret-mode) pallas against the XLA-native
-    # ragged_dot reference: forward values and both gradients.
+# name: K, N, block_t, group sizes, T (rows past the groups are a dead tail),
+# dtype, the VMEM budget as the column block gmm should just fit with (None:
+# the module's own), and the column block gmm's plan then takes (tgmm holds
+# float32 sums besides, so under a tight budget its block may be narrower).
+_GMM_CASES = {
+    "bn_is_N-f32-bt64": (32, 48, 64, [64, 0, 128, 64], 256, jnp.float32, None, 48),
+    "bn_is_N-bf16-bt8-dead_tail": (32, 256, 8, [16, 0, 24, 8], 80, jnp.bfloat16, None, 256),
+    "two_column_blocks-f32-bt64-dead_tail": (32, 512, 64, [64, 0, 192, 64], 512, jnp.float32, 256, 256),
+    "two_column_blocks-bf16-bt8": (32, 512, 8, [8, 0, 32, 8], 48, jnp.bfloat16, 256, 256),
+    "narrowest_128-f32-bt8-dead_tail": (32, 256, 8, [8, 0, 24, 16], 72, jnp.float32, 128, 128),
+    "narrowest_128-bf16-bt64": (32, 256, 64, [64, 0, 128, 64], 256, jnp.bfloat16, 128, 128),
+}
+
+
+@pytest.mark.parametrize("backend,case", [("blocked", "bn_is_N-f32-bt64")]
+                         + [("pallas", case) for case in _GMM_CASES])
+def test_gmm_backends_match_ragged_fwd_and_bwd(backend, case, monkeypatch):
+    """blocked and (interpret-mode) pallas against the XLA-native ragged_dot
+    reference: forward values and both gradients, at every width the kernels'
+    plan takes (the weights' whole width, several column blocks, the
+    narrowest block of 128, which tgmm takes over its budget), with an empty
+    group, an expert of several tiles and a dead tail of several tiles."""
     from mlx_cuda_distributed_pretraining_tpu.ops import grouped_matmul as gm
 
-    bt = 64
+    K, N, bt, sizes, T, dtype, admit, want = _GMM_CASES[case]
+    if admit is not None:
+        monkeypatch.setattr(gm, "_RESIDENT_VMEM_BUDGET",
+                            gm._vmem_bytes("gmm", K, admit, bt, jnp.dtype(dtype).itemsize))
+    assert gm.gmm_plan(K, N, bt, dtype) == want
+    dw_bn = gm.gmm_plan(K, N, bt, dtype, "tgmm")
+    assert dw_bn <= want
     rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.normal(size=(256, 32)), jnp.float32)
-    w = jnp.asarray(rng.normal(size=(4, 32, 48)), jnp.float32)
-    sizes = jnp.asarray([64, 0, 128, 64], jnp.int32)  # empty group included
+    live = (np.arange(T) < sum(sizes))[:, None]  # the dispatcher's buffer: zero rows past the groups
+    x = jnp.asarray(rng.normal(size=(T, K)) * live, dtype)
+    w = jnp.asarray(rng.normal(size=(len(sizes), K, N)), dtype)
+    sizes = jnp.asarray(sizes, jnp.int32)
 
     def loss(x, w, backend):
-        y = gm.gmm(x, w, sizes, block_t=bt, backend=backend)
+        y = gm.gmm(x, w, sizes, block_t=bt, backend=backend).astype(jnp.float32)
         return (y * y).sum(), y
 
     (ref_l, ref_y), (ref_dx, ref_dw) = jax.value_and_grad(
         loss, argnums=(0, 1), has_aux=True)(x, w, "ragged")
-    for backend in ("blocked", "pallas"):
-        (l, y), (dx, dw) = jax.value_and_grad(
-            loss, argnums=(0, 1), has_aux=True)(x, w, backend)
-        np.testing.assert_allclose(np.asarray(y), np.asarray(ref_y),
-                                   atol=1e-5, rtol=1e-5, err_msg=backend)
-        np.testing.assert_allclose(np.asarray(dx), np.asarray(ref_dx),
-                                   atol=1e-3, rtol=1e-4, err_msg=backend)
-        np.testing.assert_allclose(np.asarray(dw), np.asarray(ref_dw),
-                                   atol=1e-3, rtol=1e-4, err_msg=backend)
+    seen = gm.plan_counts()
+    (l, y), (dx, dw) = jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True)(x, w, backend)
+    traced = {k: n - seen.get(k, 0) for k, n in gm.plan_counts().items() if n - seen.get(k, 0)}
+    if backend == "pallas":  # the forward and dX (over the weights' other axis); dW
+        assert traced["gmm_resident"] == 2 and traced["tgmm_resident"] == 1
+        assert traced[f"gmm_bn{want}"] >= 1 and traced[f"tgmm_bn{dw_bn}"] == 1
+    else:
+        assert not traced
+    f32 = dtype == jnp.float32
+    for name, got, ref, atol in (("y", y, ref_y, 1e-5), ("dx", dx, ref_dx, 1e-3), ("dw", dw, ref_dw, 1e-3)):
+        ref = np.asarray(ref, np.float32)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), ref, err_msg=f"{backend} {name}",
+            atol=atol if f32 else 0.02 * np.abs(ref).max(), rtol=1e-4 if f32 else 0.02)
+
+
+# cell 2's and cell 3's expert matrices, both orientations (the forward's and
+# dX's), at the cells' row tile in bfloat16: gmm holds the whole width of the
+# weights, tgmm half of it or more
+@pytest.mark.parametrize("K,N", [(3584, 1024), (1024, 3584), (2048, 1024), (1024, 2048)])
+@pytest.mark.parametrize("kernel", ["gmm", "tgmm"])
+def test_gmm_plan_keeps_the_cells_matrices_resident(kernel, K, N):
+    from mlx_cuda_distributed_pretraining_tpu.ops import grouped_matmul as gm
+
+    bn = gm.gmm_plan(K, N, 128, jnp.bfloat16, kernel)
+    assert N % bn == 0 and bn % 128 == 0
+    assert bn >= 512 and (kernel == "tgmm" or bn == N)
+    assert gm._vmem_bytes(kernel, K, bn, 128, 2) <= gm._RESIDENT_VMEM_BUDGET
+    assert bn == gm.gmm_plan(K, N, 128, jnp.bfloat16, kernel)   # a pure function
+
+
+@pytest.mark.parametrize("K,N,block_t,dtype,kernel,want", [
+    (10**6, 1024, 128, jnp.bfloat16, "gmm", 128),   # a K no budget holds: the narrowest block
+    (10**6, 64, 8, jnp.float32, "gmm", 64),
+    (14336, 4096, 128, jnp.bfloat16, "gmm", 256),   # wider than 128 and under N
+    (14336, 4096, 128, jnp.bfloat16, "tgmm", 128),  # 128 inside the budget
+    (2048, 64, 8, jnp.bfloat16, "gmm", 64),         # a decode-sized dispatch, N under 128
+    (256, 200, 16, jnp.float32, "tgmm", 200),       # no multiple of 128 divides N
+    (2048, 128, 8, jnp.float32, "gmm", 128),
+])
+def test_gmm_plan_by_shape(K, N, block_t, dtype, kernel, want):
+    from mlx_cuda_distributed_pretraining_tpu.ops import grouped_matmul as gm
+
+    assert gm.gmm_plan(K, N, block_t, dtype, kernel) == want
+    if kernel == "gmm":
+        assert gm.gmm_plan(K, N, block_t, dtype) == want
 
 
 def _scatter_ffn(experts, x_flat, gate_idx, gate_w, num_experts, block_t, first=0):

@@ -487,10 +487,15 @@ def test_trainer_reports_the_flash_plan(tmp_path):
     moe_plan = windows[0]["moe_plan"]
     assert moe_plan == {"dispatch_gather": 0, "combine_gather": 0}
     assert "moe_plan" not in windows[1]
+    # nor a grouped matmul kernel (ops/grouped_matmul.py)
+    gmm_plan = windows[0]["gmm_plan"]
+    assert {"gmm_resident", "tgmm_resident"} <= set(gmm_plan)
+    assert not any(gmm_plan.values()) and "gmm_plan" not in windows[1]
     with open(tr.logger.log_path) as f:
         lines = [ln for ln in f if "flash plan" in ln]
-    assert len(lines) == 1
-    assert all(f"{key}={n}" in lines[0] for key, n in {**plan, **ce_plan, **moe_plan}.items())
+    assert len(lines) == 1 and "grouped matmuls (kernel calls traced" in lines[0]
+    assert all(f"{key}={n}" in lines[0]
+               for key, n in {**plan, **ce_plan, **moe_plan, **gmm_plan}.items())
 
 
 def test_trainer_registry_replays_on_construction(tmp_path):

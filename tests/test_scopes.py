@@ -214,15 +214,23 @@ def test_moe_step_scopes():
     assert not any("ffn" in scopes_of(n) for n in names)
 
 
-def test_grouped_matmul_kernels_are_named():
+@pytest.mark.parametrize("n_out", [128, 256])
+def test_grouped_matmul_kernels_are_named(n_out):
+    """``gmm`` and ``tgmm``: the names the trace's reader keys on, whatever
+    the column block (``plan_counts()`` tells the blocks apart)."""
     x = jnp.ones((32, 16), jnp.float32)
-    w = jnp.ones((4, 16, 128), jnp.float32)
+    w = jnp.ones((4, 16, n_out), jnp.float32)
     sizes = jnp.array([8, 8, 8, 8], jnp.int32)
 
     def loss(x, w):
         return gm.gmm(x, w, sizes, block_t=8, backend="pallas").sum()
 
+    before = gm.plan_counts()
     names = op_names(jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, w).compile().as_text())
+    after = gm.plan_counts()
+    assert after["gmm_resident"] == before["gmm_resident"] + 2    # the forward and dX
+    assert after["tgmm_resident"] == before["tgmm_resident"] + 1
+    assert after[f"tgmm_bn{n_out}"] == before.get(f"tgmm_bn{n_out}", 0) + 1
     assert any("gmm" in scopes_of(n) for n in names)
     assert any("tgmm" in scopes_of(n) for n in names)
 

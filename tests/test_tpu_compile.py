@@ -11,6 +11,7 @@ these say nothing about results or times — ``chip_smoke.py`` does.
 here; the tests steer it (monkeypatch), the program has no option for it.
 """
 
+import collections
 import dataclasses
 import os
 
@@ -234,6 +235,44 @@ def test_tgmm_compiles_for_v5e(v5e, compiled_kernels):
     # dX (a gmm against transposed weights) and dW (tgmm); the forward's
     # output feeds neither, so the compiler drops it
     assert hlo.count("tpu_custom_call") >= 2
+
+
+# The benchmark's two expert-parallel cells, one chunk's dispatch: rows of the
+# buffer, held experts, and an expert's [K, N] (PERF.md section 4).
+GMM_CELLS = {"xing4_0-29b-a4b-ep8": (5120, 8, 3584, 1024),
+             "trinity-mini-ep8": (67584, 16, 2048, 1024)}
+
+
+@pytest.mark.parametrize("orientation", ["up", "down"])
+@pytest.mark.parametrize("cell", list(GMM_CELLS))
+def test_gmm_resident_plan_compiles_for_v5e_at_the_cells_shapes(cell, orientation, v5e,
+                                                                compiled_kernels):
+    """``gmm`` and its gradient (dX, ``tgmm``) at the cells' own shapes: the
+    plan's column blocks (the weights' whole width in ``gmm``; half of it
+    or more, with float32 sums, in ``tgmm``), and Mosaic accepts the VMEM
+    they ask for."""
+    T, E, K, N = GMM_CELLS[cell]
+    if orientation == "down":
+        K, N = N, K
+    args = (_sds((T, K), jnp.bfloat16, v5e), _sds((E, K, N), jnp.bfloat16, v5e),
+            _sds((E,), jnp.int32, v5e))
+
+    def fwd(x, w, sizes):
+        return gm.gmm(x, w, sizes, backend="pallas")
+
+    def loss(x, w, sizes):
+        return fwd(x, w, sizes).astype(jnp.float32).sum()
+
+    before = gm.plan_counts()
+    hlo = jax.jit(fwd).lower(*args).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 1
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*args).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 2
+    traced = {k: n - before.get(k, 0) for k, n in gm.plan_counts().items() if n - before.get(k, 0)}
+    dw_bn = gm.gmm_plan(K, N, gm.DEFAULT_BLOCK_T, jnp.bfloat16, "tgmm")
+    want = collections.Counter({"gmm_resident": 3, "tgmm_resident": 1, f"tgmm_bn{dw_bn}": 1})
+    want.update({f"gmm_bn{N}": 2, f"gmm_bn{K}": 1})    # the forward twice, dX over the transposed weights
+    assert traced == dict(want) and dw_bn >= 512
 
 
 def test_paged_decode_step_fits_one_v5e(v5e, monkeypatch):
