@@ -40,6 +40,7 @@ from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding
 
 from ..parallel.sharding_rules import batch_pspec
@@ -125,10 +126,13 @@ class DevicePrefetcher:
         step = self._cursor
         snapshot = None
         t0 = time.perf_counter()
+        # Annotations on this thread: in a profile a gap under the loop's
+        # train.data_get is the read (prefetch.fetch) or the copy (prefetch.h2d).
         try:
-            batch = self.loader.generate_batch(step - 1)
-            if self._stateful:
-                snapshot = self.loader.state_dict()
+            with TraceAnnotation("prefetch.fetch", step=step):
+                batch = self.loader.generate_batch(step - 1)
+                if self._stateful:
+                    snapshot = self.loader.state_dict()
         except StopIteration:
             self._done = True
             return {"kind": "end"}
@@ -141,10 +145,11 @@ class DevicePrefetcher:
         # read every logging_interval steps.
         tokens = int(batch["mask"].sum())
         t0 = time.perf_counter()
-        dev = self._transfer(batch, self._sharding)
-        # Block HERE, in the worker: the consumer's get() never waits on
-        # the copy.
-        jax.block_until_ready(dev)
+        with TraceAnnotation("prefetch.h2d", step=step):
+            dev = self._transfer(batch, self._sharding)
+            # Block HERE, in the worker: the consumer's get() never waits on
+            # the copy.
+            jax.block_until_ready(dev)
         h2d_s = time.perf_counter() - t0
         self._cursor = step + 1
         return {
@@ -186,11 +191,13 @@ class DevicePrefetcher:
 
         ``tokens`` is this host's non-pad target count. ``waits`` carries
         ``data_wait_s`` (time this call blocked waiting for input — the
-        true stall) and ``h2d_wait_s`` (host→device transfer time for the
+        true stall), ``h2d_wait_s`` (host→device transfer time for the
         item: overlapped with compute when the worker thread is running, on
-        the critical path in synchronous mode). Raises StopIteration at end
-        of stream; re-raises loader errors.
+        the critical path in synchronous mode) and ``queue_depth`` (batches
+        that were ready when this call asked; None without a worker). Raises
+        StopIteration at end of stream; re-raises loader errors.
         """
+        depth = self._queue.qsize() if self._queue is not None else None
         if self._terminal is not None:
             item = self._terminal
             data_wait = 0.0
@@ -216,7 +223,7 @@ class DevicePrefetcher:
             if self._queue is not None:
                 self._m_queue.set(self._queue.qsize())
         return item["batch"], item["tokens"], {
-            "data_wait_s": data_wait, "h2d_wait_s": item["h2d_s"]}
+            "data_wait_s": data_wait, "h2d_wait_s": item["h2d_s"], "queue_depth": depth}
 
     # -- loader surface ------------------------------------------------------
 

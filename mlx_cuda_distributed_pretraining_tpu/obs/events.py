@@ -11,7 +11,11 @@ Event types written by the trainer / supervisor:
   compile          first dispatch finished compiling (seconds)
   step_window      one logging window (step, steps, toks, loss, tok_s,
                    mfu, goodput breakdown; a run's first one also
-                   flash_plan, the flash kernel calls traced, by path)
+                   flash_plan, the flash kernel calls traced, by path;
+                   its steps' records in summary, the slowest whole, and
+                   the host counters' differences: obs/steprecord.py,
+                   obs/hoststats.py), written when its last step closes
+  step_stall       a step over twice the run's median (its record)
   checkpoint_save  a checkpoint landed (step, seconds, blocking)
   verify           checkpoint verification outcome (tag, ok, reason)
   eval             validation ran (step, loss, seconds)

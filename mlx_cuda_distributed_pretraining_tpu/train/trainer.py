@@ -43,7 +43,7 @@ from ..obs.events import (
     replay_into,
     write_heartbeat,
 )
-from ..obs import compiles
+from ..obs import compiles, hoststats
 from ..ops.flash_attention import plan_counts as flash_plan_counts
 from ..models.moe import plan_counts as moe_plan_counts
 from ..ops.fused_ce import plan_counts as fused_ce_plan_counts
@@ -52,6 +52,7 @@ from ..obs.flops import (GoodputLedger, matmul_params, model_flops_per_token,
                          peak_flops_per_chip)
 from ..obs.flops import mfu as compute_mfu
 from ..obs.metrics import MetricsRegistry
+from ..obs.steprecord import ANNOTATED, PHASES, StepRecords
 from ..obs.trace import Tracer
 from ..optim import build_optimizer, build_schedule, schedule_value
 from ..parallel import build_mesh
@@ -474,14 +475,11 @@ class Trainer:
             summary_path=os.path.join(run_dir, "prof_summary.json"),
             report=cfg.logging.profile_report_enabled,
             top_k=cfg.logging.profile_report_top_k)
-        # Last attribution's headline fractions: exported as gauges and
-        # merged into subsequent step_window events so the profile's
-        # breakdown rides the same durable stream as tok/s and MFU.
-        self._prof_fields: Dict[str, float] = {}
         self._compiled = False  # first dispatch books into compile_s
         # obs/compiles.py totals at the last window's close: the difference
         # rides each step_window event as xla_compiles / xla_compile_s.
         self._compiles_seen = compiles.totals()
+        self._side_s = 0.0  # seconds a capture's start or stop took inside the open step
         # Which path the step's flash kernels, forward and backward, were traced to
         # (ops/flash_attention.py flash_plan), and whether its fused CE computes
         # the head's gradients in the forward walk (ops/fused_ce.py), and how many
@@ -679,6 +677,38 @@ class Trainer:
             f"{label}: " + ", ".join(f"{key}={n}" for key, n in self._plans[name].items())
             for name, (label, _) in self._plan_tallies.items()))
 
+    def _step_closed(self, rec: Optional[Dict[str, Any]]) -> None:
+        """A step's record has closed (the loop's top, the start of work
+        beside the step, or the loop's end): put it on the profiler's clock
+        and in the ring, say so if it was late, and append the window's event
+        that waited for it."""
+        self._side_s = 0.0
+        if rec is None:
+            return
+        # Zero-length: an open profiler session gets the record on the device
+        # trace's clock, on this thread; without one it is a flag test.
+        with jax.profiler.TraceAnnotation("train.step_record", **{k: rec[k] for k in ANNOTATED}):
+            pass
+        # A phase of the loop like the other four: the event's write is host
+        # work a step, which the benchmark's step_host_ms counts by its train.* name.
+        with self.tracer.phase("train.step_close", step=rec["step"]):
+            if self.tracer.enabled:
+                self.tracer.complete("train.step", rec["wall_s"],
+                                     end_mono=self._steps.closed_at, **rec)
+            over = rec.get("x_median", 0.0)
+            if over > StepRecords.STALL_FACTOR:
+                self.logger.log(
+                    f"WARNING: step {rec['step']} took {rec['wall_s']:.3f} s, {over:.1f} x the "
+                    f"run's median step; process CPU {rec['proc_cpu_s']:.3f} s, phases "
+                    + ", ".join(f"{k}={rec[k]:.3f}" for k in PHASES))
+                if self.events is not None:
+                    self.events.append("step_stall", **rec)
+            if self._pending_window is not None:
+                ev, self._pending_window = self._pending_window, None
+                fields = self._steps.window()
+                if self.events is not None:
+                    self.events.append("step_window", **ev, **fields)
+
     def _touch_heartbeat(self, step: Optional[int] = None) -> None:
         if self._hb_path is None:
             return
@@ -714,7 +744,6 @@ class Trainer:
         from ..obs.profile_report import prof_fields
 
         fields = prof_fields(report)
-        self._prof_fields = fields
         for name, val in fields.items():
             self._g_prof[name].set(val)
         agg = report["aggregate"]
@@ -1100,6 +1129,15 @@ class Trainer:
         window_start = time.perf_counter()
         last_loss = float("nan")
         stopped_early = False
+        # One record a step (obs/steprecord.py), from one top of the loop to
+        # the next, or to where an evaluation or a checkpoint begins. A
+        # window's event is built in train.log_window and waits for its last
+        # step's record to close; the host's counters (obs/hoststats.py) ride
+        # it as differences between two window closes.
+        self._steps = StepRecords()
+        self._pending_window: Optional[Dict[str, Any]] = None
+        self._side_s = 0.0
+        self._host_seen = hoststats.window_totals()
 
         # Device-side input pipeline: a background worker keeps
         # data.prefetch_depth batches resident on device, pre-sharded to the
@@ -1178,6 +1216,12 @@ class Trainer:
 
         try:
             for step in range(self.start_step + 1, self.total_steps + 1):
+                self._step_closed(self._steps.turn(
+                    step, not self._compiled, compiles.totals()[0], self._side_s))
+                # Starting or stopping a capture below is seconds of the
+                # program's own host work inside this step: no stall.
+                capture_state = (self.profiler.active, self._trace_until)
+                capture_t0 = time.perf_counter()
                 if prof_stop > prof_start:
                     if step >= prof_stop and self.profiler.active:
                         report = self.profiler.stop(step)
@@ -1218,9 +1262,13 @@ class Trainer:
                     if self.events is not None:
                         self.events.append("trace_capture", action="start",
                                            step=step, until=self._trace_until)
+                if (self.profiler.active, self._trace_until) != capture_state:
+                    self._side_s += time.perf_counter() - capture_t0
                 try:
-                    with self.tracer.phase("train.data_get", step=step):
+                    with self.tracer.phase("train.data_get", step=step) as ph:
                         batch, local_tokens, waits = self.prefetcher.get()
+                    self._steps.note(data_get_s=round(ph.seconds, 6),
+                                     queue_depth=waits["queue_depth"])
                 except StopIteration:  # finite stream ran dry (streaming sources)
                     self.logger.log(f"Data stream exhausted before step {step}; stopping")
                     break
@@ -1244,15 +1292,17 @@ class Trainer:
                         self.tracer.phase("train.dispatch", step=step) as ph:
                     self.state, metrics = self.train_step(self.state, batch)
                 self._book_dispatch(ph.seconds, step)
+                self._steps.note(dispatch_s=round(ph.seconds, 6))
 
                 window_steps += 1
                 if self.moe_stats_experts and "moe_load" in metrics:
                     # Device arrays, no sync: summed/read at the log line.
                     window_moe.append((metrics["moe_load"], metrics["moe_dropped"]))
                 if step % log_int == 0 or step == self.total_steps:
-                    with self.tracer.phase("train.loss_sync", step=step):
+                    with self.tracer.phase("train.loss_sync", step=step) as ph:
                         loss = float(metrics["loss"])  # device sync point
-                    with self.tracer.phase("train.log_window", step=step):
+                    self._steps.note(loss_sync_s=round(ph.seconds, 6))
+                    with self.tracer.phase("train.log_window", step=step) as ph:
                         last_loss = loss
                         elapsed = max(time.perf_counter() - window_start, 1e-9)
                         # Close the goodput window: components (compile, data
@@ -1381,11 +1431,12 @@ class Trainer:
                             ev.update({k: line[k] for k in (
                                 "moe_rows_held", "moe_load_max_over_mean", "moe_drop",
                                 "main_loss", "mtp_loss") if k in line})
-                            # Latest graftprof fractions ride every window
-                            # after a capture, so the durable stream records
-                            # the breakdown next to the tok/s it explains.
-                            ev.update(self._prof_fields)
-                            self.events.append("step_window", **ev)
+                            seen = hoststats.window_totals()
+                            ev.update(hoststats.window_fields(self._host_seen, seen))
+                            self._host_seen = seen
+                        # Appended when the window's last step closes (below,
+                        # or at the next top of the loop), with its steps' records.
+                        self._pending_window = ev if self.events is not None else {}
                         if self.tracer.enabled:
                             self.tracer.instant(
                                 "step_window", step=step, tok_s=round(tok_s, 2),
@@ -1395,6 +1446,14 @@ class Trainer:
                         window_tokens = 0
                         window_steps = 0
                         window_start = time.perf_counter()
+                    self._steps.note(log_window_s=round(ph.seconds, 6))
+
+                saved_this_step = bool(ckpt_int and step % ckpt_int == 0)
+                if saved_this_step or self._preempted or (val_int and step % val_int == 0):
+                    # Work beside the step is in no step's record: the step
+                    # ends here, and its window's event is written before the
+                    # evaluation's and the checkpoint's.
+                    self._step_closed(self._steps.close(compiles.totals()[0], self._side_s))
 
                 if val_int and step % val_int == 0:
                     v = self.validate()
@@ -1409,7 +1468,6 @@ class Trainer:
                 if cfg.logging.log_samples and val_int and step % val_int == 0:
                     self.generate_samples(step)
 
-                saved_this_step = bool(ckpt_int and step % ckpt_int == 0)
                 if saved_this_step:
                     # Interval saves overlap the disk write with training;
                     # final/preemption saves below stay blocking.
@@ -1442,6 +1500,8 @@ class Trainer:
             # final checkpoint needs is retained on the prefetcher object).
             if self.prefetcher is not None:
                 self.prefetcher.stop()
+            if self._pending_window is not None:  # the last window's event waits for it
+                self._step_closed(self._steps.close(compiles.totals()[0], self._side_s))
             # Drain pending async checkpoint writes even when an exception
             # escapes the loop — the interpreter would otherwise kill the
             # daemon writer mid-file (temp+rename makes that safe for the
