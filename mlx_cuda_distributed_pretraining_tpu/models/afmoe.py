@@ -249,16 +249,32 @@ def gated_attention(p: Params, x: jnp.ndarray, args: AfmoeArgs, positions, slidi
         return out @ p["wo"]["weight"]
 
 
-def routed_ffn(p: Params, x: jnp.ndarray, args: AfmoeArgs):
-    """Shared expert + the held share of the routed experts → ``(y, stats)``."""
+def routed_ffn(p: Params, x: jnp.ndarray, args: AfmoeArgs, tail=None):
+    """Shared expert + the held share of the routed experts → ``(y, stats)``;
+    with ``tail``, ``y`` is ``tail(y_c)`` of every chunk of tokens
+    (``moe.sigmoid_routed_ffn``)."""
     return moe_lib.sigmoid_routed_ffn(p, x, args.num_experts_per_tok, args.route_scale,
                                       args.experts_held, args.n_routed_experts,
-                                      args.held_chunk_rows, args.matmul_precision)
+                                      args.held_chunk_rows, args.matmul_precision, tail=tail)
 
 
 def block(p: Params, x: jnp.ndarray, positions, args: AfmoeArgs, routed: bool, sliding):
-    """One decoder layer → ``(x', routing stats | None)``."""
+    """One decoder layer → ``(x', routing stats | None)``.
+
+    A routed layer's post-norm, ``rms_norm(shared + routed, post_ffn_norm)``, is
+    handed to the expert layer's chunk loop and runs there, a chunk of tokens
+    at a time (a norm over a token's own channels): its backward reads
+    ``shared + routed``, and outside the loop that read is what made a
+    rematerialised layer run the held experts' forward a third time
+    (``moe.held_share_ffn``). The residual add stays here: its backward reads
+    no value, and inside the loop it would be one more ``[B, S, C]`` operand
+    and one more stacked cotangent (0.10 GiB of the cell's step)."""
     eps = args.rms_norm_eps
+
+    def post_norm(y):
+        with jax.named_scope("norm"):
+            return rms_norm(y, p["post_ffn_norm"]["weight"], eps)
+
     with jax.named_scope("layer"):
         with jax.named_scope("norm"):
             h = rms_norm(x, p["attention_norm"]["weight"], eps)
@@ -267,12 +283,13 @@ def block(p: Params, x: jnp.ndarray, positions, args: AfmoeArgs, routed: bool, s
             x = x + rms_norm(y, p["post_attention_norm"]["weight"], eps)
             h = rms_norm(x, p["ffn_norm"]["weight"], eps)
         if routed:
-            y, stats = routed_ffn(p["feed_forward"], h, args)
+            y, stats = routed_ffn(p["feed_forward"], h, args, tail=post_norm)
         else:
             with jax.named_scope("ffn"):
-                y, stats = mlp_block(p["feed_forward"], h), None
+                y = mlp_block(p["feed_forward"], h)
+            y, stats = post_norm(y), None
         with jax.named_scope("norm"):
-            return x + rms_norm(y, p["post_ffn_norm"]["weight"], eps), stats
+            return x + y, stats
 
 
 def _cast(tree, dtype):

@@ -48,7 +48,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import threading
-from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -271,7 +271,9 @@ def _einsum_moe(
 # holds a scatter of activation rows (XLA's scatters on the TPU are serial in
 # their updates). Counted while tracing, so this counts traces, not calls of
 # the compiled step: what a jitted program runs is what its one trace counted.
-_PLAN_KEYS = ("dispatch_gather", "combine_gather")
+# ``chunk_loop_tail``: chunk loops of a layer that holds a share traced with the
+# layer's token-local tail inside (:func:`held_share_ffn`).
+_PLAN_KEYS = ("dispatch_gather", "combine_gather", "chunk_loop_tail")
 _plan_counts: Dict[str, int] = collections.Counter()
 _plan_counts_lock = threading.Lock()
 
@@ -284,7 +286,9 @@ def _count_plan(key: str) -> None:
 def plan_counts() -> Dict[str, int]:
     """Dispatches into an expert buffer and combines out of one traced so far
     in this process, both in the gather form (:func:`dispatch_rows`,
-    :func:`combine_rows`)."""
+    :func:`combine_rows`), and chunk loops traced with their layer's tail
+    inside (:func:`held_share_ffn`: 0 in a step whose layers hold a share in
+    chunks is an executable that runs the held experts' forward a third time)."""
     with _plan_counts_lock:
         return {key: _plan_counts[key] for key in _PLAN_KEYS}
 
@@ -505,39 +509,81 @@ def held_chunks(tokens: int, top_k: int, held: int, n_routed: int,
     return n
 
 
+def _chunked(a: jnp.ndarray, axis: int, n: int) -> jnp.ndarray:
+    """``a`` with its token axes ``(B, S)`` at ``axis, axis + 1`` → ``[n, ..., T, ...]``:
+    chunk ``i`` holds tokens ``i T .. (i + 1) T - 1`` of the flattened ``B S``."""
+    a = a.reshape(a.shape[:axis] + (n, -1) + a.shape[axis + 2:])
+    return jnp.moveaxis(a, axis, 0)
+
+
 def held_share_ffn(experts: Params, x: jnp.ndarray, gate_idx: jnp.ndarray, gate_w: jnp.ndarray,
                    held: Tuple[int, int], n_routed: int, chunk_rows: int = HELD_CHUNK_ROWS,
-                   precision=None) -> jnp.ndarray:
+                   precision=None, tail: Optional[Callable] = None,
+                   operands: Sequence[Tuple[int, jnp.ndarray]] = ()):
     """The held experts' part of a routed layer: ``x [B, S, C]``, the router's
     ``gate_idx``/``gate_w [B, S, K]`` over all ``n_routed`` → ``[B, S, C]``.
     ``experts`` holds the banks of ``held = (first, count)``; the tokens go
-    through :func:`grouped_ffn` in :func:`held_chunks` chunks."""
+    through :func:`grouped_ffn` in :func:`held_chunks` chunks, each
+    rematerialised, so the step holds one chunk's buffers and not all.
+
+    ``tail(routed_c, *operands_c)`` is the rest of the layer after its experts,
+    which must be token-local (a norm over a token's channels, a mix of a
+    token's own streams): it runs inside the chunk function, under the same
+    ``jax.checkpoint``, on the chunk's routed output ``[T, C]`` and on the
+    chunk's slice of every ``(axis, array)`` of ``operands`` (the array's token
+    axes ``(B, S)`` lie at ``axis, axis + 1`` and arrive flattened to one of
+    ``T``), and its result (an array ``[T, ...]`` or a tuple of them) is the
+    chunk's part of what this function then returns, ``[B, S, ...]``.
+
+    Why it runs here: a reader of the loop's *value* outside it (a norm's or a
+    mix's backward) makes a rematerialised layer run the whole loop forward
+    once more to have that value, and then the loop's own backward recomputes
+    every chunk again: three forward passes of the experts where full remat
+    asks for two. With the tail inside, the loop's value is read only by
+    operations whose backward needs no value (a residual add, a stack), and
+    the extra pass is dead code. One chunk (every expert held, or few tokens)
+    is no loop: the tail is applied to the whole."""
     B, S, C = x.shape
     K, (first, count) = gate_idx.shape[-1], held
     n = held_chunks(B * S, K, count, n_routed, chunk_rows)
     T = B * S // n
 
-    def one(x_c, idx_c, w_c):
-        return grouped_ffn(experts, x_c, idx_c, w_c, count, gm.pick_block_t(T * K, count),
-                           precision=precision, first=first)
+    def one(x_c, idx_c, w_c, *operands_c):
+        routed = grouped_ffn(experts, x_c, idx_c, w_c, count, gm.pick_block_t(T * K, count),
+                             precision=precision, first=first)
+        if tail is None:
+            return routed
+        with jax.named_scope("layer"):  # the layer's operations, not the experts'
+            return tail(routed, *operands_c)
 
     chunks = (x.reshape(n, T, C), gate_idx.reshape(n, T, K),
               gate_w.reshape(n, T, K).astype(x.dtype))
+    with jax.named_scope("layer"):  # as the tail: what moving its operands costs is the layer's
+        chunks += tuple(_chunked(a, axis, n) for axis, a in operands)
     if n == 1:
-        routed = one(*(a[0] for a in chunks))
+        out = jax.tree_util.tree_map(lambda a: a[None], one(*(a[0] for a in chunks)))
     else:  # rematerialised, or the loop keeps every chunk's buffers for the backward
-        _, routed = jax.lax.scan(lambda _, c: (None, jax.checkpoint(one)(*c)), None, chunks)
-    return routed.reshape(B, S, C)
+        if tail is not None:
+            _count_plan("chunk_loop_tail")
+        _, out = jax.lax.scan(lambda _, c: (None, jax.checkpoint(one)(*c)), None, chunks)
+    return jax.tree_util.tree_map(lambda a: a.reshape((B, S) + a.shape[2:]), out)
 
 
 def sigmoid_routed_ffn(p: Params, x: jnp.ndarray, top_k: int, route_scale: float,
                        held: Tuple[int, int], n_routed: int, chunk_rows: int = HELD_CHUNK_ROWS,
-                       precision=None):
+                       precision=None, tail: Optional[Callable] = None,
+                       operands: Sequence[Tuple[int, jnp.ndarray]] = ()):
     """A sigmoid-routed layer as one expert-parallel rank computes it: the
     shared expert every token visits + the held share of the routed experts →
     ``(y, stats)``. ``p`` holds ``router`` (``weight``, the buffer ``bias``),
     ``shared`` and the held ``experts``; the load counts every selection over
-    the router's whole width, and nothing is dropped."""
+    the router's whole width, and nothing is dropped.
+
+    With ``tail`` (and its ``operands``: :func:`held_share_ffn`)
+    the first result is ``tail(y_c, *operands_c)`` of every chunk of tokens,
+    put together: the sum with the shared expert's output and the caller's
+    tail both run inside the chunk loop, so nothing outside it reads its value
+    (the shared expert itself is computed here, once, over all tokens)."""
     from .llama import mlp_block
 
     with jax.named_scope("moe_router"):
@@ -545,11 +591,17 @@ def sigmoid_routed_ffn(p: Params, x: jnp.ndarray, top_k: int, route_scale: float
     with jax.named_scope("ffn"):
         shared = mlp_block(p["shared"], x)
     with jax.named_scope("moe_experts"):
-        routed = held_share_ffn(p["experts"], x, gate_idx, gate_w, held, n_routed, chunk_rows,
-                                precision)
+        if tail is None:
+            out = held_share_ffn(p["experts"], x, gate_idx, gate_w, held, n_routed, chunk_rows,
+                                 precision)
+        else:
+            out = held_share_ffn(
+                p["experts"], x, gate_idx, gate_w, held, n_routed, chunk_rows, precision,
+                tail=lambda routed, shared_c, *rest: tail(shared_c + routed, *rest),
+                operands=((0, shared),) + tuple(operands))
     stats = dict(zero_stats(n_routed), moe_load=jax.lax.stop_gradient(
         jnp.bincount(gate_idx.reshape(-1), length=n_routed).astype(jnp.float32)))
-    return shared + routed, stats
+    return (shared + out if tail is None else out), stats
 
 
 def _usable_ep_mesh(args, num_experts: int):

@@ -279,14 +279,21 @@ def hc_read(p: Params, X: jnp.ndarray, args: XingArgs):
     return u, h_post, m
 
 
-def hc_write(X: jnp.ndarray, y: jnp.ndarray, h_post: jnp.ndarray, h_res: jnp.ndarray):
-    """``X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y``, summed in float32."""
-    n = X.shape[0]
+def hc_write_streams(X, y: jnp.ndarray, h_post: jnp.ndarray, h_res: jnp.ndarray):
+    """``X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y``, summed in float32, as a
+    list of the ``n`` streams, over any token axes (``X`` the streams stacked
+    or one by one ``[..., C]``, ``y [..., C]``, the maps ``[n, ...]`` and
+    ``[n, n, ...]``): a token's streams mix among themselves alone."""
+    n = len(X)
     Xf = [X[j].astype(jnp.float32) for j in range(n)]
     yf = y.astype(jnp.float32)
-    return jnp.stack([
-        (sum(h_res[i, j][..., None] * Xf[j] for j in range(n)) + h_post[i][..., None] * yf
-         ).astype(X.dtype) for i in range(n)])
+    return [(sum(h_res[i, j][..., None] * Xf[j] for j in range(n)) + h_post[i][..., None] * yf
+             ).astype(X[0].dtype) for i in range(n)]
+
+
+def hc_write(X: jnp.ndarray, y: jnp.ndarray, h_post: jnp.ndarray, h_res: jnp.ndarray):
+    """:func:`hc_write_streams`, stacked: ``X [n, B, S, C]`` → ``X' [n, B, S, C]``."""
+    return jnp.stack(hc_write_streams(X, y, h_post, h_res))
 
 
 # -- sub-layers ---------------------------------------------------------------------
@@ -327,15 +334,34 @@ def latent_attention(p: Params, x: jnp.ndarray, args: XingArgs, positions) -> jn
 HELD_CHUNK_ROWS = moe_lib.HELD_CHUNK_ROWS
 
 
-def routed_ffn(p: Params, x: jnp.ndarray, args: XingArgs):
-    """Shared expert + the held share of the routed experts → ``(y, stats)``."""
+def routed_ffn(p: Params, x: jnp.ndarray, args: XingArgs, tail=None, operands=()):
+    """Shared expert + the held share of the routed experts → ``(y, stats)``;
+    with ``tail``, ``y`` is ``tail(y_c, *operands_c)`` of every chunk of tokens
+    (``moe.sigmoid_routed_ffn``)."""
     return moe_lib.sigmoid_routed_ffn(p, x, args.num_experts_per_tok, args.routed_scaling_factor,
                                       args.experts_held, args.n_routed_experts, HELD_CHUNK_ROWS,
-                                      args.matmul_precision)
+                                      args.matmul_precision, tail=tail, operands=operands)
 
 
 def block(p: Params, X: jnp.ndarray, positions, args: XingArgs, routed: bool):
-    """One decoder layer on the streams → ``(X', routing stats | None)``."""
+    """One decoder layer on the streams → ``(X', routing stats | None)``.
+
+    A routed layer's tail, the write of ``shared + routed`` back into the
+    streams, is handed to the expert layer's chunk loop and runs there on a
+    chunk's tokens (``hc_write`` mixes a token's own streams): its gradient to
+    ``h_post`` is a row dot with ``shared + routed``, and outside the loop that
+    read is what made a rematerialised layer run the held experts' forward a
+    third time (``moe.held_share_ffn``). The streams go in and come out one by
+    one (``X[j] [B, S, C]``: tokens lead, so a chunk is a slice; the stacked
+    ``[n, B, S, C]`` would have to be transposed to ``[chunks, n, T, C]``, and
+    XLA makes 235 MB copies of that) and are stacked once after the loop. The
+    MTP module's layer is this one."""
+    n = args.hc_mult
+
+    def ffn_tail(y, *rest):
+        with jax.named_scope("hc_mix"):
+            return tuple(hc_write_streams(rest[:n], y, *rest[n:]))
+
     with jax.named_scope("layer"):
         with jax.named_scope("hc_mix"):
             u, h_post, h_res = hc_read(p["attn_hc"], X, args)
@@ -348,12 +374,15 @@ def block(p: Params, X: jnp.ndarray, positions, args: XingArgs, routed: bool):
         with jax.named_scope("norm"):
             h = rms_norm(u, p["ffn_norm"]["weight"], args.rms_norm_eps)
         if routed:
-            y, stats = routed_ffn(p["feed_forward"], h, args)
-        else:
-            with jax.named_scope("ffn"):
-                y, stats = mlp_block(p["feed_forward"], h), None
+            streams, stats = routed_ffn(
+                p["feed_forward"], h, args, tail=ffn_tail,
+                operands=tuple((0, X[j]) for j in range(n)) + ((1, h_post), (2, h_res)))
+            with jax.named_scope("hc_mix"):
+                return jnp.stack(streams), stats
+        with jax.named_scope("ffn"):
+            y = mlp_block(p["feed_forward"], h)
         with jax.named_scope("hc_mix"):
-            return hc_write(X, y, h_post, h_res), stats
+            return hc_write(X, y, h_post, h_res), None
 
 
 def _cast(tree, dtype):

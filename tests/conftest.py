@@ -56,6 +56,16 @@ def activation_scatters(hlo_text, width):
             if re.search(r"\bscatter\(", ln) and re.search(r"\[[0-9,]*\b%d\]" % width, ln)]
 
 
+def tail_after_loop(sigmoid_routed_ffn):
+    """``moe.sigmoid_routed_ffn`` as a routed layer had it until PR 36: the
+    layer's tail applied after the chunk loop, to the loop's whole value (a
+    tail takes any token axes). What the tail inside the loop is held to."""
+    def after(p, x, *a, tail=None, operands=(), **kw):
+        y, stats = sigmoid_routed_ffn(p, x, *a, **kw)
+        return (y if tail is None else tail(y, *(whole for _, whole in operands))), stats
+    return after
+
+
 def device_env(n, base=None):
     """Child-process env with ``n`` virtual CPU devices.
 

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import tail_after_loop
 from benchmark import run as harness
 from benchmark.flops import afmoe as flops
 from benchmark.flops import flash_attention as flash_flops
@@ -265,11 +266,14 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(tiny):
 
 @pytest.mark.parametrize("crowded", [False, True], ids=["as_routed", "every_choice_held"])
 @pytest.mark.parametrize("chunk_rows", [10 ** 9, 128, 32], ids=["whole", "4_chunks", "16_chunks"])
-def test_a_held_share_drops_nothing(tiny, chunk_rows, crowded):
+def test_a_held_share_drops_nothing(tiny, monkeypatch, chunk_rows, crowded):
     """The held share has no capacity: its buffer has a row for every
     selection, so the layer equals the reference's (which drops nothing) even
     when the router sends every token's every choice to the held experts, at
-    every chunk size this model may name (``held_chunk_rows``)."""
+    every chunk size this model may name (``held_chunk_rows``). And the
+    layer around it, whose post-norm runs inside the chunk loop: the bits of
+    the norm after the loop, every chunk size's output, the reference's output,
+    and the reference's gradient to every weight and to ``x``."""
     cfg, args, params, _ = tiny
     args = dataclasses.replace(args, held_chunk_rows=chunk_rows)
     ff = jax.tree_util.tree_map(jnp.asarray, params["layers"][0]["feed_forward"])
@@ -287,6 +291,24 @@ def test_a_held_share_drops_nothing(tiny, chunk_rows, crowded):
     assert float(stats["moe_dropped"]) == 0
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref.routed_layer(ff, x, cfg, "float32")),
                                atol=3e-6)
+
+    layer = {**jax.tree_util.tree_map(jnp.asarray, params["layers"][0]), "feed_forward": ff}
+    positions = jnp.arange(S, dtype=jnp.int32)
+    block = lambda a: lambda p, x: afmoe.block(p, x, positions, a, True, True)[0]
+    want = lambda p, x: ref._layer(p, x, cfg, "float32", True, SLIDING)
+    out = block(args)(layer, x)
+    np.testing.assert_allclose(   # the experts' own sums differ by 1e-9 with the chunk's rows
+        np.asarray(out), np.asarray(block(dataclasses.replace(args, held_chunk_rows=10 ** 9))(layer, x)),
+        atol=3e-6)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want(layer, x)), atol=1e-5)
+    grad = lambda f: jax.grad(lambda p, x: jnp.sum(jnp.sin(f(p, x))), (0, 1))(layer, x)
+    gaps = _leaf_gaps(grad(block(args)), grad(want))
+    assert len(gaps) == len(jax.tree_util.tree_leaves(layer)) + 1
+    assert max(gaps.values()) < 5e-4, sorted(gaps.items(), key=lambda kv: -kv[1])[:3]
+    compiled = lambda: jax.jit(lambda p, x: block(args)(p, x))(layer, x)   # a fresh trace a call
+    out = compiled()
+    monkeypatch.setattr(moe_lib, "sigmoid_routed_ffn", tail_after_loop(moe_lib.sigmoid_routed_ffn))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(compiled()))
 
 
 def test_selection_bias_moves_the_choice_and_not_the_weights_at_the_published_scale():
@@ -552,6 +574,7 @@ def test_the_cell_rehearses_through_its_traffic_kind(tmp_path, monkeypatch):
     assert plan["window_layers"] >= 2 and plan["global_layers"] >= 1     # the dense layer; the scan
     assert plan["window_simple"] == plan["window_layers"] and "flash_plan" in first
     assert first["moe_plan"]["dispatch_gather"] == first["moe_plan"]["combine_gather"] >= 1
+    assert first["moe_plan"]["chunk_loop_tail"] >= 1      # the scanned stack's loop took the post-norm
     # off the chip the expert layers run the blocked backend: no gmm or tgmm call was traced
     assert not any(first["gmm_plan"].values()) and "gmm_resident" in first["gmm_plan"]
     assert res["end_to_end"]["train_tokens_per_s_per_chip"] > 0 and res["end_to_end"]["setup_s"] > 0

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import activation_scatters
+from conftest import activation_scatters, tail_after_loop
 from mlx_cuda_distributed_pretraining_tpu.models import llama, moe
 from mlx_cuda_distributed_pretraining_tpu.optim import build_optimizer
 from mlx_cuda_distributed_pretraining_tpu.config import SystemConfig, TrainingConfig
@@ -458,12 +458,90 @@ def test_grouped_block_gradient_has_no_scatter_of_activation_rows(monkeypatch):
     grad = jax.jit(jax.grad(lambda p, x: jnp.sum(jnp.sin(moe.moe_block(p, x, args)[0])), (0, 1)))
     hlo = grad.lower(p, x).as_text(dialect="hlo")
     assert {k: n - seen[k] for k, n in moe.plan_counts().items()} == {
-        "dispatch_gather": 1, "combine_gather": 1}
+        "dispatch_gather": 1, "combine_gather": 1, "chunk_loop_tail": 0}   # no chunk loop here
     assert " gather(" in hlo and not activation_scatters(hlo, D)
 
     experts, xs, idx, gate_w, E, bt, first = _dispatch_case("held_share", jnp.float32, D=D)
     old = jax.jit(jax.grad(lambda x: jnp.sum(_scatter_ffn(experts, x, idx, gate_w, E, bt, first))))
     assert len(activation_scatters(old.lower(xs).as_text(dialect="hlo"), D)) >= 2
+
+
+def _tiny_block(arch, monkeypatch):
+    """``(block(p, x) -> x', one routed layer's weights, the layer's input)`` of
+    an architecture whose routed layers hold a share, at its rehearsal widths
+    (2 x 128 tokens, top-2, 2 of 8 experts held: 4 chunks of 128 rows)."""
+    from benchmark import run as harness
+
+    positions = jnp.arange(128, dtype=jnp.int32)
+    if arch == "afmoe":
+        import test_afmoe as t
+        from mlx_cuda_distributed_pretraining_tpu.models import afmoe
+
+        cfg = harness.merge_into(t.FULL, t.TINY["config"])
+        args = t._args(cfg)
+        block = lambda p, x: afmoe.block(p, x, positions, args, True, True)[0]
+        shape = (2, 128, cfg["hidden_size"])
+    else:
+        import test_xing as t
+        from mlx_cuda_distributed_pretraining_tpu.config import Config
+        from mlx_cuda_distributed_pretraining_tpu.models import xing
+
+        cfg = harness.merge_into(t.FULL, t.TINY["config"])
+        section = t.kind.MODEL_SECTIONS["xing_mla_moe"](cfg, {"attention_type": "simple"})
+        args = xing.XingArgs.from_config(
+            Config.from_dict({"name": "t", "model": section}).model, cfg["vocab_size"])
+        monkeypatch.setattr(xing, "HELD_CHUNK_ROWS", 128)
+        block = lambda p, X: xing.block(p, X, positions, args, True)[0]
+        shape = (args.hc_mult, 2, 128, cfg["hidden_size"])
+    layer = jax.tree_util.tree_map(jnp.asarray, t.ref.init_params(7, cfg)["layers"][0])
+    assert moe.held_chunks(2 * 128, args.num_experts_per_tok, args.experts_held[1],
+                           args.n_routed_experts, 128) == 4
+    return block, layer, jax.random.normal(jax.random.PRNGKey(3), shape, jnp.float32)
+
+
+def _equations(jaxpr, keep):
+    """Equations of ``jaxpr`` and of every jaxpr inside it that ``keep`` accepts."""
+    found = [e for e in jaxpr.eqns if keep(e)]
+    for e in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(e.params):
+            found += _equations(sub, keep)
+    return found
+
+
+@pytest.mark.parametrize("arch", ["afmoe", "xing_mla_moe"])
+def test_a_rematerialised_layer_runs_its_held_experts_forward_twice_not_thrice(arch, monkeypatch):
+    """A routed layer that holds a share, under ``jax.checkpoint`` as full
+    remat has it, 4 chunks: with the layer's token-local tail inside the chunk
+    loop nothing outside reads the loop's value, so the gradient holds the
+    loop's backward (which recomputes each chunk) and no forward loop beside
+    it; with the tail outside (the arrangement until PR 36: the tail reads the
+    loop's value) it holds one loop over the chunks more and the three expert
+    matmuls of that pass. Same output, bit for bit, and the same gradients."""
+    monkeypatch.setenv("GMM_BACKEND", "ragged")   # an expert matmul is one ``ragged_dot_general``
+    block, layer, x = _tiny_block(arch, monkeypatch)
+    def arrangement():   # fresh functions: a trace is cached by the function traced
+        fwd = lambda p, x: block(p, x)
+        loss = lambda p, x: jnp.sum(jnp.sin(jax.checkpoint(fwd)(p, x)))
+        seen = moe.plan_counts()
+        jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(layer, x)
+        traced = {k: n - seen[k] for k, n in moe.plan_counts().items()}
+        return jaxpr, traced, jax.jit(fwd)(layer, x), jax.jit(jax.grad(loss, (0, 1)))(layer, x)
+
+    new, traced, got, grads = arrangement()
+    assert traced == {"dispatch_gather": 1, "combine_gather": 1, "chunk_loop_tail": 1}
+    monkeypatch.setattr(moe, "sigmoid_routed_ffn", tail_after_loop(moe.sigmoid_routed_ffn))
+    old, traced, was, grads_were = arrangement()
+    assert traced == {"dispatch_gather": 1, "combine_gather": 1, "chunk_loop_tail": 0}
+
+    matmul = lambda e: e.primitive.name == "ragged_dot_general"
+    chunk_loop = lambda e: e.primitive.name == "scan" and e.params["length"] == 4
+    # three matmuls a pass: the forward, the loop's recomputation, dX, dW; and, with the
+    # tail outside, the rematerialised layer's own pass over the loop
+    assert len(_equations(old.jaxpr, matmul)) == 15 and len(_equations(new.jaxpr, matmul)) == 12
+    assert len(_equations(old.jaxpr, chunk_loop)) == 3 and len(_equations(new.jaxpr, chunk_loop)) == 2
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(was))
+    for a, b in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(grads_were)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-6)
 
 
 def test_gmm_unknown_backend_rejected():

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import activation_scatters
+from conftest import activation_scatters, tail_after_loop
 from benchmark import run as harness
 from benchmark.flops import flash_mla
 from benchmark.flops import xing_mla_moe as flops
@@ -149,7 +149,12 @@ def test_a_held_share_drops_nothing(tiny, monkeypatch, chunk_rows, crowded):
     """The held share has no capacity: its buffer has a row for every
     selection, so the layer equals the reference's (which drops nothing) even
     when the router sends every token's every choice to the held experts, and
-    taking the tokens in chunks changes neither the output nor a gradient."""
+    taking the tokens in chunks changes neither the output nor a gradient. And
+    the layer around it, whose write into the streams runs inside the chunk
+    loop: the bits of the write after the loop, every chunk size's output, the
+    reference's output, and the reference's gradient to every weight and to ``X``; the write alone, as the
+    loop's tail, gives ``X``, ``h_post`` and ``h_res`` the gradients the write
+    after the loop gives them."""
     cfg, args, params, _ = tiny
     ff = jax.tree_util.tree_map(jnp.asarray, params["layers"][0]["feed_forward"])
     first, count = args.experts_held
@@ -168,9 +173,43 @@ def test_a_held_share_drops_nothing(tiny, monkeypatch, chunk_rows, crowded):
                                atol=3e-6)
     loss = lambda ff, x: jnp.sum(jnp.sin(xing.routed_ffn(ff, x, args)[0]))
     grads = jax.grad(loss, (0, 1))(ff, x)
+
+    n, C = args.hc_mult, cfg["hidden_size"]
+    layer = {**jax.tree_util.tree_map(jnp.asarray, params["layers"][0]), "feed_forward": ff}
+    positions = jnp.arange(S, dtype=jnp.int32)
+    X = jax.random.normal(jax.random.PRNGKey(4), (n, B, S, C), jnp.float32)
+    block = lambda p, X: xing.block(p, X, positions, args, True)[0]
+    want = lambda p, X: jnp.moveaxis(
+        ref._layer(p, jnp.moveaxis(X, 0, 2), cfg, "float32", True), 2, 0)
+    grad = lambda f: jax.grad(lambda p, X: jnp.sum(jnp.sin(f(p, X))), (0, 1))(layer, X)
+    out, block_grads = block(layer, X), grad(block)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want(layer, X)), atol=1e-5)
+    gaps = _leaf_gaps(block_grads, grad(want))
+    assert len(gaps) == len(jax.tree_util.tree_leaves(layer)) + 1
+    assert max(gaps.values()) < 5e-4, sorted(gaps.items(), key=lambda kv: -kv[1])[:3]
+    # the write as the loop's tail against the write after the loop (the form until PR 36)
+    h_post = jax.random.uniform(jax.random.PRNGKey(5), (n, B, S), jnp.float32, 0.0, 2.0)
+    h_res = jax.random.uniform(jax.random.PRNGKey(6), (n, n, B, S), jnp.float32) / n
+    inside = lambda ff, x, X, h_post, h_res: jnp.sum(jnp.sin(jnp.stack(xing.routed_ffn(
+        ff, x, args, tail=lambda y, *rest: tuple(xing.hc_write_streams(rest[:n], y, *rest[n:])),
+        operands=tuple((0, X[j]) for j in range(n)) + ((1, h_post), (2, h_res)))[0])))
+    after = lambda ff, x, X, h_post, h_res: jnp.sum(jnp.sin(xing.hc_write(
+        X, xing.routed_ffn(ff, x, args)[0], h_post, h_res)))
+    tail_grads = jax.grad(inside, (0, 1, 2, 3, 4))(ff, x, X, h_post, h_res)
+    compiled = lambda: jax.jit(lambda p, X: block(p, X))(layer, X)   # a fresh trace a call
+    with monkeypatch.context() as m:   # compiled: op by op, XLA contracts the loop's body alone
+        inside_bits = compiled()
+        m.setattr(moe_lib, "sigmoid_routed_ffn", tail_after_loop(moe_lib.sigmoid_routed_ffn))
+        np.testing.assert_array_equal(np.asarray(inside_bits), np.asarray(compiled()))
+
     monkeypatch.setattr(xing, "HELD_CHUNK_ROWS", 10 ** 9)
+    np.testing.assert_allclose(   # the experts' own sums differ by 1e-9 with the chunk's rows
+        np.asarray(out), np.asarray(block(layer, X)), atol=3e-6)
     for a_, b_ in zip(jax.tree_util.tree_leaves(grads),
                       jax.tree_util.tree_leaves(jax.grad(loss, (0, 1))(ff, x))):
+        np.testing.assert_allclose(np.asarray(a_), np.asarray(b_), atol=3e-6)
+    for a_, b_ in zip(jax.tree_util.tree_leaves(tail_grads), jax.tree_util.tree_leaves(
+            jax.grad(after, (0, 1, 2, 3, 4))(ff, x, X, h_post, h_res))):
         np.testing.assert_allclose(np.asarray(a_), np.asarray(b_), atol=3e-6)
 
 
@@ -192,7 +231,7 @@ def test_a_held_shares_gradient_has_no_scatter_of_activation_rows(tiny, monkeypa
     grad = jax.jit(jax.grad(lambda ff, x: jnp.sum(jnp.sin(xing.routed_ffn(ff, x, args)[0])), (0, 1)))
     hlo = grad.lower(ff, x).as_text(dialect="hlo")
     assert {k: n - seen[k] for k, n in moe_lib.plan_counts().items()} == {
-        "dispatch_gather": 1, "combine_gather": 1}
+        "dispatch_gather": 1, "combine_gather": 1, "chunk_loop_tail": 0}   # no tail was handed in
     assert " gather(" in hlo and " scatter(" in hlo         # the load's bincount is one
     assert not activation_scatters(hlo, C)
 
@@ -395,6 +434,7 @@ def test_the_cell_rehearses_through_its_traffic_kind(tmp_path, monkeypatch):
     run_dir, = (os.path.join(tmp_path, "runs", d) for d in os.listdir(os.path.join(tmp_path, "runs")))
     first = next(e for e in kind.base._read_events(run_dir) if e.get("type") == "step_window")
     assert first["moe_plan"]["dispatch_gather"] == first["moe_plan"]["combine_gather"] >= 2
+    assert first["moe_plan"]["chunk_loop_tail"] == 0      # 512 selections are one chunk here: no loop
     assert not {"held_capacity_factor", "held_passes"} & set(FULL)   # no capacity anywhere
     assert res["end_to_end"]["train_tokens_per_s_per_chip"] > 0 and res["end_to_end"]["setup_s"] > 0
     flops_per_token = res["sources"]["flops_per_token"]
