@@ -22,8 +22,11 @@ def device_idle_pct(sources):
 
 
 def iter_device_ms(sources):
-    """Device busy time in the traced window over the engine iterations the
-    program counted in it (its counter also ticks on an idle loop turn)."""
+    """Device busy time in the traced window over the engine iterations that
+    did work in it: the program's ``busy_iterations`` counter, read from
+    ``/metrics`` where the trace starts and stops (``iterations`` also ticks
+    on an idle loop turn, fifty a second, and would flatter a cell below
+    capacity)."""
     tr, it = sources.get("trace"), sources.get("trace_iterations") or {}
     if not tr or "start" not in it or "stop" not in it or it["stop"] <= it["start"]:
         return None

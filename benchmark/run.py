@@ -283,9 +283,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, rehearse: bo
         else:
             line["metrics"] = end_to_end
         line["device"] = device
+        if res.get("checks"):  # a kind that pairs each compared number with its limit
+            line["checks"] = res["checks"]
         line["check_numbers"] = res.get("check_numbers")
         line["end_to_end"] = res["end_to_end"]
         line["backlog"] = res["sources"].get("backlog")
+        line["ttft_ms"] = (res["sources"].get("latency") or {}).get("ttft_ms")
         return line
     finally:
         shutil.rmtree(workdir, ignore_errors=True)
@@ -308,13 +311,19 @@ def main(argv=None) -> int:
     device = None if args.rehearse else check_devices(cell)
     line = run_cell(args.workload, args.seed, seconds, bool(args.trace),
                     rehearse=args.rehearse, device=device, keep_trace=args.keep_trace)
-    for extra in ("check_numbers", "end_to_end", "backlog"):
+    for extra in ("check_numbers", "end_to_end", "backlog", "ttft_ms"):
         line.pop(extra, None)
+    checks = line.pop("checks", None)
     if args.rehearse:
         print(json.dumps({"rehearsal": "passed", "correct_at_tiny_widths": line["correct"],
                           "device": {k: line["device"][k] for k in ("platform", "kind", "count")},
                           "proves": "control flow only; no metric is reported"}), flush=True)
         return 0
+    if checks:  # the numbers compared, each beside its limit: last on both streams
+        line["checks"] = checks
+        for name, c in checks.items():
+            print(f"check {name} = {c['value']!r} limit {c['limit']!r}", file=sys.stderr)
+        sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

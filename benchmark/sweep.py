@@ -2,7 +2,8 @@
 """Find an open-loop cell's knee once: the same cell at a few fixed rates in
 one process, each with its own short window, printing the tails and how many
 requests were in the system when the window opened and closed. The knee is the
-highest rate at which that backlog does not grow over the window; the cell's
+highest rate at which that backlog does not grow over the window (and nearly
+every first token still comes within a stated limit); the cell's
 traffic file then states 0.8 of it as a number. Run when the cell is defined,
 and again by a later benchmark PR once an optimisation has moved the knee.
 
@@ -29,6 +30,8 @@ def main(argv=None) -> int:
     p.add_argument("--rates", required=True, help="comma-separated requests/s")
     p.add_argument("--seconds", type=float, default=30.0)
     p.add_argument("--seed", type=int, default=1)
+    p.add_argument("--ttft-limit-ms", type=float, default=200.0,
+                   help="print the share of requests whose first token came within this")
     p.add_argument("--rehearse", action="store_true")
     args = p.parse_args(argv)
     bench = harness._load(os.path.join(ROOT, "BENCHMARK.json"))
@@ -44,6 +47,8 @@ def main(argv=None) -> int:
             "failed": line["failed"], "correct": line["correct"],
             "ttft_p95_ms": line["end_to_end"]["ttft_p95_ms"],
             "itl_p95_ms": line["end_to_end"]["itl_p95_ms"],
+            f"share_ttft_within_{args.ttft_limit_ms:g}_ms":
+                sum(t <= args.ttft_limit_ms for t in line["ttft_ms"]) / len(line["ttft_ms"]),
             "in_system_at_window_start": b[0], "in_system_at_window_end": b[-1],
             "in_system_highest": max(x for x in b if x is not None) if b[0] is not None else None,
             "setup_s": line["end_to_end"]["setup_s"]}), flush=True)

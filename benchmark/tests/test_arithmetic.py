@@ -131,3 +131,44 @@ def test_reduce_events_busy_idle_and_exposed_collectives():
     assert r["steps"] == 2
     assert r["idle_gaps"][0][1] == pytest.approx(1.0) and "train" in r["idle_gaps"][0][0]
     assert r["device_ops"][0][0] == "fusion"
+
+
+def _reader(name):
+    import importlib.util
+    import sys
+
+    readers = os.path.join(BENCH, "layer_metrics")
+    if readers not in sys.path:
+        sys.path.insert(0, readers)
+    spec = importlib.util.spec_from_file_location("reader_" + name.replace(".", "_"),
+                                                  os.path.join(readers, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+@pytest.mark.parametrize("name", ["iter_device_ms.steady", "iter_device_ms.closed"])
+def test_iteration_time_is_over_the_iterations_counted_between_the_trace_marks(name):
+    """The kinds read ``busy_iterations`` at the marks (``iterations`` also
+    ticks on idle turns): 2 s busy over 50 of them is 40 ms an iteration."""
+    read = _reader(name)
+    src = {"trace": {"busy_s": 2.0, "window_s": 4.0}, "trace_iterations": {"start": 950, "stop": 1000}}
+    assert read(src) == pytest.approx(40.0)
+    assert read(dict(src, trace_iterations={"start": 7, "stop": 7})) is None
+    assert read(dict(src, trace=None)) is None
+
+
+@pytest.mark.parametrize("name", ["generator_lag_p95_ms", "generator_lag_p95_ms.closed",
+                                  "batch_occupancy_pct.closed", "kv_blocks_used_peak_pct.closed"])
+def test_serving_readers_return_nothing_where_there_is_nothing_to_read(name):
+    assert _reader(name)({"window": (0.0, 1.0)}) is None
+
+
+def test_closed_readers_read_what_the_open_ones_read():
+    snaps = [{"t": 0.5, "batch_occupancy": 24, "kv_num_blocks": 100, "kv_blocks_free": 60,
+              "kv_free_watermark": 40}]
+    src = {"window": (0.0, 1.0), "snapshots": snaps, "num_slots": 32,
+           "latency": {"lag_ms": [1.0, 2.0, 3.0]}}
+    assert _reader("batch_occupancy_pct.closed")(src) == pytest.approx(75.0)
+    assert _reader("kv_blocks_used_peak_pct.closed")(src) == pytest.approx(60.0)
+    assert _reader("generator_lag_p95_ms.closed")(src) == 3.0

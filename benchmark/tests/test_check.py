@@ -1,13 +1,17 @@
 """The output check has to be able to fail.
 
-Two kinds of test, each at the tiny widths of ``rehearse.json`` but under the
-cells' own limits. The control: the reference in the next lower precision, put
-in the program's place, comes out NOT correct. The broken run: everything a
-run does after the look for a chip, with the timed path broken underneath (a
-train step that never moves the weights; an engine that alters tokens where it
-emits them), reports ``correct: false``; the same run unbroken reports true.
-The serving kinds are driven directly, because their cells are not declared
-yet: the last test shows the fault of the program that keeps them out.
+Two kinds of test, each at the tiny widths of ``rehearse.json``: the training
+cells under their own limits, the serving cells under the ``rehearse_limits``
+of their kind (float32 on the CPU serves the reference's own tokens). The
+control: the reference in the next lower precision, put in the program's
+place, comes out NOT correct. The broken run: everything a run does after the
+look for a chip, with the timed path broken underneath (a train step that
+never moves the weights; an engine that alters tokens where it emits them),
+reports ``correct: false``; the same run unbroken reports true. The serving
+cells are not declared yet (PERF.md section 7): their entries wait in
+``pending/serving-long.json`` and the tests here append them to the bench
+dict themselves. The last test pins the fault of the program that keeps them
+out, which is why the sound runs before it serve one request at a time.
 """
 
 import json
@@ -20,12 +24,36 @@ import pytest
 from benchmark import control, run as harness
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-with open(os.path.join(BENCH, "..", "BENCHMARK.json")) as _f:
-    CELLS = [w["name"] for w in json.load(_f)["workloads"]]
+BENCHMARK_JSON = os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")
+with open(BENCHMARK_JSON) as _f:
+    DECLARED = json.load(_f)
+with open(os.path.join(BENCH, "pending", "serving-long.json")) as _f:
+    WAITING = json.load(_f)
+CELLS = [w["name"] for w in DECLARED["workloads"]]
 TRAIN_CELLS = [c for c in CELLS if harness._load(os.path.join(
     BENCH, "traffic", harness._load(os.path.join(BENCH, "workloads", c + ".json"))["traffic"]
     + ".json"))["kind"] == "train_job"]
-SERVE_MIXES = ["chat-open-0.8knee", "batch-closed-64"]
+SERVE_CELLS = [w["name"] for w in WAITING["workloads"] if w["name"] not in CELLS]
+CLOSED_CELL = "internlm2-1_8b.serve-closed"
+# one request at a time: see test_concurrent_prefill_changes_served_tokens
+ALONE = {"clients": 1, "rate_per_s": 1.0}
+
+
+@pytest.fixture
+def waiting_declared(monkeypatch):
+    """The harness reads a ``BENCHMARK.json`` that also holds the waiting
+    entries, as it will once they are declared."""
+    load = harness._load
+
+    def merged(path):
+        got = load(path)
+        if os.path.abspath(path) == os.path.abspath(BENCHMARK_JSON):
+            for key in ("configs", "workloads", "end_to_end", "per_layer"):
+                got[key] = got[key] + [e for e in WAITING[key]
+                                       if e["name"] not in {x["name"] for x in got[key]}]
+        return got
+
+    monkeypatch.setattr(harness, "_load", merged)
 
 
 def _cell(name):
@@ -83,60 +111,79 @@ def test_training_run_sound_is_correct_and_frozen_is_not(name):
     assert broken["check_numbers"]["param_change_gap"] > 0.9
 
 
-def _serve_run(mix_name, tmp_path, seed=5, **mix_over):
-    """A serving mix at tiny widths through its traffic kind. The serving
-    cells are not in BENCHMARK.json yet (PERF.md section 7), so the test
-    builds the context itself; the limit is the one a float32 program has to
-    meet at these widths, where CPU arithmetic is exact: no gap at all."""
-    import importlib
-
-    config = harness.merge_into(
-        harness._load(os.path.join(BENCH, "configs", "internlm2-1_8b.json")),
-        harness._load(os.path.join(BENCH, "rehearse.json"))["config"])
-    mix = harness._load(os.path.join(BENCH, "traffic", mix_name + ".json"))
-    mix = harness.merge_into(mix, harness._load(
-        os.path.join(BENCH, "rehearse.json"))["traffic_kinds"][mix["kind"]])
-    mix = harness.merge_into(mix, mix_over)
-    cell = {"name": "internlm2-1_8b." + mix_name, "chips": 1,
-            "limits": {"served_token_gap": 0.0}}
-    ctx = harness.Context(cell, config, mix, seed, 2.0, False, True, str(tmp_path), quiet=True)
-    return importlib.import_module("benchmark.traffic_kinds." + mix["kind"]).run(ctx)
+def _serve_run(name, seed=5, **mix_over):
+    """A waiting serving cell at tiny widths through everything a run does
+    after the look for a chip; ``mix_over`` changes numbers of its mix."""
+    return harness.run_cell(name, seed, 2.0, False, rehearse=True, quiet=True,
+                            mix_overrides=mix_over)
 
 
-@pytest.mark.parametrize("mix_name", SERVE_MIXES)
-def test_serving_run_sound_is_correct_and_altered_tokens_are_not(mix_name, tmp_path, monkeypatch):
+@pytest.mark.parametrize("name", SERVE_CELLS)
+def test_serving_run_sound_is_correct_and_altered_tokens_are_not(name, waiting_declared,
+                                                                 monkeypatch):
     from mlx_cuda_distributed_pretraining_tpu.serve.engine import BatchEngine
 
-    # one request at a time: see test_concurrent_prefill_changes_served_tokens
-    alone = {"clients": 1, "rate_per_s": 1.0}
-    sound = _serve_run(mix_name, tmp_path / "sound", **alone)
+    sound = _serve_run(name, **ALONE)
     assert sound["correct"] and sound["failed"] == 0, sound["check_numbers"]
+    assert sound["checks"]["served_token_gap"] == {"value": 0.0, "limit": 0.0}
     emit = BatchEngine._emit
 
     def altered(self, req, tok, lp):  # every third token replaced where it is produced
         if len(req.tokens) % 3 == 2:
-            tok = (tok + 257) % self.args.vocab_size
+            tok = (int(tok) + 7) % self.args.vocab_size
         return emit(self, req, tok, lp)
 
     monkeypatch.setattr(BatchEngine, "_emit", altered)
-    broken = _serve_run(mix_name, tmp_path / "broken", **alone)
+    broken = _serve_run(name, **ALONE)
     assert not broken["correct"], broken["check_numbers"]
     assert broken["check_numbers"]["served_token_gap"] > 0.01
 
 
-def test_concurrent_prefill_changes_served_tokens(tmp_path):
-    """Why the serving cells wait (PERF.md, Findings of PR 23): with several
-    requests in the engine, a prompt longer than one prefill chunk is served
-    differently from the same prompt alone, because the decode step of the
-    other rows writes a masked row's token 0 into position 0 of the blocks of
-    a row that is still prefilling (``serve/engine.py::_decode_paged`` hands
+@pytest.mark.parametrize("name", SERVE_CELLS)
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_serving_control_comes_out_not_correct(name, seed, waiting_declared):
+    """On the same sampled requests, float8 operands fail one of the cell's
+    numbers: the tokens they put first lie further below the reference's best
+    than the limit allows, or their log-probabilities of the served tokens
+    leave the reference's by more than the program's may."""
+    _, _, config, mix = harness.load_cell(name, rehearse=True)
+    precision = control.control_precision(config, mix["kind"])
+    assert precision == "fp8"
+    line = harness.run_cell(name, seed, 2.0, False, rehearse=True, quiet=True,
+                            control_precision=precision, mix_overrides=ALONE)
+    assert line["correct"], line["check_numbers"]  # the program itself is sound
+    assert not control.serve_control_inside(line["check_numbers"], line["checks"])
+
+
+def test_a_number_with_no_limit_is_never_inside(waiting_declared, monkeypatch):
+    """The waiting cells' records state no limits (they are read on the
+    repaired engine): without the rehearsal's own, a sound run is not correct."""
+    from benchmark.traffic_kinds import serving
+
+    monkeypatch.setattr(serving, "limits_of", lambda ctx: {})
+    run = _serve_run(CLOSED_CELL, **ALONE)
+    assert not run["correct"]
+    assert run["checks"]["served_token_gap"] == {"value": 0.0, "limit": None}
+    assert control.serve_control_inside({"control_gap": 9.0, "control_logprob_gap": [9.0]},
+                                        run["checks"])  # and the control fails nothing
+
+
+def test_concurrent_prefill_changes_served_tokens(waiting_declared):
+    """Why the serving cells wait (PERF.md section 7, first entry): with
+    several requests in the engine, a prompt longer than one prefill chunk is
+    served differently from the same prompt alone, because the decode step of
+    the other rows writes a masked row's token 0 into position 0 of the blocks
+    of a row that is still prefilling (``serve/engine.py::_decode_paged`` hands
     the step every row's block table). The log-probability the program reports
-    for its own tokens then leaves the reference's; alone it does not. When the
-    program is repaired this test fails, and the serving cells can be added."""
+    for its own tokens then leaves the reference's; alone it does not. When
+    the program is repaired this test fails: rename it
+    ``..._does_not_change_...``, hold the crowd to the alone limit, and drop
+    ``ALONE`` from the tests above."""
     sizes = {"check_requests": 8, "num_requests": 64,
              "prompt_tokens": {"median": 120, "sigma": 0.3, "min": 70, "max": 160},
              "output_tokens": {"median": 8, "sigma": 0.5, "min": 2, "max": 16}}
-    alone = _serve_run("batch-closed-64", tmp_path / "a", clients=1, **sizes)
-    crowd = _serve_run("batch-closed-64", tmp_path / "c", clients=6, **sizes)
+    alone = _serve_run(CLOSED_CELL, clients=1, **sizes)
+    crowd = _serve_run(CLOSED_CELL, clients=6, **sizes)
     assert max(alone["check_numbers"]["served_logprob_gap"]) < 6e-5  # 4-decimal rounding
     assert max(crowd["check_numbers"]["served_logprob_gap"]) > 2e-4
+    assert alone["correct"] and not crowd["correct"]  # and the check sees it

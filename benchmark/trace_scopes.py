@@ -222,18 +222,26 @@ def reduce(path: str) -> Dict[str, Any]:
     mean over devices: {"steps", "window_s", "busy_s", "scope_s", "scope_events",
     "recompute_s", "recompute_by_scope_s", "kernels": {name: {"calls",
     "seconds", "dims"}}, "host_s": {event name: seconds inside the steps'
-    window}}. ``steps`` is 0 where the trace has no ``Steps`` line."""
+    window}}. Where the trace has no ``Steps`` line (a serving run: the
+    engine's iterations are host annotations) ``steps`` is 0 and the sums run
+    over everything on ``XLA Ops``: totals of the traced window, for the
+    table ``main`` prints; the readers take nothing from such a trace."""
     planes = read_planes(path)
     out: Dict[str, Any] = {"steps": 0, "window_s": 0.0, "busy_s": 0.0, "scope_s": {},
                            "scope_events": {}, "recompute_s": 0.0,
                            "recompute_by_scope_s": {}, "kernels": {}, "host_s": {}}
     devs = [p for p in planes["devices"] if p["lines"].get(trace_reduce.STEPS_LINE)]
+    clip = trace_reduce.STEPS_LINE
+    if not devs:
+        devs = [p for p in planes["devices"] if p["lines"].get(trace_reduce.OPS_LINE)]
+        clip = trace_reduce.OPS_LINE
     if not devs:
         return out
     n = len(devs)
-    lo = min(s for p in devs for _, s, _ in p["lines"][trace_reduce.STEPS_LINE])
-    hi = max(e for p in devs for _, _, e in p["lines"][trace_reduce.STEPS_LINE])
-    out["steps"] = len(devs[0]["lines"][trace_reduce.STEPS_LINE])
+    lo = min(s for p in devs for _, s, _ in p["lines"][clip])
+    hi = max(e for p in devs for _, _, e in p["lines"][clip])
+    if clip == trace_reduce.STEPS_LINE:
+        out["steps"] = len(devs[0]["lines"][clip])
     out["window_s"] = hi - lo
 
     def add(d: Dict[str, float], k: str, v: float) -> None:
@@ -314,7 +322,8 @@ def main(argv: List[str]) -> int:
     """``python benchmark/trace_scopes.py <file.xplane.pb>``: the table."""
     red = reduce(argv[1])
     n = max(red["steps"], 1)
-    print(f"{red['steps']} whole step(s), busy {red['busy_s']:.6f} s of {red['window_s']:.6f} s")
+    print(f"{red['steps']} whole step(s), busy {red['busy_s']:.6f} s of {red['window_s']:.6f} s"
+          + ("" if red["steps"] else ": no Steps line, so the rows below are totals of the window"))
     for scope, secs in sorted(red["scope_s"].items(), key=lambda kv: -kv[1]):
         print(f"scope {scope:16s} {1e3 * secs / n:10.3f} ms/step {100 * secs / red['busy_s']:6.2f}%  "
               f"{red['scope_events'][scope] / n:8.1f} events/step  "

@@ -2,9 +2,12 @@
 request the instant its last one completes, as offline jobs do. There is no
 rate: the queue is always full, so what is measured is what the system
 completes. Load runs ``ramp_s`` before the window opens (set-up) so the slots
-are full and past their first prefill burst; requests that *complete* inside
-the window are counted, and what is still in flight when it closes is
-abandoned, neither counted nor failed.
+are full and past their first prefill burst. The rate is over all the work of
+the window: every token a client received inside it, of requests that ended
+in it, before it or not at all. Requests that *complete* inside the window are
+the ones counted as attempted and checked; what is still in flight when it
+closes is abandoned, neither counted nor failed. The generator's lag here is
+how long a client's next request left after its last one ended.
 """
 
 from __future__ import annotations
@@ -23,6 +26,7 @@ def _discipline(served: "serving.Served", requests: List[Dict[str, Any]], t0: fl
     trace_at = w1 - float(mix["trace_s"]) if ctx.trace else None
     setup_s, trace_span, iters = None, None, {}
     nxt = 0
+    lag_ms: List[float] = []  # a client's next request after its last one ended
 
     def launch():
         nonlocal nxt
@@ -45,29 +49,36 @@ def _discipline(served: "serving.Served", requests: List[Dict[str, Any]], t0: fl
             ctx.start_trace(ctx.trace_path())
             served.poll(force=True)
             trace_span = [time.perf_counter(), 0.0]
-            iters["start"] = served.snapshots[-1]["iterations"]
+            iters["start"] = served.snapshots[-1]["busy_iterations"]
         client.pump(0.02)
         while seen < len(client.done):
+            ended = client.done[seen]["end"]
             seen += 1
             launch()
+            if ended is not None and ended >= w0:
+                lag_ms.append(1e3 * (time.perf_counter() - ended))
         served.poll()
     if trace_span is not None:
         served.poll(force=True)
-        iters["stop"] = served.snapshots[-1]["iterations"]
+        iters["stop"] = served.snapshots[-1]["busy_iterations"]
         trace_span[1] = time.perf_counter()
         ctx.stop_trace(background=True)
-    client.abandon()
+    in_flight = client.abandon()
     counted = [r for r in client.done if r["end"] is not None and w0 <= r["end"] < w1]
-    tokens = sum(len(r["token_ids"]) for r in counted if not r["error"])
+    tokens = sum(1 for r in client.done + in_flight if not r["error"]
+                 for t in r["token_times"] if w0 <= t < w1)
     rate = tokens / ctx.seconds
-    ctx.say(f"closed loop: {mix['clients']} clients; {len(counted)} requests completed in "
-            f"the window, {tokens} output tokens, {rate:.1f} tokens/s; {nxt} sent in all")
+    ctx.say(f"closed loop: {mix['clients']} clients; {tokens} output tokens received in the "
+            f"window, {rate:.1f} tokens/s; {len(counted)} requests completed in it with "
+            f"{sum(len(r['token_ids']) for r in counted if not r['error'])} tokens; "
+            f"{nxt} sent in all")
     serving.latency_summary(ctx, [r for r in counted if not r["error"]], w1)
     return {
         "counted": counted, "setup_s": setup_s,
         "end_to_end": {"serve_out_tokens_per_s": rate},
         "sources": {"window": (w0, w1), "trace_dir": ctx.trace_path() if trace_span else None,
-                    "trace_span": trace_span, "trace_iterations": iters},
+                    "trace_span": trace_span, "trace_iterations": iters,
+                    "latency": {"lag_ms": lag_ms} if lag_ms else None},
     }
 
 

@@ -36,10 +36,10 @@ def _discipline(served: "serving.Served", requests: List[Dict[str, Any]], t0: fl
             ctx.start_trace(ctx.trace_path())
             served.poll(force=True)
             trace_span = [time.perf_counter(), 0.0]
-            iters["start"] = served.snapshots[-1]["iterations"]
+            iters["start"] = served.snapshots[-1]["busy_iterations"]
         if trace_span is not None and not trace_span[1] and now >= w1:
             served.poll(force=True)
-            iters["stop"] = served.snapshots[-1]["iterations"]
+            iters["stop"] = served.snapshots[-1]["busy_iterations"]
             trace_span[1] = time.perf_counter()
             ctx.stop_trace(background=True)
         while i < len(due) and t0 + due[i] <= now:
