@@ -1017,8 +1017,7 @@ class BatchEngine:
             if tr.enabled:
                 self._open_decode_spans(dec)
             B = pool.num_slots
-            # Masked rows: token 0 at position 0 — their (freed) table rows map
-            # every entry to the shared junk block, so their writes land there.
+            # Rows outside ``dec`` ride as token 0 at position 0 of the junk block.
             tokens = np.zeros((B, S), np.int32)
             pos = np.zeros(B, np.int32)
             temps = np.zeros(B, np.float32)
@@ -1032,13 +1031,14 @@ class BatchEngine:
                 pos[r.slot] = pool.lengths[r.slot]
                 temps[r.slot] = r.temperature
                 keys[r.slot] = r.rng_key
-            bucket = self._attend(
-                int(pos[[r.slot for r in dec]].max()) + S)
+            slots = [r.slot for r in dec]
+            bucket = self._attend(int(pos[slots].max()) + S)
             step = batch_step.paged_decode_step(self.args, k, bucket,
                                                 pool.max_blocks, pool.block_size,
                                                 mesh=self.mesh)
+            tables = pool.tables_for(slots)
         with tr.phase("engine.dispatch"):
-            out = step(self.params, pool.cache, tokens, pos, pool.tables,
+            out = step(self.params, pool.cache, tokens, pos, tables,
                        temps, keys)
         pool.cache = out[0]
         with tr.phase("engine.sample_fetch"):

@@ -174,11 +174,12 @@ class PagedKVPool:  # graftsync: owner=engine-thread
       ``llama.init_paged_cache``. Logical position ``p`` of sequence ``s``
       lives at ``(tables[s][p // block_size], p % block_size)``.
     - physical block 0 is a reserved shared junk block, never allocated:
-      unmapped table entries point at it, and freed/masked rows (which the
-      fixed-shape batched step still writes every iteration) scatter their
-      junk there. This replaces the slotted pool's reserved-last-position
-      trick, so usable length is the full table extent minus the one
-      position needed to write the final emitted token's successor.
+      unmapped table entries point at it, and rows a step is not handed
+      (``tables_for``; the fixed-shape batched step still writes them every
+      iteration) scatter their junk there. This replaces the slotted pool's
+      reserved-last-position trick, so usable length is the full table
+      extent minus the one position needed to write the final emitted
+      token's successor.
     - alloc/free are O(1) list ops on ``_free_blocks``; freeing never zeroes
       data — the validity mask (k_idx <= row position) makes stale entries
       unattendable, exactly as in the slotted pool.
@@ -458,6 +459,19 @@ class PagedKVPool:  # graftsync: owner=engine-thread
         """Longest written length among ``seqs`` — drives the attend bucket
         of the next batched decode step."""
         return max((self.lengths[s] for s in seqs), default=0)
+
+    def tables_for(self, seqs):
+        """Block tables for a batched step in which only ``seqs`` take part:
+        a fresh host array of ``tables``' shape and dtype whose other rows
+        are all zeros, the junk block. A row that holds blocks and sits the
+        step out (still prefilling, between two chunks) is written there and
+        not into its own, possibly shared, first block."""
+        import numpy as np
+
+        rows = list(seqs)
+        out = np.zeros_like(self.tables)
+        out[rows] = self.tables[rows]
+        return out
 
     # -- KV transfer (public API) --------------------------------------------
     # The disaggregated-serving handoff (serve/kv_transfer.py) moves KV

@@ -437,10 +437,27 @@ def test_stall_share_of_windows_of_several_steps():
     assert _reader("step_stall_pct")(traced) == pytest.approx(100.0 * (0.5 + 1.5) / 10.0)
 
 
-def test_every_new_reader_is_declared_for_every_training_cell():
+def _benchmark(with_waiting):
+    """``BENCHMARK.json`` as it stands, or as it will read once the waiting
+    serving entries of ``benchmark/pending/`` are appended to it."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    cells = [w["name"] for w in bench["workloads"]]
+    if with_waiting:
+        with open(os.path.join(ROOT, "benchmark", "pending", "serving-long.json")) as f:
+            waiting = json.load(f)
+        for key in ("configs", "workloads", "end_to_end", "per_layer"):
+            bench[key] = bench[key] + waiting[key]
+        assert waiting["workloads"]  # a file that waits with nothing in it proves nothing
+    return bench
+
+
+@pytest.mark.parametrize("with_waiting", [False, True], ids=["declared", "with_serving_cells"])
+def test_every_new_reader_is_declared_for_every_training_cell(with_waiting):
+    bench = _benchmark(with_waiting)
+    # the cells that report what the readers move, in the benchmark's order
+    (cells,) = [m["workloads"] for m in bench["end_to_end"]
+                if m["name"] == "train_tokens_per_s_per_chip"]
+    assert cells == [w["name"] for w in bench["workloads"] if w["name"] in cells]
     declared = {m["name"]: m for m in bench["per_layer"]}
     for name in EXPECTED:
         m = declared[name]

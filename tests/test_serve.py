@@ -8,6 +8,7 @@ import urllib.error
 import urllib.request
 
 import jax
+import numpy as np
 import pytest
 
 from mlx_cuda_distributed_pretraining_tpu.config import DataConfig
@@ -391,6 +392,122 @@ def test_paged_preemption_recompute_keeps_greedy_output():
         assert out["tokens"] == ref["tokens"]
     assert m["preempted"] >= 1
     assert m["kv_blocks_used"] == 0 and m["kv_blocks_free"] == 4
+
+
+# -- a decode step writes the rows that decode and the junk block --------------
+
+def _ids(seed, n, first=None):
+    """``n`` byte ids behind BOS, none of them 0 (the token a row that sits a
+    step out rides with), a seed's own unless ``first`` gives the leading ones."""
+    rng = np.random.default_rng(seed)
+    ids = [TOK.bos_id] + [int(t) for t in rng.integers(1, 256, n - 1)]
+    if first is not None:
+        ids[:len(first)] = first
+    return ids
+
+
+def _drive(eng, reqs, until=None):
+    """The engine's iterations in the test's own thread (nothing swallows an
+    assertion), until ``until()`` holds or every request of ``reqs`` is done."""
+    for _ in range(2000):
+        if until() if until else all(r.state == "done" for r in reqs):
+            return reqs
+        eng._iteration()
+    raise AssertionError("the engine did not get there in 2000 iterations")
+
+
+def _alone(ids, max_tokens, **kw):
+    eng = _engine(**kw)
+    return _drive(eng, [eng._submit_ids(ids, max_tokens, 0.0, 0)])[0]
+
+
+def _assert_streams_alike(got, want):
+    assert got.error is None and got.finish_reason == want.finish_reason
+    assert got.tokens == want.tokens
+    np.testing.assert_allclose(got.logprobs, want.logprobs, rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("draft_len", [0, 2])
+def test_decode_iteration_leaves_every_other_rows_blocks_bit_for_bit(draft_len):
+    eng = _engine(num_slots=4, block_size=16, spec_draft_len=draft_len)
+    pool, seen, beside_prefill = eng.pool, {}, []
+    grow, decode = eng._grow_or_preempt, eng._decode
+
+    def grow_then_snapshot(dec, S):
+        # the last place a table changes before the step is dispatched
+        dec = grow(dec, S)
+        seen["may_write"] = {0} | {int(b) for r in dec for b in pool.tables[r.slot]}
+        seen["arena"] = jax.tree_util.tree_map(np.array, pool.cache)
+        return dec
+
+    def decode_then_compare(dec):
+        between_chunks = any(r.state == "prefill" and r.prefilled
+                             for r in eng.scheduler.running.values())
+        decode(dec)
+        kept = [b for b in range(pool.num_blocks + 1) if b not in seen["may_write"]]
+        for before, after in zip(jax.tree_util.tree_leaves(seen.pop("arena")),
+                                 jax.tree_util.tree_leaves(pool.cache)):
+            np.testing.assert_array_equal(np.asarray(after)[kept], before[kept])
+        beside_prefill.append(between_chunks)
+
+    eng._grow_or_preempt, eng._decode = grow_then_snapshot, decode_then_compare
+    lengths = [5, 44, 9, 58, 7, 51, 12, 40]
+    reqs = _drive(eng, [eng._submit_ids(_ids(i, n), 12, 0.0, i)
+                        for i, n in enumerate(lengths)])
+    assert all(r.error is None and r.finish_reason in ("length", "stop") for r in reqs)
+    # the case at stake was walked: rows decoded while another sat between two chunks
+    assert sum(beside_prefill) >= 4
+
+
+def test_multi_chunk_prompt_in_a_crowd_streams_what_it_streams_alone():
+    long_ids = _ids(100, 56)  # four chunks of 16
+    want = _alone(long_ids, 16, num_slots=6)
+    eng = _engine(num_slots=6)
+    crowd = [eng._submit_ids(_ids(i, 6 + i), 40, 0.0, i) for i in range(5)]
+    _drive(eng, crowd, until=lambda: all(r.state == "decode" for r in crowd))
+    got = eng._submit_ids(long_ids, 16, 0.0, 0)
+    _drive(eng, [got])
+    assert all(r.state in ("decode", "done") and r.error is None for r in crowd)
+    _assert_streams_alike(got, want)
+
+
+def test_shared_first_block_survives_a_prefill_beside_its_owners_decode():
+    head = _ids(200, 16)  # one whole block of 16, BOS first
+    a_ids, b_ids = _ids(201, 50, first=head), _ids(202, 56, first=head)
+    kw = dict(num_slots=3, block_size=16, prefix_cache=True)
+    want_a, want_b = _alone(a_ids, 24, **kw), _alone(b_ids, 8, **kw)
+    eng = _engine(**kw)
+    a = eng._submit_ids(a_ids, 24, 0.0, 0)
+    _drive(eng, [a], until=lambda: a.state == "decode")
+    shared = int(eng.pool.tables[a.slot][0])
+    before = [np.array(leaf[shared]) for leaf in jax.tree_util.tree_leaves(eng.pool.cache)]
+    b = eng._submit_ids(b_ids, 8, 0.0, 0)
+    _drive(eng, [b], until=lambda: b.state == "prefill" and b.prefilled > 16)
+    # b adopted a's first block and is between two chunks while a decodes
+    assert b.cached_tokens == 16 and int(eng.pool.tables[b.slot][0]) == shared
+    assert a.state == "decode"
+    _drive(eng, [a, b])
+    for was, leaf in zip(before, jax.tree_util.tree_leaves(eng.pool.cache)):
+        np.testing.assert_array_equal(np.asarray(leaf[shared]), was)
+    _assert_streams_alike(a, want_a)
+    _assert_streams_alike(b, want_b)
+
+
+def test_tables_for_hands_out_the_named_rows_and_junk_for_the_rest():
+    pool = PagedKVPool(ARGS, num_seqs=4, max_len=MAX_LEN, block_size=16,
+                       num_blocks=16)
+    a, b, c = (pool.allocate(n) for n in (40, 20, 33))
+    (free,) = set(range(4)) - {a, b, c}
+    own = pool.tables.copy()
+    assert own[b].any()  # b holds blocks, and sits this step out
+    got = pool.tables_for([a, c])
+    assert got.shape == own.shape and got.dtype == own.dtype
+    assert (got[[a, c]] == own[[a, c]]).all() and got[a].any() and got[c].any()
+    assert not got[[b, free]].any()
+    assert not pool.tables_for([]).any()
+    got[:] = 7  # the step's copy, never the pool's own
+    assert (pool.tables == own).all()
+    assert not np.shares_memory(pool.tables_for([a, b, c]), pool.tables)
 
 
 def test_paged_holds_twice_the_sequences_of_worst_case_rows():
