@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import threading
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -87,7 +88,7 @@ def init_moe_params(keys, args, dtype=jnp.float32) -> Params:
 # returns the merged stats through value_and_grad's aux.
 _TAPS: List[list] = []
 
-STAT_KEYS = ("moe_load", "moe_dropped")
+STAT_KEYS = ("moe_load", "moe_dropped", "moe_chunks_whole")
 
 
 @contextlib.contextmanager
@@ -111,9 +112,13 @@ def record_stats(stats: Dict[str, jnp.ndarray]) -> None:
 
 
 def zero_stats(num_experts: int) -> Dict[str, jnp.ndarray]:
+    """One layer's statistics at zero: selections an expert, selections dropped,
+    and chunks of a held share that took the whole dropless buffer because
+    their rows did not fit the small one (:func:`held_share_ffn`)."""
     return {
         "moe_load": jnp.zeros((num_experts,), jnp.float32),
         "moe_dropped": jnp.zeros((), jnp.float32),
+        "moe_chunks_whole": jnp.zeros((), jnp.float32),
     }
 
 
@@ -272,8 +277,10 @@ def _einsum_moe(
 # their updates). Counted while tracing, so this counts traces, not calls of
 # the compiled step: what a jitted program runs is what its one trace counted.
 # ``chunk_loop_tail``: chunk loops of a layer that holds a share traced with the
-# layer's token-local tail inside (:func:`held_share_ffn`).
-_PLAN_KEYS = ("dispatch_gather", "combine_gather", "chunk_loop_tail")
+# layer's token-local tail inside (:func:`held_share_ffn`); ``chunk_two_sizes``:
+# chunk functions traced at two buffer sizes, each of which adds one dispatch
+# and one combine to the tally (a layer's chunk function is traced once a size).
+_PLAN_KEYS = ("dispatch_gather", "combine_gather", "chunk_loop_tail", "chunk_two_sizes")
 _plan_counts: Dict[str, int] = collections.Counter()
 _plan_counts_lock = threading.Lock()
 
@@ -286,9 +293,12 @@ def _count_plan(key: str) -> None:
 def plan_counts() -> Dict[str, int]:
     """Dispatches into an expert buffer and combines out of one traced so far
     in this process, both in the gather form (:func:`dispatch_rows`,
-    :func:`combine_rows`), and chunk loops traced with their layer's tail
+    :func:`combine_rows`), chunk loops traced with their layer's tail
     inside (:func:`held_share_ffn`: 0 in a step whose layers hold a share in
-    chunks is an executable that runs the held experts' forward a third time)."""
+    chunks is an executable that runs the held experts' forward a third time),
+    and chunk functions traced at a small buffer and the whole one (0 in a step
+    whose layers hold less than a quarter of the experts is an executable
+    whose every chunk multiplies a row for every selection)."""
     with _plan_counts_lock:
         return {key: _plan_counts[key] for key in _PLAN_KEYS}
 
@@ -308,11 +318,30 @@ class DispatchPlan(NamedTuple):
         return self.row_sel // self.sel_row.shape[-1]
 
 
+def buffer_rows(selections: int, num_experts: int, block_t: int) -> int:
+    """Rows of the dropless buffer of ``selections`` selections: one for every
+    selection, since any of them may be a held one, and every expert's group
+    rounded up to a full tile."""
+    return gm.round_up(selections + num_experts * (block_t - 1), block_t)
+
+
+def held_rows(gate_idx: jnp.ndarray, num_experts: int, block_t: int, first: int = 0) -> jnp.ndarray:
+    """Rows (traced int32, one number for ``gate_idx [T, K]`` and one a chunk for
+    ``[n, T, K]``) the groups of the experts ``first ..`` take in a buffer, each
+    rounded up to a tile: what :func:`dispatch_plan` fills."""
+    local = gate_idx.reshape(gate_idx.shape[:-2] + (-1,)).astype(jnp.int32) - first
+    counts = jnp.sum(local[..., None] == jnp.arange(num_experts, dtype=jnp.int32), axis=-2,
+                     dtype=jnp.int32)
+    return jnp.sum(gm.round_up(counts, block_t), axis=-1)
+
+
 def dispatch_plan(gate_idx: jnp.ndarray, num_experts: int, block_t: int,
-                  first: int = 0) -> DispatchPlan:
+                  first: int = 0, rows: Optional[int] = None) -> DispatchPlan:
     """Where every selection's row lies in the ``block_t``-aligned buffer of
     the ``num_experts`` experts ``first ..``, and which selection every row
     holds. Token-major within an expert, as a stable sort by expert id gives.
+    The buffer has :func:`buffer_rows` rows, or ``rows`` where the caller has
+    seen that :func:`held_rows` of these selections is no more.
 
     A selection's rank inside its group is a running count over the one-hot of
     its expert id, so its row comes selection-major without inverting the
@@ -321,7 +350,7 @@ def dispatch_plan(gate_idx: jnp.ndarray, num_experts: int, block_t: int,
     its rank. Selections of experts held elsewhere have an all-zero one-hot."""
     T, K = gate_idx.shape
     TK = T * K
-    T_buf = gm.round_up(TK + num_experts * (block_t - 1), block_t)
+    T_buf = buffer_rows(TK, num_experts, block_t) if rows is None else rows
     local = gate_idx.reshape(TK).astype(jnp.int32) - first
     held = (local >= 0) & (local < num_experts)
     onehot = (local[:, None] == jnp.arange(num_experts, dtype=jnp.int32)).astype(jnp.int32)
@@ -425,6 +454,7 @@ def grouped_ffn(
     block_t: int,
     precision=None,
     first: int = 0,
+    rows: Optional[int] = None,
 ) -> jnp.ndarray:
     """Sorted dropless expert FFN over local tokens.
 
@@ -435,14 +465,16 @@ def grouped_ffn(
     gets no row here and adds nothing (the caller's gate weights are
     normalised over all chosen, held or not). :func:`dispatch_plan` places
     the selections, stably sorted by expert id, in a per-expert
-    ``block_t``-aligned buffer (static size: every selection could be a held
-    one, and every expert's group rounds up to a full tile);
-    :func:`dispatch_rows` gathers the tokens' rows into it, the three expert
-    matmuls run as grouped GEMMs, and :func:`combine_rows` gathers the
+    ``block_t``-aligned buffer (static size, :func:`buffer_rows`: every
+    selection could be a held one, and every expert's group rounds up to a
+    full tile; or ``rows``, where the caller has seen that these selections'
+    :func:`held_rows` is no more: the same rows in the same order in a shorter
+    buffer); :func:`dispatch_rows` gathers the tokens' rows into it, the three
+    expert matmuls run as grouped GEMMs, and :func:`combine_rows` gathers the
     gate-weighted rows back and sums them per token. No capacity, no drops,
     and no scatter, forward or backward.
     """
-    plan = dispatch_plan(gate_idx, num_experts, block_t, first)
+    plan = dispatch_plan(gate_idx, num_experts, block_t, first, rows)
     x_buf = dispatch_rows(x_flat, plan)
     T_buf = x_buf.shape[0]
 
@@ -489,12 +521,20 @@ def sigmoid_route(x: jnp.ndarray, router: Params, k: int, route_scale: float
 
 
 # Rows of one expert buffer of a layer that holds a share, where the model names no
-# size of its own. ``grouped_ffn`` is dropless: its buffer has a row for every
+# size of its own. ``grouped_ffn`` is dropless: its whole buffer has a row for every
 # selection, since any of them may be a held one, and a share of the experts fills few
 # of them. So such a layer takes its tokens in chunks whose selections fit this many
 # rows, one after another, and the step holds the buffers of one (xing4_0's benchmark
 # cell compiles to 14.36, 14.41 and 14.73 GiB at 2,048, 4,096 and 8,192).
 HELD_CHUNK_ROWS = 4096
+
+# A chunk of a held share takes a buffer for this many times the selections a balanced
+# router sends the share (``top_k * held / n_routed`` a token), and the whole dropless
+# buffer when a chunk's rows do not fit that. A multiple of the balanced load and of
+# nothing measured: it has to lie so far above what a router sends that the whole
+# buffer is for inputs a job does not produce, or the step's time follows the router
+# in steps of a buffer.
+SMALL_BUFFER_LOADS = 4
 
 
 def held_chunks(tokens: int, top_k: int, held: int, n_routed: int,
@@ -509,6 +549,17 @@ def held_chunks(tokens: int, top_k: int, held: int, n_routed: int,
     return n
 
 
+def chunk_buffer_rows(selections: int, held: int, n_routed: int, block_t: int) -> Tuple[int, int]:
+    """``(small, whole)``: rows of the two buffers of a chunk of ``selections``
+    selections in a layer that holds ``held`` of ``n_routed`` experts. The
+    small one has a row for ``SMALL_BUFFER_LOADS`` times the selections a
+    balanced router sends and the whole one's tile padding; the two are one
+    size where the layer holds that share of the experts or more."""
+    loads = -(-selections * min(n_routed, SMALL_BUFFER_LOADS * held) // n_routed)
+    return (gm.round_up(loads + held * (block_t - 1), block_t),
+            buffer_rows(selections, held, block_t))
+
+
 def _chunked(a: jnp.ndarray, axis: int, n: int) -> jnp.ndarray:
     """``a`` with its token axes ``(B, S)`` at ``axis, axis + 1`` → ``[n, ..., T, ...]``:
     chunk ``i`` holds tokens ``i T .. (i + 1) T - 1`` of the flattened ``B S``."""
@@ -516,20 +567,100 @@ def _chunked(a: jnp.ndarray, axis: int, n: int) -> jnp.ndarray:
     return jnp.moveaxis(a, axis, 0)
 
 
+def _chunks_at_either_size(one: Callable, sizes: Tuple[int, int], fit: Callable,
+                           chunks: Sequence[jnp.ndarray]):
+    """``one(rows, x_c, idx_c, w_c, *operands_c)`` of every chunk of ``chunks =
+    (x, idx, w, *operands)``, one after another, stacked, at ``rows =
+    sizes[0]`` where the traced ``fit(idx)`` holds and at ``sizes[1]`` where
+    not → ``(results, chunks that took sizes[1])``. Each chunk is
+    rematerialised: the backward has the chunks' inputs and nothing else.
+
+    Why not ``jax.checkpoint`` of a ``lax.cond``: differentiating a ``cond``
+    gives every branch the residuals of all branches, zero-filled, so the
+    small branch would write the whole buffer's residuals as zeros and the
+    step would hold both sets. Here no residual crosses a ``cond``: the
+    forward is one ``cond`` over two loops, and the backward one ``cond`` over
+    two loops whose every trip recomputes its chunk (under ``jax.checkpoint``,
+    so a profile names it ``rematted_computation`` as before), transposes it
+    and adds what ``one`` closes over (the banks, a tail's weights) its share
+    of the gradient in place. The choice is one a loop and not one a chunk:
+    chosen inside the loop, the banks' three gradients leave a ``cond`` as
+    fresh arrays in every trip (xing4_0's benchmark cell compiled to 14.756
+    GiB so, for 14.520 with one buffer size). ``one`` is traced once a size,
+    with what it closes over hoisted, since a ``custom_vjp`` differentiates
+    its arguments only."""
+    (small, consts), (whole, same) = (
+        jax.closure_convert(functools.partial(one, rows), *(a[0] for a in chunks))
+        for rows in sizes)
+    if len(consts) != len(same) or any(a is not b for a, b in zip(consts, same)):
+        raise RuntimeError("a chunk function closes over other arrays at another buffer size")
+    n = chunks[0].shape[0]
+
+    def forward(f):
+        def run(idx, consts, x, w, *operands):
+            return jax.lax.scan(lambda _, c: (None, f(*c, *consts)), None, (x, idx, w, *operands))[1]
+        return run
+
+    def backward(f):
+        def trip(consts, acc, c):
+            ct_c, idx_c, *inputs_c = c
+
+            def again(consts, x_c, w_c, *operands_c):
+                return f(x_c, idx_c, w_c, *operands_c, *consts)
+            # no barrier against merging the recomputation with a forward: this trip holds
+            # none, and the barrier cost the cells' steps 0.015 and 0.063 GiB
+            d_consts, *d_inputs_c = jax.vjp(jax.checkpoint(again, prevent_cse=False),
+                                            consts, *inputs_c)[1](ct_c)
+            return [a + d for a, d in zip(acc, d_consts)], d_inputs_c
+
+        def run(idx, consts, inputs, cts):   # the last chunk first, as a scan's transpose has it
+            return jax.lax.scan(functools.partial(trip, consts), [jnp.zeros_like(c) for c in consts],
+                                (cts, idx, *inputs), reverse=True)
+        return run
+
+    @jax.custom_vjp
+    def loop(idx, consts, x, w, *operands):
+        fits = fit(idx)
+        return (jax.lax.cond(fits, forward(small), forward(whole), idx, consts, x, w, *operands),
+                n * (1.0 - fits.astype(jnp.float32)))
+
+    def loop_forward(idx, *inputs):
+        return loop(idx, *inputs), (idx, inputs)
+
+    def loop_backward(saved, cts):
+        idx, (consts, *inputs) = saved
+        d_consts, d_inputs = jax.lax.cond(fit(idx), backward(small), backward(whole),
+                                          idx, consts, inputs, cts[0])
+        return (None, d_consts, *d_inputs)
+
+    loop.defvjp(loop_forward, loop_backward)
+    x, idx, w, *operands = chunks
+    return loop(idx, consts, x, w, *operands)
+
+
 def held_share_ffn(experts: Params, x: jnp.ndarray, gate_idx: jnp.ndarray, gate_w: jnp.ndarray,
                    held: Tuple[int, int], n_routed: int, chunk_rows: int = HELD_CHUNK_ROWS,
                    precision=None, tail: Optional[Callable] = None,
                    operands: Sequence[Tuple[int, jnp.ndarray]] = ()):
     """The held experts' part of a routed layer: ``x [B, S, C]``, the router's
-    ``gate_idx``/``gate_w [B, S, K]`` over all ``n_routed`` → ``[B, S, C]``.
-    ``experts`` holds the banks of ``held = (first, count)``; the tokens go
-    through :func:`grouped_ffn` in :func:`held_chunks` chunks, each
-    rematerialised, so the step holds one chunk's buffers and not all.
+    ``gate_idx``/``gate_w [B, S, K]`` over all ``n_routed`` → ``([B, S, C],
+    chunks that took the whole buffer)``. ``experts`` holds the banks of
+    ``held = (first, count)``; the tokens go through :func:`grouped_ffn` in
+    :func:`held_chunks` chunks, each rematerialised, so the step holds one
+    chunk's buffers and not all.
+
+    A chunk's buffer is the small one of :func:`chunk_buffer_rows` where every
+    chunk's :func:`held_rows` fit it and the whole dropless one where some
+    chunk's do not (:func:`_chunks_at_either_size`): the same rows in the same
+    order either way, nothing dropped, and the second result (float32) counts
+    the chunks that took the whole one. A layer that holds ``n_routed /
+    SMALL_BUFFER_LOADS`` experts or more has one size, traces no choice and
+    counts 0.
 
     ``tail(routed_c, *operands_c)`` is the rest of the layer after its experts,
     which must be token-local (a norm over a token's channels, a mix of a
-    token's own streams): it runs inside the chunk function, under the same
-    ``jax.checkpoint``, on the chunk's routed output ``[T, C]`` and on the
+    token's own streams): it runs inside the chunk function, rematerialised
+    with it, on the chunk's routed output ``[T, C]`` and on the
     chunk's slice of every ``(axis, array)`` of ``operands`` (the array's token
     axes ``(B, S)`` lie at ``axis, axis + 1`` and arrive flattened to one of
     ``T``), and its result (an array ``[T, ...]`` or a tuple of them) is the
@@ -547,10 +678,12 @@ def held_share_ffn(experts: Params, x: jnp.ndarray, gate_idx: jnp.ndarray, gate_
     K, (first, count) = gate_idx.shape[-1], held
     n = held_chunks(B * S, K, count, n_routed, chunk_rows)
     T = B * S // n
+    block_t = gm.pick_block_t(T * K, count)
+    small, whole = chunk_buffer_rows(T * K, count, n_routed, block_t)
 
-    def one(x_c, idx_c, w_c, *operands_c):
-        routed = grouped_ffn(experts, x_c, idx_c, w_c, count, gm.pick_block_t(T * K, count),
-                             precision=precision, first=first)
+    def one(rows, x_c, idx_c, w_c, *operands_c):
+        routed = grouped_ffn(experts, x_c, idx_c, w_c, count, block_t,
+                             precision=precision, first=first, rows=rows)
         if tail is None:
             return routed
         with jax.named_scope("layer"):  # the layer's operations, not the experts'
@@ -560,13 +693,21 @@ def held_share_ffn(experts: Params, x: jnp.ndarray, gate_idx: jnp.ndarray, gate_
               gate_w.reshape(n, T, K).astype(x.dtype))
     with jax.named_scope("layer"):  # as the tail: what moving its operands costs is the layer's
         chunks += tuple(_chunked(a, axis, n) for axis, a in operands)
-    if n == 1:
-        out = jax.tree_util.tree_map(lambda a: a[None], one(*(a[0] for a in chunks)))
-    else:  # rematerialised, or the loop keeps every chunk's buffers for the backward
-        if tail is not None:
-            _count_plan("chunk_loop_tail")
-        _, out = jax.lax.scan(lambda _, c: (None, jax.checkpoint(one)(*c)), None, chunks)
-    return jax.tree_util.tree_map(lambda a: a.reshape((B, S) + a.shape[2:]), out)
+    if tail is not None and n > 1:
+        _count_plan("chunk_loop_tail")
+    if small < whole:
+        _count_plan("chunk_two_sizes")
+        out, took_whole = _chunks_at_either_size(
+            one, (small, whole),
+            lambda idx: jnp.all(held_rows(idx, count, block_t, first) <= small), chunks)
+    else:
+        took_whole = jnp.zeros((), jnp.float32)
+        at_whole = functools.partial(one, whole)
+        if n == 1:
+            out = jax.tree_util.tree_map(lambda a: a[None], at_whole(*(a[0] for a in chunks)))
+        else:  # rematerialised, or the loop keeps every chunk's buffers for the backward
+            _, out = jax.lax.scan(lambda _, c: (None, jax.checkpoint(at_whole)(*c)), None, chunks)
+    return jax.tree_util.tree_map(lambda a: a.reshape((B, S) + a.shape[2:]), out), took_whole
 
 
 def sigmoid_routed_ffn(p: Params, x: jnp.ndarray, top_k: int, route_scale: float,
@@ -592,14 +733,14 @@ def sigmoid_routed_ffn(p: Params, x: jnp.ndarray, top_k: int, route_scale: float
         shared = mlp_block(p["shared"], x)
     with jax.named_scope("moe_experts"):
         if tail is None:
-            out = held_share_ffn(p["experts"], x, gate_idx, gate_w, held, n_routed, chunk_rows,
-                                 precision)
+            out, took_whole = held_share_ffn(p["experts"], x, gate_idx, gate_w, held, n_routed,
+                                             chunk_rows, precision)
         else:
-            out = held_share_ffn(
+            out, took_whole = held_share_ffn(
                 p["experts"], x, gate_idx, gate_w, held, n_routed, chunk_rows, precision,
                 tail=lambda routed, shared_c, *rest: tail(shared_c + routed, *rest),
                 operands=((0, shared),) + tuple(operands))
-    stats = dict(zero_stats(n_routed), moe_load=jax.lax.stop_gradient(
+    stats = dict(zero_stats(n_routed), moe_chunks_whole=took_whole, moe_load=jax.lax.stop_gradient(
         jnp.bincount(gate_idx.reshape(-1), length=n_routed).astype(jnp.float32)))
     return (shared + out if tail is None else out), stats
 
@@ -708,7 +849,7 @@ def _grouped_moe_ep(
         real2 = rid_s < e_loc
         rid_c = jnp.minimum(rid_s, e_loc - 1)
         rank2 = jnp.arange(R, dtype=jnp.int32) - raw_off[rid_c].astype(jnp.int32)
-        T_buf = gm.round_up(R + e_loc * (block_t - 1), block_t)
+        T_buf = buffer_rows(R, e_loc, block_t)
         dest2 = jnp.where(real2, (p_off[rid_c] + rank2).astype(jnp.int32), T_buf)
 
         x_buf = jnp.zeros((T_buf, D), rx.dtype).at[dest2].set(rx[order2])
@@ -804,9 +945,9 @@ def moe_block(p: Params, x: jnp.ndarray, args) -> Tuple[jnp.ndarray, jnp.ndarray
                 dropped = jnp.zeros((), jnp.float32)
 
     if stats_tap_active():
-        record_stats({
-            "moe_load": jax.lax.stop_gradient(
+        record_stats(dict(
+            zero_stats(E),
+            moe_load=jax.lax.stop_gradient(
                 jnp.bincount(gate_idx.reshape(-1), length=E).astype(jnp.float32)),
-            "moe_dropped": jax.lax.stop_gradient(dropped.astype(jnp.float32)),
-        })
+            moe_dropped=jax.lax.stop_gradient(dropped.astype(jnp.float32))))
     return out, aux

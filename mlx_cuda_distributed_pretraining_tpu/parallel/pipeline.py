@@ -588,7 +588,7 @@ def make_pipeline_loss(
         bspec = P()  # batch enters replicated w.r.t. pp (auto axes may shard)
         out_like = [0.0, 0.0, 0.0]
         if with_moe_stats:
-            out_like.append({"moe_load": 0.0, "moe_dropped": 0.0})
+            out_like.append(dict.fromkeys(zero_moe_stats(), 0.0))
         if with_slab_count:
             out_like.append(0)
         sm = jax.shard_map(
@@ -668,8 +668,7 @@ def make_pipeline_train_step(
             "nonfinite": jnp.logical_not(jnp.isfinite(loss)).astype(jnp.int32),
         }
         if moe_stats:
-            metrics["moe_load"] = stats["moe_load"]
-            metrics["moe_dropped"] = stats["moe_dropped"]
+            metrics.update(stats)  # models/moe.py::STAT_KEYS, as train_step.py has them
         if log_grad_norm:
             # grads are the global stacked tree; global_norm is exact under
             # GSPMD (XLA inserts the cross-shard reductions).

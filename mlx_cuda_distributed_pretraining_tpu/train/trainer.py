@@ -1297,7 +1297,8 @@ class Trainer:
                 window_steps += 1
                 if self.moe_stats_experts and "moe_load" in metrics:
                     # Device arrays, no sync: summed/read at the log line.
-                    window_moe.append((metrics["moe_load"], metrics["moe_dropped"]))
+                    window_moe.append((metrics["moe_load"], metrics["moe_dropped"],
+                                       metrics["moe_chunks_whole"]))
                 if step % log_int == 0 or step == self.total_steps:
                     with self.tracer.phase("train.loss_sync", step=step) as ph:
                         loss = float(metrics["loss"])  # device sync point
@@ -1383,6 +1384,8 @@ class Trainer:
                                 # it, and how unevenly.
                                 mine = load[held[0]:held[0] + held[1]]
                                 line["moe_rows_held"] = int(mine.sum())
+                                # chunks whose rows did not fit the small buffer
+                                line["moe_chunks_whole"] = int(sum(float(m[2]) for m in window_moe))
                                 line["moe_load_max_over_mean"] = float(
                                     mine.max() / max(mine.mean(), 1e-9))
                             self._g_moe_entropy.set(ent)
@@ -1429,8 +1432,8 @@ class Trainer:
                             if self.pipeline:
                                 ev["bubble"] = round(self._bubble_frac, 6)
                             ev.update({k: line[k] for k in (
-                                "moe_rows_held", "moe_load_max_over_mean", "moe_drop",
-                                "main_loss", "mtp_loss") if k in line})
+                                "moe_rows_held", "moe_chunks_whole", "moe_load_max_over_mean",
+                                "moe_drop", "main_loss", "mtp_loss") if k in line})
                             seen = hoststats.window_totals()
                             ev.update(hoststats.window_fields(self._host_seen, seen))
                             self._host_seen = seen

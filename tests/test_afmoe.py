@@ -535,11 +535,14 @@ def test_cells_one_and_two_import_nothing_of_the_new_modules():
         resolve_architecture("no_such_model")
 
 
-def test_the_cell_rehearses_through_its_traffic_kind(tmp_path, monkeypatch):
+@pytest.mark.parametrize("held_count", [2, 1], ids=["a_quarter_held", "an_eighth_held"])
+def test_the_cell_rehearses_through_its_traffic_kind(held_count, tmp_path, monkeypatch):
     """``run.py --rehearse`` looks a kind up in rehearse.json, which is closed;
     this is the new cell's rehearsal: a Context at tiny widths, the kind's own
     ``run``: Trainer.train() on architecture afmoe from a dict config, the
-    window, the events' counters, the reference's three steps, the comparison."""
+    window, the events' counters, the reference's three steps, the comparison.
+    With an eighth of the experts held, as in the cell, the chunk loops run at
+    a small buffer or the whole one (tests/test_xing.py)."""
     # the window counts steps, not this machine's seconds (tests/test_xing.py has the reason)
     ticks = itertools.count()
     monkeypatch.setattr(kind.arch.base, "time", types.SimpleNamespace(
@@ -554,8 +557,12 @@ def test_the_cell_rehearses_through_its_traffic_kind(tmp_path, monkeypatch):
     assert mix["documents"] == {"median": 2400, "sigma": 1.2, "min": 16, "max": 16384,
                                 "zipf_exponent": 1.1}
     config = harness.merge_into(config, TINY["config"])
+    config["experts_held"] = dict(config["experts_held"], count=held_count)
     mix = harness.merge_into(mix, TINY["traffic"])
     cell = dict(cell, limits={k: 0.05 for k in cell["limits"]})
+    if held_count == 1:   # one expert's three banks of 32 columns: their step-1 profile reads
+        # 0.0709 in bfloat16 beside float32, to the last digit what one buffer size read
+        cell["limits"]["first_grad_profile_gap"] = 0.1
     ctx = harness.Context(cell, config, mix, seed=3_000_000_019, seconds=1.5, trace=False,
                           rehearse=True, workdir=str(tmp_path), quiet=True)
     res = kind.run(ctx)
@@ -564,9 +571,12 @@ def test_the_cell_rehearses_through_its_traffic_kind(tmp_path, monkeypatch):
     assert len(res["check_numbers"]) == 3 + 3           # one term a step, three steps
     assert max(v for k, v in res["check_numbers"].items() if k.startswith("loss_gap")) < 1e-3
     events = res["sources"]["step_window_events"]
-    assert events and all({"moe_rows_held", "moe_load_max_over_mean", "moe_drop"} <= set(e)
-                          for e in events)
+    assert events and all({"moe_rows_held", "moe_chunks_whole", "moe_load_max_over_mean",
+                           "moe_drop"} <= set(e) for e in events)
     assert all(e["moe_drop"] == 0 and e["moe_rows_held"] > 0 for e in events)
+    # 4 chunks a layer, 3 routed layers: a step counts the chunks of the layers in which some
+    # chunk's held rows did not fit its small buffer, and with one buffer size none
+    assert all(e["moe_chunks_whole"] in ((0, 4, 8, 12) if held_count == 1 else (0,)) for e in events)
     # the run's first window says what was traced: both kinds of layer, and no kernel here
     run_dir, = (os.path.join(tmp_path, "runs", d) for d in os.listdir(os.path.join(tmp_path, "runs")))
     first = next(e for e in train_job._read_events(run_dir) if e.get("type") == "step_window")
@@ -575,6 +585,7 @@ def test_the_cell_rehearses_through_its_traffic_kind(tmp_path, monkeypatch):
     assert plan["window_simple"] == plan["window_layers"] and "flash_plan" in first
     assert first["moe_plan"]["dispatch_gather"] == first["moe_plan"]["combine_gather"] >= 1
     assert first["moe_plan"]["chunk_loop_tail"] >= 1      # the scanned stack's loop took the post-norm
+    assert first["moe_plan"]["chunk_two_sizes"] == (held_count == 1)   # 2 of 8 held: one buffer size
     # off the chip the expert layers run the blocked backend: no gmm or tgmm call was traced
     assert not any(first["gmm_plan"].values()) and "gmm_resident" in first["gmm_plan"]
     assert res["end_to_end"]["train_tokens_per_s_per_chip"] > 0 and res["end_to_end"]["setup_s"] > 0

@@ -458,7 +458,8 @@ def test_grouped_block_gradient_has_no_scatter_of_activation_rows(monkeypatch):
     grad = jax.jit(jax.grad(lambda p, x: jnp.sum(jnp.sin(moe.moe_block(p, x, args)[0])), (0, 1)))
     hlo = grad.lower(p, x).as_text(dialect="hlo")
     assert {k: n - seen[k] for k, n in moe.plan_counts().items()} == {
-        "dispatch_gather": 1, "combine_gather": 1, "chunk_loop_tail": 0}   # no chunk loop here
+        "dispatch_gather": 1, "combine_gather": 1, "chunk_loop_tail": 0,   # no chunk loop here,
+        "chunk_two_sizes": 0}                                              # and one buffer size
     assert " gather(" in hlo and not activation_scatters(hlo, D)
 
     experts, xs, idx, gate_w, E, bt, first = _dispatch_case("held_share", jnp.float32, D=D)
@@ -466,18 +467,21 @@ def test_grouped_block_gradient_has_no_scatter_of_activation_rows(monkeypatch):
     assert len(activation_scatters(old.lower(xs).as_text(dialect="hlo"), D)) >= 2
 
 
-def _tiny_block(arch, monkeypatch):
-    """``(block(p, x) -> x', one routed layer's weights, the layer's input)`` of
-    an architecture whose routed layers hold a share, at its rehearsal widths
-    (2 x 128 tokens, top-2, 2 of 8 experts held: 4 chunks of 128 rows)."""
+def _tiny_block(arch, monkeypatch, held_count=2):
+    """``(block(p, x) -> x', one routed layer's weights, the layer's input, the
+    model's args)`` of an architecture whose routed layers hold a share, at its
+    rehearsal widths (2 x 128 tokens, top-2, 2 of 8 experts held: 4 chunks of
+    128 rows at one buffer size; ``held_count`` 1 holds an eighth, and a chunk
+    then has a small buffer of 128 rows and the whole one of 192)."""
     from benchmark import run as harness
 
     positions = jnp.arange(128, dtype=jnp.int32)
+    held = {"experts_held": {"first": 2, "count": held_count}}
     if arch == "afmoe":
         import test_afmoe as t
         from mlx_cuda_distributed_pretraining_tpu.models import afmoe
 
-        cfg = harness.merge_into(t.FULL, t.TINY["config"])
+        cfg = dict(harness.merge_into(t.FULL, t.TINY["config"]), **held)
         args = t._args(cfg)
         block = lambda p, x: afmoe.block(p, x, positions, args, True, True)[0]
         shape = (2, 128, cfg["hidden_size"])
@@ -486,7 +490,7 @@ def _tiny_block(arch, monkeypatch):
         from mlx_cuda_distributed_pretraining_tpu.config import Config
         from mlx_cuda_distributed_pretraining_tpu.models import xing
 
-        cfg = harness.merge_into(t.FULL, t.TINY["config"])
+        cfg = dict(harness.merge_into(t.FULL, t.TINY["config"]), **held)
         section = t.kind.MODEL_SECTIONS["xing_mla_moe"](cfg, {"attention_type": "simple"})
         args = xing.XingArgs.from_config(
             Config.from_dict({"name": "t", "model": section}).model, cfg["vocab_size"])
@@ -494,54 +498,194 @@ def _tiny_block(arch, monkeypatch):
         block = lambda p, X: xing.block(p, X, positions, args, True)[0]
         shape = (args.hc_mult, 2, 128, cfg["hidden_size"])
     layer = jax.tree_util.tree_map(jnp.asarray, t.ref.init_params(7, cfg)["layers"][0])
-    assert moe.held_chunks(2 * 128, args.num_experts_per_tok, args.experts_held[1],
-                           args.n_routed_experts, 128) == 4
-    return block, layer, jax.random.normal(jax.random.PRNGKey(3), shape, jnp.float32)
+    assert args.experts_held == (2, held_count) and args.n_routed_experts == 8
+    assert moe.held_chunks(2 * 128, args.num_experts_per_tok, held_count, 8, 128) == 4
+    return block, layer, jax.random.normal(jax.random.PRNGKey(3), shape, jnp.float32), args
 
 
-def _equations(jaxpr, keep):
-    """Equations of ``jaxpr`` and of every jaxpr inside it that ``keep`` accepts."""
+def _equations(jaxpr, keep, path=None):
+    """Equations that ``keep`` accepts of ``jaxpr`` and of every jaxpr inside
+    it; with ``path``, of a ``cond`` only that branch (what one pass executes)."""
     found = [e for e in jaxpr.eqns if keep(e)]
     for e in jaxpr.eqns:
-        for sub in jax.core.jaxprs_in_params(e.params):
-            found += _equations(sub, keep)
+        inside = jax.core.jaxprs_in_params(e.params)
+        if path is not None and e.primitive.name == "cond":
+            inside = [e.params["branches"][path].jaxpr]
+        for sub in inside:
+            found += _equations(sub, keep, path)
     return found
 
 
+def _live(closed):
+    """``closed.jaxpr`` without the equations no output reads: what XLA's own
+    dead-code elimination leaves of it."""
+    from jax.interpreters import partial_eval as pe
+
+    return pe.dce_jaxpr(closed.jaxpr, [True] * len(closed.jaxpr.outvars))[0]
+
+
+@pytest.mark.parametrize("held_count", [2, 1])
 @pytest.mark.parametrize("arch", ["afmoe", "xing_mla_moe"])
-def test_a_rematerialised_layer_runs_its_held_experts_forward_twice_not_thrice(arch, monkeypatch):
+def test_a_rematerialised_layer_runs_its_held_experts_forward_twice_not_thrice(
+        arch, held_count, monkeypatch):
     """A routed layer that holds a share, under ``jax.checkpoint`` as full
     remat has it, 4 chunks: with the layer's token-local tail inside the chunk
     loop nothing outside reads the loop's value, so the gradient holds the
     loop's backward (which recomputes each chunk) and no forward loop beside
     it; with the tail outside (the arrangement until PR 36: the tail reads the
     loop's value) it holds one loop over the chunks more and the three expert
-    matmuls of that pass. Same output, bit for bit, and the same gradients."""
+    matmuls of that pass. Same output, bit for bit, and the same gradients.
+
+    A quarter of the experts held (2 of 8) is one buffer size and no ``cond``;
+    an eighth is two sizes, each loop the branch of a ``cond``, and the counts
+    hold along either path: the small buffer's branch of every ``cond`` (1) or
+    the whole one's (0)."""
     monkeypatch.setenv("GMM_BACKEND", "ragged")   # an expert matmul is one ``ragged_dot_general``
-    block, layer, x = _tiny_block(arch, monkeypatch)
+    block, layer, x, _ = _tiny_block(arch, monkeypatch, held_count)
+    two_sizes = int(held_count == 1)
     def arrangement():   # fresh functions: a trace is cached by the function traced
         fwd = lambda p, x: block(p, x)
         loss = lambda p, x: jnp.sum(jnp.sin(jax.checkpoint(fwd)(p, x)))
         seen = moe.plan_counts()
-        jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(layer, x)
+        jaxpr = _live(jax.make_jaxpr(jax.grad(loss, (0, 1)))(layer, x))
         traced = {k: n - seen[k] for k, n in moe.plan_counts().items()}
         return jaxpr, traced, jax.jit(fwd)(layer, x), jax.jit(jax.grad(loss, (0, 1)))(layer, x)
 
+    # a chunk function is traced once a size, whatever differentiates it afterwards
+    once = {"dispatch_gather": 1 + two_sizes, "combine_gather": 1 + two_sizes,
+            "chunk_two_sizes": two_sizes}
     new, traced, got, grads = arrangement()
-    assert traced == {"dispatch_gather": 1, "combine_gather": 1, "chunk_loop_tail": 1}
+    assert traced == dict(once, chunk_loop_tail=1)
     monkeypatch.setattr(moe, "sigmoid_routed_ffn", tail_after_loop(moe.sigmoid_routed_ffn))
     old, traced, was, grads_were = arrangement()
-    assert traced == {"dispatch_gather": 1, "combine_gather": 1, "chunk_loop_tail": 0}
+    assert traced == dict(once, chunk_loop_tail=0)
 
     matmul = lambda e: e.primitive.name == "ragged_dot_general"
     chunk_loop = lambda e: e.primitive.name == "scan" and e.params["length"] == 4
-    # three matmuls a pass: the forward, the loop's recomputation, dX, dW; and, with the
-    # tail outside, the rematerialised layer's own pass over the loop
-    assert len(_equations(old.jaxpr, matmul)) == 15 and len(_equations(new.jaxpr, matmul)) == 12
-    assert len(_equations(old.jaxpr, chunk_loop)) == 3 and len(_equations(new.jaxpr, chunk_loop)) == 2
+    choice = lambda e: e.primitive.name == "cond"
+    for path in ((1, 0) if two_sizes else (None,)):
+        # three matmuls a pass: the forward, the loop's recomputation, dX, dW; and, with the
+        # tail outside, the rematerialised layer's own pass over the loop
+        assert len(_equations(old, matmul, path)) == 15 and len(_equations(new, matmul, path)) == 12
+        assert len(_equations(old, chunk_loop, path)) == 3 and len(_equations(new, chunk_loop, path)) == 2
+    # the forward's choice and the backward's, and the extra pass's where the tail is outside
+    assert len(_equations(new, choice)) == 2 * two_sizes and len(_equations(old, choice)) == 3 * two_sizes
     np.testing.assert_array_equal(np.asarray(got), np.asarray(was))
     for a, b in zip(jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(grads_were)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-6)
+
+
+def _held_share_case(arch, ids, n_chunks, monkeypatch):
+    """``(experts, x, idx, gate_w, held, n_routed, chunk_rows)`` at ``arch``'s tiny
+    widths with an eighth of the experts held (1 of 8): ``ids`` ``fit`` draws
+    every token's top-2 over the router's whole width, so a chunk's held rows
+    fit its small buffer; ``all_held`` sends every selection to the held
+    expert, so no chunk's do."""
+    _, layer, _, args = _tiny_block(arch, monkeypatch, held_count=1)
+    first, n_routed, K = args.experts_held[0], args.n_routed_experts, args.num_experts_per_tok
+    rng = np.random.default_rng(5)
+    B, S, C = 2, 512, args.hidden_size   # 2,048 selections: a small buffer short of them
+    idx = np.stack([rng.permutation(n_routed)[:K] for _ in range(B * S)]).astype(np.int32)
+    if ids == "all_held":
+        idx = np.full_like(idx, first)
+    x = jnp.asarray(rng.normal(size=(B, S, C)), jnp.float32)
+    gate_w = jnp.asarray(rng.uniform(0.1, 1.0, size=(B, S, K)), jnp.float32)
+    return (layer["feed_forward"]["experts"], x, jnp.asarray(idx.reshape(B, S, K)), gate_w,
+            args.experts_held, n_routed, B * S * K // n_chunks)
+
+
+@pytest.mark.parametrize("n_chunks", [1, 4])
+@pytest.mark.parametrize("with_tail", [False, True], ids=["no_tail", "tail"])
+@pytest.mark.parametrize("ids", ["fit", "all_held"])
+@pytest.mark.parametrize("arch", ["afmoe", "xing_mla_moe"])
+def test_a_chunk_at_either_buffer_size_is_grouped_ffn_at_the_whole_buffer(
+        arch, ids, with_tail, n_chunks, monkeypatch):
+    """A layer that holds an eighth of the experts takes a chunk through the
+    small buffer where the chunk's held rows fit it and through the whole
+    dropless one where they do not, and counts the chunks that took the whole
+    one: 0 of ``n_chunks`` and all of them here. On either path the output and
+    the gradients (banks, tokens, gate weights; with a tail its operand and the
+    weight it closes over) are ``grouped_ffn``'s at a row for every selection
+    with the tail applied afterwards, in float32 to 1e-6 of a leaf's largest
+    value (a grouped matmul blocks by its buffer's size)."""
+    from mlx_cuda_distributed_pretraining_tpu.ops import grouped_matmul as gm
+
+    experts, x, idx, gate_w, held, n_routed, chunk_rows = _held_share_case(
+        arch, ids, n_chunks, monkeypatch)
+    B, S, C = x.shape
+    K, (first, count) = idx.shape[-1], held
+    assert moe.held_chunks(B * S, K, count, n_routed, chunk_rows) == n_chunks
+    block_t = gm.pick_block_t(chunk_rows, count)
+    small, whole = moe.chunk_buffer_rows(chunk_rows, count, n_routed, block_t)
+    assert small < whole
+    for chunk in np.asarray(idx).reshape(n_chunks, -1):
+        rows = sum(gm.round_up(int((chunk == first + e).sum()), block_t) for e in range(count))
+        assert rows <= small if ids == "fit" else small < rows <= whole
+    rng = np.random.default_rng(6)
+    gain = jnp.asarray(rng.uniform(0.5, 1.5, size=(C,)), jnp.float32)
+    res = jnp.asarray(rng.normal(size=(B, S, C)), jnp.float32)
+    mix = lambda routed, gain, res: jnp.tanh(routed * gain) + res   # token-local
+
+    def chunked(experts, x, gate_w, gain, res):
+        tail = dict(tail=lambda routed, res_c: mix(routed, gain, res_c),
+                    operands=((0, res),)) if with_tail else {}
+        return moe.held_share_ffn(experts, x, idx, gate_w, held, n_routed, chunk_rows, **tail)
+
+    def at_whole_buffer(experts, x, gate_w, gain, res):
+        out = moe.grouped_ffn(experts, x.reshape(B * S, C), idx.reshape(B * S, K),
+                              gate_w.reshape(B * S, K), count,
+                              gm.pick_block_t(B * S * K, count), first=first).reshape(B, S, C)
+        return (mix(out, gain, res) if with_tail else out), None
+
+    def run(ffn):
+        def loss(*leaves):
+            out, took_whole = ffn(*leaves)
+            return jnp.sum(jnp.sin(out)), (out, took_whole)
+        (_, (out, took_whole)), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(experts, x, gate_w, gain, res)
+        return took_whole, [out] + jax.tree_util.tree_leaves(grads)
+
+    took_whole, got = run(chunked)
+    _, want = run(at_whole_buffer)
+    assert float(took_whole) == (0 if ids == "fit" else n_chunks)
+    assert len(got) == 1 + 3 + 4
+    for name, a, b in zip(("out", "w_down", "w_gate", "w_up", "x", "gate_w", "gain", "res"),
+                          got, want):
+        if not with_tail and name in ("gain", "res"):
+            assert not np.asarray(a).any(), name
+            continue
+        a, b = np.asarray(a), np.asarray(b)
+        scale = np.abs(b).max()
+        assert a.shape == b.shape and scale > 0, name
+        np.testing.assert_allclose(a, b, atol=1e-6 * scale, rtol=1e-6, err_msg=name)
+
+
+@pytest.mark.parametrize("held", [(2, 2), (0, 8)], ids=["a_quarter_held", "every_expert_held"])
+def test_a_layer_that_holds_a_quarter_of_the_experts_or_more_traces_no_choice(held, monkeypatch):
+    """``SMALL_BUFFER_LOADS`` times the balanced share is every selection where
+    a layer holds ``n_routed / SMALL_BUFFER_LOADS`` experts or more: one buffer
+    size, so neither the layer nor its gradient holds a ``cond``, nothing is
+    tallied as traced at two sizes, and the count of whole-buffer chunks is a
+    constant 0."""
+    from mlx_cuda_distributed_pretraining_tpu.ops import grouped_matmul as gm
+
+    experts, x, idx, gate_w, _, n_routed, chunk_rows = _held_share_case(
+        "afmoe", "fit", 4, monkeypatch)
+    first, count = held
+    assert moe.SMALL_BUFFER_LOADS * count >= n_routed
+    small, whole = moe.chunk_buffer_rows(chunk_rows, count, n_routed, gm.pick_block_t(chunk_rows, count))
+    assert small == whole == moe.buffer_rows(chunk_rows, count, gm.pick_block_t(chunk_rows, count))
+    experts = jax.tree_util.tree_map(lambda a: jnp.concatenate([a] * count), experts)
+
+    def ffn(experts, x):
+        return moe.held_share_ffn(experts, x, idx, gate_w, held, n_routed, chunk_rows)
+    seen = moe.plan_counts()
+    jaxprs = [jax.make_jaxpr(ffn)(experts, x),
+              jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(ffn(*a)[0]), (0, 1)))(experts, x)]
+    assert moe.plan_counts()["chunk_two_sizes"] == seen["chunk_two_sizes"]
+    for jaxpr in jaxprs:
+        assert not _equations(jaxpr.jaxpr, lambda e: e.primitive.name == "cond")
+    assert float(jax.jit(ffn)(experts, x)[1]) == 0.0
 
 
 def test_gmm_unknown_backend_rejected():

@@ -485,7 +485,8 @@ def test_trainer_reports_the_flash_plan(tmp_path):
     # and how many expert layers it dispatches and combines by gathers
     # (models/moe.py): a dense model has none
     moe_plan = windows[0]["moe_plan"]
-    assert moe_plan == {"dispatch_gather": 0, "combine_gather": 0, "chunk_loop_tail": 0}
+    assert moe_plan == {"dispatch_gather": 0, "combine_gather": 0, "chunk_loop_tail": 0,
+                        "chunk_two_sizes": 0}
     assert "moe_plan" not in windows[1]
     # nor a grouped matmul kernel (ops/grouped_matmul.py)
     gmm_plan = windows[0]["gmm_plan"]

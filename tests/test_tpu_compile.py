@@ -238,9 +238,25 @@ def test_tgmm_compiles_for_v5e(v5e, compiled_kernels):
 
 
 # The benchmark's two expert-parallel cells, one chunk's dispatch: rows of the
-# buffer, held experts, and an expert's [K, N] (PERF.md section 4).
+# buffer (the whole dropless one, and the small one a chunk takes while its rows
+# fit: ``moe.chunk_buffer_rows``), held experts, and an expert's [K, N] (PERF.md
+# section 4).
 GMM_CELLS = {"xing4_0-29b-a4b-ep8": (5120, 8, 3584, 1024),
-             "trinity-mini-ep8": (67584, 16, 2048, 1024)}
+             "trinity-mini-ep8": (67584, 16, 2048, 1024),
+             "xing4_0-29b-a4b-ep8-small": (3072, 8, 3584, 1024),
+             "trinity-mini-ep8-small": (34816, 16, 2048, 1024)}
+
+
+def test_the_cells_buffer_rows_are_the_programs():
+    """``GMM_CELLS``' rows are what ``models/moe.py`` gives a chunk of each cell
+    (selections of a chunk, held of routed experts: PERF.md section 4)."""
+    from mlx_cuda_distributed_pretraining_tpu.models import moe
+
+    for cell, selections, held, routed in (("xing4_0-29b-a4b-ep8", 4096, 8, 64),
+                                           ("trinity-mini-ep8", 65536, 16, 128)):
+        block_t = gm.pick_block_t(selections, held)
+        assert moe.chunk_buffer_rows(selections, held, routed, block_t) == (
+            GMM_CELLS[cell + "-small"][0], GMM_CELLS[cell][0])
 
 
 @pytest.mark.parametrize("orientation", ["up", "down"])

@@ -231,7 +231,8 @@ def test_a_held_shares_gradient_has_no_scatter_of_activation_rows(tiny, monkeypa
     grad = jax.jit(jax.grad(lambda ff, x: jnp.sum(jnp.sin(xing.routed_ffn(ff, x, args)[0])), (0, 1)))
     hlo = grad.lower(ff, x).as_text(dialect="hlo")
     assert {k: n - seen[k] for k, n in moe_lib.plan_counts().items()} == {
-        "dispatch_gather": 1, "combine_gather": 1, "chunk_loop_tail": 0}   # no tail was handed in
+        "dispatch_gather": 1, "combine_gather": 1, "chunk_loop_tail": 0,   # no tail was handed in,
+        "chunk_two_sizes": 0}                               # and a quarter held is one buffer size
     assert " gather(" in hlo and " scatter(" in hlo         # the load's bincount is one
     assert not activation_scatters(hlo, C)
 
@@ -354,7 +355,8 @@ def test_new_readers_find_nothing_in_a_trace_without_their_scopes():
         values = {}
         for name in ("step_device_ms.moe", "step_device_ms.residual_mix", "step_device_ms.mtp",
                      "kernel_peak_pct.mla_flash_fwd", "kernel_peak_pct.mla_flash_bwd",
-                     "kernel_peak_pct.gmm", "moe_rows_held_per_step"):
+                     "kernel_peak_pct.gmm", "moe_rows_held_per_step",
+                     "moe_whole_buffer_chunks_per_step"):
             spec = importlib.util.spec_from_file_location("m_" + name.replace(".", "_"),
                                                           os.path.join(readers, name + ".py"))
             mod = importlib.util.module_from_spec(spec)
@@ -370,6 +372,23 @@ def test_new_readers_find_nothing_in_a_trace_without_their_scopes():
     finally:
         sys.path.remove(readers)
         shutil.rmtree(work, ignore_errors=True)
+
+
+def test_the_whole_buffer_reader_reads_the_programs_counter():
+    """``moe_whole_buffer_chunks_per_step``: the events' ``moe_chunks_whole`` over
+    their steps; 0.0 (not nothing) where every chunk fit its small buffer."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location("m_whole", os.path.join(
+        REPO, "benchmark", "layer_metrics", "moe_whole_buffer_chunks_per_step.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    window = lambda *counts: {"step_window_events": [
+        {"type": "step_window", "step": i, "steps": 2, "moe_rows_held": 9, "moe_chunks_whole": c}
+        for i, c in enumerate(counts)]}
+    assert mod.read(window(0, 0, 0)) == 0.0
+    assert mod.read(window(0, 8, 4)) == 2.0      # 12 chunk-layers over 6 steps
+    assert mod.read(window()) is None and mod.read({}) is None
 
 
 def test_cell_one_imports_none_of_the_new_modules():
@@ -391,12 +410,15 @@ def test_cell_one_imports_none_of_the_new_modules():
         resolve_architecture("no_such_model")
 
 
-def test_the_cell_rehearses_through_its_traffic_kind(tmp_path, monkeypatch):
+@pytest.mark.parametrize("held_count", [2, 1], ids=["a_quarter_held", "an_eighth_held"])
+def test_the_cell_rehearses_through_its_traffic_kind(held_count, tmp_path, monkeypatch):
     """``run.py --rehearse`` looks a kind up in rehearse.json, which is closed;
     this is the new cell's rehearsal: a Context at tiny widths, the kind's own
     ``run``: Trainer.train() on architecture xing_mla_moe from a dict config,
     the window, the events' counters, the reference's three steps, the
-    comparison."""
+    comparison. With an eighth of the experts held, as in the cell (2 of 8 is
+    a quarter), the expert layers run at a small buffer or the whole one, by
+    what the router sent, and the reference knows of neither."""
     # The window counts steps here, not this machine's seconds: the recorder's clock ticks
     # once a reading, three readings a step, so its 1.5 s hold 62 whole steps whatever else the
     # machine runs. At these widths the loss falls by 0.07 in 60 steps and varies by 0.015 from
@@ -411,6 +433,7 @@ def test_the_cell_rehearses_through_its_traffic_kind(tmp_path, monkeypatch):
     assert {k: v for k, v in mix.items() if k not in ("kind", "batch_size")} == \
         {k: v for k, v in base_mix.items() if k not in ("kind", "batch_size")}
     config = harness.merge_into(config, TINY["config"])
+    config["experts_held"] = dict(config["experts_held"], count=held_count)
     mix = harness.merge_into(mix, TINY["traffic"])
     # At these widths the median leaf is small, and the first mixing map of a stack reads
     # replicated streams: two of its three gains have a gradient that is rounding noise, which
@@ -426,15 +449,21 @@ def test_the_cell_rehearses_through_its_traffic_kind(tmp_path, monkeypatch):
     assert max(v for k, v in res["check_numbers"].items() if k.startswith("loss_gap")) < 1e-3
     events = res["sources"]["step_window_events"]
     assert events and all(
-        {"moe_rows_held", "moe_load_max_over_mean", "main_loss", "mtp_loss", "moe_drop"} <= set(e)
-        for e in events)
+        {"moe_rows_held", "moe_chunks_whole", "moe_load_max_over_mean", "main_loss", "mtp_loss",
+         "moe_drop"} <= set(e) for e in events)
     assert all(e["moe_drop"] == 0 and e["moe_rows_held"] > 0 for e in events)
+    # one chunk a layer here: a step counts the layers (the stack's and the module's) whose
+    # held rows did not fit half the selections, and with one buffer size none
+    assert all(0 <= e["moe_chunks_whole"] <= (held_count == 1) * (config["num_hidden_layers"] - 1 + 1)
+               for e in events)
     # the run's first window says the step traced its expert layers (the scanned stack's and
     # the module's) in the gather form: what tells it from an old executable out of a cache
     run_dir, = (os.path.join(tmp_path, "runs", d) for d in os.listdir(os.path.join(tmp_path, "runs")))
     first = next(e for e in kind.base._read_events(run_dir) if e.get("type") == "step_window")
     assert first["moe_plan"]["dispatch_gather"] == first["moe_plan"]["combine_gather"] >= 2
     assert first["moe_plan"]["chunk_loop_tail"] == 0      # 512 selections are one chunk here: no loop
+    # 2 of 8 held is one buffer size; 1 of 8 two, in the scanned stack and in the module
+    assert first["moe_plan"]["chunk_two_sizes"] == (2 if held_count == 1 else 0)
     assert not {"held_capacity_factor", "held_passes"} & set(FULL)   # no capacity anywhere
     assert res["end_to_end"]["train_tokens_per_s_per_chip"] > 0 and res["end_to_end"]["setup_s"] > 0
     flops_per_token = res["sources"]["flops_per_token"]
