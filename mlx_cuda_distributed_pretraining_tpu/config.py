@@ -68,6 +68,9 @@ class ModelConfig:
     mla: Dict[str, Any] = field(default_factory=dict)
     hyper_connections: Dict[str, Any] = field(default_factory=dict)
     mtp: Dict[str, Any] = field(default_factory=dict)
+    # Only architecture "sambay" reads it (models/sambay.py): the Mamba
+    # layers' d_state, d_conv, expand, dt_rank.
+    ssm: Dict[str, Any] = field(default_factory=dict)
     # Named rematerialization policy: "none" | "dots" | "full" |
     # "save_attn" (models/llama.py REMAT_POLICIES — save_attn keeps the
     # checkpoint_name-tagged attention activations and replays only the

@@ -52,6 +52,20 @@ _RULES = [
     (r"shared\.w_down\.weight$", ("tp", "fsdp")),
     (r"_hc\.phi\.weight$", ("fsdp", None)),                   # [n*D, n+n+n*n] mixing map
     (r"eh_proj\.weight$", ("fsdp", None)),                    # [2D, D] MTP joining projection
+    # models/sambay.py. The fused q, k, v projection of differential attention is
+    # column-parallel like wq; a Mamba mixer's projections split over fsdp alone
+    # (its channels stay whole: the scan's kernels are not partitioned), its
+    # per-channel leaves and the attention's lambda vectors are replicated; a gated
+    # memory unit's two matrices are a column- and a row-parallel pair.
+    (r"attention\.wqkv\.weight$", ("fsdp", "tp")),            # [D, (H + 2G)*Dh]
+    (r"attention\.lambda_[qk][12]$", (None,)),                # [Dh]
+    (r"attention\.subln\.weight$", (None,)),                  # [2 Dh] the difference norm's gain
+    (r"ssm\.(in|x|dt)_proj\.weight$", ("fsdp", None)),        # [D, 2Di], [Di, R+2N], [R, Di]
+    (r"ssm\.out_proj\.weight$", (None, "fsdp")),              # [Di, D]
+    (r"ssm\.conv\.weight$", (None, None)),                    # [Di, K] depthwise taps
+    (r"ssm\.(A_log|D)$", (None, None)),                        # [Di, N], [Di]
+    (r"gmu\.w1\.weight$", ("fsdp", "tp")),                    # [D, Di] column
+    (r"gmu\.w2\.weight$", ("tp", "fsdp")),                    # [Di, D] row
     (r"feed_forward\.w_(gate|up)\.weight(_q4?)?$", ("fsdp", "tp")),  # [D, I] column
     (r"feed_forward\.w_(gate|up)\.weight_s$", ("tp",)),              # [I]
     (r"feed_forward\.w_down\.weight(_q4?)?$", ("tp", "fsdp")),       # [I, D] row

@@ -292,13 +292,16 @@ def test_named_scopes_in_the_program_are_the_vocabulary():
     # each flash kernel twice: its resident and its streamed path share the
     # name the trace's reader keys on (plan_counts() tells them apart)
     assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dkv", "flash_bwd_dq",
-                               "flash_bwd_dq", "flash_fwd", "flash_fwd", "gmm", "tgmm"]
+                               "flash_bwd_dq", "flash_fwd", "flash_fwd", "gmm",
+                               "ssm_scan_bwd", "ssm_scan_fwd", "tgmm"]
     assert pallas_calls == len(kernels), "a pallas_call without a name="
-    # architecture xing_mla_moe opens two more and afmoe three, which the
-    # benchmark reads by their own helpers (layer_metrics/_named_scopes.py,
-    # _attn_kinds.py) until its closed vocabulary takes them in
+    # architecture xing_mla_moe opens two more, afmoe three, and sambay five
+    # with its two scan kernels, which the benchmark reads by their own helpers
+    # (layer_metrics/_named_scopes.py, _attn_kinds.py, _ssm_scan.py) until its
+    # closed vocabulary takes them in
     assert found | set(kernels) == VOCABULARY | {"hc_mix", "mtp"} | {
-        "attn_window", "attn_global", "attn_gate"}
+        "attn_window", "attn_global", "attn_gate"} | {
+        "ssm", "ssm_proj", "ssm_conv", "gmu", "attn_diff", "ssm_scan_fwd", "ssm_scan_bwd"}
 
 
 # -- the host's turns ---------------------------------------------------------------

@@ -435,3 +435,55 @@ def test_two_kinds_of_attention_core_compile_for_v5e_at_the_cells_length(v5e, co
         assert sorted(next(t for t in reversed(re.split(r"[/()]", c)) if t.startswith("flash_"))
                       for c in mine) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"], (kind, calls)
     assert len(calls) == 6 and " conditional(" in hlo
+
+
+def test_selective_scan_kernels_compile_for_v5e_at_the_cells_width(v5e, monkeypatch):
+    """``phi4-mini-flash-l6.train-seq16k``'s scan (ops/selective_scan.py): one row
+    of 16,384 steps, 5,120 channels of 16 states, forward and backward: Mosaic
+    takes both kernels (SMEM blocks of a chunk's ``B_t``, ``C_t``, the backward's
+    ``T + 1`` rebuilt states in VMEM under the raised limit), and nothing as large
+    as the ``[S, d_inner, N]`` states (5.4 GB) is left in the program."""
+    from mlx_cuda_distributed_pretraining_tpu.ops import selective_scan as ss
+
+    monkeypatch.setattr(ss, "_interpret", lambda: False)
+    S, Di, N = 16384, 5120, 16
+    ops = (_sds((1, S, Di), jnp.float32, v5e), _sds((1, S, Di), jnp.float32, v5e),
+           _sds((Di, N), jnp.float32, v5e), _sds((1, S, N), jnp.float32, v5e),
+           _sds((1, S, N), jnp.float32, v5e), _sds((Di,), jnp.float32, v5e))
+    before = ss.plan_counts()
+    grad = jax.grad(lambda *a: ss.selective_scan(*a, backend="kernel").sum(), argnums=tuple(range(6)))
+    compiled = jax.jit(grad).lower(*ops).compile()
+    traced = {k: n - before.get(k, 0) for k, n in ss.plan_counts().items() if n - before.get(k, 0)}
+    assert traced == {"fwd_kernel": 1, "bwd_kernel": 1, "fwd_kernel_chunk128": 1, "bwd_kernel_chunk128": 1}
+    hlo = compiled.as_text()
+    calls = [line for line in hlo.split("\n") if "tpu_custom_call" in line]
+    assert sum("ssm_scan_fwd" in c for c in calls) == 1 and sum("ssm_scan_bwd" in c for c in calls) == 1
+    # operands, results, saved chunk states and layout copies: a few arrays of [S, Di], not N of them
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * S * Di * 4
+
+
+def test_differential_attention_cores_compile_for_v5e_at_the_cells_length(v5e, compiled_kernels):
+    """The same cell's attention (models/sambay.py): 40 + 40 stacked query heads of
+    64 over 20 stacked key heads and values of 128, one row of 16,384, under the
+    window of 512 and causally: heads of 64 have never been the flash kernels'
+    at this length, and all six calls take the resident path."""
+    from mlx_cuda_distributed_pretraining_tpu.models import sambay
+
+    args = sambay.SambaYArgs(hidden_size=2560, num_heads=40, num_kv_heads=20, head_dim=64,
+                             sliding_window=512, attention_type="flash")
+    S = 16384
+    q = _sds((1, S, 40, 64), jnp.bfloat16, v5e)
+    k_st, vbar = _sds((1, S, 20, 64), jnp.bfloat16, v5e), _sds((1, S, 10, 128), jnp.bfloat16, v5e)
+
+    def loss(q, k_st, vbar, kind):
+        a1, a2 = sambay.diff_attention_core(q, k_st, vbar, args, kind)
+        return (a1.astype(jnp.float32) - 0.5 * a2.astype(jnp.float32)).sum()
+
+    before = sambay.attn_plan_counts()
+    for kind in ("S", "F"):
+        hlo = jax.jit(jax.grad(lambda q, k, v, kind=kind: loss(q, k, v, kind), argnums=(0, 1, 2))).lower(
+            q, k_st, vbar).compile().as_text()
+        assert sum("tpu_custom_call" in line for line in hlo.split("\n")) == 3
+    traced = {k: n - before.get(k, 0) for k, n in sambay.attn_plan_counts().items() if n - before.get(k, 0)}
+    assert traced == {f"{kind}_{what}": 1 for kind in ("window", "global")
+                      for what in ("layers", "fwd_resident", "bwd_dq_resident", "bwd_dkv_resident")}
