@@ -113,8 +113,9 @@ def record_stats(stats: Dict[str, jnp.ndarray]) -> None:
 
 def zero_stats(num_experts: int) -> Dict[str, jnp.ndarray]:
     """One layer's statistics at zero: selections an expert, selections dropped,
-    and chunks of a held share that took the whole dropless buffer because
-    their rows did not fit the small one (:func:`held_share_ffn`)."""
+    and trips of a held share's chunk loop that took the whole dropless buffer
+    because some chunk's rows did not fit the small one (:func:`held_share_ffn`:
+    all of the whole loop's trips or none)."""
     return {
         "moe_load": jnp.zeros((num_experts,), jnp.float32),
         "moe_dropped": jnp.zeros((), jnp.float32),
@@ -280,14 +281,19 @@ def _einsum_moe(
 # layer's token-local tail inside (:func:`held_share_ffn`); ``chunk_two_sizes``:
 # chunk functions traced at two buffer sizes, each of which adds one dispatch
 # and one combine to the tally (a layer's chunk function is traced once a size).
-_PLAN_KEYS = ("dispatch_gather", "combine_gather", "chunk_loop_tail", "chunk_two_sizes")
+# ``chunk_trips_small``, ``chunk_trips_whole``: the trips a traced layer's tokens
+# take through its held experts, summed over the layers traced, in the loop at
+# the small buffer (0 for a layer of one size) and in the loop at the whole one
+# (:func:`held_chunks`: each loop cuts by what its own buffer has rows for).
+_PLAN_KEYS = ("dispatch_gather", "combine_gather", "chunk_loop_tail", "chunk_two_sizes",
+              "chunk_trips_small", "chunk_trips_whole")
 _plan_counts: Dict[str, int] = collections.Counter()
 _plan_counts_lock = threading.Lock()
 
 
-def _count_plan(key: str) -> None:
+def _count_plan(key: str, n: int = 1) -> None:
     with _plan_counts_lock:
-        _plan_counts[key] += 1
+        _plan_counts[key] += n
 
 
 def plan_counts() -> Dict[str, int]:
@@ -296,9 +302,13 @@ def plan_counts() -> Dict[str, int]:
     :func:`combine_rows`), chunk loops traced with their layer's tail
     inside (:func:`held_share_ffn`: 0 in a step whose layers hold a share in
     chunks is an executable that runs the held experts' forward a third time),
-    and chunk functions traced at a small buffer and the whole one (0 in a step
+    chunk functions traced at a small buffer and the whole one (0 in a step
     whose layers hold less than a quarter of the experts is an executable
-    whose every chunk multiplies a row for every selection)."""
+    whose every chunk multiplies a row for every selection), and the trips of
+    those layers' loops at either buffer (``chunk_trips_small`` equal to
+    ``chunk_trips_whole`` in a step with ``chunk_two_sizes`` is an executable
+    whose small loop still cuts by a chunk's selections: twice the trips its
+    buffer needs where a layer holds an eighth of the experts)."""
     with _plan_counts_lock:
         return {key: _plan_counts[key] for key in _PLAN_KEYS}
 
@@ -520,12 +530,16 @@ def sigmoid_route(x: jnp.ndarray, router: Params, k: int, route_scale: float
     return gate_idx, gate_w, scores
 
 
-# Rows of one expert buffer of a layer that holds a share, where the model names no
-# size of its own. ``grouped_ffn`` is dropless: its whole buffer has a row for every
-# selection, since any of them may be a held one, and a share of the experts fills few
-# of them. So such a layer takes its tokens in chunks whose selections fit this many
-# rows, one after another, and the step holds the buffers of one (xing4_0's benchmark
-# cell compiles to 14.36, 14.41 and 14.73 GiB at 2,048, 4,096 and 8,192).
+# Rows of one chunk's expert buffer, before tile padding, in a layer that holds a share,
+# where the model names no size of its own. ``grouped_ffn`` is dropless: its whole buffer
+# has a row for every selection, since any of them may be a held one, and a share of the
+# experts fills few of them. So such a layer takes its tokens in chunks, one after another,
+# and the step holds the buffers of one. What the number bounds depends on the buffer a
+# loop takes (:func:`held_chunks`): a chunk's selections in the loop at the whole dropless
+# buffer, a chunk's ``SMALL_BUFFER_LOADS`` balanced loads in the loop at the small one,
+# which therefore takes fewer, longer trips through a buffer of the same size (xing4_0's
+# benchmark cell compiled to 14.36, 14.41 and 14.73 GiB at 2,048, 4,096 and 8,192 while
+# both loops cut by selections).
 HELD_CHUNK_ROWS = 4096
 
 # A chunk of a held share takes a buffer for this many times the selections a balanced
@@ -537,16 +551,34 @@ HELD_CHUNK_ROWS = 4096
 SMALL_BUFFER_LOADS = 4
 
 
+def _balanced_loads(selections: int, held: int, n_routed: int) -> int:
+    """Rows for ``SMALL_BUFFER_LOADS`` times what a balanced router sends ``held`` of
+    ``n_routed`` experts of ``selections`` selections: every selection where the layer
+    holds ``n_routed / SMALL_BUFFER_LOADS`` experts or more."""
+    return -(-selections * min(n_routed, SMALL_BUFFER_LOADS * held) // n_routed)
+
+
 def held_chunks(tokens: int, top_k: int, held: int, n_routed: int,
-                chunk_rows: int = HELD_CHUNK_ROWS) -> int:
-    """Chunks (a power of two dividing ``tokens``) a layer that holds ``held``
-    of ``n_routed`` experts takes its tokens in: 1 where it holds every
-    expert, whose buffer is all rows."""
-    n = 1
-    if held < n_routed:
-        while tokens * top_k > n * chunk_rows and tokens % (2 * n) == 0:
+                chunk_rows: int = HELD_CHUNK_ROWS) -> Tuple[int, int]:
+    """``(small, whole)``: the chunks (powers of two dividing ``tokens``) a layer
+    that holds ``held`` of ``n_routed`` experts takes its tokens in, by the buffer
+    a chunk takes (:func:`chunk_buffer_rows`). Each loop cuts until what its
+    buffer has rows for, tile padding apart, fits ``chunk_rows``: at the whole
+    dropless buffer a chunk's selections, at the small one a chunk's
+    ``SMALL_BUFFER_LOADS`` balanced loads, so the small loop takes as many
+    trips or fewer (half where the layer holds an eighth of the experts). One
+    count where the layer holds ``n_routed / SMALL_BUFFER_LOADS`` experts or
+    more, and ``(1, 1)`` where it holds every expert, whose buffer is all rows."""
+    def trips(rows_of: Callable[[int], int]) -> int:
+        n = 1
+        while rows_of(tokens * top_k // n) > chunk_rows and tokens % (2 * n) == 0:
             n *= 2
-    return n
+        return n
+
+    if held >= n_routed:
+        return 1, 1
+    return (trips(lambda selections: _balanced_loads(selections, held, n_routed)),
+            trips(lambda selections: selections))
 
 
 def chunk_buffer_rows(selections: int, held: int, n_routed: int, block_t: int) -> Tuple[int, int]:
@@ -555,8 +587,7 @@ def chunk_buffer_rows(selections: int, held: int, n_routed: int, block_t: int) -
     small one has a row for ``SMALL_BUFFER_LOADS`` times the selections a
     balanced router sends and the whole one's tile padding; the two are one
     size where the layer holds that share of the experts or more."""
-    loads = -(-selections * min(n_routed, SMALL_BUFFER_LOADS * held) // n_routed)
-    return (gm.round_up(loads + held * (block_t - 1), block_t),
+    return (gm.round_up(_balanced_loads(selections, held, n_routed) + held * (block_t - 1), block_t),
             buffer_rows(selections, held, block_t))
 
 
@@ -567,13 +598,53 @@ def _chunked(a: jnp.ndarray, axis: int, n: int) -> jnp.ndarray:
     return jnp.moveaxis(a, axis, 0)
 
 
-def _chunks_at_either_size(one: Callable, sizes: Tuple[int, int], fit: Callable,
-                           chunks: Sequence[jnp.ndarray]):
-    """``one(rows, x_c, idx_c, w_c, *operands_c)`` of every chunk of ``chunks =
-    (x, idx, w, *operands)``, one after another, stacked, at ``rows =
-    sizes[0]`` where the traced ``fit(idx)`` holds and at ``sizes[1]`` where
-    not → ``(results, chunks that took sizes[1])``. Each chunk is
-    rematerialised: the backward has the chunks' inputs and nothing else.
+def _longer_chunks(a: jnp.ndarray, axis: int, r: int) -> jnp.ndarray:
+    """:func:`_chunked`'s ``[n, ..., T, ...]`` (the tokens at ``axis + 1``) →
+    ``[n / r, ..., r T, ...]``: every ``r`` chunks in a row as one. A view where the
+    tokens lead (``axis`` 0), a transposed copy where they do not."""
+    a = jnp.moveaxis(a.reshape((-1, r) + a.shape[1:]), 1, axis + 1)
+    return a.reshape(a.shape[:axis + 1] + (-1,) + a.shape[axis + 3:])
+
+
+def _shorter_chunks(a: jnp.ndarray, axis: int, r: int) -> jnp.ndarray:
+    """:func:`_longer_chunks`'s inverse: ``[m, ..., r T, ...]`` → ``[m r, ..., T, ...]``."""
+    a = a.reshape(a.shape[:axis + 1] + (r, -1) + a.shape[axis + 2:])
+    a = jnp.moveaxis(a, axis + 1, 1)
+    return a.reshape((-1,) + a.shape[2:])
+
+
+class ChunkLoop(NamedTuple):
+    """One way to take a layer's tokens through its held experts: ``trips`` chunks,
+    each through a buffer of ``rows`` rows in tiles of ``block_t``."""
+    trips: int
+    rows: int
+    block_t: int
+
+
+def _chunks_at_either_size(one: Callable, loops: Tuple[ChunkLoop, ChunkLoop], fit: Callable,
+                           axes: Sequence[int], chunks: Sequence[jnp.ndarray]):
+    """``one(loop, x_c, idx_c, w_c, *operands_c)`` of every chunk of ``chunks =
+    (x, idx, w, *operands)``, one after another, stacked: as ``loops[0]``, at
+    its ``rows`` and in its ``trips``, where the traced ``fit`` holds of
+    ``idx`` in that loop's chunks, and as ``loops[1]`` where not → ``(results,
+    trips that ran as loops[1]'s)``.
+    Each chunk is rematerialised: the backward has the chunks' inputs and
+    nothing else.
+
+    ``chunks`` come in ``loops[1]``'s chunks (:func:`_chunked`, ``axes[i]`` the
+    token axis of array ``i`` before it), and the results leave in them. The
+    two loops take their own trips (:func:`held_chunks`): ``loops[0]``, whose
+    trips divide ``loops[1]``'s, takes several of those chunks as one
+    (:func:`_longer_chunks`: a view of an array whose tokens lead) and cuts
+    its results up again, inside its own branch, forward and backward, so the
+    branch not taken costs nothing. What enters a ``cond`` enters it behind an
+    ``optimization_barrier``: where one branch reshapes its operands and the
+    other does not, XLA moves the reshape that made the chunks outside (``[B,
+    S, C] -> [trips, T, C]``) into the branches, nothing then holds the
+    layer's ``[B, S, C]`` arrays to rows first, and xing4_0's streams all take
+    a layout with ``S`` innermost, with a copy of 56 MB at every matmul and
+    every loop they meet (its benchmark cell compiled to 14.93-15.02 GiB so,
+    and ran 1% slower than with equal trips; 14.633 behind the barriers).
 
     Why not ``jax.checkpoint`` of a ``lax.cond``: differentiating a ``cond``
     gives every branch the residuals of all branches, zero-filled, so the
@@ -586,51 +657,74 @@ def _chunks_at_either_size(one: Callable, sizes: Tuple[int, int], fit: Callable,
     of the gradient in place. The choice is one a loop and not one a chunk:
     chosen inside the loop, the banks' three gradients leave a ``cond`` as
     fresh arrays in every trip (xing4_0's benchmark cell compiled to 14.756
-    GiB so, for 14.520 with one buffer size). ``one`` is traced once a size,
+    GiB so, for 14.520 with one buffer size). ``one`` is traced once a loop,
     with what it closes over hoisted, since a ``custom_vjp`` differentiates
     its arguments only."""
+    ratios = [loops[1].trips // loop.trips for loop in loops]
+
+    def longer(r, arrays):
+        if r == 1:
+            return list(arrays)
+        with jax.named_scope("layer"):  # as the tail: what moving its operands costs is the layer's
+            return [_longer_chunks(a, axis, r) for a, axis in zip(arrays, axes)]
+
+    def shorter(r, tree):   # results are ``[trips, T, ...]``: their tokens lead
+        return tree if r == 1 else jax.tree_util.tree_map(lambda a: _shorter_chunks(a, 0, r), tree)
+
     (small, consts), (whole, same) = (
-        jax.closure_convert(functools.partial(one, rows), *(a[0] for a in chunks))
-        for rows in sizes)
+        jax.closure_convert(functools.partial(one, loop), *(a[0] for a in longer(r, chunks)))
+        for loop, r in zip(loops, ratios))
     if len(consts) != len(same) or any(a is not b for a, b in zip(consts, same)):
         raise RuntimeError("a chunk function closes over other arrays at another buffer size")
-    n = chunks[0].shape[0]
 
-    def forward(f):
-        def run(idx, consts, x, w, *operands):
-            return jax.lax.scan(lambda _, c: (None, f(*c, *consts)), None, (x, idx, w, *operands))[1]
+    def forward(r, f):
+        def run(consts, *chunks):
+            return shorter(r, jax.lax.scan(lambda _, c: (None, f(*c, *consts)), None,
+                                           tuple(longer(r, chunks)))[1])
         return run
 
-    def backward(f):
+    def backward(r, f):
         def trip(consts, acc, c):
-            ct_c, idx_c, *inputs_c = c
+            ct_c, x_c, idx_c, *rest_c = c
 
-            def again(consts, x_c, w_c, *operands_c):
-                return f(x_c, idx_c, w_c, *operands_c, *consts)
+            def again(consts, x_c, *rest_c):
+                return f(x_c, idx_c, *rest_c, *consts)
             # no barrier against merging the recomputation with a forward: this trip holds
             # none, and the barrier cost the cells' steps 0.015 and 0.063 GiB
             d_consts, *d_inputs_c = jax.vjp(jax.checkpoint(again, prevent_cse=False),
-                                            consts, *inputs_c)[1](ct_c)
+                                            consts, x_c, *rest_c)[1](ct_c)
             return [a + d for a, d in zip(acc, d_consts)], d_inputs_c
 
-        def run(idx, consts, inputs, cts):   # the last chunk first, as a scan's transpose has it
-            return jax.lax.scan(functools.partial(trip, consts), [jnp.zeros_like(c) for c in consts],
-                                (cts, idx, *inputs), reverse=True)
+        def run(consts, chunks, cts):   # the last chunk first, as a scan's transpose has it
+            cts = jax.tree_util.tree_map(lambda a: _longer_chunks(a, 0, r), cts) if r > 1 else cts
+            d_consts, d_inputs = jax.lax.scan(
+                functools.partial(trip, consts), [jnp.zeros_like(c) for c in consts],
+                (cts, *longer(r, chunks)), reverse=True)
+            if r > 1:   # idx, chunks[1], has no cotangent
+                with jax.named_scope("layer"):
+                    d_inputs = [_shorter_chunks(d, axis, r)
+                                for d, axis in zip(d_inputs, (axes[0], *axes[2:]))]
+            return d_consts, d_inputs
         return run
+
+    branches = list(zip(ratios, (small, whole)))
 
     @jax.custom_vjp
     def loop(idx, consts, x, w, *operands):
-        fits = fit(idx)
-        return (jax.lax.cond(fits, forward(small), forward(whole), idx, consts, x, w, *operands),
-                n * (1.0 - fits.astype(jnp.float32)))
+        fits = fit(_longer_chunks(idx, 0, ratios[0]))
+        x, idx, w, *operands = jax.lax.optimization_barrier((x, idx, w, *operands))
+        return (jax.lax.cond(fits, *(forward(r, f) for r, f in branches), consts, x, idx, w, *operands),
+                loops[1].trips * (1.0 - fits.astype(jnp.float32)))
 
     def loop_forward(idx, *inputs):
         return loop(idx, *inputs), (idx, inputs)
 
     def loop_backward(saved, cts):
-        idx, (consts, *inputs) = saved
-        d_consts, d_inputs = jax.lax.cond(fit(idx), backward(small), backward(whole),
-                                          idx, consts, inputs, cts[0])
+        idx, (consts, x, w, *operands) = saved
+        fits = fit(_longer_chunks(idx, 0, ratios[0]))
+        (x, idx, w, *operands), ct = jax.lax.optimization_barrier(((x, idx, w, *operands), cts[0]))
+        d_consts, d_inputs = jax.lax.cond(fits, *(backward(r, f) for r, f in branches),
+                                          consts, [x, idx, w, *operands], ct)
         return (None, d_consts, *d_inputs)
 
     loop.defvjp(loop_forward, loop_backward)
@@ -644,18 +738,21 @@ def held_share_ffn(experts: Params, x: jnp.ndarray, gate_idx: jnp.ndarray, gate_
                    operands: Sequence[Tuple[int, jnp.ndarray]] = ()):
     """The held experts' part of a routed layer: ``x [B, S, C]``, the router's
     ``gate_idx``/``gate_w [B, S, K]`` over all ``n_routed`` → ``([B, S, C],
-    chunks that took the whole buffer)``. ``experts`` holds the banks of
+    trips that took the whole buffer)``. ``experts`` holds the banks of
     ``held = (first, count)``; the tokens go through :func:`grouped_ffn` in
-    :func:`held_chunks` chunks, each rematerialised, so the step holds one
-    chunk's buffers and not all.
+    chunks, each rematerialised, so the step holds one chunk's buffers and not
+    all.
 
     A chunk's buffer is the small one of :func:`chunk_buffer_rows` where every
     chunk's :func:`held_rows` fit it and the whole dropless one where some
-    chunk's do not (:func:`_chunks_at_either_size`): the same rows in the same
-    order either way, nothing dropped, and the second result (float32) counts
-    the chunks that took the whole one. A layer that holds ``n_routed /
-    SMALL_BUFFER_LOADS`` experts or more has one size, traces no choice and
-    counts 0.
+    chunk's do not (:func:`_chunks_at_either_size`), and each of the two loops
+    takes the trips of the buffer it takes (:func:`held_chunks`: both buffers
+    stay within ``chunk_rows`` and their tile padding, so the small loop's
+    chunks are the longer ones and ``fit`` is asked of those): the same rows in
+    the same order either way, nothing dropped, and the second result
+    (float32) counts the trips that took the whole one, all of its loop's or
+    none. A layer that holds ``n_routed / SMALL_BUFFER_LOADS`` experts or more
+    has one size and one count, traces no choice and counts 0.
 
     ``tail(routed_c, *operands_c)`` is the rest of the layer after its experts,
     which must be token-local (a norm over a token's channels, a mix of a
@@ -676,34 +773,44 @@ def held_share_ffn(experts: Params, x: jnp.ndarray, gate_idx: jnp.ndarray, gate_
     is no loop: the tail is applied to the whole."""
     B, S, C = x.shape
     K, (first, count) = gate_idx.shape[-1], held
-    n = held_chunks(B * S, K, count, n_routed, chunk_rows)
-    T = B * S // n
-    block_t = gm.pick_block_t(T * K, count)
-    small, whole = chunk_buffer_rows(T * K, count, n_routed, block_t)
 
-    def one(rows, x_c, idx_c, w_c, *operands_c):
-        routed = grouped_ffn(experts, x_c, idx_c, w_c, count, block_t,
-                             precision=precision, first=first, rows=rows)
+    def loop_at(trips: int, size: int) -> ChunkLoop:   # size: 0 the small buffer, 1 the whole
+        selections = B * S * K // trips
+        block_t = gm.pick_block_t(selections, count)
+        return ChunkLoop(trips, chunk_buffer_rows(selections, count, n_routed, block_t)[size], block_t)
+
+    n_small, n_whole = held_chunks(B * S, K, count, n_routed, chunk_rows)
+    small, dropless = loop_at(n_small, 0), loop_at(n_small, 1)
+    two_sizes = small.rows < dropless.rows
+    whole = loop_at(n_whole, 1) if two_sizes else dropless
+
+    def one(loop, x_c, idx_c, w_c, *operands_c):
+        routed = grouped_ffn(experts, x_c, idx_c, w_c, count, loop.block_t,
+                             precision=precision, first=first, rows=loop.rows)
         if tail is None:
             return routed
         with jax.named_scope("layer"):  # the layer's operations, not the experts'
             return tail(routed, *operands_c)
 
-    chunks = (x.reshape(n, T, C), gate_idx.reshape(n, T, K),
-              gate_w.reshape(n, T, K).astype(x.dtype))
+    axes = (0, 0, 0) + tuple(axis for axis, _ in operands)
+    chunks = (x.reshape(whole.trips, -1, C), gate_idx.reshape(whole.trips, -1, K),
+              gate_w.reshape(whole.trips, -1, K).astype(x.dtype))
     with jax.named_scope("layer"):  # as the tail: what moving its operands costs is the layer's
-        chunks += tuple(_chunked(a, axis, n) for axis, a in operands)
-    if tail is not None and n > 1:
+        chunks += tuple(_chunked(a, axis, whole.trips) for axis, a in operands)
+    if tail is not None and whole.trips > 1:
         _count_plan("chunk_loop_tail")
-    if small < whole:
+    _count_plan("chunk_trips_whole", whole.trips)
+    if two_sizes:
         _count_plan("chunk_two_sizes")
+        _count_plan("chunk_trips_small", small.trips)
         out, took_whole = _chunks_at_either_size(
             one, (small, whole),
-            lambda idx: jnp.all(held_rows(idx, count, block_t, first) <= small), chunks)
+            lambda idx: jnp.all(held_rows(idx, count, small.block_t, first) <= small.rows),
+            axes, chunks)
     else:
         took_whole = jnp.zeros((), jnp.float32)
         at_whole = functools.partial(one, whole)
-        if n == 1:
+        if whole.trips == 1:
             out = jax.tree_util.tree_map(lambda a: a[None], at_whole(*(a[0] for a in chunks)))
         else:  # rematerialised, or the loop keeps every chunk's buffers for the backward
             _, out = jax.lax.scan(lambda _, c: (None, jax.checkpoint(at_whole)(*c)), None, chunks)

@@ -281,7 +281,7 @@ def test_a_held_share_drops_nothing(tiny, monkeypatch, chunk_rows, crowded):
     K = args.num_experts_per_tok
     assert count == K                                   # a token can choose all of the held
     assert moe_lib.held_chunks(B * S, K, count, args.n_routed_experts, chunk_rows) == \
-        max(1, B * S * K // min(chunk_rows, B * S * K))
+        (max(1, B * S * K // min(chunk_rows, B * S * K)),) * 2   # a quarter held: one size, one count
     if crowded:
         ff = {**ff, "router": {**ff["router"], "bias": ff["router"]["bias"].at[first:first + count].set(10.0)}}
     x = jax.random.normal(jax.random.PRNGKey(2), (B, S, cfg["hidden_size"]), jnp.float32)

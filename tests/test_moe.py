@@ -459,7 +459,7 @@ def test_grouped_block_gradient_has_no_scatter_of_activation_rows(monkeypatch):
     hlo = grad.lower(p, x).as_text(dialect="hlo")
     assert {k: n - seen[k] for k, n in moe.plan_counts().items()} == {
         "dispatch_gather": 1, "combine_gather": 1, "chunk_loop_tail": 0,   # no chunk loop here,
-        "chunk_two_sizes": 0}                                              # and one buffer size
+        "chunk_two_sizes": 0, "chunk_trips_small": 0, "chunk_trips_whole": 0}   # and one buffer size
     assert " gather(" in hlo and not activation_scatters(hlo, D)
 
     experts, xs, idx, gate_w, E, bt, first = _dispatch_case("held_share", jnp.float32, D=D)
@@ -471,8 +471,9 @@ def _tiny_block(arch, monkeypatch, held_count=2):
     """``(block(p, x) -> x', one routed layer's weights, the layer's input, the
     model's args)`` of an architecture whose routed layers hold a share, at its
     rehearsal widths (2 x 128 tokens, top-2, 2 of 8 experts held: 4 chunks of
-    128 rows at one buffer size; ``held_count`` 1 holds an eighth, and a chunk
-    then has a small buffer of 128 rows and the whole one of 192)."""
+    128 rows at one buffer size; ``held_count`` 1 holds an eighth, and the
+    tokens then go in 2 chunks through a small buffer of 256 rows (tiles of
+    128) or in 4 through the whole one of 192 (tiles of 64))."""
     from benchmark import run as harness
 
     positions = jnp.arange(128, dtype=jnp.int32)
@@ -499,7 +500,8 @@ def _tiny_block(arch, monkeypatch, held_count=2):
         shape = (args.hc_mult, 2, 128, cfg["hidden_size"])
     layer = jax.tree_util.tree_map(jnp.asarray, t.ref.init_params(7, cfg)["layers"][0])
     assert args.experts_held == (2, held_count) and args.n_routed_experts == 8
-    assert moe.held_chunks(2 * 128, args.num_experts_per_tok, held_count, 8, 128) == 4
+    assert moe.held_chunks(2 * 128, args.num_experts_per_tok, held_count, 8, 128) == (
+        (4, 4) if held_count == 2 else (2, 4))
     return block, layer, jax.random.normal(jax.random.PRNGKey(3), shape, jnp.float32), args
 
 
@@ -538,8 +540,8 @@ def test_a_rematerialised_layer_runs_its_held_experts_forward_twice_not_thrice(
 
     A quarter of the experts held (2 of 8) is one buffer size and no ``cond``;
     an eighth is two sizes, each loop the branch of a ``cond``, and the counts
-    hold along either path: the small buffer's branch of every ``cond`` (1) or
-    the whole one's (0)."""
+    hold along either path: the small buffer's branch of every ``cond`` (1),
+    whose loops take 2 trips, or the whole one's (0), whose loops take 4."""
     monkeypatch.setenv("GMM_BACKEND", "ragged")   # an expert matmul is one ``ragged_dot_general``
     block, layer, x, _ = _tiny_block(arch, monkeypatch, held_count)
     two_sizes = int(held_count == 1)
@@ -553,7 +555,7 @@ def test_a_rematerialised_layer_runs_its_held_experts_forward_twice_not_thrice(
 
     # a chunk function is traced once a size, whatever differentiates it afterwards
     once = {"dispatch_gather": 1 + two_sizes, "combine_gather": 1 + two_sizes,
-            "chunk_two_sizes": two_sizes}
+            "chunk_two_sizes": two_sizes, "chunk_trips_small": 2 * two_sizes, "chunk_trips_whole": 4}
     new, traced, got, grads = arrangement()
     assert traced == dict(once, chunk_loop_tail=1)
     monkeypatch.setattr(moe, "sigmoid_routed_ffn", tail_after_loop(moe.sigmoid_routed_ffn))
@@ -561,9 +563,9 @@ def test_a_rematerialised_layer_runs_its_held_experts_forward_twice_not_thrice(
     assert traced == dict(once, chunk_loop_tail=0)
 
     matmul = lambda e: e.primitive.name == "ragged_dot_general"
-    chunk_loop = lambda e: e.primitive.name == "scan" and e.params["length"] == 4
     choice = lambda e: e.primitive.name == "cond"
     for path in ((1, 0) if two_sizes else (None,)):
+        chunk_loop = lambda e: e.primitive.name == "scan" and e.params["length"] == (2 if path else 4)
         # three matmuls a pass: the forward, the loop's recomputation, dX, dW; and, with the
         # tail outside, the rematerialised layer's own pass over the loop
         assert len(_equations(old, matmul, path)) == 15 and len(_equations(new, matmul, path)) == 12
@@ -577,10 +579,17 @@ def test_a_rematerialised_layer_runs_its_held_experts_forward_twice_not_thrice(
 
 def _held_share_case(arch, ids, n_chunks, monkeypatch):
     """``(experts, x, idx, gate_w, held, n_routed, chunk_rows)`` at ``arch``'s tiny
-    widths with an eighth of the experts held (1 of 8): ``ids`` ``fit`` draws
-    every token's top-2 over the router's whole width, so a chunk's held rows
-    fit its small buffer; ``all_held`` sends every selection to the held
-    expert, so no chunk's do."""
+    widths with an eighth of the experts held (1 of 8), ``chunk_rows`` such that
+    the whole buffer's loop takes ``n_chunks`` trips and the small one's half
+    of them (one where ``n_chunks`` is one): ``ids`` ``fit`` draws every
+    token's top-2 over the router's whole width, so a chunk's held rows fit
+    its small buffer; ``all_held`` sends every selection to the held expert, so
+    no chunk's do; ``doubled_chunk_overflows`` sends the held expert, from each
+    half of the small loop's first chunk, as many selections as the small
+    buffer of a chunk of that half's size has rows (``fit``'s elsewhere), so
+    that each half would have fitted alone and the two together do not."""
+    from mlx_cuda_distributed_pretraining_tpu.ops import grouped_matmul as gm
+
     _, layer, _, args = _tiny_block(arch, monkeypatch, held_count=1)
     first, n_routed, K = args.experts_held[0], args.n_routed_experts, args.num_experts_per_tok
     rng = np.random.default_rng(5)
@@ -588,39 +597,48 @@ def _held_share_case(arch, ids, n_chunks, monkeypatch):
     idx = np.stack([rng.permutation(n_routed)[:K] for _ in range(B * S)]).astype(np.int32)
     if ids == "all_held":
         idx = np.full_like(idx, first)
+    elif ids == "doubled_chunk_overflows":
+        half = B * S // n_chunks                       # tokens of a chunk of the whole buffer's loop
+        fits_a_half = moe.chunk_buffer_rows(half * K, 1, n_routed, gm.pick_block_t(half * K, 1))[0]
+        assert fits_a_half % K == 0 and fits_a_half // K <= half
+        for start in (0, half):
+            idx[start:start + half] = (first + 1) % n_routed
+            idx[start:start + fits_a_half // K] = first
     x = jnp.asarray(rng.normal(size=(B, S, C)), jnp.float32)
     gate_w = jnp.asarray(rng.uniform(0.1, 1.0, size=(B, S, K)), jnp.float32)
     return (layer["feed_forward"]["experts"], x, jnp.asarray(idx.reshape(B, S, K)), gate_w,
             args.experts_held, n_routed, B * S * K // n_chunks)
 
 
-@pytest.mark.parametrize("n_chunks", [1, 4])
-@pytest.mark.parametrize("with_tail", [False, True], ids=["no_tail", "tail"])
-@pytest.mark.parametrize("ids", ["fit", "all_held"])
-@pytest.mark.parametrize("arch", ["afmoe", "xing_mla_moe"])
-def test_a_chunk_at_either_buffer_size_is_grouped_ffn_at_the_whole_buffer(
-        arch, ids, with_tail, n_chunks, monkeypatch):
-    """A layer that holds an eighth of the experts takes a chunk through the
-    small buffer where the chunk's held rows fit it and through the whole
-    dropless one where they do not, and counts the chunks that took the whole
-    one: 0 of ``n_chunks`` and all of them here. On either path the output and
-    the gradients (banks, tokens, gate weights; with a tail its operand and the
-    weight it closes over) are ``grouped_ffn``'s at a row for every selection
-    with the tail applied afterwards, in float32 to 1e-6 of a leaf's largest
-    value (a grouped matmul blocks by its buffer's size)."""
+def _chunked_is_grouped_ffn_at_the_whole_buffer(arch, ids, with_tail, n_chunks, monkeypatch):
+    """``held_share_ffn`` on :func:`_held_share_case` against ``grouped_ffn`` at a
+    row for every selection with the tail applied afterwards: output and every
+    gradient in float32 to 1e-6 of a leaf's largest value (a grouped matmul
+    blocks by its buffer's size) → the trips counted at the whole buffer."""
     from mlx_cuda_distributed_pretraining_tpu.ops import grouped_matmul as gm
 
     experts, x, idx, gate_w, held, n_routed, chunk_rows = _held_share_case(
         arch, ids, n_chunks, monkeypatch)
     B, S, C = x.shape
     K, (first, count) = idx.shape[-1], held
-    assert moe.held_chunks(B * S, K, count, n_routed, chunk_rows) == n_chunks
-    block_t = gm.pick_block_t(chunk_rows, count)
-    small, whole = moe.chunk_buffer_rows(chunk_rows, count, n_routed, block_t)
-    assert small < whole
-    for chunk in np.asarray(idx).reshape(n_chunks, -1):
-        rows = sum(gm.round_up(int((chunk == first + e).sum()), block_t) for e in range(count))
-        assert rows <= small if ids == "fit" else small < rows <= whole
+    trips = moe.held_chunks(B * S, K, count, n_routed, chunk_rows)
+    assert trips == (max(1, n_chunks // 2), n_chunks)
+    # the small loop's buffer over its own, longer chunks; the whole loop's over its own
+    (small, _), (_, whole) = (
+        moe.chunk_buffer_rows(B * S * K // n, count, n_routed, gm.pick_block_t(B * S * K // n, count))
+        for n in trips)
+    block_t = gm.pick_block_t(B * S * K // trips[0], count)
+    assert small < moe.chunk_buffer_rows(B * S * K // trips[0], count, n_routed, block_t)[1]
+    if n_chunks >= 4:     # half the trips through a buffer of the same rows (tiles of 128 in both)
+        assert small == whole
+    rows = [sum(gm.round_up(int((chunk == first + e).sum()), block_t) for e in range(count))
+            for chunk in np.asarray(idx).reshape(trips[0], -1)]
+    if ids == "fit":
+        assert max(rows) <= small
+    elif ids == "all_held":
+        assert min(rows) > small
+    else:
+        assert rows[0] > small and max(rows[1:], default=0) <= small
     rng = np.random.default_rng(6)
     gain = jnp.asarray(rng.uniform(0.5, 1.5, size=(C,)), jnp.float32)
     res = jnp.asarray(rng.normal(size=(B, S, C)), jnp.float32)
@@ -645,9 +663,12 @@ def test_a_chunk_at_either_buffer_size_is_grouped_ffn_at_the_whole_buffer(
             loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(experts, x, gate_w, gain, res)
         return took_whole, [out] + jax.tree_util.tree_leaves(grads)
 
+    seen = moe.plan_counts()
     took_whole, got = run(chunked)
+    traced = {k: n - seen[k] for k, n in moe.plan_counts().items()}
+    assert (traced["chunk_two_sizes"], traced["chunk_trips_small"], traced["chunk_trips_whole"]) == (
+        1, *trips)
     _, want = run(at_whole_buffer)
-    assert float(took_whole) == (0 if ids == "fit" else n_chunks)
     assert len(got) == 1 + 3 + 4
     for name, a, b in zip(("out", "w_down", "w_gate", "w_up", "x", "gate_w", "gain", "res"),
                           got, want):
@@ -658,6 +679,68 @@ def test_a_chunk_at_either_buffer_size_is_grouped_ffn_at_the_whole_buffer(
         scale = np.abs(b).max()
         assert a.shape == b.shape and scale > 0, name
         np.testing.assert_allclose(a, b, atol=1e-6 * scale, rtol=1e-6, err_msg=name)
+    return float(took_whole)
+
+
+@pytest.mark.parametrize("n_chunks", [1, 2, 4])
+@pytest.mark.parametrize("with_tail", [False, True], ids=["no_tail", "tail"])
+@pytest.mark.parametrize("ids", ["fit", "all_held"])
+@pytest.mark.parametrize("arch", ["afmoe", "xing_mla_moe"])
+def test_a_chunk_at_either_buffer_size_is_grouped_ffn_at_the_whole_buffer(
+        arch, ids, with_tail, n_chunks, monkeypatch):
+    """A layer that holds an eighth of the experts takes its tokens through the
+    small buffer, in half the trips the whole buffer's loop takes (one for
+    one), where every chunk's held rows fit it, and through the whole dropless
+    one, in ``n_chunks`` trips, where some chunk's do not, and counts the
+    trips that took the whole one: 0 and all ``n_chunks`` here. On either
+    path the output and the gradients (banks, tokens, gate weights; with a
+    tail its operand and the weight it closes over) are ``grouped_ffn``'s at a
+    row for every selection with the tail applied afterwards."""
+    took_whole = _chunked_is_grouped_ffn_at_the_whole_buffer(arch, ids, with_tail, n_chunks, monkeypatch)
+    assert took_whole == (0 if ids == "fit" else n_chunks)
+
+
+@pytest.mark.parametrize("n_chunks", [2, 4])
+@pytest.mark.parametrize("with_tail", [False, True], ids=["no_tail", "tail"])
+@pytest.mark.parametrize("arch", ["afmoe", "xing_mla_moe"])
+def test_one_doubled_chunk_that_overflows_sends_the_layer_through_the_whole_buffer(
+        arch, with_tail, n_chunks, monkeypatch):
+    """``fit`` is asked of the small loop's own chunks, which are two of the
+    whole loop's: where the held rows of one of them do not fit its small
+    buffer, although each of its halves would have fitted the small buffer of
+    a chunk of its own size, the layer runs the whole buffer's loop at that
+    loop's ``n_chunks`` trips, ``moe_chunks_whole`` counts them, and output and
+    gradients are the same values as ever."""
+    took_whole = _chunked_is_grouped_ffn_at_the_whole_buffer(
+        arch, "doubled_chunk_overflows", with_tail, n_chunks, monkeypatch)
+    assert took_whole == n_chunks
+
+
+@pytest.mark.parametrize("tokens, top_k, held, n_routed, chunk_rows, trips, rows", [
+    (8192, 4, 8, 64, 4096, (4, 8), (5120, 5120)),           # xing4_0-29b-a4b-ep8: 8 of 64, top-4
+    (16384, 8, 16, 128, 65536, (1, 2), (67584, 67584)),     # trinity-mini-ep8: 16 of 128, top-8
+    (8192, 4, 4, 64, 4096, (2, 8), (4608, 4608)),           # a sixteenth held: a quarter of the trips
+    (8192, 4, 16, 64, 4096, (8, 8), (6144, 6144)),          # a quarter held: one size, one count
+    (8192, 4, 64, 64, 4096, (1, 1), (40960, 40960)),        # every expert held: no loop
+    (24, 4, 1, 8, 4, (8, 8), (16, 24)),                     # 3 tokens halve no further: both stop
+], ids=["xing4_0-29b-a4b-ep8", "trinity-mini-ep8", "a_sixteenth_held", "a_quarter_held",
+        "every_expert_held", "tokens_that_halve_no_further"])
+def test_held_chunks_gives_each_loop_the_trips_of_its_own_buffer(
+        tokens, top_k, held, n_routed, chunk_rows, trips, rows):
+    """``chunk_rows`` bounds a chunk's selections in the whole buffer's loop and a
+    chunk's ``SMALL_BUFFER_LOADS`` balanced loads in the small one's → ``(small,
+    whole)`` trips, and the rows of each loop's buffer over its own chunks. The
+    benchmark's two expert cells take half the trips through a buffer of the
+    rows the whole loop's has (``tests/test_tpu_compile.py::GMM_CELLS``), a
+    layer that holds a quarter of the experts or more has one count, and one
+    that holds them all has no loop."""
+    from mlx_cuda_distributed_pretraining_tpu.ops import grouped_matmul as gm
+
+    assert moe.held_chunks(tokens, top_k, held, n_routed, chunk_rows) == trips
+    selections = [tokens * top_k // n for n in trips]
+    got = tuple(moe.chunk_buffer_rows(s, held, n_routed, gm.pick_block_t(s, held))[size]
+                for size, s in enumerate(selections))
+    assert got == rows
 
 
 @pytest.mark.parametrize("held", [(2, 2), (0, 8)], ids=["a_quarter_held", "every_expert_held"])

@@ -240,23 +240,30 @@ def test_tgmm_compiles_for_v5e(v5e, compiled_kernels):
 # The benchmark's two expert-parallel cells, one chunk's dispatch: rows of the
 # buffer (the whole dropless one, and the small one a chunk takes while its rows
 # fit: ``moe.chunk_buffer_rows``), held experts, and an expert's [K, N] (PERF.md
-# section 4).
+# section 4). Each loop cuts its chunks by what its own buffer has rows for
+# (``moe.held_chunks``), so the small loop takes half the trips and its buffer
+# over a chunk twice as long has the rows the whole loop's has.
 GMM_CELLS = {"xing4_0-29b-a4b-ep8": (5120, 8, 3584, 1024),
              "trinity-mini-ep8": (67584, 16, 2048, 1024),
-             "xing4_0-29b-a4b-ep8-small": (3072, 8, 3584, 1024),
-             "trinity-mini-ep8-small": (34816, 16, 2048, 1024)}
+             "xing4_0-29b-a4b-ep8-small": (5120, 8, 3584, 1024),
+             "trinity-mini-ep8-small": (67584, 16, 2048, 1024)}
 
 
 def test_the_cells_buffer_rows_are_the_programs():
-    """``GMM_CELLS``' rows are what ``models/moe.py`` gives a chunk of each cell
-    (selections of a chunk, held of routed experts: PERF.md section 4)."""
+    """``GMM_CELLS``' rows are what ``models/moe.py`` gives a chunk of each cell in
+    each of its two loops (tokens a step, top-k, held of routed experts, the
+    model's ``chunk_rows``: PERF.md section 4): 8,192 and 131,072 selections a
+    chunk of the small loop, 4,096 and 65,536 a chunk of the whole one."""
     from mlx_cuda_distributed_pretraining_tpu.models import moe
 
-    for cell, selections, held, routed in (("xing4_0-29b-a4b-ep8", 4096, 8, 64),
-                                           ("trinity-mini-ep8", 65536, 16, 128)):
-        block_t = gm.pick_block_t(selections, held)
-        assert moe.chunk_buffer_rows(selections, held, routed, block_t) == (
-            GMM_CELLS[cell + "-small"][0], GMM_CELLS[cell][0])
+    for cell, tokens, top_k, held, routed, chunk_rows, selections in (
+            ("xing4_0-29b-a4b-ep8", 8192, 4, 8, 64, moe.HELD_CHUNK_ROWS, (8192, 4096)),
+            ("trinity-mini-ep8", 16384, 8, 16, 128, 65536, (131072, 65536))):
+        trips = moe.held_chunks(tokens, top_k, held, routed, chunk_rows)
+        assert tuple(tokens * top_k // n for n in trips) == selections
+        small, whole = (moe.chunk_buffer_rows(s, held, routed, gm.pick_block_t(s, held))[size]
+                        for size, s in enumerate(selections))
+        assert (small, whole) == (GMM_CELLS[cell + "-small"][0], GMM_CELLS[cell][0])
 
 
 @pytest.mark.parametrize("orientation", ["up", "down"])

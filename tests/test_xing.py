@@ -164,7 +164,7 @@ def test_a_held_share_drops_nothing(tiny, monkeypatch, chunk_rows, crowded):
     x = jax.random.normal(jax.random.PRNGKey(2), (B, S, cfg["hidden_size"]), jnp.float32)
     monkeypatch.setattr(xing, "HELD_CHUNK_ROWS", chunk_rows)
     assert moe_lib.held_chunks(B * S, 2, count, cfg["n_routed_experts"], chunk_rows) == \
-        max(1, B * S * 2 // min(chunk_rows, B * S * 2))
+        (max(1, B * S * 2 // min(chunk_rows, B * S * 2)),) * 2   # a quarter held: one size, one count
     got, stats = xing.routed_ffn(ff, x, args)
     held = float(stats["moe_load"][first:first + count].sum())
     assert held == B * S * count if crowded else 0 < held < B * S
@@ -222,7 +222,7 @@ def test_a_held_shares_gradient_has_no_scatter_of_activation_rows(tiny, monkeypa
     cfg, args, params, _ = tiny
     monkeypatch.setenv("GMM_BACKEND", "ragged")
     monkeypatch.setattr(xing, "HELD_CHUNK_ROWS", 128)
-    assert moe_lib.held_chunks(B * S, 2, args.experts_held[1], args.n_routed_experts, 128) == 4 \
+    assert moe_lib.held_chunks(B * S, 2, args.experts_held[1], args.n_routed_experts, 128) == (4, 4) \
         and args.experts_held[0] > 0
     ff = jax.tree_util.tree_map(jnp.asarray, params["layers"][0]["feed_forward"])
     C = cfg["hidden_size"]
@@ -232,7 +232,8 @@ def test_a_held_shares_gradient_has_no_scatter_of_activation_rows(tiny, monkeypa
     hlo = grad.lower(ff, x).as_text(dialect="hlo")
     assert {k: n - seen[k] for k, n in moe_lib.plan_counts().items()} == {
         "dispatch_gather": 1, "combine_gather": 1, "chunk_loop_tail": 0,   # no tail was handed in,
-        "chunk_two_sizes": 0}                               # and a quarter held is one buffer size
+        "chunk_two_sizes": 0,                               # and a quarter held is one buffer size,
+        "chunk_trips_small": 0, "chunk_trips_whole": 4}     # whose one loop is the whole buffer's
     assert " gather(" in hlo and " scatter(" in hlo         # the load's bincount is one
     assert not activation_scatters(hlo, C)
 
