@@ -63,6 +63,7 @@ def compiled_kernels(monkeypatch):
     """Take the Mosaic branch of the kernels, as on a TPU backend."""
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(gm, "_interpret", lambda: False)
+    monkeypatch.setattr(fused_ce, "_interpret", lambda: False)
     # Trace fresh: a core cached by an interpret-mode test would be reused.
     fa._cached_core.cache_clear()
 
@@ -338,11 +339,12 @@ def test_scoped_train_step_compiles_for_v5e(v5e, compiled_kernels, monkeypatch):
     """The train step of the benchmark's cell (Mistral-7B widths, four scanned
     layers, full remat, flash, fused CE, Adafactor with clipping, 4 x 4,096),
     as the chip's compiler leaves it: every Mosaic call is one of the three
-    named flash kernels and every matmul sits under a scope of the vocabulary,
-    so a trace of the chip can be reduced by scope (README "Reading a
-    profile"); the head is three matmuls a chunk walk, none of them
-    recomputed (ops/fused_ce.py); and the step fits the chip as the
-    benchmark's ``step_hbm_gib`` counts it."""
+    named flash kernels or the head's ``ce_softmax_grad`` and every matmul
+    sits under a scope of the vocabulary, so a trace of the chip can be
+    reduced by scope (README "Reading a profile"); the head is three matmuls
+    and that one kernel a chunk walk, none of them recomputed
+    (ops/fused_ce.py); and the step fits the chip as the benchmark's
+    ``step_hbm_gib`` counts it."""
     import re
     from functools import partial
 
@@ -386,11 +388,12 @@ def test_scoped_train_step_compiles_for_v5e(v5e, compiled_kernels, monkeypatch):
             kernels.append(m.group(1))
         elif m and " convolution(" in line:
             matmuls.append(m.group(1))
-    # forward, its recomputation, dQ, dK/dV: four calls, three names
+    # forward, its recomputation, dQ, dK/dV: four calls, three names; and the
+    # kernel between the head's matmuls, in the walk's loop
     assert sorted(innermost(k) for k in kernels) == [
-        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd", "flash_fwd"], kernels
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd", "flash_fwd", "lm_head_ce"], kernels
     assert sum("rematted_computation" in k for k in kernels) == 1
-    assert all(re.search(r"%flash_(fwd|bwd_dq|bwd_dkv)[.\d]* = ", line)
+    assert all(re.search(r"%(flash_(fwd|bwd_dq|bwd_dkv)|ce_softmax_grad)[.\d]* = ", line)
                for line in hlo.split("\n") if "tpu_custom_call" in line and " = " in line
                and "custom-call(" in line), "XLA names the instruction after the kernel"
     assert len(matmuls) >= 20
@@ -399,12 +402,56 @@ def test_scoped_train_step_compiles_for_v5e(v5e, compiled_kernels, monkeypatch):
     # logits, dX and dW of a chunk, all in the forward walk's loop
     head = [m for m in matmuls if innermost(m) == "lm_head_ce"]
     assert len(head) == 3 and not any("rematted_computation" in m for m in head), head
-    assert (ce_after["grad_in_forward"] - ce_before["grad_in_forward"],
-            ce_after["forward_only"] - ce_before["forward_only"]) == (1, 0)
+    assert {k: ce_after[k] - ce_before[k] for k in ce_after} == {
+        "grad_in_forward": 1, "forward_only": 0, "softmax_grad_kernel": 1, "softmax_grad_xla": 0}
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes > 0, "the state is not donated"
     step_hbm_gib = (ma.argument_size_in_bytes + ma.temp_size_in_bytes) / 2**30
     assert step_hbm_gib < 13.5, f"the cell's step needs {step_hbm_gib:.3f} GiB"
+
+
+CE_CELLS = {  # a chunk's rows, the vocabulary, logit_scale, z_weight
+    "phi4-mini-flash-l6": (2048, 200064, None, 0.0),
+    "mistral-7b-v0_3-l4": (2048, 32768, None, 0.0),
+    "xing4_0-29b-a4b-ep8": (2048, 16384, None, 0.0),
+    "scaled_with_z_loss": (2048, 32768, 0.5, 1e-4),
+}
+
+
+@pytest.mark.parametrize("cell", list(CE_CELLS))
+def test_ce_softmax_grad_compiles_for_v5e_at_the_cells_vocabularies(cell, v5e, compiled_kernels):
+    """The kernel between the head's matmuls (ops/fused_ce.py) at a chunk of
+    each cell that takes it: whole rows of the vocabulary in VMEM under the
+    limit the call asks for (16 rows of 200,064 are 12.8 MB of logits)."""
+    rows, vocab, logit_scale, z_weight = CE_CELLS[cell]
+    block = fused_ce.softmax_grad_rows(rows, vocab, jnp.bfloat16)
+    assert block and fused_ce._block_vmem_bytes(block, vocab, 2) <= fused_ce._VMEM_BUDGET
+    compiled = jax.jit(lambda x, t, m: fused_ce._softmax_grad_kernel(
+        x, t, m, logit_scale, z_weight, jnp.bfloat16)).lower(
+        _sds((rows, vocab), jnp.float32, v5e), _sds((rows,), jnp.int32, v5e),
+        _sds((rows,), jnp.float32, v5e)).compile()
+    assert "ce_softmax_grad" in compiled.as_text()
+
+
+def test_head_under_fsdp_mesh_compiles_for_v5e_with_xlas_chain(v5e_devices, compiled_kernels):
+    """GSPMD cannot partition a Mosaic kernel: a head whose rows a mesh shards
+    (fsdp = 4, 8 x 2,048 rows at Mistral's vocabulary) has to compile for the
+    chip, which it does because the walk takes XLA's chain there
+    (ops/fused_ce.py ``_left_to_gspmd``)."""
+    from mlx_cuda_distributed_pretraining_tpu.parallel.context import use_mesh
+
+    mesh = Mesh(np.array(v5e_devices), ("fsdp",))
+    rows, whole = NamedSharding(mesh, P("fsdp")), NamedSharding(mesh, P())
+    operands = (_sds((8, 2048, 1024), jnp.bfloat16, rows), _sds((32768, 1024), jnp.bfloat16, whole),
+                _sds((8, 2048), jnp.int32, rows), _sds((8, 2048), jnp.float32, rows))
+    before = fused_ce.plan_counts()
+    with use_mesh(mesh):
+        hlo = jax.jit(jax.grad(fused_ce.fused_cross_entropy, argnums=(0, 1))).lower(
+            *operands).compile().as_text()
+    after = fused_ce.plan_counts()
+    assert "tpu_custom_call" not in hlo
+    assert {k: after[k] - before[k] for k in after} == {
+        "grad_in_forward": 1, "forward_only": 0, "softmax_grad_kernel": 0, "softmax_grad_xla": 1}
 
 
 def test_two_kinds_of_attention_core_compile_for_v5e_at_the_cells_length(v5e, compiled_kernels):
