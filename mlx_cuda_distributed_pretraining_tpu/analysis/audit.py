@@ -172,8 +172,6 @@ def build_programs(config_path: str,
     vocab, loss closure, optimizer) without allocating a single parameter:
     params come from ``jax.eval_shape`` over the real initializer.
     """
-    import inspect
-
     import jax
     import jax.numpy as jnp
     import jax.tree_util as jtu
@@ -231,14 +229,10 @@ def build_programs(config_path: str,
     scan_layers = bool(getattr(cfg.system, "scan_layers", False))
     overlap = bool(getattr(cfg.system, "overlap_gather", False))
     z_loss = float(cfg.training.hyperparameters.get("z_loss") or 0.0)
-    moe_experts = (
-        args.num_local_experts
-        if (args.is_moe and hasattr(arch, "loss_fn")
-            and "with_moe_stats"
-            in inspect.signature(arch.loss_fn).parameters) else 0)
+    # the keywords the trainer passes (train/trainer.py): every loss_fn takes them
+    moe_experts = args.num_local_experts if args.is_moe else 0
     _stats_kw = {"with_moe_stats": True} if moe_experts else {}
-    if (overlap and hasattr(arch, "loss_fn")
-            and "overlap" in inspect.signature(arch.loss_fn).parameters):
+    if overlap:
         _stats_kw = {**_stats_kw, "overlap": True}
 
     def loss_fn(params, batch):

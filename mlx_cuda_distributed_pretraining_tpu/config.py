@@ -72,7 +72,7 @@ class ModelConfig:
     # layers' d_state, d_conv, expand, dt_rank.
     ssm: Dict[str, Any] = field(default_factory=dict)
     # Named rematerialization policy: "none" | "dots" | "full" |
-    # "save_attn" (models/llama.py REMAT_POLICIES — save_attn keeps the
+    # "save_attn" (models/stack.py REMAT_POLICIES — save_attn keeps the
     # checkpoint_name-tagged attention activations and replays only the
     # cheap FFN elementwise work). Takes precedence over the legacy
     # system.remat / system.gradient_checkpointing knobs when set.

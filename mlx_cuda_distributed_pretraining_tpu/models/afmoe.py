@@ -31,19 +31,17 @@ side is another allocator).
 
 from __future__ import annotations
 
-import collections
 import math
-import threading
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ..ops import fused_ce
+from ..ops import attention as attention_ops
 from . import moe as moe_lib
-from .llama import (apply_rope, mlp_block, normalize_remat, remat_checkpoint_for_overlap,
-                    rms_norm, rope_cos_sin)
+from . import stack
+from .llama import apply_rope, mlp_block, rms_norm, rope_cos_sin
 from .registry import Architecture, register
 
 Params = Dict[str, Any]
@@ -123,34 +121,6 @@ class AfmoeArgs:
         )
 
 
-# -- what was traced --------------------------------------------------------------
-# Layers traced by kind, and for each kind the path ``flash_plan`` gives each of the
-# three kernels at the traced shapes (``window_fwd_resident`` ...; ``*_simple`` where
-# the model runs without the kernels). Counts traces, as the other tallies do: a
-# scanned stack counts each kind once.
-_plan_counts: Dict[str, int] = collections.Counter()
-_plan_counts_lock = threading.Lock()
-
-
-def _count_layer(kind: str, q: jnp.ndarray, flash: bool) -> None:
-    keys = [f"{kind}_layers"]
-    if flash:
-        from ..ops.flash_attention import flash_plan
-
-        S, D = q.shape[1], q.shape[3]
-        keys += [f"{kind}_{k[len('flash_'):]}_{flash_plan(S, S, D, q.dtype, kernel=k).path}"
-                 for k in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")]
-    else:
-        keys.append(f"{kind}_simple")
-    with _plan_counts_lock:
-        _plan_counts.update(keys)
-
-
-def plan_counts() -> Dict[str, int]:
-    with _plan_counts_lock:
-        return dict(_plan_counts)
-
-
 # -- init ---------------------------------------------------------------------
 def init_params(rng: jax.Array, args: AfmoeArgs, dtype=jnp.float32) -> Params:
     """normal(0.02) projections, residual outputs scaled by 1/sqrt(2 * layers),
@@ -202,27 +172,14 @@ def attention_core(q, k, v, positions, args: AfmoeArgs, layer_type: str):
     """One kind's core on head-normed ``q [B, S, H, D]``, ``k``, ``v [B, S, G,
     D]``: a sliding layer rotates q and k and attends inside its window, a full
     layer attends causally as they are."""
-    from ..ops import masks as masks_lib
-
-    sliding = layer_type == SLIDING
-    flash = args.attention_type == "flash"
-    _count_layer(_KIND[layer_type], q, flash)
-    if sliding:
+    mask = {}
+    if layer_type == SLIDING:
+        mask = dict(mask_type="sliding_window", window_size=args.sliding_window)
         with jax.named_scope("attn_qkv"):
             cos, sin = rope_cos_sin(positions, args.head_dim, args.rope_theta)
             q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-    kind_scope = jax.named_scope("attn_window") if sliding else jax.named_scope("attn_global")
-    with kind_scope, jax.named_scope("attn_core"):
-        if flash:
-            from ..ops.flash_attention import flash_attention
-
-            mask = dict(mask_type="sliding_window", window_size=args.sliding_window) if sliding \
-                else dict(mask_type="causal")
-            return flash_attention(q, k, v, precision=args.matmul_precision, **mask)
-        from ..ops.attention import reference_attention
-
-        return reference_attention(q, k, v, mask_mod=masks_lib.sliding_window(args.sliding_window)
-                                   if sliding else masks_lib.causal())
+    return attention_ops.attention_core(q, k, v, args.attention_type, kind=_KIND[layer_type],
+                                        precision=args.matmul_precision, **mask)
 
 
 def gated_attention(p: Params, x: jnp.ndarray, args: AfmoeArgs, positions, sliding) -> jnp.ndarray:
@@ -292,46 +249,23 @@ def block(p: Params, x: jnp.ndarray, positions, args: AfmoeArgs, routed: bool, s
             return x + y, stats
 
 
-def _cast(tree, dtype):
-    with jax.named_scope("layer"):  # a layer's cast weights are the layer's cost
-        return jax.tree_util.tree_map(lambda a: a.astype(dtype), tree)
-
-
 def hidden_states(params: Params, tokens: jnp.ndarray, args: AfmoeArgs, compute_dtype=jnp.float32,
                   remat: Optional[str] = None, scan_layers: bool = False):
     """tokens [B, S] → (final-normed hidden [B, S, C], layer-summed routing stats)."""
     positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
-    wrap = remat_checkpoint_for_overlap(normalize_remat(remat)) or (lambda f: f)
-    # A layer outside the scan casts its weights inside its rematerialised function: the
-    # backward pass casts them again, and the step does not hold the copies in between.
-    own_block = lambda routed, sliding: wrap(
-        lambda p, x: block(_cast(p, compute_dtype), x, positions, args, routed, sliding))
     sliding = [t == SLIDING for t in args.layer_types]
+    n_dense = args.num_dense_layers
     with jax.named_scope("embed"):
         x = params["tok_embeddings"]["weight"][tokens]
         if args.mup_enabled:
             x = x * math.sqrt(args.hidden_size)
         x = x.astype(compute_dtype)
-    n_dense = args.num_dense_layers
-    for layer, s in zip(params["dense_layers"], sliding):
-        x, _ = own_block(False, s)(layer, x)
-    stats = moe_lib.zero_stats(args.n_routed_experts)
-    if scan_layers:
-        kinds = sliding[n_dense:]
-        # one kind alone needs no flag: its core is traced as a plain call
-        flags = jnp.asarray(kinds) if len(set(kinds)) > 1 else None
-        with jax.named_scope("layer"):  # the scan's stacking and slicing too
-            stacked = jax.tree_util.tree_map(
-                lambda *ls: jnp.stack(ls), *(_cast(l, compute_dtype) for l in params["layers"]))
-            x, ys = jax.lax.scan(
-                wrap(lambda x, lf: block(lf[0], x, positions, args, True,
-                                         kinds[0] if lf[1] is None else lf[1])),
-                x, (stacked, flags))
-        stats = {k: ys[k].sum(axis=0) for k in stats}
-    else:
-        for layer, s in zip(params["layers"], sliding[n_dense:]):
-            x, one = own_block(True, s)(layer, x)
-            stats = {k: stats[k] + one[k] for k in stats}
+    x, _ = stack.run_layers(lambda p, x, s: block(p, x, positions, args, False, s), x,
+                            params["dense_layers"], compute_dtype, remat, flags=sliding[:n_dense])
+    x, stats = stack.run_layers(lambda p, x, s: block(p, x, positions, args, True, s), x,
+                                params["layers"], compute_dtype, remat, scan=scan_layers,
+                                flags=sliding[n_dense:],
+                                zero=moe_lib.zero_stats(args.n_routed_experts))
     with jax.named_scope("final_norm"):
         return rms_norm(x, params["norm"]["weight"], args.rms_norm_eps), stats
 
@@ -343,37 +277,23 @@ def forward(params: Params, tokens: jnp.ndarray, args: AfmoeArgs, cache=None, st
     if cache is not None:
         raise NotImplementedError("afmoe has no cached decode: no cache for window and full layers")
     h, _ = hidden_states(params, tokens, args, compute_dtype, remat, scan_layers)
-    with jax.named_scope("lm_head_ce"):
-        return jnp.einsum("bsc,cv->bsv", h, params["output"]["weight"].astype(compute_dtype),
-                          preferred_element_type=jnp.float32), None
+    return stack.head_logits(h, params["output"]["weight"], 1, compute_dtype), None
 
 
 def loss_fn(params: Params, batch: Dict[str, jnp.ndarray], args: AfmoeArgs,
             compute_dtype=jnp.float32, remat: Optional[str] = None, remat_ratio: float = 1.0,
             include_aux: bool = True, ce_chunk: int = -1, scan_layers: bool = False,
-            z_loss_weight: float = 0.0, with_moe_stats: bool = False):
+            z_loss_weight: float = 0.0, with_moe_stats: bool = False, overlap: bool = False):
     """``(loss, token_count)``: masked mean cross-entropy through the fused CE;
     no auxiliary term (the published ``load_balance_coeff`` belongs to the
     bias's update rule). ``with_moe_stats`` returns ``(loss, (count, stats))``
     with the routing statistics summed over the routed layers, which are
     always rematerialised whole (``remat_ratio`` is not split here)."""
-    del remat_ratio, include_aux
-    targets, mask = batch["targets"], batch["mask"].astype(jnp.float32)
-    B, S = targets.shape
-    if ce_chunk < 0:
-        ce_chunk = fused_ce.auto_chunk(B, S, args.vocab_size) or 2048
+    del remat_ratio, include_aux, overlap  # overlap: the llama stack's fsdp schedule
     h, stats = hidden_states(params, batch["inputs"], args, compute_dtype, remat, scan_layers)
-    with jax.named_scope("lm_head_ce"):
-        loss = fused_ce.fused_cross_entropy(
-            h, params["output"]["weight"].astype(compute_dtype).T, targets,
-            mask / jnp.maximum(mask.sum(), 1.0), chunk=ce_chunk, z_weight=z_loss_weight)
-    return loss, ((mask.sum(), stats) if with_moe_stats else mask.sum())
-
-
-def band_positions(seq_len: int, window: int) -> int:
-    """(query, key) pairs a sliding layer attends to in one sequence."""
-    w = min(window, seq_len)
-    return w * (w + 1) // 2 + (seq_len - w) * w
+    loss, count = stack.masked_ce(h, params["output"]["weight"], 1, batch, args.vocab_size,
+                                  ce_chunk, z_loss_weight, compute_dtype)
+    return loss, ((count, stats) if with_moe_stats else count)
 
 
 def matmul_params_per_token(args: AfmoeArgs) -> int:
@@ -393,7 +313,7 @@ def flops_per_token(args: AfmoeArgs, seq_len: int) -> float:
     """Training FLOPs a token requires: 6 a multiplied weight, and each layer's
     attention under its own mask: ``12 H D`` a (query, key) pair, forward plus
     twice backward, over the pairs its mask admits."""
-    pairs = sum(band_positions(seq_len, args.sliding_window) if t == SLIDING
+    pairs = sum(stack.band_positions(seq_len, args.sliding_window) if t == SLIDING
                 else seq_len * (seq_len + 1) // 2 for t in args.layer_types)
     return 6.0 * matmul_params_per_token(args) \
         + 12.0 * args.num_heads * args.head_dim * pairs / seq_len
@@ -402,4 +322,4 @@ def flops_per_token(args: AfmoeArgs, seq_len: int) -> float:
 register(Architecture("afmoe", AfmoeArgs, init_params, forward, loss_fn,
                       flops_per_token=flops_per_token,
                       plans={"attn_plan": ("attention layers (traced, by kind and kernel path)",
-                                           plan_counts)}))
+                                           attention_ops.core_counts)}))

@@ -19,9 +19,7 @@ TPU-first:
 
 from __future__ import annotations
 
-import dataclasses
 from dataclasses import dataclass
-from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -30,84 +28,10 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import fused_ce
 from ..ops import masks as masks_lib
-from ..ops.attention import reference_attention
+from ..ops.attention import attention_core, named_mask_mod, reference_attention
+from .stack import cast_layer, layer_checkpoint, normalize_remat
 
 Params = Dict[str, Any]
-
-# -- named remat policies ----------------------------------------------------
-# Activation sites are tagged with jax.ad_checkpoint.checkpoint_name so a
-# policy trades exactly the FLOPs we choose instead of blanket replay:
-#   "qkv"      — q/k/v projections (pre-RoPE)
-#   "attn_out" — the attention output (flash/flex/ring/reference), pre-wo
-#   "ffn_up"   — silu(gate) * up, the SwiGLU elementwise product
-#   "ffn_down" — the MLP down-projection output
-# REMAT_POLICIES maps model.remat_policy names to what the backward pass
-# may keep; anything unnamed is recomputed.
-SAVE_ATTN_NAMES = ("qkv", "attn_out")
-REMAT_POLICIES = ("none", "dots", "full", "save_attn")
-
-
-def normalize_remat(remat: Optional[str]) -> Optional[str]:
-    """"none"/"" → None; unknown names raise (a typo'd policy must not
-    silently train without remat)."""
-    if remat is None or remat == "":
-        return None
-    name = str(remat).lower()
-    if name == "none":
-        return None
-    if name not in REMAT_POLICIES:
-        raise ValueError(
-            f"unknown remat policy {remat!r} (expected one of "
-            f"{REMAT_POLICIES})")
-    return name
-
-
-def remat_wrap(remat: Optional[str]):
-    """Per-layer ``jax.checkpoint`` wrapper for a named policy, or None.
-
-    - "full": replay everything (minimum memory, maximum recompute);
-    - "dots": keep matmul outputs (checkpoint_dots_with_no_batch_dims);
-    - "save_attn": keep only the tagged attention activations (qkv +
-      attention output) — the backward never replays the O(S²) attention
-      kernel, only the cheap FFN/elementwise work.
-    """
-    remat = normalize_remat(remat)
-    if remat is None:
-        return None
-    if remat == "full":
-        return partial(jax.checkpoint, static_argnums=(2, 5, 6))
-    if remat == "dots":
-        return partial(
-            jax.checkpoint,
-            policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
-            static_argnums=(2, 5, 6))
-    return partial(
-        jax.checkpoint,
-        policy=jax.checkpoint_policies.save_only_these_names(
-            *SAVE_ATTN_NAMES),
-        static_argnums=(2, 5, 6))
-
-
-def remat_checkpoint_for_overlap(remat: Optional[str]):
-    """``jax.checkpoint`` wrapper for the overlap path's per-layer
-    ``(param_shards, x, *consts)`` function — same named policies as
-    :func:`remat_wrap` but no static_argnums (the static config is closed
-    over), so the checkpoint encloses the param gather and the backward
-    re-gathers shards instead of keeping full per-layer params alive."""
-    remat = normalize_remat(remat)
-    if remat is None:
-        return None
-    if remat == "full":
-        return jax.checkpoint
-    if remat == "dots":
-        return partial(
-            jax.checkpoint,
-            policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
-    return partial(
-        jax.checkpoint,
-        policy=jax.checkpoint_policies.save_only_these_names(
-            *SAVE_ATTN_NAMES))
-
 
 @dataclass(frozen=True)
 class LlamaArgs:
@@ -352,11 +276,7 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray, traditional: 
 
 
 def build_mask_mod(args: LlamaArgs) -> masks_lib.MaskMod:
-    if args.mask_type == "sliding_window":
-        return masks_lib.sliding_window(args.window_size)
-    if args.mask_type == "prefix_lm":
-        return masks_lib.prefix_lm(args.prefix_len)
-    return masks_lib.causal()
+    return named_mask_mod(args.mask_type, args.window_size, args.prefix_len)
 
 
 def build_score_mod(args: LlamaArgs, head: Optional[int] = None):
@@ -407,18 +327,24 @@ def attention_block(
         q = apply_rope(q, cos, sin, args.rope_traditional)
         k = apply_rope(k, cos, sin, args.rope_traditional)
 
-    with jax.named_scope("attn_core"):
-        out, new_cache = _attend(q, k, v, args, positions, cache,
-                                 attn_impl, attend_len)
+    impl = attn_impl or args.attention_type
+    new_cache = None
+    if cache is None and args.score_mod_type is None and impl not in ("ring", "flex"):
+        out = attention_core(q, k, v, impl, mask_type=args.mask_type, window_size=args.window_size,
+                             prefix_len=args.prefix_len,
+                             precision=getattr(args, "matmul_precision", None))
+    else:
+        with jax.named_scope("attn_core"):
+            out, new_cache = _attend(q, k, v, args, positions, cache, impl, attend_len)
     with jax.named_scope("attn_out"):
         out = checkpoint_name(out.reshape(B, S, Hq * Dh), "attn_out")
         return _linear(out, p["wo"]), new_cache
 
 
-def _attend(q, k, v, args, positions, cache, attn_impl, attend_len):
-    """The attention proper on rotated q/k/v: cached decode (fp or int8
-    buffers) or the training-path dispatch flash / ring / flex / reference.
-    Returns ``(out [B, S, Hq, Dh], new_cache | None)``."""
+def _attend(q, k, v, args, positions, cache, impl, attend_len):
+    """The attention proper on rotated q/k/v where ``attention_core`` does not
+    run it: cached decode (fp or int8 buffers), ring, flex, or the reference
+    under a score program. Returns ``(out [B, S, Hq, Dh], new_cache | None)``."""
     S = q.shape[1]
     new_cache = None
     if cache is not None and "k_q" in cache:
@@ -447,16 +373,7 @@ def _attend(q, k, v, args, positions, cache, attn_impl, attend_len):
         out = _cached_attention(q, ck[:, :L], cv[:, :L], positions, pos, S)
     else:
         mask_mod = build_mask_mod(args)
-        impl = attn_impl or args.attention_type
-        if impl == "flash" and args.score_mod_type is None:
-            from ..ops.flash_attention import flash_attention
-
-            out = flash_attention(q, k, v, mask_type=args.mask_type,
-                                  window_size=args.window_size,
-                                  prefix_len=args.prefix_len,
-                                  precision=getattr(args, "matmul_precision",
-                                                    None))
-        elif impl == "ring":
+        if impl == "ring":
             # Sequence/context parallelism: exact causal attention with KV
             # shards rotating over the sp mesh axis (ops/ring_attention.py).
             from ..ops.ring_attention import make_ring_attention
@@ -566,7 +483,7 @@ def forward(
     """tokens [B, S] int32 → (logits [B, S, V] fp32, new_cache | None).
 
     ``remat``: None | "none" | "full" | "dots" | "save_attn" — per-layer
-    ``jax.checkpoint`` with the named policy (see :data:`REMAT_POLICIES`);
+    ``jax.checkpoint`` with the named policy (see ``stack.REMAT_POLICIES``);
     ``remat_ratio`` checkpoints only the first fraction
     of layers (reference: system.gradient_checkpointing_ratio).
     ``return_aux=True`` appends the summed MoE aux loss:
@@ -597,15 +514,8 @@ def forward(
     positions = jnp.arange(S, dtype=jnp.int32) + start_pos
 
     remat = normalize_remat(remat)
-    wrap = remat_wrap(remat)
-    block = wrap(transformer_block) if wrap is not None else transformer_block
-
-    def cast(layer):
-        # int8 (quantized) leaves must stay int8 through the compute-dtype
-        # cast; a layer's cast weights are the layer's cost
-        with jax.named_scope("layer"):
-            return jax.tree_util.tree_map(
-                lambda a: a if a.dtype == jnp.int8 else a.astype(compute_dtype), layer)
+    block = layer_checkpoint(remat, static_argnums=(2, 5, 6))(transformer_block)
+    cast = lambda layer: cast_layer(layer, compute_dtype)
 
     new_cache = [] if cache is not None else None
     n_remat = int(round(args.num_layers * remat_ratio))
@@ -634,13 +544,11 @@ def forward(
                 layer, h, args, pos, None, None, attend_len)
             return h, aux
 
-        policy_wrap = None
-        if wrap is not None:
-            # Re-wrap WITHOUT static_argnums: overlap closes over the
-            # static config and checkpoints (gather ∘ block) together so
-            # the backward re-gathers shards instead of saving full
-            # per-layer params as residuals.
-            policy_wrap = remat_checkpoint_for_overlap(remat)
+        # Wrapped WITHOUT static_argnums: overlap closes over the static
+        # config and checkpoints (gather ∘ block) together so the backward
+        # re-gathers shards instead of saving full per-layer params as
+        # residuals.
+        policy_wrap = layer_checkpoint(remat) if remat else None
         x, aux = overlap_lib.overlapped_layer_scan(
             overlap_body, x, layers_cast, overlap_mesh,
             consts=(positions,), wrap=policy_wrap,

@@ -46,10 +46,10 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import fused_ce
+from ..ops import attention as attention_ops
 from ..ops import selective_scan as scan_lib
-from .llama import (_linear, mlp_block, normalize_remat, remat_checkpoint_for_overlap,
-                    rms_norm)
+from . import stack
+from .llama import _linear, mlp_block, rms_norm
 from .registry import Architecture, register
 
 Params = Dict[str, Any]
@@ -138,17 +138,11 @@ def lambda_init(layer: int) -> float:
 
 
 # -- what was traced --------------------------------------------------------------
-# Attention layers traced by kind with their kernels' paths, as models/afmoe.py
-# counts them; Mamba and memory-unit layers, beside what ops/selective_scan.py
-# traced. Counts traces.
-_attn_counts: Dict[str, int] = collections.Counter()
+# Mamba and memory-unit layers, beside what ops/selective_scan.py traced (the
+# attention layers by kind: ops/attention.py::core_counts). Counts traces.
 _ssm_counts: Dict[str, int] = collections.Counter()
 _plan_counts_lock = threading.Lock()
-
-
-def attn_plan_counts() -> Dict[str, int]:
-    with _plan_counts_lock:
-        return dict(_attn_counts)
+_ATTN_KIND = {"S": "window", "F": "global", "C": "cross"}  # a layer kind's name in the tally
 
 
 def ssm_plan_counts() -> Dict[str, int]:
@@ -157,22 +151,6 @@ def ssm_plan_counts() -> Dict[str, int]:
     with _plan_counts_lock:
         own = dict(_ssm_counts)
     return {**own, **{"scan_" + k: n for k, n in scan_lib.plan_counts().items()}}
-
-
-def _count_attention(kind: str, q, k, v, flash: bool) -> None:
-    name = {"S": "window", "F": "global", "C": "cross"}[kind]
-    keys = [f"{name}_layers"]
-    if flash:
-        from ..ops.flash_attention import flash_plan
-
-        S, D = q.shape[1], q.shape[3]
-        keys += [f"{name}_{kk[len('flash_'):]}_"
-                 f"{flash_plan(S, k.shape[1], D, q.dtype, kernel=kk, Dv=v.shape[3]).path}"
-                 for kk in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")]
-    else:
-        keys.append(f"{name}_simple")
-    with _plan_counts_lock:
-        _attn_counts.update(keys)
 
 
 # -- init ---------------------------------------------------------------------
@@ -278,30 +256,13 @@ def paired_kv(k: jnp.ndarray, v: jnp.ndarray):
 def diff_attention_core(q, k_st, vbar, args: SambaYArgs, kind: str):
     """``q [B, S, H, D]``, stacked keys and paired values of :func:`paired_kv` →
     the two softmax maps' outputs ``A1, A2 [B, S, H / 2, 2 D]``."""
-    from ..ops import masks as masks_lib
-
     B, S, H, D = q.shape
     q = q.reshape(B, S, H // 2, 2, D)
     q_st = jnp.concatenate([q[:, :, :, 0], q[:, :, :, 1]], axis=2)
     v_st = jnp.concatenate([vbar, vbar], axis=2)
-    flash = args.attention_type == "flash"
-    _count_attention(kind, q_st, k_st, v_st, flash)
-    window = kind == "S"
-    scope = jax.named_scope("attn_window") if window else jax.named_scope("attn_global")
-    with scope, jax.named_scope("attn_core"):
-        if flash:
-            from ..ops.flash_attention import flash_attention
-
-            mask = dict(mask_type="sliding_window", window_size=args.sliding_window) if window \
-                else dict(mask_type="causal")
-            out = flash_attention(q_st, k_st, v_st, scale=D ** -0.5,
-                                  precision=args.matmul_precision, **mask)
-        else:
-            from ..ops.attention import reference_attention
-
-            out = reference_attention(q_st, k_st, v_st, scale=D ** -0.5,
-                                      mask_mod=masks_lib.sliding_window(args.sliding_window)
-                                      if window else masks_lib.causal())
+    mask = dict(mask_type="sliding_window", window_size=args.sliding_window) if kind == "S" else {}
+    out = attention_ops.attention_core(q_st, k_st, v_st, args.attention_type, kind=_ATTN_KIND[kind],
+                                       scale=D ** -0.5, precision=args.matmul_precision, **mask)
     return out[:, :, :H // 2], out[:, :, H // 2:]
 
 
@@ -360,11 +321,6 @@ def block(p: Params, x, args: SambaYArgs, kind: str, layer: int, m=None, kv=None
             return h + y, made
 
 
-def _cast(tree, dtype):
-    with jax.named_scope("layer"):  # a layer's cast weights are the layer's cost
-        return jax.tree_util.tree_map(lambda a: a.astype(dtype), tree)
-
-
 def hidden_states(params: Params, tokens: jnp.ndarray, args: SambaYArgs, compute_dtype=jnp.float32,
                   remat: Optional[str] = None, scan_layers: bool = False):
     """tokens [B, S] → the final-normed state [B, S, C].
@@ -375,7 +331,6 @@ def hidden_states(params: Params, tokens: jnp.ndarray, args: SambaYArgs, compute
     own tree. Every layer is its own rematerialised function that casts its
     weights inside, so the step holds one layer's bf16 copies at a time."""
     del scan_layers
-    wrap = remat_checkpoint_for_overlap(normalize_remat(remat)) or (lambda f: f)
     with jax.named_scope("embed"):
         x = params["tok_embeddings"]["weight"][tokens].astype(compute_dtype)
     m = kv = None
@@ -383,8 +338,8 @@ def hidden_states(params: Params, tokens: jnp.ndarray, args: SambaYArgs, compute
         if kind in "MG":
             with _plan_counts_lock:
                 _ssm_counts["mamba_layers" if kind == "M" else "gmu_layers"] += 1
-        fn = wrap(lambda p, x, m, kv, kind=kind, i=i: block(
-            _cast(p, compute_dtype), x, args, kind, i, m, kv))
+        fn = stack.own_layer(lambda p, x, m, kv, kind=kind, i=i: block(p, x, args, kind, i, m, kv),
+                             compute_dtype, remat)
         x, made = fn(p, x, m if kind == "G" else None, kv if kind == "C" else None)
         if kind == "M" and made is not None:
             m = made
@@ -401,15 +356,13 @@ def forward(params: Params, tokens: jnp.ndarray, args: SambaYArgs, cache=None, s
     if cache is not None:
         raise NotImplementedError("sambay has no cached decode: no recurrent-state cache")
     h = hidden_states(params, tokens, args, compute_dtype, remat, scan_layers)
-    with jax.named_scope("lm_head_ce"):
-        return jnp.einsum("bsc,vc->bsv", h, params["tok_embeddings"]["weight"].astype(compute_dtype),
-                          preferred_element_type=jnp.float32), None
+    return stack.head_logits(h, params["tok_embeddings"]["weight"], 0, compute_dtype), None
 
 
 def loss_fn(params: Params, batch: Dict[str, jnp.ndarray], args: SambaYArgs,
             compute_dtype=jnp.float32, remat: Optional[str] = None, remat_ratio: float = 1.0,
             include_aux: bool = True, ce_chunk: int = -1, scan_layers: bool = False,
-            z_loss_weight: float = 0.0):
+            z_loss_weight: float = 0.0, overlap: bool = False):
     """``(loss, token_count)``: masked mean cross-entropy through the fused CE
     with the embedding as the head; every layer is rematerialised whole
     (``remat_ratio`` is not split here). The CE's chunk stays the 2,048 rows of
@@ -417,23 +370,11 @@ def loss_fn(params: Params, batch: Dict[str, jnp.ndarray], args: SambaYArgs,
     logits an array): the walk reads and writes its float32 ``dW`` (2.05 GB)
     once a chunk, so 512 rows cost 105 ms a step more on a v5e and saved 1.06
     GiB the step did not need (PERF.md section 6, PR 43)."""
-    del remat_ratio, include_aux
-    targets, mask = batch["targets"], batch["mask"].astype(jnp.float32)
-    B, S = targets.shape
-    if ce_chunk <= 0:
-        ce_chunk = fused_ce.auto_chunk(B, S, args.vocab_size) or 2048
+    del remat_ratio, include_aux, overlap  # overlap: the llama stack's fsdp schedule
     h = hidden_states(params, batch["inputs"], args, compute_dtype, remat, scan_layers)
-    with jax.named_scope("lm_head_ce"):
-        loss = fused_ce.fused_cross_entropy(
-            h, params["tok_embeddings"]["weight"].astype(compute_dtype), targets,
-            mask / jnp.maximum(mask.sum(), 1.0), chunk=ce_chunk, z_weight=z_loss_weight)
-    return loss, mask.sum()
-
-
-def band_positions(seq_len: int, window: int) -> int:
-    """(query, key) pairs a window layer attends to in one sequence."""
-    w = min(window, seq_len)
-    return w * (w + 1) // 2 + (seq_len - w) * w
+    # 0 (the trainer's "no fused CE" on an sp x tp mesh) has no unfused form here: automatic
+    return stack.masked_ce(h, params["tok_embeddings"]["weight"], 0, batch, args.vocab_size,
+                           ce_chunk or -1, z_loss_weight, compute_dtype)
 
 
 def matmul_params_per_token(args: SambaYArgs) -> int:
@@ -458,7 +399,7 @@ def flops_per_token(args: SambaYArgs, seq_len: int) -> float:
     key) pair forward, three times that with the backward. The scan's
     elementwise work (``d_inner * d_state`` state updates a token a layer) is
     VPU work and is left out."""
-    pairs = sum(band_positions(seq_len, args.sliding_window) if k == "S"
+    pairs = sum(stack.band_positions(seq_len, args.sliding_window) if k == "S"
                 else seq_len * (seq_len + 1) // 2 for k in args.layer_kinds if k in "SFC")
     return 6.0 * matmul_params_per_token(args) \
         + 3.0 * args.num_heads * 2 * (3 * args.head_dim) * pairs / seq_len
@@ -467,6 +408,6 @@ def flops_per_token(args: SambaYArgs, seq_len: int) -> float:
 register(Architecture("sambay", SambaYArgs, init_params, forward, loss_fn,
                       flops_per_token=flops_per_token,
                       plans={"attn_plan": ("attention layers (traced, by kind and kernel path)",
-                                           attn_plan_counts),
+                                           attention_ops.core_counts),
                              "ssm_plan": ("state-space layers (traced; scans by path and chunk)",
                                           ssm_plan_counts)}))

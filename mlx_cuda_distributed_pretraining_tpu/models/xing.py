@@ -41,10 +41,10 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import fused_ce
+from ..ops.attention import attention_core
 from . import moe as moe_lib
-from .llama import (apply_rope, mlp_block, normalize_remat, remat_checkpoint_for_overlap,
-                    rms_norm)
+from . import stack
+from .llama import apply_rope, mlp_block, rms_norm
 from .registry import Architecture, register
 
 Params = Dict[str, Any]
@@ -313,18 +313,8 @@ def latent_attention(p: Params, x: jnp.ndarray, args: XingArgs, positions) -> jn
         k_rope = apply_rope(kv_a[..., None, args.kv_lora_rank:], cos, sin)  # one key for all heads
         k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_rope, (B, S, H, dr))], axis=-1)
         v = kv[..., dn:]
-    with jax.named_scope("attn_core"):
-        if args.attention_type == "flash":
-            from ..ops.flash_attention import flash_attention
-
-            out = flash_attention(q, k, v, scale=softmax_scale(args),
-                                  precision=args.matmul_precision)
-        else:
-            from ..ops import masks as masks_lib
-            from ..ops.attention import reference_attention
-
-            out = reference_attention(q, k, v, mask_mod=masks_lib.causal(),
-                                      scale=softmax_scale(args))
+    out = attention_core(q, k, v, args.attention_type, scale=softmax_scale(args),
+                         precision=args.matmul_precision)
     with jax.named_scope("attn_out"):
         return out.reshape(B, S, H * dv) @ p["wo"]["weight"]
 
@@ -385,11 +375,6 @@ def block(p: Params, X: jnp.ndarray, positions, args: XingArgs, routed: bool):
             return hc_write(X, y, h_post, h_res), None
 
 
-def _cast(tree, dtype):
-    with jax.named_scope("layer"):  # a layer's cast weights are the layer's cost
-        return jax.tree_util.tree_map(lambda a: a.astype(dtype), tree)
-
-
 def _streams(x: jnp.ndarray, n: int) -> jnp.ndarray:
     return jnp.broadcast_to(x[None], (n,) + x.shape)
 
@@ -400,36 +385,17 @@ def _merge_streams(X: jnp.ndarray, gain: jnp.ndarray, args: XingArgs) -> jnp.nda
         return rms_norm(X.astype(jnp.float32).sum(axis=0).astype(X.dtype), gain, args.rms_norm_eps)
 
 
-def _remat(remat: Optional[str]):
-    return remat_checkpoint_for_overlap(normalize_remat(remat)) or (lambda f: f)
-
-
 def hidden_states(params: Params, tokens: jnp.ndarray, args: XingArgs, compute_dtype=jnp.float32,
                   remat: Optional[str] = None, scan_layers: bool = False):
     """tokens [B, S] → (final-normed hidden [B, S, C], layer-summed routing stats)."""
-    S = tokens.shape[1]
-    positions = jnp.arange(S, dtype=jnp.int32)
-    wrap = _remat(remat)
-    # A layer outside the scan casts its weights inside its rematerialised function: the
-    # backward pass casts them again, and the step does not hold the copies in between.
-    own_block = lambda routed: wrap(
-        lambda p, X: block(_cast(p, compute_dtype), X, positions, args, routed))
+    positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
     with jax.named_scope("embed"):
         X = _streams(params["tok_embeddings"]["weight"].astype(compute_dtype)[tokens], args.hc_mult)
-    for layer in params["dense_layers"]:
-        X, _ = own_block(False)(layer, X)
-    stats = moe_lib.zero_stats(args.n_routed_experts)
-    if scan_layers:
-        with jax.named_scope("layer"):  # the scan's stacking and slicing too
-            stacked = jax.tree_util.tree_map(
-                lambda *ls: jnp.stack(ls), *(_cast(l, compute_dtype) for l in params["layers"]))
-            X, ys = jax.lax.scan(
-                wrap(lambda X, l: block(l, X, positions, args, True)), X, stacked)
-        stats = {k: ys[k].sum(axis=0) for k in stats}
-    else:
-        for layer in params["layers"]:
-            X, s = own_block(True)(layer, X)
-            stats = {k: stats[k] + s[k] for k in stats}
+    X, _ = stack.run_layers(lambda p, X, _: block(p, X, positions, args, False), X,
+                            params["dense_layers"], compute_dtype, remat)
+    X, stats = stack.run_layers(lambda p, X, _: block(p, X, positions, args, True), X,
+                                params["layers"], compute_dtype, remat, scan=scan_layers,
+                                zero=moe_lib.zero_stats(args.n_routed_experts))
     return _merge_streams(X, params["norm"]["weight"], args), stats
 
 
@@ -438,7 +404,7 @@ def mtp_hidden(params: Params, hidden: jnp.ndarray, next_tokens: jnp.ndarray, ar
     """The MTP module's final-normed state: position ``i`` sees the main
     model's ``hidden[i]`` and the embedding of ``next_tokens[i] = t_{i+1}``,
     and predicts ``t_{i+2}``."""
-    p = _cast({k: v for k, v in params["mtp"].items() if k != "layer"}, compute_dtype)
+    p = stack.cast_layer({k: v for k, v in params["mtp"].items() if k != "layer"}, compute_dtype)
     positions = jnp.arange(hidden.shape[1], dtype=jnp.int32)
     with jax.named_scope("embed"):
         emb = params["tok_embeddings"]["weight"].astype(compute_dtype)[next_tokens]
@@ -448,9 +414,8 @@ def mtp_hidden(params: Params, hidden: jnp.ndarray, next_tokens: jnp.ndarray, ar
             rms_norm(emb, p["enorm"]["weight"], args.rms_norm_eps)], axis=-1)
     with jax.named_scope("layer"):
         x = both @ p["eh_proj"]["weight"]
-    X, stats = _remat(remat)(  # cast inside, as hidden_states' layers outside the scan
-        lambda l, X: block(_cast(l, compute_dtype), X, positions, args, True))(
-            params["mtp"]["layer"], _streams(x, args.hc_mult))
+    X, stats = stack.own_layer(lambda l, X: block(l, X, positions, args, True), compute_dtype, remat)(
+        params["mtp"]["layer"], _streams(x, args.hc_mult))
     return _merge_streams(X, p["norm"]["weight"], args), stats
 
 
@@ -469,15 +434,13 @@ def forward(params: Params, tokens: jnp.ndarray, args: XingArgs, cache=None, sta
     if cache is not None:
         raise NotImplementedError("xing_mla_moe has no cached decode: a latent KV cache is not built")
     h, _ = hidden_states(params, tokens, args, compute_dtype, remat, scan_layers)
-    with jax.named_scope("lm_head_ce"):
-        return jnp.einsum("bsc,cv->bsv", h, params["output"]["weight"].astype(compute_dtype),
-                          preferred_element_type=jnp.float32), None
+    return stack.head_logits(h, params["output"]["weight"], 1, compute_dtype), None
 
 
 def loss_fn(params: Params, batch: Dict[str, jnp.ndarray], args: XingArgs,
             compute_dtype=jnp.float32, remat: Optional[str] = None, remat_ratio: float = 1.0,
             include_aux: bool = True, ce_chunk: int = -1, scan_layers: bool = False,
-            z_loss_weight: float = 0.0, with_moe_stats: bool = False):
+            z_loss_weight: float = 0.0, with_moe_stats: bool = False, overlap: bool = False):
     """``(loss, token_count)``: masked mean CE of the main head, plus (training,
     ``include_aux``) ``mtp_loss_weight`` times the MTP head's over the positions
     that have a ``t_{i+2}``. ``with_moe_stats`` returns ``(loss, (count,
@@ -485,21 +448,14 @@ def loss_fn(params: Params, batch: Dict[str, jnp.ndarray], args: XingArgs,
     MTP module's too) and the two loss terms. Both heads run the fused CE on
     one output weight; the routed layers are always rematerialised whole
     (``remat_ratio`` is not split here)."""
-    del remat_ratio
+    del remat_ratio, overlap  # overlap: the llama stack's fsdp schedule
     targets, mask = batch["targets"], batch["mask"].astype(jnp.float32)
-    B, S = targets.shape
-    if ce_chunk < 0:
-        ce_chunk = fused_ce.auto_chunk(B, S, args.vocab_size) or 2048
+    ce_chunk = stack.ce_chunk_rows(ce_chunk, *targets.shape, args.vocab_size)
     h, stats = hidden_states(params, batch["inputs"], args, compute_dtype, remat, scan_layers)
-    with jax.named_scope("lm_head_ce"):
-        w_vd = params["output"]["weight"].astype(compute_dtype).T
-
-    def head(hidden, tgt, weights, w_vd=w_vd):
-        with jax.named_scope("lm_head_ce"):
-            return fused_ce.fused_cross_entropy(hidden, w_vd, tgt, weights, chunk=ce_chunk,
-                                                z_weight=z_loss_weight)
-
-    mean_of = lambda msk: msk / jnp.maximum(msk.sum(), 1.0)
+    w_vd = stack.head_weight(params["output"]["weight"], 1, compute_dtype)
+    head = lambda hidden, tgt, weights, w_vd=w_vd: stack.head_ce(
+        hidden, w_vd, tgt, weights, ce_chunk, z_loss_weight)
+    mean_of = stack.mean_weights
     if not (args.mtp_layers and include_aux):
         main = loss = head(h, targets, mean_of(mask))
         mtp = jnp.zeros((), jnp.float32)

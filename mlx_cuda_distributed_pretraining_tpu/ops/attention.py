@@ -9,14 +9,23 @@ score mods applied on index lattices.
 Layout convention throughout the framework: ``q [B, Sq, Hq, D]``,
 ``k/v [B, Skv, Hkv, D]`` with Hq a multiple of Hkv; v's head size may
 differ from q's and k's (latent attention), and is then the output's.
+
+:func:`attention_core` is where a model's training path chooses between this
+and the flash kernels (ops/flash_attention.py): one mask description, the
+scopes a profile's readers key on, and the tally of what was traced.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+import collections
+import contextlib
+import threading
+from typing import Dict, Optional
 
+import jax
 import jax.numpy as jnp
 
+from . import masks as masks_lib
 from .masks import NEG_INF, MaskMod, ScoreMod, materialize_mask
 
 
@@ -67,3 +76,74 @@ def reference_attention(
 
     out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, vh)
     return out.reshape(B, Hq, Sq, v.shape[-1]).transpose(0, 2, 1, 3)  # v may be narrower than q and k
+
+
+# -- which kernel runs a core -------------------------------------------------------
+def named_mask_mod(mask_type: str = "causal", window_size: int = 512,
+                   prefix_len: int = 0) -> MaskMod:
+    """The ``masks`` closure of a mask said as the flash kernels take it."""
+    if mask_type == "sliding_window":
+        return masks_lib.sliding_window(window_size)
+    if mask_type == "prefix_lm":
+        return masks_lib.prefix_lm(prefix_len)
+    return masks_lib.causal()
+
+
+# A kind of layer's name in the tally -> the scope its core runs under, each name
+# spelled out (tests/test_scopes.py reads the vocabulary from the source): a cross
+# layer reads the global layer's keys and values under the global layer's mask.
+_KIND_SCOPE = {None: contextlib.nullcontext,
+               "window": lambda: jax.named_scope("attn_window"),
+               "global": lambda: jax.named_scope("attn_global"),
+               "cross": lambda: jax.named_scope("attn_global")}
+_FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+# Layers traced by kind, and for each kind the path ``flash_plan`` gives each of the
+# three kernels at the traced shapes (``window_fwd_resident`` ...; ``*_simple`` where
+# the model runs without the kernels). Counts traces, as the other tallies do: a
+# scanned stack counts each kind once.
+_core_counts: Dict[str, int] = collections.Counter()
+_core_counts_lock = threading.Lock()
+
+
+def core_counts() -> Dict[str, int]:
+    with _core_counts_lock:
+        return dict(_core_counts)
+
+
+def _count_core(kind: str, q, k, v, flash: bool) -> None:
+    keys = [f"{kind}_layers"]
+    if flash:
+        from .flash_attention import flash_plan
+
+        keys += [f"{kind}_{kernel[len('flash_'):]}_"
+                 f"{flash_plan(q.shape[1], k.shape[1], q.shape[3], q.dtype, kernel=kernel, Dv=v.shape[3]).path}"
+                 for kernel in _FLASH_KERNELS]
+    else:
+        keys.append(f"{kind}_simple")
+    with _core_counts_lock:
+        _core_counts.update(keys)
+
+
+def attention_core(q, k, v, attention_type: str, kind: Optional[str] = None,
+                   scale: Optional[float] = None, precision: Optional[str] = None,
+                   mask_type: str = "causal", window_size: int = 512, prefix_len: int = 0):
+    """One attention core of the training path, on ``q [B, S, Hq, D]``, ``k``,
+    ``v``: the flash kernels where ``attention_type`` is ``"flash"``, else
+    :func:`reference_attention`, under the mask said once, as the kernels take
+    it (``mask_type="sliding_window", window_size=w``; ``mask_type="prefix_lm",
+    prefix_len=p``; nothing: causal). ``kind``
+    (``window | global | cross``) puts the core under its kind's scope above
+    ``attn_core`` and adds the layer and its kernels' paths to
+    :func:`core_counts`; a model of one kind of layer names none."""
+    flash = attention_type == "flash"
+    if kind is not None:
+        _count_core(kind, q, k, v, flash)
+    with _KIND_SCOPE[kind](), jax.named_scope("attn_core"):
+        if flash:
+            from .flash_attention import flash_attention
+
+            return flash_attention(q, k, v, mask_type=mask_type, window_size=window_size,
+                                   prefix_len=prefix_len, scale=scale, precision=precision)
+        return reference_attention(q, k, v, scale=scale,
+                                   mask_mod=named_mask_mod(mask_type, window_size, prefix_len))

@@ -20,7 +20,7 @@ module schedules the fsdp collectives by hand, Megatron-style:
   bucketed reduce-scatter per layer as soon as that layer's param
   cotangents exist — instead of one monolithic sync after the whole
   backward. Under a remat policy the checkpoint encloses the gather
-  (models/llama.py remat_checkpoint_for_overlap), so the backward
+  (models/stack.py layer_checkpoint), so the backward
   re-gathers shards rather than keeping full per-layer params alive.
 
 Scope: pure dp×fsdp meshes, dense uniform layers, no int8 leaves

@@ -276,17 +276,11 @@ class Trainer:
         # pipeline loss threads the same stats through its tick carries
         # (make_pipeline_loss with_moe_stats), so pp and non-pp runs report
         # identical routing gauges.
-        import inspect as _inspect
-
-        self.moe_stats_experts = (
-            args.num_local_experts
-            if (args.is_moe and hasattr(arch, "loss_fn")
-                and "with_moe_stats" in
-                _inspect.signature(arch.loss_fn).parameters) else 0)
+        # Every architecture's loss_fn takes the same keywords; one whose
+        # args say is_moe returns the stats under with_moe_stats.
+        self.moe_stats_experts = args.num_local_experts if args.is_moe else 0
         _stats_kw = {"with_moe_stats": True} if self.moe_stats_experts else {}
-        _ov_kw = ({"overlap": True} if (overlap and hasattr(arch, "loss_fn")
-                  and "overlap" in
-                  _inspect.signature(arch.loss_fn).parameters) else {})
+        _ov_kw = {"overlap": True} if overlap else {}
 
         def loss_fn(params, batch):
             return arch.loss_fn(

@@ -35,7 +35,9 @@ from benchmark.traffic_kinds import train_job_afmoe as kind
 from mlx_cuda_distributed_pretraining_tpu.config import Config
 from mlx_cuda_distributed_pretraining_tpu.models import afmoe
 from mlx_cuda_distributed_pretraining_tpu.models import moe as moe_lib
+from mlx_cuda_distributed_pretraining_tpu.models import stack
 from mlx_cuda_distributed_pretraining_tpu.models.registry import resolve_architecture
+from mlx_cuda_distributed_pretraining_tpu.ops.attention import core_counts
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELL = "trinity-mini-ep8.train-seq16k"
@@ -131,9 +133,9 @@ def test_a_pattern_other_than_the_published_runs_from_layer_types_alone(tiny):
     cfg = dict(cfg, num_hidden_layers=3, layer_types=[FULL_ATT, SLIDING, FULL_ATT])
     params = ref.init_params(11, cfg)
     (want_loss,), want = _reference_step(cfg, params, batch)
-    before = afmoe.plan_counts()
+    before = core_counts()
     (loss, _), got = _program_step(params, batch, _args(cfg), True)
-    traced = {k: n - before.get(k, 0) for k, n in afmoe.plan_counts().items()}
+    traced = {k: n - before.get(k, 0) for k, n in core_counts().items()}
     assert traced["global_layers"] == 2 and traced["window_layers"] == 1   # dense; the scan's two
     np.testing.assert_allclose(float(loss), float(want_loss), rtol=2e-6)
     assert max(_leaf_gaps(got, want).values()) < 5e-4
@@ -379,7 +381,7 @@ def test_parameter_and_flop_arithmetic():
     # the band: W (W + 1) / 2 + (S - W) W pairs a head, the whole triangle where W >= S
     assert flash_window.band_positions(16384, 2048) == 2048 * 2049 // 2 + (16384 - 2048) * 2048
     assert flash_window.band_positions(128, 2048) == 128 * 129 // 2
-    assert afmoe.band_positions(16384, 2048) == flash_window.band_positions(16384, 2048)
+    assert stack.band_positions(16384, 2048) == flash_window.band_positions(16384, 2048)
     share = flash_window.band_positions(16384, 2048) / (16384 * 16385 // 2)
     assert 0.23 < share < 0.24                         # what a window call should cost of a causal one
     assert flash_window.fwd(1, 32, 16384, 128, 2048) == 4 * 32 * 128 * flash_window.band_positions(16384, 2048)
@@ -604,9 +606,9 @@ def test_the_flash_paths_are_tallied_by_kind(tiny):
     """With the kernels, ``attn_plan`` says for each kind which path
     ``flash_plan`` gives the forward and the two backward kernels."""
     cfg, _, params, batch = tiny
-    before = afmoe.plan_counts()
+    before = core_counts()
     jax.eval_shape(lambda p: afmoe.loss_fn(p, batch, _args(cfg, "flash"), scan_layers=True)[0], params)
-    traced = {k: n - before.get(k, 0) for k, n in afmoe.plan_counts().items() if n - before.get(k, 0)}
+    traced = {k: n - before.get(k, 0) for k, n in core_counts().items() if n - before.get(k, 0)}
     assert traced == {"window_layers": 2, "global_layers": 1,
                       **{f"window_{k}_resident": 2 for k in ("fwd", "bwd_dq", "bwd_dkv")},
                       **{f"global_{k}_resident": 1 for k in ("fwd", "bwd_dq", "bwd_dkv")}}

@@ -22,9 +22,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # -- the counters ------------------------------------------------------------------
 def test_step_totals_never_run_backwards():
     a = hoststats.step_totals()
-    end = time.perf_counter() + 0.02
-    while time.perf_counter() < end:
-        pass
+    _busy(0.02)   # of this thread's CPU clock: a wall's 0.02 s may hold less than half of it
     b = hoststats.step_totals()
     assert len(a) == len(hoststats.STEP_FIELDS)
     assert all(y >= x for x, y in zip(a, b))
@@ -108,54 +106,82 @@ def test_window_fields_differences_and_levels():
 
 
 # -- the records -------------------------------------------------------------------
-def _steps_of(records, walls, first=0, compiles_at=()):
+class Clock:
+    """``time`` as obs/steprecord.py and obs/trace.py read it, with a wall clock
+    that only the test moves: a step takes the seconds the test says, and a busy
+    machine adds none. Everything else (the CPU clocks) is the machine's."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def perf_counter(self):
+        return self.now
+
+    def passes(self, seconds):
+        self.now += seconds
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    """The records' ``time`` (obs/steprecord.py) on a clock the test moves."""
+    from mlx_cuda_distributed_pretraining_tpu.obs import steprecord
+
+    clock = Clock()
+    monkeypatch.setattr(steprecord, "time", clock)
+    return clock
+
+
+def _steps_of(records, clock, walls, first=0, compiles_at=()):
     """Turn the records through steps of these wall times; the closed records."""
     out, compiles = [], 0
     for i, wall in enumerate(walls, start=first + 1):
         out.append(records.turn(i, i == 1, compiles))
-        time.sleep(wall)
+        clock.passes(wall)
         records.note(dispatch_s=wall)
         compiles += i in compiles_at
     return out[1:] + [records.close(compiles)]
 
 
-def test_records_name_the_late_step_and_leave_compiles_out_of_the_median():
+def test_records_name_the_late_step_and_leave_compiles_out_of_the_median(clock):
     records = StepRecords()
-    recs = _steps_of(records, [0.08] + [0.01] * 6 + [0.08, 0.01, 0.08], compiles_at=(10,))
+    recs = _steps_of(records, clock, [0.08] + [0.01] * 6 + [0.08, 0.01, 0.08], compiles_at=(10,))
     assert recs[0]["first_dispatch"] and not recs[0]["in_median"] and "x_median" not in recs[0]
     assert all(r["in_median"] for r in recs[1:9])
     assert "x_median" not in recs[StepRecords.MEDIAN_FROM]          # five steps before it says anything
-    assert recs[7]["x_median"] > StepRecords.STALL_FACTOR          # the late step
-    assert recs[8]["x_median"] < 0.5 * recs[7]["x_median"]
+    assert recs[7]["x_median"] == 8.0 > StepRecords.STALL_FACTOR   # the late step
+    assert recs[8]["x_median"] == 1.0
     assert recs[9]["xla_compiles"] == 1 and not recs[9]["in_median"] and "x_median" not in recs[9]
     w = records.window()
     assert w["slow_step"] in (1, 8, 10) and w["step_s_max"] == w["slow"]["wall_s"] >= 0.08
-    assert 0.009 < w["step_s_med"] < 0.04
+    assert w["step_s_med"] == 0.01
     assert w["proc_cpu_s"] == pytest.approx(sum(r["proc_cpu_s"] for r in recs), abs=1e-5)
     assert w["nivcsw"] == sum(r["nivcsw"] for r in recs)
     assert records.window() == {}
 
 
-def test_a_capture_stopped_inside_the_step_is_no_stall():
+def test_a_capture_stopped_inside_the_step_is_no_stall(clock):
     records = StepRecords()
-    _steps_of(records, [0.01] * 6, first=1)
+    _steps_of(records, clock, [0.01] * 6, first=1)
     assert records.turn(8, False, 0) is None and records.close(0)["step"] == 8
     assert records.turn(9, False, 0) is None   # closed before work beside the step: none was open
-    time.sleep(0.1)
+    clock.passes(0.1)
     rec = records.close(0, side_s=0.095)   # the loop stopped a profiler inside the step
-    assert rec["side_s"] == 0.095 and rec["x_median"] < StepRecords.STALL_FACTOR
+    assert rec["side_s"] == 0.095 and rec["x_median"] == 0.5 < StepRecords.STALL_FACTOR
 
 
-def test_the_median_is_over_the_newest_steps():
+def test_the_median_is_over_the_newest_steps(clock):
     records = StepRecords()
     records.MEDIAN_OVER = 6
-    _steps_of(records, [0.02] * 6 + [0.002] * 6, first=1)
-    assert records.median() < 0.01          # the six slow steps have left it
+    _steps_of(records, clock, [0.02] * 6 + [0.002] * 6, first=1)
+    assert records.median() == pytest.approx(0.002)   # the six slow steps have left it
 
 
 # -- the loop ----------------------------------------------------------------------
 LATE_STEP, LATE_S = 9, 0.3
-EVERY_S = 0.03   # every step waits this long, so that a busy sandbox's jitter doubles none
+EVERY_S = 0.03   # every step takes this long
 
 
 def _busy(seconds):
@@ -168,7 +194,11 @@ def _busy(seconds):
 
 @pytest.fixture(scope="module", params=["sleep", "busy"])
 def late_run(request, tmp_path_factory):
-    """A tiny run whose wrapped step waits, or works, 0.3 s at one step."""
+    """A tiny run whose wrapped step takes 0.3 s more at one step, waiting or
+    working, on a wall clock the fixture moves: the records (obs/steprecord.py)
+    and the ring's phases and spans (obs/trace.py) read it. A step that works
+    does so through real seconds of this thread's CPU clock."""
+    from mlx_cuda_distributed_pretraining_tpu.obs import steprecord, trace
     from mlx_cuda_distributed_pretraining_tpu.train.trainer import Trainer
     from tests.test_trainer import _tiny_config
 
@@ -177,18 +207,25 @@ def late_run(request, tmp_path_factory):
         "logging.trace": {"enabled": True},
         "logging.steps": {"logging_interval": 1, "checkpoint_interval": 0,
                           "validation_interval": 0}})
-    tr = Trainer(cfg, runs_root=str(tmp / "runs"), quiet=True)
-    inner, calls = tr.train_step, []
+    clock = Clock()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(steprecord, "time", clock)
+        patch.setattr(trace, "time", clock)
+        tr = Trainer(cfg, runs_root=str(tmp / "runs"), quiet=True)
+        inner, calls = tr.train_step, []
 
-    def step(state, batch):
-        calls.append(1)
-        time.sleep(EVERY_S)
-        if len(calls) == LATE_STEP:
-            (time.sleep if request.param == "sleep" else _busy)(LATE_S)
-        return inner(state, batch)
+        def step(state, batch):
+            calls.append(1)
+            clock.passes(EVERY_S)
+            if len(calls) == LATE_STEP:
+                clock.passes(LATE_S)
+                if request.param == "busy":
+                    _busy(LATE_S)
+            return inner(state, batch)
 
-    tr.train_step = step
-    assert tr.train()["steps"] == 12
+        tr.train_step = step
+        assert tr.train()["steps"] == 12
+    assert clock.now == pytest.approx(12 * EVERY_S + LATE_S)
     events = list(iter_events(events_path(tr.run_dir)))
     with open(os.path.join(tr.run_dir, "log.txt")) as f:
         log = f.read()
@@ -220,6 +257,9 @@ def test_the_late_step_is_the_slow_one(late_run):
     assert late["slow"]["dispatch_s"] >= LATE_S and late["slow"]["queue_depth"] >= 0
     others = [e["step_s_max"] for e in _windows(late_run)[1:] if e["step"] != LATE_STEP]
     assert max(others) < LATE_S
+    # on the fixture's clock, to the microsecond: the machine added nothing
+    assert late["step_s_max"] == pytest.approx(EVERY_S + LATE_S, abs=2e-6)
+    assert others == [pytest.approx(EVERY_S, abs=2e-6)] * 10
 
 
 def test_the_late_steps_phases_add_up_to_its_wall_time(late_run):
@@ -269,7 +309,8 @@ def test_the_step_close_phase_holds_the_events_write(late_run):
         assert nxt["ts"] - 2 <= e["ts"] and e["ts"] + e["dur"] <= nxt["ts"] + nxt["dur"] + 2
 
 
-def _quiet_run(tmp_path, name, iters, **extra):
+def _quiet_run(tmp_path, name, iters, clock, **extra):
+    """A trainer whose step takes ``EVERY_S`` of ``clock``."""
     from mlx_cuda_distributed_pretraining_tpu.train.trainer import Trainer
     from tests.test_trainer import _tiny_config
 
@@ -280,24 +321,29 @@ def _quiet_run(tmp_path, name, iters, **extra):
     inner = tr.train_step
 
     def step(state, batch):
-        time.sleep(EVERY_S)
+        clock.passes(EVERY_S)
         return inner(state, batch)
 
     tr.train_step = step
     return tr
 
 
-def test_stopping_a_capture_is_the_programs_own_work_and_no_stall(tmp_path):
-    """A profiler whose stop takes 0.3 s, stopped at the top of step 11."""
-    tr = _quiet_run(tmp_path, "prof", 14, **{"logging.profile_start": 9,
-                                             "logging.profile_stop": 11})
+def test_stopping_a_capture_is_the_programs_own_work_and_no_stall(tmp_path, monkeypatch, clock):
+    """A profiler whose stop takes 0.3 s, stopped at the top of step 11, on a
+    clock the test moves: the loop times the capture's start and stop on its own
+    ``time`` (train/trainer.py), the records on theirs."""
+    from mlx_cuda_distributed_pretraining_tpu.train import trainer
+
+    monkeypatch.setattr(trainer, "time", clock)
+    tr = _quiet_run(tmp_path, "prof", 14, clock, **{"logging.profile_start": 9,
+                                                    "logging.profile_stop": 11})
 
     def start(step=None):
         tr.profiler.active = True
         return True
 
     def stop(step=None):
-        time.sleep(0.3)
+        clock.passes(0.3)
         tr.profiler.active = False
         return None
 
@@ -309,34 +355,21 @@ def test_stopping_a_capture_is_the_programs_own_work_and_no_stall(tmp_path):
         ("start", 9), ("stop", 11)]
     assert not [e for e in events if e["type"] == "step_stall"]
     slow = [e for e in events if e["type"] == "step_window"][10]["slow"]
-    assert slow["step"] == 11 and slow["wall_s"] >= 0.3 and 0.3 <= slow["side_s"] < slow["wall_s"]
+    assert slow["step"] == 11 and slow["wall_s"] == pytest.approx(0.3 + EVERY_S)
+    assert slow["side_s"] == pytest.approx(0.3)
     assert slow["x_median"] < StepRecords.STALL_FACTOR
     with open(os.path.join(tr.run_dir, "log.txt")) as f:
         assert "WARNING: step" not in f.read()
 
 
-def test_a_window_is_written_before_its_steps_evaluation_and_checkpoint(tmp_path, monkeypatch):
+def test_a_window_is_written_before_its_steps_evaluation_and_checkpoint(tmp_path, clock):
     """Work beside the step is in no step's record, and a kill during the save
     of step N finds ``step_window`` N already in the log. The records read a
-    clock the test moves (a step call takes 0.01 s of it, a save 100 s), so a
+    clock the test moves (a step call takes 0.03 s of it, a save 100 s), so a
     busy machine changes no number here."""
-    from mlx_cuda_distributed_pretraining_tpu.obs import steprecord
-
-    class Clock:   # ``time`` as obs/steprecord.py reads it
-        now = 0.0
-
-        def perf_counter(self):
-            return self.now
-
-        def passes(self, seconds):
-            self.now += seconds
-
-    clock = Clock()
-    monkeypatch.setattr(steprecord, "time", clock)
-    tr = _quiet_run(tmp_path, "order", 12, steps={"checkpoint_interval": 8,
-                                                  "validation_interval": 8})
-    step, saved = tr.train_step, tr._save_checkpoint_inner
-    tr.train_step = lambda *a: (clock.passes(0.01), step(*a))[1]
+    tr = _quiet_run(tmp_path, "order", 12, clock, steps={"checkpoint_interval": 8,
+                                                         "validation_interval": 8})
+    saved = tr._save_checkpoint_inner
     tr._save_checkpoint_inner = lambda *a: (clock.passes(100.0), saved(*a))[1]
     tr.train()
     events = list(iter_events(events_path(tr.run_dir)))
@@ -347,8 +380,8 @@ def test_a_window_is_written_before_its_steps_evaluation_and_checkpoint(tmp_path
                                 ("step_window", 9)]
     windows = [e for e in events if e["type"] == "step_window"]
     assert [e["step"] for e in windows] == list(range(1, 13))
-    assert clock.now == pytest.approx(12 * 0.01 + 2 * 100.0)   # saves at step 8 and at the end
-    assert all(e["slow"]["wall_s"] == pytest.approx(0.01) and "side_s" not in e["slow"]
+    assert clock.now == pytest.approx(12 * EVERY_S + 2 * 100.0)   # saves at step 8 and at the end
+    assert all(e["slow"]["wall_s"] == pytest.approx(EVERY_S) and "side_s" not in e["slow"]
                for e in windows)
     assert not [e for e in events if e["type"] == "step_stall"]
 

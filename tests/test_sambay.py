@@ -32,6 +32,7 @@ from mlx_cuda_distributed_pretraining_tpu.config import Config
 from mlx_cuda_distributed_pretraining_tpu.models import sambay
 from mlx_cuda_distributed_pretraining_tpu.models.registry import resolve_architecture
 from mlx_cuda_distributed_pretraining_tpu.ops import flash_attention as fa
+from mlx_cuda_distributed_pretraining_tpu.ops.attention import core_counts
 from mlx_cuda_distributed_pretraining_tpu.ops import selective_scan as ss
 from test_afmoe import _read_metric, _trace_dir, _xplane
 
@@ -485,9 +486,9 @@ def test_the_tallies_say_what_a_step_traced(tiny, monkeypatch):
     are as they were (the 64/128 calls are counted under the same keys)."""
     monkeypatch.setenv("SSM_BACKEND", "kernel")
     cfg, params, batch = tiny
-    before = (sambay.ssm_plan_counts(), sambay.attn_plan_counts(), fa.plan_counts())
+    before = (sambay.ssm_plan_counts(), core_counts(), fa.plan_counts())
     jax.eval_shape(jax.grad(lambda p: sambay.loss_fn(p, batch, _args(cfg, "flash"))[0]), params)
-    after = (sambay.ssm_plan_counts(), sambay.attn_plan_counts(), fa.plan_counts())
+    after = (sambay.ssm_plan_counts(), core_counts(), fa.plan_counts())
     ssm_, attn, flash = ({k: n - b.get(k, 0) for k, n in a.items() if n - b.get(k, 0)}
                          for a, b in zip(after, before))
     assert ssm_ == {"mamba_layers": 2, "gmu_layers": 1, "scan_fwd_kernel": 2, "scan_bwd_kernel": 2,

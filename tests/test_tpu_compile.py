@@ -26,6 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 from mlx_cuda_distributed_pretraining_tpu.models import llama
 from mlx_cuda_distributed_pretraining_tpu.ops import flash_attention as fa
 from mlx_cuda_distributed_pretraining_tpu.ops import fused_ce
+from mlx_cuda_distributed_pretraining_tpu.ops.attention import core_counts
 from mlx_cuda_distributed_pretraining_tpu.ops import grouped_matmul as gm
 
 HBM_BYTES = 16 * 10**9  # one v5e chip (Google Cloud TPU v5e documentation)
@@ -476,10 +477,10 @@ def test_two_kinds_of_attention_core_compile_for_v5e_at_the_cells_length(v5e, co
         out = afmoe.gated_attention(att, x, args, jnp.arange(S, dtype=jnp.int32), flag)
         return out.astype(jnp.float32).sum()
 
-    before = afmoe.plan_counts()
+    before = core_counts()
     hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         att, _sds((1, S, 2048), jnp.bfloat16, v5e), _sds((), jnp.bool_, v5e)).compile().as_text()
-    traced = {k: n - before.get(k, 0) for k, n in afmoe.plan_counts().items() if n - before.get(k, 0)}
+    traced = {k: n - before.get(k, 0) for k, n in core_counts().items() if n - before.get(k, 0)}
     assert traced == {f"{kind}_{what}": 1 for kind in ("window", "global")
                       for what in ("layers", "fwd_resident", "bwd_dq_resident", "bwd_dkv_resident")}
     calls = [m.group(1) for line in hlo.split("\n") if "tpu_custom_call" in line
@@ -533,11 +534,11 @@ def test_differential_attention_cores_compile_for_v5e_at_the_cells_length(v5e, c
         a1, a2 = sambay.diff_attention_core(q, k_st, vbar, args, kind)
         return (a1.astype(jnp.float32) - 0.5 * a2.astype(jnp.float32)).sum()
 
-    before = sambay.attn_plan_counts()
+    before = core_counts()
     for kind in ("S", "F"):
         hlo = jax.jit(jax.grad(lambda q, k, v, kind=kind: loss(q, k, v, kind), argnums=(0, 1, 2))).lower(
             q, k_st, vbar).compile().as_text()
         assert sum("tpu_custom_call" in line for line in hlo.split("\n")) == 3
-    traced = {k: n - before.get(k, 0) for k, n in sambay.attn_plan_counts().items() if n - before.get(k, 0)}
+    traced = {k: n - before.get(k, 0) for k, n in core_counts().items() if n - before.get(k, 0)}
     assert traced == {f"{kind}_{what}": 1 for kind in ("window", "global")
                       for what in ("layers", "fwd_resident", "bwd_dq_resident", "bwd_dkv_resident")}
