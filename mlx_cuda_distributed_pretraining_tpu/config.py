@@ -71,6 +71,10 @@ class ModelConfig:
     # Only architecture "sambay" reads it (models/sambay.py): the Mamba
     # layers' d_state, d_conv, expand, dt_rank.
     ssm: Dict[str, Any] = field(default_factory=dict)
+    # Only architecture "sdar_moe" reads it (models/sdar.py): the noise of its
+    # block-diffusion objective (block_length, eps, mask_id), which the
+    # trainer's loader then draws (data/block_diffusion.py).
+    diffusion: Dict[str, Any] = field(default_factory=dict)
     # Named rematerialization policy: "none" | "dots" | "full" |
     # "save_attn" (models/stack.py REMAT_POLICIES — save_attn keeps the
     # checkpoint_name-tagged attention activations and replays only the

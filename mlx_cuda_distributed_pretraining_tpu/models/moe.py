@@ -530,6 +530,19 @@ def sigmoid_route(x: jnp.ndarray, router: Params, k: int, route_scale: float
     return gate_idx, gate_w, scores
 
 
+def softmax_route(x: jnp.ndarray, router: Params, k: int
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Softmax scoring over every expert, then the top-k renormalised
+    (``norm_topk_prob``) → ``(gate_idx [..., K], gate_w [..., K], probs [...,
+    E])``, float32: the weights are the chosen probabilities over their sum.
+    No selection bias and no scale."""
+    probs = jax.nn.softmax(jnp.einsum(
+        "...d,de->...e", x, router["weight"].astype(x.dtype),
+        preferred_element_type=jnp.float32), axis=-1)
+    chosen, gate_idx = jax.lax.top_k(probs, k)
+    return gate_idx, chosen / (chosen.sum(-1, keepdims=True) + 1e-20), probs
+
+
 # Rows of one chunk's expert buffer, before tile padding, in a layer that holds a share,
 # where the model names no size of its own. ``grouped_ffn`` is dropless: its whole buffer
 # has a row for every selection, since any of them may be a held one, and a share of the
@@ -817,15 +830,17 @@ def held_share_ffn(experts: Params, x: jnp.ndarray, gate_idx: jnp.ndarray, gate_
     return jax.tree_util.tree_map(lambda a: a.reshape((B, S) + a.shape[2:]), out), took_whole
 
 
-def sigmoid_routed_ffn(p: Params, x: jnp.ndarray, top_k: int, route_scale: float,
-                       held: Tuple[int, int], n_routed: int, chunk_rows: int = HELD_CHUNK_ROWS,
-                       precision=None, tail: Optional[Callable] = None,
-                       operands: Sequence[Tuple[int, jnp.ndarray]] = ()):
-    """A sigmoid-routed layer as one expert-parallel rank computes it: the
-    shared expert every token visits + the held share of the routed experts →
-    ``(y, stats)``. ``p`` holds ``router`` (``weight``, the buffer ``bias``),
-    ``shared`` and the held ``experts``; the load counts every selection over
-    the router's whole width, and nothing is dropped.
+def routed_share_ffn(p: Params, x: jnp.ndarray, route: Callable, held: Tuple[int, int],
+                     n_routed: int, chunk_rows: int = HELD_CHUNK_ROWS, precision=None,
+                     tail: Optional[Callable] = None,
+                     operands: Sequence[Tuple[int, jnp.ndarray]] = ()):
+    """A routed layer as one expert-parallel rank computes it: the held share of
+    the routed experts, and beside it the shared expert every token visits
+    where ``p`` has one (``p["shared"]``) → ``(y, stats)``. ``route(x, router)
+    -> (gate_idx, gate_w, scores)`` over the router's whole width
+    (:func:`sigmoid_route`, :func:`softmax_route`); ``p`` holds ``router`` and
+    the held ``experts``; the load counts every selection over the router's
+    whole width, and nothing is dropped.
 
     With ``tail`` (and its ``operands``: :func:`held_share_ffn`)
     the first result is ``tail(y_c, *operands_c)`` of every chunk of tokens,
@@ -835,21 +850,30 @@ def sigmoid_routed_ffn(p: Params, x: jnp.ndarray, top_k: int, route_scale: float
     from .llama import mlp_block
 
     with jax.named_scope("moe_router"):
-        gate_idx, gate_w, _ = sigmoid_route(x, p["router"], top_k, route_scale)
-    with jax.named_scope("ffn"):
-        shared = mlp_block(p["shared"], x)
+        gate_idx, gate_w, _ = route(x, p["router"])
+    shared = None
+    if "shared" in p:
+        with jax.named_scope("ffn"):
+            shared = mlp_block(p["shared"], x)
     with jax.named_scope("moe_experts"):
-        if tail is None:
-            out, took_whole = held_share_ffn(p["experts"], x, gate_idx, gate_w, held, n_routed,
-                                             chunk_rows, precision)
-        else:
-            out, took_whole = held_share_ffn(
-                p["experts"], x, gate_idx, gate_w, held, n_routed, chunk_rows, precision,
-                tail=lambda routed, shared_c, *rest: tail(shared_c + routed, *rest),
-                operands=((0, shared),) + tuple(operands))
+        if tail is not None and shared is not None:
+            tail, operands = ((lambda routed, shared_c, *rest, tail=tail: tail(shared_c + routed, *rest)),
+                              ((0, shared),) + tuple(operands))
+        out, took_whole = held_share_ffn(p["experts"], x, gate_idx, gate_w, held, n_routed,
+                                         chunk_rows, precision, tail=tail, operands=operands)
     stats = dict(zero_stats(n_routed), moe_chunks_whole=took_whole, moe_load=jax.lax.stop_gradient(
         jnp.bincount(gate_idx.reshape(-1), length=n_routed).astype(jnp.float32)))
-    return (shared + out if tail is None else out), stats
+    return (shared + out if tail is None and shared is not None else out), stats
+
+
+def sigmoid_routed_ffn(p: Params, x: jnp.ndarray, top_k: int, route_scale: float,
+                       held: Tuple[int, int], n_routed: int, chunk_rows: int = HELD_CHUNK_ROWS,
+                       precision=None, tail: Optional[Callable] = None,
+                       operands: Sequence[Tuple[int, jnp.ndarray]] = ()):
+    """:func:`routed_share_ffn` under :func:`sigmoid_route`; ``p["router"]``
+    holds ``weight`` and the buffer ``bias``, ``p["shared"]`` the shared expert."""
+    return routed_share_ffn(p, x, lambda h, router: sigmoid_route(h, router, top_k, route_scale),
+                            held, n_routed, chunk_rows, precision, tail, operands)
 
 
 def _usable_ep_mesh(args, num_experts: int):

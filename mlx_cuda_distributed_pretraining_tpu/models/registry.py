@@ -32,7 +32,7 @@ class Architecture(NamedTuple):
 _REGISTRY: Dict[str, Architecture] = {}
 # Architectures in modules of their own, imported (and so registered) when a
 # config first names them: a llama run pays nothing for them.
-_LAZY_MODULES = {"xing_mla_moe": "xing", "afmoe": "afmoe", "sambay": "sambay"}
+_LAZY_MODULES = {"xing_mla_moe": "xing", "afmoe": "afmoe", "sambay": "sambay", "sdar_moe": "sdar"}
 
 
 def register(arch: Architecture) -> None:

@@ -86,6 +86,8 @@ def named_mask_mod(mask_type: str = "causal", window_size: int = 512,
         return masks_lib.sliding_window(window_size)
     if mask_type == "prefix_lm":
         return masks_lib.prefix_lm(prefix_len)
+    if mask_type == "block_diffusion":  # blocks of window_size in two copies of prefix_len rows
+        return masks_lib.block_diffusion(prefix_len, window_size)
     return masks_lib.causal()
 
 
@@ -95,7 +97,8 @@ def named_mask_mod(mask_type: str = "causal", window_size: int = 512,
 _KIND_SCOPE = {None: contextlib.nullcontext,
                "window": lambda: jax.named_scope("attn_window"),
                "global": lambda: jax.named_scope("attn_global"),
-               "cross": lambda: jax.named_scope("attn_global")}
+               "cross": lambda: jax.named_scope("attn_global"),
+               "blockdiff": lambda: jax.named_scope("attn_blockdiff")}
 _FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 
 # Layers traced by kind, and for each kind the path ``flash_plan`` gives each of the
@@ -132,11 +135,14 @@ def attention_core(q, k, v, attention_type: str, kind: Optional[str] = None,
     ``v``: the flash kernels where ``attention_type`` is ``"flash"``, else
     :func:`reference_attention`, under the mask said once, as the kernels take
     it (``mask_type="sliding_window", window_size=w``; ``mask_type="prefix_lm",
-    prefix_len=p``; nothing: causal). ``kind``
-    (``window | global | cross``) puts the core under its kind's scope above
+    prefix_len=p``; ``mask_type="block_diffusion", window_size=b``: blocks of
+    ``b`` in the two copies that are the halves of ``S``; nothing: causal). ``kind``
+    (``window | global | cross | blockdiff``) puts the core under its kind's scope above
     ``attn_core`` and adds the layer and its kernels' paths to
     :func:`core_counts`; a model of one kind of layer names none."""
     flash = attention_type == "flash"
+    if mask_type == "block_diffusion":
+        prefix_len = q.shape[1] // 2
     if kind is not None:
         _count_core(kind, q, k, v, flash)
     with _KIND_SCOPE[kind](), jax.named_scope("attn_core"):

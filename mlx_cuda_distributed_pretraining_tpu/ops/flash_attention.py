@@ -29,7 +29,11 @@ models/attention/flash_attention.py:100,134-151). This is the real thing:
   maps are clamped into the live range, so the pipeline never fetches a
   tile it will not use. Tiles a canonical mask leaves whole skip the mask
   program: per tile in the streamed kernels, as one unmasked run between
-  masked edges in the resident walk;
+  masked edges in the resident walk. ``block_diffusion`` (two copies of a
+  sequence side by side, ops/masks.py) is live in two stretches of a tile's
+  row or column, so its plan is a list of segments (:func:`_bd_kv_segments`,
+  :func:`_bd_q_segments`): the resident walk runs each in turn over the one
+  accumulator, the streamed grid gates and clamps by their union;
 - GQA: native — each query head reads its KV group's K/V; dK/dV are
   accumulated per query head and group-reduced outside the kernel;
 - masks/score mods are traceable index-lattice functions (ops/masks.py)
@@ -58,6 +62,7 @@ from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -209,6 +214,14 @@ def _full_tile_fn(mask_type: str, window: int, prefix_len: int,
     return full
 
 
+def _live_full(i, j, lo, hi, full_tile, live_full):
+    """``(live, full | None)`` of step ``j`` of tile ``i``'s streamed walk: by
+    the plan's one range and :func:`_full_tile_fn`, or by a plan of segments."""
+    if live_full is not None:
+        return live_full(i, j)
+    return (j >= lo(i)) & (j < hi(i)), full_tile(i, j) if full_tile else None
+
+
 def _tile_dispatch(live, full, compute, masked):
     """Shared live/interior/edge tile dispatch for all three kernels.
 
@@ -230,9 +243,157 @@ def _tile_dispatch(live, full, compute, masked):
             compute(apply_mask=True)
 
 
+# -- the two-copy mask of diffusion over blocks: a plan of segments ------------
+# ``masks.block_diffusion(L, B')`` over rows [noised copy ; clean copy]. A tile
+# straddles neither copy nor a block (``L % block == 0``, ``block % B' == 0``:
+# ``flash_attention`` sends any other call down the reference path), so with
+# ``r`` a tile's first row inside its copy the live tiles of a query tile are
+# its own blocks' columns in the noised copy (masked in-tile) and, in the clean
+# copy, a run the mask leaves whole and then the tiles its edge cuts; a clean
+# query tile has no first part. Along the other axis a clean KV tile is read by
+# an edge and a whole run of each copy's query tiles, a noised one by its own
+# blocks' rows alone. Each function works on a traced tile index (in a kernel)
+# and on a Python one (:func:`block_diffusion_tiles`).
+def _cdiv(a, b: int):
+    return (a + b - 1) // b
+
+
+def _where(test, a, b):
+    """``jnp.where`` on a traced test, plain choice on a Python one (the host's
+    count of a plan, which may run while a step is traced)."""
+    return (a if test else b) if isinstance(test, bool) else jnp.where(test, a, b)
+
+
+def _bd_kv_segments(L: int, Bp: int, block_q: int, block_kv: int, canonical: bool = True):
+    """``qi -> ((lo, hi, masked), ...)``: the KV tiles of query tile ``qi``, in
+    ascending order; a segment with ``hi <= lo`` is empty. ``masked`` is static:
+    whether the segment's tiles run the in-tile mask (all do under a mask
+    program that is not the canonical one)."""
+    nL = L // block_kv
+    diagonal_masked = not canonical or not block_q == Bp == block_kv  # a tile that is one block
+
+    def segments(qi):
+        row = qi * block_q
+        clean = row >= L
+        r = _where(clean, row - L, row)
+        own = r // block_kv
+        whole = nL + _where(clean, (r + Bp) // block_kv, own)
+        end = nL + _where(clean, _cdiv(r + block_q, block_kv), _cdiv(r + block_q - Bp, block_kv))
+        return ((own, _where(clean, own, _cdiv(r + block_q, block_kv)), diagonal_masked),
+                (nL, whole, not canonical), (whole, end, True))
+
+    return segments
+
+
+def _bd_q_segments(L: int, Bp: int, block_q: int, block_kv: int, canonical: bool = True):
+    """:func:`_bd_kv_segments` along the other axis: the query tiles of KV
+    tile ``ki`` (the backward's dK/dV)."""
+    nL = L // block_q
+    # the first segment: a noised tile's own blocks, or (empty where a tile is
+    # one block) the edge of the noised rows that read a clean tile
+    diagonal_masked = not canonical or not block_q == Bp == block_kv
+
+    def segments(ki):
+        col = ki * block_kv
+        clean = col >= L
+        c = _where(clean, col - L, col)
+        own_end = _cdiv(c + block_kv, block_q)
+        edge = nL + c // block_q                   # a noised tile's second stretch: empty, here
+        whole = _where(clean, nL + _cdiv(c + block_kv - Bp, block_q), edge)
+        return ((_where(clean, (c + Bp) // block_q, c // block_q), own_end, diagonal_masked),
+                (own_end, _where(clean, nL, own_end), not canonical),
+                (edge, whole, True),
+                (whole, _where(clean, 2 * nL, edge), not canonical))
+
+    return segments
+
+
+def _walk_segments(chunk, segments):
+    """The resident walk over a plan of segments: the masked ones a chunk a
+    trip, the whole ones ``_RESIDENT_UNROLL``, as :func:`_split_walk`."""
+    for lo, hi, masked in segments:
+        _walk(chunk, lo, hi, masked, 1 if masked else _RESIDENT_UNROLL)
+
+
+def _segment_tests(segments, groups):
+    """For the streamed grids, from a plan of segments whose ``groups`` (tuples
+    of segment indices) are its two stretches: ``live_full(i, j) -> (tile j is
+    live, and whole)`` and ``clamp(i, j) -> the live tile the pipeline holds at
+    step j``, which moves only onto a tile that will be used."""
+    def live_full(i, j):
+        live = full = False
+        for lo, hi, masked in segments(i):
+            inside = (j >= lo) & (j < hi)
+            live = live | inside
+            if not masked:
+                full = full | inside
+        return live, full
+
+    def clamp(i, j):
+        segs = segments(i)
+        (lo1, hi1), (lo2, hi2) = ((segs[g[0]][0], segs[g[-1]][1]) for g in groups)
+        into = lambda lo, hi: jnp.maximum(jnp.minimum(j, hi - 1), lo)
+        first = ((j < hi1) & (hi1 > lo1)) | (hi2 <= lo2)
+        return jnp.where(first, into(lo1, hi1), into(lo2, hi2))
+
+    return live_full, clamp
+
+
+_BD_KV_GROUPS, _BD_Q_GROUPS = ((0,), (1, 2)), ((0, 1), (2, 3))
+
+
+def _bd_segments(mask_type: str, window: int, prefix_len: int, block_q: int, block_kv: int,
+                 canonical: bool, axis: str):
+    """The segment plan of a call, ``None`` for every mask but
+    ``block_diffusion`` (whose ``window`` is the block length and whose
+    ``prefix_len`` the rows of one copy). ``axis``: ``"kv"`` | ``"q"``."""
+    if mask_type != "block_diffusion":
+        return None
+    plan = _bd_kv_segments if axis == "kv" else _bd_q_segments
+    return plan(prefix_len, window, block_q, block_kv, canonical)
+
+
+# What the last forward traced under ``block_diffusion`` visits, a head: a
+# model's step reports it beside its other counters (models/sdar.py).
+_bd_tiles_traced = {"live": 0, "grid": 0}
+
+
+def _note_bd_tiles(tiles) -> None:
+    with _plan_counts_lock:
+        _bd_tiles_traced.update(live=int((tiles > 0).sum()), grid=int(tiles.size))
+
+
+def bd_tiles_traced() -> Dict[str, int]:
+    """``{"live", "grid"}``: tiles the last traced ``block_diffusion`` forward
+    computes a head, and tiles of its whole grid (0 and 0: none traced)."""
+    with _plan_counts_lock:
+        return dict(_bd_tiles_traced)
+
+
+def _streamed_segments(mask_type, window, prefix_len, block_q, block_kv, canonical, axis):
+    """``(live_full, clamp)`` of a streamed call (:func:`_segment_tests`), a
+    pair of ``None`` for a mask with no segment plan."""
+    segments = _bd_segments(mask_type, window, prefix_len, block_q, block_kv, canonical, axis)
+    if segments is None:
+        return None, None
+    return _segment_tests(segments, _BD_KV_GROUPS if axis == "kv" else _BD_Q_GROUPS)
+
+
+def block_diffusion_tiles(L: int, Bp: int, block_q: int, block_kv: int):
+    """The forward's plan on the host: ``[2L / block_q, 2L / block_kv]`` int8,
+    0 a tile no kernel visits, 1 one masked in-tile, 2 one left whole (the
+    classes of ``masks.block_mask_map``)."""
+    out = np.zeros((2 * L // block_q, 2 * L // block_kv), np.int8)
+    segments = _bd_kv_segments(L, Bp, block_q, block_kv)
+    for qi in range(out.shape[0]):
+        for lo, hi, masked in segments(qi):
+            out[qi, int(lo):max(int(hi), int(lo))] = 1 if masked else 2
+    return out
+
+
 # -- forward kernel ----------------------------------------------------------
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-                scale, mask_fn, score_fn, kv_lo, kv_hi, nkv, full_tile=None):
+                scale, mask_fn, score_fn, kv_lo, kv_hi, nkv, full_tile=None, live_full=None):
     j = pl.program_id(3)
     qi = pl.program_id(2)
     h = pl.program_id(1)
@@ -272,8 +433,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    live = (j >= kv_lo(qi)) & (j < kv_hi(qi))
-    _tile_dispatch(live, full_tile(qi, j) if full_tile else None,
+    _tile_dispatch(*_live_full(qi, j, kv_lo, kv_hi, full_tile, live_full),
                    _compute, mask_fn is not None)
 
     @pl.when(j == nkv - 1)
@@ -289,6 +449,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
 
 
 # -- resident-KV forward kernel ----------------------------------------------
+_NO_FULL_RANGE = ("full", "block_diffusion")  # nothing masked | a plan of segments instead
+
+
 def _full_range(mask_type: str, window: int, prefix_len: int,
                 block_q: int, block_kv: int):
     """``(a, b)``: the chunks ``a(qi) <= j < b(qi)`` of a query tile's KV
@@ -360,7 +523,8 @@ def _split_walk(chunk, lo, hi, i, full_range, masked):
 
 
 def _fwd_resident_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-                         scale, mask_fn, score_fn, kv_lo, kv_hi, bkv, full_range=None):
+                         scale, mask_fn, score_fn, kv_lo, kv_hi, bkv, full_range=None,
+                         segments=None):
     """One query tile against the whole K/V of its KV head, which the
     pipeline holds in VMEM: the KV walk is a loop in here over ``[bkv, D]``
     slices of the refs, from ``kv_lo(qi)`` to ``kv_hi(qi)`` in ascending
@@ -397,7 +561,10 @@ def _fwd_resident_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
-    _split_walk(chunk, kv_lo(qi), kv_hi(qi), qi, full_range, mask_fn is not None)
+    if segments is not None:
+        _walk_segments(chunk, segments(qi))
+    else:
+        _split_walk(chunk, kv_lo(qi), kv_hi(qi), qi, full_range, mask_fn is not None)
 
     l_safe = jnp.maximum(l_scr[...], 1e-30)
     o_ref[0, 0] = (acc_scr[...] / _lane_tile(l_safe, D)).astype(o_ref.dtype)
@@ -407,7 +574,8 @@ def _fwd_resident_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_
 
 # -- backward kernels --------------------------------------------------------
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *,
-                   scale, mask_fn, score_fn, kv_lo, kv_hi, nkv, full_tile=None):
+                   scale, mask_fn, score_fn, kv_lo, kv_hi, nkv, full_tile=None,
+                   live_full=None):
     j = pl.program_id(3)
     qi = pl.program_id(2)
     h = pl.program_id(1)
@@ -445,8 +613,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_s
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    live = (j >= kv_lo(qi)) & (j < kv_hi(qi))
-    _tile_dispatch(live, full_tile(qi, j) if full_tile else None,
+    _tile_dispatch(*_live_full(qi, j, kv_lo, kv_hi, full_tile, live_full),
                    _compute, mask_fn is not None)
 
     @pl.when(j == nkv - 1)
@@ -456,7 +623,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_s
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
                     dk_scr, dv_scr, *, scale, mask_fn, score_fn, q_lo, q_hi, nq,
-                    full_tile=None):
+                    full_tile=None, live_full=None):
     j = pl.program_id(3)   # q tile (streamed)
     ki = pl.program_id(2)  # kv tile (resident)
     h = pl.program_id(1)
@@ -498,10 +665,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    live = (j >= q_lo(ki)) & (j < q_hi(ki))
     # Tile geometry here is (q tile j, kv tile ki): full_tile takes
     # (query tile, kv tile) in that order.
-    _tile_dispatch(live, full_tile(j, ki) if full_tile else None,
+    _tile_dispatch(*_live_full(ki, j, q_lo, q_hi,
+                               (lambda i, jj: full_tile(jj, i)) if full_tile else None, live_full),
                    _compute, mask_fn is not None)
 
     @pl.when(j == nq - 1)
@@ -549,7 +716,8 @@ def _bwd_p_ds(s_raw, dp, lse, delta, row, col, h, *, scale, mask_fn, score_fn, a
 
 
 def _bwd_dq_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *,
-                            scale, mask_fn, score_fn, kv_lo, kv_hi, bkv, full_range=None):
+                            scale, mask_fn, score_fn, kv_lo, kv_hi, bkv, full_range=None,
+                            segments=None):
     """dQ of one query tile against the whole K/V of its KV head, held in
     VMEM as :func:`_fwd_resident_kernel` holds them: q, dO and the tile's
     ``lse`` and ``delta`` are read once, the two statistics re-laid from
@@ -581,13 +749,16 @@ def _bwd_dq_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _split_walk(chunk, kv_lo(qi), kv_hi(qi), qi, full_range, mask_fn is not None)
+    if segments is not None:
+        _walk_segments(chunk, segments(qi))
+    else:
+        _split_walk(chunk, kv_lo(qi), kv_hi(qi), qi, full_range, mask_fn is not None)
     dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
                              dk_scr, dv_scr, *, scale, mask_fn, score_fn, q_lo, q_hi,
-                             bq, full_range=None):
+                             bq, full_range=None, segments=None):
     """dK and dV of one KV tile against the whole Q and dO of one query
     head, held in VMEM with that head's ``lse`` and ``delta``; the query walk
     is a loop in here. The tile is worked K-major: scores as ``[bkv, bq]``,
@@ -624,7 +795,10 @@ def _bwd_dkv_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk
             ds.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    _split_walk(chunk, q_lo(ki), q_hi(ki), ki, full_range, mask_fn is not None)
+    if segments is not None:
+        _walk_segments(chunk, segments(ki))
+    else:
+        _split_walk(chunk, q_lo(ki), q_hi(ki), ki, full_range, mask_fn is not None)
     dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
     dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
 
@@ -808,6 +982,8 @@ def flash_fwd(q, k, v, *, mask_fn=None, score_fn=None, mask_type="causal",
     provably fully valid). :func:`flash_plan` picks the kernel from the
     shapes; ``_path`` is for tests, which run both on one input."""
     plan = _traced_plan("flash_fwd", q, k, v, block_q, block_kv, _path)
+    if mask_type == "block_diffusion":
+        _note_bd_tiles(block_diffusion_tiles(prefix_len, window, plan.block_q, plan.block_kv))
     fwd = _flash_fwd_resident if plan.path == "resident" else _flash_fwd_streamed
     return fwd(q, k, v, plan.block_q, plan.block_kv, mask_fn=mask_fn, score_fn=score_fn,
                mask_type=mask_type, window=window, prefix_len=prefix_len,
@@ -822,10 +998,11 @@ def _flash_fwd_resident(q, k, v, bq, bkv, *, mask_fn, score_fn, mask_type,
     G = Hq // Hkv
     kv_lo, kv_hi = _kv_range(mask_type, window, prefix_len, bq, bkv, Skv // bkv)
     full_range = (_full_range(mask_type, window, prefix_len, bq, bkv)
-                  if canonical_mask and mask_type != "full" else None)
+                  if canonical_mask and mask_type not in _NO_FULL_RANGE else None)
+    segments = _bd_segments(mask_type, window, prefix_len, bq, bkv, canonical_mask, "kv")
     kernel = functools.partial(
         _fwd_resident_kernel, scale=scale, mask_fn=mask_fn, score_fn=score_fn,
-        kv_lo=kv_lo, kv_hi=kv_hi, bkv=bkv, full_range=full_range)
+        kv_lo=kv_lo, kv_hi=kv_hi, bkv=bkv, full_range=full_range, segments=segments)
 
     def kv_index(b, h, i):
         # Not a function of the query tile, and the same for the G heads of
@@ -870,6 +1047,8 @@ def _flash_fwd_streamed(q, k, v, bq, bkv, *, mask_fn, score_fn, mask_type,
     kv_lo, kv_hi = _kv_range(mask_type, window, prefix_len, bq, bkv, nkv)
     full_tile = (_full_tile_fn(mask_type, window, prefix_len, bq, bkv)
                  if canonical_mask else None)
+    live_full, clamp = _streamed_segments(mask_type, window, prefix_len, bq, bkv,
+                                          canonical_mask, "kv")
 
     def kv_index(b, h, i, j):
         # Clamp skipped tiles into the live range so the pipeline never
@@ -878,14 +1057,14 @@ def _flash_fwd_streamed(q, k, v, bq, bkv, *, mask_fn, score_fn, mask_type,
         # masks: lo can exceed nkv-1, hi-1 can go below lo) are clamped
         # into [0, nkv-1] from BOTH sides — jnp.clip resolves inverted
         # bounds toward the upper one, which is always in range.
-        jc = jnp.clip(j, jnp.minimum(kv_lo(i), nkv - 1),
-                      jnp.maximum(kv_hi(i) - 1, 0))
+        jc = clamp(i, j) if clamp else jnp.clip(j, jnp.minimum(kv_lo(i), nkv - 1),
+                                                jnp.maximum(kv_hi(i) - 1, 0))
         return (b, h // G, jc, 0)
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, mask_fn=mask_fn,
         score_fn=score_fn, kv_lo=kv_lo, kv_hi=kv_hi, nkv=nkv,
-        full_tile=full_tile)
+        full_tile=full_tile, live_full=live_full)
     return pl.pallas_call(
         kernel,
         grid=(B, Hq, nq, nkv),
@@ -933,7 +1112,8 @@ def _flash_bwd_dq_resident(q, k, v, g, lse, delta, bq, bkv, *, mask_fn, score_fn
     G = Hq // Hkv
     kv_lo, kv_hi = _kv_range(mask_type, window, prefix_len, bq, bkv, Skv // bkv)
     full_range = (_full_range(mask_type, window, prefix_len, bq, bkv)
-                  if canonical_mask and mask_type != "full" else None)
+                  if canonical_mask and mask_type not in _NO_FULL_RANGE else None)
+    segments = _bd_segments(mask_type, window, prefix_len, bq, bkv, canonical_mask, "kv")
     Dv = v.shape[3]  # v and dO; q, k and dQ are D wide
     tile, tile_v = (_vmem_spec((1, 1, bq, d), lambda b, h, i: (b, h, i, 0)) for d in (D, Dv))
     held, held_v = (_vmem_spec((1, 1, Skv, d), lambda b, h, i: (b, h // G, 0, 0))
@@ -942,7 +1122,7 @@ def _flash_bwd_dq_resident(q, k, v, g, lse, delta, bq, bkv, *, mask_fn, score_fn
     return pl.pallas_call(
         functools.partial(
             _bwd_dq_resident_kernel, scale=scale, mask_fn=mask_fn, score_fn=score_fn,
-            kv_lo=kv_lo, kv_hi=kv_hi, bkv=bkv, full_range=full_range),
+            kv_lo=kv_lo, kv_hi=kv_hi, bkv=bkv, full_range=full_range, segments=segments),
         grid=(B, Hq, Sq // bq),
         in_specs=[tile, held, held_v, tile_v, stat, stat],
         out_specs=tile,
@@ -964,17 +1144,19 @@ def _flash_bwd_dq_streamed(q, k, v, g, lse, delta, bq, bkv, *, mask_fn, score_fn
     kv_lo, kv_hi = _kv_range(mask_type, window, prefix_len, bq, bkv, nkv)
     full_tile = (_full_tile_fn(mask_type, window, prefix_len, bq, bkv)
                  if canonical_mask else None)
+    live_full, clamp = _streamed_segments(mask_type, window, prefix_len, bq, bkv,
+                                          canonical_mask, "kv")
 
     def kv_index(b, h, i, j):
-        jc = jnp.clip(j, jnp.minimum(kv_lo(i), nkv - 1),
-                      jnp.maximum(kv_hi(i) - 1, 0))
+        jc = clamp(i, j) if clamp else jnp.clip(j, jnp.minimum(kv_lo(i), nkv - 1),
+                                                jnp.maximum(kv_hi(i) - 1, 0))
         return (b, h // G, jc, 0)
 
     return pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale,
                           mask_fn=mask_fn, score_fn=score_fn,
                           kv_lo=kv_lo, kv_hi=kv_hi, nkv=nkv,
-                          full_tile=full_tile),
+                          full_tile=full_tile, live_full=live_full),
         grid=(B, Hq, nq, nkv),
         in_specs=[
             _vmem_spec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
@@ -1014,7 +1196,8 @@ def _flash_bwd_dkv_resident(q, k, v, g, lse, delta, bq, bkv, *, mask_fn, score_f
     G = Hq // Hkv
     q_lo, q_hi = _q_range(mask_type, window, prefix_len, bq, bkv, Sq // bq)
     full_range = (_full_range_q(mask_type, window, prefix_len, bq, bkv)
-                  if canonical_mask and mask_type != "full" else None)
+                  if canonical_mask and mask_type not in _NO_FULL_RANGE else None)
+    segments = _bd_segments(mask_type, window, prefix_len, bq, bkv, canonical_mask, "q")
     # Q, dO and the statistics are the query head's own: fetched once a
     # (sequence, query head), whatever the KV tile.
     Dv = v.shape[3]  # v, dO and dV; q, k and dK are D wide
@@ -1026,7 +1209,7 @@ def _flash_bwd_dkv_resident(q, k, v, g, lse, delta, bq, bkv, *, mask_fn, score_f
     return pl.pallas_call(
         functools.partial(
             _bwd_dkv_resident_kernel, scale=scale, mask_fn=mask_fn, score_fn=score_fn,
-            q_lo=q_lo, q_hi=q_hi, bq=bq, full_range=full_range),
+            q_lo=q_lo, q_hi=q_hi, bq=bq, full_range=full_range, segments=segments),
         grid=(B, Hq, Skv // bkv),
         in_specs=[held, tile, tile_v, held_v, stat, stat],
         out_specs=[out, out_v],
@@ -1053,21 +1236,24 @@ def _flash_bwd_dkv_streamed(q, k, v, g, lse, delta, bq, bkv, *, mask_fn, score_f
     full_tile = (_full_tile_fn(mask_type, window, prefix_len, bq, bkv)
                  if canonical_mask else None)
 
+    live_full, clamp = _streamed_segments(mask_type, window, prefix_len, bq, bkv,
+                                          canonical_mask, "q")
+
+    def q_tile(i, j):
+        return clamp(i, j) if clamp else jnp.clip(j, jnp.minimum(q_lo(i), nq - 1),
+                                                  jnp.maximum(q_hi(i) - 1, 0))
+
     def q_index(b, h, i, j):
-        jc = jnp.clip(j, jnp.minimum(q_lo(i), nq - 1),
-                      jnp.maximum(q_hi(i) - 1, 0))
-        return (b, h, jc, 0)
+        return (b, h, q_tile(i, j), 0)
 
     def stat_index(b, h, i, j):
-        jc = jnp.clip(j, jnp.minimum(q_lo(i), nq - 1),
-                      jnp.maximum(q_hi(i) - 1, 0))
-        return (b, h, 0, jc)
+        return (b, h, 0, q_tile(i, j))
 
     return pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale,
                           mask_fn=mask_fn, score_fn=score_fn,
                           q_lo=q_lo, q_hi=q_hi, nq=nq,
-                          full_tile=full_tile),
+                          full_tile=full_tile, live_full=live_full),
         grid=(B, Hq, nkv, nq),
         in_specs=[
             _vmem_spec((1, 1, bq, D), q_index),
@@ -1200,9 +1386,12 @@ def flash_attention(
     all three kernels and the plan take both from the call's shapes.
 
     ``mask_type`` selects the block-sparsity plan (causal / sliding_window /
-    prefix_lm / full); ``mask_fn``/``score_fn`` override the in-tile
+    prefix_lm / full / block_diffusion); ``mask_fn``/``score_fn`` override the in-tile
     predicate (flex path): ``mask_fn(row, col) -> bool``,
-    ``score_fn(scores, row, col, head) -> scores``.
+    ``score_fn(scores, row, col, head) -> scores``. ``block_diffusion`` is
+    self-attention over the two copies of a sequence (``masks.block_diffusion``):
+    ``window_size`` is its block length and the rows of a copy are half the
+    call's, whatever ``prefix_len`` says.
 
     ``precision`` (model.matmul_precision): "bf16" casts q/k/v; "int8"
     quantizes them onto the symmetric int8 grid with per-row amax scales
@@ -1219,6 +1408,11 @@ def flash_attention(
     B, Sq, Hq, D = q.shape
     _, Skv, Hkv, _ = k.shape
     scale = (D ** -0.5) if scale is None else scale
+    if mask_type == "block_diffusion":
+        if Sq != Skv or Sq % 2 or (Sq // 2) % window_size:
+            raise ValueError(f"block_diffusion attends within two copies of one sequence in "
+                             f"blocks of {window_size}: got {Sq} query rows, {Skv} key rows")
+        prefix_len = Sq // 2
 
     # A score program may read the global head index, so heads stay whole
     # under one.
@@ -1241,6 +1435,12 @@ def flash_attention(
     block_kv = block_kv and fit_block(block_kv, Skv)
     no_kernel = flash_plan(Sq, Skv, D, k.dtype, block_q, block_kv,
                            Dv=v.shape[3]).path == "reference"
+    if mask_type == "block_diffusion":
+        # its plan counts in whole tiles: none straddles the copies or a block
+        no_kernel = no_kernel or any(
+            prefix_len % block or block % window_size
+            for kernel in _CHUNK_TEMPS
+            for block in flash_plan(Sq, Skv, D, k.dtype, block_q, block_kv, kernel, v.shape[3])[1:])
 
     from . import masks as M
 
@@ -1254,14 +1454,14 @@ def flash_attention(
         and plan[0] == mask_type
         and (mask_type != "sliding_window" or plan[1] == window_size)
         and (mask_type != "prefix_lm" or plan[2] == prefix_len)
+        and (mask_type != "block_diffusion" or plan[1:] == (window_size, prefix_len))
     )
     if mask_fn is None:
-        mask_fn = {
-            "causal": M.causal(),
-            "sliding_window": M.sliding_window(window_size),
-            "prefix_lm": M.prefix_lm(prefix_len),
-            "full": None,
-        }[mask_type]
+        mask_fn = (M.block_diffusion(prefix_len, window_size) if mask_type == "block_diffusion"
+                   else {"causal": M.causal(),
+                         "sliding_window": M.sliding_window(window_size),
+                         "prefix_lm": M.prefix_lm(prefix_len),
+                         "full": None}[mask_type])
 
     if no_kernel or Hq % Hkv:
         # Odd sizes: reference path with the SAME mask and score program

@@ -33,7 +33,8 @@ NEG_INF = -1e30  # large-but-finite: keeps softmax well-defined on fully-masked 
 
 # -- mask mods --------------------------------------------------------------
 # Each named builder tags its mod with ``_plan = (mask_type, window, prefix)``
-# so the flash kernel can recover the exact block-sparsity plan.
+# so the flash kernel can recover the exact block-sparsity plan
+# (``block_diffusion`` says its block length and its copy's rows there).
 
 
 @lru_cache(maxsize=None)
@@ -92,6 +93,37 @@ def prefix_lm(prefix_len: int) -> MaskMod:
         return (q >= k) | (k < prefix_len)
 
     mod._plan = ("prefix_lm", 0, prefix_len)
+    return mod
+
+
+@lru_cache(maxsize=None)
+def block_diffusion(seq_len: int, block_length: int) -> MaskMod:
+    """The training mask of diffusion over blocks (BD3-LM, arXiv:2503.09573),
+    over ``2 * seq_len`` rows: the noised copy of a sequence, then its clean
+    copy. With ``blk(r) = (r mod seq_len) // block_length``, query ``q`` sees
+    key ``k`` iff
+
+    - both noised: ``blk(k) == blk(q)`` (its own block, both directions);
+    - ``q`` noised, ``k`` clean: ``blk(k) < blk(q)`` (every earlier block, clean);
+    - both clean: ``blk(k) <= blk(q)`` (causal by block);
+    - ``q`` clean, ``k`` noised: never."""
+    L, Bp = int(seq_len), int(block_length)
+    if L < 1 or Bp < 1 or L % Bp:
+        raise ValueError(f"block_diffusion: block length {Bp} does not divide the sequence {L}")
+
+    # rows are never negative: a shift where it can be one (a vector divide is
+    # the slow way to say it inside a kernel)
+    blk = (lambda r: jnp.right_shift(r, Bp.bit_length() - 1)) if Bp & (Bp - 1) == 0 \
+        else (lambda r: r // Bp)
+
+    def mod(q, k):
+        q_clean, k_clean = q >= L, k >= L
+        qb = blk(jnp.where(q_clean, q - L, q))
+        kb = blk(jnp.where(k_clean, k - L, k))
+        # the four cases in and/or alone: Mosaic has no select between booleans
+        return (k_clean & (kb < qb)) | ((kb == qb) & ~(q_clean ^ k_clean))
+
+    mod._plan = ("block_diffusion", Bp, L)  # (mask_type, block length, rows a copy)
     return mod
 
 

@@ -144,8 +144,8 @@ def make_train_step(
     assert params_like is not None, "params_like required to derive shardings"
     probe_state = jax.eval_shape(lambda p: init_train_state(p, optimizer), params_like)
     shardings = state_sharding(probe_state, mesh, zero_level)
-    b_shard = NamedSharding(mesh, batch_pspec(mesh))
-    batch_shardings = {"inputs": b_shard, "targets": b_shard, "mask": b_shard}
+    # every array of a batch is [B, L] (a diffusion batch has five): one prefix
+    batch_shardings = NamedSharding(mesh, batch_pspec(mesh))
     metric_sharding = NamedSharding(mesh, jax.sharding.PartitionSpec())
     step_fn = jax.jit(
         train_step,
@@ -168,8 +168,7 @@ def make_eval_step(loss_fn: Callable, mesh: Optional[Mesh] = None,
 
     if mesh is None:
         return jax.jit(eval_step)
-    b_shard = NamedSharding(mesh, batch_pspec(mesh))
-    batch_shardings = {"inputs": b_shard, "targets": b_shard, "mask": b_shard}
+    batch_shardings = NamedSharding(mesh, batch_pspec(mesh))
     in_shardings = (
         state_shardings["params"] if state_shardings is not None else None,
         batch_shardings,

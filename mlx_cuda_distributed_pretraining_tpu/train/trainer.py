@@ -314,6 +314,14 @@ class Trainer:
                 process_index=jax.process_index(),
                 process_count=jax.process_count(),
             )
+            # A model trained by diffusion over blocks says how its batches are
+            # noised (models/sdar.py); the loader's own are the clean rows.
+            diffusion = getattr(args, "diffusion", None)
+            if diffusion:
+                from ..data.block_diffusion import BlockDiffusionBatches
+
+                self.data = BlockDiffusionBatches(self.data, cfg.system.seed,
+                                                  process_index=jax.process_index(), **diffusion)
 
         # -- steps / optimizer (reference setup_training :1093-1133) --------
         self.total_steps = 0
@@ -1116,6 +1124,7 @@ class Trainer:
         # Per-step MoE routing stats stay device-resident until the log
         # line reads them (one sync per window, same as loss).
         window_moe: list = []
+        window_bd_rows: list = []  # a step's positions that carry a diffusion loss
         # Anything booked so far (step-0 validation, lr finder) happened
         # before the first window's clock starts — flush it into the run
         # totals so every window's components sum to its own wall time.
@@ -1293,6 +1302,8 @@ class Trainer:
                     # Device arrays, no sync: summed/read at the log line.
                     window_moe.append((metrics["moe_load"], metrics["moe_dropped"],
                                        metrics["moe_chunks_whole"]))
+                if "bd_loss_rows" in metrics:
+                    window_bd_rows.append(metrics["bd_loss_rows"])
                 if step % log_int == 0 or step == self.total_steps:
                     with self.tracer.phase("train.loss_sync", step=step) as ph:
                         loss = float(metrics["loss"])  # device sync point
@@ -1390,6 +1401,14 @@ class Trainer:
                         for term in ("main_loss", "mtp_loss"):  # a loss of several heads
                             if term in metrics:
                                 line[term] = float(metrics[term])
+                        if window_bd_rows:
+                            # Diffusion over blocks (models/sdar.py): positions whose loss
+                            # counted in the window, and what the attention plan traced
+                            # visits of its tile grid, a head and forward call.
+                            line["bd_loss_rows"] = int(sum(float(r) for r in window_bd_rows))
+                            window_bd_rows = []
+                            for term in ("bd_tiles_live", "bd_tiles_grid"):
+                                line[term] = int(metrics[term])
                         if int(metrics["nonfinite"]):
                             self.logger.log(f"WARNING: non-finite loss at step {step}")
                             self._m_nonfinite.inc()
@@ -1427,7 +1446,8 @@ class Trainer:
                                 ev["bubble"] = round(self._bubble_frac, 6)
                             ev.update({k: line[k] for k in (
                                 "moe_rows_held", "moe_chunks_whole", "moe_load_max_over_mean",
-                                "moe_drop", "main_loss", "mtp_loss") if k in line})
+                                "moe_drop", "main_loss", "mtp_loss", "bd_loss_rows",
+                                "bd_tiles_live", "bd_tiles_grid") if k in line})
                             seen = hoststats.window_totals()
                             ev.update(hoststats.window_fields(self._host_seen, seen))
                             self._host_seen = seen

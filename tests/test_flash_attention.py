@@ -358,6 +358,16 @@ RAW_CASES = {
     "d64": dict(mask_type="causal", mask_fn=M.causal(), d=64),
     "d128_window": dict(mask_type="sliding_window", window=200,
                         mask_fn=M.sliding_window(200), d=128),
+    # two copies of 512 rows: every block fits a copy, the default ones too
+    "block_diffusion": dict(mask_type="block_diffusion", window=4, prefix_len=512,
+                            mask_fn=M.block_diffusion(512, 4), sq=1024, skv=1024),
+    "block_diffusion_b64_gqa": dict(mask_type="block_diffusion", window=64, prefix_len=512,
+                                    mask_fn=M.block_diffusion(512, 64), sq=1024, skv=1024,
+                                    hq=4, hkv=2),
+    # the plan's tiles under another mask program: every live tile masked in-tile
+    "block_diffusion_custom": dict(mask_type="block_diffusion", window=4, prefix_len=512,
+                                   canonical_mask=False, sq=1024, skv=1024,
+                                   mask_fn=lambda r, c: M.block_diffusion(512, 4)(r, c) & ((c % 5) != 0)),
 }
 
 
@@ -588,3 +598,90 @@ def test_flash_plan_takes_both_head_sizes():
                if fa.flash_plan(s, s, 192, jnp.bfloat16, Dv=128).path == "resident")
     assert fa.flash_plan(edge, edge, 192, jnp.bfloat16, Dv=192).path == "resident"
     assert fa.flash_plan(2 * edge, 2 * edge, 192, jnp.bfloat16, Dv=128).path == "streamed"
+
+
+# -- the two-copy mask of diffusion over blocks ----------------------------------------
+@pytest.mark.parametrize("L,Bp", [(128, 4), (256, 4), (512, 8), (256, 128), (512, 64)],
+                         ids=lambda v: str(v))
+def test_block_diffusion_kernels_match_reference(L, Bp, flash_path):
+    """Forward, dQ and dK/dV under ``block_diffusion`` against
+    ``reference_attention`` on the materialised mask, where a copy is 1, 2 and
+    4 tiles of 128 and a block is a few rows or a whole tile, GQA 2:1."""
+    rng = np.random.default_rng(L + Bp)
+    q, k, v, g = (jnp.asarray(rng.normal(size=(1, 2 * L, h, D)).astype(np.float32))
+                  for h in (4, 2, 2, 4))
+    mod = M.block_diffusion(L, Bp)
+    out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+        q, k, v, mask_type="block_diffusion", window_size=Bp, block_q=128, block_kv=128), q, k, v)
+    want, vjp_ref = jax.vjp(lambda q, k, v: reference_attention(q, k, v, mask_mod=mod), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
+    for got, ref, name in zip(vjp(g), vjp_ref(g), ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-4, rtol=1e-4,
+                                   err_msg=name)
+    # flex takes the named mask's plan from its tag
+    flex = flex_attention(q, k, v, mask_mod=mod, block_q=128, block_kv=128)
+    np.testing.assert_allclose(np.asarray(flex), np.asarray(want), atol=2e-5, rtol=2e-5)
+    assert fa.bd_tiles_traced() == {"live": int((fa.block_diffusion_tiles(L, Bp, 128, 128) > 0).sum()),
+                                    "grid": (2 * L // 128) ** 2}
+
+
+@pytest.mark.parametrize("L,Bp,bq,bkv", [
+    (512, 4, 128, 128), (512, 4, 256, 64), (512, 4, 64, 256), (512, 64, 64, 128),
+    (512, 128, 128, 128), (1024, 16, 512, 512), (1024, 8, 256, 512), (256, 4, 256, 256)])
+def test_block_diffusion_plan_visits_the_live_tiles_and_no_other(L, Bp, bq, bkv):
+    """Both axes' segment plans against ``masks.block_mask_map`` of the
+    materialised mask: dead tiles in no segment, whole tiles in an unmasked
+    one, cut tiles in a masked one; and what the streamed grid derives from the
+    segments (its live and whole tests, the tile its pipeline holds)."""
+    want = M.block_mask_map(M.block_diffusion(L, Bp), 2 * L, 2 * L, bq, bkv)
+    assert np.array_equal(fa.block_diffusion_tiles(L, Bp, bq, bkv), want)
+    nq, nkv = want.shape
+    plans = {"kv": (fa._bd_kv_segments(L, Bp, bq, bkv), fa._BD_KV_GROUPS, nq, nkv, lambda i, j: want[i, j]),
+             "q": (fa._bd_q_segments(L, Bp, bq, bkv), fa._BD_Q_GROUPS, nkv, nq, lambda i, j: want[j, i])}
+    for axis, (segments, groups, n, m, tile) in plans.items():
+        live_full, clamp = fa._segment_tests(segments, groups)
+        for i in range(n):
+            seen = np.zeros(m, np.int8)
+            last = -1
+            for lo, hi, masked in segments(i):
+                lo, hi = int(lo), int(hi)
+                assert lo >= last or hi <= lo, (axis, i)           # ascending, no tile twice
+                if hi > lo:
+                    assert not seen[lo:hi].any()
+                    seen[lo:hi] = 1 if masked else 2
+                    last = hi
+            assert np.array_equal(seen, [tile(i, j) for j in range(m)]), (axis, i)
+            held = [int(clamp(i, j)) for j in range(m)]
+            for j in range(m):
+                live, full = (bool(x) for x in live_full(i, j))
+                assert (live, full) == (seen[j] > 0, seen[j] == 2), (axis, i, j)
+                assert seen[held[j]] > 0 and (held[j] == j if live else True), (axis, i, j)
+            assert held == sorted(held)                              # the pipeline never goes back
+    # 288 of 1,024 tiles at the benchmark cell's size, 48 of them cut by an edge
+    cell = fa.block_diffusion_tiles(8192, 4, 512, 512)
+    assert (int((cell > 0).sum()), int((cell == 1).sum()), cell.size) == (288, 48, 1024)
+
+
+def test_block_diffusion_takes_the_reference_path_where_a_tile_would_straddle():
+    """``B'`` divides every block the plan can choose and a block divides a
+    copy, or the call runs without the kernels, as indivisible sequences do."""
+    rng = np.random.default_rng(0)
+    mk = lambda s: tuple(jnp.asarray(rng.normal(size=(1, s, 2, D)).astype(np.float32)) for _ in range(3))
+    for rows, Bp, blocks in ((384, 4, {}),                             # 192 rows a copy: no block
+                             (512, 4, {}),                             # default blocks span both copies
+                             (512, 32, dict(block_q=128, block_kv=128)),
+                             (768, 96, dict(block_q=128, block_kv=128))):  # a block is no multiple of B'
+        q, k, v = mk(rows)
+        before = fa.plan_counts()
+        out = flash_attention(q, k, v, mask_type="block_diffusion", window_size=Bp, **blocks)
+        after = fa.plan_counts()
+        kernel = (rows, Bp) == (512, 32)
+        assert after["reference"] - before["reference"] == (0 if kernel else 1), (rows, Bp)
+        assert after["resident"] - before["resident"] == (1 if kernel else 0)
+        want = reference_attention(q, k, v, mask_mod=M.block_diffusion(rows // 2, Bp))
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
+    q, k, v = mk(256)
+    with pytest.raises(ValueError, match="two copies of one sequence"):
+        flash_attention(q, k[:, :128], v[:, :128], mask_type="block_diffusion", window_size=4)
+    with pytest.raises(ValueError, match="two copies of one sequence"):
+        flash_attention(q, k, v, mask_type="block_diffusion", window_size=3)

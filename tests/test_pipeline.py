@@ -148,14 +148,17 @@ def test_pipeline_moe_loss_finite():
     margs = dataclasses.replace(
         ARGS, num_local_experts=4, num_experts_per_tok=2, moe_group_size=8
     )
+    from mlx_cuda_distributed_pretraining_tpu.parallel.context import use_mesh
+
     params = llama.init_params(jax.random.PRNGKey(0), margs)
-    loss_fn = pl.make_pipeline_loss(margs, mesh, num_microbatches=2)
-    loss, toks = jax.jit(loss_fn)(pl.stack_layers(params), _batch())
-    assert np.isfinite(float(loss))
-    # aux excluded for eval
-    ev = pl.make_pipeline_loss(margs, mesh, num_microbatches=2, include_aux=False)
-    l_eval, _ = jax.jit(ev)(pl.stack_layers(params), _batch())
-    assert float(loss) > float(l_eval)
+    with use_mesh(None):  # shield from a base mesh left by Trainer tests (the expert layer asks for it)
+        loss_fn = pl.make_pipeline_loss(margs, mesh, num_microbatches=2)
+        loss, toks = jax.jit(loss_fn)(pl.stack_layers(params), _batch())
+        assert np.isfinite(float(loss))
+        # aux excluded for eval
+        ev = pl.make_pipeline_loss(margs, mesh, num_microbatches=2, include_aux=False)
+        l_eval, _ = jax.jit(ev)(pl.stack_layers(params), _batch())
+        assert float(loss) > float(l_eval)
 
 
 @pytest.mark.slow

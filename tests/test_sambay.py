@@ -331,7 +331,8 @@ def test_flop_counts(tiny):
 def test_the_configuration_file_says_what_the_issue_says():
     bench = _load("BENCHMARK.json")
     entry = next(c for c in bench["configs"] if c["name"] == "phi4-mini-flash-l6")
-    assert entry == bench["configs"][-1] and entry["reduced"] == ["num_hidden_layers"]
+    # the fourth configuration and cell; later ones are appended behind them
+    assert entry == bench["configs"][3] and entry["reduced"] == ["num_hidden_layers"]
     assert entry["source"] == FULL["source"] and entry["file"] == "benchmark/configs/phi4-mini-flash-l6.json"
     # every number of the catalog's row under the same key; only the depth differs
     assert {k: FULL[k] for k in CATALOG if k != "num_hidden_layers"} == \
@@ -347,7 +348,7 @@ def test_the_configuration_file_says_what_the_issue_says():
     assert {"layer_ratio", "head_share"} <= set(FULL["distorts"])
     assert FULL["precision"]["control"] == "fp8" and FULL["head_dim"] == 64
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
-    assert cell == bench["workloads"][-1] and cell["chips"] == 1
+    assert cell == bench["workloads"][3] and cell["chips"] == 1
     assert (cell["config"], cell["traffic"]) == ("phi4-mini-flash-l6", "pack16k-sambay")
     mix, other = _load("benchmark/traffic/pack16k-sambay.json"), _load("benchmark/traffic/pack16k-afmoe.json")
     differs = ("kind", "shape_seed")
@@ -367,16 +368,18 @@ def test_the_cell_is_declared_for_the_metrics_it_can_report():
     for name in listed:
         assert os.path.isfile(os.path.join(REPO, "benchmark/layer_metrics", name + ".py"))
     assert set(NEW_READERS) <= listed
-    assert [m["name"] for m in bench["per_layer"][-len(NEW_READERS):]] == list(NEW_READERS)
+    names = [m["name"] for m in bench["per_layer"]]
+    mine = bench["per_layer"][names.index(NEW_READERS[0]):][:len(NEW_READERS)]   # one run of entries
+    assert [m["name"] for m in mine] == list(NEW_READERS)
     assert all(m["workloads"] == [CELL] and m["moves"] == "train_tokens_per_s_per_chip"
-               for m in bench["per_layer"][-len(NEW_READERS):])
+               for m in mine)
     assert {"train_mfu_pct", "step_hbm_gib", "step_device_ms.attn_window",
             "step_device_ms.attn_global", "step_device_ms.lm_head_ce"} <= listed
     # the shares that count D off a 128-wide output would over-count a 64/128 call
     assert not {n for n in listed if n.startswith("kernel_peak_pct.") and "diff_flash" not in n}
     assert not {"step_device_ms.moe", "step_device_ms.attn_gate", "moe_rows_held_per_step"} & listed
     e2e = next(m for m in bench["end_to_end"] if m["name"] == "train_tokens_per_s_per_chip")
-    assert e2e["workloads"][-1] == CELL and e2e["bound"] == 0.01
+    assert e2e["workloads"][3] == CELL and e2e["bound"] == 0.01
 
 
 # -- the trace readers --------------------------------------------------------------------

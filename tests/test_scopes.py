@@ -298,15 +298,15 @@ def test_named_scopes_in_the_program_are_the_vocabulary():
                                "flash_bwd_dq", "flash_bwd_dq", "flash_fwd", "flash_fwd",
                                "gmm", "ssm_scan_bwd", "ssm_scan_fwd", "tgmm"]
     assert pallas_calls == len(kernels), "a pallas_call without a name="
-    # architecture xing_mla_moe opens two more, afmoe three, and sambay five
-    # with its two scan kernels, which the benchmark reads by their own helpers
-    # (layer_metrics/_named_scopes.py, _attn_kinds.py, _ssm_scan.py) until its
+    # architecture xing_mla_moe opens two more, afmoe three, sambay five with
+    # its two scan kernels and sdar_moe two, which the benchmark reads by their own helpers
+    # (layer_metrics/_named_scopes.py, _attn_kinds.py, _ssm_scan.py, _blockdiff.py) until its
     # closed vocabulary takes them in; the head's kernel (ops/fused_ce.py) runs
     # under ``lm_head_ce`` and is read as part of that scope
     assert found | set(kernels) == VOCABULARY | {"hc_mix", "mtp"} | {
         "attn_window", "attn_global", "attn_gate"} | {
         "ssm", "ssm_proj", "ssm_conv", "gmu", "attn_diff", "ssm_scan_fwd", "ssm_scan_bwd"} | {
-        "ce_softmax_grad"}
+        "ce_softmax_grad"} | {"attn_blockdiff", "bd_rows"}
 
 
 # -- the host's turns ---------------------------------------------------------------

@@ -492,6 +492,45 @@ def test_two_kinds_of_attention_core_compile_for_v5e_at_the_cells_length(v5e, co
     assert len(calls) == 6 and " conditional(" in hlo
 
 
+@pytest.mark.parametrize("rows,path", [(16384, "resident"), (32768, "streamed")])
+def test_block_diffusion_cores_compile_for_v5e(v5e, compiled_kernels, rows, path):
+    """``sdar-30b-a3b-ep8.train-bd8k``'s attention (models/sdar.py): two copies of
+    a row of 8,192 side by side, GQA 32/4 of 128, the block-diffusion mask in
+    blocks of 4, forward and backward: Mosaic takes the in-tile mask (and/or of
+    comparisons and a shift: it has no select between booleans) and the walk
+    over a plan of segments, all three kernels on the resident path (16,384
+    rows are the last length K and V stay in VMEM); and, a length on, the
+    streamed grid gated and clamped by the same segments."""
+    import re
+
+    from mlx_cuda_distributed_pretraining_tpu.ops.attention import attention_core
+
+    def loss(q, k, v):
+        out = attention_core(q, k, v, "flash", kind="blockdiff", mask_type="block_diffusion",
+                             window_size=4)
+        return out.astype(jnp.float32).sum()
+
+    before, counted = core_counts(), fa.plan_counts()
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        _sds((1, rows, 32, 128), jnp.bfloat16, v5e), _sds((1, rows, 4, 128), jnp.bfloat16, v5e),
+        _sds((1, rows, 4, 128), jnp.bfloat16, v5e)).compile().as_text()
+    traced = {k: n - before.get(k, 0) for k, n in core_counts().items() if n - before.get(k, 0)}
+    assert traced == {f"blockdiff_{what}": 1 for what in
+                      ("layers", f"fwd_{path}", f"bwd_dq_{path}", f"bwd_dkv_{path}")}
+    plans = {k: n - counted[k] for k, n in fa.plan_counts().items() if n - counted[k]}
+    assert plans == {path: 1, f"bwd_dq_{path}": 1, f"bwd_dkv_{path}": 1}
+    blocks = fa.flash_plan(rows, rows, 128, jnp.bfloat16)[1:]
+    tiles = fa.block_diffusion_tiles(rows // 2, 4, *blocks)
+    assert fa.bd_tiles_traced() == {"live": int((tiles > 0).sum()), "grid": tiles.size}
+    if path == "resident":
+        assert fa.bd_tiles_traced() == {"live": 288, "grid": 1024}
+    calls = [m.group(1) for line in hlo.split("\n") if "tpu_custom_call" in line
+             for m in [re.search(r'op_name="([^"]+)"', line)] if m]
+    assert sorted(next(t for t in reversed(re.split(r"[/()]", c)) if t.startswith("flash_"))
+                  for c in calls) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"], calls
+    assert all("attn_blockdiff" in re.split(r"[/()]", c) for c in calls)
+
+
 def test_selective_scan_kernels_compile_for_v5e_at_the_cells_width(v5e, monkeypatch):
     """``phi4-mini-flash-l6.train-seq16k``'s scan (ops/selective_scan.py): one row
     of 16,384 steps, 5,120 channels of 16 states, forward and backward: Mosaic
