@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import grouped_matmul as gm
+from ..ops import token_sum as ts
 
 Params = Dict[str, Any]
 
@@ -285,8 +286,11 @@ def _einsum_moe(
 # take through its held experts, summed over the layers traced, in the loop at
 # the small buffer (0 for a layer of one size) and in the loop at the whole one
 # (:func:`held_chunks`: each loop cuts by what its own buffer has rows for).
+# ``token_sum_kernel``, ``token_sum_xla``: token-side passes (a combine, a dispatch's
+# backward, a combine's ``dgate_w``) traced in the kernels' form and in XLA's
+# (:func:`_token_tile`).
 _PLAN_KEYS = ("dispatch_gather", "combine_gather", "chunk_loop_tail", "chunk_two_sizes",
-              "chunk_trips_small", "chunk_trips_whole")
+              "chunk_trips_small", "chunk_trips_whole", "token_sum_kernel", "token_sum_xla")
 _plan_counts: Dict[str, int] = collections.Counter()
 _plan_counts_lock = threading.Lock()
 
@@ -308,7 +312,12 @@ def plan_counts() -> Dict[str, int]:
     those layers' loops at either buffer (``chunk_trips_small`` equal to
     ``chunk_trips_whole`` in a step with ``chunk_two_sizes`` is an executable
     whose small loop still cuts by a chunk's selections: twice the trips its
-    buffer needs where a layer holds an eighth of the experts)."""
+    buffer needs where a layer holds an eighth of the experts), and the
+    token-side passes by form: a combine traces one, its backward another for
+    ``dgate_w`` and a differentiated dispatch a third (``token_sum_kernel`` 0
+    in a step on the chip is an executable that still builds ``[T, K, D]`` for
+    the sums; off the chip they are all ``token_sum_xla``, where ``dgate_w`` is
+    taken on the buffer's side)."""
     with _plan_counts_lock:
         return {key: _plan_counts[key] for key in _PLAN_KEYS}
 
@@ -392,6 +401,30 @@ def _sum_held(rows: jnp.ndarray, scale: jnp.ndarray, dtype) -> jnp.ndarray:
                    axis=1).astype(dtype)
 
 
+def _token_tile(buf: jnp.ndarray, plan: "DispatchPlan") -> int:
+    """Tokens a tile of the token-side kernels takes for this buffer and plan, tallied;
+    0 for XLA's form (``ops/token_sum.py::token_sum_plan``: shapes and the
+    grouped-matmul backend)."""
+    T, K = plan.sel_row.shape
+    bt = ts.token_sum_plan(T, K, buf.shape[1], buf.shape[0], buf.dtype)
+    _count_plan("token_sum_kernel" if bt else "token_sum_xla")
+    return bt
+
+
+def _token_sum(buf: jnp.ndarray, plan: "DispatchPlan", scale: jnp.ndarray,
+               exact_scale: bool = False) -> jnp.ndarray:
+    """``out[t] = sum_k scale[t, k] * buf[plan.sel_row[t, k]]`` over the held
+    selections → ``[T, D]`` in ``buf``'s dtype, accumulated in float32 and cast
+    once: the kernel, which reads the rows a token holds and builds no
+    ``[T, K, D]``, or XLA's gather of a row for every selection, scaled by zero
+    where not held and summed in ascending ``k``."""
+    bt = _token_tile(buf, plan)
+    if bt:
+        return ts.token_sum(buf, plan.sel_row, plan.sel_held, scale, plan.group_sizes, bt,
+                            exact_scale=exact_scale)
+    return _sum_held(_take_rows(buf, plan.sel_row), jnp.where(plan.sel_held, scale, 0), buf.dtype)
+
+
 def _dispatch_rows(x_flat, plan):
     return jnp.where(plan.row_live[:, None], _take_rows(x_flat, plan.row_tok), 0)
 
@@ -402,7 +435,7 @@ def _dispatch_fwd(x_flat, plan):
 
 def _dispatch_bwd(plan, dx_buf):
     with jax.named_scope("moe_experts"):
-        dx = _sum_held(_take_rows(dx_buf, plan.sel_row), plan.sel_held, dx_buf.dtype)
+        dx = _token_sum(dx_buf, plan, plan.sel_held, exact_scale=True)
     return dx, None
 
 
@@ -411,8 +444,7 @@ _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 def _combine_rows(y_buf, gate_w, plan):
-    return _sum_held(_take_rows(y_buf, plan.sel_row),
-                     jnp.where(plan.sel_held, gate_w, 0), y_buf.dtype)
+    return _token_sum(y_buf, plan, gate_w)
 
 
 def _combine_fwd(y_buf, gate_w, plan):
@@ -423,14 +455,16 @@ def _combine_bwd(residuals, dout):
     y_buf, gate_w, plan = residuals
     with jax.named_scope("moe_experts"):
         w_row = _take_rows(gate_w.reshape(-1), plan.row_sel).astype(jnp.float32)
-        dy_buf = jnp.where(
-            plan.row_live[:, None],
-            _take_rows(dout, plan.row_tok).astype(jnp.float32) * w_row[:, None],
-            0).astype(y_buf.dtype)
-        # The forward's gathered rows again, and a row dot with the cotangent.
-        rows = _take_rows(y_buf, plan.sel_row).astype(jnp.float32)
-        dw = jnp.sum(rows * dout[:, None, :].astype(jnp.float32), axis=-1)
-        dw = jnp.where(plan.sel_held, dw, 0).astype(gate_w.dtype)
+        dout_row = _take_rows(dout, plan.row_tok).astype(jnp.float32)
+        dy_buf = jnp.where(plan.row_live[:, None], dout_row * w_row[:, None], 0).astype(y_buf.dtype)
+        # dgate_w: a selection's row dotted with its token's cotangent, in float32
+        bt = _token_tile(y_buf, plan)
+        if bt:
+            dw = ts.token_dot(y_buf, plan.sel_row, plan.sel_held, dout, plan.group_sizes, bt)
+        else:   # on the buffer's side, where the rows lie, then a gather of [T, K] scalars
+            dw_row = jnp.sum(y_buf.astype(jnp.float32) * dout_row, axis=-1)
+            dw = jnp.where(plan.sel_held, _take_rows(dw_row, plan.sel_row), 0)
+        dw = dw.astype(gate_w.dtype)
     return dy_buf, dw, None
 
 
@@ -441,16 +475,19 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 def dispatch_rows(x_flat: jnp.ndarray, plan: DispatchPlan) -> jnp.ndarray:
     """x_flat [T, D] → the expert buffer [T_buf, D]: every held selection's
     token row at its place, pad rows zero. One gather of ``T_buf`` rows; its
-    backward one gather of ``[T, K, D]`` and a sum over ``K``."""
+    backward one token-side sum (:func:`_token_sum`) of the buffer's cotangent."""
     _count_plan("dispatch_gather")
     return _dispatch(x_flat, plan)
 
 
 def combine_rows(y_buf: jnp.ndarray, gate_w: jnp.ndarray, plan: DispatchPlan) -> jnp.ndarray:
     """``out[t] = sum_k gate_w[t, k] * y_buf[row of (t, k)]`` over the held
-    selections → [T, D] in ``y_buf``'s dtype, the sum in float32. One gather
-    of ``[T, K, D]``; its backward one gather of ``T_buf`` rows for ``dy_buf``
-    and the forward's rows again, dotted with the cotangent, for ``dgate_w``."""
+    selections → [T, D] in ``y_buf``'s dtype, the sum in float32: one
+    token-side sum (:func:`_token_sum`). Its backward is one gather of ``T_buf``
+    rows of the cotangent, scaled for ``dy_buf``, and for ``dgate_w`` each
+    selection's row of ``y_buf`` dotted with its token's cotangent: the kernel
+    ``token_dot``, or in XLA's form the dots taken a buffer row at a time and
+    a gather of ``[T, K]`` of them."""
     _count_plan("combine_gather")
     return _combine(y_buf, gate_w, plan)
 

@@ -441,6 +441,98 @@ def test_gather_dispatch_and_combine_match_the_scatter_form(case, dtype):
             assert off <= max(2e-2, 1.25 * was), (name, off, was)
 
 
+def _token_sum_case(case, dtype, D=128):
+    """``(plan, y_buf, x, gate_w)`` of one case of the token-side sums: the plan of
+    32 tokens' selections at tiles of 16 rows, a buffer of its rows, the tokens'
+    rows and their gate weights."""
+    rng = np.random.default_rng(5)
+    T, K, E, first, router, rows = 32, 2, 4, 0, 4, None
+    if case in ("held_share", "token_holds_nothing", "short_buffer"):
+        first, router = 4, 16            # most selections belong to experts held elsewhere
+    idx = np.stack([rng.permutation(router)[:K] for _ in range(T)]).astype(np.int32)
+    if case == "empty_expert":           # expert 2 gets no row
+        idx = np.where(idx == 2, 3, idx)
+    elif case == "token_holds_nothing":  # nor do three tokens in a row
+        idx[5:8] = [12, 13]
+    elif case == "short_buffer":         # the caller has seen that the held rows fit fewer
+        rows = 64
+        assert int(moe.held_rows(jnp.asarray(idx), E, 16, first)) <= rows < moe.buffer_rows(T * K, E, 16)
+    plan = moe.dispatch_plan(jnp.asarray(idx), E, 16, first, rows)
+    y_buf = jnp.asarray(rng.normal(size=(plan.row_sel.shape[0], D)), dtype)
+    x = jnp.asarray(rng.normal(size=(T, D)), dtype)
+    gate_w = jnp.asarray(rng.uniform(0.1, 1.0, size=(T, K)), jnp.float32)
+    return plan, y_buf, x, gate_w
+
+
+@pytest.mark.parametrize("form", ["kernel", "xla"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["every_expert_held", "held_share", "empty_expert",
+                                  "token_holds_nothing", "short_buffer"])
+def test_token_side_sums_match_the_gather_of_every_selection(case, dtype, form, monkeypatch):
+    """The combine (value, ``dy_buf``, ``dgate_w``) and the dispatch's backward
+    (``dx``) against XLA's own derivative of the two lines they were,
+    ``_sum_held(_take_rows(buf, sel_row), scale)``: with the kernel forced
+    (interpret mode) and in XLA's form, where ``dgate_w`` is taken on the
+    buffer's side. The sums run in float32 in another order (the kernel's by
+    expert), so a float32 leaf agrees to 1e-6 of its largest value and a
+    bfloat16 one to a rounding of the cast."""
+    if form == "kernel":
+        monkeypatch.setenv("GMM_BACKEND", "pallas")
+    plan, y_buf, x, gate_w = _token_sum_case(case, jnp.dtype(dtype))
+    sin_sum = lambda out: jnp.sum(jnp.sin(out.astype(jnp.float32)))
+    probe = jnp.asarray(np.random.default_rng(6).normal(size=y_buf.shape), y_buf.dtype)
+
+    def gathered(y_buf, gate_w):
+        return moe._sum_held(moe._take_rows(y_buf, plan.sel_row),
+                             jnp.where(plan.sel_held, gate_w, 0), y_buf.dtype)
+    seen = moe.plan_counts()
+    got = [moe.combine_rows(y_buf, gate_w, plan),
+           *jax.grad(lambda y, w: sin_sum(moe.combine_rows(y, w, plan)), (0, 1))(y_buf, gate_w),
+           jax.grad(lambda x: sin_sum(moe.dispatch_rows(x, plan) * probe))(x)]
+    traced = {k: n - seen[k] for k, n in moe.plan_counts().items()}
+    # the combine alone, the differentiated combine and its ``dgate_w``, the dispatch's backward
+    assert traced["token_sum_kernel" if form == "kernel" else "token_sum_xla"] == 4
+    assert traced["token_sum_xla" if form == "kernel" else "token_sum_kernel"] == 0
+    want = [gathered(y_buf, gate_w),
+            *jax.grad(lambda y, w: sin_sum(gathered(y, w)), (0, 1))(y_buf, gate_w),
+            jax.grad(lambda x: sin_sum(moe._dispatch_rows(x, plan) * probe))(x)]
+    held_tokens = np.asarray(plan.sel_held).any(-1)
+    assert held_tokens.all() == (case in ("every_expert_held", "empty_expert"))
+    for name, a, b in zip(("out", "dy_buf", "dgate_w", "dx"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(b).max() > 0, name
+        tol = 1e-6 if b.dtype == a.dtype and dtype == "float32" or name == "dgate_w" else 2.0 ** -7
+        np.testing.assert_allclose(a, b, atol=tol * np.abs(b).max(), rtol=tol, err_msg=name)
+    assert not np.asarray(got[0], np.float32)[~held_tokens].any()   # a token that holds nothing sums nothing
+
+
+@pytest.mark.parametrize("arch", ["afmoe", "xing_mla_moe"])
+def test_a_routed_layers_gradient_builds_no_row_for_every_selection(arch, monkeypatch):
+    """One routed layer that holds a share, value and gradient, with the kernels
+    forced: no array ``[tokens, top-k, width]`` anywhere in the jaxpr (a
+    chunk's ``[T, K, D]``, which XLA's form gathers four times a layer), and
+    every token-side sum tallied as the kernel's; off the chip, unforced, the
+    same layer gathers it and tallies XLA's form."""
+    def traced(backend):
+        monkeypatch.setenv("GMM_BACKEND", backend)
+        block, layer, x, args = _tiny_block(arch, monkeypatch)
+        loss = lambda p, x: jnp.sum(jnp.sin(jax.checkpoint(lambda p, x: block(p, x))(p, x)))
+        seen = moe.plan_counts()
+        jaxpr = _live(jax.make_jaxpr(jax.value_and_grad(loss, (0, 1)))(layer, x))
+        chunk_tokens = 2 * 128 // moe.held_chunks(2 * 128, args.num_experts_per_tok, 2, 8, 128)[1]
+        shape = (chunk_tokens, args.num_experts_per_tok, args.hidden_size)
+        wide = _equations(jaxpr, lambda e: any(
+            getattr(v.aval, "shape", None) == shape for v in e.outvars))
+        return wide, {k: n - seen[k] for k, n in moe.plan_counts().items()}
+
+    wide, counts = traced("pallas")
+    assert not wide, [str(e.primitive) for e in wide]
+    assert counts["token_sum_kernel"] == 4 and counts["token_sum_xla"] == 0
+    wide, counts = traced("ragged")
+    assert wide and counts["token_sum_xla"] == 4 and counts["token_sum_kernel"] == 0
+
+
 def test_grouped_block_gradient_has_no_scatter_of_activation_rows(monkeypatch):
     """``moe_block`` with ``moe_impl: grouped`` on one device: no scatter or
     scatter-add of rows as wide as the activations in the lowered gradient
@@ -459,7 +551,9 @@ def test_grouped_block_gradient_has_no_scatter_of_activation_rows(monkeypatch):
     hlo = grad.lower(p, x).as_text(dialect="hlo")
     assert {k: n - seen[k] for k, n in moe.plan_counts().items()} == {
         "dispatch_gather": 1, "combine_gather": 1, "chunk_loop_tail": 0,   # no chunk loop here,
-        "chunk_two_sizes": 0, "chunk_trips_small": 0, "chunk_trips_whole": 0}   # and one buffer size
+        "chunk_two_sizes": 0, "chunk_trips_small": 0, "chunk_trips_whole": 0,   # and one buffer size;
+        # the combine, its backward's dgate_w and the dispatch's backward, XLA's form here
+        "token_sum_kernel": 0, "token_sum_xla": 3}
     assert " gather(" in hlo and not activation_scatters(hlo, D)
 
     experts, xs, idx, gate_w, E, bt, first = _dispatch_case("held_share", jnp.float32, D=D)
@@ -553,9 +647,12 @@ def test_a_rematerialised_layer_runs_its_held_experts_forward_twice_not_thrice(
         traced = {k: n - seen[k] for k, n in moe.plan_counts().items()}
         return jaxpr, traced, jax.jit(fwd)(layer, x), jax.jit(jax.grad(loss, (0, 1)))(layer, x)
 
-    # a chunk function is traced once a size, whatever differentiates it afterwards
+    # a chunk function is traced once a size, whatever differentiates it afterwards; its
+    # token-side passes once each where they run: the combine in the loop's forward and in the
+    # backward's recomputation of a chunk, its dgate_w and the dispatch's backward beside it
     once = {"dispatch_gather": 1 + two_sizes, "combine_gather": 1 + two_sizes,
-            "chunk_two_sizes": two_sizes, "chunk_trips_small": 2 * two_sizes, "chunk_trips_whole": 4}
+            "chunk_two_sizes": two_sizes, "chunk_trips_small": 2 * two_sizes, "chunk_trips_whole": 4,
+            "token_sum_kernel": 0, "token_sum_xla": 4 * (1 + two_sizes)}
     new, traced, got, grads = arrangement()
     assert traced == dict(once, chunk_loop_tail=1)
     monkeypatch.setattr(moe, "sigmoid_routed_ffn", tail_after_loop(moe.sigmoid_routed_ffn))

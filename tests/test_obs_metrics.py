@@ -489,7 +489,8 @@ def test_trainer_reports_the_flash_plan(tmp_path):
     # (models/moe.py): a dense model has none
     moe_plan = windows[0]["moe_plan"]
     assert moe_plan == {"dispatch_gather": 0, "combine_gather": 0, "chunk_loop_tail": 0,
-                        "chunk_two_sizes": 0, "chunk_trips_small": 0, "chunk_trips_whole": 0}
+                        "chunk_two_sizes": 0, "chunk_trips_small": 0, "chunk_trips_whole": 0,
+                        "token_sum_kernel": 0, "token_sum_xla": 0}
     assert "moe_plan" not in windows[1]
     # nor a grouped matmul kernel (ops/grouped_matmul.py)
     gmm_plan = windows[0]["gmm_plan"]

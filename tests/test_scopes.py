@@ -296,17 +296,18 @@ def test_named_scopes_in_the_program_are_the_vocabulary():
     # name the trace's reader keys on (plan_counts() tells them apart)
     assert sorted(kernels) == ["ce_softmax_grad", "flash_bwd_dkv", "flash_bwd_dkv",
                                "flash_bwd_dq", "flash_bwd_dq", "flash_fwd", "flash_fwd",
-                               "gmm", "ssm_scan_bwd", "ssm_scan_fwd", "tgmm"]
+                               "gmm", "ssm_scan_bwd", "ssm_scan_fwd", "tgmm", "token_dot", "token_sum"]
     assert pallas_calls == len(kernels), "a pallas_call without a name="
     # architecture xing_mla_moe opens two more, afmoe three, sambay five with
     # its two scan kernels and sdar_moe two, which the benchmark reads by their own helpers
     # (layer_metrics/_named_scopes.py, _attn_kinds.py, _ssm_scan.py, _blockdiff.py) until its
     # closed vocabulary takes them in; the head's kernel (ops/fused_ce.py) runs
-    # under ``lm_head_ce`` and is read as part of that scope
+    # under ``lm_head_ce`` and the expert layer's two token-side kernels
+    # (ops/token_sum.py) under ``moe_experts``, and each is read as part of that scope
     assert found | set(kernels) == VOCABULARY | {"hc_mix", "mtp"} | {
         "attn_window", "attn_global", "attn_gate"} | {
         "ssm", "ssm_proj", "ssm_conv", "gmu", "attn_diff", "ssm_scan_fwd", "ssm_scan_bwd"} | {
-        "ce_softmax_grad"} | {"attn_blockdiff", "bd_rows"}
+        "ce_softmax_grad", "token_sum", "token_dot"} | {"attn_blockdiff", "bd_rows"}
 
 
 # -- the host's turns ---------------------------------------------------------------

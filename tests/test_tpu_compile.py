@@ -28,6 +28,7 @@ from mlx_cuda_distributed_pretraining_tpu.ops import flash_attention as fa
 from mlx_cuda_distributed_pretraining_tpu.ops import fused_ce
 from mlx_cuda_distributed_pretraining_tpu.ops.attention import core_counts
 from mlx_cuda_distributed_pretraining_tpu.ops import grouped_matmul as gm
+from mlx_cuda_distributed_pretraining_tpu.ops import token_sum as ts
 
 HBM_BYTES = 16 * 10**9  # one v5e chip (Google Cloud TPU v5e documentation)
 
@@ -65,6 +66,7 @@ def compiled_kernels(monkeypatch):
     monkeypatch.setattr(fa, "_interpret", lambda: False)
     monkeypatch.setattr(gm, "_interpret", lambda: False)
     monkeypatch.setattr(fused_ce, "_interpret", lambda: False)
+    monkeypatch.setattr(ts, "_interpret", lambda: False)
     # Trace fresh: a core cached by an interpret-mode test would be reused.
     fa._cached_core.cache_clear()
 
@@ -298,6 +300,45 @@ def test_gmm_resident_plan_compiles_for_v5e_at_the_cells_shapes(cell, orientatio
     want = collections.Counter({"gmm_resident": 3, "tgmm_resident": 1, f"tgmm_bn{dw_bn}": 1})
     want.update({f"gmm_bn{N}": 2, f"gmm_bn{K}": 1})    # the forward twice, dX over the transposed weights
     assert traced == dict(want) and dw_bn >= 512
+
+
+# A routed layer's token-side sum in the two expert-parallel shapes (tokens of a
+# chunk of the small loop, top-k, width, rows and groups of its buffer), and a
+# decode step's four tokens through every expert of a small model.
+TOKEN_SUM_CELLS = {"trinity-mini-ep8": (16384, 8, 2048, 67584, 16, {"bfloat16": 128, "float32": 128}),
+                   "xing4_0-29b-a4b-ep8": (2048, 4, 3584, 5120, 8, {"bfloat16": 128, "float32": 0})}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("cell", list(TOKEN_SUM_CELLS))
+def test_token_sum_compiles_for_v5e_at_the_cells_shapes(cell, dtype, v5e, compiled_kernels):
+    """The kernels at the cells' own shapes (``token_sum`` with the combine's
+    float32 scale and with the 0/1 scale of the dispatch's backward, and
+    ``token_dot``): Mosaic takes the copies of 16 rows
+    out of the buffer as it lies in HBM and the VMEM the tile asks for under the
+    default scoped limit (XLA compiles a fusion into the call under that one);
+    and what the plan gives each shape: tiles of 128 tokens, and XLA's form for
+    float32 rows of 3,584 (two staging slots of them leave no room for a tile);
+    a decode step's few tokens as one tile; XLA's form off the
+    backend, for a buffer tiled by 8 and for rows too wide for any tile."""
+    T, K, D, rows, E, tiles = TOKEN_SUM_CELLS[cell]
+    tile, dtype = tiles[dtype], jnp.dtype(dtype)
+    assert ts.token_sum_plan(T, K, D, rows, dtype, "pallas") == tile
+    assert ts.token_sum_plan(4, 2, 512, 64, jnp.float32, "pallas") == 16     # a decode step: one tile
+    assert ts.token_sum_plan(4, 2, 512, 72, jnp.float32, "pallas") == 0      # its buffer tiled by 8
+    assert ts.token_sum_plan(T, K, D, rows, dtype, "blocked") == 0
+    assert ts.token_sum_plan(T, K, 64 * D, rows, dtype, "pallas") == 0
+    args = (_sds((rows, D), dtype, v5e), _sds((T, K), jnp.int32, v5e), _sds((T, K), jnp.bool_, v5e),
+            _sds((T, K), jnp.float32, v5e), _sds((E,), jnp.int32, v5e))
+    fns = [lambda buf, sel_row, sel_held, scale, sizes, exact=exact: ts.token_sum(
+        buf, sel_row, sel_held, scale, sizes, tile, exact_scale=exact) for exact in (False, True)]
+    # and ``dgate_w``'s kernel, a selection's row against its token's cotangent
+    fns.append(lambda buf, sel_row, sel_held, scale, sizes: ts.token_dot(
+        buf, sel_row, sel_held, jnp.zeros((T, D), buf.dtype) + scale[:, :1].astype(buf.dtype),
+        sizes, tile))
+    for fn in fns if tile else ():
+        hlo = jax.jit(fn).lower(*args).compile().as_text()
+        assert hlo.count("tpu_custom_call") == 1 and f"[{T},{K},{D}]" not in hlo
 
 
 def test_paged_decode_step_fits_one_v5e(v5e, monkeypatch):

@@ -233,7 +233,10 @@ def test_a_held_shares_gradient_has_no_scatter_of_activation_rows(tiny, monkeypa
     assert {k: n - seen[k] for k, n in moe_lib.plan_counts().items()} == {
         "dispatch_gather": 1, "combine_gather": 1, "chunk_loop_tail": 0,   # no tail was handed in,
         "chunk_two_sizes": 0,                               # and a quarter held is one buffer size,
-        "chunk_trips_small": 0, "chunk_trips_whole": 4}     # whose one loop is the whole buffer's
+        "chunk_trips_small": 0, "chunk_trips_whole": 4,     # whose one loop is the whole buffer's;
+        # the combine in the loop's forward and in the backward's recomputation, its dgate_w
+        # and the dispatch's backward
+        "token_sum_kernel": 0, "token_sum_xla": 4}
     assert " gather(" in hlo and " scatter(" in hlo         # the load's bincount is one
     assert not activation_scatters(hlo, C)
 
