@@ -244,7 +244,9 @@ def loss_fn(params: Params, batch: Dict[str, jnp.ndarray], args: SdarArgs,
     ``(loss, (count, stats))``: the routing statistics summed over the layers,
     the positions whose loss counted (``bd_loss_rows``) and what the attention
     plan traced visits of its tile grid (``bd_tiles_live`` of ``bd_tiles_grid``,
-    a head and forward call; 0 of 0 without the kernels)."""
+    a head and forward call, ``bd_tiles_masked`` of them under a mask over the
+    whole tile and ``bd_tiles_narrow`` in narrower squares; all 0 without the
+    kernels)."""
     del remat_ratio, include_aux, overlap  # no aux term; overlap: the llama stack's fsdp schedule
     if "noised_inputs" not in batch:
         raise KeyError("sdar_moe trains on a block-diffusion batch (noised_inputs, loss_weights): "
@@ -260,9 +262,10 @@ def loss_fn(params: Params, batch: Dict[str, jnp.ndarray], args: SdarArgs,
                          z_loss_weight)
     if not with_moe_stats:
         return loss, count
-    tiles = flash_ops.bd_tiles_traced() if args.attention_type == "flash" else {"live": 0, "grid": 0}
+    tiles = flash_ops.bd_tiles_traced() if args.attention_type == "flash" else {}
     stats = dict(stats, bd_loss_rows=(batch["loss_weights"] > 0).sum().astype(jnp.float32),
-                 bd_tiles_live=jnp.float32(tiles["live"]), bd_tiles_grid=jnp.float32(tiles["grid"]))
+                 **{f"bd_tiles_{k}": jnp.float32(tiles.get(k, 0))
+                    for k in ("live", "grid", "masked", "narrow")})
     return loss, (count, stats)
 
 

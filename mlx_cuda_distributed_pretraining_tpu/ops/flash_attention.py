@@ -33,7 +33,16 @@ models/attention/flash_attention.py:100,134-151). This is the real thing:
   sequence side by side, ops/masks.py) is live in two stretches of a tile's
   row or column, so its plan is a list of segments (:func:`_bd_kv_segments`,
   :func:`_bd_q_segments`): the resident walk runs each in turn over the one
-  accumulator, the streamed grid gates and clamps by their union;
+  accumulator, the streamed grid gates and clamps by their union. A segment
+  the canonical mask cuts says how the resident walk takes its tiles
+  (:class:`_Cut`): the copy of its rows and of its columns is known before
+  the tile starts, so its mask is one compare of two block-index vectors and
+  no program on ``[block, block]`` lattices; and the noised diagonal, where a
+  row sees its own block alone, goes in squares of ``max(128, B')`` down the
+  tile's diagonal, on the matching rows of the scratch, where the tile is a
+  square of several (512 x 512 tiles at a block length of 4: four squares of
+  128, a quarter of the tile's matmuls and exponentials). A mask program of
+  the caller's runs whole on every live tile;
 - GQA: native — each query head reads its KV group's K/V; dK/dV are
   accumulated per query head and group-reduced outside the kernel;
 - masks/score mods are traceable index-lattice functions (ops/masks.py)
@@ -55,6 +64,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import itertools
 import math
 import os
 import threading
@@ -67,7 +77,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
-from .masks import NEG_INF, MaskMod, ScoreMod
+from .masks import NEG_INF, MaskMod, ScoreMod, block_index
 
 # Lane width of the TPU vector unit: scratch vectors are padded to a full
 # register row so stores never touch partial lanes.
@@ -254,6 +264,16 @@ def _tile_dispatch(live, full, compute, masked):
 # an edge and a whole run of each copy's query tiles, a noised one by its own
 # blocks' rows alone. Each function works on a traced tile index (in a kernel)
 # and on a Python one (:func:`block_diffusion_tiles`).
+#
+# A segment is ``(lo, hi, cut)``. ``cut`` is static and says how the resident
+# walk takes the segment's tiles: ``False`` whole, no mask; ``True`` the caller's
+# mask program on the tile's index lattices (every live tile, under a program
+# that is not the canonical one); a :class:`_Cut` under the canonical mask,
+# where the copy of a segment's rows and of its columns is known before the tile
+# starts, so the live set is one compare of two block-index vectors, and the
+# noised diagonal, which no block crosses at a multiple of ``w``, is ``T / w``
+# squares of ``w`` and nothing between them (:func:`_bd_narrow_width`). The
+# streamed grid reads ``cut`` as masked or not.
 def _cdiv(a, b: int):
     return (a + b - 1) // b
 
@@ -264,13 +284,49 @@ def _where(test, a, b):
     return (a if test else b) if isinstance(test, bool) else jnp.where(test, a, b)
 
 
+class _Cut(NamedTuple):
+    """How the resident walk takes a tile the canonical mask cuts."""
+    # (rows, cols) -> bool on index vectors, one a column and one a row; None where a
+    # square is one block and nothing of it is masked
+    live: Optional[Callable]
+    width: Optional[int]     # the noised diagonal in squares this wide; None: the tile in one chunk
+
+
+def _bd_narrow_width(Bp: int, block_q: int, block_kv: int) -> Optional[int]:
+    """The width ``w`` of the squares a noised-diagonal tile is walked in, or
+    ``None`` for the whole tile at once: ``max(128, B')`` where the tile is a
+    square of more than one such ``w`` (no block crosses a multiple of ``w``,
+    so rows ``[a w, (a + 1) w)`` see columns ``[a w, (a + 1) w)`` and no other)."""
+    w = max(_LANES, Bp)
+    return w if block_q == block_kv and w < block_q and block_q % w == 0 and w % Bp == 0 else None
+
+
+def _bd_cuts(L: int, Bp: int, block_q: int, block_kv: int, canonical: bool):
+    """``(own, earlier)``: what a segment on the noised diagonal carries as its
+    ``cut``, and ``q_clean -> cut`` for the clean copy's cut tiles."""
+    if not canonical:
+        return True, lambda q_clean: True
+    blk = block_index(Bp)
+
+    def own_live(rows, cols):      # both noised: a row's own block
+        return blk(cols) == blk(rows)
+
+    def earlier(q_clean):          # keys clean: every earlier block, and a clean row's own
+        def live(rows, cols):
+            return blk(cols - L) < blk(rows - _where(q_clean, L, 0)) + _where(q_clean, 1, 0)
+        return _Cut(live, None)
+
+    if block_q == Bp == block_kv:  # a tile that is one block
+        return False, earlier
+    w = _bd_narrow_width(Bp, block_q, block_kv)
+    return _Cut(own_live if w != Bp else None, w), earlier
+
+
 def _bd_kv_segments(L: int, Bp: int, block_q: int, block_kv: int, canonical: bool = True):
-    """``qi -> ((lo, hi, masked), ...)``: the KV tiles of query tile ``qi``, in
-    ascending order; a segment with ``hi <= lo`` is empty. ``masked`` is static:
-    whether the segment's tiles run the in-tile mask (all do under a mask
-    program that is not the canonical one)."""
+    """``qi -> ((lo, hi, cut), ...)``: the KV tiles of query tile ``qi``, in
+    ascending order; a segment with ``hi <= lo`` is empty."""
     nL = L // block_kv
-    diagonal_masked = not canonical or not block_q == Bp == block_kv  # a tile that is one block
+    own_cut, earlier = _bd_cuts(L, Bp, block_q, block_kv, canonical)
 
     def segments(qi):
         row = qi * block_q
@@ -279,19 +335,22 @@ def _bd_kv_segments(L: int, Bp: int, block_q: int, block_kv: int, canonical: boo
         own = r // block_kv
         whole = nL + _where(clean, (r + Bp) // block_kv, own)
         end = nL + _where(clean, _cdiv(r + block_q, block_kv), _cdiv(r + block_q - Bp, block_kv))
-        return ((own, _where(clean, own, _cdiv(r + block_q, block_kv)), diagonal_masked),
-                (nL, whole, not canonical), (whole, end, True))
+        return ((own, _where(clean, own, _cdiv(r + block_q, block_kv)), own_cut),
+                (nL, whole, not canonical), (whole, end, earlier(clean)))
 
     return segments
 
 
-def _bd_q_segments(L: int, Bp: int, block_q: int, block_kv: int, canonical: bool = True):
+def _bd_q_segments(L: int, Bp: int, block_q: int, block_kv: int, canonical: bool = True,
+                   resident: bool = True):
     """:func:`_bd_kv_segments` along the other axis: the query tiles of KV
-    tile ``ki`` (the backward's dK/dV)."""
+    tile ``ki`` (the backward's dK/dV). The first stretch's cut tiles are a
+    noised tile's own blocks' rows, or the edge of the noised rows that read a
+    clean tile (empty where a tile is one block): two segments for the
+    ``resident`` walk, one of them empty, since each has its own closed form;
+    one for the streamed grid, which masks them alike."""
     nL = L // block_q
-    # the first segment: a noised tile's own blocks, or (empty where a tile is
-    # one block) the edge of the noised rows that read a clean tile
-    diagonal_masked = not canonical or not block_q == Bp == block_kv
+    own_cut, earlier = _bd_cuts(L, Bp, block_q, block_kv, canonical)
 
     def segments(ki):
         col = ki * block_kv
@@ -300,19 +359,46 @@ def _bd_q_segments(L: int, Bp: int, block_q: int, block_kv: int, canonical: bool
         own_end = _cdiv(c + block_kv, block_q)
         edge = nL + c // block_q                   # a noised tile's second stretch: empty, here
         whole = _where(clean, nL + _cdiv(c + block_kv - Bp, block_q), edge)
-        return ((_where(clean, (c + Bp) // block_q, c // block_q), own_end, diagonal_masked),
-                (own_end, _where(clean, nL, own_end), not canonical),
-                (edge, whole, True),
-                (whole, _where(clean, 2 * nL, edge), not canonical))
+        first = _where(clean, (c + Bp) // block_q, c // block_q)
+        if resident:
+            split = _where(clean, first, own_end)
+            head = ((first, split, own_cut), (split, own_end, earlier(False)))
+        else:
+            head = ((first, own_end, bool(own_cut)),)
+        return head + ((own_end, _where(clean, nL, own_end), not canonical),
+                       (edge, whole, earlier(True)),
+                       (whole, _where(clean, 2 * nL, edge), not canonical))
 
     return segments
 
 
-def _walk_segments(chunk, segments):
-    """The resident walk over a plan of segments: the masked ones a chunk a
-    trip, the whole ones ``_RESIDENT_UNROLL``, as :func:`_split_walk`."""
-    for lo, hi, masked in segments:
-        _walk(chunk, lo, hi, masked, 1 if masked else _RESIDENT_UNROLL)
+def _in_steps(steps):
+    """``chunk(j, mask, square=None)`` from a chunk's body written as a generator
+    that yields after its first matmuls and before its last; ``chunk.steps`` is
+    the generator, for :func:`_walk_segments` to run several bodies in step."""
+    def chunk(*args):
+        for _ in steps(*args):
+            pass
+    chunk.steps = steps
+    return chunk
+
+
+def _walk_segments(chunk, segments, block: int):
+    """The resident walk over a plan of segments: the cut ones a chunk a trip,
+    the whole ones ``_RESIDENT_UNROLL``, as :func:`_split_walk`; a cut of a
+    ``width`` as ``block / width`` squares ``(a, width)``, their bodies in
+    step: a square's matmuls are too small to hide its softmax, and one square
+    behind the other each waits for its own (forward 9.40 ms a call so, 9.29
+    in step, at cell 5's call: my chip runs, PR 50)."""
+    for lo, hi, cut in segments:
+        if isinstance(cut, _Cut) and cut.width:
+            def squares(j, cut, n=block // cut.width):
+                for _ in itertools.zip_longest(*(chunk.steps(j, cut, (a, cut.width))
+                                                 for a in range(n))):
+                    pass
+            _walk(squares, lo, hi, cut)
+        else:
+            _walk(chunk, lo, hi, cut, 1 if cut else _RESIDENT_UNROLL)
 
 
 def _segment_tests(segments, groups):
@@ -343,29 +429,32 @@ _BD_KV_GROUPS, _BD_Q_GROUPS = ((0,), (1, 2)), ((0, 1), (2, 3))
 
 
 def _bd_segments(mask_type: str, window: int, prefix_len: int, block_q: int, block_kv: int,
-                 canonical: bool, axis: str):
+                 canonical: bool, axis: str, resident: bool = True):
     """The segment plan of a call, ``None`` for every mask but
     ``block_diffusion`` (whose ``window`` is the block length and whose
     ``prefix_len`` the rows of one copy). ``axis``: ``"kv"`` | ``"q"``."""
     if mask_type != "block_diffusion":
         return None
-    plan = _bd_kv_segments if axis == "kv" else _bd_q_segments
-    return plan(prefix_len, window, block_q, block_kv, canonical)
+    if axis == "kv":
+        return _bd_kv_segments(prefix_len, window, block_q, block_kv, canonical)
+    return _bd_q_segments(prefix_len, window, block_q, block_kv, canonical, resident)
 
 
 # What the last forward traced under ``block_diffusion`` visits, a head: a
 # model's step reports it beside its other counters (models/sdar.py).
-_bd_tiles_traced = {"live": 0, "grid": 0}
+_bd_tiles_traced = {"live": 0, "grid": 0, "masked": 0, "narrow": 0}
 
 
-def _note_bd_tiles(tiles) -> None:
+def _note_bd_tiles(walk: Dict[str, int]) -> None:
     with _plan_counts_lock:
-        _bd_tiles_traced.update(live=int((tiles > 0).sum()), grid=int(tiles.size))
+        _bd_tiles_traced.update(walk)
 
 
 def bd_tiles_traced() -> Dict[str, int]:
-    """``{"live", "grid"}``: tiles the last traced ``block_diffusion`` forward
-    computes a head, and tiles of its whole grid (0 and 0: none traced)."""
+    """``{"live", "grid", "masked", "narrow"}``: tiles the last traced
+    ``block_diffusion`` forward computes a head, tiles of its whole grid, and of
+    the live ones those whose mask runs over the whole tile and those walked in
+    narrower squares (:func:`block_diffusion_walk`; all 0: none traced)."""
     with _plan_counts_lock:
         return dict(_bd_tiles_traced)
 
@@ -373,7 +462,8 @@ def bd_tiles_traced() -> Dict[str, int]:
 def _streamed_segments(mask_type, window, prefix_len, block_q, block_kv, canonical, axis):
     """``(live_full, clamp)`` of a streamed call (:func:`_segment_tests`), a
     pair of ``None`` for a mask with no segment plan."""
-    segments = _bd_segments(mask_type, window, prefix_len, block_q, block_kv, canonical, axis)
+    segments = _bd_segments(mask_type, window, prefix_len, block_q, block_kv, canonical, axis,
+                            resident=False)
     if segments is None:
         return None, None
     return _segment_tests(segments, _BD_KV_GROUPS if axis == "kv" else _BD_Q_GROUPS)
@@ -389,6 +479,25 @@ def block_diffusion_tiles(L: int, Bp: int, block_q: int, block_kv: int):
         for lo, hi, masked in segments(qi):
             out[qi, int(lo):max(int(hi), int(lo))] = 1 if masked else 2
     return out
+
+
+def block_diffusion_walk(L: int, Bp: int, block_q: int, block_kv: int, canonical: bool = True,
+                         resident: bool = True) -> Dict[str, int]:
+    """How the forward walks its plan, in tiles a head: ``live`` of ``grid``
+    visited; ``masked`` of them under a mask over the whole tile (the
+    caller's program, or a cut's closed form); ``narrow`` the noised-diagonal
+    tiles the resident walk takes in squares of :func:`_bd_narrow_width`, which
+    the streamed grid masks whole like the others."""
+    count = dict(live=0, grid=(2 * L // block_q) * (2 * L // block_kv), masked=0, narrow=0)
+    segments = _bd_kv_segments(L, Bp, block_q, block_kv, canonical)
+    for qi in range(2 * L // block_q):
+        for lo, hi, cut in segments(qi):
+            tiles = max(int(hi) - int(lo), 0)
+            count["live"] += tiles
+            if cut:
+                count["narrow" if resident and isinstance(cut, _Cut) and cut.width
+                      else "masked"] += tiles
+    return count
 
 
 # -- forward kernel ----------------------------------------------------------
@@ -486,6 +595,27 @@ def _lane_tile(x, n: int):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
+def _first(i, block: int, off: int = 0):
+    """The first index of tile ``i``'s part from ``off`` on."""
+    return i * block + off if off else i * block
+
+
+def _chunk_live(mask, mask_fn, row, col, row0, col0, rows: int, cols: int, k_major=False):
+    """The live set of one chunk's scores (``None``: all of them). ``mask`` is
+    how the plan walks the chunk: ``True`` the mask program on the index
+    lattices ``row``, ``col``; a :class:`_Cut` its closed form on two index
+    vectors, built here from the chunk's first row and column (``row0()``,
+    ``col0()``) for ``rows`` x ``cols`` scores, held ``[cols, rows]`` where
+    ``k_major``."""
+    if not isinstance(mask, _Cut):
+        return mask_fn(row, col) if mask else None
+    if mask.live is None:
+        return None
+    r_shape, c_shape = ((1, rows), (cols, 1)) if k_major else ((rows, 1), (1, cols))
+    return mask.live(row0() + jax.lax.broadcasted_iota(jnp.int32, r_shape, int(k_major)),
+                     col0() + jax.lax.broadcasted_iota(jnp.int32, c_shape, int(not k_major)))
+
+
 def _walk(chunk, lo, hi, apply_mask, unroll=1):
     """``chunk(j, apply_mask)`` for ``lo <= j < hi`` in order (none where
     ``hi <= lo``). The bounds are traced, so an unroll is by hand: ``unroll``
@@ -538,31 +668,45 @@ def _fwd_resident_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_
     acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
     q = q_ref[0, 0]
 
-    def chunk(j, apply_mask):
-        cols = pl.ds(pl.multiple_of(j * bkv, bkv), bkv)
+    @_in_steps
+    def chunk(j, mask, square=None):
+        # ``square`` (a, w): the tile's rows [a w, (a + 1) w) against the same
+        # columns of chunk j and no other, on their rows of the three scratches
+        if square is None:
+            tile, n, off, q_rows = ..., bkv, 0, q
+        else:
+            n, off = square[1], square[0] * square[1]
+            tile = pl.ds(off, n)
+            q_rows = q_ref[0, 0, tile, :]
+        row0, col0 = (lambda: _first(qi, bq, off)), (lambda: _first(j, bkv, off))
+        cols = pl.ds(pl.multiple_of(col0(), n), n)
         k = k_ref[0, 0, cols, :]
         v = v_ref[0, 0, cols, :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        s = jax.lax.dot_general(q_rows, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if score_fn is not None or apply_mask:
-            row = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
-            col = j * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
+        yield
+        rows, row, col = s.shape[0], None, None
+        if score_fn is not None or mask is True:
+            row = row0() + jax.lax.broadcasted_iota(jnp.int32, (rows, n), 0)
+            col = col0() + jax.lax.broadcasted_iota(jnp.int32, (rows, n), 1)
             if score_fn is not None:
                 s = score_fn(s, row, col, h)
-            if apply_mask:
-                s = jnp.where(mask_fn(row, col), s, NEG_INF)
-        m = m_scr[...]                                       # [bq, _LANES]
+        live = _chunk_live(mask, mask_fn, row, col, row0, col0, rows, n)
+        if live is not None:
+            s = jnp.where(live, s, NEG_INF)
+        m = m_scr[tile]                                      # [bq, _LANES]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - _lane_tile(m_new, bkv))
+        p = jnp.exp(s - _lane_tile(m_new, n))
         alpha = jnp.exp(m - m_new)
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * _lane_tile(alpha, D) + jax.lax.dot_general(
+        l_scr[tile] = alpha * l_scr[tile] + jnp.sum(p, axis=-1, keepdims=True)
+        yield
+        acc_scr[tile] = acc_scr[tile] * _lane_tile(alpha, D) + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        m_scr[tile] = m_new
 
     if segments is not None:
-        _walk_segments(chunk, segments(qi))
+        _walk_segments(chunk, segments(qi), bq)
     else:
         _split_walk(chunk, kv_lo(qi), kv_hi(qi), qi, full_range, mask_fn is not None)
 
@@ -700,13 +844,15 @@ def _full_range_q(mask_type: str, window: int, prefix_len: int,
     }[mask_type]
 
 
-def _bwd_p_ds(s_raw, dp, lse, delta, row, col, h, *, scale, mask_fn, score_fn, apply_mask):
+def _bwd_p_ds(s_raw, dp, lse, delta, row, col, h, *, scale, score_fn, live):
     """``(p, ds)`` of one tile from its raw scores and ``dp = dO V^T``, in
     whichever orientation the caller holds them: ``lse`` and ``delta``
-    broadcast against the tile, ``row``/``col`` are its index lattices."""
+    broadcast against the tile, ``row``/``col`` are its index lattices and
+    ``live()`` its live set (:func:`_chunk_live`)."""
     s = score_fn(s_raw, row, col, h) if score_fn is not None else s_raw
-    if apply_mask:
-        s = jnp.where(mask_fn(row, col), s, NEG_INF)
+    live = live()
+    if live is not None:
+        s = jnp.where(live, s, NEG_INF)
     p = jnp.exp(s - lse)
     ds = p * (dp - delta)
     d_mod = getattr(score_fn, "_d_score", None) if score_fn is not None else None
@@ -731,26 +877,40 @@ def _bwd_dq_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_
     lse = lse_ref[0, 0, 0].astype(jnp.float32)[:, None]       # [bq, 1]
     delta = delta_ref[0, 0, 0].astype(jnp.float32)[:, None]
 
-    def chunk(j, apply_mask):
-        cols = pl.ds(pl.multiple_of(j * bkv, bkv), bkv)
+    @_in_steps
+    def chunk(j, mask, square=None):
+        # ``square`` (a, w): as the forward's, on rows [a w, (a + 1) w) of dq_scr
+        if square is None:
+            tile, n, off = ..., bkv, 0
+            q_rows, do_rows, lse_rows, delta_rows = q, do, lse, delta
+        else:
+            n, off = square[1], square[0] * square[1]
+            tile = pl.ds(off, n)
+            q_rows, do_rows = q_ref[0, 0, tile, :], do_ref[0, 0, tile, :]
+            lse_rows, delta_rows = lse[off:off + n], delta[off:off + n]
+        row0, col0 = (lambda: _first(qi, bq, off)), (lambda: _first(j, bkv, off))
+        cols = pl.ds(pl.multiple_of(col0(), n), n)
         k = k_ref[0, 0, cols, :]
         v = v_ref[0, 0, cols, :]
-        s_raw = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        s_raw = jax.lax.dot_general(q_rows, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32) * scale
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+        dp = jax.lax.dot_general(do_rows, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        row = col = None
-        if score_fn is not None or apply_mask:
-            row = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
-            col = j * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
-        _, ds = _bwd_p_ds(s_raw, dp, lse, delta, row, col, h, scale=scale,
-                          mask_fn=mask_fn, score_fn=score_fn, apply_mask=apply_mask)
-        dq_scr[...] = dq_scr[...] + jax.lax.dot_general(
+        yield
+        rows, row, col = s_raw.shape[0], None, None
+        if score_fn is not None or mask is True:
+            row = row0() + jax.lax.broadcasted_iota(jnp.int32, (rows, n), 0)
+            col = col0() + jax.lax.broadcasted_iota(jnp.int32, (rows, n), 1)
+        _, ds = _bwd_p_ds(s_raw, dp, lse_rows, delta_rows, row, col, h, scale=scale,
+                          score_fn=score_fn,
+                          live=lambda: _chunk_live(mask, mask_fn, row, col, row0, col0, rows, n))
+        yield
+        dq_scr[tile] = dq_scr[tile] + jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     if segments is not None:
-        _walk_segments(chunk, segments(qi))
+        _walk_segments(chunk, segments(qi), bq)
     else:
         _split_walk(chunk, kv_lo(qi), kv_hi(qi), qi, full_range, mask_fn is not None)
     dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
@@ -772,31 +932,44 @@ def _bwd_dkv_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk
     k = k_ref[0, 0]
     v = v_ref[0, 0]
 
-    def chunk(j, apply_mask):
-        rows = pl.ds(pl.multiple_of(j * bq, bq), bq)
+    @_in_steps
+    def chunk(j, mask, square=None):
+        # ``square`` (a, w): the tile's columns [a w, (a + 1) w), on their rows of
+        # dk_scr and dv_scr, against the same w query rows of chunk j and no other
+        if square is None:
+            tile, n, off, k_cols, v_cols = ..., bq, 0, k, v
+        else:
+            n, off = square[1], square[0] * square[1]
+            tile = pl.ds(off, n)
+            k_cols, v_cols = k_ref[0, 0, tile, :], v_ref[0, 0, tile, :]
+        row0, col0 = (lambda: _first(j, bq, off)), (lambda: _first(ki, bkv, off))
+        rows = pl.ds(pl.multiple_of(row0(), n), n)
         q = q_ref[0, 0, rows, :]
         do = do_ref[0, 0, rows, :]
-        s_raw = jax.lax.dot_general(k, q, (((1,), (1,)), ((), ())),
+        s_raw = jax.lax.dot_general(k_cols, q, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32) * scale
-        dp = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+        dp = jax.lax.dot_general(v_cols, do, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        row = col = None
-        if score_fn is not None or apply_mask:
-            row = j * bq + jax.lax.broadcasted_iota(jnp.int32, (bkv, bq), 1)
-            col = ki * bkv + jax.lax.broadcasted_iota(jnp.int32, (bkv, bq), 0)
+        yield
+        cols, row, col = s_raw.shape[0], None, None
+        if score_fn is not None or mask is True:
+            row = row0() + jax.lax.broadcasted_iota(jnp.int32, (cols, n), 1)
+            col = col0() + jax.lax.broadcasted_iota(jnp.int32, (cols, n), 0)
         p, ds = _bwd_p_ds(s_raw, dp, lse_ref[0, 0, :, rows].astype(jnp.float32),
                           delta_ref[0, 0, :, rows].astype(jnp.float32), row, col, h,
-                          scale=scale, mask_fn=mask_fn, score_fn=score_fn,
-                          apply_mask=apply_mask)
-        dv_scr[...] = dv_scr[...] + jax.lax.dot_general(
+                          scale=scale, score_fn=score_fn,
+                          live=lambda: _chunk_live(mask, mask_fn, row, col, row0, col0, n, cols,
+                                                   k_major=True))
+        yield
+        dv_scr[tile] = dv_scr[tile] + jax.lax.dot_general(
             p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dk_scr[...] = dk_scr[...] + jax.lax.dot_general(
+        dk_scr[tile] = dk_scr[tile] + jax.lax.dot_general(
             ds.astype(q.dtype), q, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     if segments is not None:
-        _walk_segments(chunk, segments(ki))
+        _walk_segments(chunk, segments(ki), bkv)
     else:
         _split_walk(chunk, q_lo(ki), q_hi(ki), ki, full_range, mask_fn is not None)
     dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
@@ -983,7 +1156,8 @@ def flash_fwd(q, k, v, *, mask_fn=None, score_fn=None, mask_type="causal",
     shapes; ``_path`` is for tests, which run both on one input."""
     plan = _traced_plan("flash_fwd", q, k, v, block_q, block_kv, _path)
     if mask_type == "block_diffusion":
-        _note_bd_tiles(block_diffusion_tiles(prefix_len, window, plan.block_q, plan.block_kv))
+        _note_bd_tiles(block_diffusion_walk(prefix_len, window, plan.block_q, plan.block_kv,
+                                            canonical_mask, plan.path == "resident"))
     fwd = _flash_fwd_resident if plan.path == "resident" else _flash_fwd_streamed
     return fwd(q, k, v, plan.block_q, plan.block_kv, mask_fn=mask_fn, score_fn=score_fn,
                mask_type=mask_type, window=window, prefix_len=prefix_len,

@@ -96,6 +96,16 @@ def prefix_lm(prefix_len: int) -> MaskMod:
     return mod
 
 
+def block_index(block_length: int) -> Callable:
+    """``r -> r // block_length`` for index arrays that are never negative: a
+    shift where it can be one (a vector divide is the slow way to say it inside
+    a kernel)."""
+    Bp = int(block_length)
+    if Bp & (Bp - 1) == 0:
+        return lambda r: jnp.right_shift(r, Bp.bit_length() - 1)
+    return lambda r: r // Bp
+
+
 @lru_cache(maxsize=None)
 def block_diffusion(seq_len: int, block_length: int) -> MaskMod:
     """The training mask of diffusion over blocks (BD3-LM, arXiv:2503.09573),
@@ -111,10 +121,7 @@ def block_diffusion(seq_len: int, block_length: int) -> MaskMod:
     if L < 1 or Bp < 1 or L % Bp:
         raise ValueError(f"block_diffusion: block length {Bp} does not divide the sequence {L}")
 
-    # rows are never negative: a shift where it can be one (a vector divide is
-    # the slow way to say it inside a kernel)
-    blk = (lambda r: jnp.right_shift(r, Bp.bit_length() - 1)) if Bp & (Bp - 1) == 0 \
-        else (lambda r: r // Bp)
+    blk = block_index(Bp)
 
     def mod(q, k):
         q_clean, k_clean = q >= L, k >= L

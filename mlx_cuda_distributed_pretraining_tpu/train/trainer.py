@@ -1404,10 +1404,12 @@ class Trainer:
                         if window_bd_rows:
                             # Diffusion over blocks (models/sdar.py): positions whose loss
                             # counted in the window, and what the attention plan traced
-                            # visits of its tile grid, a head and forward call.
+                            # visits of its tile grid, a head and forward call: of the
+                            # live tiles, those masked whole and those walked in squares.
                             line["bd_loss_rows"] = int(sum(float(r) for r in window_bd_rows))
                             window_bd_rows = []
-                            for term in ("bd_tiles_live", "bd_tiles_grid"):
+                            for term in ("bd_tiles_live", "bd_tiles_grid", "bd_tiles_masked",
+                                         "bd_tiles_narrow"):
                                 line[term] = int(metrics[term])
                         if int(metrics["nonfinite"]):
                             self.logger.log(f"WARNING: non-finite loss at step {step}")
@@ -1447,7 +1449,8 @@ class Trainer:
                             ev.update({k: line[k] for k in (
                                 "moe_rows_held", "moe_chunks_whole", "moe_load_max_over_mean",
                                 "moe_drop", "main_loss", "mtp_loss", "bd_loss_rows",
-                                "bd_tiles_live", "bd_tiles_grid") if k in line})
+                                "bd_tiles_live", "bd_tiles_grid", "bd_tiles_masked",
+                                "bd_tiles_narrow") if k in line})
                             seen = hoststats.window_totals()
                             ev.update(hoststats.window_fields(self._host_seen, seen))
                             self._host_seen = seen

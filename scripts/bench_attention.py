@@ -16,10 +16,17 @@ Four modes:
   row a trace's ``flash_fwd`` self time compares with: the cell
   ``mistral-7b-v0_3-l4.train-1chip`` is
   ``--heads 32 --kv-heads 8 --head-dim 128 --batch 4 --seq 4096``.
+  ``--mask-type sliding_window --window W`` and ``--mask-type block_diffusion
+  --block-length B'`` (``--seq`` is then the call's ``2 L`` rows, both copies)
+  count the mask's own pairs (``flops/flash_window.py``: the band;
+  ``flops/flash_blockdiff.py``: ``L^2 + L B'`` a head): the cell
+  ``sdar-30b-a3b-ep8.train-bd8k`` is ``--heads 32 --kv-heads 4 --head-dim 128
+  --batch 1 --seq 16384 --mask-type block_diffusion --block-length 4``.
 - ``--backward-only``: the same rows for the raw ``flash_bwd_dq`` and
   ``flash_bwd_dkv`` (``--kernels``), on the residuals of one forward call
   (3 and 4 half squares a causal call by the benchmark's count): what a
   trace's ``flash_bwd_dq`` / ``flash_bwd_dkv`` self times compare with.
+  Given with ``--forward-only``, one process prints all three kernels' rows.
 
 Methodology: each measurement jits an on-device ``lax.fori_loop`` that
 chains N attention calls (output feeds the next query, so nothing is
@@ -73,13 +80,37 @@ def attn_flops(B, H, Sq, Skv, D, causal=True):
     return f / 2 if causal else f
 
 
+def mask_call(a):
+    """``(keywords of a raw kernel call, name -> operations of one call)`` under
+    ``--mask-type``: the mask's program and plan, and the benchmark's count of
+    the pairs it admits at ``[B, Hq, S, D]``."""
+    from benchmark.flops import flash_attention, flash_blockdiff, flash_window
+    from mlx_cuda_distributed_pretraining_tpu.ops import masks as M
+
+    shape = (a.batch, a.heads, a.seq, a.head_dim)
+    kw = dict(mask_type=a.mask_type, canonical_mask=a.mask_type != "full",
+              scale=a.head_dim ** -0.5)
+    if a.mask_type == "block_diffusion":
+        kw.update(mask_fn=M.block_diffusion(a.seq // 2, a.block_length),
+                  window=a.block_length, prefix_len=a.seq // 2)
+        count = {n: f(*shape, a.block_length) for n, f in flash_blockdiff.BY_KERNEL.items()}
+    elif a.mask_type == "sliding_window":
+        kw.update(mask_fn=M.sliding_window(a.window), window=a.window)
+        count = {n: f(*shape, a.window) for n, f in flash_window.BY_KERNEL.items()}
+    else:
+        # the benchmark's count is the causal call's; a full mask runs twice that
+        causal = a.mask_type == "causal"
+        kw.update(mask_fn=M.causal() if causal else None)
+        count = {n: f(*shape) * (1 if causal else 2)
+                 for n, f in flash_attention.BY_KERNEL.items()}
+    return kw, count
+
+
 def raw_kernel_rows(a, dtype):
     """Rows of the raw kernels, forward or backward; ``path`` "auto" leaves
     the choice to ``flash_plan`` and any other value forces it."""
     from benchmark import peaks
-    from benchmark.flops import flash_attention as flops
     from mlx_cuda_distributed_pretraining_tpu.ops import flash_attention as fa
-    from mlx_cuda_distributed_pretraining_tpu.ops import masks as M
 
     B, S, Hq, Hkv, D = a.batch, a.seq, a.heads, a.kv_heads, a.head_dim
     peak = peaks.peak(jax.devices()[0].device_kind)["bf16_flops"]
@@ -87,13 +118,11 @@ def raw_kernel_rows(a, dtype):
     q = jax.random.normal(ks[0], (B, Hq, S, D), dtype)
     k = jax.random.normal(ks[1], (B, Hkv, S, D), dtype)
     v = jax.random.normal(ks[2], (B, Hkv, S, D), dtype)
-    causal = a.mask_type == "causal"
-    mask_kw = dict(mask_type=a.mask_type, mask_fn=M.causal() if causal else None,
-                   canonical_mask=causal, scale=D ** -0.5)
-    rest = ()
+    mask_kw, count = mask_call(a)
+    rest, kernels = (), {}
     if a.forward_only:
-        kernels = {"flash_fwd": lambda qq, kk, vv, **kw: fa.flash_fwd(qq, kk, vv, **kw)[0]}
-    else:
+        kernels["flash_fwd"] = lambda qq, kk, vv, *_, **kw: fa.flash_fwd(qq, kk, vv, **kw)[0]
+    if a.backward_only:
         # the residuals a backward call gets: o and lse of the forward, a
         # cotangent, and delta = rowsum(dO * O)
         g = jax.random.normal(ks[3], (B, Hq, S, D), dtype)
@@ -103,10 +132,9 @@ def raw_kernel_rows(a, dtype):
         # dK per query head has q's shape where Sq == Skv: chained like dQ
         backward = {"dq": fa.flash_bwd_dq,
                     "dkv": lambda *ops, **kw: fa.flash_bwd_dkv(*ops, **kw)[0]}
-        kernels = {f"flash_bwd_{n}": backward[n] for n in a.kernels.split(",")}
+        kernels.update({f"flash_bwd_{n}": backward[n] for n in a.kernels.split(",")})
     for name, fn in kernels.items():
-        # the benchmark's count is the causal call's; a full mask runs twice that
-        fl = flops.BY_KERNEL[name](B, Hq, S, D) * (1 if causal else 2)
+        fl = count[name]
         for path in a.paths.split(","):
             for blocks in a.blocks.split(","):
                 # "auto": the path's own default blocks
@@ -117,6 +145,10 @@ def raw_kernel_rows(a, dtype):
                 row = {"name": name, "mask_type": a.mask_type, "path": path,
                        "block_q": bq, "block_kv": bkv, "B": B, "Hq": Hq, "Hkv": Hkv,
                        "S": S, "D": D}
+                if a.mask_type == "sliding_window":
+                    row["window"] = a.window
+                if a.mask_type == "block_diffusion":
+                    row["block_length"] = a.block_length
                 try:
                     t = timed_loop(lambda *ops: fn(*ops, **kw), q, k, v, *rest, n_hi=45)
                     row.update(ms=round(t * 1e3, 3), share_of_peak=round(fl / t / peak, 4))
@@ -141,7 +173,12 @@ def main():
                         help="KV heads (default: --heads)")
     parser.add_argument("--batch", type=int, default=4)
     parser.add_argument("--seq", type=int, default=4096)
-    parser.add_argument("--mask-type", default="causal", choices=("causal", "full"))
+    parser.add_argument("--mask-type", default="causal",
+                        choices=("causal", "full", "sliding_window", "block_diffusion"))
+    parser.add_argument("--window", type=int, default=2048,
+                        help="the band of --mask-type sliding_window")
+    parser.add_argument("--block-length", type=int, default=4,
+                        help="B' of --mask-type block_diffusion, whose --seq is both copies' rows")
     parser.add_argument("--blocks", default="auto",
                         help="comma list of auto | block_q x block_kv for --forward-only "
                              "and --backward-only")

@@ -601,28 +601,51 @@ def test_flash_plan_takes_both_head_sizes():
 
 
 # -- the two-copy mask of diffusion over blocks ----------------------------------------
-@pytest.mark.parametrize("L,Bp", [(128, 4), (256, 4), (512, 8), (256, 128), (512, 64)],
-                         ids=lambda v: str(v))
-def test_block_diffusion_kernels_match_reference(L, Bp, flash_path):
+# (rows a copy, block length, tile, KV heads of 4 query heads, the squares a
+# noised-diagonal tile is walked in on the resident path: 0 = the whole tile at once)
+_BD_CASES = [(128, 4, 128, 2, 0), (256, 4, 128, 2, 0), (512, 8, 128, 2, 0), (256, 128, 128, 2, 0),
+             (512, 64, 128, 2, 0),
+             # the benchmark cell's tiles of 512 x 512, two a copy, GQA 4:1: squares of
+             # max(128, B') where the tile holds several, else the tile whole
+             (1024, 4, 512, 1, 4), (1024, 64, 512, 1, 4), (1024, 128, 512, 1, 4),
+             (1024, 256, 512, 1, 2), (1024, 512, 512, 1, 0)]
+# under a mask program of the caller's: a case a tile size, and one whose squares are one block
+_BD_CUSTOM = [case for case in _BD_CASES if case[:2] in ((256, 4), (1024, 4), (1024, 128))]
+
+
+@pytest.mark.parametrize("L,Bp,block,hkv,squares,custom",
+                         [c + (False,) for c in _BD_CASES] + [c + (True,) for c in _BD_CUSTOM],
+                         ids=lambda v: {True: "custom_mask_fn", False: "canonical"}.get(v, str(v))
+                         if isinstance(v, bool) else str(v))
+def test_block_diffusion_kernels_match_reference(L, Bp, block, hkv, squares, custom, flash_path):
     """Forward, dQ and dK/dV under ``block_diffusion`` against
     ``reference_attention`` on the materialised mask, where a copy is 1, 2 and
-    4 tiles of 128 and a block is a few rows or a whole tile, GQA 2:1."""
+    4 tiles and a block is a few rows, a square of a tile or a whole tile; the
+    canonical mask through the cut segments' closed forms and the narrow walk
+    of the noised diagonal, a custom ``mask_fn`` through the caller's program
+    on every live tile, whole."""
     rng = np.random.default_rng(L + Bp)
     q, k, v, g = (jnp.asarray(rng.normal(size=(1, 2 * L, h, D)).astype(np.float32))
-                  for h in (4, 2, 2, 4))
+                  for h in (4, hkv, hkv, 4))
     mod = M.block_diffusion(L, Bp)
+    if custom:
+        mod = lambda r, c, named=mod: named(r, c) & (((r + c) % 7) != 0)  # noqa: E731
     out, vjp = jax.vjp(lambda q, k, v: flash_attention(
-        q, k, v, mask_type="block_diffusion", window_size=Bp, block_q=128, block_kv=128), q, k, v)
+        q, k, v, mask_type="block_diffusion", window_size=Bp, block_q=block, block_kv=block,
+        mask_fn=mod if custom else None), q, k, v)
     want, vjp_ref = jax.vjp(lambda q, k, v: reference_attention(q, k, v, mask_mod=mod), q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
     for got, ref, name in zip(vjp(g), vjp_ref(g), ("dq", "dk", "dv")):
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-4, rtol=1e-4,
                                    err_msg=name)
-    # flex takes the named mask's plan from its tag
-    flex = flex_attention(q, k, v, mask_mod=mod, block_q=128, block_kv=128)
-    np.testing.assert_allclose(np.asarray(flex), np.asarray(want), atol=2e-5, rtol=2e-5)
-    assert fa.bd_tiles_traced() == {"live": int((fa.block_diffusion_tiles(L, Bp, 128, 128) > 0).sum()),
-                                    "grid": (2 * L // 128) ** 2}
+    tiles = fa.block_diffusion_tiles(L, Bp, block, block)
+    live, cut, diagonal = int((tiles > 0).sum()), int((tiles == 1).sum()), L // block
+    narrow = diagonal if squares and flash_path == "resident" and not custom else 0
+    assert fa.bd_tiles_traced() == {"live": live, "grid": tiles.size, "narrow": narrow,
+                                    "masked": (live if custom else cut) - narrow}
+    if not custom:  # flex takes the named mask's plan from its tag
+        flex = flex_attention(q, k, v, mask_mod=mod, block_q=block, block_kv=block)
+        np.testing.assert_allclose(np.asarray(flex), np.asarray(want), atol=2e-5, rtol=2e-5)
 
 
 @pytest.mark.parametrize("L,Bp,bq,bkv", [
@@ -639,7 +662,9 @@ def test_block_diffusion_plan_visits_the_live_tiles_and_no_other(L, Bp, bq, bkv)
     plans = {"kv": (fa._bd_kv_segments(L, Bp, bq, bkv), fa._BD_KV_GROUPS, nq, nkv, lambda i, j: want[i, j]),
              "q": (fa._bd_q_segments(L, Bp, bq, bkv), fa._BD_Q_GROUPS, nkv, nq, lambda i, j: want[j, i])}
     for axis, (segments, groups, n, m, tile) in plans.items():
-        live_full, clamp = fa._segment_tests(segments, groups)
+        # the grid's form of the plan: the query axis' first two cut segments as one
+        live_full, clamp = fa._segment_tests(
+            fa._bd_q_segments(L, Bp, bq, bkv, resident=False) if axis == "q" else segments, groups)
         for i in range(n):
             seen = np.zeros(m, np.int8)
             last = -1
@@ -657,9 +682,22 @@ def test_block_diffusion_plan_visits_the_live_tiles_and_no_other(L, Bp, bq, bkv)
                 assert (live, full) == (seen[j] > 0, seen[j] == 2), (axis, i, j)
                 assert seen[held[j]] > 0 and (held[j] == j if live else True), (axis, i, j)
             assert held == sorted(held)                              # the pipeline never goes back
-    # 288 of 1,024 tiles at the benchmark cell's size, 48 of them cut by an edge
+    # 288 of 1,024 tiles at the benchmark cell's size, 48 of them cut by an edge:
+    # the 16 of the noised diagonal walked in four squares of 128, the others whole
     cell = fa.block_diffusion_tiles(8192, 4, 512, 512)
     assert (int((cell > 0).sum()), int((cell == 1).sum()), cell.size) == (288, 48, 1024)
+    assert fa.block_diffusion_walk(8192, 4, 512, 512) == {
+        "live": 288, "grid": 1024, "masked": 32, "narrow": 16}
+    assert fa.block_diffusion_walk(8192, 4, 512, 512, resident=False) == {
+        "live": 288, "grid": 1024, "masked": 48, "narrow": 0}
+    assert fa.block_diffusion_walk(8192, 4, 512, 512, canonical=False) == {
+        "live": 288, "grid": 1024, "masked": 288, "narrow": 0}
+    walk = fa.block_diffusion_walk(L, Bp, bq, bkv)
+    assert (walk["live"], walk["masked"] + walk["narrow"]) == (int((want > 0).sum()),
+                                                              int((want == 1).sum()))
+    assert fa._bd_narrow_width(4, 512, 512) == 128 and fa._bd_narrow_width(256, 512, 512) == 256
+    assert [fa._bd_narrow_width(*a) for a in ((512, 512, 512), (4, 128, 128), (4, 256, 512),
+                                              (96, 384, 384))] == [None] * 4
 
 
 def test_block_diffusion_takes_the_reference_path_where_a_tile_would_straddle():

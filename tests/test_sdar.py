@@ -116,8 +116,10 @@ def test_program_matches_reference_loss_and_every_gradient(tiny, reference_step,
     assert float(stats["moe_load"].sum()) == 2 * B * L * cfg["num_experts_per_tok"] * len(params["layers"])
     assert float(stats["moe_dropped"]) == 0
     assert float(stats["bd_loss_rows"]) == float((batch["loss_weights"] > 0).sum())
-    tiles = (float(stats["bd_tiles_live"]), float(stats["bd_tiles_grid"]))
-    assert tiles == ((3.0, 4.0) if attention_type == "flash" else (0.0, 0.0))
+    tiles = {k: int(stats[f"bd_tiles_{k}"]) for k in ("live", "grid", "masked", "narrow")}
+    # one tile a copy: the three live ones cut by the mask, none wide enough for squares
+    assert tiles == (dict(live=3, grid=4, masked=3, narrow=0) if attention_type == "flash"
+                     else dict(live=0, grid=0, masked=0, narrow=0))
 
 
 def test_program_logits_match_reference(tiny):
@@ -408,11 +410,17 @@ def test_the_cell_rehearses_through_its_traffic_kind(tmp_path, monkeypatch):
     assert mix["seq_len"] == 8192 and mix["documents"] == dict(base_mix["documents"], max=8192)
     config = harness.merge_into(config, TINY["config"])
     mix = harness.merge_into(mix, TINY["traffic"])
-    cell = dict(cell, limits={k: 0.05 for k in cell["limits"]})
+    # every number held to 0.05 but the weights' change, which the cell's own limit holds:
+    # at these widths the second layer's router reads 0.137 (64 x 8 numbers whose update Adafactor
+    # scales from a small gradient; the other seven numbers read 9e-6 to 0.013)
+    cell = dict(cell, limits={k: v if k == "param_change_gap" else 0.05
+                              for k, v in cell["limits"].items()})
+    assert cell["limits"]["param_change_gap"] == 0.3
     ctx = harness.Context(cell, config, mix, seed=3_000_000_019, seconds=1.5, trace=False,
                           rehearse=True, workdir=str(tmp_path), quiet=True)
     res = kind.run(ctx)
     assert res["correct"], res["check_numbers"]
+    assert max(v for k, v in res["check_numbers"].items() if k != "param_change_gap") < 0.05
     assert res["sources"]["block_length"] == 4 and res["sources"]["tokens_per_step"] == 2 * 128
     # one loss term a step, three steps; the three worst-leaf gaps; the two over the unrouted leaves
     assert len(res["check_numbers"]) == 3 + 3 + 2
@@ -421,10 +429,12 @@ def test_the_cell_rehearses_through_its_traffic_kind(tmp_path, monkeypatch):
     assert max(v for k, v in res["check_numbers"].items() if k.startswith("loss_gap")) < 1e-3
     events = res["sources"]["step_window_events"]
     assert events and all({"moe_rows_held", "moe_chunks_whole", "moe_drop", "bd_loss_rows",
-                           "bd_tiles_live", "bd_tiles_grid"} <= set(e) for e in events)
+                           "bd_tiles_live", "bd_tiles_grid", "bd_tiles_masked",
+                           "bd_tiles_narrow"} <= set(e) for e in events)
     assert all(e["moe_drop"] == 0 and e["moe_rows_held"] > 0 for e in events)
     assert all(0.3 * e["toks"] < e["bd_loss_rows"] < 0.7 * e["toks"] for e in events)
     assert all(e["toks"] == 256 and e["bd_tiles_grid"] == 0 for e in events)   # no kernel here
+    assert all(e["bd_tiles_masked"] == e["bd_tiles_narrow"] == 0 for e in events)
     assert 30 < _read_metric("bd_loss_row_pct", res["sources"]) < 70
     assert _read_metric("bd_live_tile_pct", res["sources"]) is None
     # what the patches of the run swapped in is put back
@@ -511,7 +521,8 @@ def test_the_sample_config_trains_through_the_cli(tmp_path):
     assert re.search(r"Step 3 validation: val_loss=", log), log[-1500:]
     assert "attention layers (traced, by kind and kernel path): " in log
     assert re.search(r"blockdiff_layers=\d+, blockdiff_simple=\d+", log)
-    assert re.search(r"bd_loss_rows=\d+ \| bd_tiles_live=0 \| bd_tiles_grid=0", log), log[-1500:]
+    assert re.search(r"bd_loss_rows=\d+ \| bd_tiles_live=0 \| bd_tiles_grid=0 \| bd_tiles_masked=0 "
+                     r"\| bd_tiles_narrow=0", log), log[-1500:]
 
 
 def test_trains_under_fsdp_as_on_one_device(tmp_path):

@@ -537,11 +537,12 @@ def test_two_kinds_of_attention_core_compile_for_v5e_at_the_cells_length(v5e, co
 def test_block_diffusion_cores_compile_for_v5e(v5e, compiled_kernels, rows, path):
     """``sdar-30b-a3b-ep8.train-bd8k``'s attention (models/sdar.py): two copies of
     a row of 8,192 side by side, GQA 32/4 of 128, the block-diffusion mask in
-    blocks of 4, forward and backward: Mosaic takes the in-tile mask (and/or of
-    comparisons and a shift: it has no select between booleans) and the walk
-    over a plan of segments, all three kernels on the resident path (16,384
+    blocks of 4, forward and backward: Mosaic takes the walk over a plan of
+    segments, a cut segment's closed form on index vectors and the noised
+    diagonal in squares of 128, all three kernels on the resident path (16,384
     rows are the last length K and V stay in VMEM); and, a length on, the
-    streamed grid gated and clamped by the same segments."""
+    in-tile mask program (and/or of comparisons and a shift: it has no select
+    between booleans) in the streamed grid gated and clamped by the same segments."""
     import re
 
     from mlx_cuda_distributed_pretraining_tpu.ops.attention import attention_core
@@ -562,9 +563,15 @@ def test_block_diffusion_cores_compile_for_v5e(v5e, compiled_kernels, rows, path
     assert plans == {path: 1, f"bwd_dq_{path}": 1, f"bwd_dkv_{path}": 1}
     blocks = fa.flash_plan(rows, rows, 128, jnp.bfloat16)[1:]
     tiles = fa.block_diffusion_tiles(rows // 2, 4, *blocks)
-    assert fa.bd_tiles_traced() == {"live": int((tiles > 0).sum()), "grid": tiles.size}
+    assert fa.bd_tiles_traced() == fa.block_diffusion_walk(rows // 2, 4, *blocks,
+                                                           resident=path == "resident")
+    assert fa.bd_tiles_traced()["live"] == int((tiles > 0).sum())
     if path == "resident":
-        assert fa.bd_tiles_traced() == {"live": 288, "grid": 1024}
+        # the noised diagonal's 16 tiles in four squares of 128 (Mosaic takes the
+        # slices of the scratches and the masks on index vectors), 32 cut tiles whole
+        assert fa.bd_tiles_traced() == {"live": 288, "grid": 1024, "masked": 32, "narrow": 16}
+    else:
+        assert fa.bd_tiles_traced()["narrow"] == 0
     calls = [m.group(1) for line in hlo.split("\n") if "tpu_custom_call" in line
              for m in [re.search(r'op_name="([^"]+)"', line)] if m]
     assert sorted(next(t for t in reversed(re.split(r"[/()]", c)) if t.startswith("flash_"))
