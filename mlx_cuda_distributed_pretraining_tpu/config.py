@@ -75,6 +75,10 @@ class ModelConfig:
     # block-diffusion objective (block_length, eps, mask_id), which the
     # trainer's loader then draws (data/block_diffusion.py).
     diffusion: Dict[str, Any] = field(default_factory=dict)
+    # Only architecture "kimi_linear" reads it (models/kimi_linear.py): the
+    # published linear_attn_config (kda_layers and full_attn_layers, 1-based;
+    # the delta-rule heads' num_heads, head_dim, short_conv_kernel_size).
+    linear_attn: Dict[str, Any] = field(default_factory=dict)
     # Named rematerialization policy: "none" | "dots" | "full" |
     # "save_attn" (models/stack.py REMAT_POLICIES — save_attn keeps the
     # checkpoint_name-tagged attention activations and replays only the

@@ -32,7 +32,8 @@ class Architecture(NamedTuple):
 _REGISTRY: Dict[str, Architecture] = {}
 # Architectures in modules of their own, imported (and so registered) when a
 # config first names them: a llama run pays nothing for them.
-_LAZY_MODULES = {"xing_mla_moe": "xing", "afmoe": "afmoe", "sambay": "sambay", "sdar_moe": "sdar"}
+_LAZY_MODULES = {"xing_mla_moe": "xing", "afmoe": "afmoe", "sambay": "sambay", "sdar_moe": "sdar",
+                 "kimi_linear": "kimi_linear"}
 
 
 def register(arch: Architecture) -> None:

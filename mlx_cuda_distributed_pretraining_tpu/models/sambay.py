@@ -215,15 +215,11 @@ def mamba_mixer(p: Params, u: jnp.ndarray, args: SambaYArgs):
     convolution, ``delta`` and the scan are float32; the four projections take
     operands in ``u``'s dtype."""
     Di, N, R = args.d_inner, args.d_state, args.dt_rank
-    S = u.shape[1]
     with jax.named_scope("ssm"):
         with jax.named_scope("ssm_proj"):
             a, z = jnp.split(u @ p["in_proj"]["weight"], 2, axis=-1)
         with jax.named_scope("ssm_conv"):
-            w = p["conv"]["weight"].astype(jnp.float32)
-            padded = jnp.pad(a.astype(jnp.float32), ((0, 0), (args.d_conv - 1, 0), (0, 0)))
-            c = jax.nn.silu(sum(w[:, j] * padded[:, j:j + S] for j in range(args.d_conv))
-                            + p["conv"]["bias"].astype(jnp.float32))
+            c = jax.nn.silu(stack.causal_depthwise_conv(a, p["conv"]["weight"], p["conv"]["bias"]))
         with jax.named_scope("ssm_proj"):
             rbc = jnp.einsum("bsd,de->bse", c.astype(u.dtype), p["x_proj"]["weight"],
                              preferred_element_type=jnp.float32)

@@ -112,6 +112,18 @@ def run_layers(block: Callable, x, layers: Sequence[Any], dtype, remat: Optional
     return x, None if zero is None else {k: ys[k].sum(axis=0) for k in zero}
 
 
+# -- a mixer's short convolution ------------------------------------------------------
+def causal_depthwise_conv(a: jnp.ndarray, weight: jnp.ndarray, bias: Optional[jnp.ndarray] = None):
+    """``a [B, S, D]`` -> float32 ``[B, S, D]``: channel ``i`` at time ``t`` is ``sum_j
+    weight[i, j] a[t - (K - 1) + j, i]`` (``weight [D, K]``; zeros before ``t = 0``),
+    plus ``bias [D]`` where there is one. The activation is the caller's."""
+    taps, S = weight.shape[1], a.shape[1]
+    w = weight.astype(jnp.float32)
+    padded = jnp.pad(a.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    out = sum(w[:, j] * padded[:, j:j + S] for j in range(taps))
+    return out if bias is None else out + bias.astype(jnp.float32)
+
+
 # -- the head and the loss tail -------------------------------------------------------
 def head_weight(weight: jnp.ndarray, vocab_axis: int, dtype) -> jnp.ndarray:
     """The head's ``[V, C]`` operand in the compute dtype, from a weight whose

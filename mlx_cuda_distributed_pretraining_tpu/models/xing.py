@@ -297,22 +297,39 @@ def hc_write(X: jnp.ndarray, y: jnp.ndarray, h_post: jnp.ndarray, h_res: jnp.nda
 
 
 # -- sub-layers ---------------------------------------------------------------------
+def latent_kv_projections(p: Params, x: jnp.ndarray, heads: int, width: int, rank: int, eps: float):
+    """The key/value side's two projections: ``x [B, S, C]`` -> ``(kv_a [B, S, rank +
+    shared], kv [B, S, heads, width])``: the down-projection whole (its last
+    channels are the key all heads share) and the up-projection of its normed
+    first ``rank``."""
+    B, S, _ = x.shape
+    kv_a = x @ p["wkv_a"]["weight"]
+    c_kv = rms_norm(kv_a[..., :rank], p["kv_norm"]["weight"], eps)
+    return kv_a, (c_kv @ p["wkv_b"]["weight"]).reshape(B, S, heads, width)
+
+
+def latent_keys_values(kv: jnp.ndarray, k_shared: jnp.ndarray, dn: int):
+    """``kv [B, S, H, dn + dv]`` and the one shared key ``[B, S, 1, dr]`` (rotated
+    or not: the caller's) -> ``(k [B, S, H, dn + dr], v [B, S, H, dv])``."""
+    B, S, H, _ = kv.shape
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_shared, (B, S, H, k_shared.shape[-1]))],
+                        axis=-1)
+    return k, kv[..., dn:]
+
+
 def latent_attention(p: Params, x: jnp.ndarray, args: XingArgs, positions) -> jnp.ndarray:
     B, S, _ = x.shape
     H, dn, dr, dv = args.num_heads, args.qk_nope_head_dim, args.qk_rope_head_dim, args.v_head_dim
     with jax.named_scope("attn_qkv"):
         c_q = rms_norm(x @ p["wq_a"]["weight"], p["q_norm"]["weight"], args.rms_norm_eps)
         q = (c_q @ p["wq_b"]["weight"]).reshape(B, S, H, dn + dr)
-        kv_a = x @ p["wkv_a"]["weight"]
-        c_kv = rms_norm(kv_a[..., :args.kv_lora_rank], p["kv_norm"]["weight"], args.rms_norm_eps)
-        kv = (c_kv @ p["wkv_b"]["weight"]).reshape(B, S, H, dn + dv)
+        kv_a, kv = latent_kv_projections(p, x, H, dn + dv, args.kv_lora_rank, args.rms_norm_eps)
         inv_freq, cs_scale = yarn_inv_freq(args)
         angles = positions.astype(jnp.float32)[:, None] * jnp.asarray(inv_freq, jnp.float32)[None]
         cos, sin = jnp.cos(angles) * cs_scale, jnp.sin(angles) * cs_scale
         q = jnp.concatenate([q[..., :dn], apply_rope(q[..., dn:], cos, sin)], axis=-1)
-        k_rope = apply_rope(kv_a[..., None, args.kv_lora_rank:], cos, sin)  # one key for all heads
-        k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_rope, (B, S, H, dr))], axis=-1)
-        v = kv[..., dn:]
+        # one rotated key for all heads
+        k, v = latent_keys_values(kv, apply_rope(kv_a[..., None, args.kv_lora_rank:], cos, sin), dn)
     out = attention_core(q, k, v, args.attention_type, scale=softmax_scale(args),
                          precision=args.matmul_precision)
     with jax.named_scope("attn_out"):

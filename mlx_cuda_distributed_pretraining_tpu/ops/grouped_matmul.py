@@ -73,6 +73,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import backend_from_env
+
 __all__ = [
     "DEFAULT_BLOCK_T",
     "gmm",
@@ -95,10 +97,7 @@ def _interpret() -> bool:
 def default_backend() -> str:
     """``pallas`` on TPU, ``blocked`` elsewhere; ``GMM_BACKEND`` overrides
     (tests force ``pallas`` to run the kernel under interpret mode)."""
-    env = os.environ.get("GMM_BACKEND", "").strip()
-    if env:
-        return env
-    return "pallas" if jax.default_backend() == "tpu" else "blocked"
+    return backend_from_env("GMM_BACKEND", "pallas", "blocked")
 
 
 def round_up(value: int, multiple: int) -> int:

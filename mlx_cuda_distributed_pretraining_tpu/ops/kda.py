@@ -1,0 +1,539 @@
+"""Kimi Delta Attention's core: a gated delta rule whose decay is a number for
+every key channel, forward and backward (arXiv:2510.26692).
+
+For one head, state ``S_t [d, d]`` (``S_0 = 0``), over time ``t``::
+
+    S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+``q, k, v [B, S, H, d]``, ``g [B, S, H, d]`` (log decay, <= 0, float32),
+``beta [B, S, H]`` -> ``o [B, S, H, d]``. The state is a matrix a head (64 KB
+at 128 x 128), so the sequence is cut into chunks of ``c`` steps: inside a
+chunk everything is matmuls, between chunks the state is handed over. With
+``G_r = sum_{i<=r} g_i`` inside the chunk and ``gam(i->r) = exp(G_r - G_i)``::
+
+    A[r, i] = beta_r sum_ch k_r k_i gam(i->r)   (i < r)     T = (I + A)^-1
+    P[r, i] = sum_ch q_r k_i gam(i->r)          (i <= r)
+    V' = T Diag(beta) (V - (K * exp(G)) S)
+    O  = (Q * exp(G)) S + P V'
+    S' = Diag(exp(G_c)) S + (K * exp(G_c - G))^T V'
+
+**No exponent here is ever positive.** ``exp(-G_i)`` alone overflows float32
+(nothing bounds a step's decay; -1.6 a step is -100 a chunk), so ``gam`` is not
+split as ``exp(G_r) exp(-G_i)`` across a chunk. A chunk is cut into sub-blocks
+of ``SUB`` = 16 steps. A pair inside one sub-block takes ``exp(G_r - G_i)``
+itself, channel by channel, on the VPU (16 partners a step). A pair in two
+sub-blocks is factored at the boundary ``b`` before the later one's first
+step, ``exp(G_r - G_b) exp(G_b - G_i)``, both factors <= 1, and is a matmul.
+Underflow is benign: the true product is smaller still.
+
+The solve ``T`` is exact block substitution in float32: the sub-blocks on the
+diagonal are nilpotent of index 16 and are inverted by ``(I - D)(I + D^2)(I +
+D^4)(I + D^8)``; with ``N = (I + D)^-1 (A - D)``, nilpotent of index ``c / 16``
+by blocks, ``T = (I - N)(I + N^2)... (I + D)^-1``. (The same product over the
+whole of ``A`` has partial sums of size ``(1 + |A|)^c``: not that.)
+
+Two paths behind one differentiable entry point, :func:`kda_plan` choosing from
+the shapes and :func:`plan_counts` tallying what a step traced:
+
+- ``kernel`` -- two TPU kernels, ``kda_fwd`` and ``kda_bwd``, the default on a
+  TPU (interpret mode in the tests). Grid (batch, heads ``HEADS_PER_STEP`` at a
+  time, chunks), chunks innermost with the state (transposed, ``[d_v, d_k]``,
+  so that its decay scales lanes) in VMEM scratch. Operands stay ``[B, S, H
+  d]`` as the model makes them: a block is a chunk of some heads' lanes. The
+  forward saves the state at every chunk's start when it is differentiated
+  (``[B, H, S / c, d, d]`` float32); the backward walks the chunks last to
+  first carrying ``dL/dS``, rebuilds a chunk's ``A``, ``P``, ``T`` and ``V'``
+  from the saved state and transposes each product above. The matmuls take
+  operands in ``q``'s dtype with float32 accumulation; ``g``, its running
+  sums, every ``exp``, the solve and the state are float32.
+- ``xla`` -- the same chunk function under ``vmap`` over (batch, head) and a
+  ``lax.scan`` over chunks, each under ``jax.checkpoint``. Differentiates
+  itself. The default off the TPU, and wherever no chunk of whole sub-blocks
+  divides ``S`` or ``d`` is no multiple of 128.
+
+``KDA_BACKEND`` (``kernel`` | ``xla``) overrides the default. Under a device
+mesh of more than one device the core takes the XLA form whatever was asked,
+as ``ops/selective_scan.py`` does (GSPMD cannot partition a Mosaic kernel).
+"""
+
+from __future__ import annotations
+
+import collections
+import functools
+import threading
+from typing import Dict, List, NamedTuple, Optional, Sequence
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .backend import backend_from_env
+
+__all__ = ["kda", "kda_plan", "plan_counts", "default_backend", "KERNEL_CHUNK"]
+
+SUB = 16               # steps of a sub-block: pairs inside one take exp(G_r - G_i) itself
+KERNEL_CHUNK = 128     # steps of a chunk: the cell's step 1,533 ms against 1,603 at 64 (PERF.md section 6, PR 51)
+_LANES = 128
+_VMEM_LIMIT = 64 * 2**20
+_F32 = jnp.float32
+_NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+def default_backend() -> str:
+    """``kernel`` on a TPU, ``xla`` elsewhere; ``KDA_BACKEND`` overrides (the
+    tests force ``kernel`` to run the kernels under interpret mode)."""
+    return backend_from_env("KDA_BACKEND", "kernel", "xla")
+
+
+class KdaPlan(NamedTuple):
+    path: str    # "kernel" | "xla"
+    chunk: int   # time steps a chunk
+
+
+def _fit_chunk(chunk: int, S: int) -> int:
+    while chunk > 1 and S % chunk:
+        chunk //= 2
+    return chunk
+
+
+def kda_plan(S: int, d: int, backend: Optional[str] = None) -> KdaPlan:
+    """Which path a core over ``S`` steps at head size ``d`` takes, and its
+    chunk; a pure function of its arguments and the backend."""
+    return _plan(S, d, backend, KERNEL_CHUNK)
+
+
+def _plan(S: int, d: int, backend: Optional[str], chunk: int) -> KdaPlan:
+    """:func:`kda_plan` at a chunk the tests and ``scripts/bench_kda.py`` name:
+    fitted to ``S`` (halved until it divides); the kernels need whole sub-blocks
+    and whole lanes."""
+    backend = backend or default_backend()
+    if backend not in ("kernel", "xla"):
+        raise ValueError(f"unknown KDA backend {backend!r} (kernel | xla)")
+    c = _fit_chunk(chunk, S)
+    if backend == "kernel" and c % SUB == 0 and d % _LANES == 0:
+        return KdaPlan("kernel", c)
+    return KdaPlan("xla", c)
+
+
+# Chosen while tracing, so this counts traces (as ops/selective_scan.py does).
+_plan_counts: Dict[str, int] = collections.Counter()
+_plan_counts_lock = threading.Lock()
+_FORMS = ("kernel", "xla")
+
+
+def _count(*keys: str) -> None:
+    with _plan_counts_lock:
+        _plan_counts.update(keys)
+
+
+def plan_counts() -> Dict[str, int]:
+    """Cores traced so far in this process by form (``kernel``: a differentiable
+    call of the two kernels; ``xla``), and each again by chunk (``kernel_chunk64``)."""
+    with _plan_counts_lock:
+        return {**{k: _plan_counts[k] for k in _FORMS},
+                **{k: n for k, n in sorted(_plan_counts.items()) if k not in _FORMS}}
+
+
+# -- one chunk of one head, on values: the kernels' bodies and the XLA form ----------
+def _dot(a, b, dims, dtype=None):
+    """2-D ``dot_general`` with a float32 result: operands in ``dtype``, or in
+    float32 at full precision where none is named (the solve, the running sums)."""
+    if dtype is None or dtype == _F32:
+        return jax.lax.dot_general(a.astype(_F32), b.astype(_F32), (dims, ((), ())),
+                                   precision=jax.lax.Precision.HIGHEST, preferred_element_type=_F32)
+    return jax.lax.dot_general(a.astype(dtype), b.astype(dtype), (dims, ((), ())),
+                               preferred_element_type=_F32)
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _tril(c: int, strict: bool = False):
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+    return (col < row) if strict else (col <= row)
+
+
+class _Decays(NamedTuple):
+    """A chunk's decays, every exponent <= 0. ``pair[j] [c, d]``: row ``r`` of
+    sub-block ``b`` holds ``exp(G_r - G_{b sub + j})`` (1 where ``r`` is the
+    earlier). ``down[a - 1] [sub, d]``: the rows of sub-block ``a`` from the
+    boundary before it; ``up[a - 1] [c, d]``: every earlier row up to that
+    boundary (1 from the boundary on, where a mask cuts)."""
+    sub: int
+    pair: List[jnp.ndarray]
+    partner: List[jnp.ndarray]     # row r of sub-block b holds k_{b sub + j}
+    down: List[jnp.ndarray]
+    up: List[jnp.ndarray]
+
+
+def _blocks(c: int) -> int:
+    return min(SUB, c)
+
+
+def _partner_rows(x, j: int, sub: int):
+    """``[c, d]`` whose row ``r`` is ``x``'s row ``(r // sub) sub + j``."""
+    c, d = x.shape
+    x3 = x.reshape(c // sub, sub, d)
+    return jnp.broadcast_to(x3[:, j:j + 1, :], x3.shape).reshape(c, d)
+
+
+def _decays(G, k) -> _Decays:
+    c = G.shape[0]
+    sub = _blocks(c)
+    pair = [jnp.exp(jnp.minimum(G - _partner_rows(G, j, sub), 0.0)) for j in range(sub)]
+    partner = [_partner_rows(k, j, sub) for j in range(sub)]
+    down, up = [], []
+    for lo in range(sub, c, sub):
+        ref = G[lo - 1:lo]
+        down.append(jnp.exp(G[lo:lo + sub] - ref))
+        up.append(jnp.exp(jnp.minimum(ref - G, 0.0)))
+    return _Decays(sub, pair, partner, down, up)
+
+
+def _column_at(c: int, sub: int, j: int):
+    """``[c, c]`` mask: in row ``r``, the column of its own sub-block's step ``j``."""
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+    return col == (row // sub) * sub + j
+
+
+def _own_column(M, at):
+    return jnp.sum(jnp.where(at, M, 0.0), axis=1, keepdims=True)
+
+
+def _decay_mats(lefts: Sequence[jnp.ndarray], k, dec: _Decays, mmdt):
+    """For every ``x`` of ``lefts``: ``M[r, i] = sum_ch x_r k_i gam(i->r)`` for
+    ``i <= r`` (0 above the diagonal), ``[c, c]`` float32."""
+    c, sub, n = k.shape[0], dec.sub, len(lefts)
+    mats = [jnp.zeros((c, c), _F32) for _ in lefts]
+    for j in range(sub):
+        w = dec.partner[j] * dec.pair[j]
+        at = _column_at(c, sub, j)
+        mats = [jnp.where(at, jnp.sum(x * w, axis=1, keepdims=True), m) for x, m in zip(lefts, mats)]
+    lower = _tril(c)
+    mats = [jnp.where(lower, m, 0.0) for m in mats]
+    if c == sub:
+        return mats
+    col = _iota((n * sub, c), 1)
+    rows = [jnp.zeros((n * sub, c), _F32)]
+    for a, lo in enumerate(range(sub, c, sub)):
+        stack = jnp.concatenate([x[lo:lo + sub] * dec.down[a] for x in lefts], axis=0)
+        rows.append(jnp.where(col < lo, _dot(stack, k * dec.up[a], _NT, mmdt), 0.0))
+    return [m + jnp.concatenate([r[i * sub:(i + 1) * sub] for r in rows], axis=0)
+            for i, m in enumerate(mats)]
+
+
+def _eye(c: int):
+    return (_iota((c, c), 0) == _iota((c, c), 1)).astype(_F32)
+
+
+def _nilpotent_inverse(N, index: int):
+    """``(I + N)^-1`` of a matrix with ``N^index = 0``: ``(I - N)(I + N^2)(I + N^4)...``."""
+    eye = _eye(N.shape[0])
+    out, power, reach = eye - N, N, 2
+    while reach < index:
+        power = _dot(power, power, _NN)
+        out = _dot(out, eye + power, _NN)
+        reach *= 2
+    return out
+
+
+def _solve(A):
+    """``(I + A)^-1`` of a strictly lower triangular ``[c, c]``, float32: the
+    sub-blocks on the diagonal first, then the blocks below them."""
+    c = A.shape[0]
+    sub = _blocks(c)
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+    D = jnp.where(row // sub == col // sub, A, 0.0)
+    d_inv = _nilpotent_inverse(D, sub)
+    if c == sub:
+        return d_inv
+    return _dot(_nilpotent_inverse(_dot(d_inv, A - D, _NN), c // sub), d_inv, _NN)
+
+
+class _Chunk(NamedTuple):
+    """What both passes of a chunk compute first."""
+    G: jnp.ndarray       # running sums of g
+    dec: _Decays
+    A0: jnp.ndarray      # k k^T under the decays, strictly lower
+    P: jnp.ndarray       # q k^T under the decays, lower
+    T: jnp.ndarray       # (I + Diag(beta) A0)^-1
+    eG: jnp.ndarray      # exp(G)
+    eD: jnp.ndarray      # exp(G_c - G)
+    eC: jnp.ndarray      # exp(G_c) [1, d]
+
+
+def _chunk_local(q, k, g, bcol, mmdt) -> _Chunk:
+    c = q.shape[0]
+    G = _dot(_tril(c).astype(_F32), g, _NN)
+    dec = _decays(G, k)
+    A0, P = _decay_mats([k, q], k, dec, mmdt)
+    A0 = jnp.where(_tril(c, strict=True), A0, 0.0)
+    T = _solve(bcol * A0)
+    last = G[c - 1:c]
+    return _Chunk(G, dec, A0, P, T, jnp.exp(G), jnp.exp(last - G), jnp.exp(last))
+
+
+def _chunk_fwd(St, q, k, v, g, bcol, brow, mmdt):
+    """One chunk of one head: ``St [d_v, d_k]`` (the state, transposed), ``q, k,
+    v, g [c, d]``, ``beta`` as a column and as a row -> ``(o [c, d_v], St')``,
+    float32."""
+    q, k, v, g = (a.astype(_F32) for a in (q, k, v, g))
+    z = _chunk_local(q, k, g, bcol, mmdt)
+    R = v - _dot(k * z.eG, St, _NT, mmdt)
+    Vp = _dot(z.T * brow, R, _NN, mmdt)
+    o = _dot(q * z.eG, St, _NT, mmdt) + _dot(z.P, Vp, _NN, mmdt)
+    return o, St * z.eC + _dot(Vp, k * z.eD, _TN, mmdt)
+
+
+def _chunk_bwd(St, dSt_new, do, q, k, v, g, bcol, brow, mmdt):
+    """:func:`_chunk_fwd` transposed -> ``(dq, dk, dv, dg [c, d], dbeta as a
+    column [c, 1] and as a row [1, c] (the two add), dSt)``, float32. With
+    ``f = sum x_r y_i exp(G_r - G_i)``: ``df/dG_r = x_r df/dx_r`` and ``df/dG_i =
+    -y_i df/dy_i``, so ``dG`` is ``q dq`` plus ``k`` times (``dk`` where ``k``
+    stands on the left, less ``dk`` where it stands on the right)."""
+    q, k, v, g, do = (a.astype(_F32) for a in (q, k, v, g, do))
+    c, sub = q.shape[0], _blocks(q.shape[0])
+    z = _chunk_local(q, k, g, bcol, mmdt)
+    dec = z.dec
+    Kg, Qg, Kd = k * z.eG, q * z.eG, k * z.eD
+    Tb = z.T * brow
+    R = v - _dot(Kg, St, _NT, mmdt)
+    Vp = _dot(Tb, R, _NN, mmdt)
+
+    dVp = _dot(z.P, do, _TN, mmdt) + _dot(Kd, dSt_new, _NT, mmdt)
+    dP = jnp.where(_tril(c), _dot(do, Vp, _NT, mmdt), 0.0)
+    dR = _dot(Tb, dVp, _TN, mmdt)
+    dTb = _dot(dVp, R, _NT, mmdt)
+    dbrow = jnp.sum(dTb * z.T, axis=0, keepdims=True)
+    dA = -_dot(_dot(z.T, dTb * brow, _TN), z.T, _NT)
+    dA = jnp.where(_tril(c, strict=True), dA, 0.0)
+    dbcol = jnp.sum(dA * z.A0, axis=1, keepdims=True)
+    dA0 = dA * bcol
+    dSt = _dot(do, Qg, _TN, mmdt) + dSt_new * z.eC - _dot(dR, Kg, _TN, mmdt)
+    d_end = jnp.sum(St * dSt_new, axis=0, keepdims=True) * z.eC     # to G_c, from the state's decay
+
+    dq = _dot(do, St, _NN, mmdt) * z.eG                 # through Q exp(G)
+    dk_left = -_dot(dR, St, _NN, mmdt) * z.eG           # through K exp(G)
+    dk_end = _dot(Vp, dSt_new, _NN, mmdt) * z.eD        # through K exp(G_c - G)
+    dk_right = jnp.zeros_like(k)
+    in_block = _iota(k.shape, 0) % sub
+    for j in range(sub):
+        at = _column_at(c, sub, j)
+        col_p, col_a = _own_column(dP, at), _own_column(dA0, at)
+        dq = dq + col_p * (dec.partner[j] * dec.pair[j])
+        dk_left = dk_left + col_a * (dec.partner[j] * dec.pair[j])
+        t = ((col_p * q + col_a * k) * dec.pair[j]).reshape(c // sub, sub, -1)
+        t = jnp.broadcast_to(jnp.sum(t, axis=1, keepdims=True), t.shape).reshape(k.shape)
+        dk_right = dk_right + jnp.where(in_block == j, t, 0.0)
+    if c > sub:
+        col = _iota((2 * sub, c), 1)
+        lefts = [jnp.zeros((2 * sub, k.shape[1]), _F32)]
+        for a, lo in enumerate(range(sub, c, sub)):
+            rows = jnp.where(col < lo, jnp.concatenate([dP[lo:lo + sub], dA0[lo:lo + sub]], axis=0), 0.0)
+            lefts.append(_dot(rows, k * dec.up[a], _NN, mmdt)
+                         * jnp.concatenate([dec.down[a], dec.down[a]], axis=0))
+            into = jnp.concatenate([q[lo:lo + sub] * dec.down[a], k[lo:lo + sub] * dec.down[a]], axis=0)
+            dk_right = dk_right + _dot(rows, into, _TN, mmdt) * dec.up[a]
+        dq = dq + jnp.concatenate([l[:sub] for l in lefts], axis=0)
+        dk_left = dk_left + jnp.concatenate([l[sub:] for l in lefts], axis=0)
+
+    dG = q * dq + k * (dk_left - dk_right - dk_end)
+    d_end = d_end + jnp.sum(k * dk_end, axis=0, keepdims=True)
+    dG = dG + jnp.where(_iota(dG.shape, 0) == c - 1, d_end, 0.0)
+    dg = _dot(_tril(c).astype(_F32), dG, _TN)
+    return dq, dk_left + dk_right + dk_end, dR, dg, dbcol, dbrow, dSt
+
+
+# -- the XLA form -------------------------------------------------------------------
+def _kda_xla(q, k, v, g, beta, chunk: int):
+    B, S, H, d = q.shape
+    n = S // chunk
+    mmdt = q.dtype
+    by_chunk = lambda a: jnp.moveaxis(a.reshape(B, n, chunk, H, -1), (1, 3), (0, 2))  # [n, B, H, c, .]
+    bc = by_chunk(beta.astype(_F32)[..., None])
+    one = jax.vmap(jax.vmap(functools.partial(_chunk_fwd, mmdt=mmdt)))
+
+    @jax.checkpoint
+    def step(St, xs):
+        qc, kc, vc, gc, b = xs
+        o, St = one(St, qc, kc, vc, gc, b, jnp.swapaxes(b, -1, -2))
+        return St, o
+
+    _, o = jax.lax.scan(step, jnp.zeros((B, H, v.shape[-1], d), _F32),
+                        (by_chunk(q), by_chunk(k), by_chunk(v), by_chunk(g.astype(_F32)), bc))
+    return jnp.moveaxis(o, (0, 2), (1, 3)).reshape(B, S, H, -1).astype(q.dtype)
+
+
+# -- the kernels --------------------------------------------------------------------
+# Heads a grid step: their chunks are independent chains of small matmuls (the solve alone is a
+# dozen dependent float32 products), so the scheduler fills one head's waits with another's work.
+HEADS_PER_STEP = 4
+
+
+def _heads_per_step(H: int, g: int = HEADS_PER_STEP) -> int:
+    while H % g:
+        g -= 1
+    return g
+
+
+def _head(ref, h: int, d: int):
+    """Head ``h``'s lanes of a ``[c, G d]`` block."""
+    return ref[:, h * d:(h + 1) * d]
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, bc_ref, br_ref, o_ref, *rest, mmdt, save: bool, G: int, d: int):
+    st_scr = rest[-1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        st_scr[...] = jnp.zeros_like(st_scr)
+
+    for h in range(G):
+        St = st_scr[h]
+        if save:
+            rest[0][h] = St          # the state this chunk starts from, for the backward
+        o, St = _chunk_fwd(St, _head(q_ref, h, d), _head(k_ref, h, d), _head(v_ref, h, d),
+                           _head(g_ref, h, d), bc_ref[h], br_ref[h], mmdt)
+        o_ref[:, h * d:(h + 1) * d] = o.astype(o_ref.dtype)
+        st_scr[h] = St
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, bc_ref, br_ref, do_ref, st_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, dbc_ref, dbr_ref, dst_scr, *, mmdt, G: int, d: int):
+    # the grid's last axis walks the chunks last to first (the index maps turn it round)
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dst_scr[...] = jnp.zeros_like(dst_scr)
+
+    for h in range(G):
+        lanes = slice(h * d, (h + 1) * d)
+        dq, dk, dv, dg, dbc, dbr, dSt = _chunk_bwd(
+            st_ref[h], dst_scr[h], _head(do_ref, h, d), _head(q_ref, h, d), _head(k_ref, h, d),
+            _head(v_ref, h, d), _head(g_ref, h, d), bc_ref[h], br_ref[h], mmdt)
+        dq_ref[:, lanes] = dq.astype(dq_ref.dtype)
+        dk_ref[:, lanes] = dk.astype(dk_ref.dtype)
+        dv_ref[:, lanes] = dv.astype(dv_ref.dtype)
+        dg_ref[:, lanes] = dg
+        dbc_ref[h] = dbc
+        dbr_ref[h] = dbr
+        dst_scr[h] = dSt
+
+
+def _params(interpret: bool):
+    return None if interpret else pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _specs(c: int, d: int, n: int, G: int, reverse: bool):
+    """Block specs of a ``[B, S, H d]`` operand (``G`` heads' lanes of one chunk),
+    of ``beta`` as a column and as a row, and of the saved states."""
+    at = (lambda i: n - 1 - i) if reverse else (lambda i: i)
+    seq = pl.BlockSpec((None, c, G * d), lambda b, h, i: (b, at(i), h))
+    col = pl.BlockSpec((None, G, None, c, 1), lambda b, h, i: (b, h, at(i), 0, 0))
+    row = pl.BlockSpec((None, G, None, 1, c), lambda b, h, i: (b, h, at(i), 0, 0))
+    state = pl.BlockSpec((None, G, None, d, d), lambda b, h, i: (b, h, at(i), 0, 0))
+    return seq, col, row, state
+
+
+def _beta_blocks(beta, c: int):
+    """``beta [B, S, H]`` -> ``[B, H, S / c, c, 1]`` and ``[B, H, S / c, 1, c]``, float32."""
+    B, S, H = beta.shape
+    b = jnp.swapaxes(beta.astype(_F32), 1, 2).reshape(B, H, S // c, c)
+    return b[..., None], b[..., None, :]
+
+
+# Jitted (``interpret`` among the static arguments: it is the backend's, and the tests steer it), so
+# that a stack of layers traces and lowers each kernel once a shape and not once a layer.
+@functools.partial(jax.jit, static_argnames=("H", "c", "G", "save", "interpret"))
+def _fwd_call(q, k, v, g, bcol, brow, H: int, c: int, G: int, save: bool, interpret: bool):
+    B, S, Hd = q.shape
+    d, n = Hd // H, S // c
+    seq, col, row, state = _specs(c, d, n, G, False)
+    out_specs, out_shape = [seq], [jax.ShapeDtypeStruct(q.shape, q.dtype)]
+    if save:
+        out_specs.append(state)
+        out_shape.append(jax.ShapeDtypeStruct((B, H, n, d, d), _F32))
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, mmdt=q.dtype, save=save, G=G, d=d),
+        grid=(B, H // G, n), in_specs=[seq, seq, seq, seq, col, row],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((G, d, d), _F32)],
+        compiler_params=_params(interpret), interpret=interpret,
+        name="kda_fwd",
+    )(q, k, v, g, bcol, brow)
+
+
+@functools.partial(jax.jit, static_argnames=("H", "c", "G", "interpret"))
+def _bwd_call(q, k, v, g, bcol, brow, do, states, H: int, c: int, G: int, interpret: bool):
+    B, S, Hd = q.shape
+    d, n = Hd // H, S // c
+    seq, col, row, state = _specs(c, d, n, G, True)
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, mmdt=q.dtype, G=G, d=d),
+        grid=(B, H // G, n), in_specs=[seq, seq, seq, seq, col, row, seq, state],
+        out_specs=[seq, seq, seq, seq, col, row],
+        out_shape=[like(q), like(k), like(v), like(g), like(bcol), like(brow)],
+        scratch_shapes=[pltpu.VMEM((G, d, d), _F32)],
+        compiler_params=_params(interpret), interpret=interpret,
+        name="kda_bwd",
+    )(q, k, v, g, bcol, brow, do, states)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _kda_kernel(q, k, v, g, beta, H, c, G):
+    """``q, k, v, g [B, S, H d]``, ``beta [B, S, H]`` -> ``o [B, S, H d]``; ``G`` heads a grid step."""
+    return _fwd_call(q, k, v, g, *_beta_blocks(beta, c), H=H, c=c, G=G, save=False,
+                     interpret=_interpret())[0]
+
+
+def _kda_kernel_fwd(q, k, v, g, beta, H, c, G):
+    bcol, brow = _beta_blocks(beta, c)
+    o, states = _fwd_call(q, k, v, g, bcol, brow, H=H, c=c, G=G, save=True, interpret=_interpret())
+    return o, (q, k, v, g, bcol, brow, states, jnp.zeros((), beta.dtype))
+
+
+def _kda_kernel_bwd(H, c, G, res, do):
+    q, k, v, g, bcol, brow, states, beta_like = res
+    dq, dk, dv, dg, dbc, dbr = _bwd_call(q, k, v, g, bcol, brow, do.astype(q.dtype), states,
+                                         H=H, c=c, G=G, interpret=_interpret())
+    B, S = q.shape[:2]
+    dbeta = jnp.swapaxes((dbc[..., 0] + dbr[..., 0, :]).reshape(B, H, S), 1, 2)
+    return dq, dk, dv, dg, dbeta.astype(beta_like.dtype)
+
+
+_kda_kernel.defvjp(_kda_kernel_fwd, _kda_kernel_bwd)
+
+
+def kda(q, k, v, g, beta, *, backend: Optional[str] = None):
+    """``o [B, S, H, d]`` (in ``q``'s dtype) of the recurrence in this module's
+    docstring from ``q, k, v [B, S, H, d]``, ``g [B, S, H, d]`` and ``beta [B, S,
+    H]``; differentiable in all five. ``q`` and ``k`` come normed and scaled:
+    the core applies neither."""
+    return _kda(q, k, v, g, beta, backend, KERNEL_CHUNK, HEADS_PER_STEP)
+
+
+def _kda(q, k, v, g, beta, backend: Optional[str], chunk: int, heads: int):
+    """:func:`kda` at a chunk and a count of heads a grid step that the tests
+    and ``scripts/bench_kda.py`` name; the program's are the two constants."""
+    from ..parallel.context import current_mesh
+
+    mesh = current_mesh()
+    if mesh is not None and mesh.size > 1:
+        backend = "xla"
+    B, S, H, d = q.shape
+    plan = _plan(S, d, backend, chunk)
+    _count(plan.path, f"{plan.path}_chunk{plan.chunk}")
+    if plan.path == "xla":
+        return _kda_xla(q, k, v, g, beta, plan.chunk)
+    flat = lambda a: a.reshape(B, S, H * d)
+    o = _kda_kernel(flat(q), flat(k), flat(v.astype(q.dtype)), flat(g.astype(_F32)), beta, H, plan.chunk,
+                    _heads_per_step(H, heads))
+    return o.reshape(B, S, H, d)

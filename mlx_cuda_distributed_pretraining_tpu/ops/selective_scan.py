@@ -46,7 +46,6 @@ from __future__ import annotations
 
 import collections
 import functools
-import os
 import threading
 from typing import Dict, NamedTuple, Optional
 
@@ -54,6 +53,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .backend import backend_from_env
 
 __all__ = ["selective_scan", "ssm_plan", "plan_counts", "default_backend"]
 
@@ -73,10 +74,7 @@ def _interpret() -> bool:
 def default_backend() -> str:
     """``kernel`` on a TPU, ``xla`` elsewhere; ``SSM_BACKEND`` overrides (the
     tests force ``kernel`` to run the kernels under interpret mode)."""
-    env = os.environ.get("SSM_BACKEND", "").strip()
-    if env:
-        return env
-    return "kernel" if jax.default_backend() == "tpu" else "xla"
+    return backend_from_env("SSM_BACKEND", "kernel", "xla")
 
 
 class SsmPlan(NamedTuple):

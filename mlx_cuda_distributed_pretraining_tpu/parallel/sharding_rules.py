@@ -66,6 +66,19 @@ _RULES = [
     (r"ssm\.(A_log|D)$", (None, None)),                        # [Di, N], [Di]
     (r"gmu\.w1\.weight$", ("fsdp", "tp")),                    # [D, Di] column
     (r"gmu\.w2\.weight$", ("tp", "fsdp")),                    # [Di, D] row
+    # models/kimi_linear.py. A delta-rule mixer's three projections are column-parallel
+    # over heads and its output projection row-parallel, like wq/wk/wv and wo (under a
+    # mesh its core takes the XLA form, which GSPMD partitions by head); the two low-rank
+    # pairs are a replicated down- and a column-parallel up-projection, as latent
+    # attention's; its per-channel and per-head leaves are replicated. Its latent layers'
+    # leaves are models/xing.py's (wq straight to the heads: the attention.wq rule).
+    (r"kda\.w[qkv]\.weight$", ("fsdp", "tp")),                # [D, H*d] column
+    (r"kda\.wo\.weight$", ("tp", "fsdp")),                    # [H*d, D] row
+    (r"kda\.[fg]_down\.weight$", ("fsdp", None)),             # [D, d]
+    (r"kda\.[fg]_up\.weight$", (None, "tp")),                 # [d, H*d]
+    (r"kda\.wb\.weight$", ("fsdp", None)),                    # [D, H] the write strength
+    (r"kda\.conv_[qkv]\.weight$", (None, None)),              # [H*d, K] depthwise taps
+    (r"kda\.(A_log|dt_bias)$", (None,)),                      # [H], [H*d]
     (r"feed_forward\.w_(gate|up)\.weight(_q4?)?$", ("fsdp", "tp")),  # [D, I] column
     (r"feed_forward\.w_(gate|up)\.weight_s$", ("tp",)),              # [I]
     (r"feed_forward\.w_down\.weight(_q4?)?$", ("tp", "fsdp")),       # [I, D] row

@@ -1,0 +1,600 @@
+"""Architecture ``kimi_linear`` (models/kimi_linear.py) against the benchmark's
+plain reference (benchmark/reference/kimi_linear.py, which imports nothing of
+the program), at tiny widths on seeded random weights, and the pieces this
+architecture brought: the delta rule's two paths (ops/kda.py) against a
+sequential recurrence, the share of the experts a chip holds, the new scopes,
+tallies and readers, and the benchmark's traffic kind for it.
+"""
+
+import gzip
+import itertools
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import run as harness
+from benchmark.flops import kda_chunk
+from benchmark.flops import kimi_linear as flops
+from benchmark.reference import kimi_linear as ref
+from benchmark.traffic_kinds import train_job
+from benchmark.traffic_kinds import train_job_kda as kind
+from mlx_cuda_distributed_pretraining_tpu.config import Config
+from mlx_cuda_distributed_pretraining_tpu.models import kimi_linear as kl
+from mlx_cuda_distributed_pretraining_tpu.models.registry import resolve_architecture
+from mlx_cuda_distributed_pretraining_tpu.ops import kda as kda_ops
+from test_afmoe import _read_metric, _trace_dir, _xplane
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CELL = "kimi-linear-48b-a3b-ep16.train-seq8k"
+B, S = 2, 64
+NEW_READERS = ("step_device_ms.kda", "step_device_ms.kda_core", "kernel_roof_pct.kda_fwd",
+               "kernel_roof_pct.kda_bwd", "kda_xla_calls_per_step")
+PEAKS = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
+
+
+def _load(path):
+    with open(os.path.join(REPO, path)) as f:
+        return json.load(f)
+
+
+FULL = _load("benchmark/configs/kimi-linear-48b-a3b-ep16.json")
+TINY = _load("benchmark/rehearse_kda.json")
+PUBLISHED_PARAMS = 49_122_681_728
+
+
+def _catalog():
+    """The catalog's row for Kimi-Linear-48B-A3B-Instruct (model-configs guide), its `config` whole."""
+    path = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if not os.path.isfile(path):
+        pytest.skip("no catalog beside the model-configs guide here")
+    with open(path) as f:
+        return next(json.loads(line) for line in f if '"Kimi-Linear-48B-A3B-Instruct"' in line)
+
+
+def _args(cfg, attention_type="simple"):
+    model = kind.arch.MODEL_SECTIONS["kimi_linear"](cfg, {"attention_type": attention_type})
+    return kl.KimiLinearArgs.from_config(Config.from_dict({"name": "t", "model": model}).model,
+                                         cfg["vocab_size"])
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """(configuration at tiny widths, seeded weights, a batch)."""
+    cfg = harness.merge_into(FULL, TINY["config"])
+    params = ref.init_params(7, cfg)
+    toks = np.random.default_rng(0).integers(3, cfg["vocab_size"], size=(B, S + 1)).astype(np.int32)
+    batch = {"inputs": jnp.asarray(toks[:, :-1]), "targets": jnp.asarray(toks[:, 1:]),
+             "mask": jnp.ones((B, S), jnp.float32)}
+    return cfg, params, batch
+
+
+@pytest.fixture(scope="module")
+def reference_step(tiny):
+    cfg, params, batch = tiny
+    return jax.jit(lambda p: ref.loss_and_grads(p, batch["inputs"], batch["targets"], cfg))(params)
+
+
+def _leaf_gaps(got, want):
+    return {jax.tree_util.keystr(path): float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-12))
+            for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(got)[0],
+                                    jax.tree_util.tree_leaves(want))}
+
+
+# -- the model against the reference ------------------------------------------------
+@pytest.mark.parametrize("attention_type,backend", [("simple", "xla"), ("flash", "xla")])
+def test_program_matches_reference_loss_and_every_gradient(tiny, reference_step, monkeypatch,
+                                                          attention_type, backend):
+    cfg, params, batch = tiny
+    monkeypatch.setenv("KDA_BACKEND", backend)
+    args = _args(cfg, attention_type)
+    (loss, count), grads = jax.jit(jax.value_and_grad(
+        lambda p: kl.loss_fn(p, batch, args, remat="full"), has_aux=True))(params)
+    (want,), want_grads = reference_step
+    assert float(count) == B * S
+    assert float(loss) == pytest.approx(float(want), rel=2e-5)
+    gaps = _leaf_gaps(grads, want_grads)
+    assert max(gaps.values()) < 2e-4, sorted(gaps.items(), key=lambda kv: -kv[1])[:5]
+    # the selection bias is a buffer: no gradient on either side
+    assert all(float(jnp.abs(l["feed_forward"]["router"]["bias"]).max()) == 0.0
+               for l in grads["layers"][1:])
+
+
+def test_the_trees_are_one_tree_and_the_counts_are_the_published_ones(tiny):
+    cfg, params, _ = tiny
+    own = jax.eval_shape(lambda: kl.init_params(jax.random.PRNGKey(0), _args(cfg)))
+    assert jax.tree_util.tree_structure(own) == jax.tree_util.tree_structure(params)
+    assert [a.shape for a in jax.tree_util.tree_leaves(own)] == \
+        [a.shape for a in jax.tree_util.tree_leaves(params)]
+    assert sum(a.size for a in jax.tree_util.tree_leaves(params)) == flops.total_params(cfg)
+    # at the cell's widths, without allocating: the tree the program trains, and the published whole
+    shapes = jax.tree_util.tree_leaves(ref.param_shapes(FULL), is_leaf=ref._is_spec)
+    assert sum(int(np.prod(s)) for s, _ in shapes) == flops.total_params(FULL)
+    assert flops.total_params(FULL, published=True) == PUBLISHED_PARAMS
+    z = flops._sizes(FULL)
+    assert (flops.kda_mixer_params(z), flops.latent_mixer_params(z), flops.expert_params(z)) == \
+        (39_514_272, 29_114_880, 7_077_888)
+    assert kl.matmul_params_per_token(_args(FULL)) == flops.matmul_params(FULL)
+    assert kl.flops_per_token(_args(FULL), 8192) == pytest.approx(
+        flops.train_flops_per_token(FULL, 8192), rel=1e-3)   # S against S + 1 keys a query
+
+
+def test_forward_gives_the_references_logits_and_has_no_cache(tiny):
+    cfg, params, batch = tiny
+    logits, cache = jax.jit(lambda p, t: kl.forward(p, t, _args(cfg)))(params, batch["inputs"])
+    want = jax.jit(lambda p, t: ref.logits_at(p, t, cfg))(params, batch["inputs"])
+    assert cache is None and float(jnp.abs(logits - want).max()) < 2e-5
+    with pytest.raises(NotImplementedError, match="recurrent-state cache"):
+        kl.forward(params, batch["inputs"], _args(cfg), cache=object())
+
+
+def test_a_changed_token_moves_no_output_before_it(tiny, monkeypatch):
+    """The convolutions, the delta rule's state and latent attention are causal:
+    another token at ``t`` leaves every logit before ``t`` as it was, and moves
+    the logits from ``t`` on."""
+    cfg, params, batch = tiny
+    t = 37
+    args = _args(cfg)
+    logits = jax.jit(lambda tokens: kl.forward(params, tokens, args)[0])
+    base = logits(batch["inputs"])
+    moved = logits(batch["inputs"].at[:, t].set((batch["inputs"][:, t] + 1) % cfg["vocab_size"]))
+    assert float(jnp.abs(moved[:, :t] - base[:, :t]).max()) == 0.0
+    assert float(jnp.abs(moved[:, t:] - base[:, t:]).max()) > 1e-4
+    # and a KDA layer alone through its kernels, across a chunk's and a sub-block's boundary
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 128, cfg["hidden_size"]), jnp.float32)
+    wide = _args(dict(cfg, linear_attn_config=dict(cfg["linear_attn_config"], head_dim=128, num_heads=1)))
+    p = kl.init_params(jax.random.PRNGKey(2), wide)["layers"][0]["kda"]
+    monkeypatch.setenv("KDA_BACKEND", "kernel")
+    mixer = jax.jit(lambda x: kl.kda_mixer(p, x, wide))
+    a, b = mixer(x), mixer(x.at[:, 70].add(1.0))
+    assert float(jnp.abs(a[:, :70] - b[:, :70]).max()) == 0.0 and float(jnp.abs(a - b).max()) > 0
+
+
+def test_the_sixteen_shares_add_up():
+    """32 experts over 16 ranks of 2: the sixteen held shares of the program's
+    routed layer, the shared expert (which every rank computes alike) counted
+    once, sum to what the uncut reference gives for the whole layer."""
+    cfg = harness.merge_into(FULL, TINY["config"])
+    cfg = dict(cfg, num_experts=32, num_experts_per_token=8, experts_held={"first": 0, "count": 32})
+    whole = ref.param_shapes(cfg)["layers"][1]["feed_forward"]
+    leaves, treedef = jax.tree_util.tree_flatten(whole, is_leaf=ref._is_spec)
+    keys = jax.random.split(jax.random.PRNGKey(3), len(leaves))
+    p = jax.tree_util.tree_unflatten(treedef, [jax.random.normal(k, s, jnp.float32) * 0.2
+                                               for k, (s, _) in zip(keys, leaves)])
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 32, cfg["hidden_size"]), jnp.float32)
+    want = ref.routed_layer(p, x, cfg, "float32")
+    shared = ref.routed_layer(p, x, cfg, "float32", first=0, count=0)
+    total = shared
+    for rank in range(16):
+        held = dict(cfg, experts_held={"first": 2 * rank, "count": 2})
+        share = dict(p, experts=jax.tree_util.tree_map(lambda a: a[2 * rank:2 * rank + 2], p["experts"]))
+        y, stats = kl.routed_ffn(share, x, _args(held))
+        total = total + (y - shared)
+        assert float(stats["moe_load"].sum()) == x.shape[0] * x.shape[1] * 8   # the router's whole width
+    assert float(jnp.abs(total - want).max()) < 1e-4 * float(jnp.abs(want).max())
+    # a share alone is not the layer
+    assert float(jnp.abs(y - want).max()) > 0.1 * float(jnp.abs(want).max())
+
+
+# -- the delta rule's core ----------------------------------------------------------------
+def _sequential(q, k, v, g, beta):
+    """Equation 4 itself, a step at a time, float32."""
+    def step(state, x):
+        q_t, k_t, v_t, g_t, b_t = x
+        state = state * jnp.exp(g_t)[..., None]
+        seen = jnp.einsum("bhk,bhkv->bhv", k_t, state)
+        state = state + (b_t[..., None] * k_t)[..., None] * (v_t - seen)[..., None, :]
+        return state, jnp.einsum("bhk,bhkv->bhv", q_t, state)
+
+    Bt, _, H, d = q.shape
+    time = lambda a: jnp.moveaxis(a, 1, 0)
+    with jax.default_matmul_precision("highest"):
+        _, o = jax.lax.scan(step, jnp.zeros((Bt, H, d, d), jnp.float32),
+                            tuple(time(a) for a in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1)
+
+
+def _core_case(Bt, S_, H, d, decay, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    n = lambda key, shape: jax.random.normal(key, shape, jnp.float32)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    return (unit(n(ks[0], (Bt, S_, H, d))) * d ** -0.5, unit(n(ks[1], (Bt, S_, H, d))),
+            n(ks[2], (Bt, S_, H, d)), -jax.nn.softplus(n(ks[3], (Bt, S_, H, d))) * decay,
+            jax.nn.sigmoid(n(ks[4], (Bt, S_, H))), n(ks[5], (Bt, S_, H, d)))
+
+
+CORE_CASES = {
+    # backend asked, (B, S, H, d), decay a step, the chunk asked, the plan expected
+    "kernel_batch_and_heads": ("kernel", (2, 256, 2, 128), 0.2, None, ("kernel", 128)),
+    "kernel_decay_overflows": ("kernel", (1, 128, 1, 128), 4.0, None, ("kernel", 128)),
+    "kernel_chunk_64": ("kernel", (1, 128, 1, 128), 0.2, 64, ("kernel", 64)),
+    "xla_batch_and_heads": ("xla", (2, 64, 2, 16), 0.2, 32, ("xla", 32)),
+    "xla_decay_overflows": ("xla", (1, 128, 2, 32), 4.0, None, ("xla", 128)),
+    "no_chunk_divides_the_row": ("kernel", (1, 40, 2, 128), 0.2, None, ("xla", 8)),
+    "heads_off_the_lanes": ("kernel", (1, 64, 2, 32), 0.2, None, ("xla", 64)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CORE_CASES))
+def test_the_core_matches_a_sequential_recurrence_in_value_and_all_five_gradients(case):
+    backend, shape, decay, chunk, plan = CORE_CASES[case]
+    q, k, v, g, beta, w = _core_case(*shape, decay)
+    chunk = chunk or kda_ops.KERNEL_CHUNK
+    assert tuple(kda_ops._plan(shape[1], shape[3], backend, chunk)) == plan
+    if "overflows" in case:   # exp(-G) over a chunk is no float32: the sums of g fall below -88.7
+        sums = jnp.sum(g.reshape(shape[0], -1, plan[1], *shape[2:]), axis=2)
+        with np.errstate(over="ignore"):
+            assert float(sums.min()) < -200 and not np.isfinite(np.exp(np.float32(-float(sums.min()))))
+    before = kda_ops.plan_counts()
+    core = lambda *a: kda_ops._kda(*a, backend, chunk, kda_ops.HEADS_PER_STEP)
+    out, want = core(q, k, v, g, beta), _sequential(q, k, v, g, beta)
+    traced = {key: n - before.get(key, 0) for key, n in kda_ops.plan_counts().items() if n - before.get(key, 0)}
+    assert traced == {plan[0]: 1, f"{plan[0]}_chunk{plan[1]}": 1}
+    rel = lambda a, b: float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
+    assert bool(jnp.all(jnp.isfinite(out))) and rel(out, want) < 5e-6
+    grads = jax.grad(lambda *a: jnp.sum(core(*a) * w), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    wants = jax.grad(lambda *a: jnp.sum(_sequential(*a) * w), argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    gaps = {name: rel(a, b) for name, a, b in zip(("q", "k", "v", "g", "beta"), grads, wants)}
+    assert max(gaps.values()) < 2e-5, gaps
+
+
+def test_the_core_plans_from_shapes_and_backend(monkeypatch):
+    monkeypatch.delenv("KDA_BACKEND", raising=False)
+    assert kda_ops.default_backend() == "xla"                  # no TPU here
+    assert kda_ops.kda_plan(8192, 128) == ("xla", 128)
+    monkeypatch.setenv("KDA_BACKEND", "kernel")
+    assert kda_ops.kda_plan(8192, 128) == ("kernel", kda_ops.KERNEL_CHUNK) == ("kernel", 128)
+    assert kda_ops._plan(8192, 128, None, 64) == ("kernel", 64)
+    assert kda_ops.kda_plan(8200, 128) == ("xla", 8)           # 8,200 = 8 x 1,025
+    with pytest.raises(ValueError, match="backend"):
+        kda_ops.kda_plan(64, 128, "mosaic")
+
+
+def test_the_core_takes_the_xla_form_under_a_mesh():
+    from jax.sharding import Mesh
+
+    from mlx_cuda_distributed_pretraining_tpu.parallel.context import use_mesh
+
+    q, k, v, g, beta, _ = _core_case(2, 64, 2, 128, 0.2)
+    before = kda_ops.plan_counts()
+    with use_mesh(Mesh(np.array(jax.devices()[:2]), ("fsdp",))):
+        out = kda_ops.kda(q, k, v, g, beta, backend="kernel")
+    after = kda_ops.plan_counts()
+    assert after["xla"] == before["xla"] + 1 and after["kernel"] == before["kernel"]
+    assert float(jnp.abs(out - _sequential(q, k, v, g, beta)).max()) < 1e-5
+
+
+def test_the_reference_in_bfloat16_state_is_another_result():
+    """The diagnosis ``float32_bf16_kda`` rounds the decay and the state alone."""
+    q, k, v, g, beta, _ = _core_case(1, 128, 2, 32, 0.2)
+    exact, low = ref.delta_rule(q, k, v, g, beta), ref.delta_rule(q, k, v, g, beta, jnp.bfloat16)
+    assert float(jnp.abs(exact - _sequential(q, k, v, g, beta)).max()) < 1e-5
+    gap = float(jnp.linalg.norm(low - exact) / jnp.linalg.norm(exact))
+    assert 1e-3 < gap < 0.1 and ref.BF16_KDA in ref.PRECISIONS
+
+
+# -- counts, configuration, declarations -------------------------------------------------------
+def test_the_cores_counts_are_the_issues():
+    at = (2, 8192, 32, 128)
+    assert kda_chunk.fwd_flops(1, 1, 1, 128) == 184_320 and kda_chunk.COUNT_CHUNK == 64
+    assert kda_chunk.bwd_flops(*at) == 2 * kda_chunk.fwd_flops(*at)
+    assert kda_chunk.fwd_bytes(1, 1, 1, 128) == 1540 and kda_chunk.bwd_bytes(1, 1, 1, 128) == 2824
+    # the HBM roof binds both passes at the cell's call
+    for flops_of, bytes_of in kda_chunk.BY_KERNEL.values():
+        assert bytes_of(*at) / PEAKS["hbm_bytes_per_s"] > flops_of(*at) / PEAKS["bf16_flops"]
+    assert 1e3 * kda_chunk.roof_seconds(kda_chunk.fwd_flops(*at), kda_chunk.fwd_bytes(*at), PEAKS) == \
+        pytest.approx(0.986, abs=0.001)
+    # by required operations a token, forward, the KDA layers are about half the cell
+    per_token = flops.train_flops_per_token(FULL, 8192) / 3
+    z = flops._sizes(FULL)
+    kda_layer = 2 * flops.kda_matmul_params(z) + kda_chunk.fwd_flops(1, 1, 32, 128)
+    assert kda_layer == pytest.approx(79.0e6 + 5.9e6, rel=0.01)
+    assert 0.40 < z["kinds"].count("K") * kda_layer / per_token < 0.55
+
+
+def test_the_configuration_file_says_what_the_issue_says():
+    catalog = _catalog()
+    assert FULL["source"] == catalog["source_url"] and FULL["architecture"] == "kimi_linear"
+    reduced = set(FULL["reduced"])
+    assert reduced == {"num_hidden_layers", "num_experts", "vocab_size", "linear_attn_config"}
+    for key, value in catalog["config"].items():
+        if key not in reduced or key == "num_experts":     # the router keeps its published width
+            assert FULL[key] == value, key
+    lin, pub = FULL["linear_attn_config"], catalog["config"]["linear_attn_config"]
+    assert {k: lin[k] for k in ("head_dim", "num_heads", "short_conv_kernel_size")} == \
+        {"head_dim": 128, "num_heads": 32, "short_conv_kernel_size": 4}
+    n = FULL["num_hidden_layers"]
+    assert 1 + 4 <= n <= 1 + 7 and FULL["first_k_dense_replace"] == 1
+    assert lin["kda_layers"] == [l for l in pub["kda_layers"] if l <= n]
+    assert lin["full_attn_layers"] == [l for l in pub["full_attn_layers"] if l <= n]
+    assert FULL["experts_held"] == {"first": 0, "count": 16} and FULL["vocab_size"] == 163840 // 8
+    assert FULL["held_chunk_rows"] in (65536, 131072) and "held_chunk_rows_why" in FULL
+    assert FULL["published"] == {
+        "num_hidden_layers": 27, "num_experts": 256, "vocab_size": 163840,
+        "linear_attn_config": {k: pub[k] for k in ("kda_layers", "full_attn_layers")},
+        "total_params": PUBLISHED_PARAMS}
+    assert (FULL["hidden_size"], FULL["intermediate_size"], FULL["moe_intermediate_size"]) == (2304, 9216, 1024)
+    assert (FULL["kv_lora_rank"], FULL["q_lora_rank"], FULL["qk_nope_head_dim"], FULL["qk_rope_head_dim"],
+            FULL["v_head_dim"]) == (512, None, 128, 64, 128)
+    assert (FULL["num_experts_per_token"], FULL["routed_scaling_factor"], FULL["num_shared_experts"]) == \
+        (8, 2.446, 1)
+    assert FULL["precision"]["control"] == "fp8" and "16 chips" in FULL["deployment"]
+    for said in ("solve", "state", "decay"):
+        assert said in FULL["precision"]["train"]
+    assert {"low_ranks", "q_k_norm", "short_conv", "decay_init", "nope", "router_bias", "packing",
+            "weights", "source_checked"} <= set(FULL["assumed"])
+    mix = _load("benchmark/traffic/pack8k-b2-kda.json")
+    base = _load("benchmark/traffic/pack16k-afmoe.json")
+    differs = ("kind", "seq_len", "batch_size", "shape_seed", "documents")
+    assert {k: v for k, v in mix.items() if k not in differs} == \
+        {k: v for k, v in base.items() if k not in differs}
+    assert (mix["kind"], mix["seq_len"], mix["batch_size"], mix["shape_seed"]) == \
+        ("train_job_kda", 8192, 2, 20261003)
+    assert mix["documents"] == dict(base["documents"], max=8192)
+    bench = _load("BENCHMARK.json")
+    entry = next(c for c in bench["configs"] if c["name"] == FULL["name"])
+    assert set(entry["reduced"]) == reduced and entry["source"] == FULL["source"]
+    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
+    assert cell["chips"] == 1 and cell["traffic"] == "pack8k-b2-kda" and bench["workloads"][-1] == cell
+    record = _load(f"benchmark/workloads/{CELL}.json")
+    assert set(record["limits_why"]) >= set(record["limits"]) and "size" in record
+
+
+def test_the_cell_is_declared_for_the_metrics_it_reports_and_no_other():
+    bench = _load("BENCHMARK.json")
+    listed = {m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", ())}
+    for name in listed:
+        assert os.path.isfile(os.path.join(REPO, "benchmark/layer_metrics", name + ".py"))
+    mine = bench["per_layer"][-len(NEW_READERS):]                  # appended, one run of entries
+    assert [m["name"] for m in mine] == list(NEW_READERS)
+    assert all(m["workloads"] == [CELL] and m["moves"] == "train_tokens_per_s_per_chip" for m in mine)
+    assert [(m["unit"], m["better"]) for m in mine[2:4]] == [("%", "higher")] * 2
+    everyone = {m["name"] for m in bench["per_layer"]
+                if len(m.get("workloads", ())) == len(bench["workloads"])}
+    assert len(everyone) == 17 and everyone <= listed
+    assert listed == everyone | set(NEW_READERS) | {
+        "step_device_ms.ffn", "step_device_ms.moe", "kernel_peak_pct.gmm", "moe_rows_held_per_step",
+        "moe_whole_buffer_chunks_per_step", "kernel_peak_pct.mla_flash_fwd", "kernel_peak_pct.mla_flash_bwd"}
+    e2e = next(m for m in bench["end_to_end"] if m["name"] == "train_tokens_per_s_per_chip")
+    assert e2e["workloads"][-1] == CELL and e2e["bound"] == 0.01 and bench["run_seconds"] == 40
+
+
+# -- the trace readers --------------------------------------------------------------------
+@pytest.mark.parametrize("stored", ["train_1chip_v5e", "train_1chip_v5e_scoped"])
+def test_new_readers_find_nothing_in_a_trace_without_their_scopes(tmp_path, stored):
+    """Run on the parent, or in a cell of another architecture, each new reader
+    returns None and raises nothing."""
+    with gzip.open(os.path.join(REPO, "benchmark/tests/data", stored + ".xplane.pb.gz")) as src:
+        work = _trace_dir(tmp_path, stored, src.read())
+    sources = {"trace_dir": work, "peaks": PEAKS, "kda_heads": 32, "kda_head_dim": 128,
+               "step_window_events": [{"type": "step_window", "steps": 1, "moe_plan": {}}]}
+    assert {n: _read_metric(n, sources) for n in NEW_READERS} == dict.fromkeys(NEW_READERS)
+    assert {n: _read_metric(n, {}) for n in NEW_READERS} == dict.fromkeys(NEW_READERS)
+
+
+def test_new_readers_read_a_trace_with_the_scopes(tmp_path):
+    """One step of 2,000 us: a KDA layer's forward call of 100 us, its
+    recomputation of 100 and its backward of 400 at ``[2, 8192, 32 x 128]``, the
+    mixer's other work, and a 192/128 causal forward beside them."""
+    pre = "jit(train_step)/jvp(jit(loss))/checkpoint/layer/"
+    again = "jit(train_step)/transpose(jvp(jit(loss)))/checkpoint/rematted_computation/layer/"
+    bwd = "jit(train_step)/transpose(jvp(jit(loss)))/checkpoint/layer/"
+    o, st = "bf16[2,8192,4096]{2,1,0}", "f32[2,32,128,128,128]{4,3,2,1,0}"
+    dg, db = "f32[2,8192,4096]{2,1,0}", "f32[2,32,128,64,1]{4,3,2,1,0}"
+    ops = [
+        (pre + "kda/kda_core/kda_fwd/pallas_call:", f"%kda_fwd.1 = {o} custom-call()", 0, 100),
+        (again + "kda/kda_core/kda_fwd/pallas_call:", f"%kda_fwd.2 = ({o}, {st}) custom-call()", 100, 100),
+        (bwd + "kda/kda_core/kda_bwd/pallas_call:",
+         f"%kda_bwd.1 = ({o}, {o}, {o}, {dg}, {db}, {db}) custom-call()", 200, 400),
+        (bwd + "kda/kda_core/transpose", "%fusion.1 = f32[2,32,8192]{2,1,0} fusion()", 600, 20),
+        (pre + "kda/kda_proj/dot_general", "%fusion.2 = bf16[16384,4096]{1,0} fusion()", 620, 300),
+        (pre + "kda/kda_out/dot_general", "%fusion.3 = bf16[16384,2304]{1,0} fusion()", 920, 80),
+        (pre + "attn_core/flash_fwd/pallas_call:",
+         "%flash_fwd.1 = (bf16[2,32,8192,128]{3,2,1,0}, f32[2,32,1,8192]{3,2,1,0}) custom-call()", 1000, 500),
+        (pre + "ffn/dot_general", "%fusion.4 = bf16[16384,9216]{1,0} fusion()", 1500, 500),
+    ]
+    sources = {"trace_dir": _trace_dir(tmp_path, "t", _xplane(ops, [(0, 2000)])), "peaks": PEAKS,
+               "kda_heads": 32, "kda_head_dim": 128, "step_window_events": [{"type": "step_window", "steps": 1}],
+               "kda_plan": {"kernel": 6, "xla": 0, "kernel_chunk128": 6}}
+    got = {n: _read_metric(n, sources) for n in NEW_READERS}
+    assert got["step_device_ms.kda"] == pytest.approx(1.000)
+    assert got["step_device_ms.kda_core"] == pytest.approx(0.620)
+    at = (2, 8192, 32, 128)
+    roof = lambda f, b: kda_chunk.roof_seconds(f(*at), b(*at), PEAKS)
+    assert got["kernel_roof_pct.kda_fwd"] == pytest.approx(
+        100 * 2 * roof(kda_chunk.fwd_flops, kda_chunk.fwd_bytes) / 200e-6)
+    assert got["kernel_roof_pct.kda_bwd"] == pytest.approx(
+        100 * roof(kda_chunk.bwd_flops, kda_chunk.bwd_bytes) / 400e-6)
+    assert got["kda_xla_calls_per_step"] == 0.0
+    assert _read_metric("kda_xla_calls_per_step", dict(sources, kda_plan={"kernel": 0, "xla": 6})) == 6.0
+    # a call of other heads than the configuration's is not counted, and the accepted rows read on
+    assert _read_metric("kernel_roof_pct.kda_fwd", dict(sources, kda_heads=16)) is None
+    assert _read_metric("step_device_ms.attn_core", sources) == pytest.approx(0.500)
+    assert _read_metric("kernel_peak_pct.mla_flash_fwd", sources) is not None
+
+
+# -- scopes, tallies, rules ------------------------------------------------------------------
+def test_the_train_step_carries_the_scopes_the_metrics_read(tiny, monkeypatch):
+    """``kda`` encloses ``kda_proj``, ``kda_core`` (with the kernels' names) and
+    ``kda_out``; the latent layers keep ``attn_qkv``, ``attn_core``, ``attn_out``."""
+    cfg, params, batch = tiny
+    monkeypatch.setenv("KDA_BACKEND", "kernel")
+    wide = dict(cfg, linear_attn_config=dict(cfg["linear_attn_config"], head_dim=128, num_heads=1))
+    args = _args(wide)
+    shapes = jax.eval_shape(lambda: kl.init_params(jax.random.PRNGKey(0), args))
+    step = jax.jit(jax.grad(lambda p: kl.loss_fn(p, batch, args, remat="full")[0]))
+    names = set(re.findall(r'op_name="([^"]+)"', step.lower(shapes).compile().as_text()))
+    stacks = [re.split(r"[/()]", n) for n in names]
+    under = lambda scope: [s for s in stacks if scope in s]
+    assert under("kda_proj") and under("kda_out") and under("attn_qkv") and under("attn_out")
+    assert all("kda" in s for s in under("kda_proj") + under("kda_core") + under("kda_out"))
+    assert any("kda_fwd" in s for s in under("kda_core")) and any("kda_bwd" in s for s in under("kda_core"))
+    # (in interpret mode a few operations of a kernel's body keep only the jitted call's own stack)
+    assert not [s for s in stacks if ("kda_fwd" in s or "kda_bwd" in s) and "layer" in s and "kda_core" not in s]
+    assert under("moe_experts") and under("moe_router") and under("ffn") and under("lm_head_ce")
+
+
+def test_sharding_rules_cover_the_new_leaves(tiny):
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from mlx_cuda_distributed_pretraining_tpu.parallel import sharding_rules
+    from mlx_cuda_distributed_pretraining_tpu.utils.tree import flatten_dict
+
+    cfg, params, _ = tiny
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("fsdp", "tp"))
+    specs = flatten_dict(sharding_rules.tree_pspecs(params, mesh))
+    for path in specs:
+        assert any(re.search(pat, path) for pat, _ in sharding_rules._RULES), path
+    pspec = lambda path, shape: sharding_rules.param_pspec(path, shape, mesh)
+    assert pspec("layers.0.kda.wq.weight", (2304, 4096)) == P("fsdp", "tp")
+    assert pspec("layers.0.kda.wo.weight", (4096, 2304)) == P("tp", "fsdp")
+    assert pspec("layers.0.kda.f_down.weight", (2304, 128)) == P("fsdp", None)
+    assert pspec("layers.0.kda.g_up.weight", (128, 4096)) == P(None, "tp")
+    assert pspec("layers.0.kda.wb.weight", (2304, 32)) == P("fsdp", None)
+    assert pspec("layers.0.kda.conv_k.weight", (4096, 4)) == P(None, None)
+    assert pspec("layers.0.kda.A_log", (32,)) == P(None) == pspec("layers.0.kda.dt_bias", (4096,))
+    assert pspec("layers.3.attention.wq.weight", (2304, 6144)) == P("fsdp", "tp")
+    assert pspec("layers.3.attention.wkv_a.weight", (2304, 576)) == P("fsdp", None)
+    assert pspec("layers.3.attention.wkv_b.weight", (512, 8192)) == P("fsdp", "tp")
+
+
+def test_cells_one_to_five_import_nothing_of_the_new_modules():
+    """A llama, xing, afmoe, sambay or sdar run pays nothing for this architecture."""
+    code = ("import sys; sys.path.insert(0, %r)\n"
+            "from benchmark.traffic_kinds import (train_job, train_job_arch, train_job_afmoe,\n"
+            "                                     train_job_sambay, train_job_sdar)\n"
+            "from mlx_cuda_distributed_pretraining_tpu.train.trainer import Trainer\n"
+            "from mlx_cuda_distributed_pretraining_tpu.models.registry import resolve_architecture\n"
+            "for name in ('llama', 'xing_mla_moe', 'afmoe', 'sambay', 'sdar_moe'):\n"
+            "    resolve_architecture(name)\n"
+            "assert 'kimi_linear' not in train_job_arch.MODEL_SECTIONS\n"
+            "new = [m for m in sys.modules if m.endswith(('kimi_linear', 'ops.kda', 'kda_chunk', "
+            "'train_job_kda'))]\n"
+            "assert not new, new\n"
+            "assert set(resolve_architecture('kimi_linear').plans) == {'kda_plan'}\n"
+            "assert any(m.endswith('ops.kda') for m in sys.modules)\n" % REPO)
+    subprocess.run([sys.executable, "-c", code], check=True, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    with pytest.raises(ValueError, match="kimi_linear"):
+        resolve_architecture("no_such_model")
+
+
+def test_from_config_reads_the_published_keys_and_refuses_lists_that_disagree_with_the_depth():
+    args = _args(FULL, "flash")
+    assert args.layer_kinds == tuple("KKKMKKKM"[:FULL["num_hidden_layers"]])
+    assert (args.kda_heads, args.kda_head_dim, args.conv_size) == (32, 128, 4)
+    assert (args.num_heads, args.qk_head_dim, args.v_head_dim, args.kv_lora_rank) == (32, 192, 128, 512)
+    assert (args.n_routed_experts, args.num_experts_per_tok, args.experts_held) == (256, 8, (0, 16))
+    assert args.routed_scaling_factor == 2.446 and args.attention_type == "flash" and args.is_moe
+    model = kind.arch.MODEL_SECTIONS["kimi_linear"](harness.merge_into(FULL, TINY["config"]),
+                                                    {"attention_type": "simple"})
+
+    def build(**over):
+        m = json.loads(json.dumps(model))
+        for section, values in over.items():
+            m[section].update(values)
+        return kl.KimiLinearArgs.from_config(Config.from_dict({"name": "t", "model": m}).model, 512)
+
+    assert build().layer_kinds == ("K", "K", "K", "M", "K")
+    for over, match in (
+            ({"linear_attn": {"kda_layers": [1, 2, 3]}}, "name each of 5 layers once"),       # layer 5 unnamed
+            ({"linear_attn": {"full_attn_layers": [4, 5]}}, "name each of 5 layers once"),    # layer 5 twice
+            ({"linear_attn": {"kda_layers": [0, 1, 2, 4]}}, "1-based"),
+            ({"dimensions": {"num_layers": 4}}, "name each of 4 layers once"),
+            ({"moe": {"experts_held": [6, 4]}}, "experts_held"),
+            ({"moe": {"first_k_dense_replace": 5}}, "leave a routed layer"),
+            ({"mla": {"q_lora_rank": 64}}, "no query rank")):
+        with pytest.raises(ValueError, match=match):
+            build(**over)
+
+
+# -- through the trainer and the benchmark's kind -----------------------------------------------
+def test_the_cell_rehearses_through_its_traffic_kind(tmp_path, monkeypatch):
+    """``run.py --rehearse`` looks a kind up in rehearse.json, which is closed;
+    this is the new cell's rehearsal: a Context at tiny widths, the kind's own
+    ``run``: Trainer.train() on architecture kimi_linear from a dict config, the
+    window, the events' tallies, the reference's three steps, the comparison."""
+    ticks = itertools.count()   # the window counts steps, not this machine's seconds
+    monkeypatch.setattr(kind.arch.base, "time", types.SimpleNamespace(
+        perf_counter=lambda: 0.008 * next(ticks)))
+    bench, cell, config, mix = harness.load_cell(CELL)
+    assert mix["kind"] == "train_job_kda" and cell["chips"] == 1
+    config = harness.merge_into(config, TINY["config"])
+    mix = harness.merge_into(mix, TINY["traffic"])
+    # every number held to 0.05 but the first gradient's profile: at these widths a held expert
+    # sees a few dozen rows, and a token bfloat16 routes elsewhere turns its bank's gradient (0.17
+    # at layer 5's w_down; the other numbers read 1e-5 to 0.013)
+    cell = dict(cell, limits={k: 0.3 if k == "first_grad_profile_gap" else 0.05 for k in cell["limits"]})
+    ctx = harness.Context(cell, config, mix, seed=3_000_000_019, seconds=1.5, trace=False,
+                          rehearse=True, workdir=str(tmp_path), quiet=True)
+    res = kind.run(ctx)
+    assert res["correct"], res["check_numbers"]
+    assert len(res["sources"]["timed_steps"]) >= 5 and len(res["check_numbers"]) == 3 + 3
+    assert (res["sources"]["kda_heads"], res["sources"]["kda_head_dim"]) == (2, 32)
+    assert max(v for k, v in res["check_numbers"].items() if k.startswith("loss_gap")) < 1e-3
+    events = res["sources"]["step_window_events"]
+    # the tally rides the run's first event, as every plan does, and the kind hands it on
+    plan = res["sources"]["kda_plan"]
+    assert plan["xla"] >= 4 and plan["kernel"] == 0 and plan["kda_layers"] >= 4 and plan["latent_layers"] >= 1
+    assert events and all(e["moe_drop"] == 0 and e["moe_rows_held"] > 0 and "kda_plan" not in e for e in events)
+    assert _read_metric("kda_xla_calls_per_step", res["sources"]) >= 4       # no kernel off the chip
+    run_dir, = (os.path.join(tmp_path, "runs", d) for d in os.listdir(os.path.join(tmp_path, "runs")))
+    first = next(e for e in train_job._read_events(run_dir) if e.get("type") == "step_window")
+    assert first["kda_plan"] == plan and plan["xla_chunk128"] >= 4
+    assert first["fused_ce_plan"]["grad_in_forward"] >= 1
+    assert res["end_to_end"]["train_tokens_per_s_per_chip"] > 0 and res["end_to_end"]["setup_s"] > 0
+    held = np.mean([e["moe_rows_held"] for e in events]) / 256 / flops.routed_layers(config)
+    assert res["sources"]["flops_per_token"] == pytest.approx(
+        flops.train_flops_per_token(config, mix["seq_len"], held))
+    # control_kda.py --sound reads the same numbers on that seed without the window: one trainer
+    # for all its seeds, the trainer's own step and state, the harness's recorder
+    from benchmark import control_kda
+    lines = []
+    control_kda.main(["--workload", CELL, "--seeds", "", "--sound", str(ctx.seed),
+                      "--rehearse", "rehearse_kda.json"], say=lines.append)
+    sound, = (json.loads(l) for l in lines if l.startswith("{"))
+    assert sound["sound"] == "program" and sound["seed"] == ctx.seed
+    assert sound["numbers"] == pytest.approx(res["check_numbers"], rel=1e-6)
+
+
+def test_the_control_runs_the_reference_in_the_place_of_the_program(tmp_path):
+    """``control_kda.py``'s comparison at tiny widths: the float32 reference
+    against itself with the delta rule's decay and state alone in bfloat16 (the
+    diagnosis; float8 operands are ``llama_dense._mm``'s, as in every cell):
+    every number moves."""
+    from benchmark import control_arch
+
+    bench, cell, config, mix = harness.load_cell(CELL)
+    config = harness.merge_into(config, TINY["config"])
+    mix = harness.merge_into(mix, dict(TINY["traffic"], checked_steps=1))
+    verdict = control_arch.train_control(dict(cell, limits=dict.fromkeys(cell["limits"], 1e-9)),
+                                         config, mix, 11, ref.BF16_KDA, rehearse=True, say=lambda _: None)
+    assert not verdict["ok"] and all(v > 0 for v in verdict["numbers"].values()), verdict["numbers"]
+
+
+def test_the_sample_config_trains_through_the_cli(tmp_path):
+    """``train.py --config configs/model-config-kimi-linear-sample.yaml`` on the
+    CPU: a tokenised corpus, training and validation, the tally on the lines."""
+    (tmp_path / "train.jsonl").write_text("".join(
+        json.dumps({"text": "the quick brown fox jumps over the lazy dog " * 8}) + "\n"
+        for _ in range(200)))
+    shutil.copy(tmp_path / "train.jsonl", tmp_path / "val.jsonl")
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "train.py"), "--config",
+         os.path.join(REPO, "configs/model-config-kimi-linear-sample.yaml"), "--runs-root",
+         str(tmp_path / "runs"), "--iters", "6", "--batch-size", "2",
+         "--set", "logging.steps.logging_interval=2", "--set", "logging.steps.validation_interval=3"],
+        cwd=tmp_path, capture_output=True, text=True, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    log = out.stdout + out.stderr
+    assert re.search(r"Step 6: loss=", log), log[-1500:]
+    assert re.search(r"Step 3 validation: val_loss=", log), log[-1500:]
+    assert re.search(r"delta-rule layers \(traced; cores by form and chunk\): kda_layers=\d+, "
+                     r"latent_layers=\d+, kernel=0, xla=\d+", log), log[-1500:]

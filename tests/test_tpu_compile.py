@@ -604,6 +604,38 @@ def test_selective_scan_kernels_compile_for_v5e_at_the_cells_width(v5e, monkeypa
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * S * Di * 4
 
 
+def test_kda_kernels_compile_for_v5e_at_the_cells_call(v5e, monkeypatch):
+    """``kimi-linear-48b-a3b-ep16.train-seq8k``'s delta-rule core (ops/kda.py): 2 rows
+    of 8,192 steps, 32 heads of 128, bfloat16 operands beside a float32 decay,
+    forward and backward: Mosaic takes both kernels (one head's lanes of a chunk
+    as a block of ``[B, S, H d]``, ``beta`` as a column and as a row, the float32
+    solve, the sub-blocks' pair passes), the differentiated call holds one
+    forward with its saved states and one backward, and nothing larger than the
+    states at chunk starts (0.5 GiB) is left in the program."""
+    from mlx_cuda_distributed_pretraining_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_interpret", lambda: False)
+    Bt, S, H, d = 2, 8192, 32, 128
+    ops = tuple(_sds((Bt, S, H, d), t, v5e) for t in (jnp.bfloat16,) * 3 + (jnp.float32,)) \
+        + (_sds((Bt, S, H), jnp.float32, v5e),)
+    before = kda.plan_counts()
+    core = lambda *a: kda.kda(*a, backend="kernel")
+    forward = jax.jit(core).lower(*ops).compile()
+    grad = jax.jit(jax.grad(lambda *a: core(*a).astype(jnp.float32).sum(), argnums=tuple(range(5))))
+    compiled = grad.lower(*ops).compile()
+    traced = {k: n - before.get(k, 0) for k, n in kda.plan_counts().items() if n - before.get(k, 0)}
+    assert traced == {"kernel": 2, f"kernel_chunk{kda.KERNEL_CHUNK}": 2}
+    calls = lambda c: [line for line in c.as_text().split("\n") if "tpu_custom_call" in line]
+    assert sum("kda_fwd" in c for c in calls(forward)) == 1 and len(calls(forward)) == 1
+    assert sum("kda_fwd" in c for c in calls(compiled)) == 1 and sum("kda_bwd" in c for c in calls(compiled)) == 1
+    states = f"f32[{Bt},{H},{S // kda.KERNEL_CHUNK},{d},{d}]"
+    # the primal alone saves no state; differentiated: the states, five cotangents and, for
+    # operands handed over as [B, S, H, d], their copies into [B, S, H d] (a model's projections
+    # come in that layout)
+    assert states not in forward.as_text() and states in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * Bt * S * H * d * 2   # a dozen operands' worth
+
+
 def test_differential_attention_cores_compile_for_v5e_at_the_cells_length(v5e, compiled_kernels):
     """The same cell's attention (models/sambay.py): 40 + 40 stacked query heads of
     64 over 20 stacked key heads and values of 128, one row of 16,384, under the
