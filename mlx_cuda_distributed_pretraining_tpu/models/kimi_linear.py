@@ -14,8 +14,9 @@ What differs from ``models/llama.py``, block by block:
   periods needs every period alike, and the first holds the dense layer (and a
   cut stack need not end on a period).
 - **KDA mixer** (``kda``): ``q, k, v`` each a projection, a causal depthwise
-  convolution of ``conv_size`` taps (``stack.causal_depthwise_conv``) and a
-  SiLU; ``q`` and ``k`` L2-normed over a head, ``q`` scaled by ``d^-1/2``; a
+  convolution of ``conv_size`` taps and a SiLU; ``q`` and ``k`` L2-normed over
+  a head, ``q`` scaled by ``d^-1/2`` (one pass over the projection,
+  ``ops/short_conv.py``: a kernel pair on a TPU); a
   log decay for every key channel ``g = -exp(A_log) softplus(x W_f^down W_f^up
   + dt_bias)`` and a write strength ``beta = sigmoid(x W_beta)`` a head; the
   gated delta rule ``ops/kda.py``; then ``RMSNorm_head(o) * sigmoid(x W_g^down
@@ -46,6 +47,7 @@ import jax.numpy as jnp
 
 from ..ops import attention as attention_ops
 from ..ops import kda as kda_ops
+from ..ops import short_conv as conv_ops
 from . import moe as moe_lib
 from . import stack
 from .llama import mlp_block, rms_norm
@@ -53,7 +55,6 @@ from .registry import Architecture, register
 from .xing import latent_keys_values, latent_kv_projections
 
 Params = Dict[str, Any]
-L2_EPS = 1e-6
 
 
 @dataclass(frozen=True)
@@ -153,10 +154,11 @@ _plan_counts_lock = threading.Lock()
 
 def kda_plan_counts() -> Dict[str, int]:
     """``kda_layers`` and ``latent_layers`` traced, then the delta-rule cores by
-    form and chunk (``ops/kda.plan_counts``)."""
+    form and chunk (``ops/kda.plan_counts``: ``kernel``, ``xla``) and the q, k, v
+    prologues by form (``ops/short_conv.plan_counts``: ``conv_kernel``, ``conv_xla``)."""
     with _plan_counts_lock:
         own = {k: _layer_counts[k] for k in ("kda_layers", "latent_layers")}
-    return {**own, **kda_ops.plan_counts()}
+    return {**own, **kda_ops.plan_counts(), **conv_ops.plan_counts()}
 
 
 # -- init ---------------------------------------------------------------------
@@ -222,11 +224,6 @@ def init_params(rng: jax.Array, args: KimiLinearArgs, dtype=jnp.float32) -> Para
 
 
 # -- sub-layers ---------------------------------------------------------------------
-def _l2_heads(a: jnp.ndarray, scale: float = 1.0) -> jnp.ndarray:
-    """``a [..., d]`` float32 over its last axis: ``a / sqrt(sum a^2 + eps)``, times ``scale``."""
-    return a * (jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + L2_EPS) * scale)
-
-
 def kda_mixer(p: Params, x: jnp.ndarray, args: KimiLinearArgs) -> jnp.ndarray:
     """``x [B, S, C]`` (normed) -> ``[B, S, C]``. The convolutions, the norms of
     ``q`` and ``k``, the decay, ``beta`` and the head norm are float32; the
@@ -236,11 +233,11 @@ def kda_mixer(p: Params, x: jnp.ndarray, args: KimiLinearArgs) -> jnp.ndarray:
     f32 = jnp.float32
     with jax.named_scope("kda"):
         with jax.named_scope("kda_proj"):
-            short = lambda w, conv: jax.nn.silu(stack.causal_depthwise_conv(
-                x @ p[w]["weight"], p[conv]["weight"])).reshape(B, S, H, d)
-            q = _l2_heads(short("wq", "conv_q"), d ** -0.5).astype(x.dtype)
-            k = _l2_heads(short("wk", "conv_k")).astype(x.dtype)
-            v = short("wv", "conv_v").astype(x.dtype)
+            short = lambda w, conv, **norm: conv_ops.short_conv(
+                x @ p[w]["weight"], p[conv]["weight"], out_dtype=x.dtype, **norm).reshape(B, S, H, d)
+            q = short("wq", "conv_q", heads=H, scale=d ** -0.5)
+            k = short("wk", "conv_k", heads=H)
+            v = short("wv", "conv_v")
             low = lambda down, up: jnp.einsum(
                 "bsr,re->bse", x @ p[down]["weight"], p[up]["weight"], preferred_element_type=f32)
             step = jax.nn.softplus(low("f_down", "f_up") + p["dt_bias"].astype(f32))

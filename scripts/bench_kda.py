@@ -1,14 +1,22 @@
 #!/usr/bin/env python3
-"""Time the Kimi Delta Attention core alone on the chip (``ops/kda.py``): the
-forward kernel, and forward (with the states saved) plus backward, at each
-``--chunks`` size, against the roof ``benchmark/flops/kda_chunk.py`` counts.
+"""Time a KDA mixer's kernels alone on the chip: the delta rule's core
+(``ops/kda.py``), the forward kernel and forward (with the states saved) plus
+backward at each ``--chunks`` size, against the roof
+``benchmark/flops/kda_chunk.py`` counts; or, with ``--prologue``, the q, k, v
+prologue (``ops/short_conv.py``), its two kernels at each ``--blocks`` shape
+and its XLA form, against the bytes a call has to move, after one line with the
+seconds the host takes to trace and lower each kernel body once (what every run
+of the cell pays before its first step, whatever the compile cache holds).
 
     python scripts/bench_kda.py [--shape 2x8192x32x128] [--chunks 64,128] [--heads 1,2,4] [--reps 5]
+    python scripts/bench_kda.py --prologue [--blocks 512x1024,256x2048] [--tiles 32] [--check]
 
 Prints ms a call (a jitted loop of ``--inner`` calls, the median of ``--reps``
 runs that end in ``block_until_ready``, over ``--inner``) and the share of the
-roof; ``--check`` also compares the kernel's value and gradients at a short
-length against the XLA form on the chip. Step 0 of PR 51 (PERF.md section 6).
+roof (``short_conv``: GB/s of the required bytes); ``--check`` also compares the
+kernel's value and gradients at a short length against the XLA form on the
+chip. Step 0 of PR 51, the block shapes of PR 52 and the bodies' host cost of PR 53
+(PERF.md section 6).
 """
 
 from __future__ import annotations
@@ -27,6 +35,7 @@ import jax.numpy as jnp
 from benchmark import peaks
 from benchmark.flops import kda_chunk
 from mlx_cuda_distributed_pretraining_tpu.ops import kda as kda_ops
+from mlx_cuda_distributed_pretraining_tpu.ops import short_conv as conv_ops
 
 
 def operands(B, S, H, d, dtype, seed=0, scale=0.3):
@@ -42,13 +51,19 @@ def operands(B, S, H, d, dtype, seed=0, scale=0.3):
     return q, k, v, g, beta, w
 
 
-def timed(fn, args, reps, inner):
-    """Every trip's ``v`` takes a (zero) term of the trip before, so the call is not invariant in
-    the loop: XLA hoisted an invariant call out and PR 51's first step 0 read a quarter of the time."""
-    def loop(q, k, v, *rest):
+def _mean(o):
+    return jnp.mean(o.astype(jnp.float32))
+
+
+def timed(fn, args, reps, inner, chain=2, taste=_mean):
+    """Every trip's ``v`` (operand ``chain``) takes a (zero) term of the trip before, so the call is
+    not invariant in the loop: XLA hoisted an invariant call out and PR 51's first step 0 read a
+    quarter of the time. ``taste``: what of each result the next trip waits for."""
+    def loop(*ops):
         def body(_, acc):
-            out = fn(q, k, v + (acc * 0.0).astype(v.dtype), *rest)
-            return acc + sum(jnp.mean(o.astype(jnp.float32)) for o in jax.tree_util.tree_leaves(out))
+            fed = ops[chain] + (acc * 0.0).astype(ops[chain].dtype)
+            out = fn(*ops[:chain], fed, *ops[chain + 1:])
+            return acc + sum(taste(o) for o in jax.tree_util.tree_leaves(out))
         return jax.lax.fori_loop(0, inner, body, jnp.zeros((), jnp.float32))
     run = jax.jit(loop)
     jax.block_until_ready(run(*args))
@@ -60,8 +75,86 @@ def timed(fn, args, reps, inner):
     return 1e3 * statistics.median(out) / inner
 
 
+def _with_grads(f, g):
+    """``f(*ops)`` and its cotangents under ``g``, one tuple: a forward and a backward call."""
+    return lambda *ops: (lambda y, vjp: (y,) + vjp(g))(*jax.vjp(f, *ops))
+
+
+def lowering_seconds(x, w, H, d):
+    """Seconds to trace and lower (``jit(...).lower()``, nothing compiled) the prologue's call
+    once from cold caches, forward alone and differentiated (the backward body and the trace of
+    the forward), with the norm (q and k: one body, the scale an operand) and without (v)."""
+    out = []
+    for label, heads in (("norm", H), ("no norm", None)):
+        call = lambda x, w: conv_ops.short_conv(x, w, heads=heads, scale=d ** -0.5 if heads else 1.0, backend="kernel")
+        grad = jax.grad(lambda x, w: call(x, w).astype(jnp.float32).sum(), argnums=(0, 1))
+        for fn in (call, grad):
+            jax.clear_caches()
+            t0 = time.perf_counter()
+            jax.jit(fn).lower(x, w)
+            out.append(time.perf_counter() - t0)
+        print(f"host, trace and lower once, {label}: forward {out[-2]:.3f} s, differentiated {out[-1]:.3f} s", flush=True)
+    print(f"host, trace and lower once, all four bodies: {sum(out):.3f} s", flush=True)
+
+
+def bench_short_conv(a, B, S, H, d):
+    """The prologue at the cell's call, ``[B, S, H d]`` bfloat16 and four taps: the kernels are
+    chained through the taps (16 K numbers) and tasted by one element, so a trip is the call
+    and nothing the size of its operand; XLA's form would be cut down to that element, so its
+    trips take a mean, which fuses into its last pass."""
+    D, dt = H * d, jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    x = jax.random.normal(ks[0], (B, S, D), jnp.float32).astype(dt)
+    w = (jax.random.normal(ks[1], (D, 4), jnp.float32) * 0.5).astype(dt)
+    g = jax.random.normal(ks[2], (B, S, D), jnp.float32).astype(dt)
+    fwd_bytes, bwd_bytes = 2 * x.size * 2, 3 * x.size * 2
+    print(f"required bytes: forward {fwd_bytes / 1e6:.0f} MB (a read, y written), backward "
+          f"{bwd_bytes / 1e6:.0f} MB (a and dy read, da written); HBM {peaks.peak(jax.devices()[0].device_kind)['hbm_bytes_per_s'] / 1e9:.0f} GB/s", flush=True)
+    lowering_seconds(x, w, H, d)
+    one = lambda o: o.ravel()[0].astype(jnp.float32)
+
+    def report(label, heads, call, taste):
+        fwd = lambda w, x: call(x, w, heads)
+        both = lambda w, x, g: _with_grads(lambda x, w: call(x, w, heads), g)(x, w)
+        t_f = timed(fwd, (w, x), a.reps, a.inner, chain=0, taste=taste)
+        t_fb = timed(both, (w, x, g), a.reps, a.inner, chain=0, taste=taste)
+        print(f"{label}, {'norm over ' + str(heads) + ' heads' if heads else 'no norm'}: forward {t_f:.3f} ms "
+              f"({fwd_bytes / t_f / 1e6:.0f} GB/s); forward + backward {t_fb:.3f} ms (backward about "
+              f"{t_fb - t_f:.3f} ms, {bwd_bytes / max(t_fb - t_f, 1e-9) / 1e6:.0f} GB/s)", flush=True)
+
+    for heads in (H, None):
+        report("xla", heads, lambda x, w, heads: conv_ops._short_conv(
+            x, w, None, heads, 1.0, dt, "xla", 0, 0), _mean)
+    for tile in (int(t) for t in a.tiles.split(",")):
+        conv_ops._TILE_ROWS = tile     # the walk inside a block: the module's constant, steered here alone
+        for rows, lanes in (tuple(int(v) for v in b.split("x")) for b in a.blocks.split(",")):
+            for heads in (H, None):
+                report(f"kernel rows {rows} lanes {lanes} tile {tile}", heads,
+                       lambda x, w, heads: conv_ops._short_conv(x, w, None, heads, 1.0, dt, "kernel", rows, lanes), one)
+    if a.check:
+        rel = lambda p, q: float(jnp.linalg.norm(p.astype(jnp.float32) - q.astype(jnp.float32))
+                                 / (jnp.linalg.norm(q.astype(jnp.float32)) + 1e-30))
+        xs, gs = x[:1, :2048], g[:1, :2048]
+        bias = jax.random.normal(ks[2], (D,), jnp.float32) * 0.1
+        for adt, heads, b in ((jnp.bfloat16, H, None), (jnp.float32, H, None), (jnp.float32, None, bias)):
+            xa, ga = xs.astype(adt), gs.astype(adt)
+            form = lambda be: jax.jit(_with_grads(lambda x, w, b: conv_ops.short_conv(
+                x, w, bias=b, heads=heads, scale=d ** -0.5 if heads else 1.0, out_dtype=adt, backend=be), ga))
+            got, want = form("kernel")(xa, w.astype(jnp.float32), b), form("xla")(xa, w.astype(jnp.float32), b)
+            print(f"check {jnp.dtype(adt).name}, {'heads ' + str(heads) if heads else 'no norm, bias'} (1 x 2048 x {D}): "
+                  "value, da, dw" + (", dbias " if b is not None else " ")
+                  + " ".join(f"{rel(p, q):.2e}" for p, q in zip(jax.tree_util.tree_leaves(got),
+                                                              jax.tree_util.tree_leaves(want))), flush=True)
+
+
 def main():
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    p.add_argument("--op", choices=("kda", "short_conv"), default="kda")
+    p.add_argument("--prologue", dest="op", action="store_const", const="short_conv",
+                   help="the q, k, v prologue's kernel pair (the same as --op short_conv)")
+    p.add_argument("--blocks", default=f"{conv_ops._BLOCK_ROWS}x{conv_ops._BLOCK_LANES}",
+                   help="short_conv: rows x lanes of a block, comma-separated")
+    p.add_argument("--tiles", default=str(conv_ops._TILE_ROWS), help="short_conv: rows a trip inside a block")
     p.add_argument("--shape", default="2x8192x32x128")
     p.add_argument("--chunks", default="64,128")
     p.add_argument("--heads", default=str(kda_ops.HEADS_PER_STEP),
@@ -74,6 +167,8 @@ def main():
     dev = jax.devices()[0]
     pk = peaks.peak(dev.device_kind)
     print(f"device: {dev.platform} {dev.device_kind}; call {B} x {S} x {H} x {d} bfloat16", flush=True)
+    if a.op == "short_conv":
+        return bench_short_conv(a, B, S, H, d)
     q, k, v, g, beta, w = operands(B, S, H, d, jnp.bfloat16)
     roof_f = 1e3 * kda_chunk.roof_seconds(kda_chunk.fwd_flops(B, S, H, d), kda_chunk.fwd_bytes(B, S, H, d), pk)
     roof_b = 1e3 * kda_chunk.roof_seconds(kda_chunk.bwd_flops(B, S, H, d), kda_chunk.bwd_bytes(B, S, H, d), pk)

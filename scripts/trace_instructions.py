@@ -4,14 +4,17 @@
     python scripts/trace_instructions.py <file.xplane.pb> [--scope moe_experts] [--top 40]
 
 ``benchmark/trace_scopes.py`` sums a trace by scope; this splits one scope (the
-innermost vocabulary name of an operation's name stack, as there) by what the
+innermost vocabulary name of an operation's name stack, as there; a name the
+closed vocabulary lacks, such as ``kda_proj``, is taken wherever it stands in
+the stack, as ``benchmark/layer_metrics/_named_scopes.py`` reads it) by what the
 instructions are: a row for every (pass, the jax primitive the name stack ends
 in, the instruction's result shapes), with its events a step and its self time
 a step over the whole steps of the trace. ``pass`` is ``recomputed`` under
 ``rematted_computation``, else ``backward`` under a ``transpose(``, else
 ``forward``. A fusion carries the name stack of its root, so a row is a
 fusion's whole time under its root's primitive. The rows add up to the scope's
-line in ``trace_scopes.py``'s table.
+line in ``trace_scopes.py``'s table (to the ``step_device_ms.<scope>`` row for a
+name outside the vocabulary).
 """
 
 from __future__ import annotations
@@ -60,10 +63,14 @@ def rows_of(path: str, scope: str):
     ops = [(m, max(s, lo), min(e, hi)) for m, s, e in p["lines"].get(trace_reduce.OPS_LINE, [])
            if min(e, hi) > max(s, lo)]
     rows = collections.defaultdict(lambda: [0, 0.0])
+    if scope in trace_scopes.VOCABULARY:
+        inside = lambda op_name: trace_scopes.scope_of(op_name) == scope
+    else:
+        inside = lambda op_name: scope in trace_scopes._SPLIT.split(op_name)
     for m, t in trace_reduce.self_times(ops):
         rec = p["events"].get(m, {})
         op_name = rec.get("tf_op") or ""
-        if trace_scopes.scope_of(op_name) != scope:
+        if not inside(op_name):
             continue
         primitive = op_name.rstrip(":/").rpartition("/")[2]
         row = rows[(passes_of(op_name), primitive, result_shapes(rec.get("name", "")))]

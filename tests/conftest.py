@@ -134,3 +134,16 @@ def flash_path(request, monkeypatch):
         assert after[key] == before[key], f"a {key} kernel was traced"
     assert (after[f"bwd_dq_{path}"] - before[f"bwd_dq_{path}"]
             == after[f"bwd_dkv_{path}"] - before[f"bwd_dkv_{path}"])
+
+
+# --- no mesh left behind by another file's Trainer ------------------------------
+# A Trainer hands its mesh to parallel/context.set_mesh, where it outlives the Trainer; under a
+# mesh of several devices the ops that choose a path from what they can see (ops/kda.py,
+# ops/short_conv.py: GSPMD cannot partition their kernels) take their XLA form. A test that counts
+# kernel calls runs under this fixture, so that what ran before it in its worker decides nothing.
+
+@pytest.fixture
+def no_mesh_left_behind(monkeypatch):
+    from mlx_cuda_distributed_pretraining_tpu.parallel import context
+
+    monkeypatch.setattr(context, "_BASE", [None])

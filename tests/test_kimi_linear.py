@@ -7,6 +7,7 @@ tallies and readers, and the benchmark's traffic kind for it.
 """
 
 import gzip
+import importlib.util
 import itertools
 import json
 import os
@@ -31,13 +32,14 @@ from mlx_cuda_distributed_pretraining_tpu.config import Config
 from mlx_cuda_distributed_pretraining_tpu.models import kimi_linear as kl
 from mlx_cuda_distributed_pretraining_tpu.models.registry import resolve_architecture
 from mlx_cuda_distributed_pretraining_tpu.ops import kda as kda_ops
+from mlx_cuda_distributed_pretraining_tpu.ops import short_conv as conv_ops
 from test_afmoe import _read_metric, _trace_dir, _xplane
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELL = "kimi-linear-48b-a3b-ep16.train-seq8k"
 B, S = 2, 64
 NEW_READERS = ("step_device_ms.kda", "step_device_ms.kda_core", "kernel_roof_pct.kda_fwd",
-               "kernel_roof_pct.kda_bwd", "kda_xla_calls_per_step")
+               "kernel_roof_pct.kda_bwd", "kda_xla_calls_per_step", "kda_conv_xla_calls_per_step")
 PEAKS = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
 
 
@@ -90,14 +92,25 @@ def _leaf_gaps(got, want):
 
 
 # -- the model against the reference ------------------------------------------------
-@pytest.mark.parametrize("attention_type,backend", [("simple", "xla"), ("flash", "xla")])
-def test_program_matches_reference_loss_and_every_gradient(tiny, reference_step, monkeypatch,
+@pytest.mark.parametrize("attention_type,backend", [("simple", "xla"), ("flash", "xla"), ("simple", "kernel")])
+def test_program_matches_reference_loss_and_every_gradient(tiny, reference_step, monkeypatch, no_mesh_left_behind,
                                                           attention_type, backend):
+    """The third case runs the mixer's four kernels (interpret mode) at a head the
+    width of a register, against the reference at the same widths."""
     cfg, params, batch = tiny
     monkeypatch.setenv("KDA_BACKEND", backend)
+    if backend == "kernel":
+        cfg = dict(cfg, linear_attn_config=dict(cfg["linear_attn_config"], head_dim=128, num_heads=1))
+        params = ref.init_params(7, cfg)
+        reference_step = ref.loss_and_grads(params, batch["inputs"], batch["targets"], cfg)
     args = _args(cfg, attention_type)
+    before = kl.kda_plan_counts()
     (loss, count), grads = jax.jit(jax.value_and_grad(
         lambda p: kl.loss_fn(p, batch, args, remat="full"), has_aux=True))(params)
+    traced = {k: n - before.get(k, 0) for k, n in kl.kda_plan_counts().items()}
+    # a mixer's core and its three prologues take one form: the kernels only where they were asked for
+    assert traced[backend] >= 4 and traced["conv_" + backend] == 3 * traced[backend]
+    assert traced["xla" if backend == "kernel" else "kernel"] == 0 == traced["conv_xla" if backend == "kernel" else "conv_kernel"]
     (want,), want_grads = reference_step
     assert float(count) == B * S
     assert float(loss) == pytest.approx(float(want), rel=2e-5)
@@ -357,6 +370,8 @@ def test_the_cell_is_declared_for_the_metrics_it_reports_and_no_other():
     assert [m["name"] for m in mine] == list(NEW_READERS)
     assert all(m["workloads"] == [CELL] and m["moves"] == "train_tokens_per_s_per_chip" for m in mine)
     assert [(m["unit"], m["better"]) for m in mine[2:4]] == [("%", "higher")] * 2
+    assert [(m["unit"], m["better"], m["source"], m["layer"]) for m in mine[4:]] == \
+        [("count", "lower", "program_counter", "train step")] * 2      # the two tallies: cores, prologues
     everyone = {m["name"] for m in bench["per_layer"]
                 if len(m.get("workloads", ())) == len(bench["workloads"])}
     assert len(everyone) == 17 and everyone <= listed
@@ -415,10 +430,27 @@ def test_new_readers_read_a_trace_with_the_scopes(tmp_path):
         100 * roof(kda_chunk.bwd_flops, kda_chunk.bwd_bytes) / 400e-6)
     assert got["kda_xla_calls_per_step"] == 0.0
     assert _read_metric("kda_xla_calls_per_step", dict(sources, kda_plan={"kernel": 0, "xla": 6})) == 6.0
+    # the parent's tally names the cores alone: nothing to read; this program's names both forms
+    assert got["kda_conv_xla_calls_per_step"] is None
+    both = dict(sources["kda_plan"], conv_kernel=18, conv_xla=0)
+    assert _read_metric("kda_conv_xla_calls_per_step", dict(sources, kda_plan=both)) == 0.0
+    assert _read_metric("kda_conv_xla_calls_per_step", dict(sources, kda_plan=dict(both, conv_xla=18))) == 18.0
+    assert _read_metric("kda_xla_calls_per_step", dict(sources, kda_plan=dict(both, conv_xla=18))) == 0.0
     # a call of other heads than the configuration's is not counted, and the accepted rows read on
     assert _read_metric("kernel_roof_pct.kda_fwd", dict(sources, kda_heads=16)) is None
     assert _read_metric("step_device_ms.attn_core", sources) == pytest.approx(0.500)
     assert _read_metric("kernel_peak_pct.mla_flash_fwd", sources) is not None
+    # scripts/trace_instructions.py splits the same scopes by instruction: a name the closed
+    # vocabulary lacks is taken wherever it stands in the stack, and its rows add up to the reader's
+    spec = importlib.util.spec_from_file_location("trace_instructions", os.path.join(REPO, "scripts/trace_instructions.py"))
+    by_instruction = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(by_instruction)
+    xplane = by_instruction.trace_reduce.find_xplane(sources["trace_dir"])
+    for scope, us in (("kda_proj", 300), ("kda_core", 620), ("kda", 1000), ("ffn", 500)):
+        steps, rows = by_instruction.rows_of(xplane, scope)
+        assert steps == 1 and sum(t for _, t in rows.values()) == pytest.approx(us * 1e-6), scope
+    _, rows = by_instruction.rows_of(xplane, "kda_core")
+    assert {k[0] for k in rows} == {"forward", "recomputed", "backward"}
 
 
 # -- scopes, tallies, rules ------------------------------------------------------------------
@@ -437,6 +469,9 @@ def test_the_train_step_carries_the_scopes_the_metrics_read(tiny, monkeypatch):
     assert under("kda_proj") and under("kda_out") and under("attn_qkv") and under("attn_out")
     assert all("kda" in s for s in under("kda_proj") + under("kda_core") + under("kda_out"))
     assert any("kda_fwd" in s for s in under("kda_core")) and any("kda_bwd" in s for s in under("kda_core"))
+    # the q, k, v prologue's kernel pair sits with the projections, outside the core's time
+    assert any("short_conv_fwd" in s for s in under("kda_proj")) and any("short_conv_bwd" in s for s in under("kda_proj"))
+    assert not [s for s in under("kda_core") if "short_conv_fwd" in s or "short_conv_bwd" in s]
     # (in interpret mode a few operations of a kernel's body keep only the jitted call's own stack)
     assert not [s for s in stacks if ("kda_fwd" in s or "kda_bwd" in s) and "layer" in s and "kda_core" not in s]
     assert under("moe_experts") and under("moe_router") and under("ffn") and under("lm_head_ce")
@@ -476,11 +511,12 @@ def test_cells_one_to_five_import_nothing_of_the_new_modules():
             "for name in ('llama', 'xing_mla_moe', 'afmoe', 'sambay', 'sdar_moe'):\n"
             "    resolve_architecture(name)\n"
             "assert 'kimi_linear' not in train_job_arch.MODEL_SECTIONS\n"
-            "new = [m for m in sys.modules if m.endswith(('kimi_linear', 'ops.kda', 'kda_chunk', "
-            "'train_job_kda'))]\n"
+            "new = [m for m in sys.modules if m.endswith(('kimi_linear', 'ops.kda', 'ops.short_conv', "
+            "'kda_chunk', 'train_job_kda'))]\n"
             "assert not new, new\n"
             "assert set(resolve_architecture('kimi_linear').plans) == {'kda_plan'}\n"
-            "assert any(m.endswith('ops.kda') for m in sys.modules)\n" % REPO)
+            "assert any(m.endswith('ops.kda') for m in sys.modules)\n"
+            "assert any(m.endswith('ops.short_conv') for m in sys.modules)\n" % REPO)
     subprocess.run([sys.executable, "-c", code], check=True, env={**os.environ, "JAX_PLATFORMS": "cpu"})
     with pytest.raises(ValueError, match="kimi_linear"):
         resolve_architecture("no_such_model")
@@ -545,6 +581,8 @@ def test_the_cell_rehearses_through_its_traffic_kind(tmp_path, monkeypatch):
     assert plan["xla"] >= 4 and plan["kernel"] == 0 and plan["kda_layers"] >= 4 and plan["latent_layers"] >= 1
     assert events and all(e["moe_drop"] == 0 and e["moe_rows_held"] > 0 and "kda_plan" not in e for e in events)
     assert _read_metric("kda_xla_calls_per_step", res["sources"]) >= 4       # no kernel off the chip
+    assert plan["conv_xla"] == 3 * plan["xla"] and plan["conv_kernel"] == 0
+    assert _read_metric("kda_conv_xla_calls_per_step", res["sources"]) == plan["conv_xla"]
     run_dir, = (os.path.join(tmp_path, "runs", d) for d in os.listdir(os.path.join(tmp_path, "runs")))
     first = next(e for e in train_job._read_events(run_dir) if e.get("type") == "step_window")
     assert first["kda_plan"] == plan and plan["xla_chunk128"] >= 4

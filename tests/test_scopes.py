@@ -296,12 +296,13 @@ def test_named_scopes_in_the_program_are_the_vocabulary():
     # name the trace's reader keys on (plan_counts() tells them apart)
     assert sorted(kernels) == ["ce_softmax_grad", "flash_bwd_dkv", "flash_bwd_dkv",
                                "flash_bwd_dq", "flash_bwd_dq", "flash_fwd", "flash_fwd",
-                               "gmm", "kda_bwd", "kda_fwd", "ssm_scan_bwd", "ssm_scan_fwd", "tgmm",
-                               "token_dot", "token_sum"]
+                               "gmm", "kda_bwd", "kda_fwd", "short_conv_bwd", "short_conv_fwd",
+                               "ssm_scan_bwd", "ssm_scan_fwd", "tgmm", "token_dot", "token_sum"]
     assert pallas_calls == len(kernels), "a pallas_call without a name="
     # architecture xing_mla_moe opens two more, afmoe three, sambay five with
     # its two scan kernels, sdar_moe two and kimi_linear four with its two delta-rule
-    # kernels, which the benchmark reads by their own helpers (layer_metrics/_named_scopes.py,
+    # kernels (and, under ``kda_proj``, the q, k, v prologue's pair, ops/short_conv.py, which is
+    # read as part of ``kda``), which the benchmark reads by their own helpers (layer_metrics/_named_scopes.py,
     # _attn_kinds.py, _ssm_scan.py, _blockdiff.py, _kda.py) until its
     # closed vocabulary takes them in; the head's kernel (ops/fused_ce.py) runs
     # under ``lm_head_ce`` and the expert layer's two token-side kernels
@@ -310,7 +311,7 @@ def test_named_scopes_in_the_program_are_the_vocabulary():
         "attn_window", "attn_global", "attn_gate"} | {
         "ssm", "ssm_proj", "ssm_conv", "gmu", "attn_diff", "ssm_scan_fwd", "ssm_scan_bwd"} | {
         "ce_softmax_grad", "token_sum", "token_dot"} | {"attn_blockdiff", "bd_rows"} | {
-        "kda", "kda_proj", "kda_core", "kda_out", "kda_fwd", "kda_bwd"}
+        "kda", "kda_proj", "kda_core", "kda_out", "kda_fwd", "kda_bwd", "short_conv_fwd", "short_conv_bwd"}
 
 
 # -- the host's turns ---------------------------------------------------------------

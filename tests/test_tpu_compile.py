@@ -636,6 +636,89 @@ def test_kda_kernels_compile_for_v5e_at_the_cells_call(v5e, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * Bt * S * H * d * 2   # a dozen operands' worth
 
 
+@pytest.mark.parametrize("heads", [32, None])
+def test_short_conv_kernels_compile_for_v5e_at_the_cells_call(v5e, monkeypatch, no_mesh_left_behind, heads):
+    """The same cell's q, k, v prologue (ops/short_conv.py): a bfloat16 projection of
+    2 x 8,192 rows of 4,096 channels and four taps, with the norm over 32 heads (q, k)
+    and without (v), forward and backward: Mosaic takes both kernels at the module's
+    block (a 16-row view of a packed operand before and after a block, the rows
+    shifted through sublane rotations, the taps' sums resident over the row axis),
+    the differentiated call is one backward kernel (the forward is not run again for
+    residuals: they are the operands), and no float32 ``[B, S, D]`` array is left in
+    the program: the largest temporary is the bfloat16 cotangent."""
+    from mlx_cuda_distributed_pretraining_tpu.ops import short_conv as sc
+
+    monkeypatch.setattr(sc, "_interpret", lambda: False)
+    Bt, S, D = 2, 8192, 4096
+    ops = (_sds((Bt, S, D), jnp.bfloat16, v5e), _sds((D, 4), jnp.bfloat16, v5e))
+    before = sc.plan_counts()
+    call = lambda a, w: sc.short_conv(a, w, heads=heads, scale=128 ** -0.5 if heads else 1.0, backend="kernel")
+    forward = jax.jit(call).lower(*ops).compile()
+    compiled = jax.jit(jax.grad(lambda a, w: call(a, w).astype(jnp.float32).sum(), argnums=(0, 1))).lower(*ops).compile()
+    assert {k: n - before[k] for k, n in sc.plan_counts().items()} == {"conv_kernel": 2, "conv_xla": 0}
+    calls = lambda c: [line for line in c.as_text().split("\n") if "tpu_custom_call" in line]
+    assert len(calls(forward)) == 1 and "short_conv_fwd" in calls(forward)[0]
+    assert len(calls(compiled)) == 1 and "short_conv_bwd" in calls(compiled)[0]
+    assert f"f32[{Bt},{S},{D}]" not in forward.as_text() + compiled.as_text()
+    assert forward.memory_analysis().temp_size_in_bytes < 2**20
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * Bt * S * D * 2
+
+
+def test_a_stack_of_kda_layers_lowers_each_prologue_body_once(v5e, monkeypatch, no_mesh_left_behind):
+    """What a run of the cell pays on the host before its first step (PERF.md section 6, PR 53):
+    the differentiated, checkpointed stack of three KDA layers at tiny widths (heads of 128)
+    calls the prologue's kernels from 27 sites (q, k, v; forward, recomputed, backward), and
+    each distinct body goes through Mosaic's lowering ONCE: two forward bodies (with the norm:
+    q and k, a scalar operand apart; without: v) and two backward. The module's text may name
+    a forward body's jitted call twice (``jax.checkpoint`` re-makes a jitted call's jaxpr for
+    its recomputation, as it does ``kda_fwd``'s; the copy's kernel is served by JAX's
+    per-equation lowering cache), never once a site."""
+    import json
+    import re
+
+    from benchmark import run as harness
+    from benchmark.reference import kimi_linear as ref
+    from benchmark.traffic_kinds import train_job_kda as kind
+    from jax._src.pallas import pallas_call as pallas_call_lib
+    from mlx_cuda_distributed_pretraining_tpu.config import Config
+    from mlx_cuda_distributed_pretraining_tpu.models import kimi_linear as kl
+    from mlx_cuda_distributed_pretraining_tpu.ops import kda
+    from mlx_cuda_distributed_pretraining_tpu.ops import short_conv as sc
+
+    monkeypatch.setattr(sc, "_interpret", lambda: False)
+    monkeypatch.setattr(kda, "_interpret", lambda: False)
+    monkeypatch.setenv("KDA_BACKEND", "kernel")
+    lowered = collections.Counter()
+    mosaic = pallas_call_lib.mosaic_tpu_backend
+    rule = mosaic.pallas_call_tpu_lowering_rule
+    monkeypatch.setattr(mosaic, "pallas_call_tpu_lowering_rule",
+                        lambda ctx, *a, **kw: (lowered.update([kw["name"]]), rule(ctx, *a, **kw))[1])
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    load = lambda path: json.load(open(os.path.join(root, path)))
+    cfg = harness.merge_into(load("benchmark/configs/kimi-linear-48b-a3b-ep16.json"), load("benchmark/rehearse_kda.json")["config"])
+    cfg = dict(cfg, num_hidden_layers=4, hidden_size=128, linear_attn_config=dict(
+        cfg["linear_attn_config"], head_dim=128, num_heads=2, kda_layers=[1, 2, 3], full_attn_layers=[4]))
+    model = kind.arch.MODEL_SECTIONS["kimi_linear"](cfg, {"attention_type": "simple"})
+    args = kl.KimiLinearArgs.from_config(Config.from_dict({"name": "t", "model": model}).model, cfg["vocab_size"])
+    Bt, S = 2, 256
+    params = jax.tree_util.tree_map(lambda x: _sds(x.shape, x.dtype, v5e), jax.eval_shape(lambda: ref.init_params(7, cfg)))
+    batch = {"inputs": _sds((Bt, S), jnp.int32, v5e), "targets": _sds((Bt, S), jnp.int32, v5e),
+             "mask": _sds((Bt, S), jnp.float32, v5e)}
+    before = kl.kda_plan_counts()
+    text = jax.jit(jax.value_and_grad(lambda p, b: kl.loss_fn(p, b, args, remat="full"), has_aux=True)).lower(
+        params, batch).as_text()
+    traced = {k: n - before.get(k, 0) for k, n in kl.kda_plan_counts().items()}
+    assert traced["kernel"] == 3 and traced["conv_kernel"] == 9 and traced["conv_xla"] == traced["xla"] == 0
+    assert {k: lowered[k] for k in ("short_conv_fwd", "short_conv_bwd")} == {"short_conv_fwd": 2, "short_conv_bwd": 2}
+    assert lowered["kda_fwd"] == lowered["kda_bwd"] == 1          # the yardstick: the cores' pair
+    functions = re.split(r"\n  func\.func ", text)
+    holding = lambda name: [f.split("(", 1)[0].split()[-1].lstrip("@") for f in functions if f'kernel_name = "{name}"' in f]
+    sites = lambda name: sum(len(re.findall(r"call @%s\(" % re.escape(f), text)) for f in holding(name))
+    assert sites("short_conv_fwd") == 18 and sites("short_conv_bwd") == 9
+    assert len(holding("short_conv_fwd")) <= 4 and len(holding("short_conv_bwd")) == 2
+
+
 def test_differential_attention_cores_compile_for_v5e_at_the_cells_length(v5e, compiled_kernels):
     """The same cell's attention (models/sambay.py): 40 + 40 stacked query heads of
     64 over 20 stacked key heads and values of 128, one row of 16,384, under the
