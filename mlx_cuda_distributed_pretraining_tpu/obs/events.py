@@ -8,10 +8,16 @@ Event types written by the trainer / supervisor:
 
   run_start        fresh run began (config name, total_steps, n_params)
   resume           run resumed from a checkpoint (tag, step)
-  compile          first dispatch finished compiling (seconds)
+  compile          first dispatch returned: its seconds and step, and the
+                   run's set-up from the process's start (process_start_t,
+                   phases, stages, step_fun, step_stages, functions, misses:
+                   train/trainer.py _setup_record, obs/compiles.py)
   step_window      one logging window (step, steps, toks, loss, tok_s,
                    mfu, goodput breakdown; a run's first one also
                    flash_plan, the flash kernel calls traced, by path;
+                   xla_compiles, xla_compile_s and, where a program was
+                   built since the last window or the compile event,
+                   xla_compiled: function name -> hit | miss | null;
                    its steps' records in summary, the slowest whole, and
                    the host counters' differences: obs/steprecord.py,
                    obs/hoststats.py), written when its last step closes

@@ -11,6 +11,8 @@ other tenants or the device's allocator. Three reads, by what they cost:
   context switches);
 * :func:`gc_totals`: collections and the seconds inside them, by
   generation, counted by a ``gc.callbacks`` listener registered on import;
+* :func:`process_start_t`, once a run: two ``/proc`` files, for the ``compile``
+  event's account of set-up;
 * :func:`machine_totals` and :func:`hbm_totals`, every window: ``/proc/stat``'s
   first line, ``/proc/pressure/cpu`` where the kernel has it, and
   ``memory_stats()`` of every local device, which asks the runtime and
@@ -120,6 +122,21 @@ def machine_totals() -> Dict[str, Optional[float]]:
     if some.startswith("some ") and total.isdigit():
         out["psi_cpu_us"] = int(total)
     return out
+
+
+def process_start_t() -> Optional[float]:
+    """When this process was created, on ``time.time()``'s clock: the boot
+    time of ``/proc/stat`` plus the start ticks of ``/proc/self/stat``, so that
+    the interpreter's start and the imports count as set-up. None where
+    ``/proc`` has neither."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open(_STAT) as f:
+            btime = next(int(line.split()[1]) for line in f if line.startswith("btime"))
+    except (OSError, ValueError, StopIteration, IndexError):
+        return None
+    return btime + ticks / _TICK
 
 
 # -- the device's allocator ----------------------------------------------------------

@@ -23,6 +23,7 @@ on disk for offline analysis.py.prof runs.
 
 from __future__ import annotations
 
+import time
 from typing import Any, Callable, Dict, Optional
 
 from .profile_report import generate_report, write_summary
@@ -52,6 +53,7 @@ class ProfileCapture:
                  report: bool = True, top_k: int = 12):
         self.dump_dir = dump_dir
         self.active = False
+        self.started_t = 0.0  # time.time() of the running session's start
         self._log = log or (lambda msg: None)
         self._sync = sync
         self._analytic_fn = analytic_fn
@@ -73,6 +75,7 @@ class ProfileCapture:
             self._log(f"profiler: unavailable ({e})")
             return False
         self.active = True
+        self.started_t = time.time()
         at = f" at step {step}" if step is not None else ""
         self._log(f"profiler: trace started{at}")
         return True

@@ -123,10 +123,12 @@ class Span:
 class Phase:
     """A live host phase (see :meth:`Tracer.phase`); ``seconds`` holds its
     duration once the ``with`` block has ended, so a ledger booked from it
-    carries the number the span carries."""
+    carries the number the span carries, and ``t`` its start on the tracer's
+    wall-anchored clock (``time.time()``'s, the one events and JAX's compile
+    spans are stamped on)."""
 
     __slots__ = ("_tracer", "_name", "_trace_id", "_args", "_ann", "_t0",
-                 "seconds")
+                 "seconds", "t")
 
     def __init__(self, tracer: "Tracer", name: str,
                  trace_id: Optional[str], args: Dict[str, Any]):
@@ -136,6 +138,7 @@ class Phase:
         self._args = args
         self._ann = TraceAnnotation(name, **args)
         self.seconds = 0.0
+        self.t = 0.0
 
     def __enter__(self) -> "Phase":
         self._ann.__enter__()
@@ -146,6 +149,7 @@ class Phase:
         self.seconds = time.perf_counter() - self._t0
         self._ann.__exit__(*exc)
         tr = self._tracer
+        self.t = tr.wall(self._t0)
         if tr.enabled and (self._trace_id is None
                            or sampled(self._trace_id, tr.sample)):
             tr._record(self._name, self._t0, self.seconds, self._trace_id,
@@ -209,8 +213,12 @@ class Tracer:
 
     # -- recording ---------------------------------------------------------
 
+    def wall(self, mono: float) -> float:
+        """A ``perf_counter`` reading on the wall-anchored clock, in seconds."""
+        return self._wall0 + (mono - self._mono0)
+
     def _wall_us(self, mono: float) -> int:
-        return int((self._wall0 + (mono - self._mono0)) * 1e6)
+        return int(self.wall(mono) * 1e6)
 
     def _push(self, ev: Dict[str, Any]) -> None:
         with self._lock:
@@ -259,18 +267,22 @@ class Tracer:
 
     def complete(self, name: str, dur_s: float,
                  trace_id: Optional[str] = None,
-                 end_mono: Optional[float] = None, **args: Any) -> None:
+                 end_mono: Optional[float] = None,
+                 end_wall: Optional[float] = None, **args: Any) -> None:
         """Record an already-measured span after the fact.
 
         Used where a duration has just been computed for another ledger
         (e.g. the trainer's goodput components) so the span carries the
         *identical* number.  The span is placed ending at ``end_mono``
-        (default: now) and extending ``dur_s`` back.
+        (default: now) and extending ``dur_s`` back; ``end_wall`` places one
+        that was stamped on ``time.time()`` (JAX's compile spans).
         """
         if not self.enabled:
             return
         if trace_id is not None and not sampled(trace_id, self.sample):
             return
+        if end_wall is not None:
+            end_mono = self._mono0 + (end_wall - self._wall0)
         end = time.perf_counter() if end_mono is None else end_mono
         self._record(name, end - dur_s, dur_s, trace_id, args or None)
 

@@ -16,11 +16,12 @@ per-host data sharding comes from ``jax.process_index()``.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import math
 import os
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,7 +54,7 @@ from ..obs.flops import (GoodputLedger, matmul_params, model_flops_per_token,
 from ..obs.flops import mfu as compute_mfu
 from ..obs.metrics import MetricsRegistry
 from ..obs.steprecord import ANNOTATED, PHASES, StepRecords
-from ..obs.trace import Tracer
+from ..obs.trace import Phase, Tracer
 from ..optim import build_optimizer, build_schedule, schedule_value
 from ..parallel import build_mesh
 from ..tokenizer import TokenizerManager
@@ -120,490 +121,515 @@ class Trainer:
         cfg = self.config
         self.for_training = for_training
         self.runs_root = runs_root
-
-        # -- system: XLA flag set, seeds, mesh (reference setup_system
-        # :964-1016). Flags FIRST: they are read once at backend init, and
-        # PRNGKey below initializes the backend.
-        from ..parallel import xla_flags as xla_flags_mod
-
-        self.xla_stamp = xla_flags_mod.apply_flag_set(
-            cfg.system.xla_flag_set, extra=cfg.system.xla_extra_flags)
-        self.rng = jax.random.PRNGKey(cfg.system.seed)
-        np.random.seed(cfg.system.seed)
-        from ..parallel.context import set_mesh
-
-        self.mesh = None
-        explicit_mesh = bool(getattr(cfg.system, "mesh", None)) or cfg.system.model_parallel
-        if explicit_mesh:
-            self.mesh = build_mesh(cfg.system)
-        elif jax.device_count() > 1 and for_training:
-            # Implicit pure-DP mesh over all devices — but only when the
-            # global batch divides evenly; otherwise stay single-program on
-            # device 0 (the reference likewise falls back to one device when
-            # distribution isn't configured: core/training.py:964-1016).
-            if cfg.training.batch_size % jax.device_count() == 0:
-                self.mesh = build_mesh(cfg.system)
-        set_mesh(self.mesh)
-
-        # -- run dir ---------------------------------------------------------
-        resume = cfg.resume is not None and bool(cfg.resume.checkpoint)
-        run_dir = os.path.join(runs_root, cfg.name)
-        # Destructive setup (overwrite rmtree) happens exactly once: on the
-        # chief, in the fleet's FIRST generation. Supervisor restarts
-        # (ELASTIC_GENERATION > 1) continue into the existing dir — wiping
-        # it again would destroy events.jsonl and race against peers. The
-        # barrier orders the chief's rmtree+mkdir before any peer writes
-        # (heartbeats, tokenizer cache) land in the same tree.
-        from ..parallel.elastic import ELASTIC_GENERATION_ENV, process_barrier
-
-        elastic_gen = int(os.environ.get(ELASTIC_GENERATION_ENV) or 1)
-        if (for_training and not resume and elastic_gen <= 1
-                and jax.process_index() == 0):
-            run_dir = CheckpointManager.setup_run_directory(runs_root, cfg.name, cfg.overwrite)
-        if for_training:
-            process_barrier("run_dir_setup")
-        self.run_dir = run_dir
-        os.makedirs(run_dir, exist_ok=True)
-        # Telemetry substrate (obs/metrics.py): one registry per Trainer —
-        # subsystems record into it, Prometheus/stats export read from it.
-        self.metrics = MetricsRegistry()
-        self.checkpoints = CheckpointManager(
-            run_dir, keep_last=cfg.logging.keep_last,
-            keep_every=cfg.logging.keep_every, metrics=self.metrics)
-        is_chief = jax.process_index() == 0
-        self.logger = Logger(run_dir, cfg, quiet=quiet or not is_chief, write_files=is_chief)
-        # Integrity events (quarantine, GC, ledger rebuild, degraded
-        # optimizer resume) surface in log.txt, not just stderr.
-        self.checkpoints.notify = self.logger.log
-        # What this run actually executes on, in the first log line and the
-        # run_start event: system.device in the config is only a label, and
-        # a multi-chip or multi-host run that silently became one device
-        # must be readable from the log alone.
-        dev = jax.devices()[0]
-        self.device_stamp = {
-            "platform": dev.platform, "device_kind": dev.device_kind,
-            "n_chips": jax.device_count(), "n_processes": jax.process_count()}
-        self.logger.log(
-            f"device: platform={dev.platform} kind={dev.device_kind} "
-            f"count={jax.device_count()} processes={jax.process_count()}")
-        if self.xla_stamp["xla_backend"] != dev.platform:
-            self.logger.log(
-                f"WARNING: XLA flag set was resolved for backend "
-                f"{self.xla_stamp['xla_backend']!r} but the run is on "
-                f"{dev.platform!r} (parallel/xla_flags.py guess_backend)")
-        if self.xla_stamp["xla_flags"]:
-            applied = self.xla_stamp["xla_flags_applied"]
-            self.logger.log(
-                f"xla flag set {self.xla_stamp['xla_flag_set']!r} "
-                f"({self.xla_stamp['xla_backend']}): "
-                + ("applied" if applied
-                   else f"NOT applied — {self.xla_stamp.get('reason')}"))
-        if for_training and not resume and is_chief:
-            cfg.to_yaml(os.path.join(run_dir, "config.yaml"))
-
-        # -- tokenizer -------------------------------------------------------
-        self.tokenizer = TokenizerManager(cfg.data, run_dir=run_dir if for_training else None)
-
-        # -- model -----------------------------------------------------------
-        arch = resolve_architecture(cfg.model.architecture)
-        self.arch = arch
-        vocab_size = self.tokenizer.vocab_size
-        if getattr(cfg.data, "source", None) == "token_shards":
-            # Pre-tokenized binary shards: the shard index's vocab is
-            # authoritative (the tokenizer is only used for sampling).
-            idx_dir = getattr(cfg.data, "input_file", None) or (
-                getattr(cfg.data, "streaming", {}) or {}).get("shard_dir")
-            if idx_dir:
-                idx_path = os.path.join(idx_dir, "index.json")
-                if os.path.isfile(idx_path):
-                    with open(idx_path) as f:
-                        vocab_size = int(json.load(f).get("vocab_size", vocab_size))
-        args = arch.args_cls.from_config(cfg.model, vocab_size)
-        if arch.force_attention:
-            args = args.__class__(**{**args.__dict__, "attention_type": arch.force_attention})
-        self.model_args = args
-        self.rng, init_key = jax.random.split(self.rng)
-        params = arch.init_params(init_key, args)
-        self.n_params = llama_mod.num_params(params)
-        # Shapes are all the step builders need (and the LR finder's
-        # rebuild, later). The arrays themselves go into self.state below
-        # and are NOT kept here: under a mesh that would hold a whole
-        # unsharded copy on the first device beside its shard (seen on four
-        # v5e chips: 4.12 GB in use on device 0, 0.83 GB on the others).
-        self.params_like = jax.eval_shape(lambda: params)
-        self.logger.log_model_summary(self.n_params, args)
-
-        self.compute_dtype = jnp.bfloat16 if cfg.system.compute_dtype == "bfloat16" else jnp.float32
-        # model.remat_policy is the first-class knob (named policies over
-        # checkpoint_name-tagged sites); system.remat / the legacy
-        # gradient_checkpointing bool remain as fallbacks.
-        remat = cfg.model.remat_policy
-        if remat is None:
-            remat = cfg.system.remat
-        if remat is None and cfg.system.gradient_checkpointing:
-            remat = "full"
-        if remat == "none":
-            remat = None
-        self.remat = remat
-        self.remat_ratio = float(cfg.system.gradient_checkpointing_ratio)
-
-        ce_chunk = int(getattr(cfg.system, "fused_ce_chunk", -1))
-        if (ce_chunk == -1 and self.mesh is not None
-                and "sp" in self.mesh.axis_names and self.mesh.shape["sp"] > 1):
-            if self.mesh.shape.get("tp", 1) > 1:
-                # With BOTH sp and tp, the projection is vocab-sharded and
-                # the sequence is sharded: neither fused path applies; the
-                # unfused CE under GSPMD is already vocab-parallel.
-                ce_chunk = 0
-                self.logger.log(
-                    "fused CE auto-disabled on sp x tp mesh (vocab-sharded "
-                    "projection); explicit fused_ce_chunk > 0 is respected")
-            else:
-                # loss_fn routes to the shard_map sequence-sharded fused CE
-                # (ops/fused_ce.py::fused_cross_entropy_sp).
-                self.logger.log("fused CE: sequence-sharded path on sp mesh")
-
-        scan_layers = bool(getattr(cfg.system, "scan_layers", False))
-        # Manual fsdp gather/compute overlap (parallel/overlap.py). The
-        # knob only requests it; models/llama.py still gates on
-        # can_overlap(mesh, ...) so unsupported meshes fall back to GSPMD.
-        overlap = bool(getattr(cfg.system, "overlap_gather", False))
-        self.overlap_gather = overlap
-        z_loss_weight = float(cfg.training.hyperparameters.get("z_loss") or 0.0)
-
-        # MoE training steps carry routing stats (expert load, dropped
-        # selections) out through loss_fn's aux — models/moe.py tap. The
-        # pipeline loss threads the same stats through its tick carries
-        # (make_pipeline_loss with_moe_stats), so pp and non-pp runs report
-        # identical routing gauges.
-        # Every architecture's loss_fn takes the same keywords; one whose
-        # args say is_moe returns the stats under with_moe_stats.
-        self.moe_stats_experts = args.num_local_experts if args.is_moe else 0
-        _stats_kw = {"with_moe_stats": True} if self.moe_stats_experts else {}
-        _ov_kw = {"overlap": True} if overlap else {}
-
-        def loss_fn(params, batch):
-            return arch.loss_fn(
-                params, batch, args, compute_dtype=self.compute_dtype,
-                remat=self.remat, remat_ratio=self.remat_ratio,
-                ce_chunk=ce_chunk, scan_layers=scan_layers,
-                z_loss_weight=z_loss_weight, **_stats_kw, **_ov_kw,
-            )
-
-        # Validation excludes MoE router aux terms: val loss / ppl stay pure
-        # LM cross-entropy, comparable across dense and MoE runs.
-        def eval_loss_fn(params, batch):
-            return arch.loss_fn(
-                params, batch, args, compute_dtype=self.compute_dtype,
-                include_aux=False, ce_chunk=ce_chunk,
-                scan_layers=scan_layers,
-            )
-
-        self.loss_fn = loss_fn
-        self.eval_loss_fn = eval_loss_fn
-
-        # -- data ------------------------------------------------------------
-        self.data: Optional[DataManager] = None
-        if for_training:
-            self.data = build_data_manager(
-                cfg,
-                self.tokenizer,
-                batch_size=cfg.training.batch_size,
-                seq_len=cfg.data.max_context_size,
-                seed=cfg.system.seed,
-                process_index=jax.process_index(),
-                process_count=jax.process_count(),
-            )
-            # A model trained by diffusion over blocks says how its batches are
-            # noised (models/sdar.py); the loader's own are the clean rows.
-            diffusion = getattr(args, "diffusion", None)
-            if diffusion:
-                from ..data.block_diffusion import BlockDiffusionBatches
-
-                self.data = BlockDiffusionBatches(self.data, cfg.system.seed,
-                                                  process_index=jax.process_index(), **diffusion)
-
-        # -- steps / optimizer (reference setup_training :1093-1133) --------
-        self.total_steps = 0
-        if for_training:
-            if cfg.training.iters:
-                self.total_steps = cfg.training.iters
-            elif hasattr(self.data, "batches_per_epoch"):
-                epochs = cfg.training.epochs or 1
-                self.total_steps = epochs * self.data.batches_per_epoch
-            else:
-                raise ValueError("streaming data sources require training.iters")
-        self.schedule = build_schedule(cfg.training, max(self.total_steps, 1))
-        self.optimizer = build_optimizer(cfg.training, max(self.total_steps, 1), schedule=self.schedule)
-        self.accum_steps = cfg.training.gradient_accumulation_steps
-
-        # Pipeline parallelism: a pp>1 mesh axis switches the whole step to
-        # the GPipe schedule (parallel/pipeline.py) over stacked layer params.
-        self.pipeline = bool(
-            self.mesh is not None
-            and "pp" in self.mesh.axis_names
-            and self.mesh.shape["pp"] > 1
-        )
-        self.pipeline_interleave = 1
-        self.pipeline_compute_skip = True
-        if self.pipeline:
-            from ..parallel.pipeline import (
-                make_pipeline_loss,
-                make_pipeline_train_step,
-                stack_layers,
-            )
-
-            pp = self.mesh.shape["pp"]
-            self.pipeline_interleave = max(1, int(
-                getattr(cfg.system, "pipeline_interleave", 1) or 1))
-            self.pipeline_compute_skip = bool(
-                getattr(cfg.system, "pipeline_compute_skip", True))
-            self.microbatches = int(cfg.system.pipeline_microbatches or 2 * pp)
-            # Pipeline microbatching IS gradient accumulation: fold the
-            # configured accum factor in so the effective batch semantics
-            # match the same config on a non-pp mesh.
-            if self.accum_steps > 1:
-                self.microbatches = max(self.microbatches, self.accum_steps)
-                self.logger.log(
-                    f"pipeline: gradient_accumulation_steps={self.accum_steps} folded "
-                    f"into {self.microbatches} microbatches"
-                )
-            if cfg.training.batch_size % self.microbatches != 0:
-                raise ValueError(
-                    f"batch_size {cfg.training.batch_size} must be divisible by "
-                    f"pipeline_microbatches {self.microbatches}"
-                )
-            if self.model_args.num_layers % (pp * self.pipeline_interleave) != 0:
-                raise ValueError(
-                    f"num_layers {self.model_args.num_layers} must be divisible "
-                    f"by pp*pipeline_interleave="
-                    f"{pp}*{self.pipeline_interleave}"
-                )
-            self.train_step, self.state_shardings = make_pipeline_train_step(
-                args, self.optimizer, self.mesh, self.microbatches,
-                compute_dtype=self.compute_dtype, remat=self.remat,
-                zero_level=cfg.system.zero_optimization_level,
-                params_like=self.params_like,
-                log_grad_norm=cfg.logging.log_gradient_norm,
-                ce_chunk=ce_chunk, z_loss_weight=z_loss_weight,
-                interleave=self.pipeline_interleave,
-                compute_skip=self.pipeline_compute_skip,
-                moe_stats_experts=self.moe_stats_experts,
-            )
-            self.eval_step = jax.jit(make_pipeline_loss(
-                args, self.mesh, self.microbatches,
-                compute_dtype=self.compute_dtype, include_aux=False,
-                ce_chunk=ce_chunk, interleave=self.pipeline_interleave,
-                compute_skip=self.pipeline_compute_skip,
-            ))
-            self.state = init_train_state(
-                stack_layers(params, interleave=self.pipeline_interleave),
-                self.optimizer)
-            self.state = _put_tree(self.state, self.state_shardings)
-        else:
-            self.train_step, self.state_shardings = make_train_step(
-                self.loss_fn, self.optimizer,
-                accum_steps=self.accum_steps,
-                mesh=self.mesh,
-                zero_level=cfg.system.zero_optimization_level,
-                log_grad_norm=cfg.logging.log_gradient_norm,
-                params_like=self.params_like,
-                moe_stats_experts=self.moe_stats_experts,
-            )
-            self.eval_step = make_eval_step(self.eval_loss_fn, self.mesh, self.state_shardings)
-
-            self.state = init_train_state(params, self.optimizer)
-            if self.mesh is not None and self.state_shardings is not None:
-                self.state = _put_tree(self.state, self.state_shardings)
-        del params
-
-        # optional live stats publishing (obs/stats_server.py hub)
-        self.stats_client = None
-        if for_training and cfg.logging.stats_url:
-            from ..obs.stats_client import StatsClient
-
-            self.stats_client = StatsClient(
-                cfg.logging.stats_url,
-                worker_id=f"{cfg.name}-p{jax.process_index()}",
-            ).start()
-            self.stats_client.register({"devices": jax.local_device_count()})
-
-        self.early_stopping = EarlyStoppingMonitor.from_config(cfg.training)
-        self.total_tokens = 0
-        self.start_step = 0
-        self.val_history: Dict[str, list] = {"steps": [], "losses": []}
-        # Created by train() right before the step loop; checkpoints read
-        # the consumed loader position through it (see _data_state).
-        self.prefetcher: Optional[DevicePrefetcher] = None
-
-        # -- telemetry (obs/): FLOPs model, goodput ledger, event log -------
-        # MFU accounting: analytic FLOPs/token from the model config + exact
-        # param count, peak from the chip's device_kind (None on CPU — log
-        # lines then report mfu=unknown; an unlisted accelerator raises).
-        self.flops_per_token = (
-            arch.flops_per_token(args, cfg.data.max_context_size)
-            if arch.flops_per_token is not None else model_flops_per_token(
-                cfg.model, self.n_params, cfg.data.max_context_size,
-                vocab_size=self.model_args.vocab_size))
-        self.peak_flops = peak_flops_per_chip()
-        self.goodput = GoodputLedger()
-        # Span tracer (obs/trace.py): mirrors every goodput booking as a
-        # chrome-trace span carrying the SAME duration, so per-window span
-        # sums reconcile with the ledger by construction. Off by default;
-        # logging.trace.enabled turns it on for the whole run, SIGUSR2
-        # opens an on-demand capture window mid-run.
+        # Span tracer (obs/trace.py), first: the constructor's sections are its
+        # phases. It mirrors every goodput booking as a chrome-trace span
+        # carrying the SAME duration, so per-window span sums reconcile with the
+        # ledger by construction. Off by default; logging.trace.enabled turns
+        # it on for the whole run, SIGUSR2 opens an on-demand capture window
+        # mid-run. Named for its process below, once the flags are applied:
+        # asking for the process index starts the backend.
         tcfg = dict(cfg.logging.trace or {})
         self.tracer = Tracer(
-            f"trainer-p{jax.process_index()}",
+            "trainer",
             capacity=int(tcfg.get("capacity", 65536)),
             sample=float(tcfg.get("sample", 1.0)),
             enabled=bool(tcfg.get("enabled", False)))
-        self._trace_capture_steps = int(tcfg.get("capture_steps", 20))
-        self._trace_request = 0   # bumped by SIGUSR2
-        self._trace_until = 0     # on-demand window end step (exclusive)
-        self._trace_owns_prof = False
-        self._trace_prev_enabled = self.tracer.enabled
-        # Single owner of jax.profiler start/stop (obs/profiler.py): the
-        # profile window, SIGUSR2 capture, and end-of-run finally all go
-        # through it, and every stop runs the graftprof attribution over
-        # the fresh dump (logging.profile_report.enabled gates it).
-        from ..obs.profiler import ProfileCapture
+        # Set-up's phases (name, start on time.time(), seconds), ring or no
+        # ring: the ``compile`` event carries them (_setup_record).
+        self._setup_phases: List[Tuple[str, float, float]] = []
+        # The newest of obs/compiles.py's spans this trainer has written out
+        # (_note_compiles): the ring and xla_compiled take what closed since.
+        self._spans_seen_t = time.time()
 
-        self.profiler = ProfileCapture(
-            os.path.join(run_dir, "profile"),
-            log=self.logger.log,
-            sync=lambda: jax.block_until_ready(self.state["step"]),
-            analytic_fn=self._prof_analytic,
-            summary_path=os.path.join(run_dir, "prof_summary.json"),
-            report=cfg.logging.profile_report_enabled,
-            top_k=cfg.logging.profile_report_top_k)
-        self._compiled = False  # first dispatch books into compile_s
-        # obs/compiles.py totals at the last window's close: the difference
-        # rides each step_window event as xla_compiles / xla_compile_s.
-        self._compiles_seen = compiles.totals()
-        self._side_s = 0.0  # seconds a capture's start or stop took inside the open step
-        # Which path the step's flash kernels, forward and backward, were traced to
-        # (ops/flash_attention.py flash_plan), and whether its fused CE computes
-        # the head's gradients in the forward walk (ops/fused_ce.py), and how many
-        # expert layers it dispatches and combines by gathers (models/moe.py), and
-        # how many gmm and tgmm calls keep an expert's block in VMEM, at which
-        # column block (ops/grouped_matmul.py gmm_plan): the tallies since here,
-        # logged after the first compile and carried by the first step_window event.
-        self._plan_tallies = {**_PLAN_TALLIES, **(arch.plans or {})}
-        self._plans_seen = {name: counts() for name, (_, counts) in self._plan_tallies.items()}
-        self._plans: Optional[Dict[str, Dict[str, int]]] = None
-        self._metrics_server = None
-        # events.jsonl is the durable telemetry source: replay it FIRST so
-        # counters survive crash-restarts, then open for append. Chief only
-        # (one file per run; non-chief processes keep a local registry).
-        self.events: Optional[EventLog] = None
-        self._hb_path: Optional[str] = None
-        if for_training and is_chief:
-            replayed = replay_into(self.metrics, events_path(run_dir))
-            if replayed:
+        with self._setup_phase("init.system"):
+            # -- system: XLA flag set, seeds, mesh (reference setup_system
+            # :964-1016). Flags FIRST: they are read once at backend init, and
+            # PRNGKey below initializes the backend.
+            from ..parallel import xla_flags as xla_flags_mod
+
+            self.xla_stamp = xla_flags_mod.apply_flag_set(
+                cfg.system.xla_flag_set, extra=cfg.system.xla_extra_flags)
+            self.rng = jax.random.PRNGKey(cfg.system.seed)
+            np.random.seed(cfg.system.seed)
+            self.tracer.service = f"trainer-p{jax.process_index()}"
+            from ..parallel.context import set_mesh
+
+            self.mesh = None
+            explicit_mesh = bool(getattr(cfg.system, "mesh", None)) or cfg.system.model_parallel
+            if explicit_mesh:
+                self.mesh = build_mesh(cfg.system)
+            elif jax.device_count() > 1 and for_training:
+                # Implicit pure-DP mesh over all devices — but only when the
+                # global batch divides evenly; otherwise stay single-program on
+                # device 0 (the reference likewise falls back to one device when
+                # distribution isn't configured: core/training.py:964-1016).
+                if cfg.training.batch_size % jax.device_count() == 0:
+                    self.mesh = build_mesh(cfg.system)
+            set_mesh(self.mesh)
+
+            # -- run dir ---------------------------------------------------------
+            resume = cfg.resume is not None and bool(cfg.resume.checkpoint)
+            run_dir = os.path.join(runs_root, cfg.name)
+            # Destructive setup (overwrite rmtree) happens exactly once: on the
+            # chief, in the fleet's FIRST generation. Supervisor restarts
+            # (ELASTIC_GENERATION > 1) continue into the existing dir — wiping
+            # it again would destroy events.jsonl and race against peers. The
+            # barrier orders the chief's rmtree+mkdir before any peer writes
+            # (heartbeats, tokenizer cache) land in the same tree.
+            from ..parallel.elastic import ELASTIC_GENERATION_ENV, process_barrier
+
+            elastic_gen = int(os.environ.get(ELASTIC_GENERATION_ENV) or 1)
+            if (for_training and not resume and elastic_gen <= 1
+                    and jax.process_index() == 0):
+                run_dir = CheckpointManager.setup_run_directory(runs_root, cfg.name, cfg.overwrite)
+            if for_training:
+                process_barrier("run_dir_setup")
+            self.run_dir = run_dir
+            os.makedirs(run_dir, exist_ok=True)
+            # Telemetry substrate (obs/metrics.py): one registry per Trainer —
+            # subsystems record into it, Prometheus/stats export read from it.
+            self.metrics = MetricsRegistry()
+            self.checkpoints = CheckpointManager(
+                run_dir, keep_last=cfg.logging.keep_last,
+                keep_every=cfg.logging.keep_every, metrics=self.metrics)
+            is_chief = jax.process_index() == 0
+            self.logger = Logger(run_dir, cfg, quiet=quiet or not is_chief, write_files=is_chief)
+            # Integrity events (quarantine, GC, ledger rebuild, degraded
+            # optimizer resume) surface in log.txt, not just stderr.
+            self.checkpoints.notify = self.logger.log
+            # What this run actually executes on, in the first log line and the
+            # run_start event: system.device in the config is only a label, and
+            # a multi-chip or multi-host run that silently became one device
+            # must be readable from the log alone.
+            dev = jax.devices()[0]
+            self.device_stamp = {
+                "platform": dev.platform, "device_kind": dev.device_kind,
+                "n_chips": jax.device_count(), "n_processes": jax.process_count()}
+            self.logger.log(
+                f"device: platform={dev.platform} kind={dev.device_kind} "
+                f"count={jax.device_count()} processes={jax.process_count()}")
+            if self.xla_stamp["xla_backend"] != dev.platform:
                 self.logger.log(
-                    f"telemetry: registry rebuilt from {replayed} events "
-                    f"in {events_path(run_dir)}")
-            self.events = EventLog(
-                events_path(run_dir),
-                max_bytes=self.config.logging.events_max_bytes)
-        if for_training:
-            # Per-host heartbeat: process 0 keeps the legacy heartbeat.json
-            # name; peers write heartbeat_p<idx>.json — so a supervisor
-            # watchdog can attribute a fleet stall to the host that
-            # stopped beating, not just "somewhere".
-            self._hb_path = heartbeat_path(run_dir, jax.process_index())
-        if for_training and jax.process_count() > 1:
-            # Generation-stamped membership record (parallel/elastic.py):
-            # every host agrees which epoch of the world it joined. The
-            # device barrier first makes sure no peer records into a run
-            # dir the chief is still (re)creating. Best-effort: telemetry
-            # must never kill training.
-            try:
-                from jax.experimental import multihost_utils
-
-                from ..parallel.elastic import record_membership
-
-                multihost_utils.sync_global_devices("elastic_membership")
-                rec = record_membership(run_dir, log=self.logger.log)
+                    f"WARNING: XLA flag set was resolved for backend "
+                    f"{self.xla_stamp['xla_backend']!r} but the run is on "
+                    f"{dev.platform!r} (parallel/xla_flags.py guess_backend)")
+            if self.xla_stamp["xla_flags"]:
+                applied = self.xla_stamp["xla_flags_applied"]
                 self.logger.log(
-                    f"elastic: recorded membership generation "
-                    f"{rec['generation']} as process "
-                    f"{jax.process_index()}/{jax.process_count()}")
-            except Exception as e:  # noqa: BLE001 - advisory record only
-                self.logger.log(
-                    f"WARNING: elastic membership record failed "
-                    f"({type(e).__name__}: {e}); continuing")
-        # Handles for the hot-path counters (idempotent re-declaration —
-        # replay_into already registered them).
-        self._m_steps = self.metrics.counter(
-            "train_steps_total", "optimizer steps completed over the run lifetime")
-        self._m_toks = self.metrics.counter(
-            "train_tokens_total", "non-pad target tokens trained on")
-        self._m_saves = self.metrics.counter(
-            "checkpoint_saves_total", "checkpoints written")
-        self._m_evals = self.metrics.counter(
-            "eval_runs_total", "validation passes")
-        self._m_goodput = self.metrics.counter(
-            "goodput_seconds_total", "wall-clock seconds by goodput component")
-        self._g_step = self.metrics.gauge("train_step", "current optimizer step")
-        self._g_loss = self.metrics.gauge("train_loss", "last logged train loss")
-        self._g_tok_s = self.metrics.gauge(
-            "train_tok_s", "global tokens/second over the last window")
-        self._g_mfu = self.metrics.gauge(
-            "train_mfu", "model FLOPs utilization over the last window")
-        # graftscope anomaly-rule inputs: the gradient norm was only ever
-        # a log-line field, and non-finite loss windows only a warning —
-        # export both so the grad-norm-blowup and NaN-sentinel rules have
-        # a scrapeable series.
-        self._g_grad_norm = self.metrics.gauge(
-            "train_grad_norm", "global gradient norm over the last window")
-        self._m_nonfinite = self.metrics.counter(
-            "train_nonfinite_total",
-            "logging windows whose loss came back NaN/Inf")
-        self._g_prof = {
-            "prof_compute_frac": self.metrics.gauge(
-                "prof_compute_frac",
-                "step time in compute ops (last graftprof attribution)"),
-            "prof_comm_frac": self.metrics.gauge(
-                "prof_comm_frac",
-                "step time in EXPOSED collectives (not hidden under "
-                "compute) from the last graftprof attribution"),
-            "prof_overlap_frac": self.metrics.gauge(
-                "prof_overlap_frac",
-                "fraction of collective time overlapped with compute "
-                "(1.0 = fully hidden) from the last graftprof attribution"),
-            "prof_idle_frac": self.metrics.gauge(
-                "prof_idle_frac",
-                "step time with no device op running (last graftprof "
-                "attribution)"),
-        }
-        if self.moe_stats_experts:
-            self._m_moe_dropped = self.metrics.counter(
-                "moe_dropped_tokens_total",
-                "expert selections dropped by capacity limits (0 when dropless)")
-            self._g_moe_load = self.metrics.gauge(
-                "moe_expert_load_frac",
-                "per-expert fraction of routed selections over the last window")
-            self._g_moe_entropy = self.metrics.gauge(
-                "moe_balance_entropy",
-                "normalized routing entropy over the last window (1.0 = uniform)")
-        self._g_bubble = None
-        self._bubble_frac = 0.0
-        if self.pipeline:
-            from ..obs.flops import pipeline_bubble_frac
+                    f"xla flag set {self.xla_stamp['xla_flag_set']!r} "
+                    f"({self.xla_stamp['xla_backend']}): "
+                    + ("applied" if applied
+                       else f"NOT applied — {self.xla_stamp.get('reason')}"))
+            if for_training and not resume and is_chief:
+                cfg.to_yaml(os.path.join(run_dir, "config.yaml"))
 
-            self._bubble_frac = pipeline_bubble_frac(
-                self.mesh.shape["pp"], self.microbatches,
-                self.pipeline_interleave)
-            self._g_bubble = self.metrics.gauge(
-                "pipeline_bubble_frac",
-                "fraction of pipeline schedule ticks spent in the "
-                "warmup/drain bubble (idle with compute-skip)")
-            self._g_bubble.set(self._bubble_frac)
+        with self._setup_phase("init.tokenizer"):
+            # -- tokenizer -------------------------------------------------------
+            self.tokenizer = TokenizerManager(cfg.data, run_dir=run_dir if for_training else None)
+
+        with self._setup_phase("init.model"):
+            # -- model -----------------------------------------------------------
+            arch = resolve_architecture(cfg.model.architecture)
+            self.arch = arch
+            vocab_size = self.tokenizer.vocab_size
+            if getattr(cfg.data, "source", None) == "token_shards":
+                # Pre-tokenized binary shards: the shard index's vocab is
+                # authoritative (the tokenizer is only used for sampling).
+                idx_dir = getattr(cfg.data, "input_file", None) or (
+                    getattr(cfg.data, "streaming", {}) or {}).get("shard_dir")
+                if idx_dir:
+                    idx_path = os.path.join(idx_dir, "index.json")
+                    if os.path.isfile(idx_path):
+                        with open(idx_path) as f:
+                            vocab_size = int(json.load(f).get("vocab_size", vocab_size))
+            args = arch.args_cls.from_config(cfg.model, vocab_size)
+            if arch.force_attention:
+                args = args.__class__(**{**args.__dict__, "attention_type": arch.force_attention})
+            self.model_args = args
+            self.rng, init_key = jax.random.split(self.rng)
+            # The host's seconds (traces, compiles or cache loads, dispatches);
+            # the device runs them behind the host and is waited for later.
+            with self._setup_phase("init.params"):
+                params = arch.init_params(init_key, args)
+            self.n_params = llama_mod.num_params(params)
+            # Shapes are all the step builders need (and the LR finder's
+            # rebuild, later). The arrays themselves go into self.state below
+            # and are NOT kept here: under a mesh that would hold a whole
+            # unsharded copy on the first device beside its shard (seen on four
+            # v5e chips: 4.12 GB in use on device 0, 0.83 GB on the others).
+            self.params_like = jax.eval_shape(lambda: params)
+            self.logger.log_model_summary(self.n_params, args)
+
+            self.compute_dtype = jnp.bfloat16 if cfg.system.compute_dtype == "bfloat16" else jnp.float32
+            # model.remat_policy is the first-class knob (named policies over
+            # checkpoint_name-tagged sites); system.remat / the legacy
+            # gradient_checkpointing bool remain as fallbacks.
+            remat = cfg.model.remat_policy
+            if remat is None:
+                remat = cfg.system.remat
+            if remat is None and cfg.system.gradient_checkpointing:
+                remat = "full"
+            if remat == "none":
+                remat = None
+            self.remat = remat
+            self.remat_ratio = float(cfg.system.gradient_checkpointing_ratio)
+
+            ce_chunk = int(getattr(cfg.system, "fused_ce_chunk", -1))
+            if (ce_chunk == -1 and self.mesh is not None
+                    and "sp" in self.mesh.axis_names and self.mesh.shape["sp"] > 1):
+                if self.mesh.shape.get("tp", 1) > 1:
+                    # With BOTH sp and tp, the projection is vocab-sharded and
+                    # the sequence is sharded: neither fused path applies; the
+                    # unfused CE under GSPMD is already vocab-parallel.
+                    ce_chunk = 0
+                    self.logger.log(
+                        "fused CE auto-disabled on sp x tp mesh (vocab-sharded "
+                        "projection); explicit fused_ce_chunk > 0 is respected")
+                else:
+                    # loss_fn routes to the shard_map sequence-sharded fused CE
+                    # (ops/fused_ce.py::fused_cross_entropy_sp).
+                    self.logger.log("fused CE: sequence-sharded path on sp mesh")
+
+            scan_layers = bool(getattr(cfg.system, "scan_layers", False))
+            # Manual fsdp gather/compute overlap (parallel/overlap.py). The
+            # knob only requests it; models/llama.py still gates on
+            # can_overlap(mesh, ...) so unsupported meshes fall back to GSPMD.
+            overlap = bool(getattr(cfg.system, "overlap_gather", False))
+            self.overlap_gather = overlap
+            z_loss_weight = float(cfg.training.hyperparameters.get("z_loss") or 0.0)
+
+            # MoE training steps carry routing stats (expert load, dropped
+            # selections) out through loss_fn's aux — models/moe.py tap. The
+            # pipeline loss threads the same stats through its tick carries
+            # (make_pipeline_loss with_moe_stats), so pp and non-pp runs report
+            # identical routing gauges.
+            # Every architecture's loss_fn takes the same keywords; one whose
+            # args say is_moe returns the stats under with_moe_stats.
+            self.moe_stats_experts = args.num_local_experts if args.is_moe else 0
+            _stats_kw = {"with_moe_stats": True} if self.moe_stats_experts else {}
+            _ov_kw = {"overlap": True} if overlap else {}
+
+            def loss_fn(params, batch):
+                return arch.loss_fn(
+                    params, batch, args, compute_dtype=self.compute_dtype,
+                    remat=self.remat, remat_ratio=self.remat_ratio,
+                    ce_chunk=ce_chunk, scan_layers=scan_layers,
+                    z_loss_weight=z_loss_weight, **_stats_kw, **_ov_kw,
+                )
+
+            # Validation excludes MoE router aux terms: val loss / ppl stay pure
+            # LM cross-entropy, comparable across dense and MoE runs.
+            def eval_loss_fn(params, batch):
+                return arch.loss_fn(
+                    params, batch, args, compute_dtype=self.compute_dtype,
+                    include_aux=False, ce_chunk=ce_chunk,
+                    scan_layers=scan_layers,
+                )
+
+            self.loss_fn = loss_fn
+            self.eval_loss_fn = eval_loss_fn
+
+        with self._setup_phase("init.data"):
+            # -- data ------------------------------------------------------------
+            self.data: Optional[DataManager] = None
+            if for_training:
+                self.data = build_data_manager(
+                    cfg,
+                    self.tokenizer,
+                    batch_size=cfg.training.batch_size,
+                    seq_len=cfg.data.max_context_size,
+                    seed=cfg.system.seed,
+                    process_index=jax.process_index(),
+                    process_count=jax.process_count(),
+                )
+                # A model trained by diffusion over blocks says how its batches are
+                # noised (models/sdar.py); the loader's own are the clean rows.
+                diffusion = getattr(args, "diffusion", None)
+                if diffusion:
+                    from ..data.block_diffusion import BlockDiffusionBatches
+
+                    self.data = BlockDiffusionBatches(self.data, cfg.system.seed,
+                                                      process_index=jax.process_index(), **diffusion)
+
+        with self._setup_phase("init.optimizer"):
+            # -- steps / optimizer (reference setup_training :1093-1133) --------
+            self.total_steps = 0
+            if for_training:
+                if cfg.training.iters:
+                    self.total_steps = cfg.training.iters
+                elif hasattr(self.data, "batches_per_epoch"):
+                    epochs = cfg.training.epochs or 1
+                    self.total_steps = epochs * self.data.batches_per_epoch
+                else:
+                    raise ValueError("streaming data sources require training.iters")
+            self.schedule = build_schedule(cfg.training, max(self.total_steps, 1))
+            self.optimizer = build_optimizer(cfg.training, max(self.total_steps, 1), schedule=self.schedule)
+            self.accum_steps = cfg.training.gradient_accumulation_steps
+
+            # Pipeline parallelism: a pp>1 mesh axis switches the whole step to
+            # the GPipe schedule (parallel/pipeline.py) over stacked layer params.
+            self.pipeline = bool(
+                self.mesh is not None
+                and "pp" in self.mesh.axis_names
+                and self.mesh.shape["pp"] > 1
+            )
+            self.pipeline_interleave = 1
+            self.pipeline_compute_skip = True
+            if self.pipeline:
+                from ..parallel.pipeline import (
+                    make_pipeline_loss,
+                    make_pipeline_train_step,
+                    stack_layers,
+                )
+
+                pp = self.mesh.shape["pp"]
+                self.pipeline_interleave = max(1, int(
+                    getattr(cfg.system, "pipeline_interleave", 1) or 1))
+                self.pipeline_compute_skip = bool(
+                    getattr(cfg.system, "pipeline_compute_skip", True))
+                self.microbatches = int(cfg.system.pipeline_microbatches or 2 * pp)
+                # Pipeline microbatching IS gradient accumulation: fold the
+                # configured accum factor in so the effective batch semantics
+                # match the same config on a non-pp mesh.
+                if self.accum_steps > 1:
+                    self.microbatches = max(self.microbatches, self.accum_steps)
+                    self.logger.log(
+                        f"pipeline: gradient_accumulation_steps={self.accum_steps} folded "
+                        f"into {self.microbatches} microbatches"
+                    )
+                if cfg.training.batch_size % self.microbatches != 0:
+                    raise ValueError(
+                        f"batch_size {cfg.training.batch_size} must be divisible by "
+                        f"pipeline_microbatches {self.microbatches}"
+                    )
+                if self.model_args.num_layers % (pp * self.pipeline_interleave) != 0:
+                    raise ValueError(
+                        f"num_layers {self.model_args.num_layers} must be divisible "
+                        f"by pp*pipeline_interleave="
+                        f"{pp}*{self.pipeline_interleave}"
+                    )
+                self.train_step, self.state_shardings = make_pipeline_train_step(
+                    args, self.optimizer, self.mesh, self.microbatches,
+                    compute_dtype=self.compute_dtype, remat=self.remat,
+                    zero_level=cfg.system.zero_optimization_level,
+                    params_like=self.params_like,
+                    log_grad_norm=cfg.logging.log_gradient_norm,
+                    ce_chunk=ce_chunk, z_loss_weight=z_loss_weight,
+                    interleave=self.pipeline_interleave,
+                    compute_skip=self.pipeline_compute_skip,
+                    moe_stats_experts=self.moe_stats_experts,
+                )
+                self.eval_step = jax.jit(make_pipeline_loss(
+                    args, self.mesh, self.microbatches,
+                    compute_dtype=self.compute_dtype, include_aux=False,
+                    ce_chunk=ce_chunk, interleave=self.pipeline_interleave,
+                    compute_skip=self.pipeline_compute_skip,
+                ))
+                self.state = init_train_state(
+                    stack_layers(params, interleave=self.pipeline_interleave),
+                    self.optimizer)
+                self.state = _put_tree(self.state, self.state_shardings)
+            else:
+                self.train_step, self.state_shardings = make_train_step(
+                    self.loss_fn, self.optimizer,
+                    accum_steps=self.accum_steps,
+                    mesh=self.mesh,
+                    zero_level=cfg.system.zero_optimization_level,
+                    log_grad_norm=cfg.logging.log_gradient_norm,
+                    params_like=self.params_like,
+                    moe_stats_experts=self.moe_stats_experts,
+                )
+                self.eval_step = make_eval_step(self.eval_loss_fn, self.mesh, self.state_shardings)
+
+                self.state = init_train_state(params, self.optimizer)
+                if self.mesh is not None and self.state_shardings is not None:
+                    self.state = _put_tree(self.state, self.state_shardings)
+            del params
+
+        with self._setup_phase("init.telemetry"):
+            # optional live stats publishing (obs/stats_server.py hub)
+            self.stats_client = None
+            if for_training and cfg.logging.stats_url:
+                from ..obs.stats_client import StatsClient
+
+                self.stats_client = StatsClient(
+                    cfg.logging.stats_url,
+                    worker_id=f"{cfg.name}-p{jax.process_index()}",
+                ).start()
+                self.stats_client.register({"devices": jax.local_device_count()})
+
+            self.early_stopping = EarlyStoppingMonitor.from_config(cfg.training)
+            self.total_tokens = 0
+            self.start_step = 0
+            self.val_history: Dict[str, list] = {"steps": [], "losses": []}
+            # Created by train() right before the step loop; checkpoints read
+            # the consumed loader position through it (see _data_state).
+            self.prefetcher: Optional[DevicePrefetcher] = None
+
+            # -- telemetry (obs/): FLOPs model, goodput ledger, event log -------
+            # MFU accounting: analytic FLOPs/token from the model config + exact
+            # param count, peak from the chip's device_kind (None on CPU — log
+            # lines then report mfu=unknown; an unlisted accelerator raises).
+            self.flops_per_token = (
+                arch.flops_per_token(args, cfg.data.max_context_size)
+                if arch.flops_per_token is not None else model_flops_per_token(
+                    cfg.model, self.n_params, cfg.data.max_context_size,
+                    vocab_size=self.model_args.vocab_size))
+            self.peak_flops = peak_flops_per_chip()
+            self.goodput = GoodputLedger()
+            self._trace_capture_steps = int(tcfg.get("capture_steps", 20))
+            self._trace_request = 0   # bumped by SIGUSR2
+            self._trace_until = 0     # on-demand window end step (exclusive)
+            self._trace_owns_prof = False
+            self._trace_prev_enabled = self.tracer.enabled
+            # Single owner of jax.profiler start/stop (obs/profiler.py): the
+            # profile window, SIGUSR2 capture, and end-of-run finally all go
+            # through it, and every stop runs the graftprof attribution over
+            # the fresh dump (logging.profile_report.enabled gates it).
+            from ..obs.profiler import ProfileCapture
+
+            self.profiler = ProfileCapture(
+                os.path.join(run_dir, "profile"),
+                log=self.logger.log,
+                sync=lambda: jax.block_until_ready(self.state["step"]),
+                analytic_fn=self._prof_analytic,
+                summary_path=os.path.join(run_dir, "prof_summary.json"),
+                report=cfg.logging.profile_report_enabled,
+                top_k=cfg.logging.profile_report_top_k)
+            self._compiled = False  # first dispatch books into compile_s
+            # obs/compiles.py's backend compiles, count and seconds, at the last
+            # window's close: the difference rides each step_window event as
+            # xla_compiles / xla_compile_s, and what was built since the newest
+            # span written out as xla_compiled, by name (the first dispatch
+            # writes out, so the first window names what the compile event does not).
+            self._compiles_seen = compiles.totals()
+            # The name JAX reports the jitted step under, read before anyone
+            # wraps it (the benchmark does).
+            self._step_fun = getattr(self.train_step, "__name__", None)
+            self._side_s = 0.0  # seconds a capture's start or stop took inside the open step
+            # Which path the step's flash kernels, forward and backward, were traced to
+            # (ops/flash_attention.py flash_plan), and whether its fused CE computes
+            # the head's gradients in the forward walk (ops/fused_ce.py), and how many
+            # expert layers it dispatches and combines by gathers (models/moe.py), and
+            # how many gmm and tgmm calls keep an expert's block in VMEM, at which
+            # column block (ops/grouped_matmul.py gmm_plan): the tallies since here,
+            # logged after the first compile and carried by the first step_window event.
+            self._plan_tallies = {**_PLAN_TALLIES, **(arch.plans or {})}
+            self._plans_seen = {name: counts() for name, (_, counts) in self._plan_tallies.items()}
+            self._plans: Optional[Dict[str, Dict[str, int]]] = None
+            self._metrics_server = None
+            # events.jsonl is the durable telemetry source: replay it FIRST so
+            # counters survive crash-restarts, then open for append. Chief only
+            # (one file per run; non-chief processes keep a local registry).
+            self.events: Optional[EventLog] = None
+            self._hb_path: Optional[str] = None
+            if for_training and is_chief:
+                replayed = replay_into(self.metrics, events_path(run_dir))
+                if replayed:
+                    self.logger.log(
+                        f"telemetry: registry rebuilt from {replayed} events "
+                        f"in {events_path(run_dir)}")
+                self.events = EventLog(
+                    events_path(run_dir),
+                    max_bytes=self.config.logging.events_max_bytes)
+            if for_training:
+                # Per-host heartbeat: process 0 keeps the legacy heartbeat.json
+                # name; peers write heartbeat_p<idx>.json — so a supervisor
+                # watchdog can attribute a fleet stall to the host that
+                # stopped beating, not just "somewhere".
+                self._hb_path = heartbeat_path(run_dir, jax.process_index())
+            if for_training and jax.process_count() > 1:
+                # Generation-stamped membership record (parallel/elastic.py):
+                # every host agrees which epoch of the world it joined. The
+                # device barrier first makes sure no peer records into a run
+                # dir the chief is still (re)creating. Best-effort: telemetry
+                # must never kill training.
+                try:
+                    from jax.experimental import multihost_utils
+
+                    from ..parallel.elastic import record_membership
+
+                    multihost_utils.sync_global_devices("elastic_membership")
+                    rec = record_membership(run_dir, log=self.logger.log)
+                    self.logger.log(
+                        f"elastic: recorded membership generation "
+                        f"{rec['generation']} as process "
+                        f"{jax.process_index()}/{jax.process_count()}")
+                except Exception as e:  # noqa: BLE001 - advisory record only
+                    self.logger.log(
+                        f"WARNING: elastic membership record failed "
+                        f"({type(e).__name__}: {e}); continuing")
+            # Handles for the hot-path counters (idempotent re-declaration —
+            # replay_into already registered them).
+            self._m_steps = self.metrics.counter(
+                "train_steps_total", "optimizer steps completed over the run lifetime")
+            self._m_toks = self.metrics.counter(
+                "train_tokens_total", "non-pad target tokens trained on")
+            self._m_saves = self.metrics.counter(
+                "checkpoint_saves_total", "checkpoints written")
+            self._m_evals = self.metrics.counter(
+                "eval_runs_total", "validation passes")
+            self._m_goodput = self.metrics.counter(
+                "goodput_seconds_total", "wall-clock seconds by goodput component")
+            self._g_step = self.metrics.gauge("train_step", "current optimizer step")
+            self._g_loss = self.metrics.gauge("train_loss", "last logged train loss")
+            self._g_tok_s = self.metrics.gauge(
+                "train_tok_s", "global tokens/second over the last window")
+            self._g_mfu = self.metrics.gauge(
+                "train_mfu", "model FLOPs utilization over the last window")
+            # graftscope anomaly-rule inputs: the gradient norm was only ever
+            # a log-line field, and non-finite loss windows only a warning —
+            # export both so the grad-norm-blowup and NaN-sentinel rules have
+            # a scrapeable series.
+            self._g_grad_norm = self.metrics.gauge(
+                "train_grad_norm", "global gradient norm over the last window")
+            self._m_nonfinite = self.metrics.counter(
+                "train_nonfinite_total",
+                "logging windows whose loss came back NaN/Inf")
+            self._g_prof = {
+                "prof_compute_frac": self.metrics.gauge(
+                    "prof_compute_frac",
+                    "step time in compute ops (last graftprof attribution)"),
+                "prof_comm_frac": self.metrics.gauge(
+                    "prof_comm_frac",
+                    "step time in EXPOSED collectives (not hidden under "
+                    "compute) from the last graftprof attribution"),
+                "prof_overlap_frac": self.metrics.gauge(
+                    "prof_overlap_frac",
+                    "fraction of collective time overlapped with compute "
+                    "(1.0 = fully hidden) from the last graftprof attribution"),
+                "prof_idle_frac": self.metrics.gauge(
+                    "prof_idle_frac",
+                    "step time with no device op running (last graftprof "
+                    "attribution)"),
+            }
+            if self.moe_stats_experts:
+                self._m_moe_dropped = self.metrics.counter(
+                    "moe_dropped_tokens_total",
+                    "expert selections dropped by capacity limits (0 when dropless)")
+                self._g_moe_load = self.metrics.gauge(
+                    "moe_expert_load_frac",
+                    "per-expert fraction of routed selections over the last window")
+                self._g_moe_entropy = self.metrics.gauge(
+                    "moe_balance_entropy",
+                    "normalized routing entropy over the last window (1.0 = uniform)")
+            self._g_bubble = None
+            self._bubble_frac = 0.0
+            if self.pipeline:
+                from ..obs.flops import pipeline_bubble_frac
+
+                self._bubble_frac = pipeline_bubble_frac(
+                    self.mesh.shape["pp"], self.microbatches,
+                    self.pipeline_interleave)
+                self._g_bubble = self.metrics.gauge(
+                    "pipeline_bubble_frac",
+                    "fraction of pipeline schedule ticks spent in the "
+                    "warmup/drain bubble (idle with compute-skip)")
+                self._g_bubble.set(self._bubble_frac)
 
         if resume and for_training:
-            self._resume()
+            with self._setup_phase("init.restore"):
+                self._resume()
 
     def _host_params(self):
         """Current params in the canonical list-of-layers layout (pipeline
@@ -660,18 +686,93 @@ class Trainer:
         if self.tracer.enabled:
             self.tracer.complete(name, dur_s, **args)
 
-    def _book_dispatch(self, seconds: float, step: int) -> None:
-        """Book one call into the jitted step: the run's first dispatch is
-        dominated by the XLA compile and goes to ``compile_s``, so that
-        steady-state ``dispatch_s`` stays meaningful (later compilations
-        show as ``xla_compiles`` on the window's event)."""
+    @contextlib.contextmanager
+    def _setup_phase(self, name: str) -> Iterator[Phase]:
+        """A phase of set-up: a ``tracer.phase`` (an annotation always, a ring
+        span with the ring on) whose name, start and seconds are also kept."""
+        with self.tracer.phase(name) as ph:
+            yield ph
+        self._setup_phases.append((name, ph.t, ph.seconds))
+
+    def _note_compiles(self) -> Dict[str, Optional[str]]:
+        """What JAX built since the last call (obs/compiles.py's outermost
+        spans): into the ring as completed ``compile.<stage>`` spans, so they
+        lie under the phase they fell in; those that ended inside this
+        trainer's profiler session onto its clock, zero-length as
+        ``train.step_record`` is; and, returned, the backend compiles by
+        function name (the 8 longest) with the cache's outcome."""
+        found = compiles.spans(self._spans_seen_t)
+        if not found:
+            return {}
+        self._spans_seen_t = max(s.end for s in found)
+        for s in found:
+            if self.tracer.enabled:
+                self.tracer.complete("compile." + s.stage, s.seconds, end_wall=s.end,
+                                     fun=s.fun, **({"cache": s.cache} if s.cache else {}))
+            if self.profiler.active and s.end >= self.profiler.started_t:
+                with jax.profiler.TraceAnnotation("xla_compile", fun=s.fun, stage=s.stage,
+                                                  seconds=round(s.seconds, 6)):
+                    pass
+        built = sorted((s for s in found if s.stage == "backend"), key=lambda s: -s.seconds)
+        return {s.fun: s.cache for s in built[:8]}
+
+    def _setup_record(self) -> Dict[str, Any]:
+        """The run's account of its set-up, from the process's start to the
+        first dispatch's end, as the ``compile`` event carries it."""
+        found = compiles.spans()
+        phases = []
+        for name, t, seconds in sorted(self._setup_phases, key=lambda ph: ph[1]):
+            ph = {"name": name, "t": round(t, 6), "seconds": round(seconds, 6)}
+            # what of the phase was a trace, a lowering, a compile or a cache load
+            ph.update({k: round(v, 6) for k, v in
+                       compiles.inside(t, t + seconds, found).items() if v >= 5e-4})
+            phases.append(ph)
+        def rounded(f: Optional[Dict[str, Any]]) -> Optional[Dict[str, Any]]:
+            return f and {k: round(v, 6) if isinstance(v, float) else v for k, v in f.items()}
+
+        funs = compiles.functions()
+        longest = sorted(funs, key=lambda n: -sum(funs[n][st + "_s"] for st in compiles.STAGES))
+        return {
+            "process_start_t": hoststats.process_start_t(),
+            "phases": phases,
+            "stages": compiles.stages(),
+            "step_fun": self._step_fun,
+            # this trainer's step: a process may have built another's before
+            "step_stages": rounded(compiles.functions(phases[0]["t"]).get(self._step_fun)),
+            "functions": {name: rounded(funs[name]) for name in longest[:8]},
+            "misses": [name for name in longest if funs[name]["cache"] == "miss"][:16],
+        }
+
+    def _book_dispatch(self, phase: Phase, step: int) -> None:
+        """Book one call into the jitted step (``phase``: its
+        ``train.dispatch``): the run's first dispatch is dominated by the
+        trace, the lowering and the XLA compile and goes to ``compile_s``, so
+        that steady-state ``dispatch_s`` stays meaningful (later compilations
+        show as ``xla_compiles`` and ``xla_compiled`` on the window's event).
+        Where it closes, set-up is over: the ``compile`` event says what it
+        was made of (``_setup_record``)."""
+        seconds = phase.seconds
         if self._compiled:
             self.goodput.add("dispatch_s", seconds)
             return
         self._compiled = True
         self.goodput.add("compile_s", seconds)
+        self._setup_phases.append(("train.dispatch", phase.t, seconds))
+        setup = self._setup_record()
+        self._note_compiles()
         if self.events is not None:
-            self.events.append("compile", seconds=round(seconds, 4), step=step)
+            self.events.append("compile", seconds=round(seconds, 4), step=step, **setup)
+        st, fn, since = setup["stages"], setup["step_stages"], setup["process_start_t"]
+        self.logger.log(
+            f"set-up to step {step}'s dispatch returning"
+            + (f", {phase.t + seconds - since:.1f} s since the process started" if since else "")
+            + ": " + ", ".join(f"{ph['name']} {ph['seconds']:.2f}" for ph in setup["phases"])
+            + (f"; {self._step_fun}: trace {fn['trace_s']:.2f}, lower {fn['lower_s']:.2f}, "
+               f"backend {fn['backend_s']:.2f} (cache {fn['cache'] or 'off'})" if fn else "")
+            + f"; every program: trace {st['trace_s']:.2f}, lower {st['lower_s']:.2f}, backend "
+            f"{st['backend_s']:.2f} s in {st['backend_n']}, cache hits {st['cache_hits']}, "
+            f"misses {st['cache_misses']}"
+            + (" (" + ", ".join(setup["misses"]) + ")" if setup["misses"] else ""))
         self._plans = {name: {key: n - self._plans_seen[name].get(key, 0)
                               for key, n in counts().items()}
                        for name, (_, counts) in self._plan_tallies.items()}
@@ -1091,95 +1192,97 @@ class Trainer:
     # -- the loop -----------------------------------------------------------
     def train(self) -> Dict[str, Any]:
         cfg = self.config
-        train_t0 = time.perf_counter()
-        # run_start is appended before any other activity (the step-0
-        # validation below emits an eval event) so the stream always
-        # opens with it on a fresh run.
-        if self.events is not None and self.start_step == 0:
-            self.events.append(
-                "run_start", name=cfg.name, total_steps=self.total_steps,
-                n_params=self.n_params, flops_per_token=self.flops_per_token,
-                peak_flops=self.peak_flops, **self.device_stamp,
-                # attribution stamp: every downstream number traces to the
-                # XLA flag set it ran under (parallel/xla_flags.py)
-                **self.xla_stamp)
-        log_int = max(1, cfg.logging.logging_interval)
-        ckpt_int = cfg.logging.checkpoint_interval
-        val_int = cfg.logging.validation_interval
-        self.maybe_run_lr_finder()
+        # From entry to the loop: what a run does before its first batch.
+        with self._setup_phase("train.start"):
+            train_t0 = time.perf_counter()
+            # run_start is appended before any other activity (the step-0
+            # validation below emits an eval event) so the stream always
+            # opens with it on a fresh run.
+            if self.events is not None and self.start_step == 0:
+                self.events.append(
+                    "run_start", name=cfg.name, total_steps=self.total_steps,
+                    n_params=self.n_params, flops_per_token=self.flops_per_token,
+                    peak_flops=self.peak_flops, **self.device_stamp,
+                    # attribution stamp: every downstream number traces to the
+                    # XLA flag set it ran under (parallel/xla_flags.py)
+                    **self.xla_stamp)
+            log_int = max(1, cfg.logging.logging_interval)
+            ckpt_int = cfg.logging.checkpoint_interval
+            val_int = cfg.logging.validation_interval
+            self.maybe_run_lr_finder()
 
-        # Optional jax.profiler trace window [profile_start, profile_stop).
-        prof_start = int(cfg.logging.profile_start or 0)
-        prof_stop = int(cfg.logging.profile_stop or 0)
+            # Optional jax.profiler trace window [profile_start, profile_stop).
+            prof_start = int(cfg.logging.profile_start or 0)
+            prof_stop = int(cfg.logging.profile_stop or 0)
 
-        if self.start_step == 0 and val_int:
-            v = self.validate()
-            if v is not None:
-                self.logger.log_validation(0, v)
-                self.val_history["steps"].append(0)
-                self.val_history["losses"].append(v)
+            if self.start_step == 0 and val_int:
+                v = self.validate()
+                if v is not None:
+                    self.logger.log_validation(0, v)
+                    self.val_history["steps"].append(0)
+                    self.val_history["losses"].append(v)
 
-        window_tokens = 0
-        window_steps = 0
-        # Per-step MoE routing stats stay device-resident until the log
-        # line reads them (one sync per window, same as loss).
-        window_moe: list = []
-        window_bd_rows: list = []  # a step's positions that carry a diffusion loss
-        # Anything booked so far (step-0 validation, lr finder) happened
-        # before the first window's clock starts — flush it into the run
-        # totals so every window's components sum to its own wall time.
-        self.goodput.close_window(time.perf_counter() - train_t0)
-        window_start = time.perf_counter()
-        last_loss = float("nan")
-        stopped_early = False
-        # One record a step (obs/steprecord.py), from one top of the loop to
-        # the next, or to where an evaluation or a checkpoint begins. A
-        # window's event is built in train.log_window and waits for its last
-        # step's record to close; the host's counters (obs/hoststats.py) ride
-        # it as differences between two window closes.
-        self._steps = StepRecords()
-        self._pending_window: Optional[Dict[str, Any]] = None
-        self._side_s = 0.0
-        self._host_seen = hoststats.window_totals()
+            window_tokens = 0
+            window_steps = 0
+            # Per-step MoE routing stats stay device-resident until the log
+            # line reads them (one sync per window, same as loss).
+            window_moe: list = []
+            window_bd_rows: list = []  # a step's positions that carry a diffusion loss
+            # Anything booked so far (step-0 validation, lr finder) happened
+            # before the first window's clock starts — flush it into the run
+            # totals so every window's components sum to its own wall time.
+            self.goodput.close_window(time.perf_counter() - train_t0)
+            window_start = time.perf_counter()
+            last_loss = float("nan")
+            stopped_early = False
+            # One record a step (obs/steprecord.py), from one top of the loop to
+            # the next, or to where an evaluation or a checkpoint begins. A
+            # window's event is built in train.log_window and waits for its last
+            # step's record to close; the host's counters (obs/hoststats.py) ride
+            # it as differences between two window closes.
+            self._steps = StepRecords()
+            self._pending_window: Optional[Dict[str, Any]] = None
+            self._side_s = 0.0
+            self._host_seen = hoststats.window_totals()
 
-        # Device-side input pipeline: a background worker keeps
-        # data.prefetch_depth batches resident on device, pre-sharded to the
-        # jitted step's expected layout, so the loop below never blocks on a
-        # host->device copy (data/device_prefetch.py).
-        self.prefetcher = DevicePrefetcher(
-            self.data,
-            mesh=self.mesh,
-            depth=int(getattr(cfg.data, "prefetch_depth", 2)),
-            start_step=self.start_step,
-            total_steps=self.total_steps,
-            metrics=self.metrics,
-        )
+            # Device-side input pipeline: a background worker keeps
+            # data.prefetch_depth batches resident on device, pre-sharded to the
+            # jitted step's expected layout, so the loop below never blocks on a
+            # host->device copy (data/device_prefetch.py).
+            self.prefetcher = DevicePrefetcher(
+                self.data,
+                mesh=self.mesh,
+                depth=int(getattr(cfg.data, "prefetch_depth", 2)),
+                start_step=self.start_step,
+                total_steps=self.total_steps,
+                metrics=self.metrics,
+            )
 
-        # Telemetry endpoints for the run: Prometheus exposition behind
-        # logging.metrics_port (EVERY process serves — process i binds
-        # metrics_port + i and stamps process_index into the exposition,
-        # so multi-host fleets expose all hosts, not just the chief; the
-        # server stays up after train() returns — daemon thread — so late
-        # scrapes see the final counters), the run_start event, and the
-        # first heartbeat so the supervisor's hang watchdog has a
-        # baseline that covers the initial compile.
-        if cfg.logging.metrics_port and self._metrics_server is None:
-            from ..obs.prometheus import start_metrics_server
+            # Telemetry endpoints for the run: Prometheus exposition behind
+            # logging.metrics_port (EVERY process serves — process i binds
+            # metrics_port + i and stamps process_index into the exposition,
+            # so multi-host fleets expose all hosts, not just the chief; the
+            # server stays up after train() returns — daemon thread — so late
+            # scrapes see the final counters), the run_start event, and the
+            # first heartbeat so the supervisor's hang watchdog has a
+            # baseline that covers the initial compile.
+            if cfg.logging.metrics_port and self._metrics_server is None:
+                from ..obs.prometheus import start_metrics_server
 
-            pidx = jax.process_index()
-            port = int(cfg.logging.metrics_port) + pidx
-            self._metrics_server = start_metrics_server(
-                self.metrics, port, process_index=pidx)
-            if self._metrics_server is not None:
-                self.logger.log(
-                    f"telemetry: serving Prometheus metrics on "
-                    f":{self._metrics_server.port}/metrics "
-                    f"(process {pidx})")
-            else:
-                self.logger.log(
-                    f"telemetry: metrics port {port} "
-                    f"unavailable; exporter disabled")
-        self._touch_heartbeat(self.start_step)
+                pidx = jax.process_index()
+                port = int(cfg.logging.metrics_port) + pidx
+                self._metrics_server = start_metrics_server(
+                    self.metrics, port, process_index=pidx)
+                if self._metrics_server is not None:
+                    self.logger.log(
+                        f"telemetry: serving Prometheus metrics on "
+                        f":{self._metrics_server.port}/metrics "
+                        f"(process {pidx})")
+                else:
+                    self.logger.log(
+                        f"telemetry: metrics port {port} "
+                        f"unavailable; exporter disabled")
+            self._touch_heartbeat(self.start_step)
 
         # Preemption-aware checkpointing (SURVEY.md §5 failure-detection
         # plan; the reference's only recovery story is checkpoint-resume):
@@ -1272,6 +1375,8 @@ class Trainer:
                         batch, local_tokens, waits = self.prefetcher.get()
                     self._steps.note(data_get_s=round(ph.seconds, 6),
                                      queue_depth=waits["queue_depth"])
+                    if not self._compiled:
+                        self._setup_phases.append(("train.data_get", ph.t, ph.seconds))
                 except StopIteration:  # finite stream ran dry (streaming sources)
                     self.logger.log(f"Data stream exhausted before step {step}; stopping")
                     break
@@ -1294,7 +1399,7 @@ class Trainer:
                 with jax.profiler.StepTraceAnnotation("train", step_num=step), \
                         self.tracer.phase("train.dispatch", step=step) as ph:
                     self.state, metrics = self.train_step(self.state, batch)
-                self._book_dispatch(ph.seconds, step)
+                self._book_dispatch(ph, step)
                 self._steps.note(dispatch_s=round(ph.seconds, 6))
 
                 window_steps += 1
@@ -1430,17 +1535,19 @@ class Trainer:
                         for comp, secs in gp.items():
                             if secs > 0:
                                 self._m_goodput.inc(secs, component=comp)
+                        compiled = compiles.totals()
+                        built = self._note_compiles() if compiled != self._compiles_seen else {}
                         if self.events is not None:
                             ev = dict(
                                 step=step, steps=window_steps,
                                 toks=int(window_tokens), loss=round(loss, 6),
                                 tok_s=round(tok_s, 2), mfu=mfu_val,
                                 goodput={k: round(v, 6) for k, v in gp.items()})
-                            seen = compiles.totals()
-                            ev["xla_compiles"] = seen[0] - self._compiles_seen[0]
+                            ev["xla_compiles"] = compiled[0] - self._compiles_seen[0]
                             ev["xla_compile_s"] = round(
-                                seen[1] - self._compiles_seen[1], 4)
-                            self._compiles_seen = seen
+                                compiled[1] - self._compiles_seen[1], 4)
+                            if built:
+                                ev["xla_compiled"] = built
                             if self._plans is not None:
                                 ev.update(self._plans)
                                 self._plans = None
@@ -1454,6 +1561,7 @@ class Trainer:
                             seen = hoststats.window_totals()
                             ev.update(hoststats.window_fields(self._host_seen, seen))
                             self._host_seen = seen
+                        self._compiles_seen = compiled
                         # Appended when the window's last step closes (below,
                         # or at the next top of the loop), with its steps' records.
                         self._pending_window = ev if self.events is not None else {}

@@ -366,7 +366,8 @@ def test_the_cell_is_declared_for_the_metrics_it_reports_and_no_other():
     listed = {m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", ())}
     for name in listed:
         assert os.path.isfile(os.path.join(REPO, "benchmark/layer_metrics", name + ".py"))
-    mine = bench["per_layer"][-len(NEW_READERS):]                  # appended, one run of entries
+    names = [m["name"] for m in bench["per_layer"]]
+    mine = bench["per_layer"][names.index(NEW_READERS[0]):][:len(NEW_READERS)]   # one run of entries
     assert [m["name"] for m in mine] == list(NEW_READERS)
     assert all(m["workloads"] == [CELL] and m["moves"] == "train_tokens_per_s_per_chip" for m in mine)
     assert [(m["unit"], m["better"]) for m in mine[2:4]] == [("%", "higher")] * 2
@@ -374,7 +375,17 @@ def test_the_cell_is_declared_for_the_metrics_it_reports_and_no_other():
         [("count", "lower", "program_counter", "train step")] * 2      # the two tallies: cores, prologues
     everyone = {m["name"] for m in bench["per_layer"]
                 if len(m.get("workloads", ())) == len(bench["workloads"])}
-    assert len(everyone) == 17 and everyone <= listed
+    # what every training cell reports: the 17 of the step and the loop, and set-up's eight (PR 54)
+    assert everyone == {
+        "input_wait_pct", "train_mfu_pct", "step_hbm_gib", "device_idle_pct.train",
+        "step_device_ms.attn_core", "step_device_ms.attn_proj", "step_device_ms.lm_head_ce",
+        "step_device_ms.optimizer", "step_device_ms.recompute", "step_device_ms.unscoped",
+        "step_host_ms", "compiles_in_window", "step_stall_pct", "slow_step_extra_cpu_ms",
+        "step_host_cpu_ms", "gc_pause_ms_per_step", "hbm_reserved_gib",
+        "setup_cache_misses"} | {"setup_part_s." + part for part in (
+            "before_trainer", "trainer_build", "step_trace", "step_lower", "step_compile_or_load",
+            "steps_to_window", "other")}
+    assert everyone <= listed
     assert listed == everyone | set(NEW_READERS) | {
         "step_device_ms.ffn", "step_device_ms.moe", "kernel_peak_pct.gmm", "moe_rows_held_per_step",
         "moe_whole_buffer_chunks_per_step", "kernel_peak_pct.mla_flash_fwd", "kernel_peak_pct.mla_flash_bwd"}

@@ -561,7 +561,9 @@ def test_paged_holds_twice_the_sequences_of_worst_case_rows():
     assert all(len(t) > 0 for t in paged_tokens)
 
 
-def test_moe_model_batch_engine_greedy_matches_generate_text():
+def test_moe_model_batch_engine_greedy_matches_generate_text(no_mesh_left_behind):
+    # (no_mesh_left_behind: under a mesh another file's Trainer left in its worker the expert
+    # layer takes its shard_map form and refuses a batch of one.)
     # The batch engine's step shares moe_block with training: a MoE
     # checkpoint must greedy-decode under --engine batch token-for-token
     # with the single-stream locked path (grouped dispatch is dropless and
