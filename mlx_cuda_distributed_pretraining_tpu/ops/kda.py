@@ -20,16 +20,19 @@ chunk everything is matmuls, between chunks the state is handed over. With
 
 **No exponent here is ever positive.** ``exp(-G_i)`` alone overflows float32
 (nothing bounds a step's decay; -1.6 a step is -100 a chunk), so ``gam`` is not
-split as ``exp(G_r) exp(-G_i)`` across a chunk. A chunk is cut into sub-blocks
-of ``SUB`` = 16 steps. A pair inside one sub-block takes ``exp(G_r - G_i)``
-itself, channel by channel, on the VPU (16 partners a step). A pair in two
-sub-blocks is factored at the boundary ``b`` before the later one's first
-step, ``exp(G_r - G_b) exp(G_b - G_i)``, both factors <= 1, and is a matmul.
+split as ``exp(G_r) exp(-G_i)`` across a chunk. Every pair ``i < r`` is
+factored at a boundary that lies between its two steps, by halving: at level
+``s`` (``s = c, c / 2, ..., 2``) a block of ``s`` steps has the boundary ``b``,
+the last step of its lower half; a row ``r`` of the upper half takes ``exp(G_r
+- G_b)``, a row ``i`` of the lower half ``exp(G_b - G_i)``, both <= 1, and one
+matmul a level, masked to (same block, ``r`` above ``b``, ``i`` not), gives
+every pair that boundary separates. The levels partition the pairs (the
+highest bit of ``r ^ i`` names a pair's level); no pair is VPU work.
 Underflow is benign: the true product is smaller still.
 
-The solve ``T`` is exact block substitution in float32: the sub-blocks on the
-diagonal are nilpotent of index 16 and are inverted by ``(I - D)(I + D^2)(I +
-D^4)(I + D^8)``; with ``N = (I + D)^-1 (A - D)``, nilpotent of index ``c / 16``
+The solve ``T`` is exact block substitution in float32: ``A``'s sub-blocks of
+``SUB`` = 16 steps on the diagonal are nilpotent of index 16 and are inverted
+by ``(I - D)(I + D^2)(I + D^4)(I + D^8)``; with ``N = (I + D)^-1 (A - D)``, nilpotent of index ``c / 16``
 by blocks, ``T = (I - N)(I + N^2)... (I + D)^-1``. (The same product over the
 whole of ``A`` has partial sums of size ``(1 + |A|)^c``: not that.)
 
@@ -62,7 +65,7 @@ from __future__ import annotations
 import collections
 import functools
 import threading
-from typing import Dict, List, NamedTuple, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -73,8 +76,8 @@ from .backend import backend_from_env
 
 __all__ = ["kda", "kda_plan", "plan_counts", "default_backend", "KERNEL_CHUNK"]
 
-SUB = 16               # steps of a sub-block: pairs inside one take exp(G_r - G_i) itself
-KERNEL_CHUNK = 128     # steps of a chunk: the cell's step 1,533 ms against 1,603 at 64 (PERF.md section 6, PR 51)
+SUB = 16               # steps of a diagonal sub-block of the solve
+KERNEL_CHUNK = 128     # steps of a chunk: forward | backward 17.80 | 22.92 ms a call of the cell against 20.46 | 25.03 at 64 (PERF.md section 6, PR 55)
 _LANES = 128
 _VMEM_LIMIT = 64 * 2**20
 _F32 = jnp.float32
@@ -134,7 +137,10 @@ def _count(*keys: str) -> None:
 
 def plan_counts() -> Dict[str, int]:
     """Cores traced so far in this process by form (``kernel``: a differentiable
-    call of the two kernels; ``xla``), and each again by chunk (``kernel_chunk64``)."""
+    call of the two kernels; ``xla``), each again by chunk (``kernel_chunk64``),
+    and by how a chunk's pairs were built: ``levels7`` (matmuls a chunk),
+    ``pair_passes0`` (partner passes over the chunk on the VPU: 16 before the
+    levels reached down to single steps; a tally without the key timed those)."""
     with _plan_counts_lock:
         return {**{k: _plan_counts[k] for k in _FORMS},
                 **{k: n for k, n in sorted(_plan_counts.items()) if k not in _FORMS}}
@@ -160,73 +166,60 @@ def _tril(c: int, strict: bool = False):
     return (col < row) if strict else (col <= row)
 
 
-class _Decays(NamedTuple):
-    """A chunk's decays, every exponent <= 0. ``pair[j] [c, d]``: row ``r`` of
-    sub-block ``b`` holds ``exp(G_r - G_{b sub + j})`` (1 where ``r`` is the
-    earlier). ``down[a - 1] [sub, d]``: the rows of sub-block ``a`` from the
-    boundary before it; ``up[a - 1] [c, d]``: every earlier row up to that
-    boundary (1 from the boundary on, where a mask cuts)."""
-    sub: int
-    pair: List[jnp.ndarray]
-    partner: List[jnp.ndarray]     # row r of sub-block b holds k_{b sub + j}
-    down: List[jnp.ndarray]
-    up: List[jnp.ndarray]
+def _levels(c: int) -> List[int]:
+    """The block sizes ``c, c / 2, ..., 2`` at which a chunk's pairs are factored."""
+    if c & (c - 1):
+        raise ValueError(f"a chunk of {c} steps does not halve down to pairs")
+    return [c >> t for t in range(c.bit_length() - 1)]
 
 
-def _blocks(c: int) -> int:
-    return min(SUB, c)
+def _boundary_rows(G, s: int):
+    """``[c, d]`` whose row ``r`` is ``G``'s row ``b``, the last step of the lower
+    half of ``r``'s block of ``s`` steps. Blocks shorter than a register's 8
+    sublanes share one: a sublane broadcast for each and a select on the row."""
+    c, d = G.shape
+    tile = max(s, min(8, c))
+    x3 = G.reshape(c // tile, tile, d)
+    at = lambda lo: jnp.broadcast_to(x3[:, lo + s // 2 - 1:lo + s // 2, :], x3.shape)
+    out, sublane = at(0), _iota(x3.shape, 1)
+    for lo in range(s, tile, s):
+        out = jnp.where(sublane >= lo, at(lo), out)
+    return out.reshape(c, d)
 
 
-def _partner_rows(x, j: int, sub: int):
-    """``[c, d]`` whose row ``r`` is ``x``'s row ``(r // sub) sub + j``."""
-    c, d = x.shape
-    x3 = x.reshape(c // sub, sub, d)
-    return jnp.broadcast_to(x3[:, j:j + 1, :], x3.shape).reshape(c, d)
+class _Level(NamedTuple):
+    """One halving of a chunk. ``at [c, c]``: the pairs it factors (same block of
+    ``s``, ``r`` in its upper half, ``i`` in its lower; over the levels they
+    partition ``i < r``: the highest bit of ``r ^ i`` names a pair's level).
+    ``e [c, d]``: a row of an upper half holds ``exp(G_r - G_b)``, one of a lower
+    half ``exp(G_b - G_i)``, ``b`` the last step of the lower half: every
+    exponent <= 0. ``ke, qe``: ``k`` and ``q`` under it."""
+    at: jnp.ndarray
+    e: jnp.ndarray
+    ke: jnp.ndarray
+    qe: jnp.ndarray
 
 
-def _decays(G, k) -> _Decays:
+def _pair_levels(G, q, k) -> List[_Level]:
     c = G.shape[0]
-    sub = _blocks(c)
-    pair = [jnp.exp(jnp.minimum(G - _partner_rows(G, j, sub), 0.0)) for j in range(sub)]
-    partner = [_partner_rows(k, j, sub) for j in range(sub)]
-    down, up = [], []
-    for lo in range(sub, c, sub):
-        ref = G[lo - 1:lo]
-        down.append(jnp.exp(G[lo:lo + sub] - ref))
-        up.append(jnp.exp(jnp.minimum(ref - G, 0.0)))
-    return _Decays(sub, pair, partner, down, up)
-
-
-def _column_at(c: int, sub: int, j: int):
-    """``[c, c]`` mask: in row ``r``, the column of its own sub-block's step ``j``."""
     row, col = _iota((c, c), 0), _iota((c, c), 1)
-    return col == (row // sub) * sub + j
+    span = jnp.where(col < row, row ^ col, 0)
+    out = []
+    for s in _levels(c):
+        e = jnp.exp(-jnp.abs(G - _boundary_rows(G, s)))
+        out.append(_Level((span >> (s.bit_length() - 2)) == 1, e, k * e, q * e))
+    return out
 
 
-def _own_column(M, at):
-    return jnp.sum(jnp.where(at, M, 0.0), axis=1, keepdims=True)
-
-
-def _decay_mats(lefts: Sequence[jnp.ndarray], k, dec: _Decays, mmdt):
-    """For every ``x`` of ``lefts``: ``M[r, i] = sum_ch x_r k_i gam(i->r)`` for
-    ``i <= r`` (0 above the diagonal), ``[c, c]`` float32."""
-    c, sub, n = k.shape[0], dec.sub, len(lefts)
-    mats = [jnp.zeros((c, c), _F32) for _ in lefts]
-    for j in range(sub):
-        w = dec.partner[j] * dec.pair[j]
-        at = _column_at(c, sub, j)
-        mats = [jnp.where(at, jnp.sum(x * w, axis=1, keepdims=True), m) for x, m in zip(lefts, mats)]
-    lower = _tril(c)
-    mats = [jnp.where(lower, m, 0.0) for m in mats]
-    if c == sub:
-        return mats
-    col = _iota((n * sub, c), 1)
-    rows = [jnp.zeros((n * sub, c), _F32)]
-    for a, lo in enumerate(range(sub, c, sub)):
-        stack = jnp.concatenate([x[lo:lo + sub] * dec.down[a] for x in lefts], axis=0)
-        rows.append(jnp.where(col < lo, _dot(stack, k * dec.up[a], _NT, mmdt), 0.0))
-    return [m + jnp.concatenate([r[i * sub:(i + 1) * sub] for r in rows], axis=0)
-            for i, m in enumerate(mats)]
+def _span_masks(c: int):
+    """``[c, levels c]`` of 0 and 1, a ``[c, c]`` block a level: in row ``t`` the
+    steps ``j`` of ``t``'s half of its block whose pairs at that level have ``t``
+    between their two steps (``i < t <= r``): ``j >= t`` in an upper half (``j``
+    is the pair's ``r``), ``j < t`` in a lower (its ``i``)."""
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+    later = jnp.where(col >= row, 1, 0)
+    masks = [((row ^ col) < s // 2) & (later == ((row >> (s.bit_length() - 2)) & 1)) for s in _levels(c)]
+    return jnp.concatenate([jnp.where(m, 1.0, 0.0) for m in masks], axis=1)
 
 
 def _eye(c: int):
@@ -248,7 +241,7 @@ def _solve(A):
     """``(I + A)^-1`` of a strictly lower triangular ``[c, c]``, float32: the
     sub-blocks on the diagonal first, then the blocks below them."""
     c = A.shape[0]
-    sub = _blocks(c)
+    sub = min(SUB, c)
     row, col = _iota((c, c), 0), _iota((c, c), 1)
     D = jnp.where(row // sub == col // sub, A, 0.0)
     d_inv = _nilpotent_inverse(D, sub)
@@ -260,7 +253,7 @@ def _solve(A):
 class _Chunk(NamedTuple):
     """What both passes of a chunk compute first."""
     G: jnp.ndarray       # running sums of g
-    dec: _Decays
+    levels: List[_Level]
     A0: jnp.ndarray      # k k^T under the decays, strictly lower
     P: jnp.ndarray       # q k^T under the decays, lower
     T: jnp.ndarray       # (I + Diag(beta) A0)^-1
@@ -272,12 +265,15 @@ class _Chunk(NamedTuple):
 def _chunk_local(q, k, g, bcol, mmdt) -> _Chunk:
     c = q.shape[0]
     G = _dot(_tril(c).astype(_F32), g, _NN)
-    dec = _decays(G, k)
-    A0, P = _decay_mats([k, q], k, dec, mmdt)
-    A0 = jnp.where(_tril(c, strict=True), A0, 0.0)
+    levels = _pair_levels(G, q, k)
+    A0 = P = jnp.zeros((c, c), _F32)
+    for lv in levels:       # M[r, i] = sum_ch x_r k_i gam(i->r) for the level's pairs, x = k and q stacked
+        prod = _dot(jnp.concatenate([lv.ke, lv.qe], axis=0), lv.ke, _NT, mmdt)
+        A0, P = jnp.where(lv.at, prod[:c], A0), jnp.where(lv.at, prod[c:], P)
+    P = jnp.where(_iota((c, c), 0) == _iota((c, c), 1), jnp.sum(q * k, axis=1, keepdims=True), P)
     T = _solve(bcol * A0)
     last = G[c - 1:c]
-    return _Chunk(G, dec, A0, P, T, jnp.exp(G), jnp.exp(last - G), jnp.exp(last))
+    return _Chunk(G, levels, A0, P, T, jnp.exp(G), jnp.exp(last - G), jnp.exp(last))
 
 
 def _chunk_fwd(St, q, k, v, g, bcol, brow, mmdt):
@@ -297,11 +293,11 @@ def _chunk_bwd(St, dSt_new, do, q, k, v, g, bcol, brow, mmdt):
     column [c, 1] and as a row [1, c] (the two add), dSt)``, float32. With
     ``f = sum x_r y_i exp(G_r - G_i)``: ``df/dG_r = x_r df/dx_r`` and ``df/dG_i =
     -y_i df/dy_i``, so ``dG`` is ``q dq`` plus ``k`` times (``dk`` where ``k``
-    stands on the left, less ``dk`` where it stands on the right)."""
+    stands on the left, less ``dk`` where it stands on the right); the pairs'
+    part of ``dg`` is summed over the steps between a pair's two (below)."""
     q, k, v, g, do = (a.astype(_F32) for a in (q, k, v, g, do))
-    c, sub = q.shape[0], _blocks(q.shape[0])
+    c = q.shape[0]
     z = _chunk_local(q, k, g, bcol, mmdt)
-    dec = z.dec
     Kg, Qg, Kd = k * z.eG, q * z.eG, k * z.eD
     Tb = z.T * brow
     R = v - _dot(Kg, St, _NT, mmdt)
@@ -322,33 +318,25 @@ def _chunk_bwd(St, dSt_new, do, q, k, v, g, bcol, brow, mmdt):
     dq = _dot(do, St, _NN, mmdt) * z.eG                 # through Q exp(G)
     dk_left = -_dot(dR, St, _NN, mmdt) * z.eG           # through K exp(G)
     dk_end = _dot(Vp, dSt_new, _NN, mmdt) * z.eD        # through K exp(G_c - G)
-    dk_right = jnp.zeros_like(k)
-    in_block = _iota(k.shape, 0) % sub
-    for j in range(sub):
-        at = _column_at(c, sub, j)
-        col_p, col_a = _own_column(dP, at), _own_column(dA0, at)
-        dq = dq + col_p * (dec.partner[j] * dec.pair[j])
-        dk_left = dk_left + col_a * (dec.partner[j] * dec.pair[j])
-        t = ((col_p * q + col_a * k) * dec.pair[j]).reshape(c // sub, sub, -1)
-        t = jnp.broadcast_to(jnp.sum(t, axis=1, keepdims=True), t.shape).reshape(k.shape)
-        dk_right = dk_right + jnp.where(in_block == j, t, 0.0)
-    if c > sub:
-        col = _iota((2 * sub, c), 1)
-        lefts = [jnp.zeros((2 * sub, k.shape[1]), _F32)]
-        for a, lo in enumerate(range(sub, c, sub)):
-            rows = jnp.where(col < lo, jnp.concatenate([dP[lo:lo + sub], dA0[lo:lo + sub]], axis=0), 0.0)
-            lefts.append(_dot(rows, k * dec.up[a], _NN, mmdt)
-                         * jnp.concatenate([dec.down[a], dec.down[a]], axis=0))
-            into = jnp.concatenate([q[lo:lo + sub] * dec.down[a], k[lo:lo + sub] * dec.down[a]], axis=0)
-            dk_right = dk_right + _dot(rows, into, _TN, mmdt) * dec.up[a]
-        dq = dq + jnp.concatenate([l[:sub] for l in lefts], axis=0)
-        dk_left = dk_left + jnp.concatenate([l[sub:] for l in lefts], axis=0)
+    on_diag = jnp.sum(_eye(c) * dP, axis=1, keepdims=True)   # exp(0): no decay, nothing to G
+    dq_pairs, dk_pairs, spans = on_diag * k, on_diag * q, []
+    for lv in z.levels:
+        rows = jnp.concatenate([jnp.where(lv.at, dP, 0.0), jnp.where(lv.at, dA0, 0.0)], axis=0)
+        lefts = _dot(rows, lv.ke, _NN, mmdt)                                        # rows of upper halves
+        right = _dot(rows, jnp.concatenate([lv.qe, lv.ke], axis=0), _TN, mmdt)      # rows of lower halves
+        to_q, to_k = lefts[:c] * lv.e, (lefts[c:] + right) * lv.e
+        dq_pairs, dk_pairs = dq_pairs + to_q, dk_pairs + to_k
+        spans.append(q * to_q + k * to_k)
 
-    dG = q * dq + k * (dk_left - dk_right - dk_end)
+    # A pair's product x_r k_i gam(i->r) depends on g_t for i < t <= r alone. Through G its gradient
+    # would be +X at r and -X at i from two matmuls whose roundings differ, and the running sum would
+    # carry every later pair's difference down the chunk; so the steps between are summed directly.
+    dG = q * dq + k * (dk_left - dk_end)
     d_end = d_end + jnp.sum(k * dk_end, axis=0, keepdims=True)
     dG = dG + jnp.where(_iota(dG.shape, 0) == c - 1, d_end, 0.0)
     dg = _dot(_tril(c).astype(_F32), dG, _TN)
-    return dq, dk_left + dk_right + dk_end, dR, dg, dbcol, dbrow, dSt
+    dg = dg + _dot(_span_masks(c), jnp.concatenate(spans, axis=0), _NN, mmdt)
+    return dq + dq_pairs, dk_left + dk_end + dk_pairs, dR, dg, dbcol, dbrow, dSt
 
 
 # -- the XLA form -------------------------------------------------------------------
@@ -373,7 +361,8 @@ def _kda_xla(q, k, v, g, beta, chunk: int):
 
 # -- the kernels --------------------------------------------------------------------
 # Heads a grid step: their chunks are independent chains of small matmuls (the solve alone is a
-# dozen dependent float32 products), so the scheduler fills one head's waits with another's work.
+# dozen dependent float32 products), so the scheduler fills one head's waits with another's work:
+# forward | backward 17.87 | 23.18 ms at 2, 17.80 | 22.92 at 4, 17.70 | 22.83 at 8, whose bodies hold twice the equations (PR 55).
 HEADS_PER_STEP = 4
 
 
@@ -530,7 +519,7 @@ def _kda(q, k, v, g, beta, backend: Optional[str], chunk: int, heads: int):
         backend = "xla"
     B, S, H, d = q.shape
     plan = _plan(S, d, backend, chunk)
-    _count(plan.path, f"{plan.path}_chunk{plan.chunk}")
+    _count(plan.path, f"{plan.path}_chunk{plan.chunk}", f"levels{len(_levels(plan.chunk))}", "pair_passes0")
     if plan.path == "xla":
         return _kda_xla(q, k, v, g, beta, plan.chunk)
     flat = lambda a: a.reshape(B, S, H * d)

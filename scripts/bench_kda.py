@@ -8,15 +8,20 @@ and its XLA form, against the bytes a call has to move, after one line with the
 seconds the host takes to trace and lower each kernel body once (what every run
 of the cell pays before its first step, whatever the compile cache holds).
 
-    python scripts/bench_kda.py [--shape 2x8192x32x128] [--chunks 64,128] [--heads 1,2,4] [--reps 5]
+    python scripts/bench_kda.py [--shape 2x8192x32x128] [--chunks 64,128] [--heads 1,2,4] [--reps 5] [--check]
     python scripts/bench_kda.py --prologue [--blocks 512x1024,256x2048] [--tiles 32] [--check]
 
 Prints ms a call (a jitted loop of ``--inner`` calls, the median of ``--reps``
 runs that end in ``block_until_ready``, over ``--inner``) and the share of the
-roof (``short_conv``: GB/s of the required bytes); ``--check`` also compares the
-kernel's value and gradients at a short length against the XLA form on the
-chip. Step 0 of PR 51, the block shapes of PR 52 and the bodies' host cost of PR 53
-(PERF.md section 6).
+roof (``short_conv``: GB/s of the required bytes). ``--check`` also holds the
+core's kernels, at each chunk, to the benchmark's float32 recurrence on the
+chip at float32 and at bfloat16 operands (value and five gradients, printed
+after the chunk's times), and the prologue's to their XLA form. Readings at the
+cell's call, forward | backward ms: 18.7 | 25.1 with sixteen partner passes a
+chunk (PR 51 to 54), 17.8 | 22.9 with the pairs as seven levels of matmuls (PR
+55: 20.5 | 25.0 at chunk 64; 17.9 | 23.2 at 2 heads, 17.7 | 22.8 at 8), against a
+float32 solve that alone is 9.6 | 7.8 of them (PERF.md section 6: step 0 of PR 51
+and of PR 55, the block shapes of PR 52, the bodies' host cost of PR 53).
 """
 
 from __future__ import annotations
@@ -173,26 +178,38 @@ def main():
     roof_f = 1e3 * kda_chunk.roof_seconds(kda_chunk.fwd_flops(B, S, H, d), kda_chunk.fwd_bytes(B, S, H, d), pk)
     roof_b = 1e3 * kda_chunk.roof_seconds(kda_chunk.bwd_flops(B, S, H, d), kda_chunk.bwd_bytes(B, S, H, d), pk)
     print(f"roof: forward {roof_f:.3f} ms, backward {roof_b:.3f} ms (HBM binds both)", flush=True)
-    for c, heads in ((int(c), int(h)) for c in a.chunks.split(",") for h in a.heads.split(",")):
-        fwd = lambda q, k, v, g, b, c=c, heads=heads: kda_ops._kda(q, k, v, g, b, "kernel", c, heads)
-        grad = jax.grad(lambda q, k, v, g, b, w, c=c: jnp.sum(fwd(q, k, v, g, b).astype(jnp.float32) * w),
-                        argnums=(0, 1, 2, 3, 4))
-        t_f = timed(fwd, (q, k, v, g, beta), a.reps, a.inner)
-        t_fb = timed(grad, (q, k, v, g, beta, w), a.reps, a.inner)
-        print(f"chunk {c}, {heads} head(s) a grid step: forward {t_f:.2f} ms ({100 * roof_f / t_f:.1f}% of "
-              f"its roof); forward with states + backward {t_fb:.2f} ms (backward about {t_fb - t_f:.2f} ms, "
-              f"{100 * roof_b / max(t_fb - t_f, 1e-9):.1f}% of its roof)", flush=True)
-    if a.check:
-        qs, ks_, vs, gs, bs, ws = operands(1, 512, 2, d, jnp.float32, seed=1)
-        rel = lambda x, y: float(jnp.linalg.norm(x - y) / (jnp.linalg.norm(y) + 1e-30))
-        for c in (int(x) for x in a.chunks.split(",")):
-            core = lambda be: (lambda *o: kda_ops._kda(*o, be, c, kda_ops.HEADS_PER_STEP))
-            f = lambda be: (lambda *o: jnp.sum(core(be)(*o) * ws))
-            val = rel(core("kernel")(qs, ks_, vs, gs, bs), core("xla")(qs, ks_, vs, gs, bs))
-            gk = jax.grad(f("kernel"), argnums=(0, 1, 2, 3, 4))(qs, ks_, vs, gs, bs)
-            gx = jax.grad(f("xla"), argnums=(0, 1, 2, 3, 4))(qs, ks_, vs, gs, bs)
-            print(f"check chunk {c} (float32, 1 x 512 x 2 x {d}): value {val:.2e}; gradients "
-                  + " ".join(f"{rel(x, y):.2e}" for x, y in zip(gk, gx)), flush=True)
+    for c in (int(c) for c in a.chunks.split(",")):
+        for heads in (int(h) for h in a.heads.split(",")):
+            fwd = lambda q, k, v, g, b, c=c, heads=heads: kda_ops._kda(q, k, v, g, b, "kernel", c, heads)
+            grad = jax.grad(lambda q, k, v, g, b, w, fwd=fwd: jnp.sum(fwd(q, k, v, g, b).astype(jnp.float32) * w),
+                            argnums=(0, 1, 2, 3, 4))
+            t_f = timed(fwd, (q, k, v, g, beta), a.reps, a.inner)
+            t_fb = timed(grad, (q, k, v, g, beta, w), a.reps, a.inner)
+            print(f"chunk {c}, {heads} head(s) a grid step: forward {t_f:.2f} ms ({100 * roof_f / t_f:.1f}% of "
+                  f"its roof); forward with states + backward {t_fb:.2f} ms (backward about {t_fb - t_f:.2f} ms, "
+                  f"{100 * roof_b / max(t_fb - t_f, 1e-9):.1f}% of its roof)", flush=True)
+        if a.check:
+            check(c, d)
+
+
+def check(c, d, shape=(1, 512, 2)):
+    """The kernels' value and five gradients at chunk ``c`` against the benchmark's float32 recurrence
+    (a step at a time, ``benchmark/reference/kimi_linear.py``) on the same inputs, at float32 and at
+    bfloat16 operands, at a gentle and at a steep decay: this device's own arithmetic, which the CPU
+    tests (interpret mode) and the cell's ``correct`` (PERF.md section 7 (f)) do not read."""
+    from benchmark.reference.kimi_linear import delta_rule
+
+    f32 = lambda x: x.astype(jnp.float32)
+    rel = lambda x, y: float(jnp.linalg.norm(f32(x) - y) / (jnp.linalg.norm(y) + 1e-30))
+    both = lambda core: jax.jit(lambda ops, w: _with_grads(lambda *o: f32(core(*o)), w)(*ops))
+    kernel = both(lambda *o: kda_ops._kda(*o, "kernel", c, kda_ops.HEADS_PER_STEP))
+    recurrence = both(delta_rule)
+    for dtype, decay in ((t, s) for t in (jnp.float32, jnp.bfloat16) for s in (0.3, 4.0)):
+        q, k, v, g, b, w = operands(*shape, d, dtype, seed=1, scale=decay)
+        got, want = kernel((q, k, v, g, b), f32(w)), recurrence((f32(q), f32(k), f32(v), g, b), f32(w))
+        print(f"check chunk {c}, {jnp.dtype(dtype).name} operands, decay {decay} a step ({' x '.join(map(str, shape))} x {d}) "
+              f"against the float32 recurrence: value {rel(got[0], want[0]):.2e}; gradients q k v g beta "
+              + " ".join(f"{rel(x, y):.2e}" for x, y in zip(got[1:], want[1:])), flush=True)
 
 
 if __name__ == "__main__":

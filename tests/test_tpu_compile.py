@@ -609,7 +609,7 @@ def test_kda_kernels_compile_for_v5e_at_the_cells_call(v5e, monkeypatch):
     of 8,192 steps, 32 heads of 128, bfloat16 operands beside a float32 decay,
     forward and backward: Mosaic takes both kernels (one head's lanes of a chunk
     as a block of ``[B, S, H d]``, ``beta`` as a column and as a row, the float32
-    solve, the sub-blocks' pair passes), the differentiated call holds one
+    solve, the pairs' seven levels of masked matmuls), the differentiated call holds one
     forward with its saved states and one backward, and nothing larger than the
     states at chunk starts (0.5 GiB) is left in the program."""
     from mlx_cuda_distributed_pretraining_tpu.ops import kda
@@ -624,7 +624,7 @@ def test_kda_kernels_compile_for_v5e_at_the_cells_call(v5e, monkeypatch):
     grad = jax.jit(jax.grad(lambda *a: core(*a).astype(jnp.float32).sum(), argnums=tuple(range(5))))
     compiled = grad.lower(*ops).compile()
     traced = {k: n - before.get(k, 0) for k, n in kda.plan_counts().items() if n - before.get(k, 0)}
-    assert traced == {"kernel": 2, f"kernel_chunk{kda.KERNEL_CHUNK}": 2}
+    assert traced == {"kernel": 2, f"kernel_chunk{kda.KERNEL_CHUNK}": 2, "levels7": 2, "pair_passes0": 2}
     calls = lambda c: [line for line in c.as_text().split("\n") if "tpu_custom_call" in line]
     assert sum("kda_fwd" in c for c in calls(forward)) == 1 and len(calls(forward)) == 1
     assert sum("kda_fwd" in c for c in calls(compiled)) == 1 and sum("kda_bwd" in c for c in calls(compiled)) == 1
