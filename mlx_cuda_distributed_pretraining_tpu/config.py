@@ -75,9 +75,10 @@ class ModelConfig:
     # block-diffusion objective (block_length, eps, mask_id), which the
     # trainer's loader then draws (data/block_diffusion.py).
     diffusion: Dict[str, Any] = field(default_factory=dict)
-    # Only architecture "kimi_linear" reads it (models/kimi_linear.py): the
-    # published linear_attn_config (kda_layers and full_attn_layers, 1-based;
-    # the delta-rule heads' num_heads, head_dim, short_conv_kernel_size).
+    # Architectures "kimi_linear" and "solar_open2" read it: the published
+    # linear_attn_config (the delta-rule heads' num_heads, head_dim,
+    # short_conv_kernel_size; kimi_linear's kda_layers and full_attn_layers,
+    # 1-based; solar_open2's kda_allow_neg_eigval and kda_use_full_proj).
     linear_attn: Dict[str, Any] = field(default_factory=dict)
     # Named rematerialization policy: "none" | "dots" | "full" |
     # "save_attn" (models/stack.py REMAT_POLICIES — save_attn keeps the

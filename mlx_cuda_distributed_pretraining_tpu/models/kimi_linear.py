@@ -20,7 +20,11 @@ What differs from ``models/llama.py``, block by block:
   log decay for every key channel ``g = -exp(A_log) softplus(x W_f^down W_f^up
   + dt_bias)`` and a write strength ``beta = sigmoid(x W_beta)`` a head; the
   gated delta rule ``ops/kda.py``; then ``RMSNorm_head(o) * sigmoid(x W_g^down
-  W_g^up)`` and ``W_o``. No positions: the state orders the tokens.
+  W_g^up)`` and ``W_o``. No positions: the state orders the tokens. The mixer
+  is shared: :func:`kda_mixer` and :func:`kda_params` take any args with
+  ``hidden_size``, ``kda_heads``, ``kda_head_dim``, ``conv_size`` and
+  ``rms_norm_eps``, and the write strength's factor is an argument, 1 here and
+  2 in ``models/solar_open2.py`` (``beta`` in (0, 2): negative eigenvalues).
 - **Latent attention** (``attention``) as ``models/xing.py`` has it, without a
   query rank and **without any rotation** (``mla_use_nope``): ``q = x W_q``
   straight to ``H x (nope + rope)``; the ``rope`` channels of the shared key
@@ -152,18 +156,52 @@ _layer_counts: Dict[str, int] = collections.Counter()
 _plan_counts_lock = threading.Lock()
 
 
-def kda_plan_counts() -> Dict[str, int]:
-    """``kda_layers`` and ``latent_layers`` traced, then the delta-rule cores by
-    form and chunk (``ops/kda.plan_counts``: ``kernel``, ``xla``) and the q, k, v
-    prologues by form (``ops/short_conv.plan_counts``: ``conv_kernel``, ``conv_xla``)."""
+_SOLVE_KEY = "solve_" + kda_ops.SOLVE_FORM
+
+
+def count_layer(*keys: str) -> None:
     with _plan_counts_lock:
-        own = {k: _layer_counts[k] for k in ("kda_layers", "latent_layers")}
-    return {**own, **kda_ops.plan_counts(), **conv_ops.plan_counts()}
+        _layer_counts.update(keys)
+
+
+def kda_plan_counts(layers=("kda_layers", "latent_layers")) -> Dict[str, int]:
+    """The layers traced by kind (``layers``: this module's two; ``solar_open2``
+    names its own), then the delta-rule cores by
+    form and chunk (``ops/kda.plan_counts``: ``kernel``, ``xla``), of the cores a
+    mixer traced ``neg_eig_cores``, those whose write strength was doubled (0 in a
+    run that lost ``kda_allow_neg_eigval``), and ``solve_<form>``, those by the
+    form of the chunk's triangular solve (``ops/kda.SOLVE_FORM``; a tally without
+    the key is an executable from before PR 56, whose solve lost two digits at a
+    write strength near 2), and the q, k, v prologues by form
+    (``ops/short_conv.plan_counts``: ``conv_kernel``, ``conv_xla``)."""
+    with _plan_counts_lock:
+        own = {k: _layer_counts[k] for k in layers}
+        cores = {k: _layer_counts[k] for k in ("neg_eig_cores", _SOLVE_KEY)}
+    return {**own, **kda_ops.plan_counts(), **cores, **conv_ops.plan_counts()}
 
 
 # -- init ---------------------------------------------------------------------
 A_RANGE = (1.0, 16.0)       # exp(A_log) ~ U(1, 16) a head
 DT_RANGE = (1e-3, 1e-1)     # softplus(dt_bias) log-uniform
+
+
+def kda_params(key, args, dtype, std: float, res_std: float) -> Params:
+    """A KDA mixer's fifteen leaves, ``key()`` a fresh key a draw (the module's
+    docstring says what ``args`` holds)."""
+    C, Hk, d = args.hidden_size, args.kda_heads, args.kda_head_dim
+    dense = lambda shape, s=std: {"weight": (jax.random.normal(key(), shape, jnp.float32) * s).astype(dtype)}
+    u = jax.random.uniform(key(), (Hk,), jnp.float32, *A_RANGE)
+    step = jnp.exp(jax.random.uniform(key(), (Hk * d,), jnp.float32)
+                   * (math.log(DT_RANGE[1]) - math.log(DT_RANGE[0])) + math.log(DT_RANGE[0]))
+    return {"wq": dense((C, Hk * d)), "wk": dense((C, Hk * d)), "wv": dense((C, Hk * d)),
+            "conv_q": dense((Hk * d, args.conv_size)), "conv_k": dense((Hk * d, args.conv_size)),
+            "conv_v": dense((Hk * d, args.conv_size)),
+            "f_down": dense((C, d)), "f_up": dense((d, Hk * d)),
+            "A_log": jnp.log(u).astype(dtype),
+            "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dtype),
+            "wb": dense((C, Hk)),
+            "g_down": dense((C, d)), "g_up": dense((d, Hk * d)),
+            "o_norm": {"weight": jnp.ones((d,), dtype)}, "wo": dense((Hk * d, C), res_std)}
 
 
 def init_params(rng: jax.Array, args: KimiLinearArgs, dtype=jnp.float32) -> Params:
@@ -175,27 +213,13 @@ def init_params(rng: jax.Array, args: KimiLinearArgs, dtype=jnp.float32) -> Para
     key = lambda: jax.random.fold_in(rng, next(counter))
     std = 0.02
     res_std = std / (2 * args.num_layers) ** 0.5
-    C, H, Hk, d = args.hidden_size, args.num_heads, args.kda_heads, args.kda_head_dim
+    C, H = args.hidden_size, args.num_heads
     dense = lambda shape, s=std: {"weight": (jax.random.normal(key(), shape, jnp.float32) * s).astype(dtype)}
     ones = lambda n: {"weight": jnp.ones((n,), dtype)}
 
     def swiglu(width, lead=()):
         return {"w_gate": dense(lead + (C, width)), "w_up": dense(lead + (C, width)),
                 "w_down": dense(lead + (width, C), res_std)}
-
-    def kda():
-        u = jax.random.uniform(key(), (Hk,), jnp.float32, *A_RANGE)
-        step = jnp.exp(jax.random.uniform(key(), (Hk * d,), jnp.float32)
-                       * (math.log(DT_RANGE[1]) - math.log(DT_RANGE[0])) + math.log(DT_RANGE[0]))
-        return {"wq": dense((C, Hk * d)), "wk": dense((C, Hk * d)), "wv": dense((C, Hk * d)),
-                "conv_q": dense((Hk * d, args.conv_size)), "conv_k": dense((Hk * d, args.conv_size)),
-                "conv_v": dense((Hk * d, args.conv_size)),
-                "f_down": dense((C, d)), "f_up": dense((d, Hk * d)),
-                "A_log": jnp.log(u).astype(dtype),
-                "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dtype),
-                "wb": dense((C, Hk)),
-                "g_down": dense((C, d)), "g_up": dense((d, Hk * d)),
-                "o_norm": ones(d), "wo": dense((Hk * d, C), res_std)}
 
     def latent():
         return {"wq": dense((C, H * args.qk_head_dim)),
@@ -214,7 +238,7 @@ def init_params(rng: jax.Array, args: KimiLinearArgs, dtype=jnp.float32) -> Para
             ff = {"router": router,
                   "shared": swiglu(args.n_shared_experts * args.moe_intermediate_size),
                   "experts": swiglu(args.moe_intermediate_size, (args.experts_held[1],))}
-        mixer = {"kda": kda()} if kind == "K" else {"attention": latent()}
+        mixer = {"kda": kda_params(key, args, dtype, std, res_std)} if kind == "K" else {"attention": latent()}
         return {"attention_norm": ones(C), **mixer, "ffn_norm": ones(C), "feed_forward": ff}
 
     return {"tok_embeddings": dense((args.vocab_size, C)),
@@ -224,10 +248,12 @@ def init_params(rng: jax.Array, args: KimiLinearArgs, dtype=jnp.float32) -> Para
 
 
 # -- sub-layers ---------------------------------------------------------------------
-def kda_mixer(p: Params, x: jnp.ndarray, args: KimiLinearArgs) -> jnp.ndarray:
+def kda_mixer(p: Params, x: jnp.ndarray, args, beta_scale: float = 1.0) -> jnp.ndarray:
     """``x [B, S, C]`` (normed) -> ``[B, S, C]``. The convolutions, the norms of
     ``q`` and ``k``, the decay, ``beta`` and the head norm are float32; the
-    projections and the core's matmuls take operands in ``x``'s dtype."""
+    projections and the core's matmuls take operands in ``x``'s dtype.
+    ``beta_scale`` (static) is the write strength's factor: ``beta = beta_scale
+    sigmoid(x W_beta)``."""
     B, S, _ = x.shape
     H, d = args.kda_heads, args.kda_head_dim
     f32 = jnp.float32
@@ -244,6 +270,9 @@ def kda_mixer(p: Params, x: jnp.ndarray, args: KimiLinearArgs) -> jnp.ndarray:
             g = -jnp.exp(p["A_log"].astype(f32))[:, None] * step.reshape(B, S, H, d)
             beta = jax.nn.sigmoid(jnp.einsum("bsc,ch->bsh", x, p["wb"]["weight"],
                                              preferred_element_type=f32))
+            if beta_scale != 1.0:
+                beta = beta_scale * beta
+        count_layer(_SOLVE_KEY, *(("neg_eig_cores",) if beta_scale > 1.0 else ()))
         with jax.named_scope("kda_core"):
             o = kda_ops.kda(q, k, v, g, beta)
         with jax.named_scope("kda_out"):
@@ -306,8 +335,7 @@ def hidden_states(params: Params, tokens: jnp.ndarray, args: KimiLinearArgs,
         x = params["tok_embeddings"]["weight"][tokens].astype(compute_dtype)
     stats = moe_lib.zero_stats(args.n_routed_experts)
     for i, (p, kind) in enumerate(zip(params["layers"], args.layer_kinds)):
-        with _plan_counts_lock:
-            _layer_counts["kda_layers" if kind == "K" else "latent_layers"] += 1
+        count_layer("kda_layers" if kind == "K" else "latent_layers")
         routed = i >= args.first_k_dense
         x, out = stack.own_layer(lambda p, x, kind=kind, routed=routed: block(p, x, args, kind, routed),
                                  compute_dtype, remat)(p, x)
@@ -342,12 +370,24 @@ def loss_fn(params: Params, batch: Dict[str, jnp.ndarray], args: KimiLinearArgs,
     return (loss, (count, stats)) if with_moe_stats else (loss, count)
 
 
+def kda_matmul_params(C: int, Hk: int, d: int) -> int:
+    """A KDA mixer's weights a token is multiplied by: four head-wide projections,
+    two low-rank pairs, ``W_beta``."""
+    return 4 * C * Hk * d + 2 * (C * d + d * Hk * d) + C * Hk
+
+
+def kda_core_flops_per_token(Hk: int, d: int, c: int = 64) -> float:
+    """The delta rule's chunked matmuls a token, forward and backward: ``10 c d + 6
+    d^2 + c^2`` a head forward at ``c`` = 64, three times that with the backward."""
+    return 3.0 * Hk * (10 * c * d + 6 * d * d + c * c)
+
+
 def matmul_params_per_token(args: KimiLinearArgs) -> int:
     """Weights a token is multiplied by (a uniform router assumed for the held
     share: ``top_k * held / routed`` experts a token); no input table, no
     gains, not the depthwise convolutions."""
-    C, H, Hk, d = args.hidden_size, args.num_heads, args.kda_heads, args.kda_head_dim
-    mixer = {"K": 4 * C * Hk * d + 2 * (C * d + d * Hk * d) + C * Hk,
+    C, H = args.hidden_size, args.num_heads
+    mixer = {"K": kda_matmul_params(C, args.kda_heads, args.kda_head_dim),
              "M": C * H * args.qk_head_dim + C * (args.kv_lora_rank + args.qk_rope_head_dim)
              + args.kv_lora_rank * H * (args.qk_nope_head_dim + args.v_head_dim)
              + H * args.v_head_dim * C}
@@ -364,8 +404,7 @@ def flops_per_token(args: KimiLinearArgs, seq_len: int) -> float:
     ``3 S H (d_qk + d_v)`` a latent layer, and the delta rule's chunked matmuls a
     KDA layer (``10 c d + 6 d^2 + c^2`` a head forward at ``c`` = 64, three times
     that with the backward)."""
-    c, d = 64, args.kda_head_dim
-    core = 3.0 * args.kda_heads * (10 * c * d + 6 * d * d + c * c)
+    core = kda_core_flops_per_token(args.kda_heads, args.kda_head_dim)
     latent = 3.0 * seq_len * args.num_heads * (args.qk_head_dim + args.v_head_dim)
     kinds = args.layer_kinds
     return 6.0 * matmul_params_per_token(args) + kinds.count("K") * core + kinds.count("M") * latent

@@ -33,7 +33,7 @@ _REGISTRY: Dict[str, Architecture] = {}
 # Architectures in modules of their own, imported (and so registered) when a
 # config first names them: a llama run pays nothing for them.
 _LAZY_MODULES = {"xing_mla_moe": "xing", "afmoe": "afmoe", "sambay": "sambay", "sdar_moe": "sdar",
-                 "kimi_linear": "kimi_linear"}
+                 "kimi_linear": "kimi_linear", "solar_open2": "solar_open2"}
 
 
 def register(arch: Architecture) -> None:

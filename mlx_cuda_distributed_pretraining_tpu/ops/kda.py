@@ -30,11 +30,17 @@ every pair that boundary separates. The levels partition the pairs (the
 highest bit of ``r ^ i`` names a pair's level); no pair is VPU work.
 Underflow is benign: the true product is smaller still.
 
-The solve ``T`` is exact block substitution in float32: ``A``'s sub-blocks of
-``SUB`` = 16 steps on the diagonal are nilpotent of index 16 and are inverted
-by ``(I - D)(I + D^2)(I + D^4)(I + D^8)``; with ``N = (I + D)^-1 (A - D)``, nilpotent of index ``c / 16``
-by blocks, ``T = (I - N)(I + N^2)... (I + D)^-1``. (The same product over the
-whole of ``A`` has partial sums of size ``(1 + |A|)^c``: not that.)
+The solve ``T`` is block substitution by the same halving, in float32: with
+``T`` the inverse of every diagonal block of ``s / 2`` steps, a block of ``s``
+is ``[[T11, 0], [-T22 A21 T11, T22]]``, so a level is ``T - T A_s T`` with
+``A_s`` the level's pairs of ``A``: two whole ``[c, c]`` products a level from
+pairs of steps (``I - A_2``, exact) up, 12 a chunk of 128. Every factor is an
+inverse already, as large as ``T`` itself and no larger, so the error does not
+grow with ``|A|``: a write strength in (0, 2) (``beta = 2 sigmoid(.)``, the
+transition's eigenvalue ``1 - beta`` along ``k`` in (-1, 1)) over keys that
+share a direction reads 4e-6 where the product form of a nilpotent block,
+``(I - D)(I + D^2)(I + D^4)(I + D^8)`` over 16 steps, whose partial products
+grow like ``(1 + |D|)^16``, read 0.2 (PERF.md section 6, PR 56).
 
 Two paths behind one differentiable entry point, :func:`kda_plan` choosing from
 the shapes and :func:`plan_counts` tallying what a step traced:
@@ -76,7 +82,8 @@ from .backend import backend_from_env
 
 __all__ = ["kda", "kda_plan", "plan_counts", "default_backend", "KERNEL_CHUNK"]
 
-SUB = 16               # steps of a diagonal sub-block of the solve
+SUB = 16               # steps the kernels' chunk is a multiple of (a bfloat16 register's sublanes)
+SOLVE_FORM = "halving"  # how a chunk's triangular solve is built (`_solve`); in the tally as solve_<form>
 KERNEL_CHUNK = 128     # steps of a chunk: forward | backward 17.80 | 22.92 ms a call of the cell against 20.46 | 25.03 at 64 (PERF.md section 6, PR 55)
 _LANES = 128
 _VMEM_LIMIT = 64 * 2**20
@@ -226,28 +233,18 @@ def _eye(c: int):
     return (_iota((c, c), 0) == _iota((c, c), 1)).astype(_F32)
 
 
-def _nilpotent_inverse(N, index: int):
-    """``(I + N)^-1`` of a matrix with ``N^index = 0``: ``(I - N)(I + N^2)(I + N^4)...``."""
-    eye = _eye(N.shape[0])
-    out, power, reach = eye - N, N, 2
-    while reach < index:
-        power = _dot(power, power, _NN)
-        out = _dot(out, eye + power, _NN)
-        reach *= 2
-    return out
-
-
-def _solve(A):
-    """``(I + A)^-1`` of a strictly lower triangular ``[c, c]``, float32: the
-    sub-blocks on the diagonal first, then the blocks below them."""
+def _solve(A, pairs=None):
+    """``(I + A)^-1`` of a strictly lower triangular ``[c, c]``, float32, by halving
+    (the module's docstring): ``pairs`` are the masks of :func:`_pair_levels`, the
+    widest block first, made here where none are handed over."""
     c = A.shape[0]
-    sub = min(SUB, c)
-    row, col = _iota((c, c), 0), _iota((c, c), 1)
-    D = jnp.where(row // sub == col // sub, A, 0.0)
-    d_inv = _nilpotent_inverse(D, sub)
-    if c == sub:
-        return d_inv
-    return _dot(_nilpotent_inverse(_dot(d_inv, A - D, _NN), c // sub), d_inv, _NN)
+    if pairs is None:
+        span = jnp.where(_tril(c, strict=True), _iota((c, c), 0) ^ _iota((c, c), 1), 0)
+        pairs = [(span >> (s.bit_length() - 2)) == 1 for s in _levels(c)]
+    T = _eye(c) - jnp.where(pairs[-1], A, 0.0)
+    for at in pairs[-2::-1]:
+        T = T - _dot(_dot(T, jnp.where(at, A, 0.0), _NN), T, _NN)
+    return T
 
 
 class _Chunk(NamedTuple):
@@ -271,7 +268,7 @@ def _chunk_local(q, k, g, bcol, mmdt) -> _Chunk:
         prod = _dot(jnp.concatenate([lv.ke, lv.qe], axis=0), lv.ke, _NT, mmdt)
         A0, P = jnp.where(lv.at, prod[:c], A0), jnp.where(lv.at, prod[c:], P)
     P = jnp.where(_iota((c, c), 0) == _iota((c, c), 1), jnp.sum(q * k, axis=1, keepdims=True), P)
-    T = _solve(bcol * A0)
+    T = _solve(bcol * A0, [lv.at for lv in levels])
     last = G[c - 1:c]
     return _Chunk(G, levels, A0, P, T, jnp.exp(G), jnp.exp(last - G), jnp.exp(last))
 

@@ -18,7 +18,8 @@ from shapes and the grouped-matmul backend alone:
   contiguous range of the buffer. A tile copies its ranges from HBM in
   pieces of 16 rows (a bfloat16 register's sublanes: every copy starts on
   the buffer's tiling; Mosaic takes no narrower slice of a tiled array in
-  HBM) into a staging slot in VMEM, 32 pieces a round, the next round's
+  HBM) into a staging slot in VMEM, 32 pieces a round (16 where two slots of
+  32 rows as wide as these leave a tile no room), the next round's
   copies (the next tile's first among them) in flight while this one is
   worked on, 128 staged rows at a time on the MXU. :func:`token_sum` places
   them with a one-hot product: ``O^T [128, bt]`` holds ``scale[t, k]`` where
@@ -56,9 +57,10 @@ __all__ = ["token_sum", "token_dot", "token_sum_plan"]
 
 _PIECE = 16           # rows a copy: a bfloat16 register's sublanes
 _CHUNK = 128          # staged rows a one-hot product
-_ROUND = 32           # pieces in flight: a staging slot holds four chunks
+_ROUNDS = (32, 16)    # pieces in flight: a staging slot holds four chunks, or two where four leave a tile no room
 _LANE_BLOCK = 512     # columns a product: its float32 result stays a few registers' worth
-_BLOCK_TOKENS = (128, 64)   # tokens a tile, where a call has more: the widest that fits
+_BLOCK_TOKENS = 128   # tokens a tile, where a call has more than _ONE_TILE: a tile's tokens lie along whole lanes
+_ONE_TILE = 64        # a call of so few tokens (a decode step) is one tile
 # The call stays inside Mosaic's default scoped limit (16 MiB), and not by
 # choice: XLA fuses the cotangent's sum after the dispatch's backward into the
 # custom call, and the fusion is compiled under the default limit whatever the
@@ -78,13 +80,19 @@ def _tile_pieces_cap(bt: int, K: int, E: int) -> int:
     return bt * K // _PIECE + 2 * min(E, bt * K)
 
 
-def _vmem_bytes(bt: int, D: int, itemsize: int) -> int:
-    """VMEM a tile of ``bt`` tokens holds: the two staging slots, the float32
-    sums, two output blocks, and a chunk's one-hot and product."""
+def _vmem_bytes(bt: int, D: int, itemsize: int, pieces: int) -> int:
+    """VMEM a tile of ``bt`` tokens holds: the two staging slots of ``pieces``
+    pieces, the float32 sums, two output blocks, and a chunk's one-hot and product."""
     lanes = gm.round_up(D, 128)
-    staging = 2 * _ROUND * _PIECE * lanes * itemsize
+    staging = 2 * pieces * _PIECE * lanes * itemsize
     return (staging + bt * lanes * 4 + 2 * bt * lanes * itemsize
             + _CHUNK * bt * 8 + bt * _LANE_BLOCK * 4)
+
+
+def _round_pieces(bt: int, D: int, itemsize: int) -> int:
+    """Pieces a round of a tile of ``bt`` tokens: the most whose two staging slots
+    fit the budget beside the tile, or 0 where none does."""
+    return next((r for r in _ROUNDS if _vmem_bytes(bt, D, itemsize, r) <= _VMEM_BUDGET), 0)
 
 
 def token_sum_plan(T: int, K: int, D: int, rows: int, dtype, backend: Optional[str] = None) -> int:
@@ -97,10 +105,9 @@ def token_sum_plan(T: int, K: int, D: int, rows: int, dtype, backend: Optional[s
     operands fit the VMEM budget."""
     if (backend or gm.default_backend()) != "pallas" or rows % _PIECE:
         return 0
-    if T <= _BLOCK_TOKENS[-1]:
+    if T <= _ONE_TILE:
         return gm.round_up(T, _PIECE)
-    itemsize = jnp.dtype(dtype).itemsize
-    return next((bt for bt in _BLOCK_TOKENS if _vmem_bytes(bt, D, itemsize) <= _VMEM_BUDGET), 0)
+    return _BLOCK_TOKENS if _round_pieces(_BLOCK_TOKENS, D, jnp.dtype(dtype).itemsize) else 0
 
 
 # -- where a tile's rows lie ---------------------------------------------------
@@ -133,7 +140,8 @@ def _tile_pieces(sel_row, sel_held, group_sizes, bt: int, cap: int):
 
 # -- the kernels ---------------------------------------------------------------
 def _staged_chunks(total_ref, src_ref, next_ref, buf_ref, stage_ref, sem_ref, rounds_ref, chunk):
-    """One tile's pieces through the two staging slots in rounds of ``_ROUND``:
+    """One tile's pieces through the two staging slots in rounds of as many pieces
+    as a slot holds (``stage_ref [2, pieces * 16, D]``):
     ``chunk(slot, at, first)`` for every 128 staged rows that hold a piece,
     ``stage_ref[slot, at : at + 128]`` being the tile's staged rows ``first ..``.
     While a round is worked on the next one's copies are in flight, the next
@@ -141,6 +149,7 @@ def _staged_chunks(total_ref, src_ref, next_ref, buf_ref, stage_ref, sem_ref, ro
     (the slot's parity) from tile to tile. Every tile takes a round, with no
     piece where it holds nothing."""
     i, n = pl.program_id(0), pl.num_programs(0)
+    round_pieces = stage_ref.shape[1] // _PIECE
 
     def piece_copy(src, j, slot):
         return pltpu.make_async_copy(
@@ -149,11 +158,11 @@ def _staged_chunks(total_ref, src_ref, next_ref, buf_ref, stage_ref, sem_ref, ro
             sem_ref.at[slot])
 
     def pieces_of(total, r):
-        return jnp.clip(total - r * _ROUND, 0, _ROUND)
+        return jnp.clip(total - r * round_pieces, 0, round_pieces)
 
     def start(pieces_ref, total, r, slot):
         def one(j, carry):
-            piece_copy(pieces_ref[0, r * _ROUND + j], j, slot).start()
+            piece_copy(pieces_ref[0, r * round_pieces + j], j, slot).start()
             return carry
         jax.lax.fori_loop(0, pieces_of(total, r), one, 0)
 
@@ -166,7 +175,7 @@ def _staged_chunks(total_ref, src_ref, next_ref, buf_ref, stage_ref, sem_ref, ro
         start(src_ref, total_ref[0], 0, 0)
 
     total = total_ref[i]
-    n_rounds = jnp.maximum(pl.cdiv(total, _ROUND), 1)
+    n_rounds = jnp.maximum(pl.cdiv(total, round_pieces), 1)
     done = rounds_ref[0]
 
     def one_round(r, carry):
@@ -189,7 +198,7 @@ def _staged_chunks(total_ref, src_ref, next_ref, buf_ref, stage_ref, sem_ref, ro
 
         def one_chunk(c, carry):
             at = pl.multiple_of(c * _CHUNK, _CHUNK)
-            chunk(slot, at, r * (_ROUND * _PIECE) + at)
+            chunk(slot, at, r * (round_pieces * _PIECE) + at)
             return carry
         jax.lax.fori_loop(0, pl.cdiv(pieces * _PIECE, _CHUNK), one_chunk, 0)
         return carry
@@ -270,6 +279,7 @@ def _tiles(buf, sel_row, sel_held, group_sizes, bt, operand_spec, out_spec, scra
     n = T // bt
     cap = _tile_pieces_cap(bt, K, group_sizes.shape[0])
     pos, src, total = _tile_pieces(sel_row, sel_held, group_sizes, bt, cap)
+    pieces = _round_pieces(bt, buf.shape[1], buf.dtype.itemsize) or _ROUNDS[0]
     src = src.reshape(n, 1, cap)
     pieces_spec = functools.partial(pl.BlockSpec, (None, 1, cap), memory_space=pltpu.SMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -284,7 +294,7 @@ def _tiles(buf, sel_row, sel_held, group_sizes, bt, operand_spec, out_spec, scra
         ],
         out_specs=out_spec,
         scratch_shapes=[
-            pltpu.VMEM((2, _ROUND * _PIECE, buf.shape[1]), buf.dtype),
+            pltpu.VMEM((2, pieces * _PIECE, buf.shape[1]), buf.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SMEM((1,), jnp.int32),
             *scratch,

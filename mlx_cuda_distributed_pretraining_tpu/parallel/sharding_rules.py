@@ -33,7 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 _RULES = [
     (r"tok_embeddings\.weight$", ("tp", "fsdp")),  # [V, D] vocab-parallel
     (r"output\.weight$", ("fsdp", "tp")),          # [D, V]
-    # wg: the output gate's projection (models/afmoe.py), column-parallel over heads like wq
+    # wg: the output gate's projection (models/afmoe.py, models/solar_open2.py), column-parallel over heads like wq
     (r"attention\.w[qkvg]\.weight(_q4?)?$", ("fsdp", "tp")),  # [D, H*Dh] column
     (r"attention\.w[qkv]\.weight_s$", ("tp",)),              # [H*Dh]
     (r"attention\.wo\.weight(_q4?)?$", ("tp", "fsdp")),      # [H*Dh, D] row
@@ -71,7 +71,8 @@ _RULES = [
     # mesh its core takes the XLA form, which GSPMD partitions by head); the two low-rank
     # pairs are a replicated down- and a column-parallel up-projection, as latent
     # attention's; its per-channel and per-head leaves are replicated. Its latent layers'
-    # leaves are models/xing.py's (wq straight to the heads: the attention.wq rule).
+    # leaves are models/xing.py's (wq straight to the heads: the attention.wq rule). models/solar_open2.py
+    # has the same kda leaves (twice as wide as its residual stream) beside gated attention's wq, wk, wv, wg, wo.
     (r"kda\.w[qkv]\.weight$", ("fsdp", "tp")),                # [D, H*d] column
     (r"kda\.wo\.weight$", ("tp", "fsdp")),                    # [H*d, D] row
     (r"kda\.[fg]_down\.weight$", ("fsdp", None)),             # [D, d]

@@ -306,7 +306,10 @@ def test_gmm_resident_plan_compiles_for_v5e_at_the_cells_shapes(cell, orientatio
 # chunk of the small loop, top-k, width, rows and groups of its buffer), and a
 # decode step's four tokens through every expert of a small model.
 TOKEN_SUM_CELLS = {"trinity-mini-ep8": (16384, 8, 2048, 67584, 16, {"bfloat16": 128, "float32": 128}),
-                   "xing4_0-29b-a4b-ep8": (2048, 4, 3584, 5120, 8, {"bfloat16": 128, "float32": 0})}
+                   "xing4_0-29b-a4b-ep8": (2048, 4, 3584, 5120, 8, {"bfloat16": 128, "float32": 0}),
+                   # rows 4,096 wide (PR 56): a round of 16 pieces, so that a tile of 128 tokens fits; the
+                   # plan's tile of 64 tokens before it was no whole lane register, which Mosaic refuses
+                   "solar-open2-250b-ep40": (16384, 8, 4096, 14208, 8, {"bfloat16": 128, "float32": 0})}
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
@@ -604,18 +607,20 @@ def test_selective_scan_kernels_compile_for_v5e_at_the_cells_width(v5e, monkeypa
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * S * Di * 4
 
 
-def test_kda_kernels_compile_for_v5e_at_the_cells_call(v5e, monkeypatch):
-    """``kimi-linear-48b-a3b-ep16.train-seq8k``'s delta-rule core (ops/kda.py): 2 rows
-    of 8,192 steps, 32 heads of 128, bfloat16 operands beside a float32 decay,
+@pytest.mark.parametrize("H", [32, 64])
+def test_kda_kernels_compile_for_v5e_at_the_cells_call(v5e, monkeypatch, H):
+    """``kimi-linear-48b-a3b-ep16.train-seq8k``'s delta-rule core (ops/kda.py) and
+    ``solar-open2-250b-ep40.train-seq8k``'s: 2 rows
+    of 8,192 steps, 32 | 64 heads of 128, bfloat16 operands beside a float32 decay,
     forward and backward: Mosaic takes both kernels (one head's lanes of a chunk
     as a block of ``[B, S, H d]``, ``beta`` as a column and as a row, the float32
     solve, the pairs' seven levels of masked matmuls), the differentiated call holds one
     forward with its saved states and one backward, and nothing larger than the
-    states at chunk starts (0.5 GiB) is left in the program."""
+    states at chunk starts (0.5 | 1 GiB) is left in the program."""
     from mlx_cuda_distributed_pretraining_tpu.ops import kda
 
     monkeypatch.setattr(kda, "_interpret", lambda: False)
-    Bt, S, H, d = 2, 8192, 32, 128
+    Bt, S, d = 2, 8192, 128
     ops = tuple(_sds((Bt, S, H, d), t, v5e) for t in (jnp.bfloat16,) * 3 + (jnp.float32,)) \
         + (_sds((Bt, S, H), jnp.float32, v5e),)
     before = kda.plan_counts()
